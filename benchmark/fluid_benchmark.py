@@ -40,7 +40,12 @@ def parse_args(argv=None):
     p.add_argument("--skip_batch_num", type=int, default=5)
     p.add_argument("--iterations", type=int, default=30)
     p.add_argument("--pass_num", type=int, default=1)
-    p.add_argument("--device", default="TPU", choices=["CPU", "TPU"])
+    p.add_argument(
+        "--device", default="TPU", choices=["CPU", "TPU"],
+        help="TPU: fluid.TPUPlace(), an error where JAX finds no TPU. CPU: "
+        "fluid.CPUPlace(), which pins nothing (JAX's default backend; run "
+        "under JAX_PLATFORMS=cpu to mean the host)",
+    )
     p.add_argument("--data_set", default="flowers",
                    choices=["cifar10", "flowers", "imagenet"])
     p.add_argument("--infer_only", action="store_true")

@@ -9,7 +9,11 @@ isn't enough.
 `import paddle_tpu.fluid as fluid` is a drop-in for `import paddle.fluid`.
 """
 
-from . import (
+from . import platform_setup
+
+platform_setup.place_compile_cache()
+
+from . import (  # noqa: E402
     backward,
     clip,
     dataset,

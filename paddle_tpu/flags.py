@@ -76,14 +76,16 @@ Honored flags:
 - serving_cache_dir: default persistent compile-cache directory for the
   serving runtime (serving/compile_cache.py): ServingEngine instances built
   without an explicit cache_dir store/load serialized jax.export artifacts
-  here, and JAX's persistent XLA-executable cache is pointed at its xla/
-  subdir — a warm replica cold-starts without tracing or compiling
-  (docs/serving.md); "" (default) disables the persistent layer (variants
-  still cache in-process).
+  here — a warm replica cold-starts without tracing (docs/serving.md); ""
+  (default) disables this layer (variants still cache in-process). JAX's
+  persistent XLA-executable cache is not placed by this flag: it lives
+  where JAX_COMPILATION_CACHE_DIR says, else in <checkout>/.jax_cache
+  (platform_setup.place_compile_cache).
 - paged_flash: dispatch tier for the paged flash-attention serving kernel
   (ops/pallas_kernels.paged_flash_attention, the decode/chunked-prefill
   fast path behind the paged_attention lowering). "auto" (default) takes
-  the Pallas kernel on a real TPU and the dense flat-gather reference
+  the Pallas kernel on a real TPU when a page is a multiple of 8 rows (all
+  Mosaic asks of the geometry) and the dense flat-gather reference
   elsewhere (an interpreted kernel in the decode hot loop is slower than
   dense XLA on the CPU test mesh); "on" forces the kernel everywhere —
   interpret mode off-TPU, how the hermetic parity tests pin it; "off"

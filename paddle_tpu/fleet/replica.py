@@ -19,8 +19,11 @@ Spec (JSON):
                          published versions to the predict engine and acks
                          as `name` — the router's staleness gate reads
                          those acks
-  port_file            where to atomically write {"port", "url", "pid"}
-                       once serving (the parent's readiness rendezvous)
+  port_file            where to atomically write {"port", "url", "pid",
+                       "device_kind"} once serving (the parent's readiness
+                       rendezvous; device_kind is what the child's JAX
+                       reports, so a replica meant for a chip that came up
+                       on the host's CPU is seen, not assumed)
   router_url           optional: self-register with the fleet router
 
 Both entry points stay import-light at module load so the launcher can be
@@ -131,8 +134,11 @@ def main(argv=None):
 
     port = server.start()
     if spec.get("port_file"):
+        import jax
+
         _atomic_json(spec["port_file"], {
             "name": name, "port": port, "url": server.url, "pid": os.getpid(),
+            "device_kind": jax.devices()[0].device_kind,
         })
     if spec.get("router_url"):
         _register_with_router(spec["router_url"], name, server.url)
@@ -159,6 +165,11 @@ class ReplicaProcess:
     chaos primitive); terminate() is the polite SIGTERM drain. restart()
     re-spawns with the same spec — same name, so after its HotReloader
     re-acks, the router's staleness gate lets it rejoin.
+
+    The child inherits the parent's environment plus `env` and nothing
+    else: which device it serves from is the caller's statement. A chip
+    belongs to one process, so a parent that holds it (or wants CPU
+    replicas) passes env={"JAX_PLATFORMS": "cpu"}.
     """
 
     def __init__(self, spec, workdir, env=None, faults=None):
@@ -187,7 +198,6 @@ class ReplicaProcess:
             pass
         _atomic_json(self.spec_path, self.spec)
         env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
         env.update(self._extra_env)
         self._log = open(self.log_path, "ab")
         self.proc = subprocess.Popen(

@@ -8,11 +8,14 @@ decision here: C++ threads parse/decompress while XLA runs; Python only sees
 packed numpy buffers.
 
 The shared library is built on demand with g++ (the toolchain is part of the
-image; there is no pip build step), cached next to the source, and rebuilt
-when the source is newer.
+image; there is no pip build step) and cached next to the source under a
+name that carries a hash of the source's bytes: the .so is git-ignored and
+trees get copied with their mtimes scrambled, so only the content can say
+whether a library on disk was built from this native.cc.
 """
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -21,17 +24,22 @@ import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "src", "native.cc")
-_LIB = os.path.join(_DIR, "src", "libptnative.so")
 
 _lib = None
 _lib_lock = threading.Lock()
 
 
-def _build():
+def _lib_path():
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_DIR, "src", "libptnative-%s.so" % digest)
+
+
+def _build(lib_path):
     # compile to a temp path and rename into place: concurrent processes
     # (pytest workers, multi-process trainers) may race the build, and a
     # half-written .so must never be dlopen-able
-    tmp = "%s.%d.tmp" % (_LIB, os.getpid())
+    tmp = "%s.%d.tmp" % (lib_path, os.getpid())
     cmd = [
         "g++",
         "-O2",
@@ -49,7 +57,7 @@ def _build():
         raise RuntimeError(
             "native runtime build failed (%s):\n%s" % (" ".join(cmd), proc.stderr)
         )
-    os.replace(tmp, _LIB)
+    os.replace(tmp, lib_path)
 
 
 def lib():
@@ -59,11 +67,10 @@ def lib():
     with _lib_lock:
         if _lib is not None:
             return _lib
-        if not os.path.exists(_LIB) or os.path.getmtime(_LIB) < os.path.getmtime(
-            _SRC
-        ):
-            _build()
-        L = ctypes.CDLL(_LIB)
+        lib_path = _lib_path()
+        if not os.path.exists(lib_path):
+            _build(lib_path)
+        L = ctypes.CDLL(lib_path)
         L.rio_writer_open.restype = ctypes.c_void_p
         L.rio_writer_open.argtypes = [
             ctypes.c_char_p,

@@ -895,6 +895,35 @@ def _bwd(causal, sm_scale, block_q, block_k, interpret, res, dout):
 flash_attention.defvjp(_fwd, _bwd)
 
 
+def _flash_on_mesh(ctx, fn, args, out_ranks):
+    """fn(*args) as the flash ops run it: as is on one device, and under
+    shard_map on an SPMD mesh, where GSPMD cannot partition a Mosaic kernel
+    (see _mesh_declines). Attention is independent per (batch, head), so the
+    batch of the (b, h, t, d) operands splits over the data axes and the
+    heads over tp with no collective; a dim the axes do not divide stays
+    whole on every device. Operands and results are rank 4, (b, h, t, d),
+    or rank 3, the (b, h, t) lse; out_ranks gives the results'."""
+    mesh = ctx.mesh
+    if mesh is None or mesh.size == 1:
+        return fn(*args)
+    from jax.sharding import PartitionSpec as P
+
+    from ..parallel.collectives import shard_map
+
+    b, h = args[0].shape[:2]
+    data = tuple(a for a in ("dp", "fsdp") if mesh.shape.get(a, 1) > 1)
+    if not data or b % int(np.prod([mesh.shape[a] for a in data])):
+        data = None
+    tp = "tp" if mesh.shape.get("tp", 1) > 1 and h % mesh.shape["tp"] == 0 else None
+    spec = {4: P(data, tp, None, None), 3: P(data, tp, None)}
+    return shard_map(
+        fn, mesh=mesh,
+        in_specs=tuple(spec[a.ndim] for a in args),
+        out_specs=tuple(spec[r] for r in out_ranks),
+        check_vma=False,
+    )(*args)
+
+
 @register("flash_attention")
 def _flash_attention_op(ctx, ins, attrs):
     """Graph-op form: Q/K/V (b, h, t, d) → Out (+ Lse residual). The
@@ -911,13 +940,17 @@ def _flash_attention_op(ctx, ins, attrs):
     (v,) = ins["V"]
     causal = bool(attrs.get("causal", False))
     sm_scale, interpret = _resolve_defaults(q, attrs.get("sm_scale"), None)
-    out, lse = _flash_forward(
-        q, k, v, causal, sm_scale, None, None, interpret, with_lse=True
+    if not flash_path_taken(q.shape[2], k.shape[2], causal):
+        # ragged tiles: the dense form, plain XLA that GSPMD partitions
+        return {"Out": [_attention_reference(q, k, v, causal, sm_scale)]}
+    out, lse = _flash_on_mesh(
+        ctx,
+        lambda q, k, v: _flash_forward(
+            q, k, v, causal, sm_scale, None, None, interpret, with_lse=True
+        ),
+        (q, k, v), (4, 3),
     )
-    res = {"Out": [out]}
-    if lse is not None:
-        res["Lse"] = [lse]
-    return res
+    return {"Out": [out], "Lse": [lse]}
 
 
 @register("flash_attention_grad", no_grad=True)
@@ -940,8 +973,13 @@ def _flash_attention_grad_op(ctx, ins, attrs):
         dq, dk, dv = vjp(dout.astype(q.dtype))
     else:
         (out,) = ins["Out"]
-        dq, dk, dv = _flash_backward(
-            q, k, v, out, lse, dout, causal, sm_scale, None, None, interpret
+        dq, dk, dv = _flash_on_mesh(
+            ctx,
+            lambda q, k, v, out, lse, dout: _flash_backward(
+                q, k, v, out, lse, dout, causal, sm_scale, None, None,
+                interpret,
+            ),
+            (q, k, v, out, lse, dout), (4, 4, 4),
         )
     return {"Q@GRAD": [dq], "K@GRAD": [dk], "V@GRAD": [dv]}
 
@@ -966,11 +1004,12 @@ _DEF_GEMM_BLOCK_K = 512
 
 # epilogue activations the kernel computes on the f32 accumulator before the
 # single rounding to the output dtype; must stay the exact functions
-# core_ops registers (gelu is the erf form, approximate=False) or fused/
-# unfused parity drifts beyond rounding
+# core_ops registers or fused/unfused parity drifts beyond rounding. Exact
+# gelu (the erf form core_ops registers) is absent: the Pallas TPU lowering
+# has neither erf nor erfc, so fuse_gemm_epilogue leaves a gelu per-op and
+# tags only the GEMM+bias in front of it.
 _GEMM_ACT_F32 = {
     "relu": lambda z: jnp.maximum(z, 0.0),
-    "gelu": lambda z: jax.nn.gelu(z, approximate=False),
     "tanh": jnp.tanh,
     "sigmoid": jax.nn.sigmoid,
 }
@@ -1070,8 +1109,8 @@ def _gemm_bias_act_dbuf(x2, w2, bias_row, act, bm, bn, bk, interpret):
     grid = (m // bm, n // bn)
     nk = k // bk
     in_specs = [
-        pl.BlockSpec(memory_space=pltpu.ANY),
-        pl.BlockSpec(memory_space=pltpu.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
         pl.BlockSpec((1, bn), lambda mi, ni: (0, ni)),
     ]
     out_spec = pl.BlockSpec((bm, bn), lambda mi, ni: (mi, ni))
@@ -1088,7 +1127,7 @@ def _gemm_bias_act_dbuf(x2, w2, bias_row, act, bm, bn, bk, interpret):
         flops=2 * m * n * k,
         bytes_accessed=(x2.size + w2.size) * x2.dtype.itemsize
         + (2 if act else 1) * m * n * x2.dtype.itemsize,
-        transcendentals=m * n if act in ("gelu", "tanh", "sigmoid") else 0,
+        transcendentals=m * n if act in ("tanh", "sigmoid") else 0,
     )
     if act is None:
         z = pl.pallas_call(
@@ -1186,7 +1225,7 @@ def gemm_bias_act(x2, w2, bias_row, act=None, *, block_m=None, block_n=None,
         flops=2 * m * n * k,
         bytes_accessed=(x2.size + w2.size) * x2.dtype.itemsize
         + (2 if act else 1) * m * n * x2.dtype.itemsize,
-        transcendentals=m * n if act in ("gelu", "tanh", "sigmoid") else 0,
+        transcendentals=m * n if act in ("tanh", "sigmoid") else 0,
     )
     if act is None:
         z = pl.pallas_call(
@@ -1359,7 +1398,7 @@ def quant_gemm_bias_act(x2, w2, scale, bias_row=None, act=None, *,
         flops=2 * m * n * k,
         bytes_accessed=(x2.size + w2.size) * x2.dtype.itemsize
         + (2 if act else 1) * m * n * jnp.dtype(out_dtype).itemsize,
-        transcendentals=m * n if act in ("gelu", "tanh", "sigmoid") else 0,
+        transcendentals=m * n if act in ("tanh", "sigmoid") else 0,
     )
     scratch = [pltpu.VMEM((bm, bn), acc_dtype)]
     if act is None:
@@ -1419,11 +1458,14 @@ def paged_flash_path_taken(n_q, n_pages, page_size, n_head, d_head):
     """EXACT mirror of the paged_attention lowering's kernel-vs-dense
     decision. The paged_flash flag picks the tier: "off" always takes the
     dense flat-gather reference; "on" always takes the kernel (interpret
-    mode off-TPU — the hermetic parity tests force this); "auto" (default)
-    takes the kernel only on a real TPU, because an interpreted Pallas body
-    in the decode hot loop is slower than the dense XLA gather on the CPU
-    test mesh. Geometry beyond this never declines: the kernel walks any
-    (pages, page_size, heads) layout page by page."""
+    mode off-TPU — the hermetic parity tests force this — and on a TPU
+    whatever Mosaic says about the geometry is raised, not hidden); "auto"
+    (default) takes the kernel only on a real TPU, because an interpreted
+    Pallas body in the decode hot loop is slower than the dense XLA gather
+    on the CPU test mesh, and only when a page is a whole number of 8-row
+    sublane tiles — the one geometry limit of the (page_size, n_head*d)
+    pool blocks (compile-checked for v5e: 2x16 up to 16x128 heads, pages of
+    8..128 rows, f32 and int8 pools)."""
     from .. import flags as _flags
 
     mode = _flags.get_flags("paged_flash")["paged_flash"]
@@ -1433,48 +1475,41 @@ def paged_flash_path_taken(n_q, n_pages, page_size, n_head, d_head):
         return False
     if mode == "on":
         return True
-    return jax.default_backend() == "tpu"
+    return jax.default_backend() == "tpu" and int(page_size) % 8 == 0
 
 
-def _paged_flash_update(s, live, v2, acc_ref, m_ref, l_ref):
-    """One page's online-softmax step. s is [rows, page_size] f32 scores
-    (masked entries already -inf), live the same-shaped mask, v2 the page's
-    [page_size, d] V rows. Carries (m, l, acc) live in VMEM scratch; m/l are
-    lane-broadcast like the flash kernels above."""
-    m_prev = m_ref[:, :1]
-    m_cur = jnp.max(s, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    # alpha rescales the old accumulator; exp(-inf - -inf) = nan, so pin
-    # never-seen rows (m_prev = -inf) to alpha = 0 explicitly
-    alpha = jnp.exp(jnp.where(m_prev == -jnp.inf, -jnp.inf, m_prev - m_new))
-    p = jnp.exp(s - m_new)
-    p = jnp.where(live, p, 0.0)  # kills the -inf - -inf nan on dead rows too
-    l_new = l_ref[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v2, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
-
-
-def _paged_flash_emit(o_ref, acc_ref, l_ref):
-    # safe softmax tail: a fully-masked row (pos < 0, nothing live) has
-    # l = 0 and emits zeros instead of 0/0 nan — the where-mask contract
-    # the dense reference shares
-    l = l_ref[:, :1]
-    o_ref[...] = (acc_ref[...] / jnp.where(l > 0.0, l, 1.0))[:, None, :].astype(
-        o_ref.dtype
-    )
-
-
-def _paged_flash_decode_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                               acc_ref, m_ref, l_ref, *, page_size, sm_scale):
-    """grid (slot, head, page): per-slot block tables, one query row each.
-    The block table rides in as a scalar-prefetch operand so the K/V
-    BlockSpec index_map can chase pages; pos masks inside the loop."""
-    si = pl.program_id(0)
-    pi = pl.program_id(2)
-    pos = pos_ref[si]
+def _paged_flash_kernel(*refs, page_size, n_head, sm_scale, per_row, quant):
+    """One K/V page against every query row the program owns, heads walked
+    in the kernel over lane slices of the whole-feature (rows, n_head*d)
+    blocks. per_row (decode): grid (slot, page), the slot's one query row,
+    its own block-table row, pos a scalar out of the prefetched pos vector.
+    Shared (chunked prefill): grid (page,), every row of the chunk against
+    the one shared table; per-row positions arrive as a (rows, 1) VMEM
+    block and the scalar-prefetched max of them gates whole pages. The
+    block tables ride in as scalar-prefetch operands so the K/V BlockSpec
+    index_map can chase pages. quant: K/V pages are int8 levels with a
+    (page_size, 1) f32 per-row scale block chasing the same table entry —
+    dequantized here, so f32 rows of an int8 pool exist only in VMEM. The
+    online-softmax carries (m, l per head, lane-broadcast like the flash
+    kernels above; acc whole-feature) live in VMEM scratch."""
+    bt_ref, sp_ref = refs[:2]
+    refs = refs[2:]
+    if per_row:
+        pi, n_pi = pl.program_id(1), pl.num_programs(1)
+        pos = last = sp_ref[pl.program_id(0)]
+    else:
+        posv_ref, refs = refs[0], refs[1:]
+        pi, n_pi = pl.program_id(0), pl.num_programs(0)
+        pos = posv_ref[...]  # (rows, 1)
+        last = sp_ref[0]
+    q_ref, k_ref, v_ref = refs[:3]
+    refs = refs[3:]
+    if quant:
+        ks_ref, vs_ref = refs[:2]
+        refs = refs[2:]
+    o_ref, acc_ref, m_ref, l_ref = refs
+    rows = q_ref.shape[0]
+    d = q_ref.shape[1] // n_head
 
     @pl.when(pi == 0)
     def _init():
@@ -1482,133 +1517,54 @@ def _paged_flash_decode_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(pi * page_size <= pos)  # pages wholly past pos: skip the MXU
+    @pl.when(pi * page_size <= last)  # pages wholly past pos: skip the MXU
     def _page():
-        q2 = q_ref[:, 0, :]  # (1, d)
-        k2 = k_ref[:, 0, :]  # (page_size, d)
-        s = jax.lax.dot_general(
-            q2, k2, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * sm_scale  # (1, page_size)
         offs = pi * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1
+            jnp.int32, (rows, page_size), 1
         )
         live = offs <= pos
-        s = jnp.where(live, s, -jnp.inf)
-        _paged_flash_update(s, live, v_ref[:, 0, :], acc_ref, m_ref, l_ref)
+        for h in range(n_head):
+            sl = slice(h * d, (h + 1) * d)
+            q2 = q_ref[:, sl]  # (rows, d)
+            k2 = k_ref[:, sl]  # (page_size, d)
+            v2 = v_ref[:, sl]
+            if quant:
+                q2 = q2.astype(jnp.float32)
+                k2 = k2.astype(jnp.float32) * ks_ref[...]
+                v2 = v2.astype(jnp.float32) * vs_ref[...]
+            s = jax.lax.dot_general(
+                q2, k2, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * sm_scale  # (rows, page_size)
+            s = jnp.where(live, s, -jnp.inf)
+            m_prev = m_ref[h][:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            # alpha rescales the old accumulator; exp(-inf - -inf) = nan, so
+            # pin never-seen rows (m_prev = -inf) to alpha = 0 explicitly
+            alpha = jnp.exp(
+                jnp.where(m_prev == -jnp.inf, -jnp.inf, m_prev - m_new)
+            )
+            # the where kills the -inf - -inf nan on dead rows too
+            p = jnp.where(live, jnp.exp(s - m_new), 0.0)
+            l_new = l_ref[h][:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[:, sl] = acc_ref[:, sl] * alpha + jax.lax.dot_general(
+                p, v2, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+            l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
 
-    @pl.when(pi == pl.num_programs(2) - 1)
+    @pl.when(pi == n_pi - 1)
     def _emit():
-        _paged_flash_emit(o_ref, acc_ref, l_ref)
-
-
-def _paged_flash_shared_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                               acc_ref, m_ref, l_ref, *, page_size, sm_scale):
-    """grid (head, page): ONE block table shared by every query row (the
-    chunked-prefill form — a chunk's rows all walk the same slot's pages),
-    so each page is streamed into VMEM once for all rows instead of once
-    per row."""
-    pi = pl.program_id(1)
-    pos = pos_ref[...]  # (rows,)
-
-    @pl.when(pi == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    @pl.when(pi * page_size <= jnp.max(pos))
-    def _page():
-        q2 = q_ref[:, 0, :]  # (rows, d)
-        k2 = k_ref[:, 0, :]  # (page_size, d)
-        s = jax.lax.dot_general(
-            q2, k2, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * sm_scale  # (rows, page_size)
-        offs = pi * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1
-        )
-        live = offs <= pos[:, None]
-        s = jnp.where(live, s, -jnp.inf)
-        _paged_flash_update(s, live, v_ref[:, 0, :], acc_ref, m_ref, l_ref)
-
-    @pl.when(pi == pl.num_programs(1) - 1)
-    def _emit():
-        _paged_flash_emit(o_ref, acc_ref, l_ref)
-
-
-def _paged_flash_decode_quant_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref,
-                                     ks_ref, vs_ref, o_ref, acc_ref, m_ref,
-                                     l_ref, *, page_size, sm_scale):
-    """int8-pool twin of _paged_flash_decode_kernel: K/V pages arrive as
-    int8 levels plus a per-row f32 scale vector per page (chasing the same
-    block table), and the dequantize multiply happens in VMEM on the page
-    walk — the f32 rows never exist in HBM, which is the whole point (the
-    pool at half the bytes holds twice the slots)."""
-    si = pl.program_id(0)
-    pi = pl.program_id(2)
-    pos = pos_ref[si]
-
-    @pl.when(pi == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    @pl.when(pi * page_size <= pos)
-    def _page():
-        q2 = q_ref[:, 0, :].astype(jnp.float32)  # (1, d)
-        k2 = k_ref[:, 0, :].astype(jnp.float32) * ks_ref[0, :][:, None]
-        v2 = v_ref[:, 0, :].astype(jnp.float32) * vs_ref[0, :][:, None]
-        s = jax.lax.dot_general(
-            q2, k2, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * sm_scale
-        offs = pi * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1
-        )
-        live = offs <= pos
-        s = jnp.where(live, s, -jnp.inf)
-        _paged_flash_update(s, live, v2, acc_ref, m_ref, l_ref)
-
-    @pl.when(pi == pl.num_programs(2) - 1)
-    def _emit():
-        _paged_flash_emit(o_ref, acc_ref, l_ref)
-
-
-def _paged_flash_shared_quant_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref,
-                                     ks_ref, vs_ref, o_ref, acc_ref, m_ref,
-                                     l_ref, *, page_size, sm_scale):
-    """int8-pool twin of _paged_flash_shared_kernel (chunked prefill — one
-    block table, one scale vector per page shared by every row)."""
-    pi = pl.program_id(1)
-    pos = pos_ref[...]  # (rows,)
-
-    @pl.when(pi == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    @pl.when(pi * page_size <= jnp.max(pos))
-    def _page():
-        q2 = q_ref[:, 0, :].astype(jnp.float32)  # (rows, d)
-        k2 = k_ref[:, 0, :].astype(jnp.float32) * ks_ref[0, :][:, None]
-        v2 = v_ref[:, 0, :].astype(jnp.float32) * vs_ref[0, :][:, None]
-        s = jax.lax.dot_general(
-            q2, k2, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * sm_scale
-        offs = pi * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1
-        )
-        live = offs <= pos[:, None]
-        s = jnp.where(live, s, -jnp.inf)
-        _paged_flash_update(s, live, v2, acc_ref, m_ref, l_ref)
-
-    @pl.when(pi == pl.num_programs(1) - 1)
-    def _emit():
-        _paged_flash_emit(o_ref, acc_ref, l_ref)
+        # safe softmax tail: a fully-masked row (pos < 0, nothing live) has
+        # l = 0 and emits zeros instead of 0/0 nan — the where-mask contract
+        # the dense reference shares
+        for h in range(n_head):
+            sl = slice(h * d, (h + 1) * d)
+            l = l_ref[h][:, :1]
+            o_ref[:, sl] = (
+                acc_ref[:, sl] / jnp.where(l > 0.0, l, 1.0)
+            ).astype(o_ref.dtype)
 
 
 def paged_flash_attention(q, k_pool, v_pool, block_table, pos, *, n_head,
@@ -1623,114 +1579,78 @@ def paged_flash_attention(q, k_pool, v_pool, block_table, pos, *, n_head,
     bit-identical, vs the dense reference (the online softmax reassociates
     the sum).
 
+    The pools are read in place as [pool_rows, n_head*d]: one page is one
+    (page_size, n_head*d) block, legal for Mosaic whenever page_size is a
+    multiple of 8, with no relayout of the pool around the call.
+
     k_scales/v_scales (both or neither): the pools hold int8 levels and
     [pool_rows] f32 per-row scales ride along; the kernel dequantizes
-    inline on the block-table walk (each page's scale vector chases the
+    inline on the block-table walk (each page's scale column chases the
     same table entry as its K/V rows), so dequantized f32 rows exist only
     in VMEM."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     rows, feat = q.shape
     d = feat // n_head
-    scale = float(sm_scale or 0.0) or d**-0.5
-    q3 = q.reshape(rows, n_head, d)
-    k3 = k_pool.reshape(-1, n_head, d)
-    v3 = v_pool.reshape(-1, n_head, d)
     bt = block_table.astype(jnp.int32)
     pos_v = pos.reshape(-1).astype(jnp.int32)
     quant = k_scales is not None
-    operands = [bt, pos_v, q3, k3, v3]
-    if quant:
-        # one f32 scale per pool row, page-structured so a (1, page_size)
-        # block can chase the block table like the K/V pages do
-        operands += [
-            k_scales.reshape(-1, page_size).astype(jnp.float32),
-            v_scales.reshape(-1, page_size).astype(jnp.float32),
-        ]
+    per_row = bt.ndim == 2
     _note_dispatch("paged_flash_int8" if quant else "paged_flash")
-    if bt.ndim == 1:
-        n_pages = bt.shape[0]
-        in_specs = [
-            pl.BlockSpec((rows, 1, d), lambda h, p, bt_r, pos_r: (0, h, 0)),
-            pl.BlockSpec(
-                (page_size, 1, d),
-                lambda h, p, bt_r, pos_r: (bt_r[p], h, 0),
-            ),
-            pl.BlockSpec(
-                (page_size, 1, d),
-                lambda h, p, bt_r, pos_r: (bt_r[p], h, 0),
-            ),
-        ]
-        if quant:
-            in_specs += [
-                pl.BlockSpec(
-                    (1, page_size), lambda h, p, bt_r, pos_r: (bt_r[p], 0)
-                ),
-            ] * 2
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(n_head, n_pages),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (rows, 1, d), lambda h, p, bt_r, pos_r: (0, h, 0)
-            ),
-            scratch_shapes=[
-                pltpu.VMEM((rows, d), jnp.float32),
-                pltpu.VMEM((rows, _LANES), jnp.float32),
-                pltpu.VMEM((rows, _LANES), jnp.float32),
-            ],
+    # a dead page (wholly past pos) re-names the last live one: the block
+    # index does not change, so the pipeline fetches nothing for it
+    if per_row:
+        grid = (rows, bt.shape[1])
+        q_spec = pl.BlockSpec((None, 1, feat), lambda s, p, bt_r, pos_r: (s, 0, 0))
+        page_map = lambda s, p, bt_r, pos_r: (
+            bt_r[s, jnp.minimum(p, jnp.maximum(pos_r[s], 0) // page_size)], 0
         )
-        kernel = functools.partial(
-            _paged_flash_shared_quant_kernel if quant
-            else _paged_flash_shared_kernel,
-            page_size=page_size, sm_scale=scale,
-        )
+        prefetch = [bt, pos_v]
+        # (rows, 1, feat): a (1, feat) block per slot is legal only as the
+        # whole of the last two dimensions
+        operands, in_specs = [q.reshape(rows, 1, feat)], [q_spec]
+        out_shape = jax.ShapeDtypeStruct((rows, 1, feat), q.dtype)
+        r = 1
     else:
-        n_pages = bt.shape[1]
-        in_specs = [
-            pl.BlockSpec(
-                (1, 1, d), lambda s, h, p, bt_r, pos_r: (s, h, 0)
-            ),
-            pl.BlockSpec(
-                (page_size, 1, d),
-                lambda s, h, p, bt_r, pos_r: (bt_r[s, p], h, 0),
-            ),
-            pl.BlockSpec(
-                (page_size, 1, d),
-                lambda s, h, p, bt_r, pos_r: (bt_r[s, p], h, 0),
-            ),
+        grid = (bt.shape[0],)
+        whole = lambda p, bt_r, last_r: (0, 0)
+        q_spec = pl.BlockSpec((rows, feat), whole)
+        page_map = lambda p, bt_r, last_r: (
+            bt_r[jnp.minimum(p, jnp.maximum(last_r[0], 0) // page_size)], 0
+        )
+        prefetch = [bt, jnp.max(pos_v).reshape(1)]
+        operands = [pos_v.reshape(rows, 1), q]
+        in_specs = [pl.BlockSpec((rows, 1), whole), q_spec]
+        out_shape = jax.ShapeDtypeStruct((rows, feat), q.dtype)
+        r = rows
+    operands += [k_pool, v_pool]
+    in_specs += [pl.BlockSpec((page_size, feat), page_map)] * 2
+    if quant:
+        operands += [
+            k_scales.reshape(-1, 1).astype(jnp.float32),
+            v_scales.reshape(-1, 1).astype(jnp.float32),
         ]
-        if quant:
-            in_specs += [
-                pl.BlockSpec(
-                    (1, page_size),
-                    lambda s, h, p, bt_r, pos_r: (bt_r[s, p], 0),
-                ),
-            ] * 2
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(rows, n_head, n_pages),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (1, 1, d), lambda s, h, p, bt_r, pos_r: (s, h, 0)
-            ),
-            scratch_shapes=[
-                pltpu.VMEM((1, d), jnp.float32),
-                pltpu.VMEM((1, _LANES), jnp.float32),
-                pltpu.VMEM((1, _LANES), jnp.float32),
-            ],
-        )
-        kernel = functools.partial(
-            _paged_flash_decode_quant_kernel if quant
-            else _paged_flash_decode_kernel,
-            page_size=page_size, sm_scale=scale,
-        )
+        in_specs += [pl.BlockSpec((page_size, 1), page_map)] * 2
     out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((rows, n_head, d), q.dtype),
+        functools.partial(
+            _paged_flash_kernel, page_size=page_size, n_head=n_head,
+            sm_scale=float(sm_scale or 0.0) or d**-0.5,
+            per_row=per_row, quant=quant,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((r, feat), jnp.float32),
+                pltpu.VMEM((n_head, r, _LANES), jnp.float32),
+                pltpu.VMEM((n_head, r, _LANES), jnp.float32),
+            ],
+        ),
+        out_shape=out_shape,
         interpret=interpret,
-    )(*operands)
+    )(*prefetch, *operands)
     return out.reshape(rows, feat)
 
 
@@ -1775,27 +1695,20 @@ def _welford_cols(s32, cols, col_chunk):
     """One-pass Welford over the column axis of an f32 (rows, chunk-multiple)
     value, merging per-chunk moments with the parallel combination — numerics
     match jnp.mean/jnp.var to f32 rounding without the naive sum-of-squares
-    cancellation at large |mean|."""
-    nc = cols // col_chunk
-
-    def body(ci, carry):
-        count, mean, m2 = carry
-        blk = jax.lax.dynamic_slice_in_dim(s32, ci * col_chunk, col_chunk, 1)
+    cancellation at large |mean|. The chunks are static lane-aligned slices
+    (a Python range): Mosaic has no dynamic_slice on a value."""
+    rows = s32.shape[0]
+    mean = jnp.zeros((rows,), jnp.float32)
+    m2 = jnp.zeros((rows,), jnp.float32)
+    for ci in range(cols // col_chunk):
+        blk = s32[:, ci * col_chunk:(ci + 1) * col_chunk]
         bmean = jnp.mean(blk, axis=1)
         bm2 = jnp.sum(jnp.square(blk - bmean[:, None]), axis=1)
+        count = ci * col_chunk
         tot = count + col_chunk
         delta = bmean - mean
         mean = mean + delta * (col_chunk / tot)
         m2 = m2 + bm2 + jnp.square(delta) * (count * col_chunk / tot)
-        return tot, mean, m2
-
-    rows = s32.shape[0]
-    init = (
-        jnp.float32(0.0),
-        jnp.zeros((rows,), jnp.float32),
-        jnp.zeros((rows,), jnp.float32),
-    )
-    _, mean, m2 = jax.lax.fori_loop(0, nc, body, init)
     return mean, m2 / cols  # biased variance — the layer_norm contract
 
 
@@ -1979,6 +1892,12 @@ def fused_layer_norm_grad(x2, scale, mean, var, dy2, eps, *, interpret=None):
 # ---------------------------------------------------------------------------
 
 _ADAM_CHUNK_ROWS = 256  # 256x128 = 32k elements per grid step
+# elements one kernel call packs. The flattened copies of a call's params,
+# grads and moments (in and out) are live in HBM together, so one call over
+# the whole bench transformer (0.67G params) needed a second copy of the
+# model: XLA's TPU compiler put that step 0.5 GB over a v5e's 15.75 GB. A
+# few calls over bounded slabs fit with room to spare.
+_ADAM_CALL_ELEMS = 1 << 26
 
 
 def adam_path_taken(n_params, zero1=False, sharded=False):
@@ -1993,8 +1912,9 @@ def adam_path_taken(n_params, zero1=False, sharded=False):
 def _multi_adam_kernel(c2p_ref, lrt_ref, p_ref, g_ref, m1_ref, m2_ref,
                        po_ref, m1o_ref, m2o_ref, *, beta1, beta2, eps):
     """One chunk: the EXACT _adam update expressions (core_ops) on the f32
-    upcast, rounded to the storage dtypes on write — bit-identical to the
-    unfused per-param chain where that chain's math is f32. lr_t (per param,
+    upcast, rounded to the storage dtypes on write — within a few ulps of the
+    unfused per-param chain where that chain's math is f32 (the same
+    expressions; where XLA contracts an FMA is not fixed). lr_t (per param,
     bias correction included) rides a scalar-prefetch table indexed by the
     chunk->param map."""
     i = pl.program_id(0)
@@ -2091,13 +2011,23 @@ class _Shape2:
         self.ndim = len(self.shape)
 
 
-def _rules_sharded(ctx, ops):
-    """True when the declarative rule engine (ctx.sharding, a
-    parallel.sharding_rules.Resolver) places any of the run's operands or
-    results on this mesh. The tiled/flattened kernels assume whole local
-    tensors — a tp-sharded weight or fsdp-sharded param would be gathered
-    around an opaque pallas_call, defeating the placement — so tagged runs
-    decline to per-op lowering, where GSPMD partitions op by op."""
+def _mesh_declines(ctx, ops):
+    """True when a tagged run must decline to per-op lowering, where GSPMD
+    partitions op by op, because of the mesh it compiles for. Two reasons:
+
+    - Mosaic kernels for a mesh of more than one device: GSPMD refuses them
+      ("Mosaic kernels cannot be automatically partitioned. Please wrap the
+      call in a shard_map"). Interpret mode — the CPU test mesh — lowers to
+      plain XLA ops and is not affected. (The flash ops have no per-op form
+      worth declining to and run under shard_map: _flash_shard_map.)
+    - the declarative rule engine (ctx.sharding, a
+      parallel.sharding_rules.Resolver) places any of the run's operands or
+      results on this mesh. The tiled/flattened kernels assume whole local
+      tensors — a tp-sharded weight or fsdp-sharded param would be gathered
+      around an opaque pallas_call, defeating the placement."""
+    mesh = getattr(ctx, "mesh", None)
+    if mesh is not None and mesh.size > 1 and jax.default_backend() == "tpu":
+        return True
     sharding = getattr(ctx, "sharding", None)
     if sharding is None:
         return False
@@ -2148,7 +2078,7 @@ def _fused_gemm_epilogue(ctx, ops, env):
     replay input)."""
     if len(ops) not in (2, 3) or ops[0].type not in ("mul", "matmul"):
         return False
-    if _rules_sharded(ctx, ops):
+    if _mesh_declines(ctx, ops):
         return False
     prod, add = ops[0], ops[1]
     act_op = ops[2] if len(ops) == 3 else None
@@ -2205,7 +2135,7 @@ def _fused_quant_gemm(ctx, ops, env):
     out-of-run consumers stay correct; XLA DCEs them when unused."""
     if len(ops) not in (3, 4, 5) or ops[0].type != "int8_mul":
         return False
-    if _rules_sharded(ctx, ops):
+    if _mesh_declines(ctx, ops):
         return False
     prod, d1, d2 = ops[0], ops[1], ops[2]
     if (
@@ -2289,7 +2219,7 @@ def _fused_layer_norm(ctx, ops, env):
     ln = ops[-1]
     if ln.type != "layer_norm" or len(ops) > 2:
         return False
-    if _rules_sharded(ctx, ops):
+    if _mesh_declines(ctx, ops):
         return False
     add = ops[0] if len(ops) == 2 else None
     if add is not None:
@@ -2340,7 +2270,7 @@ def _fused_layer_norm_grad(ctx, ops, env):
     vjp-replay fallback handles that exotic case."""
     if len(ops) != 1 or ops[0].type != "layer_norm_grad":
         return False
-    if _rules_sharded(ctx, ops):
+    if _mesh_declines(ctx, ops):
         return False
     op = ops[0]
     ins = gather_op_inputs(op, env)
@@ -2377,16 +2307,17 @@ def _fused_layer_norm_grad(ctx, ops, env):
 
 @register_fused("multi_adam")
 def _fused_multi_adam(ctx, ops, env):
-    """A contiguous run of dense adam ops through ONE multi_tensor_adam call
-    per (param, grad, moment) dtype signature. lr_t (bias correction) is
-    computed OUTSIDE the kernel with the exact _adam expressions, so the
-    fused update is bit-identical to the per-param f32 chain. The ZeRO-1
+    """A contiguous run of dense adam ops through multi_tensor_adam: one
+    call per (param, grad, moment) dtype signature and _ADAM_CALL_ELEMS
+    packed elements. lr_t (bias correction) is computed OUTSIDE the kernel
+    with the exact _adam expressions, so the fused update is the per-param
+    f32 chain to a few ulps. The ZeRO-1
     tier declines: _opt_f32's per-param GSPMD reduce-scatter/all-gather
     constraints don't survive flattening. Likewise rule-sharded (FSDP/TP)
     params — their storage layouts are per-tensor."""
     if ctx.zero1_axis is not None and ctx.mesh is not None:
         return False
-    if _rules_sharded(ctx, ops):
+    if _mesh_declines(ctx, ops):
         return False
     if len(ops) < 2 or any(op.type != "adam" for op in ops):
         return False
@@ -2425,7 +2356,17 @@ def _fused_multi_adam(ctx, ops, env):
         )
         key = (str(p.dtype), str(g.dtype), str(m1.dtype), str(m2.dtype))
         by_dtype.setdefault(key, []).append((op, p, g, m1, m2, lr_t))
+    calls = []
     for group in by_dtype.values():
+        calls.append([])
+        packed = 0
+        for rec in group:
+            if calls[-1] and packed + rec[1].size > _ADAM_CALL_ELEMS:
+                calls.append([])
+                packed = 0
+            calls[-1].append(rec)
+            packed += rec[1].size
+    for group in calls:
         p_outs, m1_outs, m2_outs = multi_tensor_adam(
             [r[1] for r in group],
             [r[2] for r in group],

@@ -220,7 +220,7 @@ def _sparse_apply(ctx, ins, attrs, state_slots, make_compute):
         mesh.shape.get(axis, 1) if use_shard else 1,
     )
     if use_shard:
-        from ..parallel.collectives import SHARD_MAP_CHECK_KW, shard_map
+        from ..parallel.collectives import shard_map
 
         nshard = len(states) + 1
         shard_spec = tuple(P((axis,), None) for _ in range(nshard))
@@ -238,7 +238,7 @@ def _sparse_apply(ctx, ins, attrs, state_slots, make_compute):
             # same update from the replicated (uniq, summed), so disabling
             # the replication check is sound
             out_specs=shard_spec,
-            **{SHARD_MAP_CHECK_KW: False},
+            check_vma=False,
         )
         outs = fn(p, *states, uniq, summed)
     else:

@@ -12,31 +12,12 @@ optimizer tier (ReduceStrategy.Reduce, docs/parallelism.md) expresses
 reduce-scatter(grad) → sharded update → all-gather(param) while leaving XLA
 free to overlap the collectives with backward compute."""
 
-import inspect
-
 import jax
 import jax.numpy as jnp
-from jax import lax
-
-try:  # newer jax exposes the function at jax.shard_map
-    from jax import shard_map as _sm
-
-    shard_map = _sm if callable(_sm) else _sm.shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
-
-# the replication-check kwarg was renamed check_rep -> check_vma across jax
-# versions; resolve once so callers can spell it portably:
-# shard_map(f, ..., **{SHARD_MAP_CHECK_KW: False})
-SHARD_MAP_CHECK_KW = (
-    "check_rep"
-    if "check_rep" in inspect.signature(shard_map).parameters
-    else "check_vma"
-)
+from jax import lax, shard_map
 
 __all__ = [
     "shard_map",
-    "SHARD_MAP_CHECK_KW",
     "all_reduce",
     "all_gather",
     "reduce_scatter",
@@ -88,12 +69,8 @@ def axis_index(axis_name):
 
 
 def axis_size(axis_name):
-    """Static extent of a bound mesh axis. lax.axis_size is a late addition;
-    on older jax, psum of the unit literal is the documented static-size
-    spelling (constant-folded, no collective emitted)."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
+    """Static extent of a bound mesh axis."""
+    return lax.axis_size(axis_name)
 
 
 # ---------------------------------------------------------------------------

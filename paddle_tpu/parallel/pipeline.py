@@ -52,9 +52,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-# version-portable shard_map + replication-check kwarg spelling (the shim
-# moved to collectives so every shard_map user in the package shares it)
-from .collectives import SHARD_MAP_CHECK_KW as _CHECK_KW, axis_size, shard_map
+from .collectives import axis_size, shard_map
 
 __all__ = [
     "gpipe",
@@ -143,7 +141,7 @@ def gpipe(stage_fn, stacked_params, x, n_micro, mesh, axis_name="pp",
         mesh=mesh,
         in_specs=(P(axis_name), P(batch_axis)),
         out_specs=P(batch_axis),
-        **{_CHECK_KW: False},
+        check_vma=False,
     )
     params_sh = jax.device_put(
         stacked_params, NamedSharding(mesh, P(axis_name))

@@ -40,7 +40,7 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..ops import pallas_kernels as pk
-from .collectives import SHARD_MAP_CHECK_KW, axis_size, shard_map
+from .collectives import axis_size, shard_map
 
 __all__ = ["ring_attention", "ring_attention_sharded"]
 
@@ -279,7 +279,7 @@ def ring_attention_sharded(
         # annotation, which the replication checker requires; collective
         # correctness there is covered by the ring-vs-dense forward/grad
         # tests. The dense tier keeps the checker.
-        **{SHARD_MAP_CHECK_KW: not use_flash},
+        check_vma=not use_flash,
     )
     return fn(q, k, v)
 

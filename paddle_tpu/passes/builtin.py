@@ -307,7 +307,10 @@ class FuseElemwiseActPass(Pass):
 # @register_fused lowering (ops/pallas_kernels.py), which declines back to
 # the per-op path — so tagging can be optimistic without risking semantics.
 _PALLAS_GEMM_PRODUCERS = ("mul", "matmul")
-_PALLAS_GEMM_ACTS = ("relu", "gelu", "tanh", "sigmoid")
+# must equal the kernel's epilogue table (pallas_kernels._GEMM_ACT_F32); gelu
+# is not in it — no erf in the Pallas TPU lowering — so a gelu stays per-op
+# behind a tagged GEMM+bias
+_PALLAS_GEMM_ACTS = ("relu", "tanh", "sigmoid")
 
 
 def _pallas_free(op):

@@ -30,7 +30,7 @@ aot_serve_lowering):
   taggers (fuse_gemm_epilogue, fuse_layer_norm, fuse_optimizer) — tagged
   chains lower to hand-tuned kernels (ops/pallas_kernels.py) instead of
   per-op XLA; fused-vs-unfused parity is within bf16 rounding (one
-  rounding per fused chain instead of one per op), bit-identical where
+  rounding per fused chain instead of one per op), a few ulps where
   the chain's math was already f32 (the multi-tensor Adam update).
 - inference_int8: the calibrated-int8 serving pipeline (passes/quant.py) —
   calibrate records activation ranges from representative feeds riding

@@ -1,7 +1,7 @@
 """Places (reference paddle/fluid/platform/place.h:26-99 — CPUPlace,
 CUDAPlace, CUDAPinnedPlace). The TPU build adds TPUPlace — SURVEY.md's north
 star — and keeps CUDAPlace as a compatibility alias so unchanged fluid scripts
-run (device selection maps onto jax devices; actual placement is XLA's)."""
+run on the chip."""
 
 import jax
 
@@ -19,6 +19,11 @@ class Place:
 
 
 class CPUPlace(Place):
+    """"Wherever JAX runs by default": an Executor built on it pins nothing,
+    so under the tests' JAX_PLATFORMS=cpu it is the host and on a machine
+    with a chip it is the chip. Only host-side staging asks it for a
+    device."""
+
     def jax_device(self):
         return jax.devices("cpu")[0]
 
@@ -27,12 +32,25 @@ class CPUPlace(Place):
 
 
 class TPUPlace(Place):
+    """Chip `device_id` of this process's TPU backend — that chip or an
+    error, never another device in its place."""
+
     def __init__(self, device_id=0):
         self.device_id = device_id
 
     def jax_device(self):
-        devs = jax.devices()
-        return devs[self.device_id % len(devs)]
+        try:
+            devs = jax.devices("tpu")
+        except RuntimeError as e:
+            raise RuntimeError(
+                "%r needs a TPU and JAX has none: the default backend is %r (%s)"
+                % (self, jax.default_backend(), e)
+            ) from e
+        if not 0 <= self.device_id < len(devs):
+            raise RuntimeError(
+                "%r: this process sees %d TPU device(s)" % (self, len(devs))
+            )
+        return devs[self.device_id]
 
     def __repr__(self):
         return "TPUPlace(%d)" % self.device_id

@@ -290,13 +290,14 @@ def device_instr_events(log_dir, aux=None):
     if not paths:
         raise FileNotFoundError("no xplane.pb under %r — run xla_trace first" % log_dir)
     # module-attr access (not `from ... import`) so the name resolves at call
-    # time — older jax builds lack ProfileData, and tests substitute it
+    # time — tests substitute it
     import jax.profiler as _jprof
 
-    profile_data = _jprof.ProfileData
     events = {}
     for path in paths:
-        _merge_device_plane_events(profile_data.from_file(path).planes, events, aux=aux)
+        _merge_device_plane_events(
+            _jprof.ProfileData.from_file(path).planes, events, aux=aux
+        )
     return events
 
 
@@ -312,14 +313,7 @@ def device_op_profile(log_dir, hlo_text=None, print_table=True):
     in stop_profiler's table shape; prints the same report format."""
     mapping = _hlo_op_map(hlo_text) if hlo_text else {}
     table = {}
-    try:
-        events = device_instr_events(log_dir)
-    except AttributeError:
-        # jaxlib without jax.profiler.ProfileData (e.g. the CPU test
-        # backend's build): no device plane to aggregate — degrade to an
-        # empty table as documented; mfu_audit keeps the loud failure
-        events = {}
-    for name, (count, total, mn, mx) in events.items():
+    for name, (count, total, mn, mx) in device_instr_events(log_dir).items():
         key = mapping.get(name)
         if key is None:
             # strip SSA suffix then retry, else group by HLO opcode

@@ -2,16 +2,19 @@
 
 A serving replica must cold-start in seconds, not re-pay one trace + XLA
 compile per (model, bucket) variant on every boot. Two layers make that
-true, both rooted in one cache directory:
+true:
 
-1. **Artifact cache** (this module's CompileCache): serialized `jax.export`
-   artifacts keyed by (program fingerprint, feed avals, fetch names,
-   jax/jaxlib version, backend platform). A hit skips the Python-side
-   program lowering and StableHLO trace entirely — the replica deserializes
-   and calls.
-2. **XLA executable cache**: the same directory's `xla/` subdir is handed to
-   JAX's persistent compilation cache, so the StableHLO→executable compile
-   of each deserialized artifact is also a disk hit on second boot.
+1. **Artifact cache** (this module's CompileCache, under `cache_dir` /
+   FLAGS_serving_cache_dir): serialized `jax.export` artifacts keyed by
+   (program fingerprint, feed avals, fetch names, jax/jaxlib version,
+   backend platform). A hit skips the Python-side program lowering and
+   StableHLO trace entirely — the replica deserializes and calls. The keys
+   hold no path, so the directory may move.
+2. **XLA executable cache**: JAX's own persistent compilation cache, in the
+   one process-wide directory the environment places
+   (platform_setup.place_compile_cache), so the StableHLO→executable
+   compile of each deserialized artifact is also a disk hit on second boot.
+   This module only zeroes its size/time thresholds.
 
 Cache writes are atomic (tmp + os.replace) and keyed content-addressed, so
 concurrent replicas sharing a cache directory race only to write identical
@@ -40,23 +43,12 @@ __all__ = [
 
 ARTIFACT_SUFFIX = ".npz"
 
-_xla_cache_dir = None  # process-global: jax's persistent-cache config is too
-
 
 def _versions():
     import jax
+    import jaxlib
 
-    try:
-        import jaxlib
-
-        jl = getattr(jaxlib, "__version__", "?")
-    except Exception:
-        jl = "?"
-    try:
-        platform = jax.default_backend()
-    except Exception:
-        platform = "?"
-    return jax.__version__, jl, platform
+    return jax.__version__, jaxlib.__version__, jax.default_backend()
 
 
 def variant_key(fingerprint, feed_avals, fetch_names, state_avals=None,
@@ -104,37 +96,18 @@ def variant_key(fingerprint, feed_avals, fetch_names, state_avals=None,
     return hashlib.sha256(json.dumps(doc, sort_keys=True).encode()).hexdigest()
 
 
-def enable_xla_executable_cache(cache_dir):
-    """Point JAX's persistent compilation cache at `<cache_dir>/xla` (once
-    per process — the jax config is global). Makes the StableHLO→executable
-    compile of every deserialized artifact a disk hit on later boots; the
-    thresholds are zeroed because serving variants are small models whose
-    compiles would otherwise fall under the default 1s/min-size cutoffs."""
-    global _xla_cache_dir
-    if _xla_cache_dir is not None:
-        return _xla_cache_dir
+def enable_xla_executable_cache():
+    """Let JAX's persistent compilation cache keep every serving compile, so
+    the StableHLO→executable compile of each deserialized artifact is a disk
+    hit on later boots: the thresholds are zeroed because serving variants
+    are small models whose compiles would otherwise fall under the default
+    1s/min-size cutoffs. Where that cache lives is not decided here
+    (platform_setup.place_compile_cache: JAX_COMPILATION_CACHE_DIR, else
+    `<checkout>/.jax_cache`)."""
     import jax
 
-    d = os.path.join(cache_dir, "xla")
-    os.makedirs(d, exist_ok=True)
-    try:
-        jax.config.update("jax_compilation_cache_dir", d)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        # the cache binds its directory at first use; by the time a serving
-        # engine constructs, model loading has already touched the backend,
-        # so force a re-read of the config or the dir update is silently
-        # ignored (no files ever written)
-        from jax.experimental.compilation_cache import (
-            compilation_cache as _jax_cc,
-        )
-
-        _jax_cc.reset_cache()
-        _xla_cache_dir = d
-    except Exception:
-        # an older jax without these knobs: the artifact layer still works
-        _xla_cache_dir = ""
-    return _xla_cache_dir
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
 
 def _atomic_write_bytes(path, blob):
@@ -156,7 +129,7 @@ class CompileCache:
         self.dir = cache_dir
         os.makedirs(cache_dir, exist_ok=True)
         if enable_xla_cache:
-            enable_xla_executable_cache(cache_dir)
+            enable_xla_executable_cache()
         from ..observability import registry as _registry
 
         reg = _registry.default_registry()

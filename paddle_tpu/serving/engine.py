@@ -330,7 +330,7 @@ class ServingEngine:
 
             # AOT-compile the wrapper for this exact signature: the variant
             # is a jax Compiled object, so warmup pays the full
-            # StableHLO->executable step up front (a disk hit when the xla/
+            # StableHLO->executable step up front (a disk hit when JAX's
             # persistent cache is warm) and the hot path can never retrace
             fn = jax.jit(
                 lambda feeds, ro, mut, _call=exported.call: _call(feeds, ro, mut)

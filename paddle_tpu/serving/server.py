@@ -19,8 +19,9 @@ Routes:
   greedy). Replies ``{"tokens": [...], "finish_reason": "eos"|"length",
   "latency_ms": float}``.
 - ``GET /healthz`` — liveness AND per-model readiness: 200 with
-  ``{"status", "ready", "model_version", "models": {name: {"kind", "ready",
-  "model_version", "queue_depth", "queued_rows", "variants"}}}``. A model is
+  ``{"status", "ready", "device_kind", "model_version", "models": {name:
+  {"kind", "ready", "model_version", "queue_depth", "queued_rows",
+  "variants"}}}`` (device_kind as this process's JAX reports it). A model is
   *ready* once its warmup precompiled every bucket — "up" (the process
   answers) and "routable" (this model serves without tracing) are different
   facts, and the fleet router + any external LB route on the second.
@@ -289,9 +290,12 @@ class ModelServer:
                 "variants": h.engine.stats()["variants"],
             }
             ready = ready and h.warmed
+        import jax
+
         return {
             "status": "ok",
             "ready": ready,
+            "device_kind": jax.devices()[0].device_kind,
             # the max over models: the fleet router gates one repo-backed
             # model, and a replica serving several reports the newest
             "model_version": max(

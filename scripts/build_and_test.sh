@@ -9,7 +9,7 @@ echo "== build native runtime =="
 python - <<'PY'
 from paddle_tpu import native
 native.lib()
-print("native runtime built:", native._LIB)
+print("native runtime built:", native._lib_path())
 PY
 
 echo "== python unittests (8-device CPU mesh, sharded) =="

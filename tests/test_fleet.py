@@ -29,6 +29,10 @@ from paddle_tpu.serving import ModelServer
 
 from test_serving import _save_mlp
 
+# ReplicaProcess children serve from whatever the environment gives them;
+# these tests state the CPU
+_CPU = {"JAX_PLATFORMS": "cpu"}
+
 
 # --------------------------------------------------------------- breaker
 
@@ -415,9 +419,9 @@ def test_sigkill_mid_request_failover_and_rejoin(tmp_path):
         "predict": {"model": "m", "model_dir": model_dir},
     }
     reps = [
-        ReplicaProcess(spec("kr0"), str(tmp_path),
+        ReplicaProcess(spec("kr0"), str(tmp_path), env=_CPU,
                        faults="replica_kill:step=1"),
-        ReplicaProcess(spec("kr1"), str(tmp_path)),
+        ReplicaProcess(spec("kr1"), str(tmp_path), env=_CPU),
     ]
     router = Router(port=0, hedge=False, probe_interval_s=0.2, seed=4,
                     total_deadline_s=30.0, attempt_timeout_s=10.0,

@@ -107,9 +107,12 @@ def test_continuous_batch_matches_serial_decode(gen_engine):
     assert eng.traces == len(eng._variants), "hot loop retraced"
 
 
-def test_paged_decode_bit_identical_to_dense_forward(gen_engine):
+def test_paged_decode_matches_dense_forward(gen_engine):
     """The paged decode path reproduces the whole-sequence dense program's
-    logits bit-for-bit (same params, same math, different cache plumbing)."""
+    logits (same params, same math, different cache plumbing) to f32
+    rounding: the two programs reduce over differently shaped operands, so
+    XLA may order the sums differently — the contract is numerical, a few
+    ulps on logits of magnitude ~1, not bit equality."""
     eng = gen_engine
     model, T = eng.model, 16
     main, _, feeds, fetches = model.build_forward(1, T)
@@ -128,12 +131,13 @@ def test_paged_decode_bit_identical_to_dense_forward(gen_engine):
     req = GenRequest(prompt, max_new_tokens=n_new, eos_id=NO_EOS)
     run = eng.start(req)
     toks = list(prompt) + [run.tokens[-1]]
-    np.testing.assert_array_equal(eng.last_prefill_logits, oracle_row(prompt))
+    close = dict(rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(eng.last_prefill_logits, oracle_row(prompt), **close)
     try:
         while not run.done:
             eng.decode_step([run])
-            np.testing.assert_array_equal(
-                eng.last_logits[run.slot], oracle_row(toks)
+            np.testing.assert_allclose(
+                eng.last_logits[run.slot], oracle_row(toks), **close
             )
             toks.append(run.tokens[-1])
     finally:

@@ -1,0 +1,221 @@
+"""Every Pallas family, and the one-layer training_fused step, lowered for
+platforms=["tpu"] through jax.export with interpret=False — on the CPU, with
+no chip. Lowering runs the Pallas→Mosaic translation, so it refuses what the
+chip would refuse first: a primitive Mosaic lacks (dynamic_slice on a value,
+erf), a block whose last two dimensions are illegal, a vector read out of a
+scalar-prefetch ref. It says nothing about Mosaic's own compile (VMEM, tile
+alignment) or about speed; chip_smoke.py's kernels phase covers those on the
+chip. This is the check that would have caught, without a chip, the three
+refusals PR 21 repaired.
+
+The kernels pick interpret mode from jax.default_backend(); the tests pin that
+to "tpu" for the duration of a lowering, which also makes every `auto` flag
+choose what it would choose on the chip.
+"""
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax import export
+
+from paddle_tpu import flags
+from paddle_tpu.ops import pallas_kernels as pk
+
+bf16, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+
+
+def S(shape, dtype):
+    return jax.ShapeDtypeStruct(tuple(shape), dtype)
+
+
+@pytest.fixture
+def as_on_tpu(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def lower_for_tpu(fn, *avals):
+    """The exported module's text; raises what the TPU lowering raises."""
+    text = export.export(jax.jit(fn), platforms=["tpu"])(*avals).mlir_module()
+    assert "tpu_custom_call" in text, "lowered without a Mosaic kernel"
+    return text
+
+
+def _flash(causal):
+    return lambda q, k, v: pk.flash_attention(q, k, v, causal)
+
+
+def _flash_grad(causal):
+    def loss(q, k, v):
+        return pk.flash_attention(q, k, v, causal).astype(f32).sum()
+
+    return jax.grad(loss, argnums=(0, 1, 2))
+
+
+_PAGED = dict(n_head=2, d=64, page=8, pages=4, pool_rows=72)
+
+
+def _paged(quant, per_row, rows):
+    g = _PAGED
+    feat = g["n_head"] * g["d"]
+
+    def fn(q, kp, vp, bt, pos, *scales):
+        return pk.paged_flash_attention(
+            q, kp, vp, bt, pos, n_head=g["n_head"], page_size=g["page"],
+            k_scales=scales[0] if quant else None,
+            v_scales=scales[1] if quant else None,
+        )
+
+    pool = S((g["pool_rows"], feat), jnp.int8 if quant else f32)
+    avals = [
+        S((rows, feat), f32), pool, pool,
+        S((rows, g["pages"]) if per_row else (g["pages"],), i32),
+        S((rows,), i32),
+    ]
+    if quant:
+        avals += [S((g["pool_rows"],), f32)] * 2
+    return fn, avals
+
+
+def _adam(moment_dtype):
+    shapes = [(256, 384), (384,), (7, 13)]
+
+    def fn(p, g, m1, m2, lr):
+        return pk.multi_tensor_adam(p, g, m1, m2, lr, 0.9, 0.999, 1e-8)
+
+    ps = [S(s, f32) for s in shapes]
+    ms = [S(s, moment_dtype) for s in shapes]
+    return fn, [ps, ps, ms, ms, [S((), f32)] * len(shapes)]
+
+
+def _gemm(act, dbuf):
+    return (
+        lambda x, w, b: pk.gemm_bias_act(x, w, b, act),
+        [S((256, 512), bf16), S((512, 256), bf16), S((1, 256), bf16)],
+        {"gemm_double_buffer": dbuf},
+    )
+
+
+def _quant_gemm(dtype, m):
+    return (
+        lambda x, w, b: pk.quant_gemm_bias_act(x, w, 0.01, b, "relu"),
+        [S((m, 256), dtype), S((256, 128), dtype), S((1, 128), f32)],
+        {"quantized_gemm": "on"},
+    )
+
+
+_LN = [S((256, 512), bf16), S((512,), f32), S((256,), f32)]  # x, scale, stat
+
+# family -> (function, avals[, flags to lower it under])
+FAMILIES = {
+    "flash": (_flash(False), [S((2, 2, 256, 64), bf16)] * 3),
+    "flash causal": (_flash(True), [S((2, 2, 256, 64), bf16)] * 3),
+    "flash_grad": (_flash_grad(False), [S((2, 2, 256, 64), bf16)] * 3),
+    "flash_grad causal": (_flash_grad(True), [S((2, 2, 256, 64), bf16)] * 3),
+    # the long-context tier takes over past ~8k tokens at d 128
+    "flash streamed": (_flash(True), [S((1, 1, 16384, 128), bf16)] * 3),
+    "flash_grad streamed": (_flash_grad(True), [S((1, 1, 16384, 128), bf16)] * 3),
+    "gemm_epilogue": _gemm(None, "off"),
+    "gemm_epilogue relu": _gemm("relu", "off"),
+    "gemm_epilogue tanh": _gemm("tanh", "off"),
+    "gemm_epilogue sigmoid": _gemm("sigmoid", "off"),
+    "gemm_dbuf": _gemm(None, "on"),
+    "gemm_dbuf tanh": _gemm("tanh", "on"),
+    "gemm_int8 m=64": _quant_gemm(jnp.int8, 64),
+    "gemm_int8 m=1024": _quant_gemm(jnp.int8, 1024),
+    "gemm_fp8 m=64": _quant_gemm(jnp.float8_e4m3fn, 64),
+    "gemm_fp8 m=1024": _quant_gemm(jnp.float8_e4m3fn, 1024),
+    "layer_norm": (
+        lambda x, s, b: pk.fused_layer_norm(x, None, s, b, 1e-5),
+        [_LN[0], _LN[1], _LN[1]],
+    ),
+    "layer_norm residual": (
+        lambda x, r, s, b: pk.fused_layer_norm(x, r, s, b, 1e-5),
+        [_LN[0], _LN[0], _LN[1], _LN[1]],
+    ),
+    "layer_norm_grad": (
+        lambda x, s, m, v, dy: pk.fused_layer_norm_grad(x, s, m, v, dy, 1e-5),
+        [_LN[0], _LN[1], _LN[2], _LN[2], _LN[0]],
+    ),
+    "multi_adam": _adam(f32),
+    "multi_adam bf16 moments": _adam(bf16),
+    "paged_flash decode": _paged(False, True, 4),
+    "paged_flash shared": _paged(False, False, 8),
+    "paged_flash shared 2 rows": _paged(False, False, 2),
+    "paged_flash_int8 decode": _paged(True, True, 4),
+    "paged_flash_int8 shared": _paged(True, False, 8),
+}
+
+# family -> the lowering's own message, for a family `auto` no longer selects
+# on a TPU because it cannot be made to lower without a redesign. Empty: every
+# family lowers (and compiles: chip_smoke.py, CHANGES.md PR 21).
+WITHDRAWN = {}
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_family_lowers_for_tpu(family, as_on_tpu):
+    fn, avals, *under = FAMILIES[family]
+    saved = flags.get_flags(["gemm_double_buffer", "quantized_gemm"])
+    try:
+        flags.set_flags(under[0] if under else {})
+        if family in WITHDRAWN:
+            with pytest.raises(Exception, match=WITHDRAWN[family]):
+                lower_for_tpu(fn, *avals)
+        else:
+            lower_for_tpu(fn, *avals)
+    finally:
+        flags.set_flags(saved)
+
+
+def test_exact_gelu_is_declined_at_tagging():
+    """No erf in the Pallas TPU lowering: the kernel has no gelu epilogue and
+    fuse_gemm_epilogue tags only the GEMM+bias in front of one."""
+    from paddle_tpu.passes import builtin
+
+    assert "gelu" not in pk._GEMM_ACT_F32
+    assert set(builtin._PALLAS_GEMM_ACTS) == set(pk._GEMM_ACT_F32)
+
+
+def test_paged_auto_declines_a_page_that_is_not_whole_tiles(as_on_tpu):
+    assert pk.paged_flash_path_taken(8, 128, 8, 12, 64)
+    assert pk.paged_flash_path_taken(8, 32, 32, 12, 64)
+    assert not pk.paged_flash_path_taken(8, 128, 4, 12, 64)
+    assert not pk.paged_flash_path_taken(8, 128, 12, 12, 64)
+
+
+def test_training_fused_step_lowers_for_tpu(monkeypatch):
+    """The one-layer bench transformer step under training_fused — every
+    fused family and both flash directions in one module."""
+    import bench
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.executor import (
+        Scope, _apply_pass_pipeline, _CompiledBlock, scope_guard,
+    )
+    from paddle_tpu.transpiler.bf16_transpiler import Bf16Transpiler
+
+    main, startup, feed, loss, _ = bench.build_transformer(
+        b=1, t=128, d=128, n_layer=1, vocab=128, moment_dtype="bfloat16"
+    )
+    scope = Scope(seed=0)
+    with scope_guard(scope):
+        fluid.Executor().run(startup)  # on the CPU: the scope's avals
+        Bf16Transpiler().transpile(main)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        feeds = list(feed)
+        prog = _apply_pass_pipeline(
+            main, scope, feeds, [loss.name], pipeline="training_fused"
+        )
+        pk.KERNEL_DISPATCHES.clear()
+        block = _CompiledBlock(prog, prog.global_block(), feeds, [loss.name], scope)
+        aval = lambda a: S(a.shape, a.dtype)
+        text = export.export(block.jitted, platforms=["tpu"])(
+            {n: aval(a) for n, a in feed.items()},
+            {n: aval(scope.vars[n]) for n in block.ro_names},
+            {n: aval(scope.vars[n]) for n in block.mut_names},
+            aval(scope.rng_key),
+        ).mlir_module()
+    for fam in ("gemm_dbuf", "gemm_epilogue", "layer_norm", "layer_norm_grad",
+                "multi_adam"):
+        assert pk.KERNEL_DISPATCHES.get(fam, 0) > 0, pk.KERNEL_DISPATCHES
+    # 3 flash forward + 3 backward, 4 GEMMs, 5 + 5 layer norms, 1 Adam
+    assert text.count("tpu_custom_call") >= 21, text.count("tpu_custom_call")
