@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from . import framework
+from . import profiler as _prof
 from .framework import Program, Variable, convert_np_dtype
 from .ops import registry
 from .ops.registry import EMPTY_VAR_NAME
@@ -1735,7 +1736,10 @@ class Executor:
         or a dict of stacked arrays with leading axis k, and each fetch comes
         back stacked [k, ...]. With no feed, k staged batches are pulled from
         the program's started py_readers."""
-        with jax.default_device(self._device):
+        self._run_seq += 1
+        with jax.default_device(self._device), _prof.RecordEvent(
+            span="executor.run", iter=self._run_seq
+        ):
             return self._run(
                 program, feed, fetch_list, scope, return_numpy,
                 use_program_cache, steps_per_run,
@@ -1743,223 +1747,247 @@ class Executor:
 
     def _run(self, program, feed, fetch_list, scope, return_numpy,
              use_program_cache, steps_per_run):
-        if program is None:
-            program = framework.default_main_program()
-        # elastic step-deadline watchdog: every run entry is a progress beat
-        # (resilience/elastic.py heartbeat — one list probe when no
-        # Supervisor is active)
-        _elastic_heartbeat()
-        # telemetry (observability.stepstats): t0 brackets the WHOLE run —
-        # reader pull, dispatch, and the fetch conversion (which is where
-        # the device sync lands under return_numpy / FLAGS_benchmark)
-        _obs, _obs_t0 = _telemetry_begin()
-        self._run_seq += 1
-        # force_multi: a reader pull that returned a 1-batch epoch tail still
-        # runs through _MultiStepBlock so fetches keep their [k, ...] axis
-        force_multi = False
-        if feed is None:
-            # pull staged batches from started py_readers (reference read_op
-            # popping the LoDTensorBlockingQueue); raises EOFException at end
-            feed, steps_per_run, force_multi = _resolve_reader_feed(
-                program, steps_per_run
-            )
-        elif isinstance(feed, (list, tuple)):
-            if steps_per_run == 1:
-                steps_per_run = len(feed)
-            if len(feed) != steps_per_run:
-                raise ValueError(
-                    "feed list has %d entries but steps_per_run=%d"
-                    % (len(feed), steps_per_run)
+        # the three phases of a run, spans fluid.executor.{prepare,call,
+        # finish} under run()'s fluid.executor.run; `iter` is the run's index
+        seq = {"iter": self._run_seq}
+        with _prof.RecordEvent(span="executor.prepare", **seq):
+            if program is None:
+                program = framework.default_main_program()
+            # elastic step-deadline watchdog: every run entry is a progress beat
+            # (resilience/elastic.py heartbeat — one list probe when no
+            # Supervisor is active)
+            _elastic_heartbeat()
+            # telemetry (observability.stepstats): t0 brackets the WHOLE run —
+            # reader pull, dispatch, and the fetch conversion (which is where
+            # the device sync lands under return_numpy / FLAGS_benchmark)
+            _obs, _obs_t0 = _telemetry_begin()
+            # force_multi: a reader pull that returned a 1-batch epoch tail still
+            # runs through _MultiStepBlock so fetches keep their [k, ...] axis
+            force_multi = False
+            if feed is None:
+                # pull staged batches from started py_readers (reference read_op
+                # popping the LoDTensorBlockingQueue); raises EOFException at end
+                feed, steps_per_run, force_multi = _resolve_reader_feed(
+                    program, steps_per_run
                 )
-            if steps_per_run == 1:
-                feed = dict(feed[0])  # single step: no stacking, no scan
-            else:
-                feed = _stack_feed_steps(list(feed))
-        if fetch_list is None:
-            fetch_list = []
-        scope = scope or global_scope()
-        # the lazy rng_key property covers the fresh-scope case from the
-        # scope's own seed; only an explicit program.random_seed overrides it
-        if program.random_seed and not getattr(scope, "_seeded", False):
-            scope.rng_key = jax.random.key(program.random_seed)
-            scope._seeded = True
-
-        fetch_names = [
-            f.name if isinstance(f, Variable) else str(f) for f in fetch_list
-        ]
-        # graph-pass choke point (docs/passes.md): FLAGS_pass_pipeline rewrites
-        # the program here, before any lowering below sees it. Reader/feed
-        # resolution above ran on the ORIGINAL program (its _py_readers);
-        # everything from here down uses the (memoized) transformed one.
-        program = _apply_pass_pipeline(
-            program, scope, list(feed.keys()), fetch_names
-        )
-        block = program.global_block()
-
-        feed_arrays = {}
-        for name, value in feed.items():
-            var = block.vars.get(name)
-            feed_arrays[name] = _as_feed_array(value, var)
-
-        _opf = _flags_opprof()
-        key = (
-            program._uid,
-            program._version,
-            tuple(sorted((n, a.shape, str(a.dtype)) for n, a in feed_arrays.items())),
-            tuple(fetch_names),
-            scope._uid,
-            steps_per_run,
-            # only the k==1 case needs disambiguating from single-step; for
-            # k>1 an explicit stacked feed and a reader pull share the
-            # compiled scan
-            force_multi and steps_per_run == 1,
-            # toggling FLAGS_tensor_stats must recompile, not serve a stale
-            # (un)instrumented block
-            _opf["tensor_stats"],
-        )
-        from . import profiler as _prof
-
-        is_multi = steps_per_run > 1 or force_multi
-        if _prof.is_profiling() and _flags_profile_ops() and not is_multi:
-            # per-op attribution mode: never cached (diagnosis path); falls
-            # through to the shared nan-check/return tail below. Multi-step
-            # runs skip it — unfused per-op eager execution is the opposite
-            # of what steps_per_run exists to measure.
-            compiled = _PerOpProfiledBlock(
-                program, block, list(feed_arrays.keys()), fetch_names
-            )
-            with _prof.RecordEvent("run/block0"):
-                fetches = compiled(scope, _eager_cast_feeds(block, feed_arrays))
-            return self._finish_run(
-                compiled, scope, fetch_names, fetches, return_numpy,
-                step=self._run_seq,
-            )
-
-        compiled = self._cache.get(key) if use_program_cache else None
-        _obs_cache_hit = compiled is not None
-        if compiled is None:
-            # FLAGS_static_verify (docs/static_analysis.md): prove the program
-            # against the fluidlint suite before paying for the trace below
-            from .analysis import maybe_static_verify
-
-            maybe_static_verify(
-                program, list(feed_arrays.keys()), fetch_names, scope=scope,
-                mode="inference" if getattr(program, "_is_test", False)
-                else "training",
-                where="executor",
-            )
-            has_host = any(
-                registry.is_registered(op.type) and registry.get(op.type).is_host
-                for op in block.ops
-            )
-            with _prof.RecordEvent("prepare/block0"):
-                if has_host:
-                    if is_multi:
-                        raise RuntimeError(
-                            "steps_per_run>1 cannot span host ops (send/recv/"
-                            "listen_and_serv): the k-step scan is one XLA "
-                            "computation with no host re-entry"
-                        )
-                    compiled = _SegmentedBlock(
-                        program, block, list(feed_arrays.keys()), fetch_names
+            elif isinstance(feed, (list, tuple)):
+                if steps_per_run == 1:
+                    steps_per_run = len(feed)
+                if len(feed) != steps_per_run:
+                    raise ValueError(
+                        "feed list has %d entries but steps_per_run=%d"
+                        % (len(feed), steps_per_run)
                     )
-                elif is_multi:
-                    compiled = _MultiStepBlock(
-                        program, block, list(feed_arrays.keys()), fetch_names,
-                        scope, steps_per_run,
-                    )
+                if steps_per_run == 1:
+                    feed = dict(feed[0])  # single step: no stacking, no scan
                 else:
-                    compiled = _CompiledBlock(
-                        program, block, list(feed_arrays.keys()), fetch_names, scope
-                    )
-            if use_program_cache:
-                self._cache[key] = compiled
+                    feed = _stack_feed_steps(list(feed))
+            if fetch_list is None:
+                fetch_list = []
+            scope = scope or global_scope()
+            # the lazy rng_key property covers the fresh-scope case from the
+            # scope's own seed; only an explicit program.random_seed overrides it
+            if program.random_seed and not getattr(scope, "_seeded", False):
+                scope.rng_key = jax.random.key(program.random_seed)
+                scope._seeded = True
 
-        from . import flags as _flags
+            fetch_names = [
+                f.name if isinstance(f, Variable) else str(f) for f in fetch_list
+            ]
+            # graph-pass choke point (docs/passes.md): FLAGS_pass_pipeline rewrites
+            # the program here, before any lowering below sees it. Reader/feed
+            # resolution above ran on the ORIGINAL program (its _py_readers);
+            # everything from here down uses the (memoized) transformed one.
+            program = _apply_pass_pipeline(
+                program, scope, list(feed.keys()), fetch_names
+            )
+            block = program.global_block()
 
-        # --- resilience: NaN injection + step guard (docs/resilience.md) ---
-        # only runs that mutate persistable state count as training steps;
-        # startup/eval programs pass through untouched
-        mut_names = getattr(compiled, "mut_names", ()) or ()
-        poison_after = False
-        guard_snapshot = None
-        if mut_names:
-            from .resilience import faults as _faults
+            feed_arrays = {}
+            for name, value in feed.items():
+                var = block.vars.get(name)
+                feed_arrays[name] = _as_feed_array(value, var)
 
-            if _faults.fires("nan_grad"):
-                feed_arrays, poison_after = _poison_nan(feed_arrays)
-            if _flags.get_flags("resilience_nan_guard")["resilience_nan_guard"]:
-                # host copies taken BEFORE the step: the donated in-place
-                # update invalidates the old device buffers, so these copies
-                # are the only way back when the step turns out poisoned.
-                # np.array on top of the __array__ view — on the CPU backend
-                # np.asarray of a jax array is zero-copy, so the donated
-                # update would rewrite the "snapshot" underneath us
-                guard_snapshot = {
-                    n: np.array(np.asarray(scope.vars[n]))
-                    for n in mut_names
-                    if scope.vars.get(n) is not None
-                }
+            _opf = _flags_opprof()
+            key = (
+                program._uid,
+                program._version,
+                tuple(sorted((n, a.shape, str(a.dtype)) for n, a in feed_arrays.items())),
+                tuple(fetch_names),
+                scope._uid,
+                steps_per_run,
+                # only the k==1 case needs disambiguating from single-step; for
+                # k>1 an explicit stacked feed and a reader pull share the
+                # compiled scan
+                force_multi and steps_per_run == 1,
+                # toggling FLAGS_tensor_stats must recompile, not serve a stale
+                # (un)instrumented block
+                _opf["tensor_stats"],
+            )
+            is_multi = steps_per_run > 1 or force_multi
+            # per-op attribution mode: never cached (diagnosis path), no guard,
+            # no telemetry; it shares only the nan-check/return tail. Multi-step
+            # runs skip it — unfused per-op eager execution is the opposite of
+            # what steps_per_run exists to measure.
+            per_op = _prof.is_profiling() and _flags_profile_ops() and not is_multi
+            if per_op:
+                compiled = _PerOpProfiledBlock(
+                    program, block, list(feed_arrays.keys()), fetch_names
+                )
+            else:
+                compiled = self._cache.get(key) if use_program_cache else None
+            _obs_cache_hit = compiled is not None
+            if compiled is None:
+                # FLAGS_static_verify (docs/static_analysis.md): prove the program
+                # against the fluidlint suite before paying for the trace below
+                from .analysis import maybe_static_verify
 
-        # pre-step rng key: scope.rng_key is consumed by the run; the
-        # provenance replay must start from the same key to reproduce the
-        # step's randomness op for op
-        pre_key = scope.rng_key if _opf["nan_provenance"] else None
+                maybe_static_verify(
+                    program, list(feed_arrays.keys()), fetch_names, scope=scope,
+                    mode="inference" if getattr(program, "_is_test", False)
+                    else "training",
+                    where="executor",
+                )
+                has_host = any(
+                    registry.is_registered(op.type) and registry.get(op.type).is_host
+                    for op in block.ops
+                )
+                with _prof.RecordEvent("prepare/block0"):
+                    if has_host:
+                        if is_multi:
+                            raise RuntimeError(
+                                "steps_per_run>1 cannot span host ops (send/recv/"
+                                "listen_and_serv): the k-step scan is one XLA "
+                                "computation with no host re-entry"
+                            )
+                        compiled = _SegmentedBlock(
+                            program, block, list(feed_arrays.keys()), fetch_names
+                        )
+                    elif is_multi:
+                        compiled = _MultiStepBlock(
+                            program, block, list(feed_arrays.keys()), fetch_names,
+                            scope, steps_per_run,
+                        )
+                    else:
+                        compiled = _CompiledBlock(
+                            program, block, list(feed_arrays.keys()), fetch_names, scope
+                        )
+                if use_program_cache:
+                    self._cache[key] = compiled
 
-        with _prof.RecordEvent("run/block0"):
+            from . import flags as _flags
+
+            # --- resilience: NaN injection + step guard (docs/resilience.md) ---
+            # only runs that mutate persistable state count as training steps;
+            # startup/eval programs pass through untouched
+            mut_names = () if per_op else getattr(compiled, "mut_names", ()) or ()
+            poison_after = False
+            guard_snapshot = None
+            if mut_names:
+                from .resilience import faults as _faults
+
+                if _faults.fires("nan_grad"):
+                    feed_arrays, poison_after = _poison_nan(feed_arrays)
+                if _flags.get_flags("resilience_nan_guard")["resilience_nan_guard"]:
+                    # host copies taken BEFORE the step: the donated in-place
+                    # update invalidates the old device buffers, so these copies
+                    # are the only way back when the step turns out poisoned.
+                    # np.array on top of the __array__ view — on the CPU backend
+                    # np.asarray of a jax array is zero-copy, so the donated
+                    # update would rewrite the "snapshot" underneath us
+                    guard_snapshot = {
+                        n: np.array(np.asarray(scope.vars[n]))
+                        for n in mut_names
+                        if scope.vars.get(n) is not None
+                    }
+
+            # pre-step rng key: scope.rng_key is consumed by the run; the
+            # provenance replay must start from the same key to reproduce the
+            # step's randomness op for op
+            pre_key = scope.rng_key if _opf["nan_provenance"] else None
+
+        if per_op:
+            with _prof.RecordEvent("run/block0", span="executor.call", **seq):
+                fetches = compiled(scope, _eager_cast_feeds(block, feed_arrays))
+            with _prof.RecordEvent(span="executor.finish", **seq):
+                return self._finish_run(
+                    compiled, scope, fetch_names, fetches, return_numpy,
+                    step=self._run_seq,
+                )
+
+        with _prof.RecordEvent("run/block0", span="executor.call", **seq):
             fetches = compiled(scope, feed_arrays)
             if _prof.is_profiling() or _flags.get_flags("benchmark")["benchmark"]:
                 # reference FLAGS_benchmark: wait so host timing is real step
                 # time (operator.cc:769 dev_ctx->Wait)
                 fetches = [jax.block_until_ready(f) for f in fetches]
 
-        nan_ok = False
-        if poison_after:
-            # integer-only feeds can't carry the injected NaN through the
-            # loss; poison the updated state directly instead
-            _poison_scope_state(scope, mut_names)
-        if guard_snapshot is not None:
-            watched = list(fetches) + [
-                scope.vars[n] for n in mut_names if scope.vars.get(n) is not None
-            ]
-            if not _all_finite(watched):
-                from .observability import flightrec as _flightrec
+        with _prof.RecordEvent(span="executor.finish", **seq):
+            nan_ok = False
+            if poison_after:
+                # integer-only feeds can't carry the injected NaN through the
+                # loss; poison the updated state directly instead
+                _poison_scope_state(scope, mut_names)
+            if guard_snapshot is not None:
+                watched = list(fetches) + [
+                    scope.vars[n] for n in mut_names if scope.vars.get(n) is not None
+                ]
+                if not _all_finite(watched):
+                    from .observability import flightrec as _flightrec
 
-                _flightrec.trigger("nan_guard", step=self._run_seq)
-                if _opf["nan_provenance"]:
-                    # localize BEFORE the rollback erases the poisoned state;
-                    # the replay itself runs against the pre-step snapshot
-                    _localize_nan(
-                        compiled, scope, feed_arrays, pre_key,
-                        "resilience_nan_guard", step=self._run_seq,
-                        mut_override=guard_snapshot,
-                    )
-                nan_ok = self._skip_nan_step(scope, guard_snapshot)
-        # correlation seed for profiler.device_op_profile: the block + feed
-        # AVALS of the latest run (abstract shapes only — storing the
-        # concrete arrays would pin a whole batch of device memory), from
-        # which compiled_hlo() lowers the metadata-carrying HLO text
-        if isinstance(compiled, (_CompiledBlock, _MultiStepBlock)):
-            # weakref: _last_run must not keep a dropped scope's parameters
-            # alive in device memory
-            self._last_run = (
-                compiled,
-                weakref.ref(scope),
-                {
-                    n: jax.ShapeDtypeStruct(a.shape, a.dtype)
-                    for n, a in feed_arrays.items()
-                },
+                    _flightrec.trigger("nan_guard", step=self._run_seq)
+                    if _opf["nan_provenance"]:
+                        # localize BEFORE the rollback erases the poisoned state;
+                        # the replay itself runs against the pre-step snapshot
+                        _localize_nan(
+                            compiled, scope, feed_arrays, pre_key,
+                            "resilience_nan_guard", step=self._run_seq,
+                            mut_override=guard_snapshot,
+                        )
+                    nan_ok = self._skip_nan_step(scope, guard_snapshot)
+            # correlation seed for profiler.device_op_profile: the block + feed
+            # AVALS of the latest run (abstract shapes only — storing the
+            # concrete arrays would pin a whole batch of device memory), from
+            # which compiled_hlo() lowers the metadata-carrying HLO text
+            if isinstance(compiled, (_CompiledBlock, _MultiStepBlock)):
+                # weakref: _last_run must not keep a dropped scope's parameters
+                # alive in device memory
+                self._last_run = (
+                    compiled,
+                    weakref.ref(scope),
+                    {
+                        n: jax.ShapeDtypeStruct(a.shape, a.dtype)
+                        for n, a in feed_arrays.items()
+                    },
+                )
+            result = self._finish_run(
+                compiled, scope, fetch_names, fetches, return_numpy, nan_ok=nan_ok,
+                step=self._run_seq, feed_arrays=feed_arrays, pre_key=pre_key,
             )
-        result = self._finish_run(
-            compiled, scope, fetch_names, fetches, return_numpy, nan_ok=nan_ok,
-            step=self._run_seq, feed_arrays=feed_arrays, pre_key=pre_key,
-        )
-        if _obs is not None:
-            _telemetry_record(
-                _obs, _obs_t0, compiled, _obs_cache_hit, nan_ok,
-                steps_per_run if is_multi else 1, result, return_numpy,
+            if _obs is not None:
+                _telemetry_record(
+                    _obs, _obs_t0, compiled, _obs_cache_hit, nan_ok,
+                    steps_per_run if is_multi else 1, result, return_numpy,
+                )
+            return result
+
+    def _last_compiled(self, what):
+        """The XLA executable of the most recently run compiled block, served
+        from the backend's compilation cache after a run (no recompile)."""
+        last = getattr(self, "_last_run", None)
+        if last is None:
+            raise RuntimeError("%s() needs a prior Executor.run" % what)
+        compiled, scope_ref, feed_avals = last
+        scope = scope_ref()
+        if scope is None:
+            raise RuntimeError(
+                "%s(): the scope of the last run no longer exists" % what
             )
-        return result
+        ro = {n: scope.vars[n] for n in compiled.ro_names}
+        mut = {n: scope.vars[n] for n in compiled.mut_names}
+        with jax.default_device(self._device):
+            lowered = compiled.jitted.lower(feed_avals, ro, mut, scope.rng_key)
+            return lowered.compile()
 
     def compiled_hlo(self):
         """Post-optimization HLO text of the most recently run compiled
@@ -1970,20 +1998,15 @@ class Executor:
         reference's CUPTI kernel→op correlation (platform/device_tracer.cc).
         The compile is served from the backend's compilation cache after a
         run, so this does not recompile."""
-        last = getattr(self, "_last_run", None)
-        if last is None:
-            raise RuntimeError("compiled_hlo() needs a prior Executor.run")
-        compiled, scope_ref, feed_avals = last
-        scope = scope_ref()
-        if scope is None:
-            raise RuntimeError(
-                "compiled_hlo(): the scope of the last run no longer exists"
-            )
-        ro = {n: scope.vars[n] for n in compiled.ro_names}
-        mut = {n: scope.vars[n] for n in compiled.mut_names}
-        with jax.default_device(self._device):
-            lowered = compiled.jitted.lower(feed_avals, ro, mut, scope.rng_key)
-            return lowered.compile().as_text()
+        return self._last_compiled("compiled_hlo").as_text()
+
+    def memory_plan(self):
+        """What the compiler planned for the most recently run compiled block
+        on one device: XLA's memory_analysis() of its executable, with
+        argument / output / temp / generated_code / alias sizes in bytes. The
+        allocator's peak_bytes_in_use leaves XLA's temporaries out; this does
+        not."""
+        return self._last_compiled("memory_plan").memory_analysis()
 
     def _skip_nan_step(self, scope, snapshot):
         """The NaN/Inf step guard tripped: roll the mutated persistables back

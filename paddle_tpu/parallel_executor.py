@@ -22,6 +22,7 @@ import jax
 from jax.sharding import Mesh
 
 from . import framework
+from . import profiler as _prof
 from .executor import (
     _CompiledBlock,
     _MultiStepBlock,
@@ -174,6 +175,7 @@ class ParallelExecutor:
         else:
             self._mesh = Mesh(np.asarray(devices), ("dp",))
         self._cache = {}
+        self._run_seq = 0  # run() calls: the `iter` tag of its spans
 
     @property
     def device_count(self):
@@ -238,197 +240,205 @@ class ParallelExecutor:
         stacked arrays with leading axis k (a feed LIST keeps its reference
         meaning of per-DEVICE dicts and is only valid for k=1); fetches come
         back stacked [k, ...]."""
-        feed = feed if feed is not None else (feed_dict or {})
-        _obs, _obs_t0 = _telemetry_begin()
-        force_multi = False  # 1-batch epoch tail keeps the [k, ...] contract
-        if not feed:
-            # pull staged batches from started py_readers, like Executor.run
-            from .executor import _resolve_reader_feed
+        self._run_seq += 1
+        seq = {"iter": self._run_seq}
+        # the same spans as Executor.run: fluid.executor.run and its three
+        # phases prepare, call, finish
+        with _prof.RecordEvent(span="executor.run", **seq):
+            with _prof.RecordEvent(span="executor.prepare", **seq):
+                feed = feed if feed is not None else (feed_dict or {})
+                _obs, _obs_t0 = _telemetry_begin()
+                force_multi = False  # 1-batch epoch tail keeps the [k, ...] contract
+                if not feed:
+                    # pull staged batches from started py_readers, like Executor.run
+                    from .executor import _resolve_reader_feed
 
-            self._install_reader_sharding()
-            feed, steps_per_run, force_multi = _resolve_reader_feed(
-                self._program, steps_per_run
-            )
-        is_multi = steps_per_run > 1 or force_multi
-        if isinstance(feed, (list, tuple)):
-            if steps_per_run > 1:
-                raise TypeError(
-                    "with steps_per_run>1 feed must be a dict of stacked "
-                    "arrays (leading axis k); a feed list means per-device "
-                    "dicts (reference parallel_executor.py:183-213)"
-                )
-            # reference API form: one dict per device (reference
-            # parallel_executor.py:183-213) — concatenate along the batch dim
-            merged = {}
-            for d in feed:
-                if not isinstance(d, dict):
-                    raise TypeError(
-                        "feed must be a dict or a list of per-device dicts; got "
-                        "list of %r" % type(d).__name__
+                    self._install_reader_sharding()
+                    feed, steps_per_run, force_multi = _resolve_reader_feed(
+                        self._program, steps_per_run
                     )
-                for k, v in d.items():
-                    merged.setdefault(k, []).append(np.asarray(v))
-            feed = {k: np.concatenate(vs, axis=0) for k, vs in merged.items()}
-        program = self._program
-        fetch_names = [
-            f.name if isinstance(f, Variable) else str(f) for f in fetch_list
-        ]
-        # graph-pass choke point, mirroring Executor.run (docs/passes.md);
-        # BuildStrategy.pass_pipeline overrides FLAGS_pass_pipeline when set
-        program = _apply_pass_pipeline(
-            program, self._scope, list(feed.keys()), fetch_names,
-            pipeline=self._build_strategy.resolved_pass_pipeline(),
-        )
-        block = program.global_block()
-        feed_arrays = {}
-        batch_dim = 1 if is_multi else 0  # stacked feeds: [k, N, ...]
-        for name, value in feed.items():
-            var = block.vars.get(name)
-            arr = _as_feed_array(value, var)
-            if (
-                len(arr.shape) > batch_dim
-                and arr.shape[batch_dim] % self.device_count != 0
-            ):
-                raise ValueError(
-                    "batch dim %d of feed %r not divisible by device count %d "
-                    "(reference PE splits the batch across devices the same way)"
-                    % (arr.shape[batch_dim], name, self.device_count)
+                is_multi = steps_per_run > 1 or force_multi
+                if isinstance(feed, (list, tuple)):
+                    if steps_per_run > 1:
+                        raise TypeError(
+                            "with steps_per_run>1 feed must be a dict of stacked "
+                            "arrays (leading axis k); a feed list means per-device "
+                            "dicts (reference parallel_executor.py:183-213)"
+                        )
+                    # reference API form: one dict per device (reference
+                    # parallel_executor.py:183-213) — concatenate along the batch dim
+                    merged = {}
+                    for d in feed:
+                        if not isinstance(d, dict):
+                            raise TypeError(
+                                "feed must be a dict or a list of per-device dicts; got "
+                                "list of %r" % type(d).__name__
+                            )
+                        for k, v in d.items():
+                            merged.setdefault(k, []).append(np.asarray(v))
+                    feed = {k: np.concatenate(vs, axis=0) for k, vs in merged.items()}
+                program = self._program
+                fetch_names = [
+                    f.name if isinstance(f, Variable) else str(f) for f in fetch_list
+                ]
+                # graph-pass choke point, mirroring Executor.run (docs/passes.md);
+                # BuildStrategy.pass_pipeline overrides FLAGS_pass_pipeline when set
+                program = _apply_pass_pipeline(
+                    program, self._scope, list(feed.keys()), fetch_names,
+                    pipeline=self._build_strategy.resolved_pass_pipeline(),
                 )
-            feed_arrays[name] = arr
+                block = program.global_block()
+                feed_arrays = {}
+                batch_dim = 1 if is_multi else 0  # stacked feeds: [k, N, ...]
+                for name, value in feed.items():
+                    var = block.vars.get(name)
+                    arr = _as_feed_array(value, var)
+                    if (
+                        len(arr.shape) > batch_dim
+                        and arr.shape[batch_dim] % self.device_count != 0
+                    ):
+                        raise ValueError(
+                            "batch dim %d of feed %r not divisible by device count %d "
+                            "(reference PE splits the batch across devices the same way)"
+                            % (arr.shape[batch_dim], name, self.device_count)
+                        )
+                    feed_arrays[name] = arr
 
-        pp = self._mesh.shape.get("pp", 1)
-        if pp > 1 and is_multi:
-            raise NotImplementedError(
-                "steps_per_run > 1 is not supported with pipeline "
-                "parallelism yet; run one step per call on a pp mesh"
-            )
-        # declarative sharding rules: BuildStrategy's own (normalized to a
-        # ShardingRules), merged by the compiled block AFTER any
-        # program-attached rules. Both fingerprints go into the cache key —
-        # rules hang off live objects and may grow between runs.
-        from .parallel.sharding_rules import ShardingRules
+                pp = self._mesh.shape.get("pp", 1)
+                if pp > 1 and is_multi:
+                    raise NotImplementedError(
+                        "steps_per_run > 1 is not supported with pipeline "
+                        "parallelism yet; run one step per call on a pp mesh"
+                    )
+                # declarative sharding rules: BuildStrategy's own (normalized to a
+                # ShardingRules), merged by the compiled block AFTER any
+                # program-attached rules. Both fingerprints go into the cache key —
+                # rules hang off live objects and may grow between runs.
+                from .parallel.sharding_rules import ShardingRules
 
-        bs_rules = self._build_strategy.sharding_rules
-        if bs_rules is not None and not isinstance(bs_rules, ShardingRules):
-            bs_rules = ShardingRules(bs_rules)
-        prog_rules = getattr(program, "_sharding_rules", None)
-        key = (
-            program._uid,
-            program._version,
-            tuple(sorted((n, a.shape, str(a.dtype)) for n, a in feed_arrays.items())),
-            tuple(fetch_names),
-            self._scope._uid,
-            steps_per_run,
-            force_multi and steps_per_run == 1,
-            (
-                self._exec_strategy.pipeline_schedule,
-                self._exec_strategy.num_microbatches,
-            )
-            if pp > 1
-            else None,
-            # toggling FLAGS_tensor_stats must recompile (executor.py key
-            # carries the same term)
-            _flags_opprof()["tensor_stats"],
-            bs_rules.fingerprint() if bs_rules is not None else None,
-            prog_rules.fingerprint() if prog_rules is not None else None,
-        )
-        compiled = self._cache.get(key)
-        _obs_cache_hit = compiled is not None
-        if compiled is None:
-            # FLAGS_static_verify (docs/static_analysis.md): mesh-aware lint —
-            # the analyzer resolves sharding specs through the same Resolver
-            # precedence the compile below uses
-            from .analysis import maybe_static_verify
-
-            maybe_static_verify(
-                program, list(feed_arrays.keys()), fetch_names,
-                scope=self._scope, mesh=self._mesh, rules=bs_rules,
-                mode="inference" if getattr(program, "_is_test", False)
-                else "training",
-                where="parallel_executor",
-            )
-            # feed_ranks are UNSTACKED ranks: rank 0 (scalars) replicate
-            feed_ranks = {
-                n: np.ndim(a) - batch_dim for n, a in feed_arrays.items()
-            }
-            # ReduceStrategy.Reduce → ZeRO-1 over the dp axis (BuildStrategy
-            # docstring); degrades to the replicated path when dp == 1
-            zero1_axis = (
-                "dp"
-                if self._build_strategy.reduce_strategy == ReduceStrategy.Reduce
-                and self._mesh.shape.get("dp", 1) > 1
-                else None
-            )
-            if pp > 1:
-                compiled = _PipelinedBlock(
-                    program, block, list(feed_arrays.keys()), fetch_names,
-                    self._scope, mesh=self._mesh, feed_ranks=feed_ranks,
-                    zero1_axis=zero1_axis, sharding_rules=bs_rules,
-                    loss_name=self._loss_name,
-                    n_micro=self._exec_strategy.num_microbatches,
-                    schedule=self._exec_strategy.pipeline_schedule,
+                bs_rules = self._build_strategy.sharding_rules
+                if bs_rules is not None and not isinstance(bs_rules, ShardingRules):
+                    bs_rules = ShardingRules(bs_rules)
+                prog_rules = getattr(program, "_sharding_rules", None)
+                key = (
+                    program._uid,
+                    program._version,
+                    tuple(sorted((n, a.shape, str(a.dtype)) for n, a in feed_arrays.items())),
+                    tuple(fetch_names),
+                    self._scope._uid,
+                    steps_per_run,
+                    force_multi and steps_per_run == 1,
+                    (
+                        self._exec_strategy.pipeline_schedule,
+                        self._exec_strategy.num_microbatches,
+                    )
+                    if pp > 1
+                    else None,
+                    # toggling FLAGS_tensor_stats must recompile (executor.py key
+                    # carries the same term)
+                    _flags_opprof()["tensor_stats"],
+                    bs_rules.fingerprint() if bs_rules is not None else None,
+                    prog_rules.fingerprint() if prog_rules is not None else None,
                 )
-            elif is_multi:
-                compiled = _MultiStepBlock(
-                    program, block, list(feed_arrays.keys()), fetch_names,
-                    self._scope, steps_per_run, mesh=self._mesh,
-                    data_axes=self._data_axes, feed_ranks=feed_ranks,
-                    zero1_axis=zero1_axis, sharding_rules=bs_rules,
-                )
-            else:
-                compiled = _CompiledBlock(
-                    program,
-                    block,
-                    list(feed_arrays.keys()),
-                    fetch_names,
-                    self._scope,
-                    mesh=self._mesh,
-                    data_axes=self._data_axes,
-                    feed_ranks=feed_ranks,
-                    zero1_axis=zero1_axis,
-                    sharding_rules=bs_rules,
-                )
-            self._cache[key] = compiled
+                compiled = self._cache.get(key)
+                _obs_cache_hit = compiled is not None
+                if compiled is None:
+                    # FLAGS_static_verify (docs/static_analysis.md): mesh-aware lint —
+                    # the analyzer resolves sharding specs through the same Resolver
+                    # precedence the compile below uses
+                    from .analysis import maybe_static_verify
 
-        # place the global batch sharded over the mesh before dispatch;
-        # rank-0 feeds (scalars like a lr) cannot be batch-sharded — replicate
-        from jax.sharding import NamedSharding, PartitionSpec as P
+                    maybe_static_verify(
+                        program, list(feed_arrays.keys()), fetch_names,
+                        scope=self._scope, mesh=self._mesh, rules=bs_rules,
+                        mode="inference" if getattr(program, "_is_test", False)
+                        else "training",
+                        where="parallel_executor",
+                    )
+                    # feed_ranks are UNSTACKED ranks: rank 0 (scalars) replicate
+                    feed_ranks = {
+                        n: np.ndim(a) - batch_dim for n, a in feed_arrays.items()
+                    }
+                    # ReduceStrategy.Reduce → ZeRO-1 over the dp axis (BuildStrategy
+                    # docstring); degrades to the replicated path when dp == 1
+                    zero1_axis = (
+                        "dp"
+                        if self._build_strategy.reduce_strategy == ReduceStrategy.Reduce
+                        and self._mesh.shape.get("dp", 1) > 1
+                        else None
+                    )
+                    if pp > 1:
+                        compiled = _PipelinedBlock(
+                            program, block, list(feed_arrays.keys()), fetch_names,
+                            self._scope, mesh=self._mesh, feed_ranks=feed_ranks,
+                            zero1_axis=zero1_axis, sharding_rules=bs_rules,
+                            loss_name=self._loss_name,
+                            n_micro=self._exec_strategy.num_microbatches,
+                            schedule=self._exec_strategy.pipeline_schedule,
+                        )
+                    elif is_multi:
+                        compiled = _MultiStepBlock(
+                            program, block, list(feed_arrays.keys()), fetch_names,
+                            self._scope, steps_per_run, mesh=self._mesh,
+                            data_axes=self._data_axes, feed_ranks=feed_ranks,
+                            zero1_axis=zero1_axis, sharding_rules=bs_rules,
+                        )
+                    else:
+                        compiled = _CompiledBlock(
+                            program,
+                            block,
+                            list(feed_arrays.keys()),
+                            fetch_names,
+                            self._scope,
+                            mesh=self._mesh,
+                            data_axes=self._data_axes,
+                            feed_ranks=feed_ranks,
+                            zero1_axis=zero1_axis,
+                            sharding_rules=bs_rules,
+                        )
+                    self._cache[key] = compiled
 
-        repl = NamedSharding(self._mesh, P())
-        sharded = {
-            n: jax.device_put(
-                a,
-                compiled._feed_sharding
-                if np.ndim(a) > batch_dim
-                else repl,
-            )
-            for n, a in feed_arrays.items()
-        }
-        fetches = compiled(self._scope, sharded)
-        # correlation seed for compiled_hlo(): abstract feed shapes only
-        # (concrete arrays would pin a batch of device memory), same
-        # contract as Executor._last_run
-        self._last_run = (
-            compiled,
-            weakref.ref(self._scope),
-            {
-                n: jax.ShapeDtypeStruct(a.shape, a.dtype)
-                for n, a in sharded.items()
-            },
-        )
-        result = [np.asarray(f) for f in fetches] if return_numpy else fetches
-        if _obs is not None:
-            # pp runs carry their schedule so the collector can group step
-            # times by (pp, schedule, m) for the two-m-slope bubble gauge
-            plan = getattr(compiled, "stage_plan", None)
-            _telemetry_record(
-                _obs, _obs_t0, compiled, _obs_cache_hit, False,
-                steps_per_run if is_multi else 1, result, return_numpy,
-                pp=pp if pp > 1 else None,
-                n_micro=plan["n_micro"] if plan else None,
-                schedule=plan["schedule"] if plan else None,
-            )
-        return result
+                # place the global batch sharded over the mesh before dispatch;
+                # rank-0 feeds (scalars like a lr) cannot be batch-sharded — replicate
+                from jax.sharding import NamedSharding, PartitionSpec as P
+
+                repl = NamedSharding(self._mesh, P())
+                sharded = {
+                    n: jax.device_put(
+                        a,
+                        compiled._feed_sharding
+                        if np.ndim(a) > batch_dim
+                        else repl,
+                    )
+                    for n, a in feed_arrays.items()
+                }
+            with _prof.RecordEvent(span="executor.call", **seq):
+                fetches = compiled(self._scope, sharded)
+            with _prof.RecordEvent(span="executor.finish", **seq):
+                # correlation seed for compiled_hlo(): abstract feed shapes only
+                # (concrete arrays would pin a batch of device memory), same
+                # contract as Executor._last_run
+                self._last_run = (
+                    compiled,
+                    weakref.ref(self._scope),
+                    {
+                        n: jax.ShapeDtypeStruct(a.shape, a.dtype)
+                        for n, a in sharded.items()
+                    },
+                )
+                result = [np.asarray(f) for f in fetches] if return_numpy else fetches
+                if _obs is not None:
+                    # pp runs carry their schedule so the collector can group step
+                    # times by (pp, schedule, m) for the two-m-slope bubble gauge
+                    plan = getattr(compiled, "stage_plan", None)
+                    _telemetry_record(
+                        _obs, _obs_t0, compiled, _obs_cache_hit, False,
+                        steps_per_run if is_multi else 1, result, return_numpy,
+                        pp=pp if pp > 1 else None,
+                        n_micro=plan["n_micro"] if plan else None,
+                        schedule=plan["schedule"] if plan else None,
+                    )
+                return result
 
     def compiled_hlo(self):
         """Post-optimization HLO text of the most recently run SPMD block

@@ -21,6 +21,8 @@ import os
 import threading
 import time
 
+from jax.profiler import TraceAnnotation
+
 __all__ = [
     "RecordEvent",
     "start_profiler",
@@ -42,31 +44,62 @@ def is_profiling():
     return _state["on"]
 
 
+SPAN_PREFIX = "fluid."
+
+
 class RecordEvent:
     """RAII event (reference platform/profiler.h:66). Nesting is recorded via
-    name stacking, like the reference's pushed event pairs."""
+    name stacking, like the reference's pushed event pairs.
 
-    def __init__(self, name):
+    Every event is also a span named "fluid.<span or name>" in jax.profiler's
+    trace (a TraceAnnotation carrying `tags`): with a profiler session on it
+    lands on the host timeline of the xplane that holds the device's
+    operations, one clock for both; with none it costs under a microsecond.
+    The catalog of span names is docs/observability.md's. With `name` None
+    the event is a span only: it adds no row to the start_profiler() table
+    and no level to the rows nested in it. `hist` (a registry
+    Histogram) observes the wall ms at exit and `stall` (anything with
+    observe()) the part of it the calling thread was not on a CPU, wall minus
+    time.thread_time(): for a phase that makes no blocking call, the time it
+    waited for the interpreter lock, a mutex or the OS. Both observe whether a
+    session is on or not, like every registry metric. `ms` is the wall time
+    of the last exit."""
+
+    def __init__(self, name=None, span=None, hist=None, stall=None, **tags):
         self.name = name
+        self.ms = None
+        self._ann = TraceAnnotation(SPAN_PREFIX + (span or name), **tags)
+        self._hist = hist
+        self._stall = stall
         self._start = None
         self._pushed = False
 
     def __enter__(self):
-        if _state["on"]:
+        if _state["on"] and self.name is not None:
             stack = getattr(_tls, "stack", None)
             if stack is None:
                 stack = _tls.stack = []
             stack.append(self.name)
             self._pushed = True
-            self._start = time.perf_counter()
+        self._ann.__enter__()
+        if self._stall is not None:
+            self._cpu = time.thread_time()
+        self._start = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
+        end = time.perf_counter()
+        self.ms = (end - self._start) * 1e3
+        if self._stall is not None:
+            cpu_ms = (time.thread_time() - self._cpu) * 1e3
+            self._stall.observe(max(0.0, self.ms - cpu_ms))
+        self._ann.__exit__(*exc)
+        if self._hist is not None:
+            self._hist.observe(self.ms)
         # pop whenever we pushed — profiling may have been stopped by another
         # thread mid-event, and a leaked stack entry would prefix every event
         # of the next session
         if self._pushed:
-            end = time.perf_counter()
             stack = _tls.stack
             full = "/".join(stack)
             stack.pop()

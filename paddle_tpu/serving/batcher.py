@@ -29,7 +29,8 @@ batcher's job is the time/row tradeoff and the failure modes:
   with ShutdownError.
 
 Telemetry (PR 4 registry, `serving/<model>/...`): queue_ms and latency_ms
-histograms split queue wait from the engine's device_ms, queue-depth and
+histograms split queue wait from the engine call's host wall (the
+`device_ms` histogram), queue-depth and
 in-flight gauges, and a `requests` counter labelled by outcome
 (ok/rejected/timeout/error/shutdown).
 """

@@ -201,7 +201,10 @@ class ServingEngine:
         reg = _registry.default_registry()
         p = "serving/%s" % self.name
         self._m_device_ms = reg.histogram(
-            p + "/device_ms", "per-engine-call device time (padded bucket)"
+            p + "/device_ms",
+            "per-engine-call host wall ms (padded bucket): dispatch, the "
+            "device's work and the outputs' copy to the host; the name is "
+            "historical",
         )
         self._m_fill = reg.histogram(
             p + "/batch_fill", "real rows / bucket rows per engine call",
@@ -529,9 +532,9 @@ class ServingEngine:
         outs = fn(padded, ro, mut)
         self._served_tls.version = ver
         outs = [np.asarray(o) for o in outs]
-        device_ms = (time.perf_counter() - t0) * 1e3
-        span.tag(device_ms=round(device_ms, 3)).end()
-        self._m_device_ms.observe(device_ms)
+        host_ms = (time.perf_counter() - t0) * 1e3
+        span.tag(host_ms=round(host_ms, 3)).end()
+        self._m_device_ms.observe(host_ms)
         self._m_rows.inc(n)
         self._m_padded.inc(bucket - n)
         self._m_fill.observe(n / float(bucket))

@@ -61,6 +61,7 @@ import numpy as np
 
 from ..executor import Scope, aot_serve_lowering, scope_guard
 from ..observability import tracing as _tracing
+from ..profiler import RecordEvent
 from ..observability.tracing import NULL_SPAN
 from .batcher import (
     ContinuousBatcher,
@@ -194,6 +195,31 @@ class _Variant:
         self.mut_names = mut_names
         self.feed_names = feed_names
         self.avals = avals
+
+
+class _Sum:
+    """A running sum with a histogram's observe(): the stall of the host-only
+    phases of one scheduler iteration, taken once per iteration."""
+
+    __slots__ = ("total",)
+
+    def __init__(self):
+        self.total = 0.0
+
+    def observe(self, value):
+        self.total += value
+
+    def take(self):
+        total, self.total = self.total, 0.0
+        return total
+
+
+# gen_ctx_tokens: positions one decode step attends, summed over its runs
+# (a step of 24 slots at full 1024-position context reads 24576)
+CTX_TOKEN_BUCKETS = (
+    16, 32, 64, 128, 256, 512, 1024, 2048, 4096, 8192, 16384, 32768, 65536,
+    131072, 262144, 524288, 1048576,
+)
 
 
 class GenerationEngine:
@@ -338,6 +364,32 @@ class GenerationEngine:
         self._m_chunks = reg.counter(
             p + "/gen_prefill_chunks", "prefill chunk calls executed"
         )
+        # the decode step's four phases (fluid.engine.* spans), decode steps
+        # only: a prefill chunk blocks only when it is a prompt's last
+        self._m_phase_ms = {
+            phase: reg.histogram(p + "/gen_%s_ms" % phase, help)
+            for phase, help in (
+                ("feed", "decode step: writing the feed buffers, wall ms"),
+                ("dispatch", "decode step: the compiled call returning "
+                             "(enqueue), wall ms"),
+                ("wait", "decode step: device sync plus the logits' copy to "
+                         "the host, wall ms"),
+                ("sample", "decode step: host sampling of every run's "
+                           "token, wall ms"),
+            )
+        }
+        self._m_ctx_tokens = reg.histogram(
+            p + "/gen_ctx_tokens",
+            "positions one decode step has to attend, summed over its runs",
+            buckets=CTX_TOKEN_BUCKETS,
+        )
+        # whoever drives the engine step by step (the scheduler) writes its
+        # iteration count here; every fluid.engine.* span and the request
+        # tracer's engine.decode / engine.prefill spans carry it as `iter`
+        self.iter = 0
+        # wall minus thread CPU time of the host-only phases (feed, sample,
+        # and the scheduler's admit and retire), summed until taken
+        self.host_stall = _Sum()
         self._m_prefix_hit = reg.gauge(
             p + "/gen_prefix_hit_rate",
             "prefix-cache page hit rate (pages hit / pages eligible)",
@@ -533,12 +585,25 @@ class GenerationEngine:
         self._m_swaps.inc()
         return len(conv)
 
-    def _call(self, variant, np_feeds):
+    def _phase(self, phase, kind):
+        """One leaf of a model step: the span fluid.engine.<phase>, the
+        phase's histogram on decode steps, the stall of the host-only ones."""
+        return RecordEvent(
+            span="engine." + phase,
+            hist=self._m_phase_ms[phase] if kind == "decode" else None,
+            stall=self.host_stall if phase in ("feed", "sample") else None,
+            kind=kind, iter=self.iter,
+        )
+
+    def _feeds(self, variant, np_feeds):
+        """(feeds, mut_in) for one call of a variant: the feed phase's end."""
         feeds = {}
         for n in variant.feed_names:
             s = variant.avals[n]
             feeds[n] = np.ascontiguousarray(np_feeds[n], dtype=s.dtype)
-        mut_in = {n: self._state[n] for n in variant.mut_names}
+        return feeds, {n: self._state[n] for n in variant.mut_names}
+
+    def _dispatch(self, variant, feeds, mut_in):
         fetches, new_mut = variant.fn(feeds, variant.ro, mut_in)
         self._state.update(new_mut)
         return fetches
@@ -613,49 +678,57 @@ class GenerationEngine:
             raise ValueError("prefill_step on a fully prefilled run")
         c = self.prefill_bucket(remaining)
         n_real = min(c, remaining)
-        tokens = np.zeros((1, c, 1), np.int64)
-        tokens[0, :n_real, 0] = req.prompt[start:start + n_real]
         span = _tracing.current()
         if span:
             span = span.child(
                 "engine.prefill", chunk=c, start=start, rows=n_real,
                 kv_dtype=self.kv_dtype, model_version=self.model_version,
+                iter=self.iter,
             )
         t0 = time.perf_counter()
         try:
-            (logits,) = self._call(
-                self._variant("prefill:%d" % c),
-                {
+            with self._phase("feed", "prefill"):
+                tokens = np.zeros((1, c, 1), np.int64)
+                tokens[0, :n_real, 0] = req.prompt[start:start + n_real]
+                variant = self._variant("prefill:%d" % c)
+                feeds, mut_in = self._feeds(variant, {
                     "gen_tokens": tokens,
                     "gen_start": np.array([start], np.int64),
                     "gen_last": np.array([n_real - 1], np.int64),
                     "gen_pages": run.table,
-                },
-            )
+                })
+            with self._phase("dispatch", "prefill") as dispatch:
+                (logits,) = self._dispatch(variant, feeds, mut_in)
         except Exception as e:
             span.error(e).end()
             raise
         prefill_ms = (time.perf_counter() - t0) * 1e3
-        span.tag(device_ms=round(prefill_ms, 3)).end()
+        span.tag(
+            host_ms=round(prefill_ms, 3), dispatch_ms=round(dispatch.ms, 3)
+        ).end()
         self._m_prefill_ms.observe(prefill_ms)
         self._m_chunks.inc()
         run.pf_pos = start + n_real
         if run.pf_pos < L:
             return False
         self._m_prefills.inc()
-        # parity surface: tests assert these rows bit-stable under
-        # batching/admission/chunking changes (docs/serving.md contract)
-        self.last_prefill_logits = np.asarray(logits)[0]
-        self._append_token(run, self.last_prefill_logits, self._max_new(req))
-        if self.prefix_cache is not None:
-            self.prefix_cache.insert(req.prompt, run.table)
-        # arm the slot's persistent decode-feed rows only now: until the
-        # last chunk lands, an interleaved decode step must keep this slot
-        # on the scratch page, never writing a page a chunk already filled
-        self._dec_feeds["dec_block_table"][run.slot] = run.table
-        self._dec_feeds["dec_tokens"][run.slot, 0] = run.tokens[-1]
-        self._dec_feeds["dec_positions"][run.slot, 0] = run.next_pos
-        self._set_pool_gauges()
+        with self._phase("wait", "prefill"):
+            # parity surface: tests assert these rows bit-stable under
+            # batching/admission/chunking changes (docs/serving.md contract)
+            self.last_prefill_logits = np.asarray(logits)[0]
+        with self._phase("sample", "prefill"):
+            self._append_token(
+                run, self.last_prefill_logits, self._max_new(req))
+            if self.prefix_cache is not None:
+                self.prefix_cache.insert(req.prompt, run.table)
+            # arm the slot's persistent decode-feed rows only now: until the
+            # last chunk lands, an interleaved decode step must keep this
+            # slot on the scratch page, never writing a page a chunk already
+            # filled
+            self._dec_feeds["dec_block_table"][run.slot] = run.table
+            self._dec_feeds["dec_tokens"][run.slot, 0] = run.tokens[-1]
+            self._dec_feeds["dec_positions"][run.slot, 0] = run.next_pos
+            self._set_pool_gauges()
         return True
 
     def start(self, req):
@@ -679,34 +752,48 @@ class GenerationEngine:
         caller retires them via finish()."""
         if not runs:
             return
-        feeds = self._dec_feeds
-        tokens, positions = feeds["dec_tokens"], feeds["dec_positions"]
-        for run in runs:
-            if run.done:
-                raise ValueError("decode_step on a finished run")
-            tokens[run.slot, 0] = run.tokens[-1]
-            positions[run.slot, 0] = run.next_pos
         span = _tracing.current()
         if span:
             span = span.child(
                 "engine.decode", slots=len(runs),
                 kv_dtype=self.kv_dtype, model_version=self.model_version,
+                iter=self.iter,
             )
         t0 = time.perf_counter()
         try:
-            (logits,) = self._call(self._variant("decode"), feeds)
+            with self._phase("feed", "decode"):
+                feeds = self._dec_feeds
+                tokens, positions = feeds["dec_tokens"], feeds["dec_positions"]
+                ctx_tokens = 0
+                for run in runs:
+                    if run.done:
+                        raise ValueError("decode_step on a finished run")
+                    tokens[run.slot, 0] = run.tokens[-1]
+                    positions[run.slot, 0] = run.next_pos
+                    ctx_tokens += run.next_pos
+                variant = self._variant("decode")
+                feeds, mut_in = self._feeds(variant, feeds)
+            with self._phase("dispatch", "decode") as dispatch:
+                (logits,) = self._dispatch(variant, feeds, mut_in)
+            with self._phase("wait", "decode") as wait:
+                logits = np.asarray(logits)
         except Exception as e:
             span.error(e).end()
             raise
-        logits = np.asarray(logits)
         self.last_logits = logits  # parity surface, see prefill_step()
         step_ms = (time.perf_counter() - t0) * 1e3
-        span.tag(device_ms=round(step_ms, 3)).end()
+        span.tag(
+            host_ms=round(step_ms, 3), dispatch_ms=round(dispatch.ms, 3),
+            wait_ms=round(wait.ms, 3),
+        ).end()
         self._m_step_ms.observe(step_ms)
+        self._m_ctx_tokens.observe(ctx_tokens)
         self._m_steps.inc()
-        for run in runs:
-            run.next_pos += 1
-            self._append_token(run, logits[run.slot], self._max_new(run.req))
+        with self._phase("sample", "decode"):
+            for run in runs:
+                run.next_pos += 1
+                self._append_token(
+                    run, logits[run.slot], self._max_new(run.req))
 
     def finish(self, run):
         """Retire a run's slot: pages return to the pool for reuse (cached
@@ -772,6 +859,13 @@ class GenerationEngine:
             self.finish(run)
         return run.result()
 
+    def compiled_hlo(self):
+        """{variant kind: post-optimization HLO text} of every variant built
+        so far ('decode', 'prefill:<bucket>'); each instruction carries the
+        op_name metadata that names the framework op behind it (see
+        Executor.compiled_hlo)."""
+        return {k: v.fn.as_text() for k, v in sorted(self._variants.items())}
+
     def stats(self):
         from ..ops import pallas_kernels as _pk
 
@@ -785,6 +879,11 @@ class GenerationEngine:
             "prefill_chunk": self.prefill_chunk,
             "prefill_chunks": self._m_chunks.value(),
             "geometry": self.geometry(),
+            # what the paged kernel walks each decode step, whatever the
+            # contexts: beside gen_ctx_tokens, the positions it has to
+            "positions_walked_per_step": (
+                self.max_slots * self.max_pages * self.page_size
+            ),
             "pool": self.pool.stats(),
             # lowering-time kernel choices (counts are per trace, not per
             # call): the smoke/bench stages assert paged_flash shows up here
@@ -854,6 +953,30 @@ class GenerationScheduler(ContinuousBatcher):
         self._m_token_ms = reg.histogram(
             p + "/gen_token_ms", "per-token latency (decode step wall)"
         )
+        # the scheduler thread's time by phase (fluid.sched.* spans): idle is
+        # the wait for work outside an iteration, the other five partition it
+        self._m_sched_ms = {
+            phase: reg.histogram(p + "/sched_%s_ms" % phase, help)
+            for phase, help in (
+                ("iter", "one scheduler iteration, wall ms"),
+                ("idle", "waiting with nothing to serve, wall ms"),
+                ("lock", "iteration: acquiring the queue's lock, wall ms"),
+                ("admit", "iteration: admission (queue pop, slot and page "
+                          "reservation), wall ms"),
+                ("prefill", "iteration: prefill chunks and first-token "
+                            "bookkeeping, wall ms"),
+                ("decode", "iteration: the decode step, wall ms"),
+                ("retire", "iteration: retiring finished runs and resolving "
+                           "their futures, wall ms"),
+            )
+        }
+        self._m_stall_ms = reg.histogram(
+            p + "/sched_host_stall_ms",
+            "iteration: wall minus thread CPU time of its host-only phases "
+            "(admit, retire, the engine's feed and sample): the scheduler "
+            "thread waiting for the interpreter lock, a mutex or the OS",
+        )
+        self._iter = 0
         super().__init__(
             engine,
             max_queue_rows=max_queue_requests,
@@ -905,21 +1028,69 @@ class GenerationScheduler(ContinuousBatcher):
         )
 
     # ---- step loop --------------------------------------------------------
+    def _phase(self, phase, stall=None):
+        return RecordEvent(
+            span="sched." + phase, hist=self._m_sched_ms[phase], stall=stall,
+            iter=self._iter,
+        )
+
     def _loop(self):
         while True:
-            with self._cond:
-                while (self._alive and not self._queue and not self._runs
-                       and not self._prefills):
-                    self._cond.wait()
+            # an unlocked peek (only this thread empties these), checked
+            # again under the lock: a busy scheduler takes the lock once a
+            # pass, inside the pass, where its wait is measured
+            if not (self._queue or self._runs or self._prefills):
+                self._wait_for_work()
+            self._iter += 1
+            self.engine.iter = self._iter
+            with self._phase("iter"):
+                if not self._pass():
+                    return
+
+    def _wait_for_work(self):
+        with self._cond:
+            if (self._alive and not self._queue and not self._runs
+                    and not self._prefills):
+                with self._phase("idle"):
+                    while (self._alive and not self._queue and not self._runs
+                           and not self._prefills):
+                        self._cond.wait()
+
+    def _pass(self):
+        """One iteration, in five phases that partition it: lock, admit,
+        prefill, decode, retire. False when the scheduler is closed and has
+        nothing left to do."""
+        stall = self.engine.host_stall
+        with self._phase("lock"):
+            self._cond.acquire()
+        with self._phase("admit", stall):
+            try:
                 if not self._alive:
                     if not self._drain_flag:
                         self._fail_runs_locked()
-                        return
+                        return False
                     if (not self._queue and not self._runs
                             and not self._prefills):
-                        return
+                        return False
                 admits = self._admit_requests_locked()
-            self._step(admits)
+            finally:
+                self._cond.release()
+            self._admit(admits)
+        with self._phase("prefill"):
+            self._prefill_chunks()
+        live = list(self._runs.values())
+        if live:
+            with self._phase("decode") as decode:
+                ok = self._decode(live)
+            if ok:
+                with self._phase("retire", stall):
+                    for run in live:
+                        self._m_token_ms.observe(decode.ms)
+                        if run.done:
+                            del self._runs[run.slot]
+                            self._retire(run)
+        self._m_stall_ms.observe(stall.take())
+        return True
 
     def _admit_requests_locked(self):
         """Pop queued requests that fit free capacity right now. Admission
@@ -965,7 +1136,7 @@ class GenerationScheduler(ContinuousBatcher):
         self._m_depth.set(self._queued_rows)
         return admits
 
-    def _step(self, admits):
+    def _admit(self, admits):
         eng = self.engine
         for pending in admits:
             queue_ms = (time.perf_counter() - pending.t_submit) * 1e3
@@ -995,68 +1166,67 @@ class GenerationScheduler(ContinuousBatcher):
             ).event("admitted", slot=run.slot, queue_ms=round(queue_ms, 3))
             self._prefills.append(run)
 
-        # advance prefill chunk-by-chunk: normally one chunk per step (its
-        # latency rides on every live slot's token), draining every pending
-        # prompt when the queue is deep OR when no slot is decoding (then
-        # there is nobody to stall). Chunks go shortest-remaining-first, so
-        # a short prompt admitted behind a half-prefilled long one
-        # overtakes it and samples its first token next step — the
-        # queue-pressure escalation bounds how long the long prompt can be
-        # overtaken. TTFT starts at the chunk that samples the first token.
-        if self._prefills:
-            n_chunks = self.prefill_per_step
-            if not self._runs or self._queued_rows >= self.pressure_queue:
-                n_chunks = len(self._prefills)
-            order = sorted(self._prefills,
-                           key=lambda r: len(r.req.prompt) - r.pf_pos)
-            for run in order[:n_chunks]:
-                try:
-                    with _tracing.tracer().activate(run.span):
-                        finished = eng.prefill_step(run)
-                except Exception as e:
-                    self._prefills.remove(run)
-                    self._m_requests.inc(outcome="error")
-                    run.span.error(e).tag(outcome="error").end()
-                    err = RuntimeError("prefill failed: %s" % (repr(e),))
-                    err.__cause__ = e
-                    run.future._set_error(err)
-                    eng.finish(run)
-                    continue
-                if finished:
-                    self._prefills.remove(run)
-                    run.t_first = time.perf_counter()
-                    ttft_ms = (run.t_first - run.t_submit) * 1e3
-                    self._m_ttft_ms.observe(ttft_ms)
-                    run.span.event("first_token", ttft_ms=round(ttft_ms, 3))
-                    if run.done:
-                        self._retire(run)
-                    else:
-                        self._runs[run.slot] = run
-
-        live = list(self._runs.values())
-        if live:
-            t0 = time.perf_counter()
+    def _prefill_chunks(self):
+        """Advance prefill chunk-by-chunk: normally one chunk per step (its
+        latency rides on every live slot's token), draining every pending
+        prompt when the queue is deep OR when no slot is decoding (then
+        there is nobody to stall). Chunks go shortest-remaining-first, so
+        a short prompt admitted behind a half-prefilled long one
+        overtakes it and samples its first token next step — the
+        queue-pressure escalation bounds how long the long prompt can be
+        overtaken. TTFT starts at the chunk that samples the first token."""
+        if not self._prefills:
+            return
+        eng = self.engine
+        n_chunks = self.prefill_per_step
+        if not self._runs or self._queued_rows >= self.pressure_queue:
+            n_chunks = len(self._prefills)
+        order = sorted(self._prefills,
+                       key=lambda r: len(r.req.prompt) - r.pf_pos)
+        for run in order[:n_chunks]:
             try:
-                # the decode step is shared across slots; its engine.decode
-                # span hangs off one representative request's trace
-                with _tracing.tracer().activate(live[0].span):
-                    eng.decode_step(live)
+                with _tracing.tracer().activate(run.span):
+                    finished = eng.prefill_step(run)
             except Exception as e:
-                for run in live:
-                    self._m_requests.inc(outcome="error")
-                    run.span.error(e).tag(outcome="error").end()
-                    err = RuntimeError("decode failed: %s" % (repr(e),))
-                    err.__cause__ = e
-                    run.future._set_error(err)
-                    eng.finish(run)
-                self._runs.clear()
-                return
-            step_ms = (time.perf_counter() - t0) * 1e3
-            for run in live:
-                self._m_token_ms.observe(step_ms)
+                self._prefills.remove(run)
+                self._m_requests.inc(outcome="error")
+                run.span.error(e).tag(outcome="error").end()
+                err = RuntimeError("prefill failed: %s" % (repr(e),))
+                err.__cause__ = e
+                run.future._set_error(err)
+                eng.finish(run)
+                continue
+            if finished:
+                self._prefills.remove(run)
+                run.t_first = time.perf_counter()
+                ttft_ms = (run.t_first - run.t_submit) * 1e3
+                self._m_ttft_ms.observe(ttft_ms)
+                run.span.event("first_token", ttft_ms=round(ttft_ms, 3))
                 if run.done:
-                    del self._runs[run.slot]
                     self._retire(run)
+                else:
+                    self._runs[run.slot] = run
+
+    def _decode(self, live):
+        """One decode step for every live run. False when it failed: every
+        live run's future then holds the error and its slot is released."""
+        eng = self.engine
+        try:
+            # the decode step is shared across slots; its engine.decode
+            # span hangs off one representative request's trace
+            with _tracing.tracer().activate(live[0].span):
+                eng.decode_step(live)
+        except Exception as e:
+            for run in live:
+                self._m_requests.inc(outcome="error")
+                run.span.error(e).tag(outcome="error").end()
+                err = RuntimeError("decode failed: %s" % (repr(e),))
+                err.__cause__ = e
+                run.future._set_error(err)
+                eng.finish(run)
+            self._runs.clear()
+            return False
+        return True
 
     def _retire(self, run):
         self.engine.finish(run)

@@ -1,0 +1,345 @@
+"""The phase spans of the scheduler loop, the engine step and Executor.run
+(profiler.RecordEvent -> jax.profiler TraceAnnotation "fluid.*",
+docs/observability.md): under a real CPU profiler session the spans land in
+the xplane, the scheduler's five phases partition their iteration and the
+engine's leaves nest in them; the phase histograms add up; gen_ctx_tokens
+counts a hand-built step; both executors leave their three leaves; outputs do
+not change with a session on; the repaired span tags; the public reads."""
+
+import glob
+import os
+import time
+
+import jax
+import numpy as np
+import pytest
+
+import paddle_tpu.fluid as fluid
+from paddle_tpu import flags as _flags
+from paddle_tpu import profiler
+from paddle_tpu.executor import Scope, scope_guard
+from paddle_tpu.models.gpt_decoder import GPTDecoder
+from paddle_tpu.observability import registry, tracing
+from paddle_tpu.serving import GenerationEngine, GenerationScheduler, GenRequest
+
+MODEL_KW = dict(
+    vocab_size=24, n_layer=2, n_head=2, d_model=16, d_inner=32, max_context=32
+)
+NO_EOS = 999
+SCHED_PHASES = ("lock", "admit", "prefill", "decode", "retire")
+ENGINE_LEAVES = ("feed", "dispatch", "wait", "sample")
+
+
+def make_engine(name):
+    eng = GenerationEngine(
+        GPTDecoder(**MODEL_KW), name=name, max_slots=3, page_size=4,
+        max_context=32, cache_dir=None,
+    )
+    eng.warmup()
+    return eng
+
+
+class Session:
+    """A jax.profiler session without the Python tracer, as chipbench starts
+    one; `spans` are the fluid.* host events it recorded."""
+
+    def __init__(self, log_dir):
+        self.log_dir = str(log_dir)
+        self.spans = []
+
+    def __enter__(self):
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(self.log_dir, profiler_options=options)
+        return self
+
+    def __exit__(self, *exc):
+        jax.profiler.stop_trace()
+        for path in glob.glob(os.path.join(self.log_dir, "**", "*.xplane.pb"),
+                              recursive=True):
+            for plane in jax.profiler.ProfileData.from_file(path).planes:
+                for line in plane.lines:
+                    for ev in line.events:
+                        if ev.name.startswith(profiler.SPAN_PREFIX):
+                            self.spans.append({
+                                "name": ev.name[len(profiler.SPAN_PREFIX):],
+                                "start": ev.start_ns,
+                                "end": ev.start_ns + ev.duration_ns,
+                                "tags": dict(ev.stats),
+                            })
+        return False
+
+    def named(self, name):
+        return [s for s in self.spans if s["name"] == name]
+
+
+def hist_sum(model, name):
+    return registry.default_registry().snapshot()[
+        "serving/%s/%s" % (model, name)]["sum"]
+
+
+def inside(inner, outer):
+    return outer["start"] <= inner["start"] and inner["end"] <= outer["end"]
+
+
+def test_scheduler_phases_partition_the_iteration(tmp_path):
+    eng = make_engine("phasegen")
+    sched = GenerationScheduler(eng, timeout_ms=60000.0)
+    rng = np.random.default_rng(0)
+    try:
+        with Session(tmp_path / "prof") as prof:
+            for batch in range(2):  # the scheduler idles between the two
+                futs = [
+                    sched.submit(rng.integers(1, 20, size=n).tolist(),
+                                 max_new_tokens=6, eos_id=NO_EOS)
+                    for n in (5, 9, 3)
+                ]
+                for f in futs:
+                    assert len(f.result(60.0).tokens) == 6
+                time.sleep(0.05)
+    finally:
+        sched.close()
+
+    names = {s["name"] for s in prof.spans}
+    assert {"sched.iter", "sched.idle"} <= names
+    assert {"sched." + p for p in SCHED_PHASES} <= names
+    assert {"engine." + p for p in ENGINE_LEAVES} <= names
+    assert {s["tags"]["kind"] for s in prof.named("engine.dispatch")} == {
+        "decode", "prefill"}
+
+    by_iter = {}
+    for s in prof.spans:
+        assert "iter" in s["tags"], s
+        by_iter.setdefault(s["tags"]["iter"], []).append(s)
+    decoded = 0
+    for it, group in by_iter.items():
+        wrappers = [s for s in group if s["name"] == "sched.iter"]
+        if not wrappers:
+            continue  # a pass cut by the session's start or end
+        (wrapper,) = wrappers
+        phases = sorted((s for s in group
+                         if s["name"][len("sched."):] in SCHED_PHASES),
+                        key=lambda s: s["start"])
+        assert [s["name"] for s in phases] == [
+            "sched." + p for p in SCHED_PHASES
+            if any(s["name"] == "sched." + p for s in phases)]
+        for a, b in zip(phases, phases[1:]):
+            assert a["end"] <= b["start"]  # none overlaps the next
+        assert all(inside(s, wrapper) for s in phases)
+        # sched.idle lies outside the iteration
+        assert not any(s["name"] == "sched.idle" and inside(s, wrapper)
+                       for s in group)
+        held = {s["name"]: s for s in phases}
+        for leaf in (s for s in group if s["name"].startswith("engine.")):
+            outer = held["sched." + leaf["tags"]["kind"]]
+            assert inside(leaf, outer), (leaf, outer)
+        if "sched.decode" in held:
+            decoded += 1
+            covered = sum(s["end"] - s["start"] for s in phases)
+            assert covered >= 0.9 * (wrapper["end"] - wrapper["start"])
+    assert decoded >= 5
+
+    # the operator's histograms tell the same story with no session at all
+    phases_ms = sum(hist_sum("phasegen", "sched_%s_ms" % p) for p in SCHED_PHASES)
+    iter_ms = hist_sum("phasegen", "sched_iter_ms")
+    assert iter_ms > 0 and abs(phases_ms - iter_ms) <= 0.1 * iter_ms
+    assert hist_sum("phasegen", "sched_idle_ms") >= 50 * 0.5  # the sleep
+    assert hist_sum("phasegen", "sched_host_stall_ms") <= iter_ms
+    snap = registry.default_registry().snapshot()
+    steps = snap["serving/phasegen/gen_step_ms"]["count"]
+    for leaf in ENGINE_LEAVES:  # decode steps only
+        assert snap["serving/phasegen/gen_%s_ms" % leaf]["count"] == steps
+
+
+def test_ctx_tokens_counts_the_positions_of_a_hand_built_step():
+    eng = make_engine("ctxgen")
+    runs = [eng.start(GenRequest(list(range(1, n + 1)), max_new_tokens=4,
+                                 eos_id=NO_EOS)) for n in (5, 9)]
+    try:
+        assert [r.next_pos for r in runs] == [5, 9]
+        eng.decode_step(runs)  # attends 5 + 9 positions
+        eng.decode_step(runs)  # 6 + 10
+        eng.decode_step(runs[:1])  # 7
+    finally:
+        for r in runs:
+            eng.finish(r)
+    h = registry.default_registry().snapshot()["serving/ctxgen/gen_ctx_tokens"]
+    assert (h["count"], h["sum"]) == (3, 14 + 16 + 7)
+    assert h["buckets"][-1] >= 24 * 1024  # a full gpt2-large pool fits a bucket
+    assert eng.stats()["positions_walked_per_step"] == 3 * 8 * 4
+
+
+def _toy_program():
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = fluid.layers.data("x", shape=[8], dtype="float32")
+        y = fluid.layers.fc(x, size=4)
+        loss = fluid.layers.mean(y)
+        fluid.optimizer.SGD(learning_rate=0.01).minimize(loss)
+    return main, startup, loss
+
+
+@pytest.mark.parametrize("kind", ["executor", "parallel_executor"])
+def test_executor_run_leaves_its_three_phases(tmp_path, kind):
+    if kind == "parallel_executor" and len(jax.devices()) < 4:
+        pytest.skip("needs four virtual CPU devices (tests/conftest.py)")
+    main, startup, loss = _toy_program()
+    feed = {"x": np.ones((8, 8), "float32")}
+    scope = Scope()
+    with scope_guard(scope):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        if kind == "executor":
+            step = lambda: exe.run(main, feed=feed, fetch_list=[loss.name])
+        else:
+            from paddle_tpu.parallel import MeshConfig
+
+            pe = fluid.ParallelExecutor(
+                loss_name=loss.name, main_program=main, scope=scope,
+                mesh_config=MeshConfig(dp=4), devices=jax.devices()[:4])
+            step = lambda: pe.run(fetch_list=[loss.name], feed=feed)
+        step()  # compile outside the session
+        with Session(tmp_path / "prof") as prof:
+            for _ in range(3):
+                step()
+    runs = prof.named("executor.run")
+    assert len(runs) == 3
+    iters = [r["tags"]["iter"] for r in runs]
+    assert iters == list(range(iters[0], iters[0] + 3))  # the running count
+    for r in runs:
+        leaves = sorted((s for s in prof.spans
+                         if s["tags"]["iter"] == r["tags"]["iter"]
+                         and s["name"] != "executor.run"),
+                        key=lambda s: s["start"])
+        assert [s["name"] for s in leaves] == [
+            "executor.prepare", "executor.call", "executor.finish"]
+        assert all(inside(s, r) for s in leaves)
+        for a, b in zip(leaves, leaves[1:]):
+            assert a["end"] <= b["start"]
+
+
+def test_outputs_are_bit_identical_with_a_session_on_and_null_span_stays(tmp_path):
+    eng = make_engine("bitgen")
+    prompt = [3, 7, 11, 2, 5]
+
+    def generate():
+        res = eng.generate(prompt, max_new_tokens=6, eos_id=NO_EOS)
+        return res.tokens, np.array(eng.last_logits), np.array(
+            eng.last_prefill_logits)
+
+    off = generate()
+    with Session(tmp_path / "prof") as prof:
+        on = generate()
+    assert prof.named("engine.wait")  # the session did record the run
+    assert on[0] == off[0]
+    assert on[1].tobytes() == off[1].tobytes()
+    assert on[2].tobytes() == off[2].tobytes()
+    # no profiler session and no FLAGS_trace_dir: the request path's span is
+    # the shared no-op, by identity
+    assert not _flags.get_flags("trace_dir")["trace_dir"]
+    assert tracing.tracer().start_span("serving.genrequest") is tracing.NULL_SPAN
+
+
+def test_record_event_observes_wall_and_stall_with_no_session():
+    reg = registry.MetricRegistry()
+    wall, stall = reg.histogram("wall_ms"), reg.histogram("stall_ms")
+    with profiler.RecordEvent("test.sleep", hist=wall, stall=stall, iter=1) as ev:
+        time.sleep(0.02)  # off the CPU: nearly all of it is stall
+    snap = reg.snapshot()
+    assert snap["wall_ms"]["count"] == snap["stall_ms"]["count"] == 1
+    assert ev.ms == snap["wall_ms"]["sum"] >= 20.0
+    assert 0.5 * ev.ms <= snap["stall_ms"]["sum"] <= ev.ms
+    with profiler.RecordEvent("test.spin", stall=stall) as spin:
+        t0 = time.perf_counter()
+        while time.perf_counter() - t0 < 0.02:
+            pass  # on the CPU: a smaller share of it is stall, on any host
+    spin_stall = reg.snapshot()["stall_ms"]["sum"] - snap["stall_ms"]["sum"]
+    assert spin_stall / spin.ms < snap["stall_ms"]["sum"] / ev.ms
+    assert not profiler._events  # the reference table records only when on
+
+
+def test_the_profiler_table_keeps_its_rows():
+    """The phase spans add no row to start_profiler()'s table and no level
+    to the rows nested in them (tools/timeline.py reads those names)."""
+    main, startup, loss = _toy_program()
+    feed = {"x": np.ones((8, 8), "float32")}
+    with scope_guard(Scope()):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        profiler.reset_profiler()
+        profiler.start_profiler()
+        try:
+            for _ in range(2):
+                exe.run(main, feed=feed, fetch_list=[loss.name])
+            with profiler.RecordEvent(span="test.span_only"):
+                with profiler.RecordEvent("row"):
+                    pass
+        finally:
+            table = profiler.stop_profiler(profile_path=None)
+            profiler.reset_profiler()
+    assert set(table) == {"prepare/block0", "run/block0", "row"}
+    assert table["prepare/block0"][0] == 1 and table["run/block0"][0] == 2
+
+
+@pytest.fixture()
+def trace_dir(tmp_path):
+    old = _flags.get_flags(["trace_dir", "trace_sample"])
+    _flags.set_flags({"trace_dir": str(tmp_path / "traces"), "trace_sample": 1.0})
+    tracing.reset()
+    try:
+        yield str(tmp_path / "traces")
+    finally:
+        tracing.reset()
+        _flags.set_flags(old)
+        tracing.reset()
+
+
+def test_request_spans_carry_host_ms_and_the_iteration(trace_dir):
+    """engine.decode / engine.prefill: the mislabelled device_ms tag is
+    host_ms, with the leaves' dispatch_ms and wait_ms beside it, and `iter`
+    joins the request's trace to the scheduler iteration that served it."""
+    eng = make_engine("taggen")
+    sched = GenerationScheduler(eng, timeout_ms=60000.0)
+    try:
+        sched.submit([4, 5, 6, 7, 8], max_new_tokens=4, eos_id=NO_EOS).result(60.0)
+    finally:
+        sched.close()
+    tracing.reset()
+    spans = tracing.load_spans(trace_dir)
+    decode = [s for s in spans if s["name"] == "engine.decode"]
+    prefill = [s for s in spans if s["name"] == "engine.prefill"]
+    assert decode and prefill
+    for s in decode:
+        assert {"host_ms", "dispatch_ms", "wait_ms", "iter"} <= set(s["tags"])
+        assert s["tags"]["dispatch_ms"] + s["tags"]["wait_ms"] <= s["tags"]["host_ms"] + 1e-3
+    for s in prefill:
+        assert {"host_ms", "dispatch_ms", "iter"} <= set(s["tags"])
+    assert not any("device_ms" in s["tags"] for s in spans)
+    iters = [s["tags"]["iter"] for s in prefill + decode]
+    assert iters == sorted(iters) and iters[0] >= 1
+
+
+def test_generation_engine_compiled_hlo_is_every_variant_s_text():
+    eng = make_engine("hlogen")
+    texts = eng.compiled_hlo()
+    assert set(texts) == {"decode"} | {
+        "prefill:%d" % b for b in eng.prefill_buckets}
+    assert all("HloModule" in t for t in texts.values())
+    assert "paged_attention" in texts["decode"]  # op_name metadata rides along
+
+
+def test_executor_memory_plan_is_the_last_run_s_memory_analysis():
+    main, startup, loss = _toy_program()
+    with scope_guard(Scope()):
+        exe = fluid.Executor(fluid.CPUPlace())
+        with pytest.raises(RuntimeError, match="memory_plan"):
+            exe.memory_plan()
+        exe.run(startup)
+        exe.run(main, feed={"x": np.ones((8, 8), "float32")},
+                fetch_list=[loss.name])
+        plan = exe.memory_plan()
+    for field in ("argument_size_in_bytes", "output_size_in_bytes",
+                  "temp_size_in_bytes", "generated_code_size_in_bytes",
+                  "alias_size_in_bytes"):
+        assert getattr(plan, field) >= 0
+    assert plan.argument_size_in_bytes >= 8 * 8 * 4  # the feed alone
