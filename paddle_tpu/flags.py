@@ -85,9 +85,10 @@ Honored flags:
   (ops/pallas_kernels.paged_flash_attention, the decode/chunked-prefill
   fast path behind the paged_attention lowering). "auto" (default) takes
   the Pallas kernel on a real TPU when a page is a multiple of 8 rows (all
-  Mosaic asks of the geometry) and the dense flat-gather reference
-  elsewhere (an interpreted kernel in the decode hot loop is slower than
-  dense XLA on the CPU test mesh); "on" forces the kernel everywhere —
+  Mosaic asks of the geometry; and at most 128 heads) and the dense
+  flat-gather reference elsewhere (an interpreted kernel in the decode hot
+  loop is slower than dense XLA on the CPU test mesh); "on" forces the
+  kernel everywhere —
   interpret mode off-TPU, how the hermetic parity tests pin it; "off"
   forces the dense reference. paged_flash_path_taken mirrors the decision.
 - gemm_double_buffer: dispatch tier for the manual double-buffered k-loop

@@ -30,15 +30,19 @@ Conventions:
     quantization on the scatter) and a ``[n_pages * page_size]`` f32 scale
     pool rides along as a second piece of written state. ``paged_attention``
     takes the matching ``KScales``/``VScales`` and dequantizes inline — in
-    the dense path on the gathered rows, in the Pallas kernel on the
-    block-table page walk (the f32 rows exist only in VMEM). One HBM pool
+    the dense path on the gathered rows; the Pallas kernel scales the
+    scores and the probabilities on its block-table walk instead, so no
+    dequantized row exists there at all. One HBM pool
     at ~¼ the bytes per row (int8 + one f32 scale per row) holds ≥2× the
     generation slots.
   * On TPU (or when FLAGS_paged_flash forces it) the lowering dispatches to
     the paged flash-attention Pallas kernel (ops/pallas_kernels.py), which
-    walks the block table page by page with an online softmax and never
-    materializes the gathered context. The dense form below stays as the
-    decline target and the parity oracle (PR 11 contract).
+    walks only the pages each row's table holds up to its position — a
+    loop bounded by pos over multi-page blocks the kernel copies out of the
+    pool itself — with an online softmax, and never materializes the
+    gathered context: its work follows the positions attended, where the
+    dense form's follows the table's width. The dense form below stays as
+    the decline target and the parity oracle (PR 11 contract).
 """
 
 import jax
@@ -104,7 +108,18 @@ def _kv_cache_write(ctx, ins, attrs):
     }
 
 
-@register("paged_attention", no_grad=True)
+def _paged_attention_infer(op, block):
+    """Out is shaped and typed like Q. A rule of its own, because deriving it
+    from the lowering (registry.infer_shape) traces the Pallas kernel once
+    for every layer of every variant a server builds, and again in every
+    start with a warm export cache: 216 traces and most of GPT-2 large's
+    set-up on the chip's host (PERF.md, PR 26)."""
+    q = block._var_recursive(op.inputs["Q"][0])
+    out = block._var_recursive(op.outputs["Out"][0])
+    out.shape, out.dtype = q.shape, q.dtype
+
+
+@register("paged_attention", no_grad=True, infer_shape=_paged_attention_infer)
 def _paged_attention(ctx, ins, attrs):
     (q,) = ins["Q"]  # [S, H*D] — one query token per row
     (kp,) = ins["KPool"]

@@ -47,6 +47,8 @@ __all__ = [
     "fp8_matmul",
     "paged_flash_attention",
     "paged_flash_path_taken",
+    "paged_positions_walked",
+    "KERNELS_REVISION",
     "fused_layer_norm",
     "fused_layer_norm_grad",
     "ln_path_taken",
@@ -1446,12 +1448,35 @@ def fp8_matmul(x, y):
 
 
 # ---------------------------------------------------------------------------
-# paged flash attention — the serving decode/chunk-prefill kernel. Walks a
-# slot's block table page by page with the online-softmax recurrence in a
-# VMEM accumulator, reading K/V pages straight out of the paged pool and
-# masking by position inside the loop — the gathered [*, ctx, heads, d]
-# context of the dense lowering is never materialized.
+# paged flash attention — the serving decode/chunk-prefill kernel. Walks only
+# the pages a slot holds: an in-kernel loop whose trip count comes from the
+# scalar-prefetched position, over compute blocks of several pages that the
+# kernel copies out of the HBM pool itself (double-buffered async copies
+# chasing the block table), with the online-softmax recurrence in VMEM
+# accumulators and the mask by position inside the loop — the gathered
+# [*, ctx, heads, d] context of the dense lowering is never materialized, and
+# an idle slot or a short context costs a block, not a table's worth of grid
+# steps.
 # ---------------------------------------------------------------------------
+
+# Pages in one compute block of the walk, by path. Swept on chip (v5e, jax
+# 0.9.0, PR 26) at gpt2l_chat's geometry: table 128 wide, 20 heads of 64, page
+# 8, f32; us a call, the parent's one-page grid first. Decode, 24 slots (10 at
+# 200-600 positions, 14 idle): 814 -> 98 / 84 / 88 / 105 at 4 / 8 / 16 / 32
+# pages; all 24 at 1023: 3031 -> 392 / 372 at 8 / 16. A 32-row chunk ending at
+# position 223: 187 -> 54 / 34 / 19 / 13 at 4 / 8 / 16 / 32; at 1023: 810 ->
+# 236 / 128 / 62 / 35 (its per-head MXU products cost the same whatever the
+# block holds, so the largest block the buffers' budget allows).
+_PAGED_PAGES_PER_BLOCK = 8  # decode: one query row a program
+_PAGED_PAGES_PER_BLOCK_CHUNK = 32  # chunked prefill: one shared table
+# what the walk's four page buffers (K and V, two blocks each) may take of the
+# ~16 MiB of VMEM a kernel is given: wide heads or large pages shrink the block
+_PAGED_BUFFER_BYTES = 8 * 1024 * 1024
+
+# Folded into serving/compile_cache.variant_key: an exported serving module
+# embeds the Mosaic kernels it was lowered with, so a cached export must miss
+# when a kernel's lowering changes. Bump it with every such change.
+KERNELS_REVISION = "26-paged-walk"
 
 
 def paged_flash_path_taken(n_q, n_pages, page_size, n_head, d_head):
@@ -1464,8 +1489,9 @@ def paged_flash_path_taken(n_q, n_pages, page_size, n_head, d_head):
     Pallas body in the decode hot loop is slower than the dense XLA gather
     on the CPU test mesh, and only when a page is a whole number of 8-row
     sublane tiles — the one geometry limit of the (page_size, n_head*d)
-    pool blocks (compile-checked for v5e: 2x16 up to 16x128 heads, pages of
-    8..128 rows, f32 and int8 pools)."""
+    page copies (compile-checked for v5e: 2x16 up to 16x128 heads, pages of
+    8..128 rows, f32 and int8 pools). More than 128 heads decline in every
+    mode: decode keeps its softmax carries a head a lane."""
     from .. import flags as _flags
 
     mode = _flags.get_flags("paged_flash")["paged_flash"]
@@ -1473,92 +1499,221 @@ def paged_flash_path_taken(n_q, n_pages, page_size, n_head, d_head):
         return False
     if min(int(n_q), int(n_pages), int(page_size), int(n_head), int(d_head)) < 1:
         return False
+    if int(n_head) > _LANES:  # decode keeps its softmax carries a head a lane
+        return False
     if mode == "on":
         return True
     return jax.default_backend() == "tpu" and int(page_size) % 8 == 0
 
 
-def _paged_flash_kernel(*refs, page_size, n_head, sm_scale, per_row, quant):
-    """One K/V page against every query row the program owns, heads walked
-    in the kernel over lane slices of the whole-feature (rows, n_head*d)
-    blocks. per_row (decode): grid (slot, page), the slot's one query row,
-    its own block-table row, pos a scalar out of the prefetched pos vector.
-    Shared (chunked prefill): grid (page,), every row of the chunk against
-    the one shared table; per-row positions arrive as a (rows, 1) VMEM
-    block and the scalar-prefetched max of them gates whole pages. The
-    block tables ride in as scalar-prefetch operands so the K/V BlockSpec
-    index_map can chase pages. quant: K/V pages are int8 levels with a
-    (page_size, 1) f32 per-row scale block chasing the same table entry —
-    dequantized here, so f32 rows of an int8 pool exist only in VMEM. The
-    online-softmax carries (m, l per head, lane-broadcast like the flash
-    kernels above; acc whole-feature) live in VMEM scratch."""
+def _paged_block_pages(n_pages, page_bytes, shared):
+    """Pages in a compute block: the module constant of the path, or the
+    whole table where that is narrower, or what of it the two K and two V
+    buffers' VMEM budget holds (page_bytes: one pool page, page_size *
+    n_head*d * itemsize)."""
+    most = _PAGED_PAGES_PER_BLOCK_CHUNK if shared else _PAGED_PAGES_PER_BLOCK
+    fits = _PAGED_BUFFER_BYTES // (4 * int(page_bytes))
+    return max(1, min(most, int(n_pages), fits))
+
+
+def paged_positions_walked(pos, page_size, n_pages, row_bytes, shared=False):
+    """EXACT mirror of the kernel's trip count, in positions: what one
+    paged_flash_attention call walks for these positions over a table
+    n_pages wide whose pool rows take row_bytes (n_head*d * itemsize).
+    Each row (decode), or the one shared table at max(pos) (chunked
+    prefill, shared=True), takes pos // block + 1 blocks, none for
+    pos < 0, at most the table's worth."""
+    block = int(page_size) * _paged_block_pages(
+        n_pages, page_size * row_bytes, shared
+    )
+    pos = np.asarray(pos, dtype=np.int64).reshape(-1)
+    if shared:
+        pos = pos.max(keepdims=True)
+    most = -(-int(n_pages) * int(page_size) // block)
+    return int(np.clip(pos // block + 1, 0, most).sum()) * block
+
+
+def _paged_flash_kernel(*refs, page_size, n_head, sm_scale, per_row, quant,
+                        n_pages, block_pages):
+    """Every query row the program owns against the pages its table holds up
+    to pos, a compute block of block_pages pages a loop trip. per_row
+    (decode): grid (slot,), the slot's one query row, its own block-table
+    row, pos a scalar out of the prefetched pos vector. Shared (chunked
+    prefill): one program, every row of the chunk against the one shared
+    table; per-row positions arrive as a (rows, 1) VMEM block and the
+    scalar-prefetched max of them bounds the walk. The block tables ride in
+    as scalar-prefetch operands; the pools stay in HBM and the kernel copies
+    a block's pages into one of two VMEM buffers, block b + 1's copies in
+    flight while block b is computed. A page wholly past pos is not copied
+    (so neither is one past a table that is no whole number of blocks): the
+    mask by pos drops what its buffer rows still hold, which the zeroing at
+    the first program keeps finite. quant: K/V pages are int8 levels and a
+    (1, block) row of f32 per-position scales comes with each block; they
+    scale the scores and the probabilities, so no dequantized row exists
+    anywhere. The online-softmax carries live in VMEM scratch.
+
+    The block's products come in two forms, by what the program owns. One
+    query row (decode) has nothing to feed the MXU per head, so q * K and
+    p * V are whole-feature VPU products, positions on the sublanes, and
+    two constant head-indicator matrices on the MXU sum q * K over each
+    head's lanes and spread p back over them (m, l: (1, 128), a head a
+    lane). A chunk of rows walks the heads over lane slices with two MXU
+    products each (m, l per head, lane-broadcast like the flash kernels
+    above). acc is whole-feature in both."""
     bt_ref, sp_ref = refs[:2]
     refs = refs[2:]
     if per_row:
-        pi, n_pi = pl.program_id(1), pl.num_programs(1)
-        pos = last = sp_ref[pl.program_id(0)]
+        row = pl.program_id(0)
+        pos = last = sp_ref[row]
+        page_of = lambda j: bt_ref[row, j]
     else:
         posv_ref, refs = refs[0], refs[1:]
-        pi, n_pi = pl.program_id(0), pl.num_programs(0)
         pos = posv_ref[...]  # (rows, 1)
         last = sp_ref[0]
-    q_ref, k_ref, v_ref = refs[:3]
-    refs = refs[3:]
+        page_of = lambda j: bt_ref[j]
+    q_ref, refs = refs[0], refs[1:]
     if quant:
-        ks_ref, vs_ref = refs[:2]
-        refs = refs[2:]
-    o_ref, acc_ref, m_ref, l_ref = refs
-    rows = q_ref.shape[0]
-    d = q_ref.shape[1] // n_head
+        ks_ref, vs_ref, refs = refs[0], refs[1], refs[2:]
+    pools, o_ref, bufs = refs[:2], refs[2], refs[3:5]  # K, V
+    sem, acc_ref, m_ref, l_ref = refs[5:9]
+    if per_row:
+        sum_ref, spread_ref = refs[9:]  # (feat, 128), (128, feat)
+    rows, feat = q_ref.shape
+    d = feat // n_head
+    block = block_pages * page_size
+    n_blocks = jnp.clip(last // block + 1, 0, pl.cdiv(n_pages, block_pages))
 
-    @pl.when(pi == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def head_lanes(shape, head_axis):
+        # 1.0 where the lane (the other axis) belongs to the head
+        lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - head_axis)
+        first = jax.lax.broadcasted_iota(jnp.int32, shape, head_axis) * d
+        return ((lane >= first) & (lane < first + d)).astype(jnp.float32)
 
-    @pl.when(pi * page_size <= last)  # pages wholly past pos: skip the MXU
-    def _page():
-        offs = pi * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, page_size), 1
+    @pl.when(pl.program_id(0) == 0)
+    def _once():
+        for buf in bufs:
+            buf[...] = jnp.zeros_like(buf)
+        if per_row:
+            sum_ref[...] = head_lanes(sum_ref.shape, 1)
+            spread_ref[...] = head_lanes(spread_ref.shape, 0)
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+    def page_copies(b, slot, start):
+        # the block's pages that hold a position up to pos, and are in the table
+        first = b * block_pages
+        n_live = jnp.clip(
+            jnp.minimum(last // page_size + 1, n_pages) - first, 0, block_pages
         )
-        live = offs <= pos
+
+        def _page(i, _):
+            row0 = pl.multiple_of(page_of(first + i) * page_size, page_size)
+            for n in range(2):
+                copy = pltpu.make_async_copy(
+                    pools[n].at[pl.ds(row0, page_size)],
+                    bufs[n].at[slot, i], sem.at[n, slot],
+                )
+                copy.start() if start else copy.wait()
+
+        jax.lax.fori_loop(0, n_live, _page, None)
+
+    def dot(x, y, contract=((1,), (0,))):
+        return jax.lax.dot_general(
+            x, y, (contract, ((), ())), preferred_element_type=jnp.float32
+        )
+
+    def softmax_step(s, live, m_prev, l_prev, axis):
+        s = jnp.where(live, s, -jnp.inf)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=axis, keepdims=True))
+        # alpha rescales the old accumulator; exp(-inf - -inf) = nan, so
+        # pin never-seen rows (m_prev = -inf) to alpha = 0 explicitly
+        alpha = jnp.exp(
+            jnp.where(m_prev == -jnp.inf, -jnp.inf, m_prev - m_new)
+        )
+        # the where kills the -inf - -inf nan on dead rows too
+        p = jnp.where(live, jnp.exp(s - m_new), 0.0)
+        l_new = l_prev * alpha + jnp.sum(p, axis=axis, keepdims=True)
+        return p, alpha, m_new, l_new
+
+    def column(scale_row):
+        # (1, block) -> (block, 1) with no transpose: the diagonal's lane sum
+        eye = (
+            jax.lax.broadcasted_iota(jnp.int32, (block, block), 0)
+            == jax.lax.broadcasted_iota(jnp.int32, (block, block), 1)
+        )
+        return jnp.sum(jnp.where(eye, scale_row, 0.0), axis=1, keepdims=True)
+
+    def one_row_block(b, slot):
+        k = bufs[0][slot].astype(jnp.float32).reshape(block, feat)
+        v = bufs[1][slot].astype(jnp.float32).reshape(block, feat)
+        s = dot(k * q_ref[...].astype(jnp.float32), sum_ref[...]) * sm_scale
+        if quant:
+            s = s * column(ks_ref[pl.ds(b, 1), :])
+        live = b * block + jax.lax.broadcasted_iota(
+            jnp.int32, (block, _LANES), 0
+        ) <= pos
+        p, alpha, m_ref[...], l_ref[...] = softmax_step(
+            s, live, m_ref[...], l_ref[...], 0
+        )
+        if quant:
+            p = p * column(vs_ref[pl.ds(b, 1), :])
+        spread = dot(
+            jnp.concatenate([p, jnp.broadcast_to(alpha, (8, _LANES))]),
+            spread_ref[...],
+        )  # (block + 8, feat)
+        acc_ref[...] = acc_ref[...] * spread[block:block + 1] + jnp.sum(
+            spread[:block] * v, axis=0, keepdims=True
+        )
+
+    def chunk_block(b, slot):
+        live = b * block + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, block), 1
+        ) <= pos
+        if quant:  # (1, block): this block's per-position scales
+            k_scale = ks_ref[pl.ds(b, 1), :] * sm_scale
+            v_scale = vs_ref[pl.ds(b, 1), :]
         for h in range(n_head):
             sl = slice(h * d, (h + 1) * d)
-            q2 = q_ref[:, sl]  # (rows, d)
-            k2 = k_ref[:, sl]  # (page_size, d)
-            v2 = v_ref[:, sl]
-            if quant:
-                q2 = q2.astype(jnp.float32)
-                k2 = k2.astype(jnp.float32) * ks_ref[...]
-                v2 = v2.astype(jnp.float32) * vs_ref[...]
-            s = jax.lax.dot_general(
-                q2, k2, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * sm_scale  # (rows, page_size)
-            s = jnp.where(live, s, -jnp.inf)
-            m_prev = m_ref[h][:, :1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            # alpha rescales the old accumulator; exp(-inf - -inf) = nan, so
-            # pin never-seen rows (m_prev = -inf) to alpha = 0 explicitly
-            alpha = jnp.exp(
-                jnp.where(m_prev == -jnp.inf, -jnp.inf, m_prev - m_new)
+            q2 = q_ref[:, sl].astype(jnp.float32)  # (rows, d)
+            k2 = bufs[0][slot, :, :, sl].astype(jnp.float32).reshape(block, d)
+            v2 = bufs[1][slot, :, :, sl].astype(jnp.float32).reshape(block, d)
+            s = dot(q2, k2, ((1,), (1,))) * (k_scale if quant else sm_scale)
+            p, alpha, m_new, l_new = softmax_step(
+                s, live, m_ref[h][:, :1], l_ref[h][:, :1], 1
             )
-            # the where kills the -inf - -inf nan on dead rows too
-            p = jnp.where(live, jnp.exp(s - m_new), 0.0)
-            l_new = l_ref[h][:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            acc_ref[:, sl] = acc_ref[:, sl] * alpha + jax.lax.dot_general(
-                p, v2, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
+            acc_ref[:, sl] = acc_ref[:, sl] * alpha + dot(
+                p * v_scale if quant else p, v2
             )
             m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
             l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
 
-    @pl.when(pi == n_pi - 1)
-    def _emit():
-        # safe softmax tail: a fully-masked row (pos < 0, nothing live) has
-        # l = 0 and emits zeros instead of 0/0 nan — the where-mask contract
-        # the dense reference shares
+    @pl.when(n_blocks > 0)
+    def _first():
+        page_copies(0, 0, True)
+
+    def _block(b, _):
+        slot = b % 2
+
+        @pl.when(b + 1 < n_blocks)
+        def _next():
+            page_copies(b + 1, 1 - slot, True)
+
+        page_copies(b, slot, False)
+        (one_row_block if per_row else chunk_block)(b, slot)
+
+    jax.lax.fori_loop(0, n_blocks, _block, None)
+
+    # safe softmax tail: a fully-masked row (pos < 0, nothing live) has
+    # l = 0 and emits zeros instead of 0/0 nan — the where-mask contract
+    # the dense reference shares
+    if per_row:
+        l = dot(jnp.broadcast_to(l_ref[...], (8, _LANES)), spread_ref[...])[:1]
+        o_ref[...] = (
+            acc_ref[...] / jnp.where(l > 0.0, l, 1.0)
+        ).astype(o_ref.dtype)
+    else:
         for h in range(n_head):
             sl = slice(h * d, (h + 1) * d)
             l = l_ref[h][:, :1]
@@ -1577,17 +1732,18 @@ def paged_flash_attention(q, k_pool, v_pool, block_table, pos, *, n_head,
     inclusive; pos < 0 means fully masked and emits zeros). Returns
     [rows, n_head*d] in q's dtype with f32 accumulation — bit-bounded, not
     bit-identical, vs the dense reference (the online softmax reassociates
-    the sum).
+    the sum). The work follows pos, not the table's width:
+    paged_positions_walked mirrors it.
 
     The pools are read in place as [pool_rows, n_head*d]: one page is one
-    (page_size, n_head*d) block, legal for Mosaic whenever page_size is a
+    (page_size, n_head*d) copy, legal for Mosaic whenever page_size is a
     multiple of 8, with no relayout of the pool around the call.
 
     k_scales/v_scales (both or neither): the pools hold int8 levels and
-    [pool_rows] f32 per-row scales ride along; the kernel dequantizes
-    inline on the block-table walk (each page's scale column chases the
-    same table entry as its K/V rows), so dequantized f32 rows exist only
-    in VMEM."""
+    [pool_rows] f32 per-row scales ride along, gathered through the table
+    ahead of the call; the kernel scales the scores and the probabilities
+    by them on the block-table walk, so no dequantized f32 row exists
+    anywhere."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     rows, feat = q.shape
@@ -1596,46 +1752,72 @@ def paged_flash_attention(q, k_pool, v_pool, block_table, pos, *, n_head,
     pos_v = pos.reshape(-1).astype(jnp.int32)
     quant = k_scales is not None
     per_row = bt.ndim == 2
+    n_pages = bt.shape[-1]
+    block_pages = _paged_block_pages(
+        n_pages, page_size * feat * k_pool.dtype.itemsize, not per_row
+    )
     _note_dispatch("paged_flash_int8" if quant else "paged_flash")
-    # a dead page (wholly past pos) re-names the last live one: the block
-    # index does not change, so the pipeline fetches nothing for it
     if per_row:
-        grid = (rows, bt.shape[1])
-        q_spec = pl.BlockSpec((None, 1, feat), lambda s, p, bt_r, pos_r: (s, 0, 0))
-        page_map = lambda s, p, bt_r, pos_r: (
-            bt_r[s, jnp.minimum(p, jnp.maximum(pos_r[s], 0) // page_size)], 0
-        )
+        grid = (rows,)
+        q_spec = pl.BlockSpec((None, 1, feat), lambda s, bt_r, pos_r: (s, 0, 0))
         prefetch = [bt, pos_v]
         # (rows, 1, feat): a (1, feat) block per slot is legal only as the
         # whole of the last two dimensions
         operands, in_specs = [q.reshape(rows, 1, feat)], [q_spec]
         out_shape = jax.ShapeDtypeStruct((rows, 1, feat), q.dtype)
-        r = 1
+        carries = [
+            pltpu.VMEM((1, feat), jnp.float32),  # acc
+            pltpu.VMEM((1, _LANES), jnp.float32),  # m, a head a lane
+            pltpu.VMEM((1, _LANES), jnp.float32),  # l
+            pltpu.VMEM((feat, _LANES), jnp.float32),  # lanes -> head sums
+            pltpu.VMEM((_LANES, feat), jnp.float32),  # head -> its lanes
+        ]
     else:
-        grid = (bt.shape[0],)
-        whole = lambda p, bt_r, last_r: (0, 0)
+        grid = (1,)
+        whole = lambda i, bt_r, last_r: (0, 0)
         q_spec = pl.BlockSpec((rows, feat), whole)
-        page_map = lambda p, bt_r, last_r: (
-            bt_r[jnp.minimum(p, jnp.maximum(last_r[0], 0) // page_size)], 0
-        )
         prefetch = [bt, jnp.max(pos_v).reshape(1)]
         operands = [pos_v.reshape(rows, 1), q]
         in_specs = [pl.BlockSpec((rows, 1), whole), q_spec]
         out_shape = jax.ShapeDtypeStruct((rows, feat), q.dtype)
-        r = rows
-    operands += [k_pool, v_pool]
-    in_specs += [pl.BlockSpec((page_size, feat), page_map)] * 2
-    if quant:
-        operands += [
-            k_scales.reshape(-1, 1).astype(jnp.float32),
-            v_scales.reshape(-1, 1).astype(jnp.float32),
+        carries = [
+            pltpu.VMEM((rows, feat), jnp.float32),  # acc
+            pltpu.VMEM((n_head, rows, _LANES), jnp.float32),  # m
+            pltpu.VMEM((n_head, rows, _LANES), jnp.float32),  # l
         ]
-        in_specs += [pl.BlockSpec((page_size, 1), page_map)] * 2
+    if quant:
+        # Mosaic copies no (page_size, 1) slice out of a scale pool (PR 26:
+        # "slice shape must be aligned to tiling (128)"), so the scales
+        # alone are gathered through the table out here, a page a row
+        # (1/feat of the K and V bytes; on chip 17 us of the call's 89),
+        # and reach the kernel as one (1, block) row a block, the table
+        # padded to whole blocks with its last page
+        n_blocks = pl.cdiv(n_pages, block_pages)
+        pages = bt[..., jnp.minimum(
+            jnp.arange(n_blocks * block_pages), n_pages - 1
+        )]
+        shape = bt.shape[:-1] + (n_blocks, block_pages * page_size)
+        operands += [
+            jnp.take(
+                scales.astype(jnp.float32).reshape(-1, page_size), pages,
+                axis=0,
+            ).reshape(shape)
+            for scales in (k_scales, v_scales)
+        ]
+        if per_row:
+            in_specs += [pl.BlockSpec(
+                (None,) + shape[1:], lambda s, bt_r, pos_r: (s, 0, 0)
+            )] * 2
+        else:
+            in_specs += [pl.BlockSpec(shape, whole)] * 2
+    operands += [k_pool, v_pool]
+    in_specs += [pl.BlockSpec(memory_space=pltpu.HBM)] * 2
     out = pl.pallas_call(
         functools.partial(
             _paged_flash_kernel, page_size=page_size, n_head=n_head,
             sm_scale=float(sm_scale or 0.0) or d**-0.5,
-            per_row=per_row, quant=quant,
+            per_row=per_row, quant=quant, n_pages=n_pages,
+            block_pages=block_pages,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
@@ -1643,10 +1825,10 @@ def paged_flash_attention(q, k_pool, v_pool, block_table, pos, *, n_head,
             in_specs=in_specs,
             out_specs=q_spec,
             scratch_shapes=[
-                pltpu.VMEM((r, feat), jnp.float32),
-                pltpu.VMEM((n_head, r, _LANES), jnp.float32),
-                pltpu.VMEM((n_head, r, _LANES), jnp.float32),
-            ],
+                pltpu.VMEM((2, block_pages, page_size, feat), k_pool.dtype),
+                pltpu.VMEM((2, block_pages, page_size, feat), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),  # [K | V, buffer]
+            ] + carries,
         ),
         out_shape=out_shape,
         interpret=interpret,

@@ -7,9 +7,10 @@ true:
 1. **Artifact cache** (this module's CompileCache, under `cache_dir` /
    FLAGS_serving_cache_dir): serialized `jax.export` artifacts keyed by
    (program fingerprint, feed avals, fetch names, jax/jaxlib version,
-   backend platform). A hit skips the Python-side program lowering and
-   StableHLO trace entirely — the replica deserializes and calls. The keys
-   hold no path, so the directory may move.
+   backend platform, the Pallas kernels' revision). A hit skips the
+   Python-side program lowering and StableHLO trace entirely — the replica
+   deserializes and calls. The keys hold no path, so the directory may
+   move.
 2. **XLA executable cache**: JAX's own persistent compilation cache, in the
    one process-wide directory the environment places
    (platform_setup.place_compile_cache), so the StableHLO→executable
@@ -74,7 +75,14 @@ def variant_key(fingerprint, feed_avals, fetch_names, state_avals=None,
     same-aval arrays, so every cached variant (and the in-process compiled
     set) stays valid across an online-learning swap; only a geometry/program
     change misses.
+
+    `ops.pallas_kernels.KERNELS_REVISION` is folded in because none of the
+    above sees a lowering: an exported module embeds the Mosaic kernels it
+    was lowered with, so without it a cache filled before a kernel changed
+    would replay the old kernel after the upgrade.
     """
+    from ..ops import pallas_kernels
+
     jax_v, jaxlib_v, platform = _versions()
     doc = {
         "fingerprint": fingerprint,
@@ -85,6 +93,7 @@ def variant_key(fingerprint, feed_avals, fetch_names, state_avals=None,
         "jax": jax_v,
         "jaxlib": jaxlib_v,
         "platform": platform,
+        "kernels": pallas_kernels.KERNELS_REVISION,
     }
     if state_avals:
         doc["state"] = sorted(

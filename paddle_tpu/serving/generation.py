@@ -63,6 +63,7 @@ from ..executor import Scope, aot_serve_lowering, scope_guard
 from ..observability import tracing as _tracing
 from ..profiler import RecordEvent
 from ..observability.tracing import NULL_SPAN
+from ..ops import pallas_kernels as _pk
 from .batcher import (
     ContinuousBatcher,
     QueueFullError,
@@ -215,7 +216,9 @@ class _Sum:
 
 
 # gen_ctx_tokens: positions one decode step attends, summed over its runs
-# (a step of 24 slots at full 1024-position context reads 24576)
+# (a step of 24 slots at full 1024-position context reads 24576);
+# gen_walked_tokens: positions the paged kernel walks for them, idle slots'
+# too (whole compute blocks: at most as many as the tables hold)
 CTX_TOKEN_BUCKETS = (
     16, 32, 64, 128, 256, 512, 1024, 2048, 4096, 8192, 16384, 32768, 65536,
     131072, 262144, 524288, 1048576,
@@ -285,6 +288,7 @@ class GenerationEngine:
         # and each gains a [pool_rows] f32 per-row scale pool sibling
         # (model.kv_scale_names) — ~1/4 the f32 bytes per cached token
         self.kv_dtype = getattr(model, "kv_dtype", "float32")
+        self._kv_row_bytes = model.d_model * np.dtype(self.kv_dtype).itemsize
         self._state = {}
         for pair in model.kv_pool_names():
             for n in pair:
@@ -381,6 +385,13 @@ class GenerationEngine:
         self._m_ctx_tokens = reg.histogram(
             p + "/gen_ctx_tokens",
             "positions one decode step has to attend, summed over its runs",
+            buckets=CTX_TOKEN_BUCKETS,
+        )
+        self._m_walked_tokens = reg.histogram(
+            p + "/gen_walked_tokens",
+            "positions the paged kernel walks in one decode step, summed "
+            "over all max_slots rows (ops.pallas_kernels."
+            "paged_positions_walked)",
             buckets=CTX_TOKEN_BUCKETS,
         )
         # whoever drives the engine step by step (the scheduler) writes its
@@ -771,6 +782,10 @@ class GenerationEngine:
                     tokens[run.slot, 0] = run.tokens[-1]
                     positions[run.slot, 0] = run.next_pos
                     ctx_tokens += run.next_pos
+                walked_tokens = _pk.paged_positions_walked(
+                    positions, self.page_size, self.max_pages,
+                    self._kv_row_bytes,
+                )
                 variant = self._variant("decode")
                 feeds, mut_in = self._feeds(variant, feeds)
             with self._phase("dispatch", "decode") as dispatch:
@@ -788,6 +803,7 @@ class GenerationEngine:
         ).end()
         self._m_step_ms.observe(step_ms)
         self._m_ctx_tokens.observe(ctx_tokens)
+        self._m_walked_tokens.observe(walked_tokens)
         self._m_steps.inc()
         with self._phase("sample", "decode"):
             for run in runs:
@@ -835,8 +851,6 @@ class GenerationEngine:
         return int(rng.choice(z.size, p=p))
 
     def _set_pool_gauges(self):
-        from ..ops import pallas_kernels as _pk
-
         st = self.pool.stats()
         self._m_slots.set(st["slots_in_use"])
         self._m_occ.set(st["slot_occupancy"])
@@ -867,8 +881,6 @@ class GenerationEngine:
         return {k: v.fn.as_text() for k, v in sorted(self._variants.items())}
 
     def stats(self):
-        from ..ops import pallas_kernels as _pk
-
         out = {
             "variants": len(self._variants),
             "traces": self.traces,
@@ -879,8 +891,9 @@ class GenerationEngine:
             "prefill_chunk": self.prefill_chunk,
             "prefill_chunks": self._m_chunks.value(),
             "geometry": self.geometry(),
-            # what the paged kernel walks each decode step, whatever the
-            # contexts: beside gen_ctx_tokens, the positions it has to
+            # the most the paged kernel can walk in a decode step (every
+            # slot at full context); gen_walked_tokens has what it walks
+            # and gen_ctx_tokens the positions it has to attend
             "positions_walked_per_step": (
                 self.max_slots * self.max_pages * self.page_size
             ),

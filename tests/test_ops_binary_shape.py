@@ -6,6 +6,8 @@ test_reduce_op.py, test_reshape_op.py etc. — numpy reference + grad check
 where the op is differentiable.
 """
 
+import zlib
+
 import numpy as np
 
 from op_test import OpTest
@@ -159,7 +161,10 @@ class TestLogicalNotOp(OpTest):
 def _make_reduce(op, ref, grad, gen=None):
     class _Case(OpTest):
         def setUp(self):
-            rng = np.random.RandomState(hash(op) % (2**31))
+            # crc32, not hash(): str hashes change with the process, and a
+            # seed that does leaves the selection ops' finite differences
+            # to land on a near-tie one run in some (PR 26: 0.0325 > 0.03)
+            rng = np.random.RandomState(zlib.crc32(op.encode()))
             x = gen(rng) if gen else _b(rng, (3, 4, 5))
             self.op_type = op
             self.inputs = {"X": x}

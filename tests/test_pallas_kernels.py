@@ -356,3 +356,96 @@ def test_paged_flash_shared_table_matches_dense():
     )
     ref = _paged_dense_ref(q, kp, vp, bt1, pos, n_head, ps)
     np.testing.assert_allclose(np.asarray(out), ref, rtol=_RTOL, atol=_ATOL)
+
+
+# The walk's own cases: a compute block is 2 pages of 4 rows here (the module
+# constants are set to 2), so positions sit on both sides of a block's edge.
+# name -> (positions, table width in pages, shared table?)
+_WALK_CASES = {
+    "idle slots on the scratch page": ([0, 0, 5], 6, False),
+    "block - 1, block, block + 1": ([7, 8, 9], 6, False),
+    "full context": ([23, 23, 0], 6, False),
+    "fully masked rows emit zeros": ([-1, 12, -1], 6, False),
+    "all rows masked": ([-1, -1, -1], 6, False),
+    "table no whole number of blocks": ([19, 4, 16], 5, False),
+    "table narrower than a block": ([3, 0, 2], 1, False),
+    "shared and non-contiguous pages": ([21, 13, 17], 6, False),
+    "shared table, rows on both sides of a block": ([5, 6, 7, 8, 9], 6, True),
+    "shared table, full context": ([20, 21, 22, 23], 6, True),
+    "shared table, odd width": ([15, 16, 17, 18, 19], 5, True),
+    "shared table, all rows masked": ([-1, -1], 6, True),
+}
+
+
+def _int8_rows(x):
+    """Symmetric per-row int8 levels and f32 scales, as kv_cache_write."""
+    from paddle_tpu.ops.generation_ops import KV_QUANT_LEVELS as top
+
+    scale = np.maximum(np.abs(x).max(axis=1), 1e-8) / top
+    levels = np.clip(np.round(x / scale[:, None]), -top, top).astype("int8")
+    return levels, scale.astype("float32")
+
+
+@pytest.mark.parametrize("pool", ["f32", "int8"])
+@pytest.mark.parametrize("case", sorted(_WALK_CASES))
+def test_paged_flash_walk_matches_dense(case, pool, monkeypatch):
+    """The walk bounded by pos over multi-page blocks, against the dense
+    gather: every case on the f32 and the int8 pool."""
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setattr(pk, "_PAGED_PAGES_PER_BLOCK", 2)
+    monkeypatch.setattr(pk, "_PAGED_PAGES_PER_BLOCK_CHUNK", 2)
+    pos, width, shared = _WALK_CASES[case]
+    pos = np.array(pos, dtype=np.int32)
+    rng = np.random.RandomState(len(case))
+    n_head, d, ps = 2, 8, 4
+    q, kp, vp, bt = _paged_case(rng, len(pos), 16, width, n_head, d, ps)
+    if case == "idle slots on the scratch page":
+        bt[:2] = 0
+    if case == "shared and non-contiguous pages":
+        bt[1, :3] = bt[0, :3]  # a shared prefix
+        bt[2] = np.sort(bt[2])[::-1]
+    if shared:
+        bt = bt[0]
+    scales = {}
+    if pool == "int8":
+        kp, ks = _int8_rows(kp)
+        vp, vs = _int8_rows(vp)
+        scales = dict(k_scales=jnp.asarray(ks), v_scales=jnp.asarray(vs))
+    out = np.asarray(pk.paged_flash_attention(
+        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(bt),
+        jnp.asarray(pos), n_head=n_head, page_size=ps, interpret=True,
+        **scales,
+    ))
+    row_bytes = n_head * d * kp.dtype.itemsize
+    if pool == "int8":  # the reference reads the dequantized rows
+        kp = kp.astype("float32") * ks[:, None]
+        vp = vp.astype("float32") * vs[:, None]
+    ref = _paged_dense_ref(q, kp, vp, bt, pos, n_head, ps)
+    np.testing.assert_allclose(out, ref, rtol=_RTOL, atol=_ATOL)
+    assert np.abs(out[pos < 0]).max(initial=0.0) == 0.0
+    # the counter mirrors the trips: blocks of 8 positions, capped at the table
+    block = min(2, width) * ps
+    trips = [0 if p < 0 else min(p // block + 1, -(-width * ps // block))
+             for p in ([pos.max()] if shared else pos)]
+    assert pk.paged_positions_walked(
+        pos, ps, width, row_bytes, shared=shared
+    ) == sum(trips) * block
+
+
+def test_paged_block_follows_the_buffers_budget():
+    """Wide rows or large pages shrink the block to what the four page
+    buffers may take of VMEM; a narrow table bounds it too."""
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    cell = 8 * 1280 * 4  # gpt2-large: page 8, 20 heads of 64, f32
+    assert pk._paged_block_pages(128, cell, False) == pk._PAGED_PAGES_PER_BLOCK
+    assert pk._paged_block_pages(128, cell, True) == (
+        pk._PAGED_PAGES_PER_BLOCK_CHUNK)
+    assert pk._paged_block_pages(3, cell, False) == 3
+    assert pk._paged_block_pages(8, 128 * 2048 * 4, True) == 2
+    assert pk._paged_block_pages(8, 1 << 30, True) == 1
+    # what the engine reports as the most a step walks is the kernel's cap
+    assert pk.paged_positions_walked(
+        [1023] * 24, 8, 128, 1280 * 4) == 24 * 128 * 8
+    assert pk.paged_positions_walked([0] * 24, 8, 128, 1280 * 4) == 24 * 64

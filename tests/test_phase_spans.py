@@ -169,6 +169,62 @@ def test_ctx_tokens_counts_the_positions_of_a_hand_built_step():
     assert eng.stats()["positions_walked_per_step"] == 3 * 8 * 4
 
 
+def test_walked_tokens_counts_the_kernel_s_trips_on_a_hand_built_step(
+        monkeypatch):
+    """gen_walked_tokens is the kernel's trip count in positions, over all
+    three slots: a block is one 4-row page here, so the runs at 5 and 9
+    take 2 and 3 trips and the idle slot, at position 0, one."""
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setattr(pk, "_PAGED_PAGES_PER_BLOCK", 1)
+    eng = make_engine("walkgen")
+    runs = [eng.start(GenRequest(list(range(1, n + 1)), max_new_tokens=4,
+                                 eos_id=NO_EOS)) for n in (5, 9)]
+    walked = []
+    try:
+        for step in (runs, runs, runs[:1]):
+            eng.decode_step(step)
+            # the mirror on the very positions the compiled step was fed
+            fed = eng._dec_feeds["dec_positions"]
+            assert fed.shape == (3, 1)
+            walked.append(pk.paged_positions_walked(fed, 4, 8, 16 * 4))
+    finally:
+        for r in runs:
+            eng.finish(r)
+    # (5, 9, idle), (6, 10, idle), then 7 with the second slot's row left
+    # at 10: the kernel walks every row it is given
+    assert walked == [(2 + 3 + 1) * 4] * 3
+    snap = registry.default_registry().snapshot()
+    h = snap["serving/walkgen/gen_walked_tokens"]
+    assert (h["count"], h["sum"]) == (3, sum(walked))
+    assert h["buckets"] == snap["serving/walkgen/gen_ctx_tokens"]["buckets"]
+    # the most a step can walk keeps its name and its value
+    assert eng.stats()["positions_walked_per_step"] == 3 * 8 * 4
+    assert pk.paged_positions_walked([31] * 3, 4, 8, 16 * 4) == 3 * 8 * 4
+
+
+def test_variant_key_follows_the_kernels_revision_and_nothing_else(
+        monkeypatch):
+    """An exported serving module embeds the Mosaic kernels it was lowered
+    with: the cache key has to miss when their revision changes, and only
+    then."""
+    from paddle_tpu.ops import pallas_kernels as pk
+    from paddle_tpu.serving import compile_cache
+
+    def key():
+        return compile_cache.variant_key(
+            "fp", {"x": ((3, 1), "int32")}, ["logits"],
+            state_avals={"k": ((8, 16), "float32")}, geometry={"page_size": 4},
+        )
+
+    before = key()
+    assert key() == before
+    monkeypatch.setattr(pk, "KERNELS_REVISION", pk.KERNELS_REVISION + "+1")
+    assert key() != before
+    monkeypatch.undo()
+    assert key() == before
+
+
 def _toy_program():
     main, startup = fluid.Program(), fluid.Program()
     with fluid.program_guard(main, startup):

@@ -247,13 +247,22 @@ def _kv_f32_ref():
     return _KV_F32_REF[0]
 
 
-@pytest.mark.parametrize("paged_flash", ["off", "on"])
-def test_kv_int8_generation_drift_bounded(paged_flash, restore_flags):
+@pytest.mark.parametrize("paged_flash, block_pages", [
+    ("off", None), ("on", None),
+    ("on", 1),  # the 4-page table walked a page a block, decode and chunks
+])
+def test_kv_int8_generation_drift_bounded(paged_flash, block_pages,
+                                          restore_flags, monkeypatch):
     """int8-with-per-page-scales KV at 2x the slots in ~half the pool
     bytes: the last-step logits must track the fp32-pool engine within the
     quantization drift bound — on the dense reference AND with the paged
     flash kernel pinned on (inline dequant on the block-table walk)."""
+    from paddle_tpu.ops import pallas_kernels as pk
+
     e_f32 = _kv_f32_ref()
+    if block_pages:
+        monkeypatch.setattr(pk, "_PAGED_PAGES_PER_BLOCK", block_pages)
+        monkeypatch.setattr(pk, "_PAGED_PAGES_PER_BLOCK_CHUNK", block_pages)
     _, e_i8 = _kv_engines(paged_flash, with_f32=False)
     assert e_i8.pool.stats()["storage_dtype"] == "int8"
     assert e_i8.pool.stats()["resident_bytes"] < (

@@ -53,10 +53,11 @@ def _flash_grad(causal):
 
 
 _PAGED = dict(n_head=2, d=64, page=8, pages=4, pool_rows=72)
+# gpt2l_chat's own geometry: 24 slots x a 128-page table, 20 heads of 64
+_PAGED_CELL = dict(n_head=20, d=64, page=8, pages=128, pool_rows=24584)
 
 
-def _paged(quant, per_row, rows):
-    g = _PAGED
+def _paged(quant, per_row, rows, g=_PAGED):
     feat = g["n_head"] * g["d"]
 
     def fn(q, kp, vp, bt, pos, *scales):
@@ -144,6 +145,10 @@ FAMILIES = {
     "paged_flash shared 2 rows": _paged(False, False, 2),
     "paged_flash_int8 decode": _paged(True, True, 4),
     "paged_flash_int8 shared": _paged(True, False, 8),
+    "paged_flash decode gpt2l_chat": _paged(False, True, 24, _PAGED_CELL),
+    "paged_flash shared gpt2l_chat": _paged(False, False, 32, _PAGED_CELL),
+    "paged_flash_int8 decode gpt2l_chat": _paged(True, True, 24, _PAGED_CELL),
+    "paged_flash_int8 shared gpt2l_chat": _paged(True, False, 32, _PAGED_CELL),
 }
 
 # family -> the lowering's own message, for a family `auto` no longer selects
