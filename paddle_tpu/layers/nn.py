@@ -79,6 +79,12 @@ __all__ = [
     "beam_search_decode",
     "kv_cache_write",
     "paged_attention",
+    "rms_norm",
+    "rotary_embedding",
+    "swiglu",
+    "short_conv",
+    "moe_router",
+    "moe_experts",
 ]
 
 
@@ -1362,7 +1368,8 @@ def kv_cache_write(pool, rows, block_table, pos, page_size, scales=None,
 
 
 def paged_attention(q, k_pool, v_pool, block_table, pos, n_head, page_size,
-                    sm_scale=None, k_scales=None, v_scales=None, name=None):
+                    sm_scale=None, k_scales=None, v_scales=None, name=None,
+                    n_kv_head=None):
     """One-query-per-slot attention over a paged KV pool.
 
     ``q`` is ``[slots, n_head * d_head]`` (one decode token per slot),
@@ -1371,10 +1378,14 @@ def paged_attention(q, k_pool, v_pool, block_table, pos, n_head, page_size,
     its block table. Unused table entries point at the scratch page and are
     masked by the position bound. ``k_scales``/``v_scales`` (both or
     neither) read int8 pools: per-row f32 scales dequantize the gathered
-    levels inline (see ops/generation_ops.py int8 pool mode)."""
+    levels inline (see ops/generation_ops.py int8 pool mode). ``n_kv_head``
+    (grouped-query attention): the pools' rows are ``n_kv_head * d_head``
+    wide and query head ``i`` reads KV head ``i // (n_head // n_kv_head)``."""
     helper = LayerHelper("paged_attention", name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
     attrs = {"n_head": int(n_head), "page_size": int(page_size)}
+    if n_kv_head is not None and int(n_kv_head) != int(n_head):
+        attrs["n_kv_head"] = int(n_kv_head)
     if sm_scale is not None:
         attrs["sm_scale"] = float(sm_scale)
     inputs = {
@@ -1392,5 +1403,141 @@ def paged_attention(q, k_pool, v_pool, block_table, pos, n_head, page_size,
         inputs=inputs,
         outputs={"Out": [out.name]},
         attrs=attrs,
+    )
+    return out
+
+
+def rms_norm(x, epsilon=1e-5, groups=1, param_attr=None, name=None):
+    """Root-mean-square norm over the last axis with a learned scale, in
+    float32 whatever ``x``'s dtype. ``groups`` > 1 norms each of that many
+    equal slices of the last axis on its own with one ``[d / groups]``
+    scale: the per-head q/k norm over ``[rows, n_head * d_head]``."""
+    from ..initializer import Constant
+
+    helper = LayerHelper("rms_norm", name=name)
+    width = int(x.shape[-1]) // int(groups)
+    scale = helper.create_parameter(
+        attr=param_attr, shape=[width], dtype="float32",
+        default_initializer=Constant(1.0),
+    )
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op(
+        type="rms_norm",
+        inputs={"X": [x.name], "Scale": [scale.name]},
+        outputs={"Out": [out.name]},
+        attrs={"epsilon": float(epsilon), "groups": int(groups)},
+    )
+    return out
+
+
+def rotary_embedding(x, pos, n_head, theta=10000.0, name=None):
+    """Rotary position embedding (half-rotation form) of ``[rows, n_head *
+    d_head]`` at the rows' positions ``pos``."""
+    helper = LayerHelper("rotary_embedding", name=name)
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op(
+        type="rotary_embedding",
+        inputs={"X": [x.name], "Pos": [pos.name]},
+        outputs={"Out": [out.name]},
+        attrs={"n_head": int(n_head), "theta": float(theta)},
+    )
+    return out
+
+
+def swiglu(gate, up, name=None):
+    """``silu(gate) * up``, the gated feed-forward's activation."""
+    helper = LayerHelper("swiglu", name=name)
+    out = helper.create_variable_for_type_inference(gate.dtype)
+    helper.append_op(
+        type="swiglu",
+        inputs={"X": [gate.name], "Y": [up.name]},
+        outputs={"Out": [out.name]},
+    )
+    return out
+
+
+def short_conv(x, taps, param_attr=None, state=None, rows=None, start=None,
+               live=None, mode="chunk", seq_len=0, name=None):
+    """Gated short convolution between its projections: ``x`` is ``[rows, 3
+    * d]`` = ``[B, C, z]``; returns ``C * conv(B * z)`` with a depthwise
+    causal kernel of ``taps`` taps. ``state`` is the persistable
+    ``[state_rows, (taps - 1) * d]`` pool holding each sequence's last
+    ``taps - 1`` rows of ``B * z``, indexed by the row table ``rows`` (row 0
+    is scratch); the op writes it in place. ``mode`` "chunk": consecutive
+    positions of one sequence from ``start``, ``live`` marks the rows that
+    are not padding; "step": one position of a sequence a row. Without a
+    state the rows are whole sequences of ``seq_len`` positions."""
+    helper = LayerHelper("short_conv", name=name)
+    d = int(x.shape[-1]) // 3
+    kernel = helper.create_parameter(
+        attr=param_attr, shape=[d, int(taps)], dtype="float32")
+    out = helper.create_variable_for_type_inference(x.dtype)
+    inputs = {"X": [x.name], "Kernel": [kernel.name]}
+    outputs = {"Out": [out.name]}
+    if state is not None:
+        inputs["State"] = [state.name]
+        inputs["Rows"] = [rows.name]
+        outputs["StateOut"] = [state.name]
+        if mode == "chunk":
+            inputs["Start"] = [start.name]
+            inputs["Live"] = [live.name]
+    helper.append_op(
+        type="short_conv", inputs=inputs, outputs=outputs,
+        attrs={"mode": mode, "seq_len": int(seq_len)},
+    )
+    return out
+
+
+def moe_router(x, num_experts, k, live=None, use_bias=True, norm_topk=True,
+               scale=1.0, param_attr=None, bias_attr=None, name=None):
+    """Sigmoid top-k router in float32: returns ``(picks [rows, k] int32,
+    weights [rows, k] float32)``. The bias is added to the scores for the
+    choice only. Rows with ``live`` == 0 get the pick ``num_experts`` (no
+    expert) and weight 0."""
+    helper = LayerHelper("moe_router", name=name)
+    w = helper.create_parameter(
+        attr=param_attr, shape=[int(x.shape[-1]), int(num_experts)],
+        dtype="float32")
+    inputs = {"X": [x.name], "W": [w.name]}
+    if use_bias:
+        b = helper.create_parameter(
+            attr=bias_attr, shape=[int(num_experts)], dtype="float32",
+            is_bias=True)
+        inputs["Bias"] = [b.name]
+    if live is not None:
+        inputs["Live"] = [live.name]
+    picks = helper.create_variable_for_type_inference("int32")
+    weights = helper.create_variable_for_type_inference("float32")
+    helper.append_op(
+        type="moe_router", inputs=inputs,
+        outputs={"Picks": [picks.name], "Weights": [weights.name]},
+        attrs={"k": int(k), "norm_topk": bool(norm_topk),
+               "scale": float(scale)},
+    )
+    return picks, weights
+
+
+def moe_experts(x, picks, weights, num_experts, width, experts_held=None,
+                w1_attr=None, w3_attr=None, w2_attr=None, name=None):
+    """The held experts' part of the routed sum of gated feed-forwards
+    (``width`` wide): rows grouped by expert and one ragged product a
+    matrix. ``experts_held`` lists the global ids of the experts whose
+    weights this op owns (default: all ``num_experts``)."""
+    helper = LayerHelper("moe_experts", name=name)
+    held = [int(e) for e in (experts_held or [])]
+    n_held = len(held) or int(num_experts)
+    d = int(x.shape[-1])
+    dtype = x.dtype
+    w1 = helper.create_parameter(attr=w1_attr, shape=[n_held, d, int(width)], dtype=dtype)
+    w3 = helper.create_parameter(attr=w3_attr, shape=[n_held, d, int(width)], dtype=dtype)
+    w2 = helper.create_parameter(attr=w2_attr, shape=[n_held, int(width), d], dtype=dtype)
+    out = helper.create_variable_for_type_inference(dtype)
+    helper.append_op(
+        type="moe_experts",
+        inputs={"X": [x.name], "Picks": [picks.name],
+                "Weights": [weights.name], "W1": [w1.name], "W3": [w3.name],
+                "W2": [w2.name]},
+        outputs={"Out": [out.name]},
+        attrs={"num_experts": int(num_experts), "experts_held": held},
     )
     return out

@@ -2,7 +2,8 @@
 BASELINE.json (MNIST LeNet, ResNet-50, VGG, Transformer NMT, DeepFM CTR,
 stacked-LSTM LM), mirroring reference benchmark/fluid/models/."""
 
-from . import alexnet, googlenet, gpt_decoder, lenet, resnet, se_resnext, vgg
+from . import alexnet, block_decoder, googlenet, gpt_decoder, lenet, resnet, se_resnext, vgg
+from .block_decoder import BlockDecoder, lfm2_moe
 from .gpt_decoder import GPTDecoder
 from .lenet import lenet5
 from .resnet import resnet50, resnet_cifar10

@@ -16,4 +16,5 @@ from . import compose_ops  # noqa: F401 — registration side effects
 from . import frame_ops  # noqa: F401 — registration side effects
 from . import pallas_kernels  # noqa: F401 — registration side effects
 from . import generation_ops  # noqa: F401 — registration side effects
+from . import decoder_ops  # noqa: F401 — registration side effects
 from .registry import OPS, get, is_registered, register

@@ -196,7 +196,12 @@ def _matmul(ctx, ins, attrs):
 
         out = fp8_matmul(x, y)
     else:
-        out = jnp.matmul(x, y)
+        # out_dtype: the accumulator's dtype kept for the result (bf16
+        # operands, float32 logits) instead of one more rounding
+        out_dtype = attrs.get("out_dtype")
+        out = jnp.matmul(
+            x, y,
+            preferred_element_type=_dtype(out_dtype) if out_dtype else None)
     if alpha != 1.0:
         out = out * jnp.asarray(alpha, out.dtype)
     return {"Out": [out]}

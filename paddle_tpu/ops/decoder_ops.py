@@ -1,0 +1,263 @@
+"""Ops of the block-specified decoder (models/block_decoder.py): RMS norm,
+rotary position embedding, the gated feed-forward's activation, the gated
+short convolution with its fixed per-sequence state, and the mixture of
+experts (router, and a grouped expert product whose work follows the
+routing).
+
+Conventions:
+  * Activations and weights may be bfloat16; every op here computes its
+    reductions, its transcendental functions and its accumulations in
+    float32 and rounds once, to its input's dtype. The router's scores,
+    weights and the matrix that makes them are float32 throughout: a top-k
+    over bf16 scores picks other experts.
+  * ``short_conv`` keeps a sequence's state — the last ``L - 1`` rows of
+    ``v = B * z`` — in a persistable ``[state_rows, (L - 1) * d]`` pool that
+    a fed row table indexes, as block tables index KV pages. Row 0 is a
+    scratch row: idle and mid-prefill slots of a decode step write there
+    and never to a live state. The op's ``StateOut`` IS the pool variable
+    (the ``kv_cache_write`` idiom), so the serving lowering classifies it as
+    written state and donates it.
+  * ``moe_router`` routes over all ``num_experts``; rows that carry no live
+    token (``Live`` == 0) get the pick ``num_experts`` — no expert — and
+    weight 0. ``moe_experts`` computes the part of ``sum_i g_i FF_i(u)``
+    that the experts it holds give (``experts_held``, default all): rows
+    are sorted by expert and ``jax.lax.ragged_dot`` multiplies each group by
+    its own expert's matrices, so the FLOPs follow the routing and not
+    ``num_experts``.
+  * Every op has a shape rule of its own: deriving shapes from the lowering
+    (registry.infer_shape) traces it once a layer a variant (PERF.md, PR 26).
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from .registry import register
+
+__all__ = []
+
+_F32 = jnp.float32
+
+
+def _var(block, op, slot, outputs=False):
+    names = (op.outputs if outputs else op.inputs).get(slot) or [None]
+    return None if names[0] is None else block._var_recursive(names[0])
+
+
+def _like(src_slot, out_slots=("Out",)):
+    """Shape rule: every slot of out_slots is shaped and typed like the
+    input src_slot."""
+
+    def rule(op, block):
+        src = _var(block, op, src_slot)
+        for slot in out_slots:
+            out = _var(block, op, slot, outputs=True)
+            if out is not None:
+                out.shape, out.dtype = src.shape, src.dtype
+
+    return rule
+
+
+# ---------------------------------------------------------------------------
+# norms, positions, activation
+# ---------------------------------------------------------------------------
+
+
+@register("rms_norm", no_grad=True, infer_shape=_like("X"))
+def _rms_norm(ctx, ins, attrs):
+    """x * rsqrt(mean(x^2, -1) + eps) * w in f32. groups > 1 norms each of
+    `groups` equal slices of the last axis on its own with the one [d] scale
+    (the per-head q/k norm over [rows, n_head * d_head])."""
+    (x,) = ins["X"]
+    (w,) = ins["Scale"]
+    eps = float(attrs.get("epsilon", 1e-5))
+    groups = int(attrs.get("groups", 1))
+    x32 = x.astype(_F32)
+    shape = x32.shape
+    if groups > 1:
+        x32 = x32.reshape(shape[:-1] + (groups, shape[-1] // groups))
+    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
+    y = y * w.astype(_F32)
+    return {"Out": [y.reshape(shape).astype(x.dtype)]}
+
+
+@register("rotary_embedding", no_grad=True, infer_shape=_like("X"))
+def _rotary_embedding(ctx, ins, attrs):
+    """Rotary position embedding over the whole head, half-rotation form
+    (rotate_half): out = x * cos + concat(-x2, x1) * sin, the angle of
+    feature pair i at position p being p * theta^(-2i/d)."""
+    (x,) = ins["X"]  # [rows, n_head * d]
+    (pos,) = ins["Pos"]
+    n_head = int(attrs["n_head"])
+    theta = float(attrs.get("theta", 10000.0))
+    rows = x.shape[0]
+    d = x.shape[-1] // n_head
+    inv = theta ** (-jnp.arange(0, d, 2, dtype=_F32) / d)
+    ang = pos.reshape(rows, 1).astype(_F32) * inv[None, :]
+    cos = jnp.cos(ang)[:, None, :]
+    sin = jnp.sin(ang)[:, None, :]
+    xh = x.astype(_F32).reshape(rows, n_head, d)
+    x1, x2 = xh[..., : d // 2], xh[..., d // 2:]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    return {"Out": [out.reshape(x.shape).astype(x.dtype)]}
+
+
+@register("swiglu", no_grad=True, infer_shape=_like("X"))
+def _swiglu(ctx, ins, attrs):
+    """silu(X) * Y: the gate of the gated feed-forward, in f32."""
+    (g,) = ins["X"]
+    (u,) = ins["Y"]
+    return {"Out": [(jax.nn.silu(g.astype(_F32)) * u.astype(_F32)).astype(g.dtype)]}
+
+
+# ---------------------------------------------------------------------------
+# gated short convolution
+# ---------------------------------------------------------------------------
+
+
+def _short_conv_infer(op, block):
+    x = _var(block, op, "X")
+    out = _var(block, op, "Out", outputs=True)
+    out.shape = tuple(x.shape[:-1]) + (x.shape[-1] // 3,)
+    out.dtype = x.dtype
+    state = _var(block, op, "State")
+    state_out = _var(block, op, "StateOut", outputs=True)
+    if state is not None and state_out is not None:
+        state_out.shape, state_out.dtype = state.shape, state.dtype
+
+
+@register("short_conv", no_grad=True, infer_shape=_short_conv_infer)
+def _short_conv(ctx, ins, attrs):
+    """Gated short convolution between its two projections: X is
+    ``u @ W_in`` = [B, C, z] (in that order, [rows, 3 * d]); v = B * z;
+    c_t = sum_j Kernel[:, j] * v_{t - (L-1) + j} (depthwise, causal);
+    Out = C * c.
+
+    mode "chunk": the rows are consecutive positions of ONE sequence from
+    Start; the (L - 1) rows of v before them come from State[Rows[0]] (zeros
+    when Start is 0), and the state after the last live row (Live counts
+    them; padding follows) is written back. With no State the rows are whole
+    sequences of seq_len positions each, every one from zeros. mode "step":
+    one position of a sequence a row, each with its own state row Rows[r];
+    the new state shifts v_t in.
+
+    v is rounded to the state's dtype before it is used, in both modes, so
+    what a later chunk or step reads back from the state is bit for bit what
+    the same position saw inside a chunk: the result does not depend on
+    where a prompt was cut."""
+    (x,) = ins["X"]
+    (kern,) = ins["Kernel"]  # [d, L]
+    state = ins.get("State", [None])[0]
+    d, taps = kern.shape
+    keep = taps - 1
+    rows = x.shape[0]
+    b, c, z = (x[:, i * d:(i + 1) * d].astype(_F32) for i in range(3))
+    store = state.dtype if state is not None else x.dtype
+    v = (b * z).astype(store).astype(_F32)
+    k32 = kern.astype(_F32)
+    if attrs.get("mode", "chunk") == "step":
+        idx = ins["Rows"][0].reshape(-1).astype(jnp.int32)
+        prev = jnp.take(state, idx, axis=0).astype(_F32).reshape(rows, keep, d)
+        conv = v * k32[:, keep]
+        for j in range(keep):
+            conv = conv + prev[:, j] * k32[:, j]
+        new = jnp.concatenate([prev[:, 1:], v[:, None]], 1).reshape(rows, keep * d)
+        out = (c * conv).astype(x.dtype)
+        return {"Out": [out],
+                "StateOut": [state.at[idx].set(new.astype(state.dtype))]}
+    if state is None:
+        # whole sequences of seq_len rows each (all the rows are one when 0)
+        t = int(attrs.get("seq_len", 0)) or rows
+        vv = jnp.pad(v.reshape(rows // t, t, d), ((0, 0), (keep, 0), (0, 0)))
+        conv = sum(vv[:, j:j + t] * k32[:, j] for j in range(taps))
+        return {"Out": [(c * conv.reshape(rows, d)).astype(x.dtype)]}
+    row = ins["Rows"][0].reshape(-1)[0].astype(jnp.int32)
+    start = ins["Start"][0].reshape(-1)[0]
+    prev = jnp.where(start > 0, state[row].astype(_F32).reshape(keep, d), 0.0)
+    vv = jnp.concatenate([prev, v], 0)  # v_t sits at row t + keep
+    conv = sum(vv[j:j + rows] * k32[:, j] for j in range(taps))
+    n_live = jnp.sum(ins["Live"][0].reshape(-1).astype(jnp.int32))
+    new = jax.lax.dynamic_slice_in_dim(vv, n_live, keep, 0)
+    return {
+        "Out": [(c * conv).astype(x.dtype)],
+        "StateOut": [
+            state.at[row].set(new.reshape(keep * d).astype(state.dtype))],
+    }
+
+
+# ---------------------------------------------------------------------------
+# mixture of experts
+# ---------------------------------------------------------------------------
+
+
+def _moe_router_infer(op, block):
+    x = _var(block, op, "X")
+    k = int(op.attrs["k"])
+    for slot, dtype in (("Picks", "int32"), ("Weights", "float32")):
+        out = _var(block, op, slot, outputs=True)
+        out.shape, out.dtype = (x.shape[0], k), dtype
+
+
+@register("moe_router", no_grad=True, infer_shape=_moe_router_infer)
+def _moe_router(ctx, ins, attrs):
+    """s = sigmoid(x @ W) in f32; the k experts with the largest s + Bias
+    (the bias picks, it does not weigh); Weights = s_i, over their sum + 1e-6
+    when norm_topk, times `scale`. Rows with Live == 0 get the pick
+    num_experts (no expert) and weight 0."""
+    (x,) = ins["X"]
+    (w,) = ins["W"]  # [d, num_experts] float32
+    bias = ins.get("Bias", [None])[0]
+    live = ins.get("Live", [None])[0]
+    k = int(attrs["k"])
+    n_exp = w.shape[-1]
+    s = jax.nn.sigmoid(jnp.dot(
+        x.astype(_F32), w.astype(_F32), precision=jax.lax.Precision.HIGHEST))
+    sel = s if bias is None else s + bias.astype(_F32)[None, :]
+    _, picks = jax.lax.top_k(sel, k)
+    g = jnp.take_along_axis(s, picks, axis=1)
+    if attrs.get("norm_topk", True):
+        g = g / (jnp.sum(g, -1, keepdims=True) + 1e-6)
+    g = g * float(attrs.get("scale", 1.0))
+    picks = picks.astype(jnp.int32)
+    if live is not None:
+        on = live.reshape(-1, 1) != 0
+        picks = jnp.where(on, picks, n_exp)
+        g = jnp.where(on, g, 0.0)
+    return {"Picks": [picks], "Weights": [g]}
+
+
+@register("moe_experts", no_grad=True, infer_shape=_like("X"))
+def _moe_experts(ctx, ins, attrs):
+    """The held experts' part of sum_i Weights_i * FF_{Picks_i}(x), with
+    FF_e(u) = (silu(u @ W1[e]) * (u @ W3[e])) @ W2[e]. The rows' picks are
+    sorted by expert; each group meets its own expert's matrices in a ragged
+    product, so an expert nobody picked costs nothing and a pick of an
+    expert held elsewhere (or of no expert: a row without a live token) is
+    not computed at all."""
+    (x,) = ins["X"]
+    (picks,) = ins["Picks"]
+    (weights,) = ins["Weights"]
+    (w1,) = ins["W1"]  # [held, d, f]
+    (w3,) = ins["W3"]
+    (w2,) = ins["W2"]  # [held, f, d]
+    n_exp = int(attrs["num_experts"])
+    n_held = w1.shape[0]
+    # the experts held, as global ids: all of them on one chip by default
+    held = [int(e) for e in (attrs.get("experts_held") or range(n_held))]
+    rows, k = picks.shape
+    where = np.full(n_exp + 1, n_held, np.int32)  # global id -> held index
+    where[held] = np.arange(n_held, dtype=np.int32)
+    local = jnp.asarray(where)[picks.reshape(-1)]
+    order = jnp.argsort(local, stable=True)
+    sorted_local = local[order]
+    token = order // k
+    sizes = jnp.bincount(local, length=n_held + 1)[:n_held].astype(jnp.int32)
+    xs = jnp.take(x, token, axis=0)
+    ragged = lambda a, b: jax.lax.ragged_dot(
+        a, b, sizes, preferred_element_type=_F32)
+    h = (jax.nn.silu(ragged(xs, w1)) * ragged(xs, w3)).astype(x.dtype)
+    y = ragged(h, w2)
+    g = weights.reshape(-1)[order].astype(_F32)
+    y = jnp.where((sorted_local < n_held)[:, None], y * g[:, None], 0.0)
+    out = jnp.zeros((rows, x.shape[-1]), _F32).at[token].add(y)
+    return {"Out": [out.astype(x.dtype)]}

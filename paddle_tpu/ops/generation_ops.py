@@ -10,8 +10,9 @@ mutable state and can be donated, so pages update in place and the step
 never retraces.
 
 Conventions:
-  * A pool is a persistable ``[n_pages * page_size, n_head * d_head]`` f32
-    array. Row ``page_id * page_size + offset`` holds the K (or V) row for
+  * A pool is a persistable ``[n_pages * page_size, n_kv_head * d_head]``
+    array (f32, bf16 or int8 levels; ``n_kv_head`` is ``n_head`` unless the
+    op's attribute says otherwise: grouped-query attention). Row ``page_id * page_size + offset`` holds the K (or V) row for
     one token. Page 0 is a scratch page the allocator never hands out —
     writes landing there (padded prefill tail, idle decode slots) are
     masked out of every attention read.
@@ -127,6 +128,9 @@ def _paged_attention(ctx, ins, attrs):
     (bt,) = ins["BlockTable"]  # [S, P] or [P] int32 page ids (0 = scratch)
     (pos,) = ins["Pos"]  # [S] position of each query (attends 0..pos)
     n_head = int(attrs["n_head"])
+    # grouped-query attention: the pools' rows hold n_kv_head heads and query
+    # head i reads KV head i // (n_head // n_kv_head)
+    n_kv = int(attrs.get("n_kv_head") or n_head)
     page_size = int(attrs["page_size"])
     s = q.shape[0]
     p = bt.shape[-1]
@@ -142,7 +146,7 @@ def _paged_attention(ctx, ins, attrs):
         out = _pk.paged_flash_attention(
             q, kp, vp, bt, pos,
             n_head=n_head, page_size=page_size, sm_scale=scale,
-            k_scales=ks, v_scales=vs,
+            k_scales=ks, v_scales=vs, n_kv_head=n_kv,
         )
         return {"Out": [out]}
 
@@ -156,13 +160,16 @@ def _paged_attention(ctx, ins, attrs):
         return x * sc.astype(jnp.float32).reshape(flat_idx.shape + (1, 1))
 
     qh = q.reshape(s, n_head, d).astype(jnp.float32)
+    # every query head beside its KV head: a no-op when n_kv == n_head
+    heads = lambda x: jnp.repeat(
+        x.reshape(x.shape[:-1] + (n_kv, d)), n_head // n_kv, axis=-2)
     offsets = jnp.arange(page_size, dtype=jnp.int32)
     if bt.ndim == 1:
         # one shared page list: gather each context row once for all queries
         flat = (bt.astype(jnp.int32)[:, None] * page_size + offsets[None, :])
         flat = flat.reshape(ctx_len)
-        k = jnp.take(kp, flat, axis=0).reshape(ctx_len, n_head, d)
-        v = jnp.take(vp, flat, axis=0).reshape(ctx_len, n_head, d)
+        k = heads(jnp.take(kp, flat, axis=0))
+        v = heads(jnp.take(vp, flat, axis=0))
         k = _deq(k, ks, flat)
         v = _deq(v, vs, flat)
         scores = jnp.einsum("shd,chd->shc", qh, k.astype(jnp.float32)) * scale
@@ -171,8 +178,8 @@ def _paged_attention(ctx, ins, attrs):
             bt.astype(jnp.int32)[:, :, None] * page_size
             + offsets[None, None, :]
         ).reshape(s, ctx_len)
-        k = jnp.take(kp, flat.reshape(-1), axis=0).reshape(s, ctx_len, n_head, d)
-        v = jnp.take(vp, flat.reshape(-1), axis=0).reshape(s, ctx_len, n_head, d)
+        k = heads(jnp.take(kp, flat.reshape(-1), axis=0).reshape(s, ctx_len, -1))
+        v = heads(jnp.take(vp, flat.reshape(-1), axis=0).reshape(s, ctx_len, -1))
         k = _deq(k, ks, flat)
         v = _deq(v, vs, flat)
         scores = jnp.einsum("shd,schd->shc", qh, k.astype(jnp.float32)) * scale

@@ -1476,7 +1476,7 @@ _PAGED_BUFFER_BYTES = 8 * 1024 * 1024
 # Folded into serving/compile_cache.variant_key: an exported serving module
 # embeds the Mosaic kernels it was lowered with, so a cached export must miss
 # when a kernel's lowering changes. Bump it with every such change.
-KERNELS_REVISION = "26-paged-walk"
+KERNELS_REVISION = "27-paged-walk-gqa"
 
 
 def paged_flash_path_taken(n_q, n_pages, page_size, n_head, d_head):
@@ -1533,8 +1533,8 @@ def paged_positions_walked(pos, page_size, n_pages, row_bytes, shared=False):
     return int(np.clip(pos // block + 1, 0, most).sum()) * block
 
 
-def _paged_flash_kernel(*refs, page_size, n_head, sm_scale, per_row, quant,
-                        n_pages, block_pages):
+def _paged_flash_kernel(*refs, page_size, n_head, n_kv_head, sm_scale,
+                        per_row, quant, n_pages, block_pages):
     """Every query row the program owns against the pages its table holds up
     to pos, a compute block of block_pages pages a loop trip. per_row
     (decode): grid (slot,), the slot's one query row, its own block-table
@@ -1559,7 +1559,15 @@ def _paged_flash_kernel(*refs, page_size, n_head, sm_scale, per_row, quant,
     head's lanes and spread p back over them (m, l: (1, 128), a head a
     lane). A chunk of rows walks the heads over lane slices with two MXU
     products each (m, l per head, lane-broadcast like the flash kernels
-    above). acc is whole-feature in both."""
+    above). acc is whole-feature in both.
+
+    Grouped-query attention (n_kv_head < n_head, group = their ratio): the
+    pools' rows hold n_kv_head heads. Decode gets its query row as `group`
+    rows of the pools' width, row g holding member g of every KV head's
+    group, so each is one whole-feature product with the same K and V block
+    and the indicator matrices of row g put its heads on lanes g * n_kv_head
+    + j (with group 1 this is the one row and the one pair of matrices there
+    was). A chunk's query head h reads the lane slice of KV head h // group."""
     bt_ref, sp_ref = refs[:2]
     refs = refs[2:]
     if per_row:
@@ -1577,16 +1585,19 @@ def _paged_flash_kernel(*refs, page_size, n_head, sm_scale, per_row, quant,
     pools, o_ref, bufs = refs[:2], refs[2], refs[3:5]  # K, V
     sem, acc_ref, m_ref, l_ref = refs[5:9]
     if per_row:
-        sum_ref, spread_ref = refs[9:]  # (feat, 128), (128, feat)
-    rows, feat = q_ref.shape
-    d = feat // n_head
+        sum_ref, spread_ref = refs[9:]  # (group, feat, 128), (group, 128, feat)
+    rows, feat = q_ref.shape  # decode: (group, the pools' width)
+    group = n_head // n_kv_head
+    d = (feat if per_row else bufs[0].shape[-1]) // n_kv_head
     block = block_pages * page_size
     n_blocks = jnp.clip(last // block + 1, 0, pl.cdiv(n_pages, block_pages))
 
-    def head_lanes(shape, head_axis):
-        # 1.0 where the lane (the other axis) belongs to the head
+    def head_lanes(shape, head_axis, g):
+        # 1.0 where the lane (the other axis) belongs to the KV head whose
+        # group member g sits on this head lane (g * n_kv_head + the KV head)
         lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - head_axis)
-        first = jax.lax.broadcasted_iota(jnp.int32, shape, head_axis) * d
+        first = (jax.lax.broadcasted_iota(jnp.int32, shape, head_axis)
+                 - g * n_kv_head) * d
         return ((lane >= first) & (lane < first + d)).astype(jnp.float32)
 
     @pl.when(pl.program_id(0) == 0)
@@ -1594,8 +1605,9 @@ def _paged_flash_kernel(*refs, page_size, n_head, sm_scale, per_row, quant,
         for buf in bufs:
             buf[...] = jnp.zeros_like(buf)
         if per_row:
-            sum_ref[...] = head_lanes(sum_ref.shape, 1)
-            spread_ref[...] = head_lanes(spread_ref.shape, 0)
+            for g in range(group):
+                sum_ref[g] = head_lanes(sum_ref.shape[1:], 1, g)
+                spread_ref[g] = head_lanes(spread_ref.shape[1:], 0, g)
 
     acc_ref[...] = jnp.zeros_like(acc_ref)
     m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
@@ -1648,7 +1660,10 @@ def _paged_flash_kernel(*refs, page_size, n_head, sm_scale, per_row, quant,
     def one_row_block(b, slot):
         k = bufs[0][slot].astype(jnp.float32).reshape(block, feat)
         v = bufs[1][slot].astype(jnp.float32).reshape(block, feat)
-        s = dot(k * q_ref[...].astype(jnp.float32), sum_ref[...]) * sm_scale
+        s = sum(
+            dot(k * q_ref[g:g + 1].astype(jnp.float32), sum_ref[g])
+            for g in range(group)
+        ) * sm_scale
         if quant:
             s = s * column(ks_ref[pl.ds(b, 1), :])
         live = b * block + jax.lax.broadcasted_iota(
@@ -1659,13 +1674,12 @@ def _paged_flash_kernel(*refs, page_size, n_head, sm_scale, per_row, quant,
         )
         if quant:
             p = p * column(vs_ref[pl.ds(b, 1), :])
-        spread = dot(
-            jnp.concatenate([p, jnp.broadcast_to(alpha, (8, _LANES))]),
-            spread_ref[...],
-        )  # (block + 8, feat)
-        acc_ref[...] = acc_ref[...] * spread[block:block + 1] + jnp.sum(
-            spread[:block] * v, axis=0, keepdims=True
-        )
+        p_alpha = jnp.concatenate([p, jnp.broadcast_to(alpha, (8, _LANES))])
+        for g in range(group):
+            spread = dot(p_alpha, spread_ref[g])  # (block + 8, feat)
+            acc_ref[g:g + 1] = acc_ref[g:g + 1] * spread[block:block + 1] + (
+                jnp.sum(spread[:block] * v, axis=0, keepdims=True)
+            )
 
     def chunk_block(b, slot):
         live = b * block + jax.lax.broadcasted_iota(
@@ -1676,9 +1690,10 @@ def _paged_flash_kernel(*refs, page_size, n_head, sm_scale, per_row, quant,
             v_scale = vs_ref[pl.ds(b, 1), :]
         for h in range(n_head):
             sl = slice(h * d, (h + 1) * d)
+            kv = slice(h // group * d, (h // group + 1) * d)
             q2 = q_ref[:, sl].astype(jnp.float32)  # (rows, d)
-            k2 = bufs[0][slot, :, :, sl].astype(jnp.float32).reshape(block, d)
-            v2 = bufs[1][slot, :, :, sl].astype(jnp.float32).reshape(block, d)
+            k2 = bufs[0][slot, :, :, kv].astype(jnp.float32).reshape(block, d)
+            v2 = bufs[1][slot, :, :, kv].astype(jnp.float32).reshape(block, d)
             s = dot(q2, k2, ((1,), (1,))) * (k_scale if quant else sm_scale)
             p, alpha, m_new, l_new = softmax_step(
                 s, live, m_ref[h][:, :1], l_ref[h][:, :1], 1
@@ -1709,10 +1724,12 @@ def _paged_flash_kernel(*refs, page_size, n_head, sm_scale, per_row, quant,
     # l = 0 and emits zeros instead of 0/0 nan — the where-mask contract
     # the dense reference shares
     if per_row:
-        l = dot(jnp.broadcast_to(l_ref[...], (8, _LANES)), spread_ref[...])[:1]
-        o_ref[...] = (
-            acc_ref[...] / jnp.where(l > 0.0, l, 1.0)
-        ).astype(o_ref.dtype)
+        l8 = jnp.broadcast_to(l_ref[...], (8, _LANES))
+        for g in range(group):
+            l = dot(l8, spread_ref[g])[:1]
+            o_ref[g:g + 1] = (
+                acc_ref[g:g + 1] / jnp.where(l > 0.0, l, 1.0)
+            ).astype(o_ref.dtype)
     else:
         for h in range(n_head):
             sl = slice(h * d, (h + 1) * d)
@@ -1724,7 +1741,7 @@ def _paged_flash_kernel(*refs, page_size, n_head, sm_scale, per_row, quant,
 
 def paged_flash_attention(q, k_pool, v_pool, block_table, pos, *, n_head,
                           page_size, sm_scale=None, k_scales=None,
-                          v_scales=None, interpret=None):
+                          v_scales=None, interpret=None, n_kv_head=None):
     """Paged attention over the KV pool without materializing the gathered
     context. q is [rows, n_head*d]; block_table is [rows, P] (decode — one
     page list per query row) or [P] (chunked prefill — one list shared by
@@ -1735,9 +1752,11 @@ def paged_flash_attention(q, k_pool, v_pool, block_table, pos, *, n_head,
     the sum). The work follows pos, not the table's width:
     paged_positions_walked mirrors it.
 
-    The pools are read in place as [pool_rows, n_head*d]: one page is one
-    (page_size, n_head*d) copy, legal for Mosaic whenever page_size is a
-    multiple of 8, with no relayout of the pool around the call.
+    The pools are read in place as [pool_rows, n_kv_head*d]: one page is one
+    (page_size, n_kv_head*d) copy, legal for Mosaic whenever page_size is a
+    multiple of 8 (of 16 for a bf16 pool), with no relayout of the pool
+    around the call. n_kv_head (default n_head) < n_head is grouped-query
+    attention: query head i reads KV head i // (n_head // n_kv_head).
 
     k_scales/v_scales (both or neither): the pools hold int8 levels and
     [pool_rows] f32 per-row scales ride along, gathered through the table
@@ -1748,29 +1767,35 @@ def paged_flash_attention(q, k_pool, v_pool, block_table, pos, *, n_head,
         interpret = jax.default_backend() != "tpu"
     rows, feat = q.shape
     d = feat // n_head
+    n_kv = int(n_kv_head or n_head)
+    group, kv_feat = n_head // n_kv, n_kv * d
     bt = block_table.astype(jnp.int32)
     pos_v = pos.reshape(-1).astype(jnp.int32)
     quant = k_scales is not None
     per_row = bt.ndim == 2
     n_pages = bt.shape[-1]
     block_pages = _paged_block_pages(
-        n_pages, page_size * feat * k_pool.dtype.itemsize, not per_row
+        n_pages, page_size * kv_feat * k_pool.dtype.itemsize, not per_row
     )
     _note_dispatch("paged_flash_int8" if quant else "paged_flash")
     if per_row:
         grid = (rows,)
-        q_spec = pl.BlockSpec((None, 1, feat), lambda s, bt_r, pos_r: (s, 0, 0))
+        q_spec = pl.BlockSpec(
+            (None, group, kv_feat), lambda s, bt_r, pos_r: (s, 0, 0))
         prefetch = [bt, pos_v]
-        # (rows, 1, feat): a (1, feat) block per slot is legal only as the
-        # whole of the last two dimensions
-        operands, in_specs = [q.reshape(rows, 1, feat)], [q_spec]
-        out_shape = jax.ShapeDtypeStruct((rows, 1, feat), q.dtype)
+        # (rows, group, kv_feat): a slot's block is legal only as the whole
+        # of the last two dimensions; row g holds member g of every KV
+        # head's group of query heads
+        operands = [q.reshape(rows, n_kv, group, d).transpose(0, 2, 1, 3)
+                    .reshape(rows, group, kv_feat)]
+        in_specs = [q_spec]
+        out_shape = jax.ShapeDtypeStruct((rows, group, kv_feat), q.dtype)
         carries = [
-            pltpu.VMEM((1, feat), jnp.float32),  # acc
+            pltpu.VMEM((group, kv_feat), jnp.float32),  # acc
             pltpu.VMEM((1, _LANES), jnp.float32),  # m, a head a lane
             pltpu.VMEM((1, _LANES), jnp.float32),  # l
-            pltpu.VMEM((feat, _LANES), jnp.float32),  # lanes -> head sums
-            pltpu.VMEM((_LANES, feat), jnp.float32),  # head -> its lanes
+            pltpu.VMEM((group, kv_feat, _LANES), jnp.float32),  # lanes -> head sums
+            pltpu.VMEM((group, _LANES, kv_feat), jnp.float32),  # head -> its lanes
         ]
     else:
         grid = (1,)
@@ -1815,7 +1840,7 @@ def paged_flash_attention(q, k_pool, v_pool, block_table, pos, *, n_head,
     out = pl.pallas_call(
         functools.partial(
             _paged_flash_kernel, page_size=page_size, n_head=n_head,
-            sm_scale=float(sm_scale or 0.0) or d**-0.5,
+            n_kv_head=n_kv, sm_scale=float(sm_scale or 0.0) or d**-0.5,
             per_row=per_row, quant=quant, n_pages=n_pages,
             block_pages=block_pages,
         ),
@@ -1825,14 +1850,16 @@ def paged_flash_attention(q, k_pool, v_pool, block_table, pos, *, n_head,
             in_specs=in_specs,
             out_specs=q_spec,
             scratch_shapes=[
-                pltpu.VMEM((2, block_pages, page_size, feat), k_pool.dtype),
-                pltpu.VMEM((2, block_pages, page_size, feat), v_pool.dtype),
+                pltpu.VMEM((2, block_pages, page_size, kv_feat), k_pool.dtype),
+                pltpu.VMEM((2, block_pages, page_size, kv_feat), v_pool.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),  # [K | V, buffer]
             ] + carries,
         ),
         out_shape=out_shape,
         interpret=interpret,
     )(*prefetch, *operands)
+    if per_row:
+        out = out.reshape(rows, group, n_kv, d).transpose(0, 2, 1, 3)
     return out.reshape(rows, feat)
 
 

@@ -165,7 +165,7 @@ class _SlotRun:
 
     __slots__ = ("req", "slot", "table", "tokens", "next_pos", "rng",
                  "pf_pos", "done", "finish_reason", "future", "t_submit",
-                 "t_first", "span")
+                 "t_first", "span", "cut", "snaps", "picks", "chunk_picks")
 
     def __init__(self, req, slot, table, rng):
         self.req = req
@@ -175,6 +175,18 @@ class _SlotRun:
         self.next_pos = len(req.prompt)
         self.rng = rng
         self.pf_pos = 0  # next prompt position to prefill (past prefix hits)
+        # a model with a per-sequence state: the prompt position at which a
+        # chunk must end so that a snapshot lands on a boundary other
+        # prompts share (0: none), and the snapshots this prefill has left,
+        # {prompt tokens: state handle}
+        self.cut = 0
+        self.snaps = {}
+        # engine.record_picks: the routers' picks of each chunk and step,
+        # [(first position, picks [n_moe, rows, k])]
+        self.picks = []
+        # the prefill chunks' picks still on the device, [(first position,
+        # live rows, array)]: read with the prompt's last chunk's logits
+        self.chunk_picks = []
         self.done = False
         self.finish_reason = None
         self.future = None
@@ -223,6 +235,43 @@ CTX_TOKEN_BUCKETS = (
     16, 32, 64, 128, 256, 512, 1024, 2048, 4096, 8192, 16384, 32768, 65536,
     131072, 262144, 524288, 1048576,
 )
+
+
+# gen_experts_touched / gen_expert_rows_max: small counts
+EXPERT_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024, 2048, 4096)
+
+
+def cache_specs(model):
+    """A model's cache tensors as per-layer specs (ROADMAP R-A4):
+    {name, kind, width, dtype}, kind "paged" (rows indexed through block
+    tables) or "state" (one fixed row a slot, row 0 scratch). A model that
+    only names its K/V pools (GPTDecoder) gets d_model-wide paged rows in
+    its kv_dtype and, in int8 mode, its scale pools (width 0: one float a
+    row)."""
+    own = getattr(model, "cache_specs", None)
+    if own is not None:
+        return own()
+    dtype = getattr(model, "kv_dtype", "float32")
+    specs = [
+        {"name": n, "kind": "paged", "width": model.d_model, "dtype": dtype}
+        for pair in model.kv_pool_names() for n in pair]
+    specs += [
+        {"name": n, "kind": "paged", "width": 0, "dtype": "float32"}
+        for pair in getattr(model, "kv_scale_names", lambda: [])()
+        for n in pair]
+    return specs
+
+
+def _experts_touched(picks):
+    """(distinct experts picked, summed over the expert layers; the most
+    rows any one expert of any layer got) of picks [n_moe, live rows, k]."""
+    n_moe = picks.shape[0]
+    width = int(picks.max()) + 1
+    counts = np.bincount(
+        (picks.reshape(n_moe, -1)
+         + np.arange(n_moe, dtype=picks.dtype)[:, None] * width).ravel(),
+        minlength=n_moe * width)
+    return int(np.count_nonzero(counts)), int(counts.max())
 
 
 class GenerationEngine:
@@ -279,7 +328,6 @@ class GenerationEngine:
         # longest admissible prompt must leave room for >= 1 generated
         # token; chunking covers any prompt up to the context bound
         self.max_prompt_len = self.max_context - 1
-        self.prefix_cache = PrefixCache(self.pool) if prefix_cache else None
 
         self.scope = scope or Scope()
         model.ensure_params(self.scope, place)
@@ -288,27 +336,50 @@ class GenerationEngine:
         # and each gains a [pool_rows] f32 per-row scale pool sibling
         # (model.kv_scale_names) — ~1/4 the f32 bytes per cached token
         self.kv_dtype = getattr(model, "kv_dtype", "float32")
-        self._kv_row_bytes = model.d_model * np.dtype(self.kv_dtype).itemsize
+        # per-layer cache specs: paged rows of each layer's own width, and
+        # for a layer whose state is fixed (a short convolution) one row a
+        # slot in a [max_slots + 1, width] pool, row 0 scratch: a decode
+        # step's idle and mid-prefill rows write there, as they write the
+        # scratch page
+        specs = cache_specs(model)
         self._state = {}
-        for pair in model.kv_pool_names():
-            for n in pair:
-                arr = jnp.zeros(
-                    (pool_rows, model.d_model), jnp.dtype(self.kv_dtype)
-                )
-                self.scope.vars[n] = arr
-                self._state[n] = arr
-        for pair in getattr(model, "kv_scale_names", lambda: [])():
-            for n in pair:
-                # scale 1.0 everywhere: scratch-page reads dequantize to
-                # in-range garbage instead of inf/nan before being masked
-                arr = jnp.ones((pool_rows,), jnp.float32)
-                self.scope.vars[n] = arr
-                self._state[n] = arr
-        self.kv_state_bytes = sum(
-            int(np.prod(a.shape)) * jnp.dtype(a.dtype).itemsize
-            for a in self._state.values()
-        )
+        self._state_names = []  # the fixed per-slot state pools
+        self.kv_state_bytes = self.seq_state_bytes = 0
+        self.state_row_bytes = 0  # one slot's state: what a snapshot holds
+        for spec in specs:
+            dtype = jnp.dtype(spec["dtype"])
+            if spec["kind"] == "state":
+                arr = jnp.zeros((self.max_slots + 1, spec["width"]), dtype)
+                self._state_names.append(spec["name"])
+                self.seq_state_bytes += arr.size * dtype.itemsize
+                self.state_row_bytes += spec["width"] * dtype.itemsize
+            else:
+                if spec["width"]:
+                    arr = jnp.zeros((pool_rows, spec["width"]), dtype)
+                else:
+                    # scale 1.0 everywhere: scratch-page reads dequantize to
+                    # in-range garbage instead of inf/nan before being masked
+                    arr = jnp.ones((pool_rows,), dtype)
+                self.kv_state_bytes += arr.size * dtype.itemsize
+            self.scope.vars[spec["name"]] = arr
+            self._state[spec["name"]] = arr
+        paged = [s for s in specs if s["kind"] == "paged" and s["width"]]
+        # one pooled row of one layer, as the paged kernel copies it
+        self._kv_row_bytes = (
+            paged[0]["width"] * jnp.dtype(paged[0]["dtype"]).itemsize
+            if paged else 0)
         self.pool.row_bytes = self.kv_state_bytes // pool_rows
+        self.pool.state_bytes = self.seq_state_bytes
+        self.prefix_cache = (
+            PrefixCache(self.pool, state_bytes=self.state_row_bytes)
+            if prefix_cache else None)
+        self._build_kw = (
+            {"state_rows": self.max_slots + 1} if self._state_names else {})
+        self._state_fns = None  # (snapshot, restore), compiled at warm-up
+        # record_picks: keep each run's router picks (run.picks) and copy a
+        # prefill chunk's to the host; off in serving (a chunk's fetch stays
+        # on the device until the prompt's last)
+        self.record_picks = False
 
         if cache_dir is None:
             from .. import flags as _flags
@@ -329,6 +400,11 @@ class GenerationEngine:
             "dec_block_table": np.zeros(
                 (self.max_slots, self.max_pages), np.int32
             ),
+            # set anew every step for the runs it advances (a model without
+            # experts or a fixed state does not feed them): 1 / the slot's
+            # state row for those, 0 / the scratch row for every other slot
+            "dec_live": np.zeros((self.max_slots,), np.int32),
+            "dec_state_rows": np.zeros((self.max_slots,), np.int32),
         }
 
         self._variants = {}
@@ -417,6 +493,45 @@ class GenerationEngine:
             "resident KV state bytes (level pools + scale pools)",
         )
         self._m_kv_bytes.set(float(self.kv_state_bytes))
+        self._m_state_bytes = reg.gauge(
+            p + "/gen_state_bytes",
+            "resident bytes of the fixed per-slot sequence state (conv "
+            "layers' rows), scratch row included",
+        )
+        self._m_state_bytes.set(float(self.seq_state_bytes))
+        self._m_snap_bytes = reg.gauge(
+            p + "/gen_state_snapshot_bytes",
+            "bytes of the sequence-state snapshots the prefix cache holds",
+        )
+        self._m_restore_ms = reg.histogram(
+            p + "/gen_state_restore_ms",
+            "a prefix hit's copy of the cached sequence state into the "
+            "slot's state rows (span fluid.engine.state_restore), wall ms",
+        )
+        self._m_states = reg.counter(
+            p + "/gen_state_snapshots",
+            "sequence-state snapshots by event: taken (kept by the prefix "
+            "cache), restored (a prefix hit), evicted",
+        )
+        self._states_seen = {"taken": 0, "restored": 0, "evicted": 0}
+        self._m_experts_touched = reg.histogram(
+            p + "/gen_experts_touched",
+            "distinct experts with a live row in one decode step, summed "
+            "over the expert layers",
+            buckets=EXPERT_BUCKETS,
+        )
+        self._m_chunk_experts = reg.histogram(
+            p + "/gen_chunk_experts_touched",
+            "distinct experts with a live row in one prefill chunk, summed "
+            "over the expert layers (observed with the prompt's last chunk)",
+            buckets=EXPERT_BUCKETS,
+        )
+        self._m_expert_rows_max = reg.histogram(
+            p + "/gen_expert_rows_max",
+            "most live rows any one expert of any layer got in one decode "
+            "step",
+            buckets=EXPERT_BUCKETS,
+        )
         # precision label for the monitor's serve rows: 0 = fp32 pools,
         # 1 = int8 pools (tools/monitor.py maps it back to a string)
         self._m_precision = reg.gauge(
@@ -439,7 +554,7 @@ class GenerationEngine:
 
     # ---- geometry / cache keys --------------------------------------------
     def geometry(self):
-        return {
+        geo = {
             "page_size": self.page_size,
             "pool_pages": self.pool_pages,
             "max_slots": self.max_slots,
@@ -447,6 +562,10 @@ class GenerationEngine:
             "max_context": self.max_context,
             "kv_dtype": self.kv_dtype,
         }
+        spec = getattr(self.model, "spec", None)
+        if spec is not None:  # a block-specified decoder: its layers are data
+            geo["block_spec"] = spec()
+        return geo
 
     def _canon_dtype(self, dtype):
         import jax.numpy as jnp
@@ -467,12 +586,14 @@ class GenerationEngine:
             pool_rows = self.pool_pages * self.page_size
             if kind == "decode":
                 main, _, feeds, fetches = self.model.build_decode(
-                    self.max_slots, self.page_size, self.max_pages, pool_rows
+                    self.max_slots, self.page_size, self.max_pages, pool_rows,
+                    **self._build_kw
                 )
             elif kind.startswith("prefill:"):
                 t = int(kind.split(":", 1)[1])
                 main, _, feeds, fetches = self.model.build_prefill(
-                    t, self.page_size, self.max_pages, pool_rows
+                    t, self.page_size, self.max_pages, pool_rows,
+                    **self._build_kw
                 )
             else:
                 raise ValueError("unknown variant kind %r" % kind)
@@ -551,7 +672,47 @@ class GenerationEngine:
         self._variant("decode")
         for b in self.prefill_buckets:
             self._variant("prefill:%d" % b)
+        self._state_programs()
         return len(self._variants)
+
+    # ---- the fixed per-slot state: snapshot and restore --------------------
+    def _state_programs(self):
+        """(snapshot, restore) over the state pools, compiled once (at
+        warm-up, so that a prefix hit in the hot loop compiles nothing), or
+        None for a model whose every layer's state is pages. snapshot(pools,
+        row) -> a tuple of the row of each pool (a copy: 82 KB for ten conv
+        layers of 2 x 2048 bf16); restore(pools, snapshot, row) -> the pools
+        with that row set, the pools donated."""
+        if not self._state_names or self._state_fns is not None:
+            return self._state_fns
+        import jax
+
+        pools = tuple(self._state[n] for n in self._state_names)
+        row = np.int32(0)
+        snapshot = jax.jit(
+            lambda pools, row: tuple(p[row] for p in pools)
+        ).lower(pools, row).compile()
+        restore = jax.jit(
+            lambda pools, snap, row: tuple(
+                p.at[row].set(v) for p, v in zip(pools, snap)),
+            donate_argnums=(0,),
+        ).lower(pools, snapshot(pools, row), row).compile()
+        self._state_fns = (snapshot, restore)
+        return self._state_fns
+
+    def _snapshot_state(self, slot):
+        snapshot, _ = self._state_programs()
+        pools = tuple(self._state[n] for n in self._state_names)
+        return snapshot(pools, np.int32(slot + 1))
+
+    def _restore_state(self, slot, snap):
+        """A prefix hit: the cached boundary's state into the slot's rows."""
+        _, restore = self._state_programs()
+        with RecordEvent(span="engine.state_restore",
+                         hist=self._m_restore_ms, iter=self.iter):
+            pools = tuple(self._state[n] for n in self._state_names)
+            new = restore(pools, snap, np.int32(slot + 1))
+            self._state.update(zip(self._state_names, new))
 
     # ---- hot swap ---------------------------------------------------------
     def set_params(self, updates, version=None, stamp=None):
@@ -654,9 +815,11 @@ class GenerationEngine:
                 % (L, self.max_prompt_len)
             )
         max_new = self._max_new(req)
-        shared = []
+        shared, state, matched = [], None, 0
         if self.prefix_cache is not None:
-            shared = self.prefix_cache.lookup(req.prompt)  # pages pinned
+            # pages pinned; with a per-sequence state, only down to a
+            # boundary whose snapshot comes with them
+            shared, state, matched = self.prefix_cache.lookup_state(req.prompt)
         try:
             try:
                 slot, table = self.pool.acquire(L + max_new, shared)
@@ -674,6 +837,13 @@ class GenerationEngine:
             self._sample_counter += 1
         run = _SlotRun(req, slot, table, np.random.default_rng(seed))
         run.pf_pos = len(shared) * self.page_size
+        if self._state_names and self.prefix_cache is not None:
+            if state is not None:
+                self._restore_state(slot, state)
+            if matched > len(shared):
+                # other prompts share more of this one than any snapshot
+                # covers: end a chunk at that boundary and leave one there
+                run.cut = matched * self.page_size
         self._set_pool_gauges()
         return run
 
@@ -687,6 +857,8 @@ class GenerationEngine:
         remaining = L - start
         if remaining <= 0:
             raise ValueError("prefill_step on a fully prefilled run")
+        if start < run.cut:
+            remaining = min(remaining, run.cut - start)
         c = self.prefill_bucket(remaining)
         n_real = min(c, remaining)
         span = _tracing.current()
@@ -707,9 +879,13 @@ class GenerationEngine:
                     "gen_start": np.array([start], np.int64),
                     "gen_last": np.array([n_real - 1], np.int64),
                     "gen_pages": run.table,
+                    # padding rows route to no expert and stay out of the
+                    # conv state
+                    "gen_live": np.arange(c) < n_real,
+                    "gen_state_row": np.array([run.slot + 1], np.int32),
                 })
             with self._phase("dispatch", "prefill") as dispatch:
-                (logits,) = self._dispatch(variant, feeds, mut_in)
+                logits, *extra = self._dispatch(variant, feeds, mut_in)
         except Exception as e:
             span.error(e).end()
             raise
@@ -720,6 +896,14 @@ class GenerationEngine:
         self._m_prefill_ms.observe(prefill_ms)
         self._m_chunks.inc()
         run.pf_pos = start + n_real
+        if extra:
+            run.chunk_picks.append((start, n_real, extra[0]))
+        ps = self.page_size
+        if (self._state_names and self.prefix_cache is not None
+                and run.pf_pos % ps == 0 and run.pf_pos <= (L - 1) // ps * ps):
+            # the chunk ended on a page boundary a later lookup can reach:
+            # keep the state there, for the prefix cache to hold
+            run.snaps[run.pf_pos] = self._snapshot_state(run.slot)
         if run.pf_pos < L:
             return False
         self._m_prefills.inc()
@@ -727,11 +911,18 @@ class GenerationEngine:
             # parity surface: tests assert these rows bit-stable under
             # batching/admission/chunking changes (docs/serving.md contract)
             self.last_prefill_logits = np.asarray(logits)[0]
+            for first, n_live, picks in run.chunk_picks:
+                picks = np.asarray(picks)[:, :n_live]
+                self._m_chunk_experts.observe(_experts_touched(picks)[0])
+                if self.record_picks:
+                    run.picks.append((first, picks))
+            run.chunk_picks = []
         with self._phase("sample", "prefill"):
             self._append_token(
                 run, self.last_prefill_logits, self._max_new(req))
             if self.prefix_cache is not None:
-                self.prefix_cache.insert(req.prompt, run.table)
+                self.prefix_cache.insert(req.prompt, run.table, run.snaps)
+                run.snaps = {}
             # arm the slot's persistent decode-feed rows only now: until the
             # last chunk lands, an interleaved decode step must keep this
             # slot on the scratch page, never writing a page a chunk already
@@ -775,12 +966,17 @@ class GenerationEngine:
             with self._phase("feed", "decode"):
                 feeds = self._dec_feeds
                 tokens, positions = feeds["dec_tokens"], feeds["dec_positions"]
+                live, state_rows = feeds["dec_live"], feeds["dec_state_rows"]
+                live[:] = 0
+                state_rows[:] = 0
                 ctx_tokens = 0
                 for run in runs:
                     if run.done:
                         raise ValueError("decode_step on a finished run")
                     tokens[run.slot, 0] = run.tokens[-1]
                     positions[run.slot, 0] = run.next_pos
+                    live[run.slot] = 1
+                    state_rows[run.slot] = run.slot + 1
                     ctx_tokens += run.next_pos
                 walked_tokens = _pk.paged_positions_walked(
                     positions, self.page_size, self.max_pages,
@@ -789,9 +985,12 @@ class GenerationEngine:
                 variant = self._variant("decode")
                 feeds, mut_in = self._feeds(variant, feeds)
             with self._phase("dispatch", "decode") as dispatch:
-                (logits,) = self._dispatch(variant, feeds, mut_in)
+                logits, *extra = self._dispatch(variant, feeds, mut_in)
             with self._phase("wait", "decode") as wait:
                 logits = np.asarray(logits)
+                # the routers' picks, [n_moe, slots, k]: a small extra fetch
+                # of the same step
+                picks = np.asarray(extra[0]) if extra else None
         except Exception as e:
             span.error(e).end()
             raise
@@ -805,11 +1004,24 @@ class GenerationEngine:
         self._m_ctx_tokens.observe(ctx_tokens)
         self._m_walked_tokens.observe(walked_tokens)
         self._m_steps.inc()
+        if picks is not None:
+            self._note_picks(picks, runs)
         with self._phase("sample", "decode"):
             for run in runs:
                 run.next_pos += 1
                 self._append_token(
                     run, logits[run.slot], self._max_new(run.req))
+
+    def _note_picks(self, picks, runs):
+        """Experts touched by one decode step's live rows, from its picks."""
+        self.last_picks = picks  # parity surface, like last_logits
+        touched, rows_max = _experts_touched(
+            picks[:, [run.slot for run in runs], :])
+        self._m_experts_touched.observe(touched)
+        self._m_expert_rows_max.observe(rows_max)
+        if self.record_picks:
+            for run in runs:
+                run.picks.append((run.next_pos, picks[:, run.slot:run.slot + 1]))
 
     def finish(self, run):
         """Retire a run's slot: pages return to the pool for reuse (cached
@@ -858,7 +1070,17 @@ class GenerationEngine:
         self._m_pages_shared.set(st["pages_shared"])
         self._m_paged_flash.set(_pk.KERNEL_DISPATCHES.get("paged_flash", 0))
         if self.prefix_cache is not None:
-            self._m_prefix_hit.set(self.prefix_cache.stats()["hit_rate"])
+            st = self.prefix_cache.stats()
+            self._m_prefix_hit.set(st["hit_rate"])
+            if self._state_names:
+                self._m_snap_bytes.set(float(st["state_bytes"]))
+                for event, key in (("taken", "states_taken"),
+                                   ("restored", "states_hit"),
+                                   ("evicted", "states_evicted")):
+                    new = st[key] - self._states_seen[event]
+                    if new:
+                        self._m_states.inc(new, event=event)
+                        self._states_seen[event] = st[key]
 
     # ---- convenience / stats ----------------------------------------------
     def generate(self, prompt, max_new_tokens=16, **kw):
@@ -910,6 +1132,11 @@ class GenerationEngine:
             "kv": {
                 "dtype": self.kv_dtype,
                 "resident_bytes": self.kv_state_bytes,
+                # the fixed per-slot state beside the pages (conv layers'
+                # rows, scratch row included), and one slot's share of it:
+                # what a prefix-cache snapshot holds
+                "state_bytes": self.seq_state_bytes,
+                "state_row_bytes": self.state_row_bytes,
             },
         }
         if self.prefix_cache is not None:

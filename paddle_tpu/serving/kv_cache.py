@@ -39,6 +39,21 @@ produce the first sampled logits). Eviction is LRU over unreferenced
 entries (descendants first, so the trie never has unreachable tails) and
 runs on demand when admission wants pages the free list can't supply.
 
+**A fixed per-sequence state beside the pages** (a model whose layers are
+not all attention: a short convolution's last rows, a recurrence's carry).
+The engine keeps it in per-slot state rows (row 0 scratch, slot s at row
+s + 1) that no allocator manages: a slot owns its row. What the prefix cache
+has to add is that *a hit must restore the state*: pages alone put the
+attention layers at the boundary, not the others. So with ``state_bytes`` >
+0 a trie node may carry a **snapshot** of the state at its boundary (an
+opaque handle the engine made: device arrays, ``state_bytes`` each),
+``lookup_state`` returns pages only down to the deepest boundary that has
+one, with it, and reports how deep the prompt *matched* — where a later
+prompt's prefill should leave a snapshot so that the next one can hit.
+Snapshots are capped at ``capacity_states`` (LRU among the nodes that have
+one; the node and its page stay) and go with their node on eviction;
+``stats()`` counts them and their bytes beside the pages.
+
 Thread-safety: the GenerationScheduler's worker thread is the only caller;
 a lock still guards acquire/release so `stats()` from other threads is
 consistent.
@@ -76,6 +91,10 @@ class PagedKVPool:
         # scales), surfaced through stats() for the monitor's kv-pool row.
         self.storage_dtype = str(storage_dtype)
         self.row_bytes = int(row_bytes)
+        # device bytes of the fixed per-slot state rows beside the pages (a
+        # model with conv or recurrent layers; the engine sets it), scratch
+        # row included
+        self.state_bytes = 0
         self._lock = threading.Lock()
         # LIFO free lists: hottest pages get reused first (best for any
         # future device-side page cache locality)
@@ -197,22 +216,36 @@ class PagedKVPool:
                 "slot_occupancy": slots / float(self.max_slots),
                 "storage_dtype": self.storage_dtype,
                 "resident_bytes": self.row_bytes * self.n_pages * self.page_size,
+                "state_bytes": self.state_bytes,
             }
 
 
 class _PrefixNode:
-    __slots__ = ("page", "stamp")
+    __slots__ = ("page", "stamp", "state")
 
-    def __init__(self, page, stamp):
+    def __init__(self, page, stamp, state=None):
         self.page = page
         self.stamp = stamp
+        self.state = state  # the sequence state at this boundary, if kept
 
 
 class PrefixCache:
     """Prompt-token trie over immutable full KV pages (module docstring)."""
 
-    def __init__(self, pool, capacity_pages=None):
+    def __init__(self, pool, capacity_pages=None, state_bytes=0,
+                 capacity_states=None):
         self.pool = pool
+        # state_bytes > 0: the model keeps a fixed per-sequence state beside
+        # its pages, and only a boundary that carries a snapshot of it can
+        # be hit. The reserve: capacity_states snapshots of state_bytes.
+        self.state_bytes = int(state_bytes)
+        if capacity_states is None:
+            capacity_states = 4 * pool.max_slots if self.state_bytes else 0
+        self.capacity_states = int(capacity_states)
+        self._n_states = 0  # nodes that carry a snapshot now
+        self.states_taken = 0
+        self.states_hit = 0
+        self.states_evicted = 0
         # default cap: the whole pool minus one slot's worst case, so the
         # cache alone can never wedge admission even before eviction runs
         if capacity_pages is None:
@@ -230,17 +263,27 @@ class PrefixCache:
         self.evictions = 0
 
     def lookup(self, prompt):
-        """Page ids for the longest cached prefix of `prompt`, capped so at
-        least the final prompt token is always prefilled (its hidden state
-        produces the first sampled logits). Each returned page is PINNED
-        (+1 reference) so an eviction between lookup and acquire can never
-        free it — the caller unpins once acquire() has taken the slot's own
-        reference (or on admission failure). Counters feed the
-        gen/prefix_hit_rate telemetry."""
+        """lookup_state()'s pages alone: all a model without a state needs."""
+        return self.lookup_state(prompt)[0]
+
+    def lookup_state(self, prompt):
+        """(pages, state, matched): page ids for the longest cached prefix of
+        `prompt`, capped so at least the final prompt token is always
+        prefilled (its hidden state produces the first sampled logits). Each
+        returned page is PINNED (+1 reference) so an eviction between lookup
+        and acquire can never free it — the caller unpins once acquire() has
+        taken the slot's own reference (or on admission failure). Counters
+        feed the gen/prefix_hit_rate telemetry.
+
+        With a per-sequence state (state_bytes > 0) the pages stop at the
+        deepest boundary that carries a snapshot, `state` is that snapshot
+        (None without a hit) and `matched` >= len(pages) is how many pages
+        of the prompt the trie holds at all: a boundary other prompts share,
+        where this prompt's prefill should leave a snapshot."""
         ps = self.pool.page_size
         prompt = tuple(int(t) for t in prompt)
         eligible = (len(prompt) - 1) // ps
-        pages = []
+        pages, state = [], None
         with self._lock:
             self._clock += 1
             for i in range(eligible):
@@ -249,6 +292,16 @@ class PrefixCache:
                     break
                 node.stamp = self._clock
                 pages.append(node.page)
+            matched = len(pages)
+            if self.state_bytes:
+                usable = 0
+                for i in range(matched, 0, -1):
+                    node = self._nodes[prompt[: i * ps]]
+                    if node.state is not None:
+                        usable, state = i, node.state
+                        self.states_hit += 1
+                        break
+                del pages[usable:]
             self.pages_eligible += eligible
             self.pages_hit += len(pages)
             if pages:
@@ -257,13 +310,16 @@ class PrefixCache:
                 self.misses += 1
         if pages:
             self.pool.pin_pages(pages)
-        return pages
+        return pages, state, matched
 
-    def insert(self, prompt, table):
+    def insert(self, prompt, table, states=None):
         """Publish a finished prefill's full prompt pages into the trie.
         Valid by the immutability invariant: pages 0..len(prompt)//ps - 1
         hold exactly the prompt tokens' K/V and nothing ever rewrites
-        them. Already-cached depths are left alone."""
+        them. Already-cached depths are left alone, but for a snapshot they
+        lack: `states` is {tokens: the sequence state after that many prompt
+        tokens}, what this prefill left at its page-aligned chunk ends."""
+        states = states or {}
         ps = self.pool.page_size
         prompt = tuple(int(t) for t in prompt)
         n_full = len(prompt) // ps
@@ -272,8 +328,14 @@ class PrefixCache:
             self._clock += 1
             for i in range(n_full):
                 key = prompt[: (i + 1) * ps]
+                state = states.get((i + 1) * ps)
                 if key in self._nodes:
-                    self._nodes[key].stamp = self._clock
+                    node = self._nodes[key]
+                    node.stamp = self._clock
+                    if state is not None and node.state is None:
+                        node.state = state
+                        self.states_taken += 1
+                        self._n_states += 1
                     continue
                 if len(self._nodes) >= self.capacity_pages:
                     if not self._evict_locked(1):
@@ -282,9 +344,24 @@ class PrefixCache:
                 if page == SCRATCH_PAGE:
                     break
                 self.pool.pin_pages([page])
-                self._nodes[key] = _PrefixNode(page, self._clock)
+                self._nodes[key] = _PrefixNode(page, self._clock, state)
+                if state is not None:
+                    self.states_taken += 1
+                    self._n_states += 1
                 added += 1
+            if self._n_states > self.capacity_states:
+                self._cap_states_locked()
         return added
+
+    def _cap_states_locked(self):
+        """Drop the coldest snapshots beyond capacity_states; their nodes
+        and pages stay (a prompt can still match through them)."""
+        kept = [n for n in self._nodes.values() if n.state is not None]
+        over = len(kept) - self.capacity_states
+        for node in sorted(kept, key=lambda n: n.stamp)[:max(over, 0)]:
+            node.state = None
+            self.states_evicted += 1
+            self._n_states -= 1
 
     def evict_for(self, n_pages):
         """Free up to `n_pages` unreferenced cached pages (LRU). Returns the
@@ -312,6 +389,9 @@ class PrefixCache:
                 continue  # has live descendants; they sort earlier anyway
             del self._nodes[key]
             self.pool.unpin_pages([node.page])
+            if node.state is not None:
+                self.states_evicted += 1
+                self._n_states -= 1
             self.evictions += 1
             evicted += 1
         return evicted
@@ -332,12 +412,20 @@ class PrefixCache:
                 self.pool.unpin_pages([node.page])
             n = len(self._nodes)
             self._nodes.clear()
+            self._n_states = 0
             return n
 
     def stats(self):
         with self._lock:
             elig = self.pages_eligible
+            n_states = self._n_states
             return {
+                "cached_states": n_states,
+                "state_bytes": n_states * self.state_bytes,
+                "state_bytes_reserved": self.capacity_states * self.state_bytes,
+                "states_taken": self.states_taken,
+                "states_hit": self.states_hit,
+                "states_evicted": self.states_evicted,
                 "cached_pages": len(self._nodes),
                 "capacity_pages": self.capacity_pages,
                 "lookups_hit": self.hits,

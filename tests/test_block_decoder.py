@@ -1,0 +1,267 @@
+"""The block-specified decoder (models/block_decoder.py) as the lfm2_moe
+architecture at a tiny size, through GenerationEngine's caches, against the
+plain reference (models/lfm2_moe_reference.py) on seeded random weights:
+logits, not tokens."""
+
+import numpy as np
+import pytest
+
+from paddle_tpu.executor import Executor, Scope, scope_guard
+from paddle_tpu.models import lfm2_moe_reference as ref
+from paddle_tpu.models.block_decoder import BlockDecoder, lfm2_moe
+from paddle_tpu.serving.generation import (
+    GenerationEngine, GenerationScheduler, GenRequest)
+
+# the published layer pattern in miniature: a leading dense conv layer, then
+# [full_attention, conv, conv] periods; 4 query heads on 2 KV heads; 8
+# experts, 2 a token
+TINY = dict(
+    vocab_size=97, hidden_size=32, intermediate_size=48,
+    moe_intermediate_size=24, num_attention_heads=4, num_key_value_heads=2,
+    num_hidden_layers=5, num_dense_layers=1,
+    layer_types=["conv", "full_attention", "conv", "conv", "full_attention"],
+    num_experts=8, num_experts_per_tok=2, use_expert_bias=True,
+    norm_topk_prob=True, routed_scaling_factor=1.0, conv_L_cache=3,
+    norm_eps=1e-5, rope_theta=1e6, max_position_embeddings=64)
+F32_TOL = 2e-5  # float32 both sides; the products differ in summation order
+# bfloat16 weights, activations and caches against float32 of the same
+# weights: ~2^-9 relative a rounding, a few dozen roundings deep, on logits
+# of about 0.7
+BF16_BAND = 2e-2
+
+
+def _engine(dtype="float32", seed=3, **kw):
+    model = lfm2_moe(TINY, max_context=64, dtype=dtype)
+    kw = dict(dict(max_slots=3, page_size=4, prefill_chunk=8), **kw)
+    eng = GenerationEngine(model, scope=Scope(seed=seed), **kw)
+    eng.record_picks = True
+    return model, eng
+
+
+@pytest.fixture(scope="module")
+def served():
+    return _engine()
+
+
+def _weights(model, eng):
+    return {n: eng.scope.vars[n] for n in model.param_names()}
+
+
+def _picks(run, n):
+    """The run's picks by position, [n_moe, n, k]."""
+    parts = [p for _, p in sorted(run.picks, key=lambda sp: sp[0])]
+    return np.concatenate(parts, axis=1)[:, :n]
+
+
+def _prompt(n, seed=0):
+    return [int(v) for v in np.random.default_rng(seed).integers(2, 97, n)]
+
+
+def _reference(model, eng, tokens, picks):
+    """Reference logits [t, vocab] following the system's picks; every pick
+    the reference would not have made itself has to be a near tie."""
+    logits, own, gaps = ref.forward(
+        _weights(model, eng), tokens, ref.spec_of(model), picks=picks)
+    assert float(np.max(gaps)) < 1e-4, "a pick the reference's scores do not bear out"
+    return np.asarray(logits)
+
+
+def test_forward_program_matches_the_reference(served):
+    model, eng = served
+    tokens = np.array([_prompt(13, 1), _prompt(13, 2)], np.int64)
+    main, _, feeds, fetches = model.build_forward(2, 13)
+    with scope_guard(eng.scope):
+        logits, picks = Executor().run(
+            main, feed={"fwd_tokens": tokens[:, :, None]}, fetch_list=fetches)
+    picks = np.asarray(picks).reshape(len(model.moe_layers()), 2, 13, -1)
+    for b in range(2):
+        want = _reference(model, eng, tokens[b], picks[:, b])
+        np.testing.assert_allclose(np.asarray(logits)[b], want, atol=F32_TOL)
+
+
+def test_chunked_prefill_then_decode_matches_the_full_forward_pass(served):
+    """21 prompt tokens in chunks of 8, 8 and 5 (the last padded to 8), then
+    six decode steps, all through the pages and the conv state rows: the
+    first-token logits and every step's against the reference's one pass
+    over the whole sequence."""
+    model, eng = served
+    prompt = _prompt(21)
+    run = eng.start(GenRequest(prompt, max_new_tokens=8))
+    got = [np.array(eng.last_prefill_logits)]
+    for _ in range(6):
+        eng.decode_step([run])
+        got.append(np.array(eng.last_logits[run.slot]))
+    tokens = prompt + run.tokens[:6]
+    eng.finish(run)
+    assert [s for s, _ in run.picks[:3]] == [0, 8, 16]
+    want = _reference(model, eng, tokens, _picks(run, len(tokens)))
+    for i, g in enumerate(got):
+        np.testing.assert_allclose(g, want[len(prompt) - 1 + i], atol=F32_TOL)
+
+
+def test_prefix_hit_restores_the_state_and_equals_a_cold_admission():
+    """A prompt admitted through a prefix hit (pages shared, conv state
+    restored from the boundary's snapshot) against the same prompt in an
+    engine that has seen nothing: the same logits to float32 rounding (the
+    hit cuts the chunks elsewhere, so the products sum in another order)."""
+    model, warm = _engine()
+    _, cold = _engine(prefix_cache=False)
+    shared = _prompt(12, 7)
+    first = shared + _prompt(9, 8)
+    second = shared + _prompt(10, 9)
+    third = shared + _prompt(11, 10)
+    for p in (first, second):  # the second leaves the snapshot at 12
+        warm.finish(warm.start(GenRequest(p, max_new_tokens=2)))
+    st = warm.prefix_cache.stats()
+    assert st["states_taken"] >= 2 and st["state_bytes"] == (
+        st["cached_states"] * warm.state_row_bytes)
+    run = warm.start(GenRequest(third, max_new_tokens=4))
+    assert run.picks[0][0] == 12  # prefill began at the shared boundary
+    assert warm.prefix_cache.stats()["states_hit"] == st["states_hit"] + 1
+    run_cold = cold.start(GenRequest(third, max_new_tokens=4))
+    np.testing.assert_allclose(
+        warm.last_prefill_logits, cold.last_prefill_logits, atol=F32_TOL)
+    for _ in range(3):
+        warm.decode_step([run])
+        cold.decode_step([run_cold])
+        np.testing.assert_allclose(
+            warm.last_logits[run.slot], cold.last_logits[run_cold.slot],
+            atol=F32_TOL)
+    assert run.tokens == run_cold.tokens
+
+
+def test_lookup_returns_only_a_boundary_that_has_a_state():
+    """Pages alone are no hit for a model with a state: the first repeat of
+    a shared prefix matches pages, gets none, and cuts its prefill there."""
+    model, eng = _engine()
+    shared = _prompt(12, 7)
+    eng.finish(eng.start(GenRequest(shared + _prompt(9, 8), max_new_tokens=2)))
+    pc = eng.prefix_cache
+    pages, state, matched = pc.lookup_state(shared + _prompt(10, 9))
+    pc.pool.unpin_pages(pages)
+    # chunks of 8: snapshots at 8 and 16, so 2 of the 3 shared pages are usable
+    assert (len(pages), matched) == (2, 3) and state is not None
+    run = eng.admit(GenRequest(shared + _prompt(10, 9), max_new_tokens=2))
+    assert (run.pf_pos, run.cut) == (8, 12)
+    while not eng.prefill_step(run):
+        pass
+    eng.finish(run)
+    pages, state, matched = pc.lookup_state(shared + _prompt(11, 10))
+    pc.pool.unpin_pages(pages)
+    assert (len(pages), matched) == (3, 3) and state is not None
+
+
+def test_state_snapshots_are_capped_evicted_and_counted():
+    model, eng = _engine()
+    pc = eng.prefix_cache
+    pc.capacity_states = 2
+    for seed in range(4):
+        eng.finish(eng.start(GenRequest(_prompt(17, 20 + seed), max_new_tokens=1)))
+    st = pc.stats()
+    assert st["cached_states"] == 2 and st["states_taken"] == 8
+    assert st["states_evicted"] == 6
+    assert st["state_bytes"] == 2 * eng.state_row_bytes
+    assert st["state_bytes_reserved"] == 2 * eng.state_row_bytes
+    pc.evict_for(10 ** 6)
+    st = pc.stats()
+    assert st["cached_states"] == 0 and st["states_evicted"] == 8
+    assert eng.stats()["kv"]["state_bytes"] == eng.seq_state_bytes > 0
+    assert eng.pool.stats()["state_bytes"] == eng.seq_state_bytes
+
+
+def test_decode_step_leaves_idle_and_mid_prefill_states_untouched(served):
+    """A decode step advances one run while a second slot is mid-prefill and
+    a third is idle: neither of their state rows changes, and the
+    mid-prefill run finishes to the same logits as alone."""
+    model, eng = served
+    a = eng.start(GenRequest(_prompt(9, 30), max_new_tokens=8))
+    b = eng.admit(GenRequest(_prompt(19, 31), max_new_tokens=4))
+    assert not eng.prefill_step(b)  # one chunk of three
+    rows = lambda: {n: np.array(eng._state[n]) for n in eng._state_names}
+    before = rows()
+    eng.decode_step([a])
+    after = rows()
+    idle = ({0, 1, 2} - {a.slot, b.slot}).pop()
+    for n in before:
+        for slot in (b.slot, idle):
+            np.testing.assert_array_equal(before[n][slot + 1], after[n][slot + 1])
+        assert (before[n][a.slot + 1] != after[n][a.slot + 1]).any()
+    while not eng.prefill_step(b):
+        pass
+    got = np.array(eng.last_prefill_logits)
+    eng.finish(a)
+    eng.finish(b)
+    alone = eng.start(GenRequest(_prompt(19, 31), max_new_tokens=4))
+    np.testing.assert_allclose(got, eng.last_prefill_logits, atol=F32_TOL)
+    eng.finish(alone)
+
+
+def test_rows_without_a_live_token_are_not_routed(served):
+    """The picks of a decode step: live rows pick experts, every other row
+    the pick `num_experts`; the histograms count live rows only."""
+    model, eng = served
+    a = eng.start(GenRequest(_prompt(5, 40), max_new_tokens=4))
+    eng.decode_step([a])
+    picks = eng.last_picks
+    n_exp = TINY["num_experts"]
+    assert (picks[:, a.slot] < n_exp).all()
+    others = [s for s in range(3) if s != a.slot]
+    assert (picks[:, others] == n_exp).all()
+    eng.finish(a)
+
+
+def test_bf16_stays_in_its_band_of_float32():
+    """bfloat16 weights, activations, pages and conv state against float32
+    of the same weights, following the system's picks."""
+    model, eng = _engine("bfloat16")
+    prompt = _prompt(21)
+    run = eng.start(GenRequest(prompt, max_new_tokens=6))
+    first = np.array(eng.last_prefill_logits)
+    for _ in range(4):
+        eng.decode_step([run])
+    last = np.array(eng.last_logits[run.slot])
+    tokens = prompt + run.tokens[:4]
+    eng.finish(run)
+    assert first.dtype == np.float32
+    assert str(eng._state[eng._state_names[0]].dtype) == "bfloat16"
+    logits, _, gaps = ref.forward(
+        _weights(model, eng), tokens, ref.spec_of(model),
+        picks=_picks(run, len(tokens)))
+    want = np.asarray(logits)
+    # a pick may differ where bf16 activations moved a score past its
+    # neighbour: such a score is within a few 1e-3 of the k-th largest
+    assert float(np.max(gaps)) < 2e-2
+    for got, row in ((first, len(prompt) - 1), (last, len(tokens) - 1)):
+        err = float(np.abs(got - want[row]).max())
+        assert 1e-5 < err < BF16_BAND, err
+
+
+def test_scheduler_serves_concurrent_requests_as_the_serial_engine(served):
+    """Through GenerationScheduler: continuous batching, chunked prefill and
+    the prefix cache give each request the tokens a serial run gives it."""
+    model, eng = served
+    prompts = [_prompt(12, 7) + _prompt(3 + i, 50 + i) for i in range(5)]
+    serial = [eng.generate(p, max_new_tokens=5).tokens for p in prompts]
+    sched = GenerationScheduler(eng)
+    try:
+        futures = [sched.submit(p, max_new_tokens=5) for p in prompts]
+        got = [f.result(120).tokens for f in futures]
+    finally:
+        sched.close()
+    assert got == serial
+    assert eng.prefix_cache.stats()["states_hit"] >= 4
+
+
+def test_the_block_specification_is_part_of_the_cache_key(served):
+    model, eng = served
+    geo = eng.geometry()
+    assert geo["block_spec"]["blocks"][0] == ["conv", "dense"]
+    assert geo["block_spec"]["moe"]["num_experts"] == 8
+    other = BlockDecoder(
+        97, 32, [("attention", "dense")], 4, 2, 8, 48, max_context=64,
+        dtype="float32")
+    assert other.spec() != model.spec()
+    assert [s["kind"] for s in other.cache_specs()] == ["paged", "paged"]
+    kinds = [s["kind"] for s in model.cache_specs()]
+    assert kinds.count("state") == 3 and kinds.count("paged") == 4
+    assert {s["width"] for s in model.cache_specs() if s["kind"] == "paged"} == {16}

@@ -1,0 +1,459 @@
+"""Direct numeric tests of the block decoder's ops (ops/decoder_ops.py)
+against numpy, the precision each must keep, the expert share, and
+grouped-query paged attention (the Pallas kernel in interpret mode and the
+dense lowering) against a dense gather."""
+
+import unittest
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from op_test import OpTest
+from paddle_tpu.ops import registry
+
+
+def _lower(op_type, ins, attrs):
+    """The op's lowering on concrete arrays: {slot: [array]} -> {slot: [array]}."""
+    return registry.get(op_type).lower(None, ins, attrs)
+
+
+def _sigmoid(x):
+    return 1.0 / (1.0 + np.exp(-x))
+
+
+def _silu(x):
+    return x * _sigmoid(x)
+
+
+def _rms_np(x, w, eps, groups=1):
+    shape = x.shape
+    x = x.astype(np.float64).reshape(shape[:-1] + (groups, shape[-1] // groups))
+    y = x / np.sqrt(np.mean(x * x, -1, keepdims=True) + eps) * w
+    return y.reshape(shape)
+
+
+class TestRmsNorm(OpTest):
+    def setUp(self):
+        self.op_type = "rms_norm"
+        x = np.random.randn(6, 32).astype("float32")
+        w = np.random.rand(32).astype("float32") + 0.5
+        self.inputs = {"X": x, "Scale": w}
+        self.attrs = {"epsilon": 1e-5}
+        self.outputs = {"Out": _rms_np(x, w, 1e-5).astype("float32")}
+
+    def test_check_output(self):
+        self.check_output(atol=1e-5)
+
+
+class TestRmsNormPerHead(OpTest):
+    """groups: each head's slice normed on its own with one [d_head] scale."""
+
+    def setUp(self):
+        self.op_type = "rms_norm"
+        x = np.random.randn(5, 4 * 8).astype("float32")
+        w = np.random.rand(8).astype("float32") + 0.5
+        self.inputs = {"X": x, "Scale": w}
+        self.attrs = {"epsilon": 1e-5, "groups": 4}
+        self.outputs = {"Out": _rms_np(x, w, 1e-5, groups=4).astype("float32")}
+
+    def test_check_output(self):
+        self.check_output(atol=1e-5)
+
+
+def _rotary_np(x, pos, n_head, theta):
+    rows = x.shape[0]
+    d = x.shape[1] // n_head
+    inv = theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
+    ang = pos.reshape(rows, 1).astype(np.float64) * inv[None, :]
+    cos = np.concatenate([np.cos(ang)] * 2, -1)[:, None, :]
+    sin = np.concatenate([np.sin(ang)] * 2, -1)[:, None, :]
+    xh = x.astype(np.float64).reshape(rows, n_head, d)
+    rot = np.concatenate([-xh[..., d // 2:], xh[..., : d // 2]], -1)
+    return (xh * cos + rot * sin).reshape(x.shape)
+
+
+class TestRotaryEmbedding(OpTest):
+    def setUp(self):
+        self.op_type = "rotary_embedding"
+        x = np.random.randn(7, 3 * 8).astype("float32")
+        pos = np.array([0, 1, 5, 17, 100, 1000, 2047], "int64")
+        self.inputs = {"X": x, "Pos": pos}
+        self.attrs = {"n_head": 3, "theta": 1e6}
+        self.outputs = {"Out": _rotary_np(x, pos, 3, 1e6).astype("float32")}
+
+    def test_check_output(self):
+        self.check_output(atol=1e-5)
+
+
+class TestSwiglu(OpTest):
+    def setUp(self):
+        self.op_type = "swiglu"
+        g = np.random.randn(4, 10).astype("float32")
+        u = np.random.randn(4, 10).astype("float32")
+        self.inputs = {"X": g, "Y": u}
+        self.outputs = {"Out": (_silu(g.astype(np.float64)) * u).astype("float32")}
+
+    def test_check_output(self):
+        self.check_output(atol=1e-6)
+
+
+def _conv_np(bcz, kern, prev):
+    """(C * conv(B * z), v) for one sequence; prev: the taps - 1 rows of v
+    before it."""
+    d, taps = kern.shape
+    b, c, z = (bcz[:, i * d:(i + 1) * d].astype(np.float64) for i in range(3))
+    v = b * z
+    vv = np.concatenate([prev, v], 0)
+    conv = sum(vv[j:j + len(v)] * kern[:, j] for j in range(taps))
+    return c * conv, vv
+
+
+class TestShortConvWholeSequences(OpTest):
+    """No state: the rows are two sequences of five positions from zeros."""
+
+    def setUp(self):
+        self.op_type = "short_conv"
+        d, t = 6, 5
+        x = np.random.randn(2 * t, 3 * d).astype("float32")
+        kern = np.random.randn(d, 3).astype("float32")
+        self.inputs = {"X": x, "Kernel": kern}
+        self.attrs = {"mode": "chunk", "seq_len": t}
+        zeros = np.zeros((2, d))
+        self.outputs = {"Out": np.concatenate([
+            _conv_np(x[i * t:(i + 1) * t], kern, zeros)[0] for i in range(2)
+        ]).astype("float32")}
+
+    def test_check_output(self):
+        self.check_output(atol=1e-5)
+
+
+class TestShortConvChunk(OpTest):
+    """A chunk of one sequence from position 12 with two rows of padding:
+    reads the state row, writes the state after the last live row."""
+
+    def setUp(self):
+        self.op_type = "short_conv"
+        d, t, live = 6, 8, 6
+        x = np.random.randn(t, 3 * d).astype("float32")
+        kern = np.random.randn(d, 3).astype("float32")
+        state = np.random.randn(4, 2 * d).astype("float32")
+        self.inputs = {
+            "X": x, "Kernel": kern, "State": state,
+            "Rows": np.array([2], "int32"), "Start": np.array([12], "int64"),
+            "Live": (np.arange(t) < live).astype("int32")}
+        self.attrs = {"mode": "chunk"}
+        out, vv = _conv_np(x, kern, state[2].reshape(2, d))
+        new = state.copy()
+        new[2] = vv[live:live + 2].reshape(-1)
+        self.outputs = {"Out": out.astype("float32"), "StateOut": new}
+
+    def test_check_output(self):
+        self.check_output(atol=1e-5)
+
+
+class TestShortConvStep(OpTest):
+    """One position a row, each with its own state row; rows 1 and 3 are
+    idle (the scratch row 0)."""
+
+    def setUp(self):
+        self.op_type = "short_conv"
+        d, rows = 6, 4
+        x = np.random.randn(rows, 3 * d).astype("float32")
+        kern = np.random.randn(d, 3).astype("float32")
+        state = np.random.randn(5, 2 * d).astype("float32")
+        idx = np.array([3, 0, 1, 0], "int32")
+        self.inputs = {"X": x, "Kernel": kern, "State": state, "Rows": idx}
+        self.attrs = {"mode": "step"}
+        out, new = np.zeros((rows, d)), state.copy()
+        for r in (0, 2):
+            o, vv = _conv_np(x[r:r + 1], kern, state[idx[r]].reshape(2, d))
+            out[r], new[idx[r]] = o[0], vv[1:3].reshape(-1)
+        self.outputs = {"Out": out.astype("float32"), "StateOut": new}
+
+    def test_check_output(self):
+        main, startup = self._build()
+        got = self._exe.run(main, feed=self._feed, fetch_list=["Out", "StateOut"])
+        live = [0, 2]
+        np.testing.assert_allclose(
+            np.asarray(got[0])[live], self.outputs["Out"][live], atol=1e-5)
+        # every state row but the scratch row: the idle rows wrote only there
+        np.testing.assert_allclose(
+            np.asarray(got[1])[1:], self.outputs["StateOut"][1:], atol=1e-5)
+
+
+def test_short_conv_does_not_depend_on_the_cut():
+    """A sequence in one chunk, and cut into a chunk, a shorter chunk with
+    padding and three steps, through a bf16 state: the same bits."""
+    rng = np.random.default_rng(5)
+    d, t = 8, 12
+    x = jnp.asarray(rng.standard_normal((t, 3 * d)), jnp.bfloat16)
+    kern = jnp.asarray(rng.standard_normal((d, 3)), jnp.float32)
+    whole = _lower("short_conv", {"X": [x], "Kernel": [kern]},
+                   {"mode": "chunk"})["Out"][0]
+    state = jnp.zeros((3, 2 * d), jnp.bfloat16)
+    outs = []
+    for start, n, rows in ((0, 5, 5), (5, 4, 8)):
+        chunk = jnp.zeros((rows, 3 * d), jnp.bfloat16).at[:n].set(x[start:start + n])
+        got = _lower("short_conv", {
+            "X": [chunk], "Kernel": [kern], "State": [state],
+            "Rows": [jnp.array([1], jnp.int32)],
+            "Start": [jnp.array([start], jnp.int32)],
+            "Live": [(jnp.arange(rows) < n).astype(jnp.int32)]}, {"mode": "chunk"})
+        state = got["StateOut"][0]
+        outs.append(got["Out"][0][:n])
+    for pos in range(9, t):
+        got = _lower("short_conv", {
+            "X": [x[pos:pos + 1]], "Kernel": [kern], "State": [state],
+            "Rows": [jnp.array([1], jnp.int32)]}, {"mode": "step"})
+        state = got["StateOut"][0]
+        outs.append(got["Out"][0])
+    np.testing.assert_array_equal(
+        np.asarray(jnp.concatenate(outs), np.float32), np.asarray(whole, np.float32))
+
+
+def _router_np(x, w, b, k, live=None, norm=True, scale=1.0):
+    s = _sigmoid(x.astype(np.float64) @ w.astype(np.float64))
+    sel = s + (0.0 if b is None else b)
+    picks = np.argsort(-sel, axis=1, kind="stable")[:, :k]
+    g = np.take_along_axis(s, picks, axis=1)
+    if norm:
+        g = g / (g.sum(-1, keepdims=True) + 1e-6)
+    g = g * scale
+    if live is not None:
+        picks = np.where(live[:, None] != 0, picks, w.shape[1])
+        g = np.where(live[:, None] != 0, g, 0.0)
+    return picks.astype("int32"), g.astype("float32")
+
+
+class TestMoeRouter(OpTest):
+    """The bias picks and does not weigh; a row that is not live routes
+    nowhere."""
+
+    def setUp(self):
+        self.op_type = "moe_router"
+        x = np.random.randn(9, 16).astype("float32")
+        w = (np.random.randn(16, 8) * 0.5).astype("float32")
+        b = (np.random.randn(8) * 0.3).astype("float32")
+        live = np.array([1, 1, 0, 1, 1, 1, 0, 1, 1], "int32")
+        self.inputs = {"X": x, "W": w, "Bias": b, "Live": live}
+        self.attrs = {"k": 3, "norm_topk": True, "scale": 1.0}
+        picks, g = _router_np(x, w, b, 3, live)
+        self.outputs = {"Picks": picks, "Weights": g}
+
+    def test_check_output(self):
+        self.check_output(atol=1e-5)
+
+
+def _experts_np(x, picks, g, w1, w3, w2, held):
+    out = np.zeros((x.shape[0], w2.shape[-1]))
+    x = x.astype(np.float64)
+    for r in range(x.shape[0]):
+        for e, ge in zip(picks[r], g[r]):
+            if e in held:
+                j = held.index(e)
+                out[r] += ge * ((_silu(x[r] @ w1[j]) * (x[r] @ w3[j])) @ w2[j])
+    return out
+
+
+def _moe_case(rows=11, d=16, f=12, n_exp=8, k=3, seed=0):
+    rng = np.random.default_rng(seed)
+    x = rng.standard_normal((rows, d)).astype("float32")
+    live = (rng.random(rows) > 0.2).astype("int32")
+    picks, g = _router_np(
+        x, rng.standard_normal((d, n_exp)).astype("float32") * 0.5,
+        rng.standard_normal(n_exp).astype("float32") * 0.3, k, live)
+    w1, w3 = (rng.standard_normal((n_exp, d, f)).astype("float32") * 0.3
+              for _ in range(2))
+    w2 = rng.standard_normal((n_exp, f, d)).astype("float32") * 0.3
+    return x, picks, g, w1, w3, w2
+
+
+class TestMoeExperts(OpTest):
+    def setUp(self):
+        self.op_type = "moe_experts"
+        x, picks, g, w1, w3, w2 = _moe_case()
+        self.inputs = {"X": x, "Picks": picks, "Weights": g,
+                       "W1": w1, "W3": w3, "W2": w2}
+        self.attrs = {"num_experts": 8, "experts_held": []}
+        self.outputs = {"Out": _experts_np(
+            x, picks, g, w1, w3, w2, list(range(8))).astype("float32")}
+
+    def test_check_output(self):
+        self.check_output(atol=1e-4)
+
+
+def test_moe_expert_shares_add_up_to_the_whole_layer():
+    """experts_held: four shares of eight of 32 experts, each op holding only
+    its own experts' weights and routing over all 32; the four partial
+    results add up to the uncut reference's MoE layer (every expert, the
+    plain reference's own router)."""
+    from paddle_tpu.models import lfm2_moe_reference as ref
+
+    rng = np.random.default_rng(3)
+    rows, d, f, n_exp, k = 40, 16, 12, 32, 4
+    x = rng.standard_normal((rows, d)).astype("float32")
+    router_w = rng.standard_normal((d, n_exp)).astype("float32") * 0.5
+    router_b = rng.standard_normal(n_exp).astype("float32") * 0.3
+    w1, w3 = (rng.standard_normal((n_exp, d, f)).astype("float32") * 0.3
+              for _ in range(2))
+    w2 = rng.standard_normal((n_exp, f, d)).astype("float32") * 0.3
+    moe = {"num_experts": n_exp, "k": k, "norm_topk": True, "scale": 1.0}
+    whole, _, _ = ref.moe_layer(
+        jnp.asarray(x), jnp.asarray(router_w), jnp.asarray(router_b),
+        jnp.asarray(w1), jnp.asarray(w3), jnp.asarray(w2), moe)
+    routed = _lower("moe_router", {
+        "X": [jnp.asarray(x)], "W": [jnp.asarray(router_w)],
+        "Bias": [jnp.asarray(router_b)]}, {"k": k, "norm_topk": True})
+    total = np.zeros((rows, d))
+    for share in range(4):
+        held = list(range(share * 8, share * 8 + 8))
+        part = _lower("moe_experts", {
+            "X": [jnp.asarray(x)], "Picks": routed["Picks"],
+            "Weights": routed["Weights"], "W1": [jnp.asarray(w1[held])],
+            "W3": [jnp.asarray(w3[held])], "W2": [jnp.asarray(w2[held])]},
+            {"num_experts": n_exp, "experts_held": held})["Out"][0]
+        assert np.abs(np.asarray(part)).max() > 0.01  # every share gives a part
+        total += np.asarray(part, np.float64)
+    np.testing.assert_allclose(total, np.asarray(whole), atol=2e-4)
+    assert np.abs(np.asarray(whole)).max() > 0.1
+
+
+def test_moe_rows_without_a_live_token_route_nowhere():
+    """A row whose picks are `num_experts` (no expert) is in no group: the
+    groups' sizes count live rows only, and its output is zero."""
+    x, picks, g, w1, w3, w2 = _moe_case(seed=1)
+    dead = np.flatnonzero((picks == 8).all(1))
+    assert len(dead) >= 1
+    out = _lower("moe_experts", {
+        "X": [jnp.asarray(x)], "Picks": [jnp.asarray(picks)],
+        "Weights": [jnp.asarray(g)], "W1": [jnp.asarray(w1)],
+        "W3": [jnp.asarray(w3)], "W2": [jnp.asarray(w2)]},
+        {"num_experts": 8})["Out"][0]
+    assert not np.asarray(out)[dead].any()
+
+
+# ---- precision: the norms and the router run in float32 whatever the dtype
+
+
+def _bf16_ulp(x):
+    return 2.0 ** (np.floor(np.log2(np.maximum(np.abs(x), 1e-30))) - 7)
+
+
+def test_rms_norm_of_bf16_rounds_once():
+    """bf16 in, bf16 out, float32 in between: the result is the exact value
+    rounded once (within 0.51 ulp). A norm that squares, sums or scales in
+    bf16 misses by whole ulps."""
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.standard_normal((16, 256)) * 3.0, jnp.bfloat16)
+    w = jnp.asarray(rng.random(256) + 0.5, jnp.float32)
+    out = _lower("rms_norm", {"X": [x], "Scale": [w]}, {"epsilon": 1e-5})["Out"][0]
+    assert out.dtype == jnp.bfloat16
+    exact = _rms_np(np.asarray(x, np.float32), np.asarray(w), 1e-5)
+    err = np.abs(np.asarray(out, np.float64) - exact)
+    assert (err <= 0.51 * _bf16_ulp(exact)).all()
+    # and the bf16 form of the same computation does miss: the test can fail
+    xb = x * x
+    low = (x * (1.0 / jnp.sqrt(jnp.mean(xb, -1, keepdims=True) + 1e-5)).astype(
+        jnp.bfloat16) * w.astype(jnp.bfloat16))
+    assert (np.abs(np.asarray(low, np.float64) - exact)
+            > 0.51 * _bf16_ulp(exact)).any()
+
+
+def test_router_scores_are_float32_of_a_bf16_input():
+    """bf16 activations in, float32 weights out that agree with the float64
+    router of the same (bf16-rounded) input to 1e-5; the bf16 router of it
+    is a hundred times further off and picks other experts."""
+    rng = np.random.default_rng(1)
+    x = jnp.asarray(rng.standard_normal((64, 128)), jnp.bfloat16)
+    w = jnp.asarray(rng.standard_normal((128, 32)) * 0.1, jnp.float32)
+    b = jnp.asarray(rng.standard_normal(32) * 0.05, jnp.float32)
+    got = _lower("moe_router", {"X": [x], "W": [w], "Bias": [b]},
+                 {"k": 4, "norm_topk": True})
+    picks, g = np.asarray(got["Picks"][0]), got["Weights"][0]
+    assert g.dtype == jnp.float32
+    want_picks, want_g = _router_np(
+        np.asarray(x, np.float32), np.asarray(w), np.asarray(b), 4)
+    same = (np.sort(picks, 1) == np.sort(want_picks, 1)).all(1)
+    assert same.mean() > 0.98  # a tie within float32 rounding may flip a row
+    order = np.argsort(picks, 1)
+    want_order = np.argsort(want_picks, 1)
+    np.testing.assert_allclose(
+        np.take_along_axis(np.asarray(g), order, 1)[same],
+        np.take_along_axis(want_g, want_order, 1)[same], atol=1e-5)
+    low = _router_np(
+        np.asarray((x @ w.astype(jnp.bfloat16)).astype(jnp.float32)),
+        np.eye(32, dtype="float32"), np.asarray(b), 4)  # scores from bf16 logits
+    low_same = (np.sort(low[0], 1) == np.sort(want_picks, 1)).all(1)
+    assert low_same.mean() < same.mean()
+
+
+# ---- grouped-query paged attention
+
+
+def _gqa_np(q, kp, vp, bt, pos, n_head, n_kv, ps):
+    rows, d = q.shape[0], q.shape[1] // n_head
+    out = np.zeros(q.shape, np.float64)
+    for r in range(rows):
+        table = bt if bt.ndim == 1 else bt[r]
+        n = int(pos[r]) + 1
+        if n <= 0:
+            continue
+        idx = [table[p // ps] * ps + p % ps for p in range(n)]
+        k = kp[idx].reshape(n, n_kv, d).astype(np.float64)
+        v = vp[idx].reshape(n, n_kv, d).astype(np.float64)
+        for h in range(n_head):
+            j = h // (n_head // n_kv)
+            s = k[:, j] @ q[r, h * d:(h + 1) * d] * d ** -0.5
+            w = np.exp(s - s.max())
+            out[r, h * d:(h + 1) * d] = (w / w.sum()) @ v[:, j]
+    return out
+
+
+def _gqa_case(per_row, dtype, n_head=8, n_kv=2, d=16, ps=16, n_pages=12, seed=0):
+    rng = np.random.default_rng(seed)
+    pool = lambda: np.asarray(jnp.asarray(
+        rng.standard_normal((n_pages * ps, n_kv * d)), dtype), np.float32)
+    kp, vp = pool(), pool()
+    if per_row:
+        rows = 5
+        bt = np.zeros((rows, 4), np.int32)
+        bt[:, :2] = rng.permutation(np.arange(1, n_pages))[:rows * 2].reshape(rows, 2)
+        pos = np.array([0, 5, 17, 31, -1], np.int32)
+    else:
+        rows = 8
+        bt = np.array([3, 7, 2, 0], np.int32)
+        pos = np.arange(20, 20 + rows).astype(np.int32)
+    q = np.asarray(jnp.asarray(
+        rng.standard_normal((rows, n_head * d)), dtype), np.float32)
+    return q, kp, vp, bt, pos, n_head, n_kv, ps
+
+
+@pytest.mark.parametrize("per_row", [True, False], ids=["decode", "chunk"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("path", ["kernel", "dense"])
+def test_gqa_paged_attention_against_a_dense_gather(per_row, dtype, path):
+    """32 query heads on 8 KV heads in miniature (8 on 2): pools n_kv_head *
+    d_head wide; the Pallas walk in interpret mode and the dense lowering
+    both agree with a per-row gather in numpy."""
+    from paddle_tpu import flags
+
+    q, kp, vp, bt, pos, n_head, n_kv, ps = _gqa_case(per_row, jnp.dtype(dtype))
+    want = _gqa_np(q, kp, vp, bt, pos, n_head, n_kv, ps)
+    prev = flags.get_flags("paged_flash")
+    flags.set_flags({"paged_flash": "on" if path == "kernel" else "off"})
+    try:
+        got = _lower("paged_attention", {
+            "Q": [jnp.asarray(q, dtype)], "KPool": [jnp.asarray(kp, dtype)],
+            "VPool": [jnp.asarray(vp, dtype)], "BlockTable": [jnp.asarray(bt)],
+            "Pos": [jnp.asarray(pos)]},
+            {"n_head": n_head, "n_kv_head": n_kv, "page_size": ps})["Out"][0]
+    finally:
+        flags.set_flags(prev)
+    assert got.dtype == jnp.dtype(dtype)
+    tol = 1e-5 if dtype == "float32" else 8e-3  # one bf16 rounding of |out| < 2
+    np.testing.assert_allclose(np.asarray(got, np.float64), want, atol=tol)
+
+
+if __name__ == "__main__":
+    unittest.main()

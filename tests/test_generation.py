@@ -69,7 +69,7 @@ def test_paged_pool_reuse_and_exhaustion():
     assert pool.stats() == {
         "pages_total": 4, "pages_in_use": 0, "pages_shared": 0,
         "slots_total": 2, "slots_in_use": 0, "slot_occupancy": 0.0,
-        "resident_bytes": 0, "storage_dtype": "float32",
+        "resident_bytes": 0, "storage_dtype": "float32", "state_bytes": 0,
     }
 
 

@@ -55,21 +55,27 @@ def _flash_grad(causal):
 _PAGED = dict(n_head=2, d=64, page=8, pages=4, pool_rows=72)
 # gpt2l_chat's own geometry: 24 slots x a 128-page table, 20 heads of 64
 _PAGED_CELL = dict(n_head=20, d=64, page=8, pages=128, pool_rows=24584)
+# lfm2moe_chat's: 32 slots x a 128-page table of 16-row pages, 32 query heads
+# of 64 on 8 KV heads, bf16 pools 512 wide
+_PAGED_GQA = dict(n_head=32, n_kv=8, d=64, page=16, pages=128,
+                  pool_rows=65552, dtype=bf16)
 
 
 def _paged(quant, per_row, rows, g=_PAGED):
     feat = g["n_head"] * g["d"]
+    dtype = g.get("dtype", f32)
 
     def fn(q, kp, vp, bt, pos, *scales):
         return pk.paged_flash_attention(
             q, kp, vp, bt, pos, n_head=g["n_head"], page_size=g["page"],
             k_scales=scales[0] if quant else None,
-            v_scales=scales[1] if quant else None,
+            v_scales=scales[1] if quant else None, n_kv_head=g.get("n_kv"),
         )
 
-    pool = S((g["pool_rows"], feat), jnp.int8 if quant else f32)
+    pool = S((g["pool_rows"], g.get("n_kv", g["n_head"]) * g["d"]),
+             jnp.int8 if quant else dtype)
     avals = [
-        S((rows, feat), f32), pool, pool,
+        S((rows, feat), dtype), pool, pool,
         S((rows, g["pages"]) if per_row else (g["pages"],), i32),
         S((rows,), i32),
     ]
@@ -149,6 +155,8 @@ FAMILIES = {
     "paged_flash shared gpt2l_chat": _paged(False, False, 32, _PAGED_CELL),
     "paged_flash_int8 decode gpt2l_chat": _paged(True, True, 24, _PAGED_CELL),
     "paged_flash_int8 shared gpt2l_chat": _paged(True, False, 32, _PAGED_CELL),
+    "paged_flash gqa bf16 decode lfm2moe_chat": _paged(False, True, 32, _PAGED_GQA),
+    "paged_flash gqa bf16 shared lfm2moe_chat": _paged(False, False, 128, _PAGED_GQA),
 }
 
 # family -> the lowering's own message, for a family `auto` no longer selects
