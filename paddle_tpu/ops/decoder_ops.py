@@ -21,9 +21,14 @@ Conventions:
     token (``Live`` == 0) get the pick ``num_experts`` — no expert — and
     weight 0. ``moe_experts`` computes the part of ``sum_i g_i FF_i(u)``
     that the experts it holds give (``experts_held``, default all): rows
-    are sorted by expert and ``jax.lax.ragged_dot`` multiplies each group by
-    its own expert's matrices, so the FLOPs follow the routing and not
-    ``num_experts``.
+    are sorted by expert and one Pallas kernel
+    (``pallas_kernels.grouped_expert_ffn``) streams each expert that has rows
+    through VMEM once, gating and down-projecting there, so the bytes and the
+    FLOPs follow the routing and not ``num_experts``. Where the kernel
+    declines (``grouped_ffn_path_taken``: a model or expert width that is not
+    whole lane tiles, a dtype other than bf16 / f32, weights in another dtype
+    than the rows) three ``jax.lax.ragged_dot`` calls do the same with the
+    same roundings.
   * Every op has a shape rule of its own: deriving shapes from the lowering
     (registry.infer_shape) traces it once a layer a variant (PERF.md, PR 26).
 """
@@ -32,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import pallas_kernels as _pk
 from .registry import register
 
 __all__ = []
@@ -230,10 +236,14 @@ def _moe_router(ctx, ins, attrs):
 def _moe_experts(ctx, ins, attrs):
     """The held experts' part of sum_i Weights_i * FF_{Picks_i}(x), with
     FF_e(u) = (silu(u @ W1[e]) * (u @ W3[e])) @ W2[e]. The rows' picks are
-    sorted by expert; each group meets its own expert's matrices in a ragged
-    product, so an expert nobody picked costs nothing and a pick of an
-    expert held elsewhere (or of no expert: a row without a live token) is
-    not computed at all."""
+    sorted by expert; each group meets its own expert's matrices, so an
+    expert nobody picked costs nothing and a pick of an expert held
+    elsewhere (or of no expert: a row without a live token) is not computed
+    at all. The sort, the gather and the weighted scatter are XLA's; the
+    groups' products are the Pallas kernel grouped_expert_ffn, or three
+    ragged_dot calls for the widths and dtypes it declines. Either way every
+    product accumulates in float32, h is rounded once to x's dtype, and the
+    picks are weighed and summed in float32."""
     (x,) = ins["X"]
     (picks,) = ins["Picks"]
     (weights,) = ins["Weights"]
@@ -253,10 +263,14 @@ def _moe_experts(ctx, ins, attrs):
     token = order // k
     sizes = jnp.bincount(local, length=n_held + 1)[:n_held].astype(jnp.int32)
     xs = jnp.take(x, token, axis=0)
-    ragged = lambda a, b: jax.lax.ragged_dot(
-        a, b, sizes, preferred_element_type=_F32)
-    h = (jax.nn.silu(ragged(xs, w1)) * ragged(xs, w3)).astype(x.dtype)
-    y = ragged(h, w2)
+    if _pk.grouped_ffn_path_taken(x.shape[-1], w1.shape[-1], x.dtype, w1.dtype):
+        # no pallas_kernel= scope: its device time reads op:moe_experts
+        y = _pk.grouped_expert_ffn(xs, w1, w3, w2, sizes)
+    else:
+        ragged = lambda a, b: jax.lax.ragged_dot(
+            a, b, sizes, preferred_element_type=_F32)
+        h = (jax.nn.silu(ragged(xs, w1)) * ragged(xs, w3)).astype(x.dtype)
+        y = ragged(h, w2)
     g = weights.reshape(-1)[order].astype(_F32)
     y = jnp.where((sorted_local < n_held)[:, None], y * g[:, None], 0.0)
     out = jnp.zeros((rows, x.shape[-1]), _F32).at[token].add(y)

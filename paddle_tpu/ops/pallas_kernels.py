@@ -48,6 +48,8 @@ __all__ = [
     "paged_flash_attention",
     "paged_flash_path_taken",
     "paged_positions_walked",
+    "grouped_expert_ffn",
+    "grouped_ffn_path_taken",
     "KERNELS_REVISION",
     "fused_layer_norm",
     "fused_layer_norm_grad",
@@ -1476,7 +1478,7 @@ _PAGED_BUFFER_BYTES = 8 * 1024 * 1024
 # Folded into serving/compile_cache.variant_key: an exported serving module
 # embeds the Mosaic kernels it was lowered with, so a cached export must miss
 # when a kernel's lowering changes. Bump it with every such change.
-KERNELS_REVISION = "27-paged-walk-gqa"
+KERNELS_REVISION = "28-moe-grouped"
 
 
 def paged_flash_path_taken(n_q, n_pages, page_size, n_head, d_head):
@@ -1861,6 +1863,199 @@ def paged_flash_attention(q, k_pool, v_pool, block_table, pos, *, n_head,
     if per_row:
         out = out.reshape(rows, group, n_kv, d).transpose(0, 2, 1, 3)
     return out.reshape(rows, feat)
+
+
+# ---------------------------------------------------------------------------
+# grouped expert feed-forward (moe_experts): rows sorted by expert, each
+# touched expert's three matrices streamed through VMEM once
+# ---------------------------------------------------------------------------
+
+# Swept on chip (v5e, jax 0.9.0, PR 28) at lfm2moe_chat's widths: 32 experts
+# of 2048 x 1792, bf16; us a call, three ragged_dot calls first. A decode step
+# of 19 live rows x 4 picks (30 experts touched): 1571 -> 907-922 at every
+# block_f of 256 / 896 / 1792 and every window of 16 / 32 / 64 / 128 rows
+# (725 GB/s of the touched experts' bytes, 88 % of 819: the copies bound it
+# and the MXU hides behind them whatever the window); 32 live rows (31
+# experts) 1618 -> 936-948; 8 (19 experts) 1003 -> 590-603. A 128-row chunk
+# (512 sorted rows, 32 experts): 2798 -> 974 as one 512-row tile, 1009 as four
+# of 128 (35 expert reads). So the smallest tiles that tie: less VMEM, less
+# MXU work.
+#
+# The sorted rows one row tile holds in VMEM beside their f32 result (a
+# decode step brings 128 of them, a prefill chunk 512): every expert whose
+# group lies in the tile is read once for the whole tile.
+_MOE_BLOCK_ROWS = 512
+# The rows one product takes: a group's rows are met a window at a time,
+# from the group's offset rounded down to the sublane tile, the other
+# groups' rows in the window masked out of `h`.
+_MOE_ROW_WINDOW = 32
+_MOE_ROW_ALIGN = 16  # a bf16 sublane tile; whole f32 tiles too
+# what a call's two buffers of the three weight tiles may take of VMEM (128
+# MiB on a v5e core: 256 of lfm2moe_chat's 1792 columns a tile), and what
+# the call asks Mosaic for beyond its blocks
+_MOE_WEIGHT_BUFFER_BYTES = 16 * 1024 * 1024
+_MOE_VMEM_SLACK_BYTES = 16 * 1024 * 1024
+
+
+def grouped_ffn_path_taken(d, f, x_dtype, w_dtype):
+    """EXACT mirror of moe_experts' kernel-vs-ragged_dot decision: the
+    kernel takes bf16 or f32 rows against weights of the same dtype whose
+    model and expert widths are whole lane tiles; jax.lax.ragged_dot is the
+    lowering for everything else. Off-TPU the kernel runs in interpret
+    mode."""
+    x_dtype, w_dtype = jnp.dtype(x_dtype), jnp.dtype(w_dtype)
+    return (
+        x_dtype == w_dtype
+        and x_dtype in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32))
+        and min(int(d), int(f)) > 0
+        and int(d) % _LANES == 0
+        and int(f) % _LANES == 0
+    )
+
+
+def _moe_block_f(d, f, itemsize):
+    """The widest tile of f, in whole lane tiles that divide f, whose three
+    weight tiles fit their VMEM budget twice over."""
+    lanes = f // _LANES
+    for parts in range(1, lanes + 1):
+        if lanes % parts == 0 and (
+            6 * d * (f // parts) * itemsize <= _MOE_WEIGHT_BUFFER_BYTES
+        ):
+            return f // parts
+    return _LANES
+
+
+def _moe_visits(sizes, n_tiles, block_rows):
+    """The (row tile, expert) pairs a call computes, in the order it walks
+    them, from the groups' sizes [held]: offsets [held + 1] of the groups in
+    the sorted rows, and the expert and the row tile of each of the
+    held + n_tiles - 1 grid steps, the count of real ones last. An expert
+    nobody picked is in no pair; the steps past the count repeat the last
+    real pair, whose blocks are resident, so they fetch nothing."""
+    n_held = sizes.shape[0]
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    first = starts // block_rows
+    tiles = jnp.where(sizes > 0, (ends - 1) // block_rows - first + 1, 0)
+    visit_end = jnp.cumsum(tiles)
+    count = visit_end[-1]
+    step = jnp.clip(
+        jnp.arange(n_held + n_tiles - 1, dtype=jnp.int32), 0, count - 1)
+    expert = jnp.minimum(
+        jnp.searchsorted(visit_end, step, side="right"), n_held - 1
+    ).astype(jnp.int32)
+    tile = first[expert] + step - (visit_end - tiles)[expert]
+    offsets = jnp.concatenate([jnp.zeros(1, jnp.int32), ends])
+    return (offsets.astype(jnp.int32), expert,
+            jnp.clip(tile, 0, n_tiles - 1).astype(jnp.int32),
+            count.reshape(1).astype(jnp.int32))
+
+
+def _grouped_ffn_kernel(offs_ref, expert_ref, tile_ref, count_ref, x_ref,
+                        w1_ref, w3_ref, w2_ref, o_ref, *, block_rows, window):
+    """Grid (visit, tile of f). One visit is one expert's rows inside one row
+    tile; o_ref, the tile's f32 result, stays resident over the tile's
+    visits and takes each f tile's partial down-projection."""
+    v, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(v < count_ref[0])
+    def _():
+        e, t = expert_ref[v], tile_ref[v]
+        base = t * block_rows
+        lo = jnp.maximum(offs_ref[e], base) - base
+        hi = jnp.minimum(offs_ref[e + 1], base + block_rows) - base
+
+        @pl.when((j == 0) & ((v == 0) | (tile_ref[jnp.maximum(v - 1, 0)] != t)))
+        def _():
+            o_ref[...] = jnp.zeros_like(o_ref)
+
+        start = lo // _MOE_ROW_ALIGN * _MOE_ROW_ALIGN
+
+        def one_window(i, carry):
+            want = start + i * window
+            at = pl.multiple_of(
+                jnp.minimum(want, block_rows - window), _MOE_ROW_ALIGN)
+            row = at + jax.lax.broadcasted_iota(jnp.int32, (window, 1), 0)
+            x = x_ref[pl.ds(at, window), :]
+            up = lambda w_ref: jnp.dot(
+                x, w_ref[...], preferred_element_type=jnp.float32)
+            h = (jax.nn.silu(up(w1_ref)) * up(w3_ref)).astype(x.dtype)
+            # the window's rows of other groups, and what a window pushed
+            # back from the tile's end shares with the one before it
+            h = jnp.where((row >= jnp.maximum(lo, want)) & (row < hi), h, 0)
+            o_ref[pl.ds(at, window), :] += jnp.dot(
+                h, w2_ref[...], preferred_element_type=jnp.float32)
+            return carry
+
+        jax.lax.fori_loop(0, pl.cdiv(hi - start, window), one_window, 0)
+
+
+def grouped_expert_ffn(xs, w1, w3, w2, sizes, *, block_rows=None,
+                       block_f=None, row_window=None, interpret=None):
+    """y[r] = (silu(xs[r] @ W1[e]) * (xs[r] @ W3[e])) @ W2[e] in float32 for
+    rows sorted by the expert e that takes them: xs [rows, d], w1 / w3
+    [held, d, f], w2 [held, f, d], sizes [held] the groups' row counts in
+    order. Rows past sum(sizes) belong to no expert: their result is
+    unspecified (zero inside a row tile some group reaches, unwritten
+    elsewhere) and the caller drops it. Every product accumulates in float32
+    and h is rounded once, to xs' dtype, as three ragged_dot calls would.
+
+    Each expert with rows is read from HBM once a row tile it reaches, in
+    (d, block_f) and (block_f, d) tiles that the grid's pipeline double
+    buffers; an expert without rows is never fetched (its grid steps name the
+    blocks already resident). xs' tile and its f32 result stay in VMEM for
+    the tile's visits and h never leaves it. The MXU work follows the groups:
+    row_window rows a product."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    rows, d = xs.shape
+    n_held, _, f = w1.shape
+    itemsize = xs.dtype.itemsize
+    tile_rows = -(-max(rows, 1) // _MOE_ROW_ALIGN) * _MOE_ROW_ALIGN
+    block_rows = int(block_rows or min(_MOE_BLOCK_ROWS, tile_rows))
+    window = min(int(row_window or _MOE_ROW_WINDOW), block_rows)
+    block_f = int(block_f or _moe_block_f(d, f, itemsize))
+    n_tiles, f_tiles = pl.cdiv(rows, block_rows), f // block_f
+    if rows < n_tiles * block_rows:
+        xs = jnp.pad(xs, ((0, n_tiles * block_rows - rows), (0, 0)))
+    prefetch = _moe_visits(sizes.astype(jnp.int32), n_tiles, block_rows)
+    _note_dispatch("moe_grouped")
+
+    def f_tile(v, j, count_r):
+        # a step past the last real visit keeps the last step's tile
+        return jnp.where(v < count_r[0], j, f_tiles - 1)
+
+    up_spec = pl.BlockSpec(
+        (None, d, block_f),
+        lambda v, j, offs_r, e_r, t_r, n_r: (e_r[v], 0, f_tile(v, j, n_r)))
+    down_spec = pl.BlockSpec(
+        (None, block_f, d),
+        lambda v, j, offs_r, e_r, t_r, n_r: (e_r[v], f_tile(v, j, n_r), 0))
+    row_spec = pl.BlockSpec(
+        (block_rows, d), lambda v, j, offs_r, e_r, t_r, n_r: (t_r[v], 0))
+    vmem = (
+        6 * d * block_f * itemsize  # two buffers of the three weight tiles
+        + 2 * block_rows * d * (itemsize + 4)  # xs and the f32 result
+        + _MOE_VMEM_SLACK_BYTES
+    )
+    out = pl.pallas_call(
+        functools.partial(
+            _grouped_ffn_kernel, block_rows=block_rows, window=window),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(n_held + n_tiles - 1, f_tiles),
+            in_specs=[row_spec, up_spec, up_spec, down_spec],
+            out_specs=row_spec,
+        ),
+        out_shape=jax.ShapeDtypeStruct(
+            (n_tiles * block_rows, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=vmem,
+        ),
+        interpret=interpret,
+    )(*prefetch, xs, w1, w3, w2)
+    return out[:rows]
 
 
 # ---------------------------------------------------------------------------

@@ -200,14 +200,17 @@ class _SlotRun:
 
 
 class _Variant:
-    __slots__ = ("fn", "ro", "mut_names", "feed_names", "avals")
+    __slots__ = ("fn", "ro", "mut_names", "feed_names", "avals",
+                 "moe_kernel_layers")
 
-    def __init__(self, fn, ro, mut_names, feed_names, avals):
+    def __init__(self, fn, ro, mut_names, feed_names, avals,
+                 moe_kernel_layers=0):
         self.fn = fn
         self.ro = ro
         self.mut_names = mut_names
         self.feed_names = feed_names
         self.avals = avals
+        self.moe_kernel_layers = moe_kernel_layers
 
 
 class _Sum:
@@ -260,6 +263,20 @@ def cache_specs(model):
         for pair in getattr(model, "kv_scale_names", lambda: [])()
         for n in pair]
     return specs
+
+
+def _moe_kernel_layers(block):
+    """How many of a program's moe_experts ops lower to the Pallas grouped
+    kernel, by the mirror of the op's own decision (an exported variant out
+    of the cache is never traced, so KERNEL_DISPATCHES cannot say)."""
+    n = 0
+    for op in block.ops:
+        if op.type == "moe_experts":
+            x = block._var_recursive(op.inputs["X"][0])
+            w1 = block._var_recursive(op.inputs["W1"][0])
+            n += bool(_pk.grouped_ffn_path_taken(
+                x.shape[-1], w1.shape[-1], x.dtype, w1.dtype))
+    return n
 
 
 def _experts_touched(picks):
@@ -532,6 +549,13 @@ class GenerationEngine:
             "step",
             buckets=EXPERT_BUCKETS,
         )
+        self._m_moe_kernel_layers = reg.histogram(
+            p + "/gen_moe_kernel_layers",
+            "expert layers of one decode step whose grouped product is the "
+            "Pallas kernel (ops.pallas_kernels.grouped_expert_ffn) and not "
+            "jax.lax.ragged_dot",
+            buckets=EXPERT_BUCKETS,
+        )
         # precision label for the monitor's serve rows: 0 = fp32 pools,
         # 1 = int8 pools (tools/monitor.py maps it back to a string)
         self._m_precision = reg.gauge(
@@ -664,7 +688,8 @@ class GenerationEngine:
             lambda feeds, ro_, mut_, _call=exported.call: _call(feeds, ro_, mut_),
             donate_argnums=(2,),
         ).lower(avals, ro, {n: self._state[n] for n in mut}).compile()
-        return _Variant(fn, ro, sorted(mut), list(feed_names), avals)
+        return _Variant(fn, ro, sorted(mut), list(feed_names), avals,
+                        _moe_kernel_layers(block))
 
     def warmup(self):
         """Precompile the decode step and every prefill bucket. Returns the
@@ -1006,6 +1031,7 @@ class GenerationEngine:
         self._m_steps.inc()
         if picks is not None:
             self._note_picks(picks, runs)
+            self._m_moe_kernel_layers.observe(variant.moe_kernel_layers)
         with self._phase("sample", "decode"):
             for run in runs:
                 run.next_pos += 1
@@ -1126,8 +1152,9 @@ class GenerationEngine:
             "kernel_dispatches": {
                 k: v
                 for k, v in _pk.KERNEL_DISPATCHES.items()
-                if k in ("paged_flash", "paged_flash_int8", "gemm_dbuf",
-                         "gemm_epilogue", "gemm_int8", "gemm_fp8")
+                if k in ("paged_flash", "paged_flash_int8", "moe_grouped",
+                         "gemm_dbuf", "gemm_epilogue", "gemm_int8",
+                         "gemm_fp8")
             },
             "kv": {
                 "dtype": self.kv_dtype,

@@ -265,3 +265,48 @@ def test_the_block_specification_is_part_of_the_cache_key(served):
     kinds = [s["kind"] for s in model.cache_specs()]
     assert kinds.count("state") == 3 and kinds.count("paged") == 4
     assert {s["width"] for s in model.cache_specs() if s["kind"] == "paged"} == {16}
+
+
+# a width the grouped kernel takes (whole lane tiles): two expert layers
+WIDE = dict(
+    TINY, hidden_size=128, intermediate_size=128, moe_intermediate_size=128,
+    num_hidden_layers=3, layer_types=["conv", "full_attention", "conv"])
+
+
+@pytest.mark.parametrize("config, layers", [(WIDE, 2), (TINY, 0)],
+                         ids=["whole lane tiles", "declined"])
+def test_decode_step_observes_the_expert_layers_on_the_grouped_kernel(
+        config, layers):
+    """gen_moe_kernel_layers: the decode variant's moe_experts ops that lower
+    to ops.pallas_kernels.grouped_expert_ffn, observed a decode step: every
+    expert layer where the widths are whole lane tiles, none where the op
+    declines to jax.lax.ragged_dot; the engine's mirror agrees with what
+    the trace dispatched, and stats() names the family."""
+    from paddle_tpu.observability import registry
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    name = "moegen%d" % layers
+    model = lfm2_moe(config, max_context=64, dtype="float32")
+    assert len(model.moe_layers()) == (layers or 4)
+    eng = GenerationEngine(model, name=name, scope=Scope(seed=5), max_slots=3,
+                           page_size=4, prefill_chunk=8)
+    run = eng.start(GenRequest(_prompt(5, 7), max_new_tokens=4))
+    # the prompt's chunk traced its own; the decode variant is traced next
+    pk.KERNEL_DISPATCHES.pop("moe_grouped", None)
+    for _ in range(2):
+        eng.decode_step([run])
+    eng.finish(run)
+    assert pk.KERNEL_DISPATCHES.get("moe_grouped", 0) == layers
+    assert eng.stats()["kernel_dispatches"].get("moe_grouped", 0) == layers
+    h = registry.default_registry().snapshot()[
+        "serving/%s/gen_moe_kernel_layers" % name]
+    assert (h["count"], h["sum"]) == (2, 2 * layers)
+    # the wide decoder through the kernel is the reference's decoder
+    if layers:
+        tokens = _prompt(5, 7) + run.tokens[:2]
+        eng.record_picks = True
+        again = eng.start(GenRequest(tokens, max_new_tokens=2))
+        want = _reference(model, eng, tokens, _picks(again, len(tokens)))
+        np.testing.assert_allclose(
+            eng.last_prefill_logits, want[len(tokens) - 1], atol=1e-4)
+        eng.finish(again)

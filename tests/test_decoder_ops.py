@@ -325,12 +325,148 @@ def test_moe_rows_without_a_live_token_route_nowhere():
     x, picks, g, w1, w3, w2 = _moe_case(seed=1)
     dead = np.flatnonzero((picks == 8).all(1))
     assert len(dead) >= 1
-    out = _lower("moe_experts", {
+    out = _moe_lower(x, picks, g, w1, w3, w2, 8)
+    assert not np.asarray(out)[dead].any()
+
+
+# ---- the grouped kernel (ops.pallas_kernels.grouped_expert_ffn, interpret mode)
+
+
+def _moe_lower(x, picks, g, w1, w3, w2, n_exp, held=None):
+    attrs = {"num_experts": n_exp}
+    if held is not None:
+        attrs["experts_held"] = held
+    return _lower("moe_experts", {
         "X": [jnp.asarray(x)], "Picks": [jnp.asarray(picks)],
         "Weights": [jnp.asarray(g)], "W1": [jnp.asarray(w1)],
-        "W3": [jnp.asarray(w3)], "W2": [jnp.asarray(w2)]},
-        {"num_experts": 8})["Out"][0]
-    assert not np.asarray(out)[dead].any()
+        "W3": [jnp.asarray(w3)], "W2": [jnp.asarray(w2)]}, attrs)["Out"][0]
+
+
+def _kernel_case(name):
+    """(x, picks, g, w1, w3, w2, n_exp) at widths the kernel takes."""
+    rows, d, f, n_exp, k = {
+        # a decode step: 32 rows x 4 picks = 128 sorted rows, one row tile
+        "decode": (32, 128, 256, 32, 4),
+        "one expert for every row": (32, 128, 256, 32, 4),
+        # a chunk: 160 x 4 = 640 sorted rows, two row tiles of 512, and
+        # groups of ~80 rows: three 32-row windows each
+        "chunk": (160, 128, 128, 8, 4),
+    }[name]
+    x, picks, g, w1, w3, w2 = _moe_case(rows, d, f, n_exp, k, seed=len(name))
+    if name == "one expert for every row":
+        live = picks[:, 0] < n_exp
+        picks[live, 0] = np.where((picks[live, 1:] == 5).any(1), picks[live, 0], 5)
+        assert ((picks == 5).sum(1) == 1)[live].all()
+    return x, picks, g, w1 * 0.3, w3 * 0.3, w2 * 0.3, n_exp
+
+
+@pytest.mark.parametrize("name", ["decode", "one expert for every row", "chunk"])
+def test_moe_grouped_kernel_matches_the_per_expert_sum(name):
+    """Dead rows, experts nobody picked, one expert picked by every live
+    row, and groups that straddle row tiles and take several row windows:
+    the kernel's result is the plain per-expert sum."""
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    x, picks, g, w1, w3, w2, n_exp = _kernel_case(name)
+    dead = np.flatnonzero((picks == n_exp).all(1))
+    unpicked = set(range(n_exp)) - set(picks.ravel())
+    assert len(dead) >= 1
+    if name == "decode":
+        assert unpicked  # an expert without rows is in no grid step's work
+    pk.KERNEL_DISPATCHES.pop("moe_grouped", None)
+    out = np.asarray(_moe_lower(x, picks, g, w1, w3, w2, n_exp))
+    assert pk.KERNEL_DISPATCHES.get("moe_grouped") == 1
+    want = _experts_np(x, picks, g, w1, w3, w2, list(range(n_exp)))
+    assert np.abs(want).max() > 0.1
+    np.testing.assert_allclose(out, want, atol=1e-4)
+    assert not out[dead].any()
+
+
+@pytest.mark.parametrize("block_rows, window", [(32, 16), (64, 64), (128, 32)])
+def test_moe_grouped_kernel_tiles_rows_without_changing_the_result(
+        block_rows, window):
+    """Groups cut by row tiles of every size, windows narrower than a group
+    and as wide as the tile: the same sums."""
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    rng = np.random.default_rng(block_rows)
+    sizes = np.array([0, 37, 5, 0, 70, 1, 48, 0], np.int32)  # 161 of 200 rows
+    xs = rng.standard_normal((200, 128)).astype("float32")
+    w1, w3 = (rng.standard_normal((8, 128, 256)).astype("float32") * 0.1
+              for _ in range(2))
+    w2 = rng.standard_normal((8, 256, 128)).astype("float32") * 0.1
+    got = np.asarray(pk.grouped_expert_ffn(
+        jnp.asarray(xs), jnp.asarray(w1), jnp.asarray(w3), jnp.asarray(w2),
+        jnp.asarray(sizes), block_rows=block_rows, block_f=128,
+        row_window=window))
+    assert got.shape == (200, 128) and got.dtype == np.float32
+    e = np.repeat(np.arange(8), sizes)
+    u = xs[:161].astype(np.float64)
+    want = np.stack([
+        (_silu(u[r] @ w1[e[r]]) * (u[r] @ w3[e[r]])) @ w2[e[r]]
+        for r in range(161)])
+    np.testing.assert_allclose(got[:161], want, atol=1e-4)
+
+
+def test_moe_expert_shares_add_up_through_the_grouped_kernel():
+    """experts_held at the kernel's widths: four shares of eight experts,
+    each holding only its own weights; a pick of an expert held elsewhere is
+    in no group, and the four parts are the whole layer."""
+    x, picks, g, w1, w3, w2, n_exp = _kernel_case("decode")
+    whole = _experts_np(x, picks, g, w1, w3, w2, list(range(n_exp)))
+    total = np.zeros_like(whole)
+    for share in range(4):
+        held = list(range(share * 8, share * 8 + 8))
+        part = np.asarray(_moe_lower(
+            x, picks, g, w1[held], w3[held], w2[held], n_exp, held), np.float64)
+        np.testing.assert_allclose(
+            part, _experts_np(x, picks, g, w1[held], w3[held], w2[held], held),
+            atol=1e-4)
+        total += part
+    np.testing.assert_allclose(total, whole, atol=2e-4)
+
+
+def test_moe_grouped_kernel_in_bf16_rounds_h_and_the_result_only():
+    """bf16 in, bf16 out: float32 products, h rounded once to bf16, the
+    picks weighed and summed in float32, one last rounding. Against that
+    computation in float64 the result is within one bf16 ulp."""
+    x, picks, g, w1, w3, w2, n_exp = _kernel_case("decode")
+    b = lambda a: jnp.asarray(a, jnp.bfloat16)
+    out = _moe_lower(b(x), picks, g, b(w1), b(w3), b(w2), n_exp)
+    assert out.dtype == jnp.bfloat16
+    xb, w1b, w3b, w2b = (np.asarray(b(a), np.float64) for a in (x, w1, w3, w2))
+    exact = np.zeros(x.shape)
+    for r in range(x.shape[0]):
+        for e, ge in zip(picks[r], g[r]):
+            if e < n_exp:
+                h = _silu(xb[r] @ w1b[e]) * (xb[r] @ w3b[e])
+                h = np.asarray(b(h.astype(np.float32)), np.float64)
+                exact[r] += np.float32(ge) * (h @ w2b[e])
+    err = np.abs(np.asarray(out, np.float64) - exact)
+    # h within float32 rounding of a bf16 tie may round the other way
+    assert (err <= 1.01 * _bf16_ulp(exact)).all()
+    assert (err <= 0.51 * _bf16_ulp(exact)).mean() > 0.99
+
+
+@pytest.mark.parametrize("d, f, dtype", [
+    (128, 192, "float32"), (96, 128, "float32"), (128, 128, "float16")],
+    ids=["f not whole lanes", "d not whole lanes", "a dtype it does not take"])
+def test_moe_shapes_the_kernel_declines_stay_on_ragged_dot(d, f, dtype):
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    assert not pk.grouped_ffn_path_taken(d, f, dtype, dtype)
+    assert pk.grouped_ffn_path_taken(128, 256, "bfloat16", "bfloat16")
+    assert not pk.grouped_ffn_path_taken(128, 256, "bfloat16", "float32")
+    x, picks, g, w1, w3, w2 = _moe_case(12, d, f, 8, 3, seed=2)
+    w1, w3, w2 = w1 * 0.3, w3 * 0.3, w2 * 0.3
+    cast = lambda a: jnp.asarray(a, dtype)
+    pk.KERNEL_DISPATCHES.pop("moe_grouped", None)
+    out = _moe_lower(cast(x), picks, g, cast(w1), cast(w3), cast(w2), 8)
+    assert pk.KERNEL_DISPATCHES.get("moe_grouped", 0) == 0
+    want = _experts_np(x, picks, g, w1, w3, w2, list(range(8)))
+    np.testing.assert_allclose(
+        np.asarray(out, np.float64), want,
+        atol=1e-4 if dtype == "float32" else 5e-2)
 
 
 # ---- precision: the norms and the router run in float32 whatever the dtype

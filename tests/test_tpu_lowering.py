@@ -111,6 +111,16 @@ def _quant_gemm(dtype, m):
     )
 
 
+def _moe_grouped(sorted_rows, d=2048, f=1792, held=32):
+    """lfm2moe_chat's expert layer: 32 experts of 2048 x 1792, bf16; a decode
+    step brings 32 rows x 4 picks, a prefill chunk 128 x 4."""
+    return (
+        pk.grouped_expert_ffn,
+        [S((sorted_rows, d), bf16), S((held, d, f), bf16),
+         S((held, d, f), bf16), S((held, f, d), bf16), S((held,), i32)],
+    )
+
+
 _LN = [S((256, 512), bf16), S((512,), f32), S((256,), f32)]  # x, scale, stat
 
 # family -> (function, avals[, flags to lower it under])
@@ -157,6 +167,9 @@ FAMILIES = {
     "paged_flash_int8 shared gpt2l_chat": _paged(True, False, 32, _PAGED_CELL),
     "paged_flash gqa bf16 decode lfm2moe_chat": _paged(False, True, 32, _PAGED_GQA),
     "paged_flash gqa bf16 shared lfm2moe_chat": _paged(False, False, 128, _PAGED_GQA),
+    "moe_grouped decode lfm2moe_chat": _moe_grouped(128),
+    "moe_grouped chunk lfm2moe_chat": _moe_grouped(512),
+    "moe_grouped two row tiles": _moe_grouped(640, d=256, f=384, held=4),
 }
 
 # family -> the lowering's own message, for a family `auto` no longer selects
