@@ -9,7 +9,7 @@ commit. It drives the two main paths once through the entry points a user
 calls, at the full width of a model the repository supports, and checks what
 comes out by the repository's own means:
 
-  train    the MFU-bench transformer of bench.build_transformer (enc-dec,
+  train    the transformer of build_transformer below (enc-dec,
            4+4 layers, d_model 2048, 16 heads, d_inner 8192, vocab 32000,
            b 8, t 1024, flash attention, bf16 with bf16 Adam moments) under
            pass_pipeline="training_fused" through
@@ -119,15 +119,60 @@ def _cache_entries():
 # --------------------------------------------------------------------------
 
 
+def build_transformer(b=8, t=1024, d=2048, n_layer=4, vocab=32000,
+                      moment_dtype=None):
+    """Build the enc-dec Transformer train step. Returns
+    (main, startup, feed, loss) with the feed already staged on device."""
+    import jax
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu import framework
+    from paddle_tpu.models import transformer as T
+
+    n_head, d_inner = 16, 4 * d
+    main, startup = framework.Program(), framework.Program()
+    with fluid.unique_name.guard():
+        with fluid.program_guard(main, startup):
+            feeds = {}
+            for name, shape, dtype in [
+                ("src_word", [t], "int64"), ("src_pos", [t], "int64"),
+                ("trg_word", [t], "int64"), ("trg_pos", [t], "int64"),
+                ("label", [t], "int64"), ("label_weight", [t, 1], "float32"),
+            ]:
+                feeds[name] = fluid.layers.data(name=name, shape=shape, dtype=dtype)
+            loss, _ = T.transformer(
+                feeds["src_word"], feeds["src_pos"], feeds["trg_word"],
+                feeds["trg_pos"], None, None, None,
+                feeds["label"], feeds["label_weight"],
+                src_vocab_size=vocab, trg_vocab_size=vocab,
+                n_layer=n_layer, n_head=n_head, d_model=d, d_inner=d_inner,
+                d_key=d // n_head, d_value=d // n_head,
+                dropout=0.0, max_length=t + 1, use_flash=True, padded=False,
+            )
+            fluid.optimizer.Adam(
+                learning_rate=1e-4, moment_dtype=moment_dtype
+            ).minimize(loss)
+
+    rng = np.random.RandomState(0)
+    pos = np.tile(np.arange(t), (b, 1)).astype("int64")
+    feed = {
+        "src_word": jax.device_put(rng.randint(0, vocab, (b, t)).astype("int64")),
+        "src_pos": jax.device_put(pos),
+        "trg_word": jax.device_put(rng.randint(0, vocab, (b, t)).astype("int64")),
+        "trg_pos": jax.device_put(pos.copy()),
+        "label": jax.device_put(rng.randint(0, vocab, (b, t)).astype("int64")),
+        "label_weight": jax.device_put(np.ones((b, t, 1), "float32")),
+    }
+    return main, startup, feed, loss
+
+
 def _train_run(cfg, pipeline, n_steps, multistep):
-    """Build the bench transformer afresh, train `n_steps` single-dispatch
+    """Build the train transformer afresh, train `n_steps` single-dispatch
     steps on its fixed batch under `pipeline`, then (multistep > 0) one
     steps_per_run call. Everything it put on the device is dropped before it
     returns."""
     import jax
     import jax.numpy as jnp
 
-    import bench
     import paddle_tpu.fluid as fluid
     from paddle_tpu import flags
     from paddle_tpu.executor import Scope, scope_guard
@@ -135,7 +180,7 @@ def _train_run(cfg, pipeline, n_steps, multistep):
     from paddle_tpu.transpiler.bf16_transpiler import Bf16Transpiler
 
     platform = jax.devices()[0].platform
-    main, startup, feed, loss, _ = bench.build_transformer(
+    main, startup, feed, loss = build_transformer(
         moment_dtype="bfloat16", **cfg
     )
     prev = flags.get_flags("pass_pipeline")
@@ -573,23 +618,6 @@ def phase_kernels():
     finally:
         flags.set_flags(prev)
 
-    # fp8_matmul is a dtype policy over XLA's own matmul, not a Mosaic
-    # kernel. Its dense reference is the bf16 product, held to what rounding
-    # both operands to e4m3 (2^-4 relative each) can move a sum of K terms;
-    # how far it actually moved is reported, because XLA for the v5e leaves
-    # an in-fusion cast to e4m3 unrounded and the two then agree to bf16.
-    kk = 2048
-    x = jnp.asarray(rng.randn(8192, kk) * 0.1, bf16)
-    w = jnp.asarray(rng.randn(kk, 2048) * 0.1, bf16)
-    out = jax.block_until_ready(jax.jit(pk.fp8_matmul)(x, w))
-    ref = jnp.matmul(x, w, preferred_element_type=f32)
-    bound = 4 * 2.0 ** -4 * 0.1 * 0.1 * kk ** 0.5
-    _close("fp8_matmul", out, ref, 0.0, bound)
-    moved = float(jnp.max(jnp.abs(out.astype(f32) - ref)))
-    done["fp8_matmul"] = (
-        "ran (XLA dtype policy, no Mosaic kernel); max |out - bf16 product| "
-        "%.2e, e4m3 rounding allows %.2e" % (moved, bound)
-    )
     say("kernels: ok, %s" % ", ".join(sorted(done)))
     return done
 
@@ -600,7 +628,7 @@ def phase_kernels():
 
 
 def transformer_sharding_rules(n_layer):
-    """SpecLayout roles for the parameters of bench.build_transformer, whose
+    """SpecLayout roles for the parameters of build_transformer, whose
     fc layers are named by position: six to an encoder layer (q, k, v, out,
     ffn up, ffn down), ten to a decoder layer (self and cross attention, then
     the ffn), and the vocabulary projection last."""
@@ -631,14 +659,13 @@ def _chips4_run(cfg, mesh_axes, with_rules):
     parameter and moment is born on chip 0 and resharded by the first step."""
     import jax
 
-    import bench
     import paddle_tpu.fluid as fluid
     from paddle_tpu.executor import Scope, scope_guard
     from paddle_tpu.parallel import MeshConfig
     from paddle_tpu.parallel_executor import BuildStrategy
     from paddle_tpu.transpiler.bf16_transpiler import Bf16Transpiler
 
-    main, startup, feed, loss, _ = bench.build_transformer(
+    main, startup, feed, loss = build_transformer(
         moment_dtype="bfloat16", **cfg
     )
     strat = BuildStrategy()
@@ -774,11 +801,17 @@ def main(argv=None):
               % (args.chips, len(devices)), file=sys.stderr)
         return 2
 
-    import bench
+    from importlib import metadata
+
+    import jaxlib
     import paddle_tpu  # noqa: F401 — places the compile cache
 
-    versions = dict(bench.device_record()["versions"],
-                    python=sys.version.split()[0])
+    try:
+        libtpu = metadata.version("libtpu")
+    except metadata.PackageNotFoundError:
+        libtpu = None
+    versions = {"jax": jax.__version__, "jaxlib": jaxlib.__version__,
+                "libtpu": libtpu, "python": sys.version.split()[0]}
     say("device %s x%d (%s), versions %s" % (
         dev.device_kind, len(devices), dev.platform, json.dumps(versions)))
 

@@ -22,7 +22,7 @@ collapses that machinery into one engine that owns:
 
 A table qualifies as "giant" when its dense optimizer state would not fit one
 chip; `state_bytes_per_device` quantifies that and feeds the embedding/
-gauges (observability registry) and BENCH_recsys.json.
+gauges (observability registry).
 """
 
 import json

@@ -102,12 +102,6 @@ Honored flags:
   epilogue). Same "auto"/"on"/"off" semantics as paged_flash; the dense
   fallback keeps the same wide-accumulate/round-once numerics either way.
   quant_gemm_path_taken mirrors the decision.
-- fp8_matmul: when True, the training matmul/mul lowerings cast floating
-  operands to float8_e4m3fn and contract with f32 accumulation
-  (ops/pallas_kernels.fp8_matmul) — the MXU runs e4m3 pairs at the int8
-  rate (2× bf16). A dtype policy for step-time experiments (the BENCH fp8
-  transformer entry), NOT numerics-preserving: off (default) keeps the
-  native-dtype matmul.
 - data_num_workers: default worker count for the native data runtime
   (paddle_tpu/data/, docs/data.md): PyReader.decorate_* calls that do not
   pass num_workers explicitly use this many multiprocess decode workers;
@@ -194,7 +188,6 @@ _DEFAULTS = {
     "paged_flash": "auto",
     "gemm_double_buffer": "auto",
     "quantized_gemm": "auto",
-    "fp8_matmul": False,
     "data_num_workers": 0,
     "data_ring_slots": 0,
     "data_prefetch": 2,

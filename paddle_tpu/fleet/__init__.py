@@ -7,7 +7,7 @@ tail-latency hedging for idempotent predicts, graceful drain, and a
 staleness gate tied to the PR 15 online-learning repository (a replica is
 routable only once it has landed AND acked the published model version).
 `ReplicaProcess` spawns replicas as real subprocesses so SIGKILL chaos
-(bench.py fleet, tests/test_fleet.py) exercises true process death.
+(tests/test_fleet.py) exercises true process death.
 """
 
 from .breaker import CLOSED, HALF_OPEN, OPEN, CircuitBreaker

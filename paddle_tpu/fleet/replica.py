@@ -3,7 +3,7 @@
 `python -m paddle_tpu.fleet.replica <spec.json>` boots ONE ModelServer from
 a declarative spec and serves until SIGTERM (drain + exit 0) or SIGKILL
 (the chaos case — no goodbye, in-flight requests fail over at the router).
-`ReplicaProcess` is the parent-side handle bench.py and the fleet tests use
+`ReplicaProcess` is the parent-side handle the fleet tests use
 to spawn/kill/restart replicas as real OS processes — a SIGKILLed thread is
 not a thing, so fleet failover can only be exercised with subprocesses.
 

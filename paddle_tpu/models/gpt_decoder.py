@@ -6,8 +6,7 @@ the *three program families* it can emit over one shared parameter set
 pattern):
 
   * ``build_forward``  — whole-sequence causal logits ``[b, t, vocab]``.
-    Used for training, parity tests, and as the naive
-    whole-sequence-per-request serving ablation in ``bench.py generation``.
+    Used for training and as the dense reference of the parity tests.
   * ``build_prefill``  — one prompt *chunk* of bucketed static length ``t``
     (batch 1) starting at an arbitrary position: K/V of every chunk row
     scattered into the paged pool through the slot's page list, then paged

@@ -13,8 +13,8 @@ router's own registry, and merges:
              element-wise count addition yields exactly the histogram a
              single pooled process would have held, and fleet-level
              p50/p99 from `hist_percentile` are bit-equal to percentiles
-             over the pooled raw observations (tested + gated by
-             `bench.py slo`). Mismatched grids are skipped and counted.
+             over the pooled raw observations (tests/test_slo.py).
+             Mismatched grids are skipped and counted.
 
 The merged view is served by the fleet router as `GET /fleet/metrics`
 (exposition text via registry.render_prometheus) and `GET /fleet/stats`

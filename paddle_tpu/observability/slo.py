@@ -9,8 +9,7 @@ both windows burn error budget faster than the rule's factor — the long
 window proves the problem is real, the short window makes the alert
 resolve quickly once the fault clears. Defaults are the Workbook's page
 (5m/1h at 14.4x) and ticket (30m/6h at 6x) rules; windows, factors and the
-clock are injectable so tests and `bench.py slo` run at compressed
-timescales.
+clock are injectable so tests run at compressed timescales.
 
 The engine reads windowed deltas from any history provider with a
 `history(window_s=None) -> [(ts, snapshot)]` method: the fleet-merged

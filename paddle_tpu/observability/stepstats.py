@@ -17,13 +17,11 @@ model, docs/parallelism.md), a single microbatch count m cannot separate
 the per-tick time τ from the fixed overhead c — so the collector keeps
 per-(pp, schedule, m) minimum step times and, once two m groups exist,
 computes τ from the two-m slope and the bubble 1 - m·τ/t(m) for the
-smallest m. This is the SAME estimator bench.py's run_pp_bench uses for
-MULTICHIP_PP.json (measured 0.459 vs analytic 0.429 on the dp2×pp4 bench),
-so the runtime gauge `pp/bubble_measured` is directly comparable to the
-bench number. Until a second m group exists, only the analytic gauge
+smallest m, published as the runtime gauge `pp/bubble_measured`
+(tests/test_observability.py). Until a second m group exists, only the analytic gauge
 `pp/bubble_analytic` = (pp-1)/(m+pp-1) is published. Min-over-steps is the
 right aggregate here: harness noise is one-sided (stalls only ever ADD
-time), the same argument bench.py makes for its min-over-windows headline.
+time).
 """
 
 import sys
@@ -217,7 +215,7 @@ class StepStatsCollector:
         if est is not None:
             self.registry.gauge(
                 "pp/bubble_measured",
-                "two-m-slope runtime bubble (bench.py run_pp_bench estimator)",
+                "two-m-slope runtime bubble",
             ).set(round(max(0.0, min(1.0, est["bubble"])), 4))
 
     def bubble_estimate(self):
@@ -316,7 +314,7 @@ class StepStatsCollector:
         for k, v in deltas.items():
             parts.append("%s=+%d" % (k, v))
         # the "is it alive" line (docs/observability.md): stderr so JSON
-        # emitters on stdout (bench.py, the dist runners) stay parseable
+        # emitters on stdout (chipbench.run, the dist runners) stay parseable
         print("[telemetry] " + " ".join(parts), file=sys.stderr, flush=True)
 
     # ---- introspection --------------------------------------------------

@@ -145,20 +145,6 @@ def _shape(ctx, ins, attrs):
 # ---------------------------------------------------------------------------
 
 
-def _fp8_matmul_taken(x, y):
-    """FLAGS_fp8_matmul dtype policy for the dense matmul lowerings: floating
-    operands contract as float8_e4m3fn with f32 accumulation
-    (pallas_kernels.fp8_matmul). Integer/bool operands keep the native path
-    regardless of the flag."""
-    from .. import flags as _flags
-
-    if not _flags.get_flags("fp8_matmul")["fp8_matmul"]:
-        return False
-    return jnp.issubdtype(x.dtype, jnp.floating) and jnp.issubdtype(
-        y.dtype, jnp.floating
-    )
-
-
 @register("mul")
 def _mul(ctx, ins, attrs):
     (x,) = ins["X"]
@@ -167,12 +153,7 @@ def _mul(ctx, ins, attrs):
     ync = int(attrs.get("y_num_col_dims", 1))
     x2 = x.reshape((int(np.prod(x.shape[:xnc])), -1))
     y2 = y.reshape((int(np.prod(y.shape[:ync])), -1))
-    if _fp8_matmul_taken(x2, y2):
-        from .pallas_kernels import fp8_matmul
-
-        out = fp8_matmul(x2, y2)
-    else:
-        out = x2 @ y2
+    out = x2 @ y2
     out_shape = tuple(x.shape[:xnc]) + tuple(y.shape[ync:])
     return {"Out": [out.reshape(out_shape)]}
 
@@ -191,17 +172,11 @@ def _matmul(ctx, ins, attrs):
         x = jnp.swapaxes(x, -1, -2)
     if ty:
         y = jnp.swapaxes(y, -1, -2)
-    if _fp8_matmul_taken(x, y):
-        from .pallas_kernels import fp8_matmul
-
-        out = fp8_matmul(x, y)
-    else:
-        # out_dtype: the accumulator's dtype kept for the result (bf16
-        # operands, float32 logits) instead of one more rounding
-        out_dtype = attrs.get("out_dtype")
-        out = jnp.matmul(
-            x, y,
-            preferred_element_type=_dtype(out_dtype) if out_dtype else None)
+    # out_dtype: the accumulator's dtype kept for the result (bf16
+    # operands, float32 logits) instead of one more rounding
+    out_dtype = attrs.get("out_dtype")
+    out = jnp.matmul(
+        x, y, preferred_element_type=_dtype(out_dtype) if out_dtype else None)
     if alpha != 1.0:
         out = out * jnp.asarray(alpha, out.dtype)
     return {"Out": [out]}
@@ -1515,8 +1490,7 @@ def _opt_f32(fn):
     dtype. Without the output casts, f32 promotion (the f32 LearningRate)
     silently retypes the written-back state, which both changes training
     numerics and — because the state dtype is part of the compile-cache
-    key — forces a full recompile on the next step (caught by the round-4
-    per-HLO MFU audit, PROFILE.md)."""
+    key — forces a full recompile on the next step."""
 
     @functools.wraps(fn)
     def wrapped(ctx, ins, attrs):

@@ -44,7 +44,6 @@ __all__ = [
     "gemm_dbuf_path_taken",
     "quant_gemm_bias_act",
     "quant_gemm_path_taken",
-    "fp8_matmul",
     "paged_flash_attention",
     "paged_flash_path_taken",
     "paged_positions_walked",
@@ -1431,22 +1430,6 @@ def quant_gemm_bias_act(x2, w2, scale, bias_row=None, act=None, *,
         interpret=interpret,
     )(scale, x2, w2, bias_row)
     return z, y
-
-
-def fp8_matmul(x, y):
-    """Training-matmul fp8 tier (FLAGS_fp8_matmul): cast both operands to
-    float8_e4m3fn, contract on the MXU with f32 accumulation, and return in
-    the input dtype. One rounding per operand plus the output cast — the
-    delayed-scaling recipes keep amax history per tensor; this is the
-    simpler static cast form, enough for the BENCH step-time entry (the MXU
-    runs e4m3×e4m3 at the int8 rate). Shapes are unrestricted: this is a
-    dtype policy, not a kernel, so XLA owns the tiling."""
-    _note_dispatch("matmul_fp8")
-    f8 = jnp.float8_e4m3fn
-    out = jnp.matmul(
-        x.astype(f8), y.astype(f8), preferred_element_type=jnp.float32
-    )
-    return out.astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------

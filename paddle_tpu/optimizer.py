@@ -395,8 +395,7 @@ class AdamOptimizer(Optimizer):
         """moment_dtype="bfloat16" stores BOTH moments in bf16 (beyond the
         reference — the 8-bit-Adam family technique, TPU-style): halves
         optimizer-state memory and its HBM traffic in the fused dW+update
-        tier (the round-4 per-HLO audit measured that traffic at ~0.56 ms
-        per large dW fusion, PROFILE.md). The update itself still computes
+        tier. The update itself still computes
         in f32 (ops/core_ops.py _opt_f32 upcasts state and casts the
         written-back moments to their storage dtype); bias-correction pows
         stay f32. bf16 keeps f32's exponent range, so unlike int8 quantized

@@ -10,10 +10,10 @@ Two sources of the cut, mirroring the reference pipeline optimizer's split
   not run on an earlier stage); unannotated ops inherit the surrounding
   stage.
 
-- ANALYTIC: balance stages by per-op cost from the same counting model as
-  `tools/mfu_audit.py` (dot FLOPs = 2·M·N·K, conv FLOPs = 2·out·Cin·kh·kw,
+- ANALYTIC: balance stages by per-op cost from a counting model
+  (dot FLOPs = 2·M·N·K, conv FLOPs = 2·out·Cin·kh·kw,
   everything else bandwidth-bound at in+out bytes), converted to microseconds
-  against the measured v5e peaks so a matmul-heavy op and a byte-heavy op
+  against the two peaks below so a matmul-heavy op and a byte-heavy op
   land on one scale, plus each op's parameter read bytes (a stage that owns
   more weight bytes pays more HBM traffic per microbatch). The cut minimizes
   the maximum stage weight over the LEGAL cut points the caller provides
@@ -32,7 +32,7 @@ __all__ = [
     "balanced_partition",
 ]
 
-# measured single-chip peaks from tools/mfu_audit.py (v5e bf16 matmul and
+# single-chip peaks of the cost model (a bf16 matmul rate and a
 # large-fusion HBM bandwidth); only their RATIO matters here — the partition
 # is invariant to rescaling both.
 _PEAK_MM_FLOPS_PER_US = 192.0e6  # 192 TFLOP/s
@@ -54,8 +54,7 @@ def analytic_op_flops_bytes(op_type, in_avals, out_avals):
     balances on.
 
     in_avals: {slot: [aval, ...]} of the op's inputs; out_avals likewise.
-    Mirrors HloIndex.instr_flops' counting (tools/mfu_audit.py) at the
-    Program level: dot-family ops get 2·M·N·K, conv gets
+    At the Program level: dot-family ops get 2·M·N·K, conv gets
     2·out_elems·Cin·kh·kw, everything else is bandwidth-bound.
     """
     flat_in = [a for vs in in_avals.values() for a in vs if a is not None]

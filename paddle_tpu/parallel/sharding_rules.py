@@ -29,8 +29,7 @@ Wire behavior falls out of GSPMD (docs/parallelism.md): an fsdp rule on a
 parameter makes its use all-gather and its gradient combine reduce-scatter
 (FSDP); a ("fsdp","tp")/("tp","fsdp") column/row pair on a matmul pair
 makes the partitioner place the tp all-reduce after the second matmul
-(Megatron TP). tools/comm_audit.py cross-checks both against analytic ring
-formulas.
+(Megatron TP).
 """
 
 import re

@@ -445,8 +445,8 @@ class ParallelExecutor:
         (Executor.compiled_hlo analog). Every collective the GSPMD partition
         inserted — the gradient all-reduce, or the ZeRO-1 reduce-scatter /
         all-gather pair under ReduceStrategy.Reduce — is visible here with
-        shapes and replica_groups; tools/comm_audit.py parses this text for
-        the per-collective wire-volume audit. Served from the backend's
+        shapes and replica_groups (tests/test_parallel_executor.py reads the
+        ZeRO-1 gather from this text). Served from the backend's
         compilation cache after a run, so this does not recompile."""
         last = getattr(self, "_last_run", None)
         if last is None:

@@ -268,8 +268,8 @@ def _merge_device_plane_events(planes, events, aux=None):
 
     `aux` (optional dict) additionally collects XLA cost-analysis stats the
     trace carries per instruction — {instr_name: {"flops": f, "bytes": b}} —
-    without changing the 4-element row shape existing callers (mfu_audit,
-    device_op_profile) depend on."""
+    without changing the 4-element row shape existing callers
+    (device_op_profile) depend on."""
     for plane in planes:
         if "TPU" not in plane.name and "GPU" not in plane.name:
             continue
@@ -307,8 +307,8 @@ def _merge_device_plane_events(planes, events, aux=None):
 
 def device_instr_events(log_dir, aux=None):
     """Per-HLO-instruction device timings from an xla_trace log dir:
-    {instr_name: [count, total_ms, min_ms, max_ms]}. Shared base for
-    device_op_profile and tools/mfu_audit.py. Pass a dict as `aux` to also
+    {instr_name: [count, total_ms, min_ms, max_ms]}. The base of
+    device_op_profile. Pass a dict as `aux` to also
     collect per-instruction XLA cost-analysis stats when the trace carries
     them (see _merge_device_plane_events).
 

@@ -148,9 +148,7 @@ class PyReader:
         cleanly, later start() calls replay the cached device arrays through
         the same queue/feeder machinery with the reader, host assembly, and
         the host->device wire all out of the loop. For an image set that
-        fits HBM this removes the wire-bound stage entirely
-        (PIPELINE_KEEPUP.json keep-up evidence; tools/pipeline_probe.py
-        measures the replay rate). Caching keys off the staged arrays, so a
+        fits HBM this removes the wire-bound stage entirely. Caching keys off the staged arrays, so a
         new decorate_* call or a mid-epoch reset() invalidates it."""
         self.feed_names = list(feed_names)
         self.capacity = capacity

@@ -20,8 +20,8 @@ true:
 Cache writes are atomic (tmp + os.replace) and keyed content-addressed, so
 concurrent replicas sharing a cache directory race only to write identical
 bytes. Hit/miss counts ride the PR 4 metric registry
-(`serving/compile_cache/{hits,misses}`), which is how the bench and the CI
-smoke stage assert "zero compilations after warmup".
+(`serving/compile_cache/{hits,misses}`), which is how the tests assert
+"zero compilations after warmup".
 
 This module also owns the `export_compiled` artifact layout (an .npz holding
 the serialized StableHLO plus parameters), folded in from inference.py so

@@ -32,7 +32,7 @@ Every variant builds through the persistent CompileCache with the decode
 state avals and page geometry folded into the key, then AOT-compiles
 (`.lower().compile()`) at warmup — the hot loop calls only precompiled
 executables, so it can never retrace regardless of the prompt/output
-length mix (`stats()["traces"]` is the proof the smoke stage asserts).
+length mix (`stats()["traces"]` is the proof the tests assert).
 Prefill/decode wrappers are jitted with `donate_argnums=(2,)`: the pool
 buffers update in place, verified by input-output aliasing in
 tests/test_generation.py; single-shot serving stays donation-free.

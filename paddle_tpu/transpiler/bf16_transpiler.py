@@ -20,9 +20,8 @@ and cast outputs back, ops/core_ops.py `_opt_f32`). Blacklisted ops AND
 their `_grad` twins are f32 islands: inputs cast up, flipped outputs cast
 back down — so e.g. softmax_with_cross_entropy's backward emits a bf16
 logits-gradient instead of silently pushing f32 into every downstream
-matmul. The round-4 per-HLO audit (PROFILE.md) measured f32-operand matmuls
-at 81-131 TF/s vs 188 TF/s for bf16×bf16 on the bench chip — dtype
-discipline on the backward path is worth ~25% of the whole train step.
+matmul: an f32-operand matmul runs the MXU well under its bf16 rate, so
+dtype discipline on the backward path is part of the train step's speed.
 """
 
 from ..framework import Operator, OpRole

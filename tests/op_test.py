@@ -245,7 +245,7 @@ class OpTest(unittest.TestCase):
         # fresh Scope per evaluation would recompile the program for every
         # perturbed element (thousands of XLA compiles for an RNN op's grad
         # check; measured as the dominant harness cost, and each compile is a
-        # roll of the flaky XLA-CPU-compiler dice — see build_and_test.sh).
+        # roll of the flaky XLA-CPU-compiler dice).
         # The loss program is stateless (inputs are fed, nothing persists),
         # so sharing the scope only shares the compiled executable.
         fwd_main, fwd_loss = self._loss_program()

@@ -5,7 +5,7 @@ threaded variant lives in test_transpiler.py; this one exercises real
 process isolation: separate interpreters, sockets across processes, COMPLETE
 teardown.
 
-Round-4 matrix (VERDICT-3 missing 3): beyond the dense MLP case, the
+Round-4 matrix: beyond the dense MLP case, the
 reference's subprocess family is covered by
 - word2vec embedding cluster (dist_word2vec.py): row-sliced shared embedding
   table across pservers, LOSS PARITY vs a single-process run on the same

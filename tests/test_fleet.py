@@ -2,9 +2,10 @@
 wall time, retry-budget arithmetic, router/replica parity (bit-equal
 outputs + model_version through the proxy), staleness-gated routing against
 a PR 15 model repository, failover on connection reset, breaker
-open/half-open/close under a browned-out replica, hedged first-wins for
-slow primaries, drain-then-stop with zero dropped requests, and SIGKILL
-mid-request failover + rejoin with REAL replica subprocesses."""
+open/half-open/close under a replica that errors or times out, `:generate`
+through the router, hedged first-wins for slow primaries, drain-then-stop
+with zero dropped requests, and SIGKILL mid-request failover + ack-gated
+rejoin with REAL replica subprocesses tracing into one directory."""
 
 import json
 import threading
@@ -260,14 +261,17 @@ def test_conn_reset_fails_over_to_other_replica(mlp_dir):
             s.stop()
 
 
-def test_breaker_opens_on_broken_replica_then_recloses(mlp_dir):
-    """A replica answering 500s trips its breaker (traffic shifts to the
-    healthy one); once it heals, the half-open probe re-closes the breaker
-    and it serves again. No client-visible errors throughout."""
+@pytest.mark.parametrize("fault", ["error_500", "timeout"])
+def test_breaker_opens_on_broken_replica_then_recloses(mlp_dir, fault):
+    """A replica answering 500s, or answering later than the attempt's
+    timeout, trips its breaker (traffic shifts to the healthy one); once it
+    heals, the half-open probe re-closes the breaker and it serves again. No
+    client-visible errors throughout."""
     model_dir, xname = mlp_dir
     servers = [_start_server(model_dir) for _ in range(2)]
     router = Router(
         port=0, hedge=False, probe_interval_s=60.0, seed=3,
+        attempt_timeout_s=0.3 if fault == "timeout" else 5.0,
         breaker_opts=dict(failure_threshold=2, open_for_s=0.05,
                           success_threshold=1),
         retry_budget_ratio=1.0,
@@ -282,6 +286,9 @@ def test_breaker_opens_on_broken_replica_then_recloses(mlp_dir):
         orig_run = eng.run
 
         def broken(feed):
+            if fault == "timeout":
+                time.sleep(1.0)
+                return orig_run(feed)
             raise RuntimeError("injected engine brown-out")
 
         eng.run = broken
@@ -289,7 +296,7 @@ def test_breaker_opens_on_broken_replica_then_recloses(mlp_dir):
         url = "http://127.0.0.1:%d/v1/models/m:predict" % rport
         for _ in range(12):
             code, _out = _post(url, doc)
-            assert code == 200  # failover absorbs every 500
+            assert code == 200  # failover absorbs every failed attempt
         rep0 = router.replicas()["rep0"]
         assert rep0.breaker.stats()["opens"] >= 1
         assert router._m_breaker.value(replica="rep0", to="open") >= 1
@@ -303,6 +310,43 @@ def test_breaker_opens_on_broken_replica_then_recloses(mlp_dir):
             time.sleep(0.05)
         assert rep0.breaker.state == CLOSED
         assert rep0.requests_ok > 0  # the healed replica serves again
+    finally:
+        router.stop()
+        for s in servers:
+            s.stop()
+
+
+def test_generate_routes_and_replicas_agree():
+    """`:generate` is proxied like `:predict`, and replicas built from the
+    same seed answer a prompt with the same tokens, whichever serves it."""
+    from paddle_tpu.executor import Scope
+    from paddle_tpu.models.gpt_decoder import GPTDecoder
+
+    gen_kw = dict(vocab_size=24, n_layer=2, n_head=2, d_model=16, d_inner=32,
+                  max_context=16)
+    servers = []
+    for _ in range(2):
+        s = ModelServer(port=0, request_timeout_ms=60000.0)
+        s.add_generation_model("g", model=GPTDecoder(**gen_kw),
+                               scope=Scope(seed=0), max_slots=2, page_size=4,
+                               max_context=16)
+        s.start()
+        servers.append(s)
+    router = Router(port=0, hedge=False, probe_interval_s=60.0, seed=7)
+    rport = router.start()
+    try:
+        for i, s in enumerate(servers):
+            router.register("rep%d" % i, s.url)
+        router.probe_once()
+        doc = {"prompt": [1, 2, 3], "max_new_tokens": 4, "eos_id": 999}
+        direct = [_post(s.url + "/v1/models/g:generate", doc)[1]["tokens"]
+                  for s in servers]
+        assert direct[0] == direct[1] and len(direct[0]) == 4
+        for _ in range(4):
+            code, out = _post(
+                "http://127.0.0.1:%d/v1/models/g:generate" % rport, doc)
+            assert code == 200 and out["tokens"] == direct[0]
+        assert router._m_requests.value(kind="generate", code="200") == 4
     finally:
         router.stop()
         for s in servers:
@@ -408,24 +452,45 @@ def test_drain_then_stop_drops_nothing(mlp_dir):
 
 
 def test_sigkill_mid_request_failover_and_rejoin(tmp_path):
-    """REAL process death: two replica subprocesses, one armed to SIGKILL
-    itself on its FIRST request (mid-request — the socket dies with no
-    reply). Every client request still gets a 200 via failover; the killed
-    replica goes DOWN at the router, and a restart rejoins the pool."""
+    """REAL process death: two replica subprocesses following one model
+    repository, one armed to reset its FIRST connection and SIGKILL itself on
+    its SECOND request (mid-request — the socket dies with no reply). Every
+    client request still gets a 200 via failover, and the reset's trace id
+    joins three OS processes (router, the replica that failed, the one that
+    answered); the killed replica goes DOWN at the router, and a restart
+    rejoins the pool only once its HotReloader has acked the published
+    version."""
+    from paddle_tpu import flags as _flags
+    from paddle_tpu.observability import tracing as _tracing
+    from paddle_tpu.online import ModelPublisher, read_latest
+    from paddle_tpu.serving import ServingEngine
+
     model_dir, _, _, xname, _ = _save_mlp(tmp_path, prefix="kfl")
+    repo = str(tmp_path / "repo")
+    eng = ServingEngine(model_dir, name="m", batch_buckets=(1,))
+    ModelPublisher(repo).publish(
+        {n: np.asarray(eng.scope.vars[n]).copy() for n in eng.param_names()}, 1)
+    target = read_latest(repo)["version"]
+    tdir = str(tmp_path / "traces")
+    env = dict(_CPU, FLAGS_trace_dir=tdir, FLAGS_trace_sample="1.0")
     spec = lambda name: {
         "name": name,
         "request_timeout_ms": 10000.0,
         "predict": {"model": "m", "model_dir": model_dir},
+        "repo": repo,
+        "poll_interval_s": 0.1,
     }
     reps = [
-        ReplicaProcess(spec("kr0"), str(tmp_path), env=_CPU,
-                       faults="replica_kill:step=1"),
-        ReplicaProcess(spec("kr1"), str(tmp_path), env=_CPU),
+        ReplicaProcess(spec("kr0"), str(tmp_path), env=env,
+                       faults="conn_reset:step=1,replica_kill:step=2"),
+        ReplicaProcess(spec("kr1"), str(tmp_path), env=env),
     ]
+    old_flags = _flags.get_flags(["trace_dir", "trace_sample"])
+    _flags.set_flags({"trace_dir": tdir, "trace_sample": 1.0})
+    _tracing.reset()
     router = Router(port=0, hedge=False, probe_interval_s=0.2, seed=4,
                     total_deadline_s=30.0, attempt_timeout_s=10.0,
-                    down_after=2)
+                    down_after=2, repo=repo, repo_model="m")
     rport = router.start()
     try:
         for r in reps:
@@ -434,15 +499,33 @@ def test_sigkill_mid_request_failover_and_rejoin(tmp_path):
             r.wait_ready(timeout=180.0)
             router.register(r.name, r.url)
         router.probe_once()
+        # both landed and acked the published version before they served
         assert sorted(router.stats()["routable"]) == ["kr0", "kr1"]
 
         doc = {"inputs": {xname: [[0.7] * 6]}}
         url = "http://127.0.0.1:%d/v1/models/m:predict" % rport
-        codes = [_post(url, doc, timeout=60.0)[0] for _ in range(10)]
-        assert codes == [200] * 10  # the SIGKILL never reached a client
+
+        def reset_span_on_disk():
+            return any(s["name"] == "server.request"
+                       and s.get("tags", {}).get("fault") == "conn_reset"
+                       for s in _tracing.load_spans(tdir))
+
+        codes, retries0, flushed = [], router._m_retries.value(kind="predict"), False
+        for _ in range(10):
+            codes.append(_post(url, doc, timeout=60.0)[0])
+            if not flushed and router._m_retries.value(kind="predict") > retries0:
+                # kr0 reset that connection; its next request kills it, so
+                # its writer thread has the span on disk before we send one
+                deadline = time.monotonic() + 30.0
+                while not reset_span_on_disk() and time.monotonic() < deadline:
+                    time.sleep(0.02)
+                assert reset_span_on_disk()
+                flushed = True
+        assert codes == [200] * 10  # neither fault reached a client
 
         deadline = time.monotonic() + 30.0
         while reps[0].alive() and time.monotonic() < deadline:
+            _post(url, doc, timeout=60.0)
             time.sleep(0.1)
         assert not reps[0].alive()  # the fault plan really killed it
         router.probe_once()
@@ -456,6 +539,8 @@ def test_sigkill_mid_request_failover_and_rejoin(tmp_path):
         router.register(reps[0].name, reps[0].url)  # re-register: new port
         router.probe_once()
         assert sorted(router.stats()["routable"]) == ["kr0", "kr1"]
+        # the staleness gate: routable because it acked the published version
+        assert router.replicas()["kr0"].version_for_gate("m") >= target
         codes = [_post(url, doc, timeout=60.0)[0] for _ in range(4)]
         assert codes == [200] * 4
     finally:
@@ -465,3 +550,18 @@ def test_sigkill_mid_request_failover_and_rejoin(tmp_path):
                 r.kill()
             except Exception:
                 pass
+        _tracing.reset()  # flush the router's shard
+        _flags.set_flags(old_flags)
+        _tracing.reset()
+
+    by_trace = {}
+    for s in _tracing.load_spans(tdir):
+        by_trace.setdefault(s["trace"], []).append(s)
+    joined = [
+        sp for sp in by_trace.values()
+        if len({(s.get("host"), s.get("pid")) for s in sp}) >= 3
+        and {"error", "ok"} <= {s["status"] for s in sp
+                                if s["name"] == "router.attempt"}
+    ]
+    assert joined, "no failover trace spans three processes (%d traces)" % len(
+        by_trace)

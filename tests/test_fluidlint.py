@@ -215,9 +215,8 @@ def test_seeded_donation_alias():
 # the zoo is clean (same programs the CLI lints): asserted in
 # tests/test_analysis.py::test_zoo_facts_agree_with_traced_metadata, which
 # builds each zoo model once for both the lint-clean and the
-# facts-vs-traced-metadata contracts; the CLI path over the full zoo runs
-# in scripts/build_and_test.sh (`fluidlint.py --zoo --strict`) and in
-# test_cli_smoke below.
+# facts-vs-traced-metadata contracts; the CLI path runs in test_cli_smoke
+# below.
 # ---------------------------------------------------------------------------
 
 

@@ -1,4 +1,4 @@
-"""Input-pipeline overlap evidence (VERDICT round 1 item 5).
+"""Input-pipeline overlap evidence.
 
 The PyReader feeder thread must stage batch N+1 (host assembly +
 device_put) WHILE step N computes — the reference's double-buffer reader

@@ -196,7 +196,7 @@ def test_pyreader_reset_mid_epoch_stops_thread():
 def test_pyreader_epoch_cache_replays_without_reader():
     """cache_epoch=True: epoch 1 pulls from the source; epoch 2+ replays the
     staged batches device-resident — the source, host assembly, and wire are
-    out of the loop (PIPELINE_KEEPUP.json keep-up evidence path)."""
+    out of the loop."""
     from paddle_tpu.py_reader import PyReader, EOFException
 
     pulls = []

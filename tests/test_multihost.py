@@ -1,6 +1,6 @@
 """Multi-host (multi-process) collective DP over the DCN analog.
 
-VERDICT round 1 item 6: parallel/multihost.py had no test. This is the
+parallel/multihost.py had no test. This is the
 reference's subprocess-cluster pattern (test_dist_base.py:423
 _run_cluster_nccl2) mapped to TPU-native collectives: 2 processes × 4
 virtual CPU devices form one 8-device mesh via jax.distributed (gloo as the

@@ -395,7 +395,7 @@ def test_pp_run_emits_bubble_gauge_and_monitor_renders(tmp_path):
     assert est["analytic"] == pytest.approx(3 / 7, abs=1e-4)
 
     # the published gauge is the estimator's value, clamped to [0, 1] (the
-    # ISSUE tolerance: gauge ≡ the same two-m estimator bench.py uses)
+    # ISSUE tolerance: gauge ≡ the two-m estimator)
     gauge = col.registry.get("pp/bubble_measured").value()
     assert gauge == pytest.approx(
         max(0.0, min(1.0, est["bubble"])), abs=1e-3)
@@ -526,5 +526,5 @@ def test_telemetry_off_overhead_is_negligible(tmp_path):
                       "telemetry_interval_steps": 10})
         run_n(2)
         t_on = run_n(40)
-    # generous CI-noise bound; the real check is in scripts/build_and_test.sh
+    # generous CI-noise bound
     assert t_on < t_off * 3 + 0.25, (t_off, t_on)

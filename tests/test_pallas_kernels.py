@@ -163,8 +163,8 @@ def test_flash_streamed_long_context_tier():
     accumulators — used when whole-side residency would overflow VMEM past
     ~8k tokens, pallas_kernels._resident_ok) match the dense reference for
     forward and gradients, causal and not. Forced on small shapes by
-    patching the residency predicate; on-chip validation at t=16384-65536 is
-    recorded in PROFILE.md."""
+    patching the residency predicate; chip_smoke.py's kernels phase compiles
+    the streamed tier on the chip."""
     import jax
     import jax.numpy as jnp
 

@@ -7,6 +7,7 @@ MLP (test_parallel_executor_mnist analog), SE-ResNeXt
 (_seresnext), Transformer (_transformer), CRF (_crf), and a
 bounded-While training case (test_parallel_executor_test_while_train)."""
 
+
 import numpy as np
 
 import paddle_tpu.fluid as fluid
@@ -193,6 +194,66 @@ def test_pe_zero1_matches_allreduce_convergence():
             assert str(val.dtype) == "bfloat16", (n, val.dtype)
         # replicated path keeps all state whole on every chip
         assert not base_pe._last_run[0].zero1_state_names
+
+
+def _wire(strategy):
+    """One Adam step of the MLP under `strategy`: (pe, program, collective
+    rows of the compiled step, optimizer-state bytes a chip holds)."""
+    from hlo_collectives import collectives
+
+    rng = np.random.RandomState(7)
+    x, y = make_data(rng, 64)
+    main, startup, loss = _build_adam_program()
+    scope = Scope(seed=3)
+    with scope_guard(scope):
+        fluid.Executor(fluid.CPUPlace()).run(startup)
+        pe = fluid.ParallelExecutor(
+            loss_name=loss.name, main_program=main,
+            build_strategy=strategy, scope=scope,
+        )
+        pe.run(fetch_list=[loss.name], feed={"x": x, "y": y})
+        rows = collectives(pe.compiled_hlo(), pe._mesh)
+        state = sum(
+            v.addressable_shards[0].data.nbytes
+            for n, v in scope.vars.items()
+            if "_acc" in n and hasattr(v, "addressable_shards")
+        )
+    return pe, main, rows, state
+
+
+def test_pe_allreduce_reduces_the_gradients_and_gathers_nothing():
+    """The wire signature of the replicated dp step, read from the compiled
+    step: it reduce-combines the gradient bytes (+ the scalar loss) once and
+    all-gathers nothing. Bytes, not opcodes: see hlo_collectives."""
+    from hlo_collectives import gathered_bytes, reduced_bytes, within
+
+    pe, main, rows, _ = _wire(None)
+    if pe.device_count < 2:
+        return
+    grads = sum(int(np.prod(p.shape)) * 4
+                for p in main.global_block().all_parameters())
+    assert within(reduced_bytes(rows), grads), (reduced_bytes(rows), grads)
+    assert gathered_bytes(rows) == 0, rows
+
+
+def test_pe_zero1_gathers_what_it_shards():
+    """ZeRO-1's: it reduce-combines the same gradient bytes, all-gathers each
+    dp-shardable parameter once (the updated shards return to every rank) and
+    keeps less optimizer state a chip than the replicated step."""
+    from hlo_collectives import gathered_bytes, reduced_bytes, within
+
+    base_pe, _, _, base_state = _wire(None)
+    if base_pe.device_count < 2:
+        return
+    z1_pe, main, rows, z1_state = _wire(_zero1_strategy())
+    params = main.global_block().all_parameters()
+    grads = sum(int(np.prod(p.shape)) * 4 for p in params)
+    shardable = sum(int(np.prod(p.shape)) * 4 for p in params
+                    if p.shape[0] % z1_pe.device_count == 0)
+    assert within(reduced_bytes(rows), grads), (reduced_bytes(rows), grads)
+    assert within(gathered_bytes(rows), shardable), (
+        gathered_bytes(rows), shardable)
+    assert z1_state < base_state, (z1_state, base_state)
 
 
 def test_pe_zero1_checkpoint_roundtrip(tmp_path):

@@ -111,7 +111,7 @@ def test_scheduler_phases_partition_the_iteration(tmp_path):
     for s in prof.spans:
         assert "iter" in s["tags"], s
         by_iter.setdefault(s["tags"]["iter"], []).append(s)
-    decoded = 0
+    covered_share = []  # of each decoding pass
     for it, group in by_iter.items():
         wrappers = [s for s in group if s["name"] == "sched.iter"]
         if not wrappers:
@@ -134,18 +134,40 @@ def test_scheduler_phases_partition_the_iteration(tmp_path):
             outer = held["sched." + leaf["tags"]["kind"]]
             assert inside(leaf, outer), (leaf, outer)
         if "sched.decode" in held:
-            decoded += 1
             covered = sum(s["end"] - s["start"] for s in phases)
-            assert covered >= 0.9 * (wrapper["end"] - wrapper["start"])
-    assert decoded >= 5
+            covered_share.append(covered / (wrapper["end"] - wrapper["start"]))
+    assert len(covered_share) >= 5
+    # The phases cover the pass. A thread descheduled between two phases
+    # opens a gap in that pass alone, so the claim is held on the median pass.
+    assert np.median(covered_share) >= 0.9, sorted(covered_share)
 
-    # the operator's histograms tell the same story with no session at all
-    phases_ms = sum(hist_sum("phasegen", "sched_%s_ms" % p) for p in SCHED_PHASES)
+    # the operator's histograms tell the same story with no session at all.
+    # A histogram's interval lies inside its span's (the annotation opens
+    # first and closes last), so it observes as often, never more time, and
+    # less only by what a thread descheduled between the two exits adds to
+    # the span alone: a tenth, and half a millisecond a pass beside it.
+    snap = registry.default_registry().snapshot()
+
+    def close_below(less, more, passes):
+        return less <= more + 1.0 and more - less <= 0.1 * more + 0.5 * passes
+
+    for name in ("iter",) + SCHED_PHASES:
+        hist = snap["serving/phasegen/sched_%s_ms" % name]
+        spans = prof.named("sched." + name)
+        # the slack: a pass the session's start or end cut is in one only
+        assert abs(hist["count"] - len(spans)) <= 2, (name, hist["count"], len(spans))
+        span_ms = sum(s["end"] - s["start"] for s in spans) / 1e6
+        assert close_below(hist["sum"], span_ms, len(spans)), (
+            name, hist["sum"], span_ms)
+    passes = snap["serving/phasegen/sched_iter_ms"]["count"]
     iter_ms = hist_sum("phasegen", "sched_iter_ms")
-    assert iter_ms > 0 and abs(phases_ms - iter_ms) <= 0.1 * iter_ms
+    assert iter_ms > 0
+    phases_ms = sum(hist_sum("phasegen", "sched_%s_ms" % p) for p in SCHED_PHASES)
+    # the phases lie inside the pass and fill it
+    assert phases_ms <= iter_ms * (1 + 1e-6)
+    assert close_below(phases_ms, iter_ms, passes), (phases_ms, iter_ms)
     assert hist_sum("phasegen", "sched_idle_ms") >= 50 * 0.5  # the sleep
     assert hist_sum("phasegen", "sched_host_stall_ms") <= iter_ms
-    snap = registry.default_registry().snapshot()
     steps = snap["serving/phasegen/gen_step_ms"]["count"]
     for leaf in ENGINE_LEAVES:  # decode steps only
         assert snap["serving/phasegen/gen_%s_ms" % leaf]["count"] == steps

@@ -3,7 +3,7 @@ quant-GEMM family, the int8 paged-KV pool): calibrated-int8 ServingEngine
 output parity, quant-GEMM kernel-vs-dense parity under FLAGS_quantized_gemm,
 fuse_attention substitution bit-parity and decline rules, kv-int8 generation
 parity with the paged-flash kernel pinned on, quantize_static op semantics,
-the fp8 training-matmul flag, and int8/native variants coexisting in one
+and int8/native variants coexisting in one
 persistent compile cache across fresh processes."""
 
 import json
@@ -25,7 +25,7 @@ from paddle_tpu.serving import GenerationEngine, ServingEngine
 
 @pytest.fixture
 def restore_flags():
-    keep = pt_flags.get_flags(["quantized_gemm", "paged_flash", "fp8_matmul"])
+    keep = pt_flags.get_flags(["quantized_gemm", "paged_flash"])
     yield
     pt_flags.set_flags(keep)
 
@@ -305,37 +305,6 @@ def test_kv_int8_write_populates_scales():
         # the zero-division guard), so the write trail is exact
         assert (ks[~written] == 1.0).all()
     assert wrote >= 2 * len(p)  # k and v rows for every cached token
-
-
-# ------------------------------------------------------------- fp8 matmul
-
-
-def test_fp8_matmul_flag_casts_and_dispatches(restore_flags):
-    """FLAGS_fp8_matmul: the training matmul lowering must route through
-    the e4m3 cast path (dispatch counter) and stay within fp8 resolution
-    of the f32 product."""
-    from paddle_tpu.ops.pallas_kernels import KERNEL_DISPATCHES
-
-    rng = np.random.RandomState(0)
-    main, startup = framework.Program(), framework.Program()
-    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
-        a = fluid.layers.data(name="fa", shape=[64], dtype="float32")
-        y = fluid.layers.fc(a, size=32)
-    exe = fluid.Executor(fluid.CPUPlace())
-    x = rng.randn(16, 64).astype("float32")
-    with scope_guard(Scope(seed=3)):
-        exe.run(startup)
-        (ref,) = exe.run(main, feed={"fa": x}, fetch_list=[y.name])
-    pt_flags.set_flags({"fp8_matmul": True})
-    before = KERNEL_DISPATCHES.get("matmul_fp8", 0)
-    with scope_guard(Scope(seed=3)):
-        exe.run(startup)
-        (got,) = exe.run(main, feed={"fa": x}, fetch_list=[y.name])
-    assert KERNEL_DISPATCHES.get("matmul_fp8", 0) > before
-    rel = np.abs(np.asarray(got) - np.asarray(ref)).max() / (
-        np.abs(np.asarray(ref)).max() + 1e-9
-    )
-    assert 0 < rel < 0.1, rel  # e4m3 rounding is real but bounded
 
 
 # ------------------------------------- compile-cache precision coexistence

@@ -146,6 +146,7 @@ _TP_RULES = [
     (r"^fc_1\.w_0$", ("tp", None)),
 ]
 _FSDP_RULES = [(r"^fc_\d+\.(w|b)_0$", ("fsdp",))]
+_PARAMS = ("fc_0.w_0", "fc_0.b_0", "fc_1.w_0", "fc_1.b_0")
 
 
 def test_tp_rules_match_single_device():
@@ -177,12 +178,74 @@ def test_fsdp_rules_match_single_device():
     multi, scope, pe = _train(batches, MeshConfig(dp=2, fsdp=4), _FSDP_RULES)
     np.testing.assert_allclose(single, multi, rtol=_RTOL, atol=_ATOL)
     if pe.device_count > 1:
-        for name in ("fc_0.w_0", "fc_0.b_0", "fc_1.w_0", "fc_1.b_0"):
+        for name in _PARAMS:
             assert _spec_axes(scope, name) == ("fsdp",), name
+            # a quarter of the parameter lives on a chip
+            val = scope.vars[name]
+            assert val.addressable_shards[0].data.nbytes * 4 == val.nbytes
         moments = [n for n in scope.vars if "_moment" in n and "_acc" in n]
         assert moments
         for n in moments:
             assert _spec_axes(scope, n) == ("fsdp",), n
+
+
+def _wire(mesh_cfg, rules):
+    """One step under the rules: (collective rows of the compiled step,
+    {param: (shape, bytes, bytes a chip stores)}), or None on one device."""
+    from hlo_collectives import collectives
+
+    _, scope, pe = _train([_make_data(np.random.RandomState(2), 64)],
+                          mesh_cfg, rules)
+    if pe.device_count < 2:
+        return None
+    stored = {
+        n: (tuple(scope.vars[n].shape), scope.vars[n].nbytes,
+            scope.vars[n].addressable_shards[0].data.nbytes)
+        for n in _PARAMS
+    }
+    return collectives(pe.compiled_hlo(), pe._mesh), stored
+
+
+def test_fsdp_gathers_each_parameter_once():
+    """The wire signature of FSDP over dp2 x fsdp4, read from the compiled
+    step: each rule-sharded parameter is all-gathered over `fsdp` once a step
+    (weight streaming; a second gather of the same tensor doubles the bytes).
+    Gathers are matched by shape, so the partitioner's own activation gathers
+    do not count. The gradients are not combined twice either: everything
+    reduced stays under 1.5x the gradient bytes (1.24x here: the CPU
+    partitioner spells the reduce-scatter as all-reduce + dynamic-slice and
+    adds a few activation partials; a second ring of the large gradient
+    would make it 2x)."""
+    from hlo_collectives import reduced_bytes, within
+
+    wire = _wire(MeshConfig(dp=2, fsdp=4), _FSDP_RULES)
+    if wire is None:
+        return
+    rows, stored = wire
+    shapes = {shape for shape, _, _ in stored.values()}
+    params = sum(nbytes for _, nbytes, _ in stored.values())
+    gathered = sum(b for op, axis, shape, b in rows
+                   if op == "all-gather" and axis == "fsdp" and shape in shapes)
+    assert within(gathered, params), (gathered, params, rows)
+    assert reduced_bytes(rows) <= 1.5 * params, (reduced_bytes(rows), rows)
+
+
+def test_tp_reduces_stored_shards_and_one_activation():
+    """The wire signature of the Megatron pair over dp4 x tp2: the dp ring
+    carries each gradient at its parameter's stored shard size, and the only
+    tp collective is the row-parallel product's partial sum, (batch / dp) x
+    classes f32. The backward adds none: the first operand is the feed."""
+    from hlo_collectives import gathered_bytes, reduced_bytes, within
+
+    wire = _wire(MeshConfig(dp=4, tp=2), _TP_RULES)
+    if wire is None:
+        return
+    rows, stored = wire
+    shards = sum(chip for _, _, chip in stored.values())
+    assert within(reduced_bytes(rows, "dp"), shards), (rows, shards)
+    assert within(reduced_bytes(rows, "tp"), 64 // 4 * 4 * 4), rows
+    assert gathered_bytes(rows) == 0, rows
+    assert all(axis in ("dp", "tp") for _, axis, _, _ in rows), rows
 
 
 def test_fsdp_checkpoint_roundtrip_topology_change():

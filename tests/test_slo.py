@@ -252,23 +252,40 @@ def test_latency_slo_and_min_events():
     assert [e.name for e in eng.firing()] == ["lat"]
 
 
-def test_alert_log_jsonl(tmp_path):
+def test_alert_log_jsonl_and_flight_recorder_bundle(tmp_path):
+    """A fired alert lands in the JSONL log and, with the flight recorder on,
+    in an slo_alert bundle that carries the offending window's series."""
+    from paddle_tpu import flags as _flags
+    from paddle_tpu.observability import flightrec as _flightrec
+
     clock = [0.0]
     reg = obs_registry.MetricRegistry()
     req = reg.counter("req")
     sampler = LocalSampler(reg, clock=lambda: clock[0])
     out = str(tmp_path / "alerts.jsonl")
+    fdir = str(tmp_path / "flightrec")
+    old = _flags.get_flags(["flightrec_dir"])
+    _flags.set_flags({"flightrec_dir": fdir})
+    _flightrec.reset()
     eng = AlertEngine(
         slos=[SLO("avail", 0.9, counter="req", bad={"code": "5"})],
         history=sampler, rules=[BurnRateRule("page", 20, 40, 1.0)],
         registry=reg, clock=lambda: clock[0], out_path=out,
-        log_stderr=False, flightrec=False,
+        log_stderr=False, flightrec=True,
     )
-    for _ in range(6):
-        req.inc(5, code="500")
-        clock[0] += 10
-        sampler.sample()
-        eng.evaluate()
+    try:
+        for _ in range(6):
+            req.inc(5, code="500")
+            clock[0] += 10
+            sampler.sample()
+            eng.evaluate()
+    finally:
+        _flags.set_flags(old)
+        _flightrec.reset()
+    (bundle,) = [d for d in os.listdir(fdir) if "-slo_alert-" in d]
+    info = json.load(open(os.path.join(fdir, bundle, "event.json")))["info"]
+    assert info["name"] == "avail" and info["severity"] == "page"
+    assert info["series"]
     req.inc(200, code="200")
     for _ in range(6):
         clock[0] += 10
@@ -476,7 +493,6 @@ def test_monitor_renders_fleet_section():
 
 
 # ------------------------------------------------------------------ router
-@pytest.mark.slow
 def test_router_fleet_endpoints():
     import urllib.error
     import urllib.request

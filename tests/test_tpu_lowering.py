@@ -210,16 +210,16 @@ def test_paged_auto_declines_a_page_that_is_not_whole_tiles(as_on_tpu):
 
 
 def test_training_fused_step_lowers_for_tpu(monkeypatch):
-    """The one-layer bench transformer step under training_fused — every
+    """The one-layer chip_smoke transformer step under training_fused — every
     fused family and both flash directions in one module."""
-    import bench
+    import chip_smoke
     import paddle_tpu.fluid as fluid
     from paddle_tpu.executor import (
         Scope, _apply_pass_pipeline, _CompiledBlock, scope_guard,
     )
     from paddle_tpu.transpiler.bf16_transpiler import Bf16Transpiler
 
-    main, startup, feed, loss, _ = bench.build_transformer(
+    main, startup, feed, loss = chip_smoke.build_transformer(
         b=1, t=128, d=128, n_layer=1, vocab=128, moment_dtype="bfloat16"
     )
     scope = Scope(seed=0)

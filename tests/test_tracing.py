@@ -7,6 +7,8 @@ trace id carries the failed attempt AND the successful retry."""
 import glob
 import json
 import os
+import sys
+import time
 import urllib.request
 
 import numpy as np
@@ -24,6 +26,9 @@ from paddle_tpu.observability.tracing import (
 from paddle_tpu.serving import ContinuousBatcher, ModelServer, ServingEngine
 
 from test_serving import _save_mlp
+
+TOOLS = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                     "tools")
 
 
 @pytest.fixture()
@@ -198,10 +203,10 @@ def test_router_failover_one_trace_with_error_and_retry(tmp_path, trace_env):
     request + both attempt spans (one error, one ok), the winning replica's
     server.request and the batcher's serving.request — and rides back to the
     client in the response header."""
-    from paddle_tpu.fleet import Router
+    from paddle_tpu.fleet import CLOSED, Router
     from paddle_tpu.resilience import faults
 
-    tdir, _ = trace_env
+    tdir, fdir = trace_env
     model_dir, _, _, xname, _ = _save_mlp(tmp_path, prefix="rtr")
     servers = []
     for _i in range(2):
@@ -209,7 +214,11 @@ def test_router_failover_one_trace_with_error_and_retry(tmp_path, trace_env):
         s.add_model("m", model_dir=model_dir)
         s.start()
         servers.append(s)
-    router = Router(port=0, hedge=False, probe_interval_s=60.0, seed=5)
+    # the breaker trips on the one reset and re-closes on the next success:
+    # each transition dumps a flight-recorder bundle
+    router = Router(port=0, hedge=False, probe_interval_s=60.0, seed=5,
+                    breaker_opts=dict(failure_threshold=1, open_for_s=0.05,
+                                      success_threshold=1))
     rport = router.start()
     try:
         for i, s in enumerate(servers):
@@ -229,6 +238,14 @@ def test_router_failover_one_trace_with_error_and_retry(tmp_path, trace_env):
             faults.install(None)
         assert code == 200 and "outputs" in out
         assert headers.get(TRACE_HEADER, "").startswith(client_trace + "-")
+        # more traffic until the tripped breaker has re-closed
+        (tripped,) = [r for r in router.replicas().values()
+                      if r.breaker.stats()["opens"]]
+        deadline = time.monotonic() + 10.0
+        while tripped.breaker.state != CLOSED and time.monotonic() < deadline:
+            time.sleep(0.06)
+            _post("http://127.0.0.1:%d/v1/models/m:predict" % rport, doc)
+        assert tripped.breaker.state == CLOSED
     finally:
         router.stop()
         for s in servers:
@@ -257,6 +274,31 @@ def test_router_failover_one_trace_with_error_and_retry(tmp_path, trace_env):
     # the reset leg left an error server span on the losing replica
     assert any(s["status"] == "error" and s["tags"].get("fault") == "conn_reset"
                for s in by_name["server.request"])
+
+    # a breaker-transition bundle's span ring shows that failover: the failed
+    # attempt and the successful retry under the client's trace id
+    bundles = sorted(d for d in os.listdir(fdir) if d.startswith("bundle-"))
+    assert any("-breaker_transition-" in b for b in bundles), bundles
+    shown = False
+    for b in bundles:
+        ring = _tracing.load_spans(os.path.join(fdir, b, "spans.jsonl"))
+        att = [s["status"] for s in ring
+               if s["trace"] == client_trace and s["name"] == "router.attempt"]
+        shown = shown or sorted(att) == ["error", "ok"]
+    assert shown, bundles
+
+    # both renderers read the shards
+    sys.path.insert(0, TOOLS)
+    try:
+        import timeline
+        import trace_view
+    finally:
+        sys.path.remove(TOOLS)
+    n_events = timeline.convert("", str(tmp_path / "timeline.json"),
+                                trace_path=tdir)
+    assert n_events >= len(_tracing.load_spans(tdir))
+    assert trace_view.main([tdir, "--top", "5"]) == 0
+    assert trace_view.main([tdir, "--trace", client_trace]) == 0
 
 
 # ------------------------------------------------ parity + disabled cost

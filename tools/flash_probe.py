@@ -1,8 +1,8 @@
-"""On-chip flash-kernel rate probe (VERDICT r04 item 1 evidence).
+"""On-chip flash-kernel rate probe.
 
-Times paddle_tpu's Pallas flash forward and backward at the MFU-bench
-attention shape, reports effective TF/s (bench-accounted flops: 4*b*h*t*t*d
-fwd, 2x that bwd — the same accounting bench.py's MFU uses), and compares
+Times paddle_tpu's Pallas flash forward and backward at chip_smoke.py's
+attention shape, reports effective TF/s (4*b*h*t*t*d flops forward, twice
+that backward: the products the algorithm needs), and compares
 against (a) XLA's dense attention chain and (b) jax's own TPU flash kernel
 (jax.experimental.pallas.ops.tpu.flash_attention) as the hardware-ceiling
 probe.

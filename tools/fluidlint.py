@@ -11,8 +11,8 @@ Usage:
   python tools/fluidlint.py --zoo --strict        # exit 1 on warnings too
 
 Exit code: 0 clean, 1 any error finding (or, with --strict, any finding at
-all), 2 usage/build failure. CI runs `--zoo --strict` as a smoke stage
-(scripts/build_and_test.sh), so the zoo linting clean is an invariant.
+all), 2 usage/build failure. The zoo linting clean is an invariant
+(tests/test_analysis.py::test_zoo_facts_agree_with_traced_metadata).
 
 The ZOO registry of `name -> build() -> (program, feed_names, fetch_names)`
 is also imported by tests/test_fluidlint.py — the clean-zoo test and this

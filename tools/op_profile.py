@@ -83,9 +83,8 @@ def _fmt_flops(f):
         f /= 1000.0
 
 
-# roofline peaks for the Roof% column / headroom rollup: analytic defaults
-# matching tools/mfu_audit.py; a record carrying "peak_tflops"/"peak_bw_gbs"
-# (mfu_audit writes the measured-bandwidth variant) overrides them
+# roofline peaks for the Roof% column / headroom rollup: analytic defaults;
+# a record carrying "peak_tflops"/"peak_bw_gbs" overrides them
 PEAK_MM_TFLOPS = 192.0
 PEAK_BW_GBS = 676.0
 
