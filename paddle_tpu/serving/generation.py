@@ -45,7 +45,11 @@ when idle, draining every pending prompt when the queue is deep), runs one
 decode step for all live slots, and retires slots on EOS/max-len,
 releasing their pages for reuse.
 
-Sampling (greedy / temperature / top-k) happens host-side on the fetched
+A greedy row's token is chosen on the device: every variant ends in an
+`arg_max` over the vocabulary of the logits it computes (`_emit_ids`), and
+a step's fetch is those ids (and the routers' picks), not the logits, which
+stay on the device for whoever asks (`last_logits`). A row with a
+temperature (temperature / top-k) is sampled host-side from that row's
 logits with a per-request counter-based RNG stream seeded from the scope
 seed — so a request's tokens are a pure function of (params, prompt,
 sampling config, seed), independent of which slot it lands in or who
@@ -213,6 +217,23 @@ class _Variant:
         self.moe_kernel_layers = moe_kernel_layers
 
 
+class _Logits:
+    """One step's logits, left on the device where the step put them: copied
+    to the host on the first read and once (the parity surface's reads, no
+    part of a step's fetch)."""
+
+    __slots__ = ("dev", "_host")
+
+    def __init__(self, dev):
+        self.dev = dev
+        self._host = None
+
+    def host(self):
+        if self._host is None:
+            self._host = np.asarray(self.dev)
+        return self._host
+
+
 class _Sum:
     """A running sum with a histogram's observe(): the stall of the host-only
     phases of one scheduler iteration, taken once per iteration."""
@@ -242,6 +263,27 @@ CTX_TOKEN_BUCKETS = (
 
 # gen_experts_touched / gen_expert_rows_max: small counts
 EXPERT_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024, 2048, 4096)
+
+
+# gen_fetch_bytes: what one decode step copies to the host (32 ids are 128
+# bytes; a sampled row of 65536 float32 logits 262144)
+FETCH_BYTE_BUCKETS = tuple(4 ** i for i in range(2, 14))
+
+
+def _emit_ids(main, fetch_names):
+    """The greedy token of every row, chosen where the logits are: append
+    arg_max over the vocabulary axis of the variant's logits (its first
+    fetch) to `main`, and its int32 ids to the fetches. The one place that
+    turns logits into a token for a greedy row; tie: the first index, as
+    numpy's argmax."""
+    block = main.global_block()
+    logits = fetch_names[0]
+    ids = block.create_var(name=logits + ".ids", dtype="int32")
+    block.append_op(
+        type="arg_max", inputs={"X": [logits]}, outputs={"Out": [ids.name]},
+        attrs={"axis": -1},
+    )
+    return list(fetch_names) + [ids.name]
 
 
 def cache_specs(model):
@@ -393,6 +435,10 @@ class GenerationEngine:
         self._build_kw = (
             {"state_rows": self.max_slots + 1} if self._state_names else {})
         self._state_fns = None  # (snapshot, restore), compiled at warm-up
+        self._row_fn = None  # a sampled row's logits, compiled at warm-up
+        # the last decode step's and the last prompt's logits (_Logits)
+        self._last_logits = self._last_prefill_logits = None
+        self.fetch_bytes = 0  # what the last decode step copied to the host
         # record_picks: keep each run's router picks (run.picks) and copy a
         # prefill chunk's to the host; off in serving (a chunk's fetch stays
         # on the device until the prompt's last)
@@ -469,12 +515,18 @@ class GenerationEngine:
                 ("feed", "decode step: writing the feed buffers, wall ms"),
                 ("dispatch", "decode step: the compiled call returning "
                              "(enqueue), wall ms"),
-                ("wait", "decode step: device sync plus the logits' copy to "
-                         "the host, wall ms"),
-                ("sample", "decode step: host sampling of every run's "
-                           "token, wall ms"),
+                ("wait", "decode step: device sync plus the ids' and "
+                         "picks' copy to the host, wall ms"),
+                ("sample", "decode step: appending every run's token (a "
+                           "row with a temperature: host sampling), wall ms"),
             )
         }
+        self._m_fetch_bytes = reg.histogram(
+            p + "/gen_fetch_bytes",
+            "bytes one decode step copied to the host: its ids, the routers' "
+            "picks, and the logits of each row that has a temperature",
+            buckets=FETCH_BYTE_BUCKETS,
+        )
         self._m_ctx_tokens = reg.histogram(
             p + "/gen_ctx_tokens",
             "positions one decode step has to attend, summed over its runs",
@@ -621,7 +673,7 @@ class GenerationEngine:
                 )
             else:
                 raise ValueError("unknown variant kind %r" % kind)
-            v = self._build_variant(kind, main, feeds, fetches)
+            v = self._build_variant(kind, main, feeds, _emit_ids(main, fetches))
             self._variants[kind] = v
             return v
 
@@ -698,7 +750,37 @@ class GenerationEngine:
         for b in self.prefill_buckets:
             self._variant("prefill:%d" % b)
         self._state_programs()
+        self._row_program()
         return len(self._variants)
+
+    # ---- the parity surface, and a sampled row's logits --------------------
+    @property
+    def last_logits(self):
+        """The last decode step's logits [max_slots, vocab], read on demand
+        from the step's own output (tests and the benchmark's probe assert
+        these rows bit-stable under batching / admission / chunking changes:
+        the docs/serving.md contract). The first read copies, once."""
+        held = self._last_logits
+        return None if held is None else held.host()
+
+    @property
+    def last_prefill_logits(self):
+        """The logits [vocab] of the last prompt's last position, likewise."""
+        held = self._last_prefill_logits
+        return None if held is None else held.host()[0]
+
+    def _row_program(self):
+        """row(logits, slot) -> logits[slot] of the decode step's logits, on
+        the device: what a row with a temperature fetches (262 KB of the 8.4
+        MB at 32 x 65536). Compiled once (at warm-up, so that a sampled row
+        in the hot loop compiles nothing)."""
+        if self._row_fn is None:
+            import jax
+
+            (logits, *_), _ = self._variant("decode").fn.out_info
+            self._row_fn = jax.jit(lambda logits, slot: logits[slot]).lower(
+                logits, np.int32(0)).compile()
+        return self._row_fn
 
     # ---- the fixed per-slot state: snapshot and restore --------------------
     def _state_programs(self):
@@ -910,7 +992,7 @@ class GenerationEngine:
                     "gen_state_row": np.array([run.slot + 1], np.int32),
                 })
             with self._phase("dispatch", "prefill") as dispatch:
-                logits, *extra = self._dispatch(variant, feeds, mut_in)
+                logits, *extra, ids = self._dispatch(variant, feeds, mut_in)
         except Exception as e:
             span.error(e).end()
             raise
@@ -933,9 +1015,8 @@ class GenerationEngine:
             return False
         self._m_prefills.inc()
         with self._phase("wait", "prefill"):
-            # parity surface: tests assert these rows bit-stable under
-            # batching/admission/chunking changes (docs/serving.md contract)
-            self.last_prefill_logits = np.asarray(logits)[0]
+            self._last_prefill_logits = _Logits(logits)
+            ids = np.asarray(ids)
             for first, n_live, picks in run.chunk_picks:
                 picks = np.asarray(picks)[:, :n_live]
                 self._m_chunk_experts.observe(_experts_touched(picks)[0])
@@ -943,8 +1024,10 @@ class GenerationEngine:
                     run.picks.append((first, picks))
             run.chunk_picks = []
         with self._phase("sample", "prefill"):
-            self._append_token(
-                run, self.last_prefill_logits, self._max_new(req))
+            # one row: a prompt with a temperature reads its logits whole
+            tok = (self._sample(self.last_prefill_logits, req, run.rng)
+                   if req.temperature else int(ids[0]))
+            self._append_token(run, tok, self._max_new(req))
             if self.prefix_cache is not None:
                 self.prefix_cache.insert(req.prompt, run.table, run.snaps)
                 run.snaps = {}
@@ -1010,16 +1093,27 @@ class GenerationEngine:
                 variant = self._variant("decode")
                 feeds, mut_in = self._feeds(variant, feeds)
             with self._phase("dispatch", "decode") as dispatch:
-                logits, *extra = self._dispatch(variant, feeds, mut_in)
+                # the step's fetch: the ids [slots], the routers' picks
+                # [n_moe, slots, k] where the model has experts, and the
+                # logits of each row that has a temperature; the copies
+                # start when the step ends, not when the host asks
+                logits, *extra, ids = self._dispatch(variant, feeds, mut_in)
+                rows = {
+                    run.slot: self._row_program()(logits, np.int32(run.slot))
+                    for run in runs if run.req.temperature}
+                fetch = [ids, *extra, *rows.values()]
+                for arr in fetch:
+                    arr.copy_to_host_async()
             with self._phase("wait", "decode") as wait:
-                logits = np.asarray(logits)
-                # the routers' picks, [n_moe, slots, k]: a small extra fetch
-                # of the same step
+                ids = np.asarray(ids)
                 picks = np.asarray(extra[0]) if extra else None
+                rows = {slot: np.asarray(row) for slot, row in rows.items()}
         except Exception as e:
             span.error(e).end()
             raise
-        self.last_logits = logits  # parity surface, see prefill_step()
+        self._last_logits = _Logits(logits)
+        self.fetch_bytes = sum(arr.nbytes for arr in fetch)
+        self._m_fetch_bytes.observe(self.fetch_bytes)
         step_ms = (time.perf_counter() - t0) * 1e3
         span.tag(
             host_ms=round(step_ms, 3), dispatch_ms=round(dispatch.ms, 3),
@@ -1035,8 +1129,9 @@ class GenerationEngine:
         with self._phase("sample", "decode"):
             for run in runs:
                 run.next_pos += 1
-                self._append_token(
-                    run, logits[run.slot], self._max_new(run.req))
+                tok = (self._sample(rows[run.slot], run.req, run.rng)
+                       if run.req.temperature else int(ids[run.slot]))
+                self._append_token(run, tok, self._max_new(run.req))
 
     def _note_picks(self, picks, runs):
         """Experts touched by one decode step's live rows, from its picks."""
@@ -1060,8 +1155,7 @@ class GenerationEngine:
         self._dec_feeds["dec_positions"][run.slot] = 0
         self._set_pool_gauges()
 
-    def _append_token(self, run, logits_row, max_new):
-        tok = self._sample(logits_row, run.req, run.rng)
+    def _append_token(self, run, tok, max_new):
         run.tokens.append(tok)
         self.tokens_generated += 1
         self._m_tokens.inc()
@@ -1074,10 +1168,8 @@ class GenerationEngine:
             run.done, run.finish_reason = True, "length"
 
     def _sample(self, logits, req, rng):
-        if not req.temperature:
-            # greedy stays on the raw fetch dtype: the float64 upcast can't
-            # change the argmax winner and costs real time per decode step
-            return int(np.asarray(logits).argmax())
+        """The token of a row that has a temperature, from its logits and
+        the request's own seeded stream (a greedy row's is _emit_ids')."""
         logits = np.asarray(logits, np.float64)
         z = logits / req.temperature
         if req.top_k and req.top_k < z.size:
@@ -1145,6 +1237,10 @@ class GenerationEngine:
             "positions_walked_per_step": (
                 self.max_slots * self.max_pages * self.page_size
             ),
+            # bytes the last decode step copied to the host (ids + picks +
+            # the logits of each sampled row); gen_fetch_bytes has every
+            # step's
+            "decode_fetch_bytes": self.fetch_bytes,
             "pool": self.pool.stats(),
             # lowering-time kernel choices (counts are per trace, not per
             # call): the smoke/bench stages assert paged_flash shows up here

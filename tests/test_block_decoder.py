@@ -310,3 +310,87 @@ def test_decode_step_observes_the_expert_layers_on_the_grouped_kernel(
         np.testing.assert_allclose(
             eng.last_prefill_logits, want[len(tokens) - 1], atol=1e-4)
         eng.finish(again)
+
+
+def _host_sample(logits, temperature, top_k, rng):
+    """The host sampler as it stood before the ids moved to the device: the
+    contract of a seeded request's stream."""
+    z = np.asarray(logits, np.float64) / temperature
+    if top_k and top_k < z.size:
+        kth = np.partition(z, -top_k)[-top_k]
+        z = np.where(z < kth, -np.inf, z)
+    p = np.exp(z - z.max())
+    p /= p.sum()
+    return int(rng.choice(z.size, p=p))
+
+
+def test_the_step_s_ids_are_the_argmax_of_its_logits_and_its_fetch_is_small():
+    """Through the block decoder's variant, as through GPTDecoder's: a
+    greedy row's token is argmax over its row of last_logits, a row with a
+    temperature draws what the host sampler draws for its seed from that
+    row; 17 steps with a row joining and one leaving. The step copies its
+    ids and the routers' picks, and the logits of each sampled row only."""
+    from paddle_tpu.observability import registry
+
+    model = lfm2_moe(TINY, max_context=64, dtype="float32")
+    eng = GenerationEngine(model, name="idsblk", scope=Scope(seed=4),
+                           max_slots=3, page_size=4, prefill_chunk=8)
+    eng.warmup()
+    n_moe, k, vocab = len(model.moe_layers()), 2, 97
+    small = 3 * 4 + n_moe * 3 * k * 4  # ids + picks [n_moe, slots, k]
+    reqs = [GenRequest(_prompt(9, 1), max_new_tokens=20),
+            GenRequest(_prompt(5, 2), max_new_tokens=9, temperature=0.7,
+                       top_k=6, seed=21),
+            GenRequest(_prompt(11, 3), max_new_tokens=12)]
+    rngs = [None, np.random.default_rng(21), None]
+
+    def want(i, row):
+        if rngs[i] is None:
+            return int(np.argmax(row))
+        return _host_sample(row, reqs[i].temperature, reqs[i].top_k, rngs[i])
+
+    def start(i):
+        run = eng.start(reqs[i])
+        assert run.tokens == [want(i, eng.last_prefill_logits)]
+        return run
+
+    live = {0: start(0), 1: start(1)}
+    steps = sampled_steps = 0
+    try:
+        for step in range(17):
+            if step == 4:
+                live[2] = start(2)
+            eng.decode_step(list(live.values()))
+            steps += 1
+            sampled_steps += 1 in live
+            assert eng.last_picks.shape == (n_moe, 3, k)
+            assert eng.stats()["decode_fetch_bytes"] == (
+                small + (1 in live) * vocab * 4)
+            rows = eng.last_logits
+            for i, run in list(live.items()):
+                assert run.tokens[-1] == want(i, rows[run.slot]), (step, i)
+                if run.done:
+                    eng.finish(live.pop(i))
+    finally:
+        for run in live.values():
+            eng.finish(run)
+    assert sorted(live) == [0] and 0 < sampled_steps < steps
+    h = registry.default_registry().snapshot()["serving/idsblk/gen_fetch_bytes"]
+    assert (h["count"], h["sum"]) == (
+        steps, steps * small + sampled_steps * vocab * 4)
+
+
+def test_a_tie_over_the_whole_vocabulary_goes_to_the_first_index():
+    """The last norm's weight at 0 makes every logit exactly 0: the id the
+    device chooses is numpy's argmax of that row, index 0."""
+    model, eng = _engine(seed=6)
+    norm = model._p("final_norm_w")
+    eng.set_params({norm: np.zeros_like(np.asarray(eng.scope.vars[norm]))})
+    run = eng.start(GenRequest(_prompt(6, 5), max_new_tokens=4))
+    try:
+        assert not eng.last_prefill_logits.any() and run.tokens == [0]
+        eng.decode_step([run])
+        row = eng.last_logits[run.slot]
+        assert not row.any() and run.tokens[-1] == int(np.argmax(row)) == 0
+    finally:
+        eng.finish(run)

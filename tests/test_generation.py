@@ -546,3 +546,246 @@ def test_prefix_cache_trie_lru_and_guarded_eviction():
     pool.release(s3)
     assert cache.clear() == 1
     assert pool.stats()["pages_in_use"] == 0
+
+
+# ------------------------------------------- the ids are born on the device
+
+
+IDS_KW = dict(MODEL_KW, max_context=48)
+
+
+def _ids_engine(name, **kw):
+    eng = GenerationEngine(
+        GPTDecoder(**IDS_KW), name=name, max_slots=3, page_size=4,
+        max_context=48, **dict(dict(cache_dir=None), **kw))
+    eng.warmup()
+    return eng
+
+
+@pytest.fixture(scope="module")
+def ids_engine():
+    return _ids_engine("tgen_ids")
+
+
+def _host_sample(logits, temperature, top_k, rng):
+    """The host sampler as it stood before the ids moved to the device
+    (numpy, float64, one rng.choice a token): the contract of a seeded
+    request's stream."""
+    z = np.asarray(logits, np.float64) / temperature
+    if top_k and top_k < z.size:
+        kth = np.partition(z, -top_k)[-top_k]
+        z = np.where(z < kth, -np.inf, z)
+    p = np.exp(z - z.max())
+    p /= p.sum()
+    return int(rng.choice(z.size, p=p))
+
+
+def _fetch_hist(name):
+    from paddle_tpu.observability import registry
+
+    h = registry.default_registry().snapshot()[
+        "serving/%s/gen_fetch_bytes" % name]
+    return h["count"], h["sum"]
+
+
+def test_greedy_ids_are_the_argmax_of_the_step_s_logits_rows_joining_and_leaving(
+        ids_engine):
+    """A decode step's ids equal argmax over the rows of last_logits, which
+    is what the host sampler chose: 18 steps, a second row joining at step 3
+    and leaving at its length, a third joining at step 8; the first token
+    likewise from the prompt's logits. An all-greedy step fetches its ids
+    and nothing else (this decoder has no routers)."""
+    eng = ids_engine
+    a = eng.start(GenRequest([3, 1, 4, 1, 5], max_new_tokens=19, eos_id=NO_EOS))
+    assert a.tokens == [int(np.argmax(eng.last_prefill_logits))]
+    joins = {3: GenRequest([9, 2, 6], max_new_tokens=6, eos_id=NO_EOS),
+             8: GenRequest([7, 7, 1, 2], max_new_tokens=12, eos_id=NO_EOS)}
+    live, seen = [a], [a]
+    count0, sum0 = _fetch_hist("tgen_ids")
+    try:
+        for step in range(18):
+            if step in joins:
+                run = eng.start(joins[step])
+                assert run.tokens == [int(np.argmax(eng.last_prefill_logits))]
+                live.append(run)
+                seen.append(run)
+            eng.decode_step(live)
+            assert eng._last_logits._host is None  # the step copied no logits
+            rows = eng.last_logits
+            for run in live:
+                assert run.tokens[-1] == int(np.argmax(rows[run.slot])), step
+            assert eng.stats()["decode_fetch_bytes"] == 3 * 4
+            for run in [r for r in live if r.done]:
+                eng.finish(run)
+                live.remove(run)
+    finally:
+        for run in live:
+            eng.finish(run)
+    assert [len(r.tokens) for r in seen] == [19, 6, 11]
+    assert [r.finish_reason for r in seen] == ["length", "length", None]
+    count1, sum1 = _fetch_hist("tgen_ids")
+    assert (count1 - count0, sum1 - sum0) == (18, 18 * 12)
+    # the serial reference path replies with the same tokens
+    again = eng.generate([3, 1, 4, 1, 5], max_new_tokens=19, eos_id=NO_EOS)
+    assert again.tokens == seen[0].tokens
+
+
+def test_a_tie_goes_to_the_first_index_as_numpy_s_argmax():
+    """The last norm's scale at 0 and its shift at b make every row's hidden
+    state b; head columns 3 and 7 equal b and the others 0: logits 3 and 7
+    tie at |b|^2 above the rest, and the device's id is 3, the first."""
+    eng = _ids_engine("tgen_tie")
+    model = eng.model
+    b = np.linspace(0.5, 1.5, IDS_KW["d_model"]).astype(np.float32)
+    head = np.zeros((IDS_KW["d_model"], IDS_KW["vocab_size"]), np.float32)
+    head[:, 3] = head[:, 7] = b
+    eng.set_params({
+        model._p("lnf_w"): np.zeros_like(b), model._p("lnf_b"): b,
+        model._p("head_w"): head})
+    runs = [eng.start(GenRequest(p, max_new_tokens=4, eos_id=NO_EOS))
+            for p in ([2, 5, 8], [11, 4])]
+    try:
+        row = eng.last_prefill_logits
+        assert row[3] == row[7] == row.max() > 0
+        assert [r.tokens for r in runs] == [[3], [3]]
+        eng.decode_step(runs)
+        rows = eng.last_logits
+        for run in runs:
+            row = rows[run.slot]
+            assert row[3] == row[7] == row.max() > 0
+            assert run.tokens[-1] == int(np.argmax(row)) == 3
+    finally:
+        for run in runs:
+            eng.finish(run)
+
+
+def test_a_step_mixing_greedy_and_sampled_rows_keeps_the_seeded_stream(
+        ids_engine):
+    """Rows with a temperature get the tokens the host sampler gives for the
+    same seed from that row's logits, the greedy row its argmax; the step
+    fetches the ids and one row of logits for each sampled row."""
+    eng = ids_engine
+    vocab = IDS_KW["vocab_size"]
+    reqs = [
+        GenRequest([3, 1, 4], max_new_tokens=12, eos_id=NO_EOS),
+        GenRequest([2, 7, 1, 8], max_new_tokens=12, eos_id=NO_EOS,
+                   temperature=0.8, top_k=5, seed=7),
+        GenRequest([6, 6], max_new_tokens=12, eos_id=NO_EOS,
+                   temperature=1.3, seed=9),
+    ]
+    rngs = [None, np.random.default_rng(7), np.random.default_rng(9)]
+
+    def want(req, rng, row):
+        if rng is None:
+            return int(np.argmax(row))
+        return _host_sample(row, req.temperature, req.top_k, rng)
+
+    runs = []
+    try:
+        for req, rng in zip(reqs, rngs):
+            runs.append(eng.start(req))
+            assert runs[-1].tokens == [want(req, rng, eng.last_prefill_logits)]
+        for step in range(10):
+            # the last steps leave the second sampled row out
+            live = runs if step < 6 else runs[:2]
+            count0, sum0 = _fetch_hist("tgen_ids")
+            eng.decode_step(live)
+            fetched = 3 * 4 + (len(live) - 1) * vocab * 4
+            assert eng.stats()["decode_fetch_bytes"] == fetched
+            count1, sum1 = _fetch_hist("tgen_ids")
+            assert (count1 - count0, sum1 - sum0) == (1, fetched)
+            rows = eng.last_logits
+            for run, req, rng in list(zip(runs, reqs, rngs))[:len(live)]:
+                assert run.tokens[-1] == want(req, rng, rows[run.slot]), step
+    finally:
+        for run in runs:
+            eng.finish(run)
+    assert len({tuple(r.tokens) for r in runs}) == 3
+
+
+def test_last_logits_is_read_on_demand_from_the_step_s_own_output(ids_engine):
+    eng = ids_engine
+    run = eng.start(GenRequest([5, 3, 2], max_new_tokens=4, eos_id=NO_EOS))
+    try:
+        assert eng._last_prefill_logits._host is None
+        first = eng.last_prefill_logits
+        np.testing.assert_array_equal(
+            first, np.asarray(eng._last_prefill_logits.dev)[0])
+        eng.decode_step([run])
+        held = eng._last_logits
+        assert held._host is None  # nothing copied until someone asks
+        eager = np.asarray(held.dev)
+        rows = eng.last_logits
+        assert rows.shape == (3, IDS_KW["vocab_size"])
+        assert rows.dtype == np.float32
+        np.testing.assert_array_equal(rows, eager)
+        assert eng.last_logits is rows  # the second read copies nothing
+        assert eng.last_prefill_logits.base is first.base
+        eng.decode_step([run])
+        assert eng._last_logits is not held  # the next step's own
+    finally:
+        eng.finish(run)
+
+
+def test_the_decode_variant_without_the_ids_is_another_cache_key(
+        tmp_path, monkeypatch):
+    """A cache directory filled by an engine whose variants fetch no ids
+    (the key and the module of every engine before the ids) misses: the
+    variants are traced again, never replayed, and then hit their own."""
+    from paddle_tpu.serving import generation
+
+    cache = str(tmp_path / "gen_cache")
+
+    def make():
+        return GenerationEngine(
+            GPTDecoder(**IDS_KW), name="tgen_key", max_slots=3, page_size=4,
+            max_context=48, prefill_buckets=(4,), cache_dir=cache)
+
+    with monkeypatch.context() as m:
+        m.setattr(generation, "_emit_ids", lambda main, fetches: list(fetches))
+        old = make()
+        old._variant("decode")
+        old._variant("prefill:4")
+        assert (old.traces, old.cache_hits) == (2, 0)
+        old_keys = set(os.listdir(cache))
+
+    def boot():
+        eng = make()
+        eng.warmup()
+        return eng
+
+    new = boot()
+    assert (new.traces, new.cache_hits) == (2, 0)
+    assert old_keys < set(os.listdir(cache))
+    warm = boot()
+    assert (warm.traces, warm.cache_hits) == (0, 2)
+    assert warm.generate([1, 2, 3], max_new_tokens=3).tokens == new.generate(
+        [1, 2, 3], max_new_tokens=3).tokens
+
+
+def test_warmup_leaves_nothing_to_compile_for_a_sampled_row():
+    import jax.monitoring
+
+    eng = _ids_engine("tgen_warm")
+    compiles = []
+    listening = [True]
+
+    def listen(event, duration, **_):
+        if listening[0] and event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    traces = eng.traces
+    runs = [
+        eng.start(GenRequest([3, 1, 4], max_new_tokens=4, eos_id=NO_EOS)),
+        eng.start(GenRequest([2, 7], max_new_tokens=4, eos_id=NO_EOS,
+                             temperature=0.9, top_k=3, seed=1)),
+    ]
+    try:
+        eng.decode_step(runs)
+        eng.decode_step(runs[1:])
+    finally:
+        listening[0] = False
+        for run in runs:
+            eng.finish(run)
+    assert compiles == [] and eng.traces == traces

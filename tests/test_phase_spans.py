@@ -173,6 +173,55 @@ def test_scheduler_phases_partition_the_iteration(tmp_path):
         assert snap["serving/phasegen/gen_%s_ms" % leaf]["count"] == steps
 
 
+def test_engine_leaves_partition_a_decode_step_whatever_its_rows_fetch(tmp_path):
+    """The ids come from the device and a sampled row fetches its logits:
+    the decode step is still feed, dispatch, wait, sample in that order,
+    none overlapping the next, once a step each; the first three fill the
+    step's wall (gen_step_ms ends where sample starts) and gen_fetch_bytes
+    observes once a step."""
+    eng = make_engine("leafgen")
+    runs = [
+        eng.start(GenRequest([1, 2, 3, 4, 5], max_new_tokens=12, eos_id=NO_EOS)),
+        eng.start(GenRequest([7, 8, 9], max_new_tokens=12, eos_id=NO_EOS,
+                             temperature=0.8, top_k=4, seed=3)),
+    ]
+    steps = 8
+    try:
+        with Session(tmp_path / "prof") as prof:
+            for step in range(steps):
+                eng.iter = step + 1
+                eng.decode_step(runs if step % 2 else runs[:1])
+    finally:
+        for r in runs:
+            eng.finish(r)
+    by_iter = {}
+    for s in prof.spans:
+        if s["name"].startswith("engine.") and s["tags"]["kind"] == "decode":
+            by_iter.setdefault(s["tags"]["iter"], []).append(s)
+    assert len(by_iter) == steps
+    covered_share = []
+    for group in by_iter.values():
+        group.sort(key=lambda s: s["start"])
+        assert [s["name"] for s in group] == ["engine." + p for p in ENGINE_LEAVES]
+        for a, b in zip(group, group[1:]):
+            assert a["end"] <= b["start"]
+        covered = sum(s["end"] - s["start"] for s in group)
+        covered_share.append(covered / (group[-1]["end"] - group[0]["start"]))
+    assert np.median(covered_share) >= 0.9, sorted(covered_share)
+    snap = registry.default_registry().snapshot()
+    for leaf in ENGINE_LEAVES:
+        assert snap["serving/leafgen/gen_%s_ms" % leaf]["count"] == steps
+    assert snap["serving/leafgen/gen_step_ms"]["count"] == steps
+    fetched = snap["serving/leafgen/gen_fetch_bytes"]
+    assert (fetched["count"], fetched["sum"]) == (
+        steps, steps * 3 * 4 + (steps // 2) * MODEL_KW["vocab_size"] * 4)
+    to_sample = sum(hist_sum("leafgen", "gen_%s_ms" % leaf)
+                    for leaf in ENGINE_LEAVES[:3])
+    step_ms = hist_sum("leafgen", "gen_step_ms")
+    assert to_sample <= step_ms * (1 + 1e-6)
+    assert step_ms - to_sample <= 0.1 * step_ms + 0.5 * steps
+
+
 def test_ctx_tokens_counts_the_positions_of_a_hand_built_step():
     eng = make_engine("ctxgen")
     runs = [eng.start(GenRequest(list(range(1, n + 1)), max_new_tokens=4,
