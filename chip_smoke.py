@@ -615,6 +615,35 @@ def phase_kernels():
             ref = jax.jit(lambda ins: paged(None, ins, attrs)["Out"][0])(ins)
             _close(name, out, ref, MXU_RTOL, MXU_ATOL)
             done[name] = "compiled"
+
+        # the latent paged walk (one pool read once as key and value) at
+        # xing4_docs_chat's widths, against the op's dense lowering
+        n_head, width, dv, ps, n_pages, slots = 32, 640, 512, 64, 32, 8
+        latent = registry.get("mla_attention").lower
+        pool = jnp.asarray(
+            rng.randn((slots * n_pages + 1) * ps, width) * 0.5, bf16
+        ).at[:, 576:].set(0)
+        attrs = {"page_size": ps, "d_value": dv, "sm_scale": 0.13}
+        table = rng.permutation(np.arange(1, slots * n_pages + 1)).astype("int32")
+        cases = {
+            "paged_latent decode": (
+                slots, table.reshape(slots, n_pages),
+                np.array([0, 63, 64, 511, 512, 1500, 2047, -1], "int32")),
+            "paged_latent shared": (
+                64, table[:n_pages], (1300 + np.arange(64)).astype("int32")),
+        }
+        for name, (rows, bt, pos) in cases.items():
+            ins = {
+                "Q": [jnp.asarray(rng.randn(rows, n_head * width) * 0.5, bf16)],
+                "Pool": [pool], "BlockTable": [jnp.asarray(bt)],
+                "Pos": [jnp.asarray(pos)],
+            }
+            flags.set_flags({"paged_flash": "on"})
+            out = _compiled(name, lambda ins: latent(None, ins, attrs)["Out"][0], ins)
+            flags.set_flags({"paged_flash": "off"})
+            ref = jax.jit(lambda ins: latent(None, ins, attrs)["Out"][0])(ins)
+            _close(name, out, ref, MXU_RTOL, MXU_ATOL)
+            done[name] = "compiled"
     finally:
         flags.set_flags(prev)
 

@@ -81,6 +81,9 @@ __all__ = [
     "paged_attention",
     "rms_norm",
     "rotary_embedding",
+    "hyper_connection",
+    "hyper_connection_post",
+    "mla_attention",
     "swiglu",
     "short_conv",
     "moe_router",
@@ -1430,16 +1433,95 @@ def rms_norm(x, epsilon=1e-5, groups=1, param_attr=None, name=None):
     return out
 
 
-def rotary_embedding(x, pos, n_head, theta=10000.0, name=None):
-    """Rotary position embedding (half-rotation form) of ``[rows, n_head *
-    d_head]`` at the rows' positions ``pos``."""
+def rotary_embedding(x, pos, n_head, theta=10000.0, rotary_dim=None,
+                     form="half", yarn=None, mscale=1.0, name=None):
+    """Rotary position embedding of ``[rows, n_head * d_head]`` at the rows'
+    positions ``pos``: of the whole head, or of its last ``rotary_dim``
+    features; ``form`` "half" (pairs ``(i, i + d/2)``, rotate_half) or
+    "interleaved" (pairs ``(2i, 2i + 1)``); ``yarn`` ``[factor, original
+    positions, beta_fast, beta_slow]`` blends the frequencies as YaRN does
+    (ops/decoder_ops.py ``yarn_inv_freq``); cos and sin times ``mscale``."""
     helper = LayerHelper("rotary_embedding", name=name)
     out = helper.create_variable_for_type_inference(x.dtype)
+    attrs = {"n_head": int(n_head), "theta": float(theta)}
+    if rotary_dim:
+        attrs["rotary_dim"] = int(rotary_dim)
+    if form != "half":
+        attrs["form"] = str(form)
+    if yarn:
+        attrs["yarn"] = [float(v) for v in yarn]
+    if float(mscale) != 1.0:
+        attrs["mscale"] = float(mscale)
     helper.append_op(
         type="rotary_embedding",
         inputs={"X": [x.name], "Pos": [pos.name]},
         outputs={"Out": [out.name]},
-        attrs={"n_head": int(n_head), "theta": float(theta)},
+        attrs=attrs,
+    )
+    return out
+
+
+def hyper_connection(x, streams, iters, eps, clamp, norm_eps=1e-6,
+                     w_attr=None, alpha_attr=None, bias_attr=None, name=None):
+    """The read side of a hyper-connection around one sub-layer
+    (ops/decoder_ops.py ``hyper_connection``): ``x`` is the residual stream
+    ``[rows, streams * d]``; returns ``(u [rows, d], maps)``, what the
+    sub-layer reads and the float32 maps ``hyper_connection_post`` writes
+    its result back with. Its parameters are float32: ``W [streams * d,
+    2 * streams + streams^2]``, ``Alpha [3]``, ``Bias [2 * streams +
+    streams^2]``."""
+    helper = LayerHelper("hyper_connection", name=name)
+    n = int(streams)
+    cols = 2 * n + n * n
+    w = helper.create_parameter(
+        attr=w_attr, shape=[int(x.shape[-1]), cols], dtype="float32")
+    alpha = helper.create_parameter(attr=alpha_attr, shape=[3], dtype="float32")
+    bias = helper.create_parameter(
+        attr=bias_attr, shape=[cols], dtype="float32", is_bias=True)
+    out = helper.create_variable_for_type_inference(x.dtype)
+    maps = helper.create_variable_for_type_inference("float32")
+    helper.append_op(
+        type="hyper_connection",
+        inputs={"X": [x.name], "W": [w.name], "Alpha": [alpha.name],
+                "Bias": [bias.name]},
+        outputs={"Out": [out.name], "Maps": [maps.name]},
+        attrs={"streams": n, "iters": int(iters), "eps": float(eps),
+               "clamp_min": float(clamp[0]), "clamp_max": float(clamp[1]),
+               "norm_eps": float(norm_eps)},
+    )
+    return out, maps
+
+
+def hyper_connection_post(x, y, maps, streams, name=None):
+    """The write side: the stream ``x`` mixed by the maps' ``H_res`` plus
+    ``y`` spread by their ``H_post``; ``[rows, streams * d]``."""
+    helper = LayerHelper("hyper_connection_post", name=name)
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op(
+        type="hyper_connection_post",
+        inputs={"X": [x.name], "Y": [y.name], "Maps": [maps.name]},
+        outputs={"Out": [out.name]},
+        attrs={"streams": int(streams)},
+    )
+    return out
+
+
+def mla_attention(q, pool, block_table, pos, page_size, d_value, sm_scale,
+                  name=None):
+    """Latent attention, absorbed form, over one paged pool whose row is
+    every head's key and, its first ``d_value`` columns, their value
+    (ops/generation_ops.py ``mla_attention``). ``q`` is ``[rows, n_head *
+    width]`` with ``width`` the pool's; returns ``[rows, n_head *
+    d_value]``."""
+    helper = LayerHelper("mla_attention", name=name)
+    out = helper.create_variable_for_type_inference(q.dtype)
+    helper.append_op(
+        type="mla_attention",
+        inputs={"Q": [q.name], "Pool": [pool.name],
+                "BlockTable": [block_table.name], "Pos": [pos.name]},
+        outputs={"Out": [out.name]},
+        attrs={"page_size": int(page_size), "d_value": int(d_value),
+               "sm_scale": float(sm_scale)},
     )
     return out
 

@@ -4,7 +4,8 @@ so that a configuration is data. It implements the GenerationEngine's model
 protocol (``build_prefill`` / ``build_decode`` / ``build_forward`` /
 ``cache_specs`` / ``ensure_params``) over one shared parameter set.
 
-A layer is ``h = x + OP(norm(x)); x' = h + FF(norm(h))`` with
+A layer is two sub-layers, ``h = x + OP(norm(x)); x' = h + FF(norm(h))``
+under the ``plain`` residual, with
 
   * OP ``"attention"``: grouped-query attention, per-head RMS norm of q and
     k before rotary positions (half-rotation form), causal, through
@@ -13,20 +14,43 @@ A layer is ``h = x + OP(norm(x)); x' = h + FF(norm(h))`` with
   * OP ``"conv"``: the gated short convolution (ops/decoder_ops.py
     ``short_conv``), whose per-sequence state — ``conv_taps - 1`` rows of
     ``d_model`` — lives in a per-slot state pool beside the pages;
+  * OP ``"mla"``: latent attention (DeepSeek-V2's MLA). The query goes
+    through a low-rank bottleneck with its own norm; one projection gives
+    the latent ``c_kv`` (``kv_rank`` wide, RMS-normed) and a rotary key
+    ``k_r`` (``d_rope`` wide) that every head shares; each head's query is
+    ``[q_nope | q_rope]``, its key ``[c_kv W_uk_h | k_r]``, its value ``c_kv
+    W_uv_h``. The cache is ONE paged pool a layer whose row is ``[c_kv | k_r
+    | zeros to whole lane tiles]``, and the served path attends in the
+    absorbed form: ``q_nope W_uk_h^T`` against ``c_kv`` (``mla_attention``
+    reads each pooled row once for all the heads), the context ``softmax
+    c_kv`` through ``W_uv_h``. Rotary is partial (the ``d_rope`` columns),
+    interleaved pairs, YaRN frequencies where the configuration scales them;
   * FF ``"dense"``: SwiGLU of width ``d_ffn``;
   * FF ``"moe"``: sigmoid top-k router over ``num_experts`` and the held
     experts' gated feed-forwards of width ``moe_width`` (``moe_router`` /
-    ``moe_experts``; ``experts_held`` is the chip's share, default all).
+    ``moe_experts``; ``experts_held`` is the chip's share, default all),
+    plus, with ``shared_width``, a dense SwiGLU of that width that every row
+    takes (the shared expert: plain ``fc`` / ``swiglu`` ops).
 
-The head is tied to the token embedding; there is no position embedding.
+Under the ``hyper`` residual (``hyper={streams, iters, eps, clamp}``) the
+stream between layers is ``streams`` copies wide, ``[rows, streams *
+d_model]``: each sub-layer reads a learned, input-dependent mix of the
+copies and writes back through a doubly stochastic mix of them
+(``hyper_connection`` / ``hyper_connection_post``, ops/decoder_ops.py). The
+embedding is repeated into every copy and the copies are summed before the
+last norm.
+
+The head is tied to the token embedding unless ``tied_head=False`` (its own
+``[vocab, d_model]`` matrix); there is no position embedding.
 Parameters, activations and caches are in ``dtype`` (bfloat16 as published;
 float32 for tight tests); norm scales, the router's matrix and bias and the
 conv taps are float32, and the norms, router, softmax and the conv's
 accumulation compute in float32 whatever ``dtype`` is. Logits leave the
 last product in float32.
 
-``lfm2_moe(config)`` turns an ``lfm2_moe`` ``config.json`` into such a
-decoder: the architecture is data for this module.
+``lfm2_moe(config)`` and ``xing4_0(config)`` turn a ``config.json`` of
+those model types into such a decoder: the architecture is data for this
+module.
 """
 
 import numpy as np
@@ -38,9 +62,10 @@ from ..initializer import Normal
 from ..layer_helper import LayerHelper
 from ..param_attr import ParamAttr
 
-__all__ = ["BlockDecoder", "lfm2_moe"]
+__all__ = ["BlockDecoder", "lfm2_moe", "xing4_0"]
 
-OPERATORS = ("attention", "conv")
+OPERATORS = ("attention", "conv", "mla")
+_LANES = 128  # a latent pool's row is padded to whole lane tiles
 FEED_FORWARDS = ("dense", "moe")
 
 
@@ -48,10 +73,14 @@ class BlockDecoder:
     def __init__(self, vocab_size, d_model, blocks, n_head, n_kv_head, d_head,
                  d_ffn, moe=None, conv_taps=3, norm_eps=1e-5, rope_theta=1e6,
                  max_context=2048, eos_id=-1, prefix="bd", dtype="bfloat16",
-                 init_std=0.02):
+                 init_std=0.02, mla=None, hyper=None, tied_head=True):
         """``blocks`` is ``[(operator, feed_forward)]``, a layer each.
         ``moe`` is ``{num_experts, k, width, use_bias, norm_topk, scale,
-        experts_held}`` (the last optional)."""
+        experts_held, shared_width}`` (the last two optional). ``mla`` is
+        ``{q_rank, kv_rank, d_nope, d_rope, d_value, sm_scale, yarn,
+        rope_mscale}`` (``yarn`` = [factor, original positions, beta_fast,
+        beta_slow] or None). ``hyper`` is ``{streams, iters, eps, clamp}``
+        or None for the plain residual."""
         self.blocks = [(str(o), str(f)) for o, f in blocks]
         for o, f in self.blocks:
             if o not in OPERATORS or f not in FEED_FORWARDS:
@@ -69,6 +98,11 @@ class BlockDecoder:
         self.moe = dict(moe or {})
         if any(f == "moe" for _, f in self.blocks) and not self.moe:
             raise ValueError("a moe block needs the moe settings")
+        self.mla = dict(mla or {})
+        if any(o == "mla" for o, _ in self.blocks) and not self.mla:
+            raise ValueError("an mla block needs the mla settings")
+        self.hyper = dict(hyper) if hyper else None
+        self.tied_head = bool(tied_head)
         self.conv_taps = int(conv_taps)
         self.norm_eps = float(norm_eps)
         self.rope_theta = float(rope_theta)
@@ -92,7 +126,20 @@ class BlockDecoder:
             "d_head": self.d_head, "d_model": self.d_model,
             "d_ffn": self.d_ffn, "conv_taps": self.conv_taps,
             "moe": {k: self.moe[k] for k in sorted(self.moe)},
+            "mla": {k: self.mla[k] for k in sorted(self.mla)},
+            "hyper": self.hyper, "tied_head": self.tied_head,
         }
+
+    @property
+    def experts_held(self):
+        """The global ids of the experts this decoder holds (None: all)."""
+        return self.moe.get("experts_held")
+
+    def latent_width(self):
+        """(values, columns) of a latent pool's row: [c_kv | k_r] and the
+        whole lane tiles it is padded to."""
+        values = self.mla["kv_rank"] + self.mla["d_rope"]
+        return values, -(-values // _LANES) * _LANES
 
     def moe_layers(self):
         return [i for i, (_, f) in enumerate(self.blocks) if f == "moe"]
@@ -103,6 +150,9 @@ class BlockDecoder:
             parts = ["op_norm_w"]
             if op == "conv":
                 parts += ["conv_in_w", "conv_k", "conv_out_w"]
+            elif op == "mla":
+                parts += ["q_a_w", "q_a_norm_w", "q_b_w", "kv_a_w",
+                          "kv_a_norm_w", "uk_w", "uv_w", "o_w"]
             else:
                 parts += ["q_w", "k_w", "v_w", "q_norm_w", "k_norm_w", "o_w"]
             parts.append("ffn_norm_w")
@@ -113,15 +163,24 @@ class BlockDecoder:
                 if self.moe.get("use_bias", True):
                     parts.append("router_b")
                 parts += ["exp_w1", "exp_w3", "exp_w2"]
+                if self.moe.get("shared_width"):
+                    parts += ["sh_w1", "sh_w3", "sh_w2"]
+            if self.hyper:
+                parts += [w + s for w in ("op", "ffn")
+                          for s in ("_hc_w", "_hc_a", "_hc_b")]
             names += [self._p("l%d" % i, s) for s in parts]
         names.append(self._p("final_norm_w"))
+        if not self.tied_head:
+            names.append(self._p("head_w"))
         return names
 
     def cache_specs(self):
         """One entry a cache tensor, in layer order: ``kind`` "paged" (a
         ``[pool_rows, width]`` pool indexed through block tables) or "state"
         (a ``[max_slots + 1, width]`` pool of fixed per-slot rows, row 0
-        scratch)."""
+        scratch). An attention layer has a K and a V pool; an mla layer ONE
+        pool, ``form`` "latent", whose row holds ``values`` = kv_rank +
+        d_rope values and zeros up to ``width``."""
         specs = []
         for i, (op, _) in enumerate(self.blocks):
             li = "l%d" % i
@@ -131,6 +190,12 @@ class BlockDecoder:
                         "name": self._p(li, s), "kind": "paged", "layer": i,
                         "width": self.n_kv_head * self.d_head,
                         "dtype": self.dtype})
+            elif op == "mla":
+                values, width = self.latent_width()
+                specs.append({
+                    "name": self._p(li, "kv_latent"), "kind": "paged",
+                    "layer": i, "width": width, "values": values,
+                    "form": "latent", "dtype": self.dtype})
             else:
                 specs.append({
                     "name": self._p(li, "conv_state"), "kind": "state",
@@ -139,8 +204,10 @@ class BlockDecoder:
         return specs
 
     def kv_pool_names(self):
-        """[(k_pool, v_pool)] of the attention layers."""
-        paged = [s["name"] for s in self.cache_specs() if s["kind"] == "paged"]
+        """[(k_pool, v_pool)] of the attention layers (an mla layer's one
+        pool is in cache_specs alone)."""
+        paged = [s["name"] for s in self.cache_specs()
+                 if s["kind"] == "paged" and s.get("form") != "latent"]
         return list(zip(paged[0::2], paged[1::2]))
 
     # ------------------------------------------------------------ submodules
@@ -160,11 +227,78 @@ class BlockDecoder:
         return layers.fc(x, size=size, num_flatten_dims=1,
                          param_attr=self._attr(i, suffix), bias_attr=False)
 
-    def _embed(self, tokens):
-        return layers.embedding(
+    def _param(self, i, suffix, shape, dtype=None):
+        """A parameter no layer function makes: a matrix of several axes."""
+        return LayerHelper("block_decoder").create_parameter(
+            self._attr(i, suffix), shape, dtype or self.dtype)
+
+    def _embed(self, tokens, rows):
+        """[rows, d_model] token embeddings; under the hyper residual
+        [rows, streams * d_model], every copy the embedding."""
+        x = layers.reshape(layers.embedding(
             tokens, size=[self.vocab_size, self.d_model], dtype=self.dtype,
             param_attr=ParamAttr(name=self._p("tok_emb"),
-                                 initializer=Normal(0.0, self.init_std)))
+                                 initializer=Normal(0.0, self.init_std))),
+            [rows, self.d_model])
+        if self.hyper:
+            x = layers.concat([x] * self.hyper["streams"], axis=1)
+        return x
+
+    def _mla_rotary(self, x, pos, n_head, rotary_dim=None):
+        m = self.mla
+        return layers.rotary_embedding(
+            x, pos, n_head, self.rope_theta, rotary_dim=rotary_dim,
+            form="interleaved", yarn=m.get("yarn"),
+            mscale=m.get("rope_mscale", 1.0))
+
+    def _latent_row(self, parts, lead, axis):
+        """parts ([c | rope], shaped lead + [.]) joined and padded with
+        zeros to the latent pool's width."""
+        values, width = self.latent_width()
+        if width > values:
+            parts = parts + [layers.fill_constant(
+                lead + [width - values], self.dtype, 0.0)]
+        return layers.concat(parts, axis=axis)
+
+    def _mla_query(self, u, i, pos):
+        """The absorbed query [rows, n_head * width]: a head's [q_nope @
+        W_uk_h^T | rot(q_rope) | zeros], what meets a latent pool's row."""
+        m, li, rows = self.mla, "l%d" % i, int(u.shape[0])
+        h, dn, dr = self.n_head, m["d_nope"], m["d_rope"]
+        c_q = self._norm(self._linear(u, i, "q_a_w", m["q_rank"]),
+                         self._p(li, "q_a_norm_w"))
+        q = self._mla_rotary(
+            self._linear(c_q, i, "q_b_w", h * (dn + dr)), pos, h, dr)
+        q_nope, q_rope = layers.split(
+            layers.reshape(q, [rows, h, dn + dr]), [dn, dr], dim=2)
+        uk = self._param(i, "uk_w", [h, dn, m["kv_rank"]])
+        q_lat = layers.transpose(layers.matmul(
+            layers.transpose(q_nope, [1, 0, 2]), uk), [1, 0, 2])
+        return layers.reshape(
+            self._latent_row([q_lat, q_rope], [rows, h], 2),
+            [rows, h * self.latent_width()[1]])
+
+    def _mla_latent(self, u, i, pos):
+        """The row a position leaves in the cache: [rms(c_kv) | rot(k_r) |
+        zeros] [rows, width]."""
+        m, li = self.mla, "l%d" % i
+        c_kv, k_r = layers.split(
+            self._linear(u, i, "kv_a_w", m["kv_rank"] + m["d_rope"]),
+            [m["kv_rank"], m["d_rope"]], dim=1)
+        return self._latent_row(
+            [self._norm(c_kv, self._p(li, "kv_a_norm_w")),
+             self._mla_rotary(k_r, pos, 1)], [int(u.shape[0])], 1)
+
+    def _mla_output(self, ctx, i):
+        """Each head's context over c_kv [rows, n_head * kv_rank] through
+        its W_uv, then the output projection."""
+        m, rows, h = self.mla, int(ctx.shape[0]), self.n_head
+        uv = self._param(i, "uv_w", [h, m["kv_rank"], m["d_value"]])
+        v = layers.transpose(layers.matmul(layers.transpose(
+            layers.reshape(ctx, [rows, h, m["kv_rank"]]), [1, 0, 2]), uv),
+            [1, 0, 2])
+        return self._linear(
+            layers.reshape(v, [rows, h * m["d_value"]]), i, "o_w", self.d_model)
 
     def _qkv(self, u, i, pos):
         q = self._linear(u, i, "q_w", self.n_head * self.d_head)
@@ -198,28 +332,63 @@ class BlockDecoder:
             # that the `+ b` of the choice is exercised
             bias_attr=self._attr(i, "router_b", std=0.05))
         picks_out.append(picks)
-        return layers.moe_experts(
+        routed = layers.moe_experts(
             u, picks, weights, m["num_experts"], m["width"],
             experts_held=m.get("experts_held"),
             w1_attr=self._attr(i, "exp_w1"), w3_attr=self._attr(i, "exp_w3"),
             w2_attr=self._attr(i, "exp_w2"))
+        if not m.get("shared_width"):
+            return routed
+        # the shared expert: every row's, dense, outside op:moe_*
+        g = layers.swiglu(self._linear(u, i, "sh_w1", m["shared_width"]),
+                          self._linear(u, i, "sh_w3", m["shared_width"]))
+        return layers.elementwise_add(
+            routed, self._linear(g, i, "sh_w2", self.d_model))
+
+    def _sublayer(self, x, i, which, fn):
+        """One sub-layer under the residual scheme: x + fn(norm(x)), or the
+        same through a hyper-connection over the stream's copies. `which`
+        is "op" or "ffn": the names of its norm and its connection."""
+        norm = self._p("l%d" % i, which + "_norm_w")
+        if not self.hyper:
+            return layers.elementwise_add(x, fn(self._norm(x, norm)))
+        h = self.hyper
+        # alpha, bias from the seed at sizes at which the maps depend on the
+        # input and differ from a plain sum (a checkpoint's are learned)
+        u, maps = layers.hyper_connection(
+            x, h["streams"], h["iters"], h["eps"], h["clamp"],
+            norm_eps=self.norm_eps, w_attr=self._attr(i, which + "_hc_w"),
+            alpha_attr=self._attr(i, which + "_hc_a", std=0.1, mean=0.5),
+            bias_attr=self._attr(i, which + "_hc_b", std=0.5))
+        return layers.hyper_connection_post(
+            x, fn(self._norm(u, norm)), maps, h["streams"])
 
     def _layer(self, x, i, operator, live, picks_out):
-        """x: [rows, d_model]; operator(u, i) -> OP's output."""
-        op, ff = self.blocks[i]
-        li = "l%d" % i
-        o = operator(self._norm(x, self._p(li, "op_norm_w")), i)
-        h = layers.elementwise_add(x, o)
-        f = self._feed_forward(
-            self._norm(h, self._p(li, "ffn_norm_w")), i, ff, live, picks_out)
-        return layers.elementwise_add(h, f)
+        """x: the residual stream [rows, d_model] (hyper: streams times as
+        wide); operator(u, i) -> OP's output."""
+        ff = self.blocks[i][1]
+        h = self._sublayer(x, i, "op", lambda u: operator(u, i))
+        return self._sublayer(
+            h, i, "ffn",
+            lambda u: self._feed_forward(u, i, ff, live, picks_out))
 
     def _logits(self, x):
-        """[rows, vocab] float32: the last norm, then the tied head."""
+        """[rows, vocab] float32: the stream's copies summed (hyper), the
+        last norm, then the head (tied to the embedding, or its own)."""
+        if self.hyper:
+            x = layers.reduce_sum(layers.reshape(
+                x, [int(x.shape[0]), self.hyper["streams"], self.d_model]),
+                dim=1)
         h = self._norm(x, self._p("final_norm_w"))
         helper = LayerHelper("tied_head")
-        emb = framework.default_main_program().global_block().var(
-            self._p("tok_emb"))
+        if self.tied_head:
+            emb = framework.default_main_program().global_block().var(
+                self._p("tok_emb"))
+        else:
+            emb = helper.create_parameter(
+                ParamAttr(name=self._p("head_w"),
+                          initializer=Normal(0.0, self.init_std)),
+                [self.vocab_size, self.d_model], self.dtype)
         out = helper.create_variable_for_type_inference("float32")
         helper.append_op(
             type="matmul", inputs={"X": [h.name], "Y": [emb.name]},
@@ -244,12 +413,20 @@ class BlockDecoder:
 
     def _cached_operator(self, caches, table, pos, page_size, conv_state):
         """OP through the caches: attention writes its K/V rows and attends
-        the pool; conv reads and writes its state row(s)."""
+        the pool; mla writes its latent row and attends it absorbed; conv
+        reads and writes its state row(s)."""
         def operator(u, i):
             li = "l%d" % i
             if self.blocks[i][0] == "conv":
                 return self._conv(
                     u, i, state=caches[self._p(li, "conv_state")], **conv_state)
+            if self.blocks[i][0] == "mla":
+                pool = caches[self._p(li, "kv_latent")]
+                layers.kv_cache_write(
+                    pool, self._mla_latent(u, i, pos), table, pos, page_size)
+                return self._mla_output(layers.mla_attention(
+                    self._mla_query(u, i, pos), pool, table, pos, page_size,
+                    self.mla["kv_rank"], self.mla["sm_scale"]), i)
             q, k, v = self._qkv(u, i, pos)
             k_pool = caches[self._p(li, "kv_k")]
             v_pool = caches[self._p(li, "kv_v")]
@@ -279,11 +456,39 @@ class BlockDecoder:
             # one sequence are (group member, position)
             tri = layers.assign(np.tile(
                 np.triu(np.full((t, t), -1e9, "float32"), k=1), (group, 1)))
-            x = layers.reshape(self._embed(tokens), [batch * t, self.d_model])
+            x = self._embed(tokens, batch * t)
+            # mla: a sequence's rows are (position, head), every head of a
+            # position under the same mask
+            tri_mla = layers.assign(np.repeat(
+                np.triu(np.full((t, t), -1e9, "float32"), k=1),
+                self.n_head, axis=0)) if self.mla else None
+
+            def mla_operator(u, i):
+                # the absorbed form, dense, a sequence at a time
+                m = self.mla
+                q = self._mla_query(u, i, pos)
+                lat = self._mla_latent(u, i, pos)
+                width = self.latent_width()[1]
+                ctxs = []
+                for b in range(batch):
+                    rows = lambda y: layers.slice(y, [0], [b * t], [(b + 1) * t])
+                    lat_b = rows(lat)
+                    scores = layers.matmul(
+                        layers.reshape(rows(q), [t * self.n_head, width]),
+                        lat_b, transpose_y=True, alpha=m["sm_scale"])
+                    w = layers.softmax(layers.elementwise_add(
+                        layers.cast(scores, "float32"), tri_mla))
+                    ctxs.append(layers.reshape(layers.matmul(
+                        layers.cast(w, self.dtype),
+                        layers.slice(lat_b, [1], [0], [m["kv_rank"]])),
+                        [t, self.n_head * m["kv_rank"]]))
+                return self._mla_output(layers.concat(ctxs, axis=0), i)
 
             def operator(u, i):
                 if self.blocks[i][0] == "conv":
                     return self._conv(u, i, seq_len=t)
+                if self.blocks[i][0] == "mla":
+                    return mla_operator(u, i)
                 q, k, v = self._qkv(u, i, pos)
                 # [b, n_kv, group * t, d] against [b, n_kv, t, d]
                 q = layers.reshape(
@@ -335,7 +540,7 @@ class BlockDecoder:
             caches = self._cache_vars(pool_rows, state_rows)
             pos = layers.elementwise_add(
                 layers.assign(np.arange(t, dtype="int64")), start)
-            x = layers.reshape(self._embed(tokens), [t, self.d_model])
+            x = self._embed(tokens, t)
             operator = self._cached_operator(
                 caches, pages, pos, page_size,
                 dict(rows=state_row, start=start, live=live, mode="chunk"))
@@ -368,7 +573,7 @@ class BlockDecoder:
             live = data("dec_live", [slots], "int32")
             state_rows_ = data("dec_state_rows", [slots], "int32")
             caches = self._cache_vars(pool_rows, state_rows)
-            x = self._embed(tokens)
+            x = self._embed(tokens, slots)
             operator = self._cached_operator(
                 caches, table, positions, page_size,
                 dict(rows=state_rows_, mode="step"))
@@ -431,3 +636,75 @@ def lfm2_moe(config, **kw):
         d_ffn=int(c["intermediate_size"]), moe=moe,
         conv_taps=int(c["conv_L_cache"]), norm_eps=float(c["norm_eps"]),
         rope_theta=float(c["rope_theta"]), **kw)
+
+
+def xing4_0(config, **kw):
+    """The BlockDecoder of a ``xing4_0`` ``config.json`` (as a dict, under
+    the source's own keys): every layer latent attention (``q_lora_rank``,
+    ``kv_lora_rank``, ``qk_nope_head_dim``, ``qk_rope_head_dim``,
+    ``v_head_dim``; YaRN from ``rope_scaling``), the first
+    ``first_k_dense_replace`` layers with the dense feed-forward and the
+    rest ``n_routed_experts`` sigmoid top-k experts (``noaux_tc``: a bias
+    picks) beside ``n_shared_experts`` shared ones, a ``hc_mult``-stream
+    hyper-connection residual, an untied head. ``experts_held`` (keyword)
+    lists the routed experts this chip holds; the router keeps its width.
+    The multi-token-prediction module (``num_nextn_predict_layers``) is
+    built nowhere: the main model's forward pass does not read it. Further
+    keywords (max_context, dtype, eos_id, prefix) pass through."""
+    c = config
+    if int(c.get("n_group", 1)) != 1 or int(c.get("topk_group", 1)) != 1:
+        raise ValueError("group-limited routing (n_group > 1) is not built")
+    if c.get("scoring_func", "sigmoid") != "sigmoid":
+        raise ValueError("only the sigmoid router is built")
+    n, dense = int(c["num_hidden_layers"]), int(c["first_k_dense_replace"])
+    blocks = [("mla", "dense" if i < dense else "moe") for i in range(n)]
+    moe = {
+        "num_experts": int(c["n_routed_experts"]),
+        "k": int(c["num_experts_per_tok"]),
+        "width": int(c["moe_intermediate_size"]),
+        "use_bias": c.get("topk_method", "noaux_tc") == "noaux_tc",
+        "norm_topk": bool(c.get("norm_topk_prob", True)),
+        "scale": float(c.get("routed_scaling_factor", 1.0)),
+        "shared_width": (int(c.get("n_shared_experts") or 0)
+                         * int(c["moe_intermediate_size"])),
+    }
+    held = kw.pop("experts_held", None)
+    if held is not None:
+        moe["experts_held"] = [int(e) for e in held]
+    d_nope, d_rope = int(c["qk_nope_head_dim"]), int(c["qk_rope_head_dim"])
+    mla = {
+        "q_rank": int(c["q_lora_rank"]), "kv_rank": int(c["kv_lora_rank"]),
+        "d_nope": d_nope, "d_rope": d_rope, "d_value": int(c["v_head_dim"]),
+        "sm_scale": (d_nope + d_rope) ** -0.5, "yarn": None,
+        "rope_mscale": 1.0,
+    }
+    rs = c.get("rope_scaling")
+    if rs:
+        if rs.get("type") != "yarn":
+            raise ValueError("rope_scaling type %r is not built" % rs.get("type"))
+        factor = float(rs["factor"])
+        # YaRN's attention temperature: 0.1 * m * ln(factor) + 1
+        m_of = lambda m: 0.1 * float(m) * np.log(factor) + 1.0 if factor > 1 else 1.0
+        all_dim = m_of(rs.get("mscale_all_dim", 0))
+        mla["yarn"] = [factor, float(rs["original_max_position_embeddings"]),
+                       float(rs["beta_fast"]), float(rs["beta_slow"])]
+        mla["rope_mscale"] = float(m_of(rs.get("mscale", 1)) / all_dim)
+        mla["sm_scale"] = float(mla["sm_scale"] * all_dim * all_dim)
+    hyper = None
+    if int(c.get("hc_mult") or 1) > 1:
+        hyper = {
+            "streams": int(c["hc_mult"]),
+            "iters": int(c["hc_sinkhorn_iters"]), "eps": float(c["hc_eps"]),
+            "clamp": [float(c["mhc_h_res_clamp_min"]),
+                      float(c["mhc_h_res_clamp_max"])],
+        }
+    n_head = int(c["num_attention_heads"])
+    kw.setdefault("max_context", int(c["max_position_embeddings"]))
+    kw.setdefault("prefix", "xing4")
+    return BlockDecoder(
+        vocab_size=int(c["vocab_size"]), d_model=int(c["hidden_size"]),
+        blocks=blocks, n_head=n_head, n_kv_head=n_head,
+        d_head=d_nope + d_rope, d_ffn=int(c["intermediate_size"]), moe=moe,
+        norm_eps=float(c["rms_norm_eps"]), rope_theta=float(c["rope_theta"]),
+        mla=mla, hyper=hyper,
+        tied_head=bool(c.get("tie_word_embeddings", False)), **kw)

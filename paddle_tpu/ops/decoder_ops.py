@@ -1,5 +1,7 @@
 """Ops of the block-specified decoder (models/block_decoder.py): RMS norm,
-rotary position embedding, the gated feed-forward's activation, the gated
+rotary position embedding (whole or partial, plain or YaRN frequencies), the
+gated feed-forward's activation, the hyper-connection that reads and writes
+a residual stream of several copies, the gated
 short convolution with its fixed per-sequence state, and the mixture of
 experts (router, and a grouped expert product whose work follows the
 routing).
@@ -87,24 +89,60 @@ def _rms_norm(ctx, ins, attrs):
     return {"Out": [y.reshape(shape).astype(x.dtype)]}
 
 
+def yarn_inv_freq(dim, theta, factor, original, beta_fast, beta_slow):
+    """The `dim // 2` rotary frequencies under YaRN (arXiv:2309.00071, as the
+    published DeepSeek-V2/V3 modelling code computes them): pair i turns at
+    theta^(-2i/dim), divided by `factor` where it completes fewer than
+    beta_slow turns over the `original` positions, as it is where it
+    completes more than beta_fast, and a linear blend between the two
+    pair indices. numpy float64: constants of the program."""
+    extra = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+    def pair_of(turns):
+        return dim * np.log(original / (turns * 2 * np.pi)) / (2 * np.log(theta))
+
+    low = max(int(np.floor(pair_of(beta_fast))), 0)
+    high = min(int(np.ceil(pair_of(beta_slow))), dim - 1)
+    span = float(high - low) or 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low) / span, 0, 1)
+    return extra / float(factor) * ramp + extra * (1.0 - ramp)
+
+
 @register("rotary_embedding", no_grad=True, infer_shape=_like("X"))
 def _rotary_embedding(ctx, ins, attrs):
-    """Rotary position embedding over the whole head, half-rotation form
-    (rotate_half): out = x * cos + concat(-x2, x1) * sin, the angle of
-    feature pair i at position p being p * theta^(-2i/d)."""
+    """Rotary position embedding of the last `rotary_dim` features of every
+    head (0: the whole head), the features before them passed through. The
+    angle of feature pair i at position p is p * inv_freq_i, inv_freq =
+    theta^(-2i/d), or YaRN's blend of it when `yarn` = [factor, original
+    positions, beta_fast, beta_slow] is given (yarn_inv_freq); cos and sin
+    are scaled by `mscale`. Form "half" (rotate_half): pair i is features
+    (i, i + d/2), out = x * cos + concat(-x2, x1) * sin; form "interleaved":
+    pair i is features (2i, 2i + 1)."""
     (x,) = ins["X"]  # [rows, n_head * d]
     (pos,) = ins["Pos"]
     n_head = int(attrs["n_head"])
     theta = float(attrs.get("theta", 10000.0))
     rows = x.shape[0]
-    d = x.shape[-1] // n_head
-    inv = theta ** (-jnp.arange(0, d, 2, dtype=_F32) / d)
+    d_head = x.shape[-1] // n_head
+    d = int(attrs.get("rotary_dim") or 0) or d_head
+    yarn = attrs.get("yarn")
+    inv = (jnp.asarray(yarn_inv_freq(d, theta, *yarn), _F32) if yarn
+           else theta ** (-jnp.arange(0, d, 2, dtype=_F32) / d))
     ang = pos.reshape(rows, 1).astype(_F32) * inv[None, :]
-    cos = jnp.cos(ang)[:, None, :]
-    sin = jnp.sin(ang)[:, None, :]
-    xh = x.astype(_F32).reshape(rows, n_head, d)
-    x1, x2 = xh[..., : d // 2], xh[..., d // 2:]
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    mscale = float(attrs.get("mscale", 1.0))
+    cos = (jnp.cos(ang) * mscale)[:, None, :]
+    sin = (jnp.sin(ang) * mscale)[:, None, :]
+    xh = x.astype(_F32).reshape(rows, n_head, d_head)
+    keep, xr = xh[..., : d_head - d], xh[..., d_head - d:]
+    if attrs.get("form", "half") == "interleaved":
+        x1, x2 = xr[..., 0::2], xr[..., 1::2]
+        out = jnp.stack(
+            [x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1
+        ).reshape(rows, n_head, d)
+    else:
+        x1, x2 = xr[..., : d // 2], xr[..., d // 2:]
+        out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    out = jnp.concatenate([keep, out], -1)
     return {"Out": [out.reshape(x.shape).astype(x.dtype)]}
 
 
@@ -114,6 +152,98 @@ def _swiglu(ctx, ins, attrs):
     (g,) = ins["X"]
     (u,) = ins["Y"]
     return {"Out": [(jax.nn.silu(g.astype(_F32)) * u.astype(_F32)).astype(g.dtype)]}
+
+
+# ---------------------------------------------------------------------------
+# hyper-connection: a residual stream of n parallel copies
+# ---------------------------------------------------------------------------
+
+
+def sinkhorn(logits, iters, eps):
+    """exp(logits) [..., n, n] scaled to doubly stochastic: `iters` times,
+    every row over its sum + eps, then every column over its sum + eps."""
+    m = jnp.exp(logits)
+    for _ in range(int(iters)):
+        m = m / (jnp.sum(m, -1, keepdims=True) + eps)
+        m = m / (jnp.sum(m, -2, keepdims=True) + eps)
+    return m
+
+
+def _hyper_connection_infer(op, block):
+    x = _var(block, op, "X")
+    n = int(op.attrs["streams"])
+    u = _var(block, op, "Out", outputs=True)
+    u.shape, u.dtype = tuple(x.shape[:-1]) + (x.shape[-1] // n,), x.dtype
+    maps = _var(block, op, "Maps", outputs=True)
+    maps.shape, maps.dtype = tuple(x.shape[:-1]) + (n + n * n,), "float32"
+
+
+@register("hyper_connection", no_grad=True, infer_shape=_hyper_connection_infer)
+def _hyper_connection(ctx, ins, attrs):
+    """The read side of a manifold-constrained hyper-connection
+    (arXiv:2512.24880) around one sub-layer. X is the residual stream
+    [rows, n * d], stream j in columns j * d .. (j + 1) * d. All float32:
+
+        x~ = X * rsqrt(mean(X^2 over all n * d) + norm_eps)   (no scale)
+        [pre~ n | post~ n | res~ n * n] = x~ @ W * repeat(Alpha) + Bias
+        H_pre = sigmoid(pre~); H_post = 2 sigmoid(post~)
+        H_res = sinkhorn(clip(res~, clamp_min, clamp_max), iters, eps)
+
+    Out = sum_j H_pre[j] X_j, rounded to X's dtype: what the sub-layer
+    reads. Maps = [H_post | H_res row-major] float32 [rows, n + n * n], for
+    hyper_connection_post."""
+    (x,) = ins["X"]
+    (w,) = ins["W"]  # [n * d, 2n + n * n] float32
+    (alpha,) = ins["Alpha"]  # [3]: pre, post, res
+    (bias,) = ins["Bias"]  # [2n + n * n]
+    n = int(attrs["streams"])
+    rows = x.shape[0]
+    x32 = x.astype(_F32)
+    xt = x32 * jax.lax.rsqrt(
+        jnp.mean(x32 * x32, -1, keepdims=True)
+        + float(attrs.get("norm_eps", 1e-6)))
+    raw = jnp.dot(xt, w.astype(_F32), precision=jax.lax.Precision.HIGHEST)
+    a32 = alpha.astype(_F32)
+    gain = jnp.concatenate([
+        jnp.broadcast_to(a32[i:i + 1], (width,))
+        for i, width in enumerate((n, n, n * n))])
+    raw = raw * gain[None, :] + bias.astype(_F32)[None, :]
+    lo, hi = float(attrs["clamp_min"]), float(attrs["clamp_max"])
+    iters, eps = int(attrs["iters"]), float(attrs["eps"])
+    if _pk.hyper_maps_path_taken():
+        # one kernel for the sigmoids and the rounds (its time reads
+        # op:hyper_connection: no pallas_kernel= scope)
+        maps = _pk.hyper_maps(raw, n, iters, eps, (lo, hi))
+        h_pre, maps = maps[:, :n], maps[:, n:]
+    else:
+        h_pre = jax.nn.sigmoid(raw[:, :n])
+        h_res = sinkhorn(
+            jnp.clip(raw[:, 2 * n:], lo, hi).reshape(rows, n, n), iters, eps)
+        maps = jnp.concatenate([
+            2.0 * jax.nn.sigmoid(raw[:, n:2 * n]), h_res.reshape(rows, n * n)],
+            -1)
+    # n is small: broadcast products on the VPU, no batched 1 x n matmul
+    xs = x32.reshape(rows, n, -1)
+    u = sum(h_pre[:, j:j + 1] * xs[:, j] for j in range(n))
+    return {"Out": [u.astype(x.dtype)], "Maps": [maps]}
+
+
+@register("hyper_connection_post", no_grad=True, infer_shape=_like("X"))
+def _hyper_connection_post(ctx, ins, attrs):
+    """The write side: X'_j = sum_k H_res[j, k] X_k + H_post[j] * Y, in
+    float32, rounded to X's dtype. Maps is hyper_connection's."""
+    (x,) = ins["X"]  # [rows, n * d]
+    (y,) = ins["Y"]  # [rows, d]
+    (maps,) = ins["Maps"]
+    n = int(attrs["streams"])
+    rows = x.shape[0]
+    h_post, h_res = maps[:, :n], maps[:, n:].reshape(rows, n, n)
+    xs, y32 = x.astype(_F32).reshape(rows, n, -1), y.astype(_F32)
+    out = jnp.stack([
+        sum(h_res[:, j, k:k + 1] * xs[:, k] for k in range(n))
+        + h_post[:, j:j + 1] * y32
+        for j in range(n)], 1)
+    return {"Out": [out.reshape(x.shape).astype(x.dtype)]}
 
 
 # ---------------------------------------------------------------------------

@@ -203,3 +203,66 @@ def _paged_attention(ctx, ins, attrs):
     else:
         out = jnp.einsum("shc,schd->shd", w, v.astype(jnp.float32))
     return {"Out": [out.reshape(s, n_head * d).astype(q.dtype)]}
+
+
+def _mla_attention_infer(op, block):
+    q = block._var_recursive(op.inputs["Q"][0])
+    pool = block._var_recursive(op.inputs["Pool"][0])
+    out = block._var_recursive(op.outputs["Out"][0])
+    heads = q.shape[-1] // pool.shape[-1]
+    out.shape = tuple(q.shape[:-1]) + (heads * int(op.attrs["d_value"]),)
+    out.dtype = q.dtype
+
+
+@register("mla_attention", no_grad=True, infer_shape=_mla_attention_infer)
+def _mla_attention(ctx, ins, attrs):
+    """Latent attention in its absorbed form over ONE paged pool: a pooled
+    row ``[c_kv | k_rope | zero padding]`` is the key of every query head
+    and, its first ``d_value`` columns, their value. Q is ``[S, n_head *
+    width]`` (``width`` the pool's: a head's ``[q_nope @ W_uk | q_rope |
+    zeros]``), Out ``[S, n_head * d_value]``: each head's softmax-weighted
+    sum of ``c_kv``. BlockTable, Pos and the where-mask with its safe
+    softmax are paged_attention's. On TPU (or when FLAGS_paged_flash forces
+    it) the lowering is the Pallas walk latent_paged_attention, which reads
+    each pooled row once for all the heads; the dense form below is the
+    decline target and the parity oracle."""
+    (q,) = ins["Q"]
+    (pool,) = ins["Pool"]
+    (bt,) = ins["BlockTable"]
+    (pos,) = ins["Pos"]
+    page_size = int(attrs["page_size"])
+    d_value = int(attrs["d_value"])
+    scale = float(attrs["sm_scale"])
+    s, width = q.shape[0], pool.shape[-1]
+    n_head = q.shape[-1] // width
+    p = bt.shape[-1]
+    ctx_len = p * page_size
+
+    from . import pallas_kernels as _pk
+
+    if _pk.latent_paged_path_taken(s, p, page_size, n_head, width):
+        return {"Out": [_pk.latent_paged_attention(
+            q, pool, bt, pos, n_head=n_head, page_size=page_size,
+            d_value=d_value, sm_scale=scale)]}
+
+    qh = q.reshape(s, n_head, width).astype(jnp.float32)
+    offsets = jnp.arange(page_size, dtype=jnp.int32)
+    flat = (bt.astype(jnp.int32)[..., None] * page_size + offsets).reshape(
+        bt.shape[:-1] + (ctx_len,))
+    k = jnp.take(pool, flat.reshape(-1), axis=0).astype(jnp.float32).reshape(
+        flat.shape + (width,))
+    shared = bt.ndim == 1
+    scores = jnp.einsum("shw,cw->shc" if shared else "shw,scw->shc", qh, k)
+    live = (
+        jnp.arange(ctx_len, dtype=jnp.int32)[None, :]
+        <= pos.reshape(-1).astype(jnp.int32)[:, None]
+    )[:, None, :]
+    scores = jnp.where(live, scores * scale, -jnp.inf)
+    m = jnp.max(scores, axis=-1, keepdims=True)
+    m = jnp.where(jnp.isfinite(m), m, 0.0)
+    w = jnp.where(live, jnp.exp(scores - m), 0.0)
+    denom = jnp.sum(w, axis=-1, keepdims=True)
+    w = w / jnp.where(denom > 0.0, denom, 1.0)
+    out = jnp.einsum(
+        "shc,cv->shv" if shared else "shc,scv->shv", w, k[..., :d_value])
+    return {"Out": [out.reshape(s, n_head * d_value).astype(q.dtype)]}

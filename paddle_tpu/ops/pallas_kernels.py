@@ -47,6 +47,11 @@ __all__ = [
     "paged_flash_attention",
     "paged_flash_path_taken",
     "paged_positions_walked",
+    "latent_paged_attention",
+    "latent_paged_path_taken",
+    "latent_positions_walked",
+    "hyper_maps",
+    "hyper_maps_path_taken",
     "grouped_expert_ffn",
     "grouped_ffn_path_taken",
     "KERNELS_REVISION",
@@ -1461,7 +1466,7 @@ _PAGED_BUFFER_BYTES = 8 * 1024 * 1024
 # Folded into serving/compile_cache.variant_key: an exported serving module
 # embeds the Mosaic kernels it was lowered with, so a cached export must miss
 # when a kernel's lowering changes. Bump it with every such change.
-KERNELS_REVISION = "28-moe-grouped"
+KERNELS_REVISION = "31-paged-latent"
 
 
 def paged_flash_path_taken(n_q, n_pages, page_size, n_head, d_head):
@@ -1846,6 +1851,266 @@ def paged_flash_attention(q, k_pool, v_pool, block_table, pos, *, n_head,
     if per_row:
         out = out.reshape(rows, group, n_kv, d).transpose(0, 2, 1, 3)
     return out.reshape(rows, feat)
+
+
+# ---------------------------------------------------------------------------
+# latent paged attention (mla_attention): one pool whose row is the key of
+# every query head and, its leading columns, their value
+# ---------------------------------------------------------------------------
+
+# Positions in one compute block of the latent walk, and the tokens of a
+# prefill chunk one program owns (x n_head query rows: its MXU rows). A
+# decode program owns one token: its n_head query rows are the MXU's rows,
+# so the walk is two products a block whatever the heads. A chunk's programs
+# each walk the prefix again: 16 tokens x 32 heads are 512 rows against 1024
+# positions, 2 MiB of float32 scores.
+_LATENT_BLOCK_POSITIONS = 512
+_LATENT_BLOCK_POSITIONS_CHUNK = 1024
+_LATENT_CHUNK_TOKENS = 16
+_LATENT_VMEM_BYTES = 64 * 1024 * 1024
+
+
+def latent_paged_path_taken(n_q, n_pages, page_size, n_head, width):
+    """EXACT mirror of the mla_attention lowering's kernel-vs-dense decision,
+    under the paged_flash flag as paged_flash_path_taken: "auto" takes the
+    kernel on a TPU when a page is whole sublane tiles of bfloat16 and the
+    pool's row whole lane tiles."""
+    from .. import flags as _flags
+
+    mode = _flags.get_flags("paged_flash")["paged_flash"]
+    if mode == "off":
+        return False
+    if min(int(n_q), int(n_pages), int(page_size), int(n_head), int(width)) < 1:
+        return False
+    if mode == "on":
+        return True
+    return (jax.default_backend() == "tpu" and int(page_size) % 16 == 0
+            and int(width) % _LANES == 0)
+
+
+def _latent_block_pages(n_pages, page_size, shared):
+    most = _LATENT_BLOCK_POSITIONS_CHUNK if shared else _LATENT_BLOCK_POSITIONS
+    return max(1, min(most // int(page_size), int(n_pages)))
+
+
+def latent_positions_walked(pos, page_size, n_pages, shared=False):
+    """EXACT mirror of latent_paged_attention's trip count, in positions, as
+    paged_positions_walked is of paged_flash_attention's: each row (decode),
+    or each program's _LATENT_CHUNK_TOKENS rows at their largest position
+    (a prefill chunk, shared=True), takes pos // block + 1 blocks."""
+    block = int(page_size) * _latent_block_pages(n_pages, page_size, shared)
+    pos = np.asarray(pos, dtype=np.int64).reshape(-1)
+    if shared:
+        tokens = min(pos.size, _LATENT_CHUNK_TOKENS)
+        pad = -pos.size % tokens
+        pos = np.concatenate([pos, np.full(pad, -1)]).reshape(-1, tokens).max(1)
+    most = -(-int(n_pages) * int(page_size) // block)
+    return int(np.clip(pos // block + 1, 0, most).sum()) * block
+
+
+def _latent_paged_kernel(bt_ref, last_ref, pos_ref, q_ref, pool, o_ref, buf,
+                         sem, acc_ref, m_ref, l_ref, *, page_size, d_value,
+                         sm_scale, per_row, n_pages, block_pages):
+    """One program's query rows (tokens x heads, every head of a token a row)
+    against the pages one block table holds up to `last`, the program's
+    largest position. Every row has the same key and value: a pooled row is
+    the key, and its first d_value columns the value, of all of them, so a
+    block is read once and met by two MXU products, rows x block and block x
+    d_value. per_row (decode): the program is a slot, its table row
+    bt[program]; shared (a prefill chunk): every program walks the one
+    table. The walk, its double buffer and its safe softmax are
+    _paged_flash_kernel's."""
+    i = pl.program_id(0)
+    last = last_ref[i]
+    page_of = (lambda j: bt_ref[i, j]) if per_row else (lambda j: bt_ref[j])
+    pos = pos_ref[...]  # (rows, 1)
+    rows = q_ref.shape[0]
+    block = block_pages * page_size
+    n_blocks = jnp.clip(last // block + 1, 0, pl.cdiv(n_pages, block_pages))
+
+    @pl.when(i == 0)
+    def _once():  # what a page never copied leaves in a buffer stays finite
+        buf[...] = jnp.zeros_like(buf)
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+    def page_copies(b, slot, start):
+        first = b * block_pages
+        n_live = jnp.clip(
+            jnp.minimum(last // page_size + 1, n_pages) - first, 0, block_pages
+        )
+
+        def _page(j, _):
+            row0 = pl.multiple_of(page_of(first + j) * page_size, page_size)
+            copy = pltpu.make_async_copy(
+                pool.at[pl.ds(row0, page_size)], buf.at[slot, j], sem.at[slot])
+            copy.start() if start else copy.wait()
+
+        jax.lax.fori_loop(0, n_live, _page, None)
+
+    @pl.when(n_blocks > 0)
+    def _first():
+        page_copies(0, 0, True)
+
+    def _block(b, _):
+        slot = b % 2
+
+        @pl.when(b + 1 < n_blocks)
+        def _next():
+            page_copies(b + 1, 1 - slot, True)
+
+        page_copies(b, slot, False)
+        k = buf[slot].reshape(block, buf.shape[-1])
+        s = jax.lax.dot_general(
+            q_ref[...], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale
+        live = b * block + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, block), 1) <= pos
+        s = jnp.where(live, s, -jnp.inf)
+        m_prev, l_prev = m_ref[:, :1], l_ref[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        # exp(-inf - -inf) = nan: a row that has seen nothing keeps alpha 0
+        alpha = jnp.exp(jnp.where(m_prev == -jnp.inf, -jnp.inf, m_prev - m_new))
+        p = jnp.where(live, jnp.exp(s - m_new), 0.0)
+        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+            p.astype(k.dtype), k[:, :d_value],
+            preferred_element_type=jnp.float32)
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+
+    jax.lax.fori_loop(0, n_blocks, _block, None)
+    l = l_ref[:, :1]  # a fully-masked row (pos < 0) emits zeros, not 0 / 0
+    o_ref[...] = (acc_ref[...] / jnp.where(l > 0.0, l, 1.0)).astype(o_ref.dtype)
+
+
+def latent_paged_attention(q, pool, block_table, pos, *, n_head, page_size,
+                           d_value, sm_scale, interpret=None):
+    """Attention of n_head query heads over ONE paged pool whose row serves
+    every head as its key (all of its columns) and as its value (the first
+    d_value): latent attention in its absorbed form, where the pool holds
+    [c_kv | k_rope (| zero padding to whole lane tiles)] a position and q is
+    [rows, n_head * width], a head's [q_nope @ W_uk | q_rope (| zeros)].
+    Returns [rows, n_head * d_value] in q's dtype: each head's softmax-
+    weighted sum of c_kv, for the caller to take through W_uv. block_table,
+    pos and the masking are paged_flash_attention's: [rows, P] (decode) or
+    [P] (a prefill chunk); row r attends 0..pos[r]; pos < 0 emits zeros. A
+    pooled row is read once a program whatever n_head: a decode program is
+    one slot, a chunk's program _LATENT_CHUNK_TOKENS of its rows.
+    latent_positions_walked mirrors the walk."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    rows, feat = q.shape
+    width = feat // n_head
+    bt = block_table.astype(jnp.int32)
+    pos_v = pos.reshape(-1).astype(jnp.int32)
+    per_row = bt.ndim == 2
+    n_pages = bt.shape[-1]
+    block_pages = _latent_block_pages(n_pages, page_size, not per_row)
+    tokens = 1 if per_row else min(rows, _LATENT_CHUNK_TOKENS)
+    n_prog = pl.cdiv(rows, tokens)
+    if n_prog * tokens > rows:  # rows of no token: masked whole
+        q = jnp.pad(q, ((0, n_prog * tokens - rows), (0, 0)))
+        pos_v = jnp.pad(pos_v, (0, n_prog * tokens - rows), constant_values=-1)
+    own = tokens * n_head  # a program's query rows
+    _note_dispatch("paged_latent")
+    mine = lambda i, bt_r, last_r: (i, 0)
+    out = pl.pallas_call(
+        functools.partial(
+            _latent_paged_kernel, page_size=page_size, d_value=d_value,
+            sm_scale=float(sm_scale), per_row=per_row, n_pages=n_pages,
+            block_pages=block_pages,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n_prog,),
+            in_specs=[
+                pl.BlockSpec((own, 1), mine),
+                pl.BlockSpec((own, width), mine),
+                pl.BlockSpec(memory_space=pltpu.HBM),
+            ],
+            out_specs=pl.BlockSpec((own, d_value), mine),
+            scratch_shapes=[
+                pltpu.VMEM((2, block_pages, page_size, width), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((own, d_value), jnp.float32),  # acc
+                pltpu.VMEM((own, _LANES), jnp.float32),  # m
+                pltpu.VMEM((own, _LANES), jnp.float32),  # l
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((n_prog * own, d_value), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_LATENT_VMEM_BYTES,
+        ),
+        interpret=interpret,
+    )(
+        bt, pos_v.reshape(n_prog, tokens).max(axis=1),
+        jnp.repeat(pos_v, n_head).reshape(-1, 1),
+        q.astype(pool.dtype).reshape(n_prog * own, width), pool,
+    )
+    return out[: rows * n_head].reshape(rows, n_head * d_value)
+
+
+# ---------------------------------------------------------------------------
+# hyper-connection maps (hyper_connection): the sigmoids and the Sinkhorn
+# rounds of a residual stream's n copies, one kernel
+# ---------------------------------------------------------------------------
+
+
+def hyper_maps_path_taken():
+    """EXACT mirror of hyper_connection's kernel-vs-XLA decision: the kernel
+    on a TPU. The same rounds in jax.numpy are right everywhere and compact,
+    but on the chip each round's two sums are two tiny kernels (1600 a
+    decode step of 20 layers), and written out entry by entry so that XLA
+    fuses them they are 2,500 instructions a connection: 100k a program,
+    250 s of compilation a variant (my chip run, PR 31)."""
+    return jax.default_backend() == "tpu"
+
+
+def _hyper_maps_kernel(raw_ref, out_ref, *, n, iters, eps, lo, hi):
+    """raw_ref, out_ref: (2n + n * n, rows) float32, a row of the block one
+    entry of the maps for every token (tokens on the lanes): n sigmoids, n
+    doubled sigmoids, then exp(clip(.)) of the n x n entries through `iters`
+    Sinkhorn rounds. Row j of a token's matrix is the block's n rows from
+    2n + j * n, its entries on the sublanes: a row's sum is a sublane
+    reduction, the columns' sums the n blocks added."""
+    out_ref[0:n, :] = jax.nn.sigmoid(raw_ref[0:n, :])
+    out_ref[n:2 * n, :] = 2.0 * jax.nn.sigmoid(raw_ref[n:2 * n, :])
+    at = lambda j: slice(2 * n + j * n, 2 * n + (j + 1) * n)
+    m = tuple(jnp.exp(jnp.clip(raw_ref[at(j), :], lo, hi)) for j in range(n))
+
+    def one_round(_, m):
+        m = tuple(r / (jnp.sum(r, axis=0, keepdims=True) + eps) for r in m)
+        cols = sum(m[1:], m[0]) + eps
+        return tuple(r / cols for r in m)
+
+    m = jax.lax.fori_loop(0, iters, one_round, m)
+    for j in range(n):
+        out_ref[at(j), :] = m[j]
+
+
+def hyper_maps(raw, n, iters, eps, clamp, interpret=None):
+    """The three maps of a hyper-connection from their pre-activations raw
+    [rows, 2n + n * n] float32 ([pre | post | res row-major]): [sigmoid |
+    2 sigmoid | Sinkhorn(exp(clip(res, *clamp)), iters, eps)], the same
+    shape. One program holds every row: the arrays are a few KB."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    rows, cols = raw.shape
+    pad = -rows % _LANES  # tokens on the lanes, whole tiles of them
+    raw_t = jnp.pad(raw.astype(jnp.float32).T, ((0, 0), (0, pad)))
+    _note_dispatch("hyper_maps")
+    out = pl.pallas_call(
+        functools.partial(
+            _hyper_maps_kernel, n=int(n), iters=int(iters), eps=float(eps),
+            lo=float(clamp[0]), hi=float(clamp[1])),
+        out_shape=jax.ShapeDtypeStruct((cols, rows + pad), jnp.float32),
+        interpret=interpret,
+    )(raw_t)
+    return out[:, :rows].T
 
 
 # ---------------------------------------------------------------------------

@@ -261,7 +261,8 @@ CTX_TOKEN_BUCKETS = (
 )
 
 
-# gen_experts_touched / gen_expert_rows_max: small counts
+# gen_experts_touched / gen_expert_rows_max: small counts (of the experts
+# this chip holds: a pick of an expert held elsewhere is no work here)
 EXPERT_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024, 2048, 4096)
 
 
@@ -321,15 +322,22 @@ def _moe_kernel_layers(block):
     return n
 
 
-def _experts_touched(picks):
+def _experts_touched(picks, held=None):
     """(distinct experts picked, summed over the expert layers; the most
-    rows any one expert of any layer got) of picks [n_moe, live rows, k]."""
+    rows any one expert of any layer got) of picks [n_moe, live rows, k].
+    `held`: the global ids of the experts this chip holds (None: all); a
+    pick of an expert held elsewhere is no work here and is not counted,
+    as moe_experts does not visit it."""
     n_moe = picks.shape[0]
     width = int(picks.max()) + 1
     counts = np.bincount(
         (picks.reshape(n_moe, -1)
          + np.arange(n_moe, dtype=picks.dtype)[:, None] * width).ravel(),
-        minlength=n_moe * width)
+        minlength=n_moe * width).reshape(n_moe, width)
+    if held is not None:
+        counts = counts[:, [e for e in held if e < width]]
+    if not counts.size:
+        return 0, 0
     return int(np.count_nonzero(counts)), int(counts.max())
 
 
@@ -427,6 +435,11 @@ class GenerationEngine:
         self._kv_row_bytes = (
             paged[0]["width"] * jnp.dtype(paged[0]["dtype"]).itemsize
             if paged else 0)
+        # latent pools (one a layer, read once as key and value): the walk
+        # is ops.pallas_kernels.latent_paged_attention's
+        self._latent = bool(paged) and paged[0].get("form") == "latent"
+        # the routed experts this chip holds (None: all of them)
+        self._experts_held = getattr(model, "experts_held", None)
         self.pool.row_bytes = self.kv_state_bytes // pool_rows
         self.pool.state_bytes = self.seq_state_bytes
         self.prefix_cache = (
@@ -538,6 +551,25 @@ class GenerationEngine:
             "over all max_slots rows (ops.pallas_kernels."
             "paged_positions_walked)",
             buckets=CTX_TOKEN_BUCKETS,
+        )
+        self._m_chunk_ctx_tokens = reg.histogram(
+            p + "/gen_chunk_ctx_tokens",
+            "distinct positions one prefill chunk attends: its own rows and "
+            "the prefix before them",
+            buckets=CTX_TOKEN_BUCKETS,
+        )
+        self._m_chunk_ctx_pairs = reg.histogram(
+            p + "/gen_chunk_ctx_pairs",
+            "(row, position) pairs one prefill chunk attends: each real row "
+            "and every position up to its own",
+            buckets=tuple(b * 64 for b in CTX_TOKEN_BUCKETS),
+        )
+        self._m_feed_bytes = reg.histogram(
+            p + "/gen_feed_bytes",
+            "bytes one decode step feeds the device: tokens, positions, the "
+            "block tables (max_slots x max_context / page_size ids), the "
+            "live and state rows",
+            buckets=FETCH_BYTE_BUCKETS,
         )
         # whoever drives the engine step by step (the scheduler) writes its
         # iteration count here; every fluid.engine.* span and the request
@@ -1002,6 +1034,9 @@ class GenerationEngine:
         ).end()
         self._m_prefill_ms.observe(prefill_ms)
         self._m_chunks.inc()
+        self._m_chunk_ctx_tokens.observe(start + n_real)
+        self._m_chunk_ctx_pairs.observe(
+            n_real * start + n_real * (n_real + 1) // 2)
         run.pf_pos = start + n_real
         if extra:
             run.chunk_picks.append((start, n_real, extra[0]))
@@ -1019,7 +1054,8 @@ class GenerationEngine:
             ids = np.asarray(ids)
             for first, n_live, picks in run.chunk_picks:
                 picks = np.asarray(picks)[:, :n_live]
-                self._m_chunk_experts.observe(_experts_touched(picks)[0])
+                self._m_chunk_experts.observe(
+                    _experts_touched(picks, self._experts_held)[0])
                 if self.record_picks:
                     run.picks.append((first, picks))
             run.chunk_picks = []
@@ -1086,12 +1122,16 @@ class GenerationEngine:
                     live[run.slot] = 1
                     state_rows[run.slot] = run.slot + 1
                     ctx_tokens += run.next_pos
-                walked_tokens = _pk.paged_positions_walked(
-                    positions, self.page_size, self.max_pages,
-                    self._kv_row_bytes,
-                )
+                walked_tokens = (
+                    _pk.latent_positions_walked(
+                        positions, self.page_size, self.max_pages)
+                    if self._latent else _pk.paged_positions_walked(
+                        positions, self.page_size, self.max_pages,
+                        self._kv_row_bytes))
                 variant = self._variant("decode")
                 feeds, mut_in = self._feeds(variant, feeds)
+                self._m_feed_bytes.observe(
+                    sum(a.nbytes for a in feeds.values()))
             with self._phase("dispatch", "decode") as dispatch:
                 # the step's fetch: the ids [slots], the routers' picks
                 # [n_moe, slots, k] where the model has experts, and the
@@ -1137,7 +1177,7 @@ class GenerationEngine:
         """Experts touched by one decode step's live rows, from its picks."""
         self.last_picks = picks  # parity surface, like last_logits
         touched, rows_max = _experts_touched(
-            picks[:, [run.slot for run in runs], :])
+            picks[:, [run.slot for run in runs], :], self._experts_held)
         self._m_experts_touched.observe(touched)
         self._m_expert_rows_max.observe(rows_max)
         if self.record_picks:
@@ -1248,7 +1288,8 @@ class GenerationEngine:
             "kernel_dispatches": {
                 k: v
                 for k, v in _pk.KERNEL_DISPATCHES.items()
-                if k in ("paged_flash", "paged_flash_int8", "moe_grouped",
+                if k in ("paged_flash", "paged_flash_int8", "paged_latent",
+                         "hyper_maps", "moe_grouped",
                          "gemm_dbuf", "gemm_epilogue", "gemm_int8",
                          "gemm_fp8")
             },

@@ -27,10 +27,14 @@ lives here, on the host, where it costs nothing per token:
   overwritten by prefill/decode writes before any read, see
   docs/serving.md lifecycle).
 
-**PrefixCache** is a prompt-token trie over *full* pages: the key for depth
-k is the exact first ``k * page_size`` prompt tokens (token tuples, not
-hashes — no collisions), the value the pool page holding those positions'
-K/V. Shared pages are immutable by construction — a prefill after a prefix
+**PrefixCache** is a prompt-token trie over *full* pages: a node at depth
+k stands for the exact first ``k * page_size`` prompt tokens and its key is
+(its parent node's id, the page's own ``page_size`` tokens) — token tuples
+and an exact parent, not hashes, so no collisions, and a lookup or an insert
+touches each prompt token once (keyed by the whole prefix, an 8k-token
+prompt of 64-token pages hashed half a million tokens a lookup: a quarter
+of the scheduler's wall in a cell of long shared documents, PERF.md PR 31).
+The value is the pool page holding those positions' K/V. Shared pages are immutable by construction — a prefill after a prefix
 hit starts at the first uncached position, and decode writes land at
 positions >= the prompt length, so no program ever writes through a shared
 table entry; copy-on-write is unnecessary. Lookup always leaves at least
@@ -190,6 +194,13 @@ class PagedKVPool:
         with self._lock:
             return self._refs.get(int(page), 0)
 
+    def count_sole_refs(self, pages):
+        """How many of `pages` have exactly one reference, under one lock
+        (the prefix cache asks this of every page it holds, every pass)."""
+        with self._lock:
+            refs = self._refs
+            return sum(1 for p in pages if refs.get(p, 0) == 1)
+
     def _unref_locked(self, page):
         c = self._refs.get(page, 0) - 1
         if c > 0:
@@ -221,12 +232,16 @@ class PagedKVPool:
 
 
 class _PrefixNode:
-    __slots__ = ("page", "stamp", "state")
+    __slots__ = ("page", "stamp", "state", "uid", "parent", "depth", "children")
 
-    def __init__(self, page, stamp, state=None):
+    def __init__(self, page, stamp, state, uid, parent):
         self.page = page
         self.stamp = stamp
         self.state = state  # the sequence state at this boundary, if kept
+        self.uid = uid  # what its children's keys name
+        self.parent = parent  # the node one page up; None at depth 1
+        self.depth = 1 if parent is None else parent.depth + 1
+        self.children = 0
 
 
 class PrefixCache:
@@ -254,7 +269,9 @@ class PrefixCache:
             )
         self.capacity_pages = int(capacity_pages)
         self._lock = threading.Lock()
-        self._nodes = {}  # tuple(prompt[:k*page_size]) -> _PrefixNode
+        # (parent's uid, or 0 at depth 1; the page's tokens) -> _PrefixNode
+        self._nodes = {}
+        self._next_uid = 1
         self._clock = 0
         self.hits = 0  # lookups that found >= 1 page
         self.misses = 0
@@ -283,25 +300,27 @@ class PrefixCache:
         ps = self.pool.page_size
         prompt = tuple(int(t) for t in prompt)
         eligible = (len(prompt) - 1) // ps
-        pages, state = [], None
+        found, state = [], None
         with self._lock:
             self._clock += 1
+            parent = 0
             for i in range(eligible):
-                node = self._nodes.get(prompt[: (i + 1) * ps])
+                node = self._nodes.get((parent, prompt[i * ps:(i + 1) * ps]))
                 if node is None:
                     break
                 node.stamp = self._clock
-                pages.append(node.page)
-            matched = len(pages)
+                found.append(node)
+                parent = node.uid
+            matched = len(found)
             if self.state_bytes:
                 usable = 0
                 for i in range(matched, 0, -1):
-                    node = self._nodes[prompt[: i * ps]]
-                    if node.state is not None:
-                        usable, state = i, node.state
+                    if found[i - 1].state is not None:
+                        usable, state = i, found[i - 1].state
                         self.states_hit += 1
                         break
-                del pages[usable:]
+                del found[usable:]
+            pages = [node.page for node in found]
             self.pages_eligible += eligible
             self.pages_hit += len(pages)
             if pages:
@@ -326,29 +345,42 @@ class PrefixCache:
         added = 0
         with self._lock:
             self._clock += 1
+            parent = parent_key = None
             for i in range(n_full):
-                key = prompt[: (i + 1) * ps]
+                key = (parent.uid if parent else 0, prompt[i * ps:(i + 1) * ps])
                 state = states.get((i + 1) * ps)
-                if key in self._nodes:
-                    node = self._nodes[key]
+                node = self._nodes.get(key)
+                if node is not None:
                     node.stamp = self._clock
                     if state is not None and node.state is None:
                         node.state = state
                         self.states_taken += 1
                         self._n_states += 1
+                    parent, parent_key = node, key
                     continue
                 if len(self._nodes) >= self.capacity_pages:
                     if not self._evict_locked(1):
+                        break
+                    # the path just walked is the warmest there is, but its
+                    # end is a leaf until this page hangs from it: had the
+                    # eviction taken it, a child would be unreachable
+                    if parent is not None and (
+                            self._nodes.get(parent_key) is not parent):
                         break
                 page = int(table[i])
                 if page == SCRATCH_PAGE:
                     break
                 self.pool.pin_pages([page])
-                self._nodes[key] = _PrefixNode(page, self._clock, state)
+                node = _PrefixNode(page, self._clock, state, self._next_uid, parent)
+                self._next_uid += 1
+                if parent is not None:
+                    parent.children += 1
+                self._nodes[key] = node
                 if state is not None:
                     self.states_taken += 1
                     self._n_states += 1
                 added += 1
+                parent, parent_key = node, key
             if self._n_states > self.capacity_states:
                 self._cap_states_locked()
         return added
@@ -370,11 +402,11 @@ class PrefixCache:
             return self._evict_locked(n_pages)
 
     def _evict_locked(self, n_pages):
-        # children before parents: a longer key is always at least as cold
-        # as its prefix's extension, and dropping a parent first would leave
-        # unreachable descendants pinned
+        # children before parents: a deeper node is at least as cold as the
+        # node above it, and dropping a parent first would leave unreachable
+        # descendants pinned
         order = sorted(
-            self._nodes.items(), key=lambda kv: (kv[1].stamp, -len(kv[0]))
+            self._nodes.items(), key=lambda kv: (kv[1].stamp, -kv[1].depth)
         )
         evicted = 0
         for key, node in order:
@@ -383,11 +415,11 @@ class PrefixCache:
             # only pages no slot is reading (our pin is the sole reference)
             if self.pool.page_refcount(node.page) != 1:
                 continue
-            if any(
-                k != key and k[: len(key)] == key for k in self._nodes
-            ):
+            if node.children:
                 continue  # has live descendants; they sort earlier anyway
             del self._nodes[key]
+            if node.parent is not None:
+                node.parent.children -= 1
             self.pool.unpin_pages([node.page])
             if node.state is not None:
                 self.states_evicted += 1
@@ -400,11 +432,8 @@ class PrefixCache:
         """Cached pages only the trie holds — evictable on demand (the
         scheduler counts these as available when budgeting admissions)."""
         with self._lock:
-            return sum(
-                1
-                for n in self._nodes.values()
-                if self.pool.page_refcount(n.page) == 1
-            )
+            return self.pool.count_sole_refs(
+                [n.page for n in self._nodes.values()])
 
     def clear(self):
         with self._lock:

@@ -394,3 +394,237 @@ def test_a_tie_over_the_whole_vocabulary_goes_to_the_first_index():
         assert not row.any() and run.tokens[-1] == int(np.argmax(row)) == 0
     finally:
         eng.finish(run)
+
+
+# ---------------------------------------------------------------------------
+# the xing4_0 architecture at a tiny size: latent attention through one
+# paged pool a layer, the hyper-connection residual, a shared expert beside
+# the routed ones, an untied head, partial YaRN rotary
+# ---------------------------------------------------------------------------
+
+from paddle_tpu.models import xing4_reference as xref  # noqa: E402
+from paddle_tpu.models.block_decoder import xing4_0  # noqa: E402
+
+XING = dict(
+    model_type="xing4_0", vocab_size=101, hidden_size=64, intermediate_size=96,
+    moe_intermediate_size=32, num_attention_heads=4, num_key_value_heads=4,
+    q_lora_rank=24, kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+    v_head_dim=16, num_hidden_layers=3, first_k_dense_replace=1,
+    n_routed_experts=8, n_shared_experts=1, num_experts_per_tok=2,
+    n_group=1, topk_group=1, topk_method="noaux_tc", scoring_func="sigmoid",
+    norm_topk_prob=True, routed_scaling_factor=2, hc_mult=4,
+    hc_sinkhorn_iters=20, hc_eps=1e-6, mhc_h_res_clamp_min=-30,
+    mhc_h_res_clamp_max=30, rms_norm_eps=1e-6, rope_theta=10000,
+    rope_scaling={"type": "yarn", "factor": 4, "beta_fast": 32, "beta_slow": 1,
+                  "mscale": 1, "mscale_all_dim": 1,
+                  "original_max_position_embeddings": 32},
+    tie_word_embeddings=False, max_position_embeddings=128,
+    num_nextn_predict_layers=1)
+
+
+def _xing_engine(dtype="float32", seed=5, held=None, **kw):
+    model = xing4_0(XING, max_context=128, dtype=dtype, experts_held=held)
+    kw = dict(dict(max_slots=3, page_size=8, prefill_chunk=16, pool_pages=40), **kw)
+    eng = GenerationEngine(model, scope=Scope(seed=seed), **kw)
+    eng.record_picks = True
+    return model, eng
+
+
+@pytest.fixture(scope="module")
+def xing():
+    return _xing_engine()
+
+
+def _xing_reference(model, eng, tokens, picks):
+    logits, own, gaps = xref.forward(
+        _weights(model, eng), tokens, xref.spec_of(model), picks=picks)
+    assert float(np.max(gaps)) < 1e-4, "a pick the reference's scores do not bear out"
+    return np.asarray(logits)
+
+
+def test_xing4_is_built_from_the_source_s_keys(xing):
+    model, eng = xing
+    assert model.blocks == [("mla", "dense"), ("mla", "moe"), ("mla", "moe")]
+    assert model.hyper == {"streams": 4, "iters": 20, "eps": 1e-6,
+                           "clamp": [-30.0, 30.0]}
+    assert not model.tied_head and model.moe["shared_width"] == 32
+    m = 0.1 * np.log(4) + 1.0
+    assert model.mla["sm_scale"] == pytest.approx(24 ** -0.5 * m * m)
+    assert model.mla["yarn"] == [4.0, 32.0, 32.0, 1.0]
+    # one paged pool a layer, [c_kv 32 | k_r 8] in a 128-wide row, no K/V pair
+    specs = model.cache_specs()
+    assert [s["kind"] for s in specs] == ["paged"] * 3
+    assert {(s["width"], s["values"], s["form"]) for s in specs} == {(128, 40, "latent")}
+    assert model.kv_pool_names() == []
+    rows = eng.pool_pages * eng.page_size
+    assert eng.stats()["kv"]["resident_bytes"] == rows * 3 * 128 * 4
+    assert set(model.param_names()) == {
+        n for n in eng.scope.vars if n.startswith("xing4_") and "kv_latent" not in n}
+    with pytest.raises(ValueError):
+        xing4_0(dict(XING, n_group=2))
+
+
+def test_xing4_forward_program_matches_the_reference(xing):
+    model, eng = xing
+    tokens = np.array([_prompt(19, 1), _prompt(19, 2)], np.int64)
+    main, _, feeds, fetches = model.build_forward(2, 19)
+    with scope_guard(eng.scope):
+        logits, picks = Executor().run(
+            main, feed={"fwd_tokens": tokens[:, :, None]}, fetch_list=fetches)
+    picks = np.asarray(picks).reshape(len(model.moe_layers()), 2, 19, -1)
+    for b in range(2):
+        want = _xing_reference(model, eng, tokens[b], picks[:, b])
+        np.testing.assert_allclose(np.asarray(logits)[b], want, atol=F32_TOL)
+
+
+@pytest.mark.parametrize("paged_flash", ["off", "on"], ids=["dense", "kernel"])
+def test_xing4_chunked_prefill_then_decode_matches_the_full_forward_pass(paged_flash):
+    """37 prompt tokens in chunks of 16, 16 and 5, then six decode steps,
+    all through the latent pages in the absorbed form (the dense lowering,
+    and the Pallas walk in interpret mode): the first-token logits and every
+    step's against the reference's one plain-form pass over the sequence."""
+    from paddle_tpu import flags
+
+    prev = flags.get_flags("paged_flash")
+    flags.set_flags({"paged_flash": paged_flash})
+    try:
+        model, eng = _xing_engine()
+        prompt = _prompt(37)
+        run = eng.start(GenRequest(prompt, max_new_tokens=8))
+        got = [np.array(eng.last_prefill_logits)]
+        for _ in range(6):
+            eng.decode_step([run])
+            got.append(np.array(eng.last_logits[run.slot]))
+        tokens = prompt + run.tokens[:6]
+        eng.finish(run)
+    finally:
+        flags.set_flags(prev)
+    assert [s for s, _ in run.picks[:3]] == [0, 16, 32]
+    walks = eng.stats()["kernel_dispatches"].get("paged_latent", 0)
+    assert (walks > 0) == (paged_flash == "on")
+    want = _xing_reference(model, eng, tokens, _picks(run, len(tokens)))
+    for i, g in enumerate(got):
+        np.testing.assert_allclose(g, want[len(prompt) - 1 + i], atol=F32_TOL)
+
+
+def test_xing4_prefix_hit_shares_the_latent_pages_and_equals_a_cold_admission():
+    model, warm = _xing_engine()
+    _, cold = _xing_engine(prefix_cache=False)
+    doc = _prompt(24, 7)  # three whole pages
+    first, second = doc + _prompt(9, 8), doc + _prompt(11, 9)
+    warm.finish(warm.start(GenRequest(first, max_new_tokens=2)))
+    run = warm.start(GenRequest(second, max_new_tokens=4))
+    assert run.picks[0][0] == 24  # prefill began behind the document
+    assert warm.prefix_cache.stats()["pages_hit"] == 3
+    run_cold = cold.start(GenRequest(second, max_new_tokens=4))
+    np.testing.assert_allclose(
+        warm.last_prefill_logits, cold.last_prefill_logits, atol=F32_TOL)
+    for _ in range(3):
+        warm.decode_step([run])
+        cold.decode_step([run_cold])
+        np.testing.assert_allclose(
+            warm.last_logits[run.slot], cold.last_logits[run_cold.slot],
+            atol=F32_TOL)
+    assert run.tokens == run_cold.tokens
+
+
+def test_xing4_scheduler_serves_concurrent_requests_as_the_serial_engine(xing):
+    model, eng = xing
+    prompts = [_prompt(16, 7) + _prompt(3 + i, 50 + i) for i in range(5)]
+    serial = [eng.generate(p, max_new_tokens=5).tokens for p in prompts]
+    sched = GenerationScheduler(eng)
+    try:
+        futures = [sched.submit(p, max_new_tokens=5) for p in prompts]
+        got = [f.result(120).tokens for f in futures]
+    finally:
+        sched.close()
+    assert got == serial
+
+
+def test_xing4_engine_counts_the_held_experts_and_the_chunks_context():
+    """experts_held: the touched-experts histograms count only experts this
+    chip holds (the mirror of moe_experts' visits); a chunk observes the
+    positions and the pairs it attends, a decode step the bytes it feeds."""
+    from paddle_tpu.observability import registry
+    from paddle_tpu.serving.generation import _experts_touched
+
+    picks = np.array([[[0, 5], [5, 7]], [[1, 2], [2, 6]]])
+    assert _experts_touched(picks) == (6, 2)
+    assert _experts_touched(picks, held=[0, 1, 2, 3]) == (3, 2)
+    assert _experts_touched(picks, held=[4]) == (0, 0)
+    model, eng = _xing_engine(held=[0, 1, 2, 5], name="xing_held")
+    snap = lambda h: registry.default_registry().snapshot()["serving/xing_held/" + h]
+    run = eng.start(GenRequest(_prompt(21), max_new_tokens=4))
+    eng.decode_step([run])
+    all_picked, _ = _experts_touched(eng.last_picks[:, [run.slot], :])
+    held_picked, _ = _experts_touched(eng.last_picks[:, [run.slot], :], [0, 1, 2, 5])
+    assert snap("gen_experts_touched")["sum"] == held_picked <= all_picked
+    eng.finish(run)
+    # chunks of 16 and 5 rows: 16 + 21 positions; 16 * 17 / 2 + (5 * 16 + 15) pairs
+    assert snap("gen_chunk_ctx_tokens")["sum"] == 16 + 21
+    assert snap("gen_chunk_ctx_pairs")["sum"] == 136 + 95
+    # int32 on the device: tokens, positions, the table, live, state rows
+    feeds = eng.max_slots * (4 + 4 + 4 * eng.max_pages + 4 + 4)
+    assert snap("gen_feed_bytes")["sum"] == feeds
+    # the same logits as the reference given the same share
+    tokens = _prompt(21) + run.tokens[:1]
+    want = _xing_reference(model, eng, tokens, _picks(run, len(tokens)))
+    np.testing.assert_allclose(
+        np.array(eng.last_logits[run.slot]), want[-1], atol=F32_TOL)
+
+
+def test_xing4_expert_shares_and_the_shared_expert_add_up_to_the_whole_layer():
+    """The eight shares of an expert-parallel-8 layer in miniature (16
+    experts, two a share): each share's routed part, plus the shared expert
+    counted once, add up to the uncut reference's whole expert layer."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import registry
+
+    rng = np.random.default_rng(4)
+    rows, d, f, n_exp, k = 24, 16, 12, 16, 4
+    x = rng.standard_normal((rows, d)).astype("float32")
+    router_w = rng.standard_normal((d, n_exp)).astype("float32") * 0.5
+    router_b = rng.standard_normal(n_exp).astype("float32") * 0.3
+    w1, w3 = (rng.standard_normal((n_exp, d, f)).astype("float32") * 0.3
+              for _ in range(2))
+    w2 = rng.standard_normal((n_exp, f, d)).astype("float32") * 0.3
+    s1, s3 = (rng.standard_normal((d, f)).astype("float32") * 0.3 for _ in range(2))
+    s2 = rng.standard_normal((f, d)).astype("float32") * 0.3
+    moe = {"num_experts": n_exp, "k": k, "norm_topk": True, "scale": 2.0}
+    J = jnp.asarray
+    whole, _, _ = xref.moe_layer(
+        J(x), J(router_w), J(router_b), J(w1), J(w3), J(w2), moe)
+    whole = np.asarray(whole + xref.swiglu_ff(J(x), J(s1), J(s3), J(s2)))
+    lower = lambda t, ins, attrs: registry.get(t).lower(None, ins, attrs)
+    routed = lower("moe_router", {"X": [J(x)], "W": [J(router_w)],
+                                  "Bias": [J(router_b)]},
+                   {"k": k, "norm_topk": True, "scale": 2.0})
+    total = np.asarray(xref.swiglu_ff(J(x), J(s1), J(s3), J(s2)), np.float64)
+    for share in range(8):
+        held = [2 * share, 2 * share + 1]
+        part = lower("moe_experts", {
+            "X": [J(x)], "Picks": routed["Picks"], "Weights": routed["Weights"],
+            "W1": [J(w1[held])], "W3": [J(w3[held])], "W2": [J(w2[held])]},
+            {"num_experts": n_exp, "experts_held": held})["Out"][0]
+        total += np.asarray(part, np.float64)
+        # the reference given that share computes the same part
+        mine, _, _ = xref.moe_layer(
+            J(x), J(router_w), J(router_b), J(w1[held]), J(w3[held]),
+            J(w2[held]), dict(moe, experts_held=held))
+        np.testing.assert_allclose(np.asarray(part), np.asarray(mine), atol=2e-4)
+    np.testing.assert_allclose(total, whole, atol=5e-4)
+    assert np.abs(whole).max() > 0.1
+
+
+def test_xing4_bf16_stays_in_its_band_of_float32():
+    model, eng = _xing_engine(dtype="bfloat16", seed=9)
+    prompt = _prompt(29, 3)
+    run = eng.start(GenRequest(prompt, max_new_tokens=4))
+    got = np.array(eng.last_prefill_logits)
+    eng.finish(run)
+    want, own, gaps = xref.forward(
+        _weights(model, eng), prompt, xref.spec_of(model),
+        picks=_picks(run, len(prompt)))
+    assert float(np.max(gaps)) < 5e-2
+    assert np.abs(got - np.asarray(want)[-1]).max() < BF16_BAND

@@ -591,5 +591,313 @@ def test_gqa_paged_attention_against_a_dense_gather(per_row, dtype, path):
     np.testing.assert_allclose(np.asarray(got, np.float64), want, atol=tol)
 
 
+# ---------------------------------------------------------------------------
+# partial YaRN rotary, the hyper-connection, latent paged attention
+# ---------------------------------------------------------------------------
+
+
+def _yarn_np(dim, theta, factor, original, beta_fast, beta_slow):
+    """YaRN's blended frequencies, transcribed from the published DeepSeek
+    modelling code (find_correction_range, linear_ramp_mask)."""
+    extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    inter = extra / factor
+    corr = lambda turns: dim * np.log(original / (turns * 2 * np.pi)) / (
+        2 * np.log(theta))
+    low, high = max(np.floor(corr(beta_fast)), 0), min(np.ceil(corr(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0, 1)
+    mask = 1.0 - ramp
+    return inter * (1 - mask) + extra * mask
+
+
+@pytest.mark.parametrize("yarn", [None, [64.0, 4096.0, 32.0, 1.0], [4.0, 64.0, 32.0, 1.0]],
+                         ids=["plain", "published", "small"])
+def test_partial_interleaved_rotary_against_numpy(yarn):
+    """The last 64 features of each 192-wide head rotated in interleaved
+    pairs at YaRN's frequencies; the 128 before them pass through."""
+    rng = np.random.default_rng(0)
+    n_head, d_head, d_rot = 3, 192, 64
+    x = rng.standard_normal((6, n_head * d_head)).astype("float32")
+    pos = np.array([0, 1, 77, 4095, 4096, 12479], "int64")
+    inv = (_yarn_np(d_rot, 10000.0, *yarn) if yarn
+           else 10000.0 ** (-np.arange(0, d_rot, 2) / d_rot))
+    if yarn and yarn[0] == 64.0:
+        # the published settings blend: neither every pair scaled nor none
+        plain = 10000.0 ** (-np.arange(0, d_rot, 2) / d_rot)
+        assert inv[0] == plain[0] and np.isclose(inv[-1], plain[-1] / 64)
+        assert 0 < np.sum((inv < plain) & (inv > plain / 64)) < d_rot // 2
+    ang = pos[:, None] * inv[None, :]
+    xh = x.astype(np.float64).reshape(6, n_head, d_head)
+    want = xh.copy()
+    x1, x2 = xh[..., 128::2], xh[..., 129::2]
+    cos, sin = np.cos(ang)[:, None], np.sin(ang)[:, None]
+    want[..., 128::2] = x1 * cos - x2 * sin
+    want[..., 129::2] = x2 * cos + x1 * sin
+    attrs = {"n_head": n_head, "theta": 10000.0, "rotary_dim": d_rot,
+             "form": "interleaved"}
+    if yarn:
+        attrs["yarn"] = yarn
+    got = _lower("rotary_embedding", {
+        "X": [jnp.asarray(x)], "Pos": [jnp.asarray(pos)]}, attrs)["Out"][0]
+    # float32 angles of positions up to 12479: ~1e-3 rad at the fastest pair
+    np.testing.assert_allclose(np.asarray(got), want.reshape(x.shape), atol=5e-3)
+    np.testing.assert_array_equal(
+        np.asarray(got).reshape(6, n_head, d_head)[..., :128], xh[..., :128].astype("float32"))
+
+
+def _hyper_np(x, w, alpha, bias, n, iters, eps, clamp, norm_eps=1e-6):
+    x = x.astype(np.float64)
+    rows = x.shape[0]
+    xt = x / np.sqrt(np.mean(x * x, -1, keepdims=True) + norm_eps)
+    raw = xt @ w * np.repeat(alpha, [n, n, n * n]) + bias
+    h_pre, h_post = _sigmoid(raw[:, :n]), 2 * _sigmoid(raw[:, n:2 * n])
+    m = np.exp(np.clip(raw[:, 2 * n:], *clamp)).reshape(rows, n, n)
+    for _ in range(iters):
+        m = m / (m.sum(-1, keepdims=True) + eps)
+        m = m / (m.sum(-2, keepdims=True) + eps)
+    return h_pre, h_post, m
+
+
+def _hyper_case(dtype="float32", rows=9, n=4, d=24, seed=0, past_clamp=False):
+    """Pre-activations of about the served model's size (std ~1.3)."""
+    rng = np.random.default_rng(seed)
+    x = np.asarray(jnp.asarray(rng.standard_normal((rows, n * d)), dtype), np.float32)
+    w = (rng.standard_normal((n * d, 2 * n + n * n)) * 0.12).astype("float32")
+    alpha = np.array([0.5, 0.7, 1.0], "float32")
+    bias = (rng.standard_normal(2 * n + n * n) * 0.5).astype("float32")
+    if past_clamp:
+        # two entries of one row past the clamp: equal under it, e^5 apart
+        # without it
+        bias[2 * n], bias[2 * n + 1] = 40.0, 35.0
+    return x, w, alpha, bias
+
+
+HC_ATTRS = {"streams": 4, "iters": 20, "eps": 1e-6, "clamp_min": -30.0,
+            "clamp_max": 30.0, "norm_eps": 1e-6}
+
+
+def test_hyper_connection_against_numpy():
+    """The read side (u = H_pre X and the maps) and the write side (X' =
+    H_res X + H_post^T y) against numpy in float64; H_res is doubly
+    stochastic after 20 rounds, an entry past the clamp included."""
+    x, w, alpha, bias = _hyper_case()
+    n, d = 4, 24
+    h_pre, h_post, h_res = _hyper_np(x, w, alpha, bias, n, 20, 1e-6, (-30, 30))
+    out = _lower("hyper_connection", {
+        "X": [jnp.asarray(x)], "W": [jnp.asarray(w)],
+        "Alpha": [jnp.asarray(alpha)], "Bias": [jnp.asarray(bias)]}, HC_ATTRS)
+    u, maps = np.asarray(out["Out"][0]), np.asarray(out["Maps"][0])
+    xs = x.astype(np.float64).reshape(-1, n, d)
+    np.testing.assert_allclose(u, np.einsum("rj,rjd->rd", h_pre, xs), atol=1e-5)
+    np.testing.assert_allclose(maps[:, :n], h_post, atol=1e-5)
+    got_res = maps[:, n:].reshape(-1, n, n)
+    np.testing.assert_allclose(got_res, h_res, atol=1e-5)
+    assert np.abs(got_res.sum(-1) - 1).max() < 1e-4
+    assert np.abs(got_res.sum(-2) - 1).max() < 1e-4
+    assert got_res.max() < 0.95 and got_res.min() > 0.0  # mixed, not a permutation
+    # an entry past the clamp is the clamp's: numpy with the clip agrees,
+    # numpy without it does not
+    xc, wc, ac, bc = _hyper_case(past_clamp=True)
+    clamped = np.asarray(_lower("hyper_connection", {
+        "X": [jnp.asarray(xc)], "W": [jnp.asarray(wc)],
+        "Alpha": [jnp.asarray(ac)], "Bias": [jnp.asarray(bc)]},
+        HC_ATTRS)["Maps"][0])[:, n:].reshape(-1, n, n)
+    np.testing.assert_allclose(
+        clamped, _hyper_np(xc, wc, ac, bc, n, 20, 1e-6, (-30, 30))[2], atol=1e-5)
+    assert np.abs(
+        clamped - _hyper_np(xc, wc, ac, bc, n, 20, 1e-6, (-99, 99))[2]).max() > 1e-4
+    y = np.random.default_rng(1).standard_normal((x.shape[0], d)).astype("float32")
+    post = _lower("hyper_connection_post", {
+        "X": [jnp.asarray(x)], "Y": [jnp.asarray(y)],
+        "Maps": [jnp.asarray(maps)]}, {"streams": n})["Out"][0]
+    want = np.einsum("rjk,rkd->rjd", h_res, xs) + h_post[:, :, None] * y[:, None, :]
+    np.testing.assert_allclose(np.asarray(post), want.reshape(x.shape), atol=1e-5)
+
+
+def test_hyper_connection_of_bf16_computes_its_maps_in_float32():
+    """A bfloat16 stream: the maps are float32 and doubly stochastic to 1e-4;
+    the same rounds in bfloat16 (what the op must not do) miss that by far,
+    and u and X' are rounded once, at the end."""
+    from paddle_tpu.ops.decoder_ops import sinkhorn
+
+    x, w, alpha, bias = _hyper_case("bfloat16")
+    n = 4
+    out = _lower("hyper_connection", {
+        "X": [jnp.asarray(x, jnp.bfloat16)], "W": [jnp.asarray(w)],
+        "Alpha": [jnp.asarray(alpha)], "Bias": [jnp.asarray(bias)]}, HC_ATTRS)
+    maps = out["Maps"][0]
+    assert maps.dtype == jnp.float32 and out["Out"][0].dtype == jnp.bfloat16
+    res = np.asarray(maps)[:, n:].reshape(-1, n, n)
+    assert np.abs(res.sum(-1) - 1).max() < 1e-4
+    assert np.abs(res.sum(-2) - 1).max() < 1e-4
+    _, _, want = _hyper_np(x, w, alpha, bias, n, 20, 1e-6, (-30, 30))
+    np.testing.assert_allclose(res, want, atol=1e-5)
+    raw = np.log(np.maximum(want, 1e-30))
+    low = np.asarray(sinkhorn(jnp.asarray(raw, jnp.bfloat16), 20, 1e-6), np.float64)
+    assert max(np.abs(low.sum(-1) - 1).max(), np.abs(low.sum(-2) - 1).max()) > 1e-3
+    h_pre = _hyper_np(x, w, alpha, bias, n, 20, 1e-6, (-30, 30))[0]
+    u = np.einsum("rj,rjd->rd", h_pre, x.astype(np.float64).reshape(-1, n, 24))
+    got = np.asarray(out["Out"][0], np.float64)
+    assert np.all(np.abs(got - u) <= _bf16_ulp(u) * 0.51 + 1e-6)
+
+
+class TestHyperConnection(OpTest):
+    def setUp(self):
+        self.op_type = "hyper_connection"
+        x, w, alpha, bias = _hyper_case()
+        h_pre, h_post, h_res = _hyper_np(x, w, alpha, bias, 4, 20, 1e-6, (-30, 30))
+        self.inputs = {"X": x, "W": w, "Alpha": alpha, "Bias": bias}
+        self.attrs = dict(HC_ATTRS)
+        self.outputs = {
+            "Out": np.einsum("rj,rjd->rd", h_pre, x.reshape(-1, 4, 24)
+                             ).astype("float32"),
+            "Maps": np.concatenate(
+                [h_post, h_res.reshape(-1, 16)], -1).astype("float32")}
+
+    def test_check_output(self):
+        self.check_output(atol=1e-5)
+
+
+class TestHyperConnectionPost(OpTest):
+    def setUp(self):
+        self.op_type = "hyper_connection_post"
+        rng = np.random.default_rng(2)
+        x = rng.standard_normal((7, 4 * 24)).astype("float32")
+        y = rng.standard_normal((7, 24)).astype("float32")
+        maps = rng.random((7, 20)).astype("float32")
+        want = np.einsum("rjk,rkd->rjd", maps[:, 4:].reshape(7, 4, 4),
+                         x.reshape(7, 4, 24)) + maps[:, :4, None] * y[:, None, :]
+        self.inputs = {"X": x, "Y": y, "Maps": maps}
+        self.attrs = {"streams": 4}
+        self.outputs = {"Out": want.reshape(7, 96).astype("float32")}
+
+    def test_check_output(self):
+        self.check_output(atol=1e-5)
+
+
+@pytest.mark.parametrize("rows", [9, 32, 256])
+def test_hyper_maps_kernel_matches_the_rounds_in_numpy(rows):
+    """The Pallas kernel the op takes on a TPU (interpret mode here): the
+    sigmoids and 20 Sinkhorn rounds of every row in one call, against numpy
+    and against the op's own jax.numpy path."""
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    x, w, alpha, bias = _hyper_case(rows=rows, past_clamp=True)
+    n = 4
+    h_pre, h_post, h_res = _hyper_np(x, w, alpha, bias, n, 20, 1e-6, (-30, 30))
+    xt = x / np.sqrt(np.mean(x.astype(np.float64) ** 2, -1, keepdims=True) + 1e-6)
+    raw = (xt @ w * np.repeat(alpha, [n, n, n * n]) + bias).astype("float32")
+    before = pk.KERNEL_DISPATCHES.get("hyper_maps", 0)
+    got = np.asarray(pk.hyper_maps(
+        jnp.asarray(raw), n, 20, 1e-6, (-30.0, 30.0), interpret=True))
+    assert pk.KERNEL_DISPATCHES["hyper_maps"] == before + 1
+    assert got.shape == (rows, 24) and got.dtype == np.float32
+    np.testing.assert_allclose(got[:, :n], h_pre, atol=1e-5)
+    np.testing.assert_allclose(got[:, n:2 * n], h_post, atol=1e-5)
+    np.testing.assert_allclose(
+        got[:, 2 * n:].reshape(rows, n, n), h_res, atol=1e-5)
+    maps = np.asarray(_lower("hyper_connection", {
+        "X": [jnp.asarray(x)], "W": [jnp.asarray(w)],
+        "Alpha": [jnp.asarray(alpha)], "Bias": [jnp.asarray(bias)]},
+        HC_ATTRS)["Maps"][0])
+    np.testing.assert_allclose(got[:, n:], maps, atol=1e-5)
+    assert not pk.hyper_maps_path_taken()  # off the chip the op stays in XLA
+
+
+def _latent_np(q, pool, bt, pos, n_head, d_value, ps, scale):
+    rows, width = q.shape[0], pool.shape[1]
+    out = np.zeros((rows, n_head * d_value), np.float64)
+    for r in range(rows):
+        table = bt if bt.ndim == 1 else bt[r]
+        n = int(pos[r]) + 1
+        if n <= 0:
+            continue
+        k = pool[[table[p // ps] * ps + p % ps for p in range(n)]].astype(np.float64)
+        for h in range(n_head):
+            s = k @ q[r, h * width:(h + 1) * width] * scale
+            w = np.exp(s - s.max())
+            out[r, h * d_value:(h + 1) * d_value] = (w / w.sum()) @ k[:, :d_value]
+    return out
+
+
+def _latent_case(per_row, dtype, n_head=8, width=128, ps=16, n_pages=40, seed=0):
+    rng = np.random.default_rng(seed)
+    pool = np.asarray(jnp.asarray(
+        rng.standard_normal((n_pages * ps, width)), dtype), np.float32)
+    if per_row:  # five slots, one past a compute block of 512 positions
+        rows = 5
+        bt = np.zeros((rows, 36), np.int32)
+        bt[0, :36] = rng.permutation(np.arange(1, n_pages))[:36]
+        bt[1:, :2] = rng.permutation(np.arange(1, n_pages))[:8].reshape(4, 2)
+        pos = np.array([530, 5, 17, 31, -1], np.int32)
+    else:  # 20 rows: two programs of 16, the second padded
+        rows = 20
+        bt = np.concatenate([rng.permutation(np.arange(1, n_pages))[:4],
+                             np.zeros(2, np.int64)]).astype(np.int32)
+        pos = np.arange(30, 30 + rows).astype(np.int32)
+    q = np.asarray(jnp.asarray(
+        rng.standard_normal((rows, n_head * width)) * 0.3, dtype), np.float32)
+    return q, pool, bt, pos
+
+
+class TestMlaAttention(OpTest):
+    """The op through a program (its shape rule too): decode rows."""
+
+    def setUp(self):
+        self.op_type = "mla_attention"
+        q, pool, bt, pos = _latent_case(True, jnp.dtype("float32"))
+        self.inputs = {"Q": q, "Pool": pool, "BlockTable": bt, "Pos": pos}
+        self.attrs = {"page_size": 16, "d_value": 96, "sm_scale": 0.11}
+        self.outputs = {"Out": _latent_np(
+            q, pool, bt, pos, 8, 96, 16, 0.11).astype("float32")}
+
+    def test_check_output(self):
+        self.check_output(atol=1e-5)
+
+
+@pytest.mark.parametrize("per_row", [True, False], ids=["decode", "chunk"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("path", ["kernel", "dense"])
+def test_latent_paged_attention_against_a_dense_gather(per_row, dtype, path):
+    """One pool whose row is every head's key and, its first 96 columns,
+    their value: the Pallas walk in interpret mode and the dense lowering
+    both agree with a per-row gather in numpy, decode rows (one past a
+    compute block, one fully masked) and a chunk over a shared table."""
+    from paddle_tpu import flags
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    q, pool, bt, pos = _latent_case(per_row, jnp.dtype(dtype))
+    want = _latent_np(q, pool, bt, pos, 8, 96, 16, 0.11)
+    prev = flags.get_flags("paged_flash")
+    flags.set_flags({"paged_flash": "on" if path == "kernel" else "off"})
+    before = pk.KERNEL_DISPATCHES.get("paged_latent", 0)
+    try:
+        got = _lower("mla_attention", {
+            "Q": [jnp.asarray(q, dtype)], "Pool": [jnp.asarray(pool, dtype)],
+            "BlockTable": [jnp.asarray(bt)], "Pos": [jnp.asarray(pos)]},
+            {"page_size": 16, "d_value": 96, "sm_scale": 0.11})["Out"][0]
+    finally:
+        flags.set_flags(prev)
+    assert pk.KERNEL_DISPATCHES.get("paged_latent", 0) - before == (path == "kernel")
+    assert got.dtype == jnp.dtype(dtype) and got.shape == (q.shape[0], 8 * 96)
+    # bf16: the probabilities are rounded before they meet the values
+    tol = 1e-5 if dtype == "float32" else 2e-2
+    np.testing.assert_allclose(np.asarray(got, np.float64), want, atol=tol)
+
+
+def test_latent_positions_walked_mirrors_the_kernel():
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    # decode: 512-position blocks of 64-row pages, a 200-page table
+    assert pk.latent_positions_walked([0, 511, 512, 12479, -1], 64, 200) == (
+        512 * (1 + 1 + 2 + 25 + 0))
+    # a chunk of 40 rows: programs of 16, 16 and 8 rows, 1024-position blocks
+    pos = np.arange(1000, 1040)
+    assert pk.latent_positions_walked(pos, 64, 200, shared=True) == 1024 * (1 + 2 + 2)
+    # a table narrower than a block
+    assert pk.latent_positions_walked([5, 70], 16, 6) == 2 * 96
+
+
 if __name__ == "__main__":
     unittest.main()

@@ -548,6 +548,50 @@ def test_prefix_cache_trie_lru_and_guarded_eviction():
     assert pool.stats()["pages_in_use"] == 0
 
 
+def test_prefix_trie_keys_a_page_by_its_parent_and_evicts_leaves_first():
+    """Two prompts that share a first page branch under it; the shared page
+    has two children and goes only after both; the same page's tokens under
+    another parent are another node; at capacity an insert never hangs a
+    page from a node the eviction just took; and a long prompt costs its
+    tokens once (its keys are one page each, not whole prefixes)."""
+    from paddle_tpu.serving import PrefixCache
+
+    pool = PagedKVPool(n_pages=16, page_size=2, max_slots=3,
+                       max_pages_per_slot=5)
+    cache = PrefixCache(pool, capacity_pages=5)
+    sa, ta = pool.acquire(6)
+    assert cache.insert([1, 2, 3, 4, 5, 6], ta) == 3
+    sb, tb = pool.acquire(6, shared_pages=[int(ta[0])])
+    assert cache.insert([1, 2, 7, 8, 3, 4], tb) == 2  # the first page is there
+    assert all(len(key[1]) == 2 for key in cache._nodes)  # one page a key
+    # [3, 4] under [1, 2, 7, 8] is not [3, 4] under [1, 2]
+    got = cache.lookup([1, 2, 7, 8, 3, 4, 0])
+    assert got == [int(ta[0]), int(tb[1]), int(tb[2])]
+    pool.unpin_pages(got)
+    pool.release(sa)
+    pool.release(sb)
+    # leaves first, coldest first: the first prompt's chain from its end,
+    # the shared first page only after both branches under it
+    assert cache.evict_for(2) == 2
+    assert cache.lookup([1, 2, 3, 4, 5, 6, 0]) == [int(ta[0])]
+    pool.unpin_pages([int(ta[0])])
+    assert cache.evict_for(9) == 3 and cache.stats()["cached_pages"] == 0
+    assert pool.stats()["pages_in_use"] == 0
+    # at capacity: a third prompt's pages push the coldest leaves out and
+    # every page left in the trie can still be reached from the top
+    cache = PrefixCache(pool, capacity_pages=3)
+    for first in (10, 20, 30):
+        s, t = pool.acquire(6)
+        cache.insert([first, 1, first, 2, first, 3], t)
+        pool.release(s)
+    assert cache.stats()["cached_pages"] == 3
+    got = cache.lookup([30, 1, 30, 2, 30, 3, 0])
+    assert len(got) == 3
+    pool.unpin_pages(got)
+    assert cache.lookup([10, 1, 10, 2, 0]) == []
+    assert cache.clear() == 3 and pool.stats()["pages_in_use"] == 0
+
+
 # ------------------------------------------- the ids are born on the device
 
 

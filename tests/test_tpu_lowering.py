@@ -84,6 +84,29 @@ def _paged(quant, per_row, rows, g=_PAGED):
     return fn, avals
 
 
+# xing4_docs_chat's: 32 slots x a 200-page table of 64-row pages, 32 query
+# heads over one latent pool 640 wide (512 + 64 + 64 zeros), bf16, the value
+# its first 512 columns
+_LATENT_CELL = dict(n_head=32, width=640, d_value=512, page=64, pages=200,
+                    pool_rows=196672, dtype=bf16)
+_LATENT = dict(n_head=8, width=128, d_value=64, page=16, pages=6,
+               pool_rows=112, dtype=f32)
+
+
+def _latent(per_row, rows, g):
+    def fn(q, pool, bt, pos):
+        return pk.latent_paged_attention(
+            q, pool, bt, pos, n_head=g["n_head"], page_size=g["page"],
+            d_value=g["d_value"], sm_scale=0.1)
+
+    return fn, [
+        S((rows, g["n_head"] * g["width"]), g["dtype"]),
+        S((g["pool_rows"], g["width"]), g["dtype"]),
+        S((rows, g["pages"]) if per_row else (g["pages"],), i32),
+        S((rows,), i32),
+    ]
+
+
 def _adam(moment_dtype):
     shapes = [(256, 384), (384,), (7, 13)]
 
@@ -167,6 +190,17 @@ FAMILIES = {
     "paged_flash_int8 shared gpt2l_chat": _paged(True, False, 32, _PAGED_CELL),
     "paged_flash gqa bf16 decode lfm2moe_chat": _paged(False, True, 32, _PAGED_GQA),
     "paged_flash gqa bf16 shared lfm2moe_chat": _paged(False, False, 128, _PAGED_GQA),
+    "hyper_maps decode xing4_docs_chat": (
+        lambda raw: pk.hyper_maps(raw, 4, 20, 1e-6, (-30.0, 30.0)),
+        [S((32, 24), f32)]),
+    "hyper_maps chunk xing4_docs_chat": (
+        lambda raw: pk.hyper_maps(raw, 4, 20, 1e-6, (-30.0, 30.0)),
+        [S((256, 24), f32)]),
+    "paged_latent decode": _latent(True, 3, _LATENT),
+    "paged_latent shared": _latent(False, 8, _LATENT),
+    "paged_latent bf16 decode xing4_docs_chat": _latent(True, 32, _LATENT_CELL),
+    "paged_latent bf16 shared 256 xing4_docs_chat": _latent(False, 256, _LATENT_CELL),
+    "paged_latent bf16 shared 32 xing4_docs_chat": _latent(False, 32, _LATENT_CELL),
     "moe_grouped decode lfm2moe_chat": _moe_grouped(128),
     "moe_grouped chunk lfm2moe_chat": _moe_grouped(512),
     "moe_grouped two row tiles": _moe_grouped(640, d=256, f=384, held=4),
