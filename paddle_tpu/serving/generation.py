@@ -41,9 +41,19 @@ tests/test_generation.py; single-shot serving stays donation-free.
 scheduler: the worker loop admits queued requests into free decode slots
 *mid-batch* between steps (admission is host-only; prefill CHUNKS are
 interleaved with decode under a queue-pressure policy — one chunk per step
-when idle, draining every pending prompt when the queue is deep), runs one
-decode step for all live slots, and retires slots on EOS/max-len,
-releasing their pages for reuse.
+when idle, draining every pending prompt when the queue is deep), dispatches
+the next decode step for all live slots, reads the step before it, and
+retires slots on EOS/max-len, releasing their pages for reuse.
+
+**The host reads a decode step's results one step late.** The decode
+variant takes each row's token either from the host or from the ids the
+step before it left on the device (`_take_ids`), so step n+1 is dispatched
+before step n's ids have crossed to the host and the device goes from one
+step into the next (`decode_step(runs, ahead=True)`, the scheduler's form).
+A row that ends by length is known before the dispatch and is left out; a
+row that ends by eos is seen one step late and has ridden one row-step
+whose id is thrown away, inside pages it still holds. A step with a row
+that has a temperature is synchronous: the host samples that token.
 
 A greedy row's token is chosen on the device: every variant ends in an
 `arg_max` over the vocabulary of the logits it computes (`_emit_ids`), and
@@ -234,6 +244,26 @@ class _Logits:
         return self._host
 
 
+class _Step:
+    """One decode step dispatched and not yet read: what its read needs."""
+
+    __slots__ = ("by_slot", "positions", "variant", "logits", "ids", "picks",
+                 "rows", "fetch_bytes", "ctx_tokens", "walked_tokens", "span",
+                 "host_ms", "dispatch_ms")
+
+    def __init__(self, runs):
+        self.by_slot = {run.slot: run for run in runs}
+
+    @property
+    def runs(self):
+        return list(self.by_slot.values())
+
+    def rides(self, run):
+        """Whether this step advances `run` (the run, not a later tenant of
+        its slot)."""
+        return self.by_slot.get(run.slot) is run
+
+
 class _Sum:
     """A running sum with a histogram's observe(): the stall of the host-only
     phases of one scheduler iteration, taken once per iteration."""
@@ -285,6 +315,31 @@ def _emit_ids(main, fetch_names):
         attrs={"axis": -1},
     )
     return list(fetch_names) + [ids.name]
+
+
+# a row of dec_tokens that holds this takes the device's id, not the host's
+FROM_DEVICE = -1
+
+
+def _take_ids(serve):
+    """The decode variant takes each row's token where it was born: beside
+    the host's dec_tokens it is fed dec_prev_ids [slots] int32, the ids the
+    decode step before it emitted (a device array the host need not have
+    read). A row whose host token is FROM_DEVICE takes the device's id; any
+    other row (its first token came from its prefill, or the step before
+    was read) keeps the host's. The merge is part of the variant's one
+    program."""
+    import jax.numpy as jnp
+
+    def serve_merged(feeds, ro, mut):
+        feeds = dict(feeds)
+        prev = feeds.pop("dec_prev_ids")
+        tokens = feeds["dec_tokens"]
+        feeds["dec_tokens"] = jnp.where(
+            tokens == FROM_DEVICE, prev[:, None].astype(tokens.dtype), tokens)
+        return serve(feeds, ro, mut)
+
+    return serve_merged
 
 
 def cache_specs(model):
@@ -482,6 +537,11 @@ class GenerationEngine:
             "dec_live": np.zeros((self.max_slots,), np.int32),
             "dec_state_rows": np.zeros((self.max_slots,), np.int32),
         }
+        # the ids of the decode step dispatched last, on the device: what the
+        # next one is fed as dec_prev_ids, read by the host or not
+        self._ids = jnp.zeros((self.max_slots,), jnp.int32)
+        # the decode step dispatched and not yet read (_Step), if any
+        self._in_flight = None
 
         self._variants = {}
         self._build_lock = threading.Lock()
@@ -528,12 +588,25 @@ class GenerationEngine:
                 ("feed", "decode step: writing the feed buffers, wall ms"),
                 ("dispatch", "decode step: the compiled call returning "
                              "(enqueue), wall ms"),
-                ("wait", "decode step: device sync plus the ids' and "
-                         "picks' copy to the host, wall ms"),
+                ("wait", "decode step: the host blocked on the step's ids "
+                         "and picks (read one step late: while the next "
+                         "step runs), wall ms"),
                 ("sample", "decode step: appending every run's token (a "
                            "row with a temperature: host sampling), wall ms"),
             )
         }
+        self._m_in_flight = reg.histogram(
+            p + "/gen_steps_in_flight",
+            "decode steps dispatched and unread at a decode dispatch, this "
+            "one included: 1 = synchronous, 2 = one ahead",
+            buckets=(1, 2),
+        )
+        self._m_drains = reg.counter(
+            p + "/gen_pipeline_drains",
+            "times the scheduler's step in flight was read with none behind "
+            "it, by reason: temperature (the host samples the token), idle "
+            "(no row goes on), close",
+        )
         self._m_fetch_bytes = reg.histogram(
             p + "/gen_fetch_bytes",
             "bytes one decode step copied to the host: its ids, the routers' "
@@ -730,6 +803,10 @@ class GenerationEngine:
             avals[n] = jax.ShapeDtypeStruct(
                 tuple(int(d) for d in var.shape), self._canon_dtype(var.dtype)
             )
+        if kind == "decode":
+            serve = _take_ids(serve)
+            avals["dec_prev_ids"] = jax.ShapeDtypeStruct(
+                (self.max_slots,), np.int32)
 
         def build():
             self.traces += 1
@@ -907,11 +984,14 @@ class GenerationEngine:
         )
 
     def _feeds(self, variant, np_feeds):
-        """(feeds, mut_in) for one call of a variant: the feed phase's end."""
+        """(feeds, mut_in) for one call of a variant: the feed phase's end.
+        Copies: the call returns before the device has taken its feeds, and
+        the decode step's buffers are written again for the next step while
+        this one is in flight."""
         feeds = {}
         for n in variant.feed_names:
             s = variant.avals[n]
-            feeds[n] = np.ascontiguousarray(np_feeds[n], dtype=s.dtype)
+            feeds[n] = np.array(np_feeds[n], dtype=s.dtype, order="C")
         return feeds, {n: self._state[n] for n in variant.mut_names}
 
     def _dispatch(self, variant, feeds, mut_in):
@@ -1092,12 +1172,80 @@ class GenerationEngine:
             self.finish(run)
             raise
 
-    def decode_step(self, runs):
+    def decode_step(self, runs, ahead=False):
         """One fixed-shape decode step advancing every run in `runs` by one
-        token (all must be live). Finished runs are NOT auto-released — the
-        caller retires them via finish()."""
-        if not runs:
-            return
+        token. Finished runs are NOT auto-released — the caller retires them
+        via finish(). Returns the runs whose tokens this call read.
+
+        `ahead=False`: dispatch, then read the same step (all must be live).
+
+        `ahead=True`, the scheduler's form: dispatch the step, then read the
+        one dispatched by the call before and leave this one in flight, so
+        that the device goes from one into the other. A run that rode the
+        step in flight takes that step's id on the device; a run whose token
+        in flight is its last by length is left out (no position past its
+        reserved pages is written); a run whose eos the late read finds has
+        ridden this step for nothing, inside pages it still holds, and the
+        step's read throws its id away. With no run to go on, the step in
+        flight is read and none dispatched. A step with a run that has a
+        temperature is dispatched after its predecessor is read and read
+        before it returns: the host samples that token."""
+        prev, self._in_flight = self._in_flight, None
+        if ahead:
+            runs = self._going_on(runs, prev)
+        elif any(run.done for run in runs):
+            raise ValueError("decode_step on a finished run")
+        sampled = any(run.req.temperature for run in runs)
+        read = []
+        if prev is not None and sampled:
+            read += self._read_step(prev)
+            prev = None
+            runs = [run for run in runs if not run.done]
+        step = self._dispatch_step(runs, prev) if runs else None
+        if prev is not None:
+            read += self._read_step(prev)
+        if step is not None and (sampled or not ahead):
+            read += self._read_step(step)
+            step = None
+        if ahead and sampled:
+            self._m_drains.inc(reason="temperature")
+        elif ahead and prev is not None and step is None:
+            self._m_drains.inc(reason="idle")
+        self._in_flight = step
+        return list(dict.fromkeys(read))
+
+    @property
+    def steps_in_flight(self):
+        """Decode steps dispatched and not yet read (0 or 1 between calls):
+        whoever drives decode_step(ahead=True) reads the last one before it
+        sleeps or closes."""
+        return 0 if self._in_flight is None else 1
+
+    def drain(self, reason):
+        """Read the step in flight, if any (reason: gen_pipeline_drains')."""
+        step, self._in_flight = self._in_flight, None
+        if step is not None:
+            self._m_drains.inc(reason=reason)
+            self._read_step(step)
+
+    def _going_on(self, runs, prev):
+        """Those of `runs` that a step dispatched now advances: not a
+        finished one, and not one whose token in flight is its last by
+        length; the latter's feed rows drop back to the scratch page."""
+        out = []
+        for run in runs:
+            if run.done:
+                continue
+            riding = prev is not None and prev.rides(run)
+            if len(run.tokens) + riding >= self._max_new(run.req):
+                self._disarm(run.slot)
+                continue
+            out.append(run)
+        return out
+
+    def _dispatch_step(self, runs, prev):
+        """Feed and dispatch one decode step for `runs`; `prev` is the step
+        in flight, whose ids its runs take. Returns the _Step to read."""
         span = _tracing.current()
         if span:
             span = span.child(
@@ -1105,6 +1253,8 @@ class GenerationEngine:
                 kv_dtype=self.kv_dtype, model_version=self.model_version,
                 iter=self.iter,
             )
+        step = _Step(runs)
+        step.span = span
         t0 = time.perf_counter()
         try:
             with self._phase("feed", "decode"):
@@ -1113,76 +1263,106 @@ class GenerationEngine:
                 live, state_rows = feeds["dec_live"], feeds["dec_state_rows"]
                 live[:] = 0
                 state_rows[:] = 0
-                ctx_tokens = 0
+                step.positions = [run.next_pos for run in runs]
                 for run in runs:
-                    if run.done:
-                        raise ValueError("decode_step on a finished run")
-                    tokens[run.slot, 0] = run.tokens[-1]
+                    tokens[run.slot, 0] = (
+                        FROM_DEVICE if prev is not None and prev.rides(run)
+                        else run.tokens[-1])
                     positions[run.slot, 0] = run.next_pos
                     live[run.slot] = 1
                     state_rows[run.slot] = run.slot + 1
-                    ctx_tokens += run.next_pos
-                walked_tokens = (
+                    run.next_pos += 1
+                step.ctx_tokens = sum(step.positions)
+                step.walked_tokens = (
                     _pk.latent_positions_walked(
                         positions, self.page_size, self.max_pages)
                     if self._latent else _pk.paged_positions_walked(
                         positions, self.page_size, self.max_pages,
                         self._kv_row_bytes))
-                variant = self._variant("decode")
+                step.variant = variant = self._variant("decode")
                 feeds, mut_in = self._feeds(variant, feeds)
                 self._m_feed_bytes.observe(
                     sum(a.nbytes for a in feeds.values()))
+                feeds["dec_prev_ids"] = self._ids
             with self._phase("dispatch", "decode") as dispatch:
+                self._m_in_flight.observe(1 if prev is None else 2)
                 # the step's fetch: the ids [slots], the routers' picks
                 # [n_moe, slots, k] where the model has experts, and the
                 # logits of each row that has a temperature; the copies
                 # start when the step ends, not when the host asks
-                logits, *extra, ids = self._dispatch(variant, feeds, mut_in)
-                rows = {
-                    run.slot: self._row_program()(logits, np.int32(run.slot))
+                step.logits, *extra, step.ids = self._dispatch(
+                    variant, feeds, mut_in)
+                self._ids = step.ids
+                step.picks = extra[0] if extra else None
+                step.rows = {
+                    run.slot: self._row_program()(
+                        step.logits, np.int32(run.slot))
                     for run in runs if run.req.temperature}
-                fetch = [ids, *extra, *rows.values()]
+                fetch = (step.ids, *extra, *step.rows.values())
                 for arr in fetch:
                     arr.copy_to_host_async()
-            with self._phase("wait", "decode") as wait:
-                ids = np.asarray(ids)
-                picks = np.asarray(extra[0]) if extra else None
-                rows = {slot: np.asarray(row) for slot, row in rows.items()}
+                step.fetch_bytes = sum(arr.nbytes for arr in fetch)
         except Exception as e:
             span.error(e).end()
             raise
-        self._last_logits = _Logits(logits)
-        self.fetch_bytes = sum(arr.nbytes for arr in fetch)
+        step.host_ms = (time.perf_counter() - t0) * 1e3
+        step.dispatch_ms = dispatch.ms
+        return step
+
+    def _read_step(self, step):
+        """Read a dispatched step: block on its fetch, count it, append each
+        run's token. Returns its runs."""
+        runs = step.runs
+        t0 = time.perf_counter()
+        try:
+            with self._phase("wait", "decode") as wait:
+                ids = np.asarray(step.ids)
+                picks = None if step.picks is None else np.asarray(step.picks)
+                rows = {slot: np.asarray(row)
+                        for slot, row in step.rows.items()}
+        except Exception as e:
+            step.span.error(e).end()
+            raise
+        self._last_logits = _Logits(step.logits)
+        self.fetch_bytes = step.fetch_bytes
         self._m_fetch_bytes.observe(self.fetch_bytes)
-        step_ms = (time.perf_counter() - t0) * 1e3
-        span.tag(
-            host_ms=round(step_ms, 3), dispatch_ms=round(dispatch.ms, 3),
+        # the host's time in the step: its dispatch's and its read's, not
+        # what the scheduler did between the two
+        step_ms = step.host_ms + (time.perf_counter() - t0) * 1e3
+        step.span.tag(
+            host_ms=round(step_ms, 3), dispatch_ms=round(step.dispatch_ms, 3),
             wait_ms=round(wait.ms, 3),
         ).end()
         self._m_step_ms.observe(step_ms)
-        self._m_ctx_tokens.observe(ctx_tokens)
-        self._m_walked_tokens.observe(walked_tokens)
+        self._m_ctx_tokens.observe(step.ctx_tokens)
+        self._m_walked_tokens.observe(step.walked_tokens)
         self._m_steps.inc()
         if picks is not None:
-            self._note_picks(picks, runs)
-            self._m_moe_kernel_layers.observe(variant.moe_kernel_layers)
+            self._note_picks(picks, step)
+            self._m_moe_kernel_layers.observe(step.variant.moe_kernel_layers)
         with self._phase("sample", "decode"):
             for run in runs:
-                run.next_pos += 1
+                if run.done:
+                    # its eos was read after this step went out: the id of
+                    # the row-step it rode for nothing is thrown away
+                    continue
                 tok = (self._sample(rows[run.slot], run.req, run.rng)
                        if run.req.temperature else int(ids[run.slot]))
                 self._append_token(run, tok, self._max_new(run.req))
+        return runs
 
-    def _note_picks(self, picks, runs):
+    def _note_picks(self, picks, step):
         """Experts touched by one decode step's live rows, from its picks."""
         self.last_picks = picks  # parity surface, like last_logits
+        runs = step.runs
         touched, rows_max = _experts_touched(
             picks[:, [run.slot for run in runs], :], self._experts_held)
         self._m_experts_touched.observe(touched)
         self._m_expert_rows_max.observe(rows_max)
         if self.record_picks:
-            for run in runs:
-                run.picks.append((run.next_pos, picks[:, run.slot:run.slot + 1]))
+            for run, pos in zip(runs, step.positions):
+                if not run.done:
+                    run.picks.append((pos, picks[:, run.slot:run.slot + 1]))
 
     def finish(self, run):
         """Retire a run's slot: pages return to the pool for reuse (cached
@@ -1190,10 +1370,14 @@ class GenerationEngine:
         persistent decode-feed rows drop back to the scratch page so the
         next tenant can't inherit a stale table."""
         self.pool.release(run.slot)
-        self._dec_feeds["dec_block_table"][run.slot] = 0
-        self._dec_feeds["dec_tokens"][run.slot] = 0
-        self._dec_feeds["dec_positions"][run.slot] = 0
+        self._disarm(run.slot)
         self._set_pool_gauges()
+
+    def _disarm(self, slot):
+        """A slot's persistent decode-feed rows back to the scratch page."""
+        self._dec_feeds["dec_block_table"][slot] = 0
+        self._dec_feeds["dec_tokens"][slot] = 0
+        self._dec_feeds["dec_positions"][slot] = 0
 
     def _append_token(self, run, tok, max_new):
         run.tokens.append(tok)
@@ -1332,9 +1516,14 @@ class GenerationScheduler(ContinuousBatcher):
          of every live slot's token latency), escalating to ALL free slots
          when the queue is deeper than `pressure_queue` (throughput beats
          tail latency once a backlog forms);
-      2. run ONE fixed-shape decode step for every live slot;
-      3. retire finished slots (EOS / max-new / context bound), releasing
-         their pages, and resolve their futures with GenResult.
+      2. dispatch ONE fixed-shape decode step for every live slot that
+         goes on, from the ids the step before it left on the device, then
+         read that step before it (engine.decode_step(ahead=True): the
+         host is one step behind the device, except where a row has a
+         temperature);
+      3. retire the slots the read found finished (EOS, seen one step
+         late / max-new / context bound), releasing their pages, and
+         resolve their futures with GenResult.
 
     The queue is bounded in REQUESTS (one row each — a generation request's
     device debt is a slot, not its prompt length).
@@ -1443,7 +1632,7 @@ class GenerationScheduler(ContinuousBatcher):
             # an unlocked peek (only this thread empties these), checked
             # again under the lock: a busy scheduler takes the lock once a
             # pass, inside the pass, where its wait is measured
-            if not (self._queue or self._runs or self._prefills):
+            if not self._busy():
                 self._wait_for_work()
             self._iter += 1
             self.engine.iter = self._iter
@@ -1451,19 +1640,24 @@ class GenerationScheduler(ContinuousBatcher):
                 if not self._pass():
                     return
 
+    def _busy(self):
+        """Whether a pass has anything to do: a request queued, admitted or
+        decoding, or a decode step dispatched and not yet read."""
+        return bool(self._queue or self._runs or self._prefills
+                    or self.engine.steps_in_flight)
+
     def _wait_for_work(self):
         with self._cond:
-            if (self._alive and not self._queue and not self._runs
-                    and not self._prefills):
+            if self._alive and not self._busy():
                 with self._phase("idle"):
-                    while (self._alive and not self._queue and not self._runs
-                           and not self._prefills):
+                    while self._alive and not self._busy():
                         self._cond.wait()
 
     def _pass(self):
         """One iteration, in five phases that partition it: lock, admit,
-        prefill, decode, retire. False when the scheduler is closed and has
-        nothing left to do."""
+        prefill, decode (dispatch the next step, read the one in flight),
+        retire. False when the scheduler is closed and has nothing left to
+        do."""
         stall = self.engine.host_stall
         with self._phase("lock"):
             self._cond.acquire()
@@ -1473,8 +1667,7 @@ class GenerationScheduler(ContinuousBatcher):
                     if not self._drain_flag:
                         self._fail_runs_locked()
                         return False
-                    if (not self._queue and not self._runs
-                            and not self._prefills):
+                    if not self._busy():
                         return False
                 admits = self._admit_requests_locked()
             finally:
@@ -1482,17 +1675,17 @@ class GenerationScheduler(ContinuousBatcher):
             self._admit(admits)
         with self._phase("prefill"):
             self._prefill_chunks()
-        live = list(self._runs.values())
-        if live:
+        if self._runs or self.engine.steps_in_flight:
             with self._phase("decode") as decode:
-                ok = self._decode(live)
-            if ok:
-                with self._phase("retire", stall):
-                    for run in live:
-                        self._m_token_ms.observe(decode.ms)
-                        if run.done:
-                            del self._runs[run.slot]
-                            self._retire(run)
+                read = self._decode(list(self._runs.values()))
+            with self._phase("retire", stall):
+                for run in read:
+                    if self._runs.get(run.slot) is not run:
+                        continue  # retired at its eos, one step ago
+                    self._m_token_ms.observe(decode.ms)
+                    if run.done:
+                        del self._runs[run.slot]
+                        self._retire(run)
         self._m_stall_ms.observe(stall.take())
         return True
 
@@ -1612,14 +1805,17 @@ class GenerationScheduler(ContinuousBatcher):
                     self._runs[run.slot] = run
 
     def _decode(self, live):
-        """One decode step for every live run. False when it failed: every
-        live run's future then holds the error and its slot is released."""
+        """Dispatch the next decode step for the live runs that go on and
+        read the one in flight; returns the runs read. When either failed,
+        none: every live run (those of the step in flight are among them)
+        then holds the error and its slot is released."""
         eng = self.engine
         try:
             # the decode step is shared across slots; its engine.decode
             # span hangs off one representative request's trace
-            with _tracing.tracer().activate(live[0].span):
-                eng.decode_step(live)
+            with _tracing.tracer().activate(
+                    live[0].span if live else NULL_SPAN):
+                return eng.decode_step(live, ahead=True)
         except Exception as e:
             for run in live:
                 self._m_requests.inc(outcome="error")
@@ -1629,8 +1825,7 @@ class GenerationScheduler(ContinuousBatcher):
                 run.future._set_error(err)
                 eng.finish(run)
             self._runs.clear()
-            return False
-        return True
+            return []
 
     def _retire(self, run):
         self.engine.finish(run)
@@ -1645,6 +1840,10 @@ class GenerationScheduler(ContinuousBatcher):
         run.future._set_result(run.result())
 
     def _fail_runs_locked(self):
+        try:
+            self.engine.drain("close")  # no slot is released under a step
+        except Exception:
+            pass  # its runs fail below either way
         for run in list(self._runs.values()) + self._prefills:
             self._m_requests.inc(outcome="shutdown")
             run.span.tag(outcome="shutdown").end("error")

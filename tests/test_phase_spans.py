@@ -112,6 +112,7 @@ def test_scheduler_phases_partition_the_iteration(tmp_path):
         assert "iter" in s["tags"], s
         by_iter.setdefault(s["tags"]["iter"], []).append(s)
     covered_share = []  # of each decoding pass
+    leaves_seen = set()  # the decode leaves of a pass, in order
     for it, group in by_iter.items():
         wrappers = [s for s in group if s["name"] == "sched.iter"]
         if not wrappers:
@@ -133,10 +134,21 @@ def test_scheduler_phases_partition_the_iteration(tmp_path):
         for leaf in (s for s in group if s["name"].startswith("engine.")):
             outer = held["sched." + leaf["tags"]["kind"]]
             assert inside(leaf, outer), (leaf, outer)
+        # a pass feeds and dispatches step n+1, then waits for and samples
+        # step n (the host reads a decode step one step late): the first
+        # decoding pass has no step to read, the last none to dispatch
+        decode_leaves = [s["name"][len("engine."):] for s in sorted(
+            (s for s in group if s["name"].startswith("engine.")
+             and s["tags"]["kind"] == "decode"), key=lambda s: s["start"])]
+        assert decode_leaves in (
+            [], list(ENGINE_LEAVES), list(ENGINE_LEAVES[:2]),
+            list(ENGINE_LEAVES[2:])), decode_leaves
+        leaves_seen.add(tuple(decode_leaves))
         if "sched.decode" in held:
             covered = sum(s["end"] - s["start"] for s in phases)
             covered_share.append(covered / (wrapper["end"] - wrapper["start"]))
     assert len(covered_share) >= 5
+    assert ENGINE_LEAVES in leaves_seen  # most passes: dispatch n+1, read n
     # The phases cover the pass. A thread descheduled between two phases
     # opens a gap in that pass alone, so the claim is held on the median pass.
     assert np.median(covered_share) >= 0.9, sorted(covered_share)
