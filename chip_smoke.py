@@ -646,9 +646,74 @@ def phase_kernels():
             done[name] = "compiled"
     finally:
         flags.set_flags(prev)
+    _kernel_ssm_state_step(done)
 
     say("kernels: ok, %s" % ", ".join(sorted(done)))
     return done
+
+
+def _kernel_ssm_state_step(done):
+    """The state-space decode step's in-place kernel at granite4h_burst_chat's
+    widths (48 slots, a state row [128, 4096] float32, the pool donated)
+    against XLA's gather, update and scatter of the same step: the live
+    slots' rows updated, every other row of the pool bit for bit as it was,
+    each live slot's y in its own row, over patterns of live slots with idle
+    ones before, among and behind them."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    slots, hp, n = 48, 4096, 128
+    rng = np.random.default_rng(0)
+    mk = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    check(pk.ssm_step_path_taken(hp, n, 1),
+          "kernels: ssm_scan's step declines the kernel at the cell's widths")
+
+    def xla_step(pool, rows, xdt, dec, b, c):
+        prev = jnp.take(pool, rows, axis=0)
+        new = prev * dec[:, None, :] + b[:, :, None] * xdt[:, None, :]
+        keep = (rows != 0)[:, None, None]
+        return (jnp.where(keep[:, 0], jnp.sum(new * c[:, :, None], axis=1), 0.0),
+                pool.at[rows].set(jnp.where(keep, new, prev)))
+
+    kernel = jax.jit(pk.ssm_state_step, donate_argnums=(0,))
+    exe = kernel.lower(mk(slots + 1, n, hp), jnp.zeros(slots, jnp.int32),
+                       mk(slots, hp), mk(slots, hp), mk(slots, n),
+                       mk(slots, n)).compile()
+    check("tpu_custom_call" in exe.as_text(),
+          "kernels: ssm_state_step compiled without a tpu_custom_call")
+    state_rows = np.arange(1, slots + 1)
+    every = np.arange(slots)
+    patterns = {
+        "none": np.zeros(slots, bool), "all": np.ones(slots, bool),
+        "first": every == 0, "last": every == slots - 1,
+        "every other": every % 2 == 0,
+        "thirty, rows permuted": rng.permutation(slots) < 30,
+    }
+    for name, live in patterns.items():
+        rows = np.where(live, state_rows, 0).astype(np.int32)
+        if "permuted" in name:  # a slot's state row is not its own number
+            rows = np.where(live, rng.permutation(state_rows), 0).astype(np.int32)
+        pool = mk(slots + 1, n, hp)
+        args = (jnp.asarray(rows), mk(slots, hp),
+                jnp.asarray(rng.uniform(0.5, 1, (slots, hp)), jnp.float32),
+                mk(slots, n), mk(slots, n))
+        want_y, want_pool = jax.jit(xla_step)(pool, *args)
+        y, new = exe(pool, *args)  # the pool is donated: `pool` is gone
+        new, want_pool = np.asarray(new), np.asarray(want_pool)
+        idle = np.setdiff1d(state_rows, rows)
+        check(np.array_equal(new[idle], want_pool[idle]),
+              "kernels: ssm_state_step (%s): a row no live slot holds changed" % name)
+        if live.any():
+            _close("ssm_state_step live rows (%s)" % name, new[rows[live]],
+                   want_pool[rows[live]], 1e-6, 1e-6)
+        check(bool(np.isfinite(new[0]).all()),
+              "kernels: ssm_state_step (%s): the scratch row is not finite" % name)
+        check(bool((np.asarray(y)[~live] == 0).all()),
+              "kernels: ssm_state_step (%s): an idle slot's y is not 0" % name)
+        _close("ssm_state_step y (%s)" % name, y, want_y, 1e-5, 1e-4)
+    done["ssm_state_step %d patterns" % len(patterns)] = "compiled"
 
 
 # --------------------------------------------------------------------------

@@ -86,6 +86,7 @@ __all__ = [
     "mla_attention",
     "swiglu",
     "short_conv",
+    "ssm_scan",
     "moe_router",
     "moe_experts",
 ]
@@ -1539,33 +1540,87 @@ def swiglu(gate, up, name=None):
 
 
 def short_conv(x, taps, param_attr=None, state=None, rows=None, start=None,
-               live=None, mode="chunk", seq_len=0, name=None):
-    """Gated short convolution between its projections: ``x`` is ``[rows, 3
-    * d]`` = ``[B, C, z]``; returns ``C * conv(B * z)`` with a depthwise
-    causal kernel of ``taps`` taps. ``state`` is the persistable
-    ``[state_rows, (taps - 1) * d]`` pool holding each sequence's last
-    ``taps - 1`` rows of ``B * z``, indexed by the row table ``rows`` (row 0
-    is scratch); the op writes it in place. ``mode`` "chunk": consecutive
-    positions of one sequence from ``start``, ``live`` marks the rows that
-    are not padding; "step": one position of a sequence a row. Without a
-    state the rows are whole sequences of ``seq_len`` positions."""
+               live=None, mode="chunk", seq_len=0, form="gated",
+               bias_attr=None, name=None):
+    """A depthwise causal convolution of ``taps`` taps over the sequence.
+    ``form`` "gated": between its projections, ``x`` is ``[rows, 3 * d]`` =
+    ``[B, C, z]`` and the result ``C * conv(B * z)``; "silu": ``x`` is
+    ``[rows, d]`` and the result ``silu(conv(x) + bias)`` (the bias with
+    ``bias_attr``). ``state`` is the persistable ``[state_rows, (taps - 1)
+    * d]`` pool holding each sequence's last ``taps - 1`` rows of what is
+    convolved, indexed by the row table ``rows`` (row 0 is scratch); the op
+    writes it in place. ``mode`` "chunk": consecutive positions of one
+    sequence from ``start``, ``live`` marks the rows that are not padding;
+    "step": one position of a sequence a row. Without a state the rows are
+    whole sequences of ``seq_len`` positions."""
     helper = LayerHelper("short_conv", name=name)
-    d = int(x.shape[-1]) // 3
+    gated = form == "gated"
+    d = int(x.shape[-1]) // (3 if gated else 1)
     kernel = helper.create_parameter(
         attr=param_attr, shape=[d, int(taps)], dtype="float32")
     out = helper.create_variable_for_type_inference(x.dtype)
     inputs = {"X": [x.name], "Kernel": [kernel.name]}
     outputs = {"Out": [out.name]}
-    if state is not None:
-        inputs["State"] = [state.name]
-        inputs["Rows"] = [rows.name]
-        outputs["StateOut"] = [state.name]
-        if mode == "chunk":
-            inputs["Start"] = [start.name]
-            inputs["Live"] = [live.name]
+    attrs = {"mode": mode, "seq_len": int(seq_len)}
+    if not gated:
+        attrs["form"] = str(form)
+        if bias_attr is not None:
+            bias = helper.create_parameter(
+                attr=bias_attr, shape=[d], dtype="float32", is_bias=True)
+            inputs["Bias"] = [bias.name]
+    _seq_state_slots(inputs, outputs, state, rows, start, live, mode)
     helper.append_op(
-        type="short_conv", inputs=inputs, outputs=outputs,
-        attrs={"mode": mode, "seq_len": int(seq_len)},
+        type="short_conv", inputs=inputs, outputs=outputs, attrs=attrs)
+    return out
+
+
+def _seq_state_slots(inputs, outputs, state, rows, start, live, mode):
+    """The slots of an op that keeps a per-sequence state row in a pool it
+    writes in place (short_conv, ssm_scan)."""
+    if state is None:
+        return
+    inputs["State"] = [state.name]
+    inputs["Rows"] = [rows.name]
+    outputs["StateOut"] = [state.name]
+    if mode == "chunk":
+        inputs["Start"] = [start.name]
+        inputs["Live"] = [live.name]
+
+
+def ssm_scan(x, dt, z, heads, d_head, d_state, groups=1, chunk=256,
+             epsilon=1e-5, dt_bias_attr=None, a_log_attr=None, d_attr=None,
+             norm_attr=None, state=None, rows=None, start=None, live=None,
+             mode="chunk", seq_len=0, name=None):
+    """The Mamba-2 state-space operator between its convolution and its
+    output projection (ops/decoder_ops.py ``ssm_scan``): ``x`` is the
+    convolved ``[x | B | C]`` (``[rows, heads * d_head + 2 * groups *
+    d_state]``), ``dt`` the raw step sizes ``[rows, heads]``, ``z`` the gate
+    ``[rows, heads * d_head]``; returns the gated, RMS-normed output, shaped
+    like ``z``. Its parameters (``dt_bias``, ``A_log``, ``D`` a head, the
+    norm's scale) are float32, as is ``state``, the persistable
+    ``[state_rows, d_state * heads * d_head]`` pool of the sequences'
+    recurrent states, handled as ``short_conv``'s: ``rows``, ``start``,
+    ``live``, ``mode`` and ``seq_len`` mean what they mean there."""
+    helper = LayerHelper("ssm_scan", name=name)
+    f32 = lambda attr, shape: helper.create_parameter(
+        attr=attr, shape=shape, dtype="float32")
+    heads = int(heads)
+    inputs = {
+        "X": [x.name], "Dt": [dt.name], "Z": [z.name],
+        "DtBias": [f32(dt_bias_attr, [heads]).name],
+        "ALog": [f32(a_log_attr, [heads]).name],
+        "D": [f32(d_attr, [heads]).name],
+        "NormScale": [f32(norm_attr, [heads * int(d_head)]).name],
+    }
+    out = helper.create_variable_for_type_inference(z.dtype)
+    outputs = {"Out": [out.name]}
+    _seq_state_slots(inputs, outputs, state, rows, start, live, mode)
+    helper.append_op(
+        type="ssm_scan", inputs=inputs, outputs=outputs,
+        attrs={"mode": mode, "seq_len": int(seq_len), "heads": heads,
+               "d_head": int(d_head), "d_state": int(d_state),
+               "groups": int(groups), "chunk": int(chunk),
+               "epsilon": float(epsilon)},
     )
     return out
 

@@ -8,9 +8,20 @@ A layer is two sub-layers, ``h = x + OP(norm(x)); x' = h + FF(norm(h))``
 under the ``plain`` residual, with
 
   * OP ``"attention"``: grouped-query attention, per-head RMS norm of q and
-    k before rotary positions (half-rotation form), causal, through
+    k (``qk_norm``) before rotary positions (``position`` "rotary", the
+    half-rotation form; "none": no position enters at all), scores scaled by
+    ``attn_scale`` (default ``d_head ** -0.5``), causal, through
     ``kv_cache_write`` / ``paged_attention`` over pools whose rows are
     ``n_kv_head * d_head`` wide;
+  * OP ``"mamba"``: the Mamba-2 state-space operator (``ssm={heads, d_head,
+    d_state, groups, conv, chunk}``): one projection gives ``[z | xBC |
+    dt]``, ``xBC`` goes through a depthwise causal convolution with bias and
+    silu (``short_conv``, form "silu"), and ``ssm_scan`` runs the recurrence
+    ``H_t = exp(dt_t A) H_{t-1} + dt_t x_t (outer) B_t``, ``y_t = H_t C_t +
+    D x_t``, gates with ``silu(z)`` and RMS-norms, all in float32. A
+    sequence keeps two state rows a layer beside the pages: the convolution's
+    last ``conv - 1`` inputs in ``dtype`` and the recurrent state ``H``
+    (``heads * d_head * d_state`` values) in float32;
   * OP ``"conv"``: the gated short convolution (ops/decoder_ops.py
     ``short_conv``), whose per-sequence state — ``conv_taps - 1`` rows of
     ``d_model`` — lives in a per-slot state pool beside the pages;
@@ -41,15 +52,18 @@ embedding is repeated into every copy and the copies are summed before the
 last norm.
 
 The head is tied to the token embedding unless ``tied_head=False`` (its own
-``[vocab, d_model]`` matrix); there is no position embedding.
+``[vocab, d_model]`` matrix); there is no position embedding. Three
+multipliers scale the stream (all 1 by default): the embedding times
+``embed_scale``, every sub-layer's output times ``residual_scale`` before it
+is added, the logits over ``logit_scale``.
 Parameters, activations and caches are in ``dtype`` (bfloat16 as published;
 float32 for tight tests); norm scales, the router's matrix and bias and the
 conv taps are float32, and the norms, router, softmax and the conv's
 accumulation compute in float32 whatever ``dtype`` is. Logits leave the
 last product in float32.
 
-``lfm2_moe(config)`` and ``xing4_0(config)`` turn a ``config.json`` of
-those model types into such a decoder: the architecture is data for this
+``lfm2_moe(config)``, ``xing4_0(config)`` and ``granitemoehybrid(config)``
+turn a ``config.json`` of those model types into such a decoder: the architecture is data for this
 module.
 """
 
@@ -58,22 +72,58 @@ import numpy as np
 from .. import framework, unique_name
 from .. import layers
 from ..executor import Executor
-from ..initializer import Normal
+from ..initializer import Constant, Initializer, Normal
 from ..layer_helper import LayerHelper
 from ..param_attr import ParamAttr
 
-__all__ = ["BlockDecoder", "lfm2_moe", "xing4_0"]
+__all__ = ["BlockDecoder", "lfm2_moe", "xing4_0", "granitemoehybrid"]
 
-OPERATORS = ("attention", "conv", "mla")
+OPERATORS = ("attention", "conv", "mla", "mamba")
 _LANES = 128  # a latent pool's row is padded to whole lane tiles
 FEED_FORWARDS = ("dense", "moe")
+
+
+class _MambaInit(Initializer):
+    """From the seed, by Mamba-2's own initialisation of a state-space
+    layer's rates and step sizes. ``"a_log"``: A uniform in [1, 16], the
+    parameter its log. ``"dt_bias"``: dt log-uniform in [1e-3, 1e-1], the
+    parameter its inverse softplus, dt + log(1 - exp(-dt))."""
+
+    def __init__(self, which):
+        self.which = which
+
+    def __call__(self, var, block):
+        lo, hi = ((1.0, 16.0) if self.which == "a_log"
+                  else (float(np.log(1e-3)), float(np.log(1e-1))))
+        block.append_op(
+            type="uniform_random", outputs={"Out": [var.name]},
+            attrs={"shape": list(var.shape), "dtype": var.dtype,
+                   "min": lo, "max": hi, "seed": 0})
+        one = lambda t, src, dst, **attrs: block.append_op(
+            type=t, inputs={"X": [src.name]}, outputs={"Out": [dst.name]},
+            attrs=attrs)
+        if self.which == "a_log":
+            return one("log", var, var)
+        tmp = block.create_var(
+            name=unique_name.generate(var.name + "_init"), shape=var.shape,
+            dtype=var.dtype)
+        one("exp", var, var)  # dt
+        one("scale", var, tmp, scale=-1.0)
+        one("exp", tmp, tmp)
+        one("scale", tmp, tmp, scale=-1.0, bias=1.0)
+        one("log", tmp, tmp)  # log(1 - exp(-dt))
+        return block.append_op(
+            type="elementwise_add", inputs={"X": [var.name], "Y": [tmp.name]},
+            outputs={"Out": [var.name]}, attrs={"axis": -1})
 
 
 class BlockDecoder:
     def __init__(self, vocab_size, d_model, blocks, n_head, n_kv_head, d_head,
                  d_ffn, moe=None, conv_taps=3, norm_eps=1e-5, rope_theta=1e6,
                  max_context=2048, eos_id=-1, prefix="bd", dtype="bfloat16",
-                 init_std=0.02, mla=None, hyper=None, tied_head=True):
+                 init_std=0.02, mla=None, hyper=None, tied_head=True,
+                 ssm=None, position="rotary", qk_norm=True, attn_scale=None,
+                 embed_scale=1.0, residual_scale=1.0, logit_scale=1.0):
         """``blocks`` is ``[(operator, feed_forward)]``, a layer each.
         ``moe`` is ``{num_experts, k, width, use_bias, norm_topk, scale,
         experts_held, shared_width}`` (the last two optional). ``mla`` is
@@ -101,6 +151,18 @@ class BlockDecoder:
         self.mla = dict(mla or {})
         if any(o == "mla" for o, _ in self.blocks) and not self.mla:
             raise ValueError("an mla block needs the mla settings")
+        self.ssm = dict(ssm or {})
+        if any(o == "mamba" for o, _ in self.blocks) and not self.ssm:
+            raise ValueError("a mamba block needs the ssm settings")
+        if position not in ("rotary", "none"):
+            raise ValueError("position must be 'rotary' or 'none'")
+        self.position = position
+        self.qk_norm = bool(qk_norm)
+        self.attn_scale = (
+            self.d_head ** -0.5 if attn_scale is None else float(attn_scale))
+        self.embed_scale = float(embed_scale)
+        self.residual_scale = float(residual_scale)
+        self.logit_scale = float(logit_scale)
         self.hyper = dict(hyper) if hyper else None
         self.tied_head = bool(tied_head)
         self.conv_taps = int(conv_taps)
@@ -120,7 +182,7 @@ class BlockDecoder:
     def spec(self):
         """The block specification as plain data: what the compile cache's
         key folds in beside the program (GenerationEngine.geometry)."""
-        return {
+        spec = {
             "blocks": [list(b) for b in self.blocks], "dtype": self.dtype,
             "n_head": self.n_head, "n_kv_head": self.n_kv_head,
             "d_head": self.d_head, "d_model": self.d_model,
@@ -129,6 +191,27 @@ class BlockDecoder:
             "mla": {k: self.mla[k] for k in sorted(self.mla)},
             "hyper": self.hyper, "tied_head": self.tied_head,
         }
+        # what a decoder built before these settings existed does not say:
+        # its key in the compile cache stays what it was
+        if self.ssm:
+            spec["ssm"] = {k: self.ssm[k] for k in sorted(self.ssm)}
+        own = {"position": self.position, "qk_norm": self.qk_norm,
+               "attn_scale": self.attn_scale, "embed_scale": self.embed_scale,
+               "residual_scale": self.residual_scale,
+               "logit_scale": self.logit_scale}
+        default = {"position": "rotary", "qk_norm": True,
+                   "attn_scale": self.d_head ** -0.5, "embed_scale": 1.0,
+                   "residual_scale": 1.0, "logit_scale": 1.0}
+        spec.update({k: v for k, v in own.items() if v != default[k]})
+        return spec
+
+    def ssm_widths(self):
+        """(d_inner, conv_dim): the state-space operator's inner width,
+        heads * d_head, and the width of what its convolution sees, [x | B |
+        C] = d_inner + 2 * groups * d_state."""
+        m = self.ssm
+        d_inner = m["heads"] * m["d_head"]
+        return d_inner, d_inner + 2 * m.get("groups", 1) * m["d_state"]
 
     @property
     def experts_held(self):
@@ -153,8 +236,15 @@ class BlockDecoder:
             elif op == "mla":
                 parts += ["q_a_w", "q_a_norm_w", "q_b_w", "kv_a_w",
                           "kv_a_norm_w", "uk_w", "uv_w", "o_w"]
+            elif op == "mamba":
+                parts += ["ssm_in_w", "ssm_conv_k", "ssm_conv_b",
+                          "ssm_dt_bias", "ssm_a_log", "ssm_d", "ssm_norm_w",
+                          "ssm_out_w"]
             else:
-                parts += ["q_w", "k_w", "v_w", "q_norm_w", "k_norm_w", "o_w"]
+                parts += ["q_w", "k_w", "v_w"]
+                if self.qk_norm:
+                    parts += ["q_norm_w", "k_norm_w"]
+                parts.append("o_w")
             parts.append("ffn_norm_w")
             if ff == "dense":
                 parts += ["ff1_w", "ff3_w", "ff2_w"]
@@ -180,7 +270,12 @@ class BlockDecoder:
         (a ``[max_slots + 1, width]`` pool of fixed per-slot rows, row 0
         scratch). An attention layer has a K and a V pool; an mla layer ONE
         pool, ``form`` "latent", whose row holds ``values`` = kv_rank +
-        d_rope values and zeros up to ``width``."""
+        d_rope values and zeros up to ``width``; a mamba layer two state
+        pools of different dtype and width, the convolution's and the
+        float32 recurrent state's (``form`` "recurrent"; its row is not
+        flat but ``shape`` = [d_state, heads * d_head], so a pool is
+        ``[max_slots + 1, d_state, heads * d_head]``: a sequence's state in
+        tiles of its own)."""
         specs = []
         for i, (op, _) in enumerate(self.blocks):
             li = "l%d" % i
@@ -196,6 +291,17 @@ class BlockDecoder:
                     "name": self._p(li, "kv_latent"), "kind": "paged",
                     "layer": i, "width": width, "values": values,
                     "form": "latent", "dtype": self.dtype})
+            elif op == "mamba":
+                d_inner, conv_dim = self.ssm_widths()
+                specs.append({
+                    "name": self._p(li, "ssm_conv_state"), "kind": "state",
+                    "layer": i, "width": (self.ssm["conv"] - 1) * conv_dim,
+                    "dtype": self.dtype})
+                specs.append({
+                    "name": self._p(li, "ssm_state"), "kind": "state",
+                    "layer": i, "width": d_inner * self.ssm["d_state"],
+                    "shape": [self.ssm["d_state"], d_inner],
+                    "form": "recurrent", "dtype": "float32"})
             else:
                 specs.append({
                     "name": self._p(li, "conv_state"), "kind": "state",
@@ -240,6 +346,8 @@ class BlockDecoder:
             param_attr=ParamAttr(name=self._p("tok_emb"),
                                  initializer=Normal(0.0, self.init_std))),
             [rows, self.d_model])
+        if self.embed_scale != 1.0:
+            x = self._scaled(x, self.embed_scale)
         if self.hyper:
             x = layers.concat([x] * self.hyper["streams"], axis=1)
         return x
@@ -304,11 +412,41 @@ class BlockDecoder:
         q = self._linear(u, i, "q_w", self.n_head * self.d_head)
         k = self._linear(u, i, "k_w", self.n_kv_head * self.d_head)
         v = self._linear(u, i, "v_w", self.n_kv_head * self.d_head)
-        q = self._norm(q, self._p("l%d" % i, "q_norm_w"), groups=self.n_head)
-        k = self._norm(k, self._p("l%d" % i, "k_norm_w"), groups=self.n_kv_head)
-        q = layers.rotary_embedding(q, pos, self.n_head, self.rope_theta)
-        k = layers.rotary_embedding(k, pos, self.n_kv_head, self.rope_theta)
+        if self.qk_norm:
+            q = self._norm(q, self._p("l%d" % i, "q_norm_w"), groups=self.n_head)
+            k = self._norm(
+                k, self._p("l%d" % i, "k_norm_w"), groups=self.n_kv_head)
+        if self.position == "rotary":
+            q = layers.rotary_embedding(q, pos, self.n_head, self.rope_theta)
+            k = layers.rotary_embedding(k, pos, self.n_kv_head, self.rope_theta)
         return q, k, v
+
+    def _mamba(self, u, i, conv_state=None, ssm_state=None, **state):
+        """The state-space operator: [z | xBC | dt] = u W_in; the
+        convolution over xBC; the scan, gate and norm; W_out."""
+        m = self.ssm
+        d_inner, conv_dim = self.ssm_widths()
+        z, xbc, dt = layers.split(
+            self._linear(u, i, "ssm_in_w", d_inner + conv_dim + m["heads"]),
+            [d_inner, conv_dim, m["heads"]], dim=1)
+        xbc = layers.short_conv(
+            xbc, m["conv"], form="silu", state=conv_state,
+            param_attr=self._attr(i, "ssm_conv_k", std=0.5),
+            bias_attr=self._attr(i, "ssm_conv_b", std=0.1), **state)
+        at = lambda suffix, init: ParamAttr(
+            name=self._p("l%d" % i, suffix), initializer=init)
+        y = layers.ssm_scan(
+            xbc, dt, z, m["heads"], m["d_head"], m["d_state"],
+            groups=m.get("groups", 1), chunk=m.get("chunk", 256),
+            epsilon=self.norm_eps, state=ssm_state,
+            # the rates and step sizes as Mamba-2 initialises them: with
+            # Normal(0, init_std) every decay is ~exp(-0.7) and the state
+            # forgets in a few steps, so nothing would tell a wrong state
+            dt_bias_attr=at("ssm_dt_bias", _MambaInit("dt_bias")),
+            a_log_attr=at("ssm_a_log", _MambaInit("a_log")),
+            d_attr=at("ssm_d", Constant(1.0)),
+            norm_attr=at("ssm_norm_w", Normal(1.0, 0.1)), **state)
+        return self._linear(y, i, "ssm_out_w", self.d_model)
 
     def _conv(self, u, i, **state):
         bcz = self._linear(u, i, "conv_in_w", 3 * self.d_model)
@@ -345,11 +483,23 @@ class BlockDecoder:
         return layers.elementwise_add(
             routed, self._linear(g, i, "sh_w2", self.d_model))
 
+    def _scaled(self, x, factor):
+        """x * factor, the product in float32 and rounded once (the scale
+        op's factor is rounded to x's dtype first: 0.22 is 0.2197 in
+        bfloat16)."""
+        if x.dtype in ("float32", np.float32):
+            return layers.scale(x, scale=factor)
+        return layers.cast(
+            layers.scale(layers.cast(x, "float32"), scale=factor), self.dtype)
+
     def _sublayer(self, x, i, which, fn):
         """One sub-layer under the residual scheme: x + fn(norm(x)), or the
         same through a hyper-connection over the stream's copies. `which`
         is "op" or "ffn": the names of its norm and its connection."""
         norm = self._p("l%d" % i, which + "_norm_w")
+        if self.residual_scale != 1.0:
+            inner = fn
+            fn = lambda u: self._scaled(inner(u), self.residual_scale)
         if not self.hyper:
             return layers.elementwise_add(x, fn(self._norm(x, norm)))
         h = self.hyper
@@ -393,8 +543,8 @@ class BlockDecoder:
         helper.append_op(
             type="matmul", inputs={"X": [h.name], "Y": [emb.name]},
             outputs={"Out": [out.name]},
-            attrs={"transpose_X": False, "transpose_Y": True, "alpha": 1.0,
-                   "out_dtype": "float32"})
+            attrs={"transpose_X": False, "transpose_Y": True,
+                   "alpha": 1.0 / self.logit_scale, "out_dtype": "float32"})
         return out
 
     def _picks(self, picks_out):
@@ -407,19 +557,23 @@ class BlockDecoder:
         for s in self.cache_specs():
             rows = pool_rows if s["kind"] == "paged" else state_rows
             out[s["name"]] = block.create_var(
-                name=s["name"], shape=[rows, s["width"]], dtype=s["dtype"],
-                persistable=True)
+                name=s["name"], shape=[rows] + list(s.get("shape") or [s["width"]]),
+                dtype=s["dtype"], persistable=True)
         return out
 
     def _cached_operator(self, caches, table, pos, page_size, conv_state):
         """OP through the caches: attention writes its K/V rows and attends
         the pool; mla writes its latent row and attends it absorbed; conv
-        reads and writes its state row(s)."""
+        and mamba read and write their state row(s)."""
         def operator(u, i):
             li = "l%d" % i
             if self.blocks[i][0] == "conv":
                 return self._conv(
                     u, i, state=caches[self._p(li, "conv_state")], **conv_state)
+            if self.blocks[i][0] == "mamba":
+                return self._mamba(
+                    u, i, conv_state=caches[self._p(li, "ssm_conv_state")],
+                    ssm_state=caches[self._p(li, "ssm_state")], **conv_state)
             if self.blocks[i][0] == "mla":
                 pool = caches[self._p(li, "kv_latent")]
                 layers.kv_cache_write(
@@ -434,7 +588,9 @@ class BlockDecoder:
             layers.kv_cache_write(v_pool, v, table, pos, page_size)
             att = layers.paged_attention(
                 q, k_pool, v_pool, table, pos, n_head=self.n_head,
-                page_size=page_size, n_kv_head=self.n_kv_head)
+                page_size=page_size, n_kv_head=self.n_kv_head,
+                sm_scale=(None if self.attn_scale == self.d_head ** -0.5
+                          else self.attn_scale))
             return self._linear(att, i, "o_w", self.d_model)
         return operator
 
@@ -487,6 +643,8 @@ class BlockDecoder:
             def operator(u, i):
                 if self.blocks[i][0] == "conv":
                     return self._conv(u, i, seq_len=t)
+                if self.blocks[i][0] == "mamba":
+                    return self._mamba(u, i, seq_len=t)
                 if self.blocks[i][0] == "mla":
                     return mla_operator(u, i)
                 q, k, v = self._qkv(u, i, pos)
@@ -499,7 +657,7 @@ class BlockDecoder:
                 kv = lambda y: layers.transpose(layers.reshape(
                     y, [batch, t, self.n_kv_head, self.d_head]), [0, 2, 1, 3])
                 scores = layers.matmul(
-                    q, kv(k), transpose_y=True, alpha=self.d_head ** -0.5)
+                    q, kv(k), transpose_y=True, alpha=self.attn_scale)
                 w = layers.softmax(layers.elementwise_add(
                     layers.cast(scores, "float32"), tri))
                 ctx = layers.matmul(layers.cast(w, self.dtype), kv(v))
@@ -708,3 +866,63 @@ def xing4_0(config, **kw):
         norm_eps=float(c["rms_norm_eps"]), rope_theta=float(c["rope_theta"]),
         mla=mla, hyper=hyper,
         tied_head=bool(c.get("tie_word_embeddings", False)), **kw)
+
+
+def granitemoehybrid(config, **kw):
+    """The BlockDecoder of a ``granitemoehybrid`` ``config.json`` (as a
+    dict, under the source's own keys). ``layer_types`` gives each layer's
+    operator, "mamba" (``mamba_n_heads`` heads of ``mamba_d_head`` on a
+    state of ``mamba_d_state``, ``mamba_n_groups`` groups of B and C, a
+    convolution of ``mamba_d_conv`` taps with bias, chunks of
+    ``mamba_chunk_size``) or "attention" (grouped-query, no q/k norm,
+    scores times ``attention_multiplier``, rotary positions only where
+    ``position_embedding_type`` is "rope": "nope" has none); every layer
+    has the dense gated feed-forward of ``shared_intermediate_size``. The
+    embedding is scaled by ``embedding_multiplier``, every sub-layer's
+    output by ``residual_multiplier``, the logits by 1 /
+    ``logits_scaling``; the head is tied. Routed experts beside the shared
+    feed-forward (``num_local_experts`` > 0) are not built. Further
+    keywords (max_context, dtype, eos_id, prefix) pass through."""
+    c = config
+    if int(c.get("num_local_experts") or 0) > 0:
+        raise ValueError(
+            "num_local_experts > 0: routed experts beside the shared "
+            "feed-forward are not built")
+    for key in ("attention_bias", "mamba_proj_bias"):
+        if c.get(key):
+            raise ValueError("%s is not built" % key)
+    if not c.get("mamba_conv_bias", True):
+        raise ValueError("a convolution without its bias is not built")
+    if c.get("normalization_function", "rmsnorm") != "rmsnorm":
+        raise ValueError("only rmsnorm is built")
+    if not c.get("tie_word_embeddings", True):
+        raise ValueError("an untied head is not built for this model type")
+    n = int(c["num_hidden_layers"])
+    if len(c["layer_types"]) != n:
+        raise ValueError("layer_types must name num_hidden_layers layers")
+    blocks = [(str(t), "dense") for t in c["layer_types"]]
+    d_model, n_head = int(c["hidden_size"]), int(c["num_attention_heads"])
+    ssm = {
+        "heads": int(c["mamba_n_heads"]), "d_head": int(c["mamba_d_head"]),
+        "d_state": int(c["mamba_d_state"]),
+        "groups": int(c.get("mamba_n_groups", 1)),
+        "conv": int(c["mamba_d_conv"]),
+        "chunk": int(c.get("mamba_chunk_size", 256)),
+    }
+    if ssm["heads"] * ssm["d_head"] != int(c["mamba_expand"]) * d_model:
+        raise ValueError("mamba_n_heads x mamba_d_head != mamba_expand x hidden_size")
+    position = {"nope": "none", "rope": "rotary"}[
+        c.get("position_embedding_type", "nope")]
+    kw.setdefault("max_context", int(c["max_position_embeddings"]))
+    kw.setdefault("prefix", "granite4h")
+    return BlockDecoder(
+        vocab_size=int(c["vocab_size"]), d_model=d_model, blocks=blocks,
+        n_head=n_head, n_kv_head=int(c["num_key_value_heads"]),
+        d_head=int(c.get("head_dim") or d_model // n_head),
+        d_ffn=int(c["shared_intermediate_size"]),
+        norm_eps=float(c["rms_norm_eps"]), rope_theta=float(c["rope_theta"]),
+        ssm=ssm, position=position, qk_norm=False,
+        attn_scale=float(c["attention_multiplier"]),
+        embed_scale=float(c["embedding_multiplier"]),
+        residual_scale=float(c["residual_multiplier"]),
+        logit_scale=float(c["logits_scaling"]), **kw)

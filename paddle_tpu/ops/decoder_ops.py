@@ -1,10 +1,11 @@
 """Ops of the block-specified decoder (models/block_decoder.py): RMS norm,
 rotary position embedding (whole or partial, plain or YaRN frequencies), the
 gated feed-forward's activation, the hyper-connection that reads and writes
-a residual stream of several copies, the gated
-short convolution with its fixed per-sequence state, and the mixture of
-experts (router, and a grouped expert product whose work follows the
-routing).
+a residual stream of several copies, the short
+convolution (gated, or with bias and silu) with its fixed per-sequence
+state, the Mamba-2 state-space scan with its float32 recurrent state, and
+the mixture of experts (router, and a grouped expert product whose work
+follows the routing).
 
 Conventions:
   * Activations and weights may be bfloat16; every op here computes its
@@ -12,8 +13,12 @@ Conventions:
     float32 and rounds once, to its input's dtype. The router's scores,
     weights and the matrix that makes them are float32 throughout: a top-k
     over bf16 scores picks other experts.
+  * ``ssm_scan`` keeps a sequence's recurrent state in a float32 pool
+    ``[state_rows, d_state, heads * d_head]`` under the same row table; its
+    step writes live rows only, in place (``pallas_kernels.ssm_state_step``
+    on a TPU), because a row is megabytes where a convolution's is KB.
   * ``short_conv`` keeps a sequence's state — the last ``L - 1`` rows of
-    ``v = B * z`` — in a persistable ``[state_rows, (L - 1) * d]`` pool that
+    what it convolves — in a persistable ``[state_rows, (L - 1) * d]`` pool that
     a fed row table indexes, as block tables index KV pages. Row 0 is a
     scratch row: idle and mid-prefill slots of a decode step write there
     and never to a live state. The op's ``StateOut`` IS the pool variable
@@ -254,8 +259,13 @@ def _hyper_connection_post(ctx, ins, attrs):
 def _short_conv_infer(op, block):
     x = _var(block, op, "X")
     out = _var(block, op, "Out", outputs=True)
-    out.shape = tuple(x.shape[:-1]) + (x.shape[-1] // 3,)
+    gated = op.attrs.get("form", "gated") == "gated"
+    out.shape = tuple(x.shape[:-1]) + (x.shape[-1] // (3 if gated else 1),)
     out.dtype = x.dtype
+    _state_out_like_state(op, block)
+
+
+def _state_out_like_state(op, block):
     state = _var(block, op, "State")
     state_out = _var(block, op, "StateOut", outputs=True)
     if state is not None and state_out is not None:
@@ -264,10 +274,14 @@ def _short_conv_infer(op, block):
 
 @register("short_conv", no_grad=True, infer_shape=_short_conv_infer)
 def _short_conv(ctx, ins, attrs):
-    """Gated short convolution between its two projections: X is
+    """A depthwise causal convolution of `L` taps over the sequence,
+    c_t = sum_j Kernel[:, j] * v_{t - (L-1) + j}, in one of two forms:
+
+    form "gated" (the default): between its two projections. X is
     ``u @ W_in`` = [B, C, z] (in that order, [rows, 3 * d]); v = B * z;
-    c_t = sum_j Kernel[:, j] * v_{t - (L-1) + j} (depthwise, causal);
     Out = C * c.
+    form "silu": X is v itself ([rows, d]); Out = silu(c + Bias) (Bias [d]
+    optional): the convolution in front of a state-space scan.
 
     mode "chunk": the rows are consecutive positions of ONE sequence from
     Start; the (L - 1) rows of v before them come from State[Rows[0]] (zeros
@@ -287,9 +301,16 @@ def _short_conv(ctx, ins, attrs):
     d, taps = kern.shape
     keep = taps - 1
     rows = x.shape[0]
-    b, c, z = (x[:, i * d:(i + 1) * d].astype(_F32) for i in range(3))
     store = state.dtype if state is not None else x.dtype
-    v = (b * z).astype(store).astype(_F32)
+    if attrs.get("form", "gated") == "gated":
+        b, c, z = (x[:, i * d:(i + 1) * d].astype(_F32) for i in range(3))
+        v = (b * z).astype(store).astype(_F32)
+        finish = lambda conv: (c * conv).astype(x.dtype)
+    else:
+        bias = ins.get("Bias", [None])[0]
+        v = x.astype(store).astype(_F32)
+        b32 = 0.0 if bias is None else bias.astype(_F32)
+        finish = lambda conv: jax.nn.silu(conv + b32).astype(x.dtype)
     k32 = kern.astype(_F32)
     if attrs.get("mode", "chunk") == "step":
         idx = ins["Rows"][0].reshape(-1).astype(jnp.int32)
@@ -298,15 +319,14 @@ def _short_conv(ctx, ins, attrs):
         for j in range(keep):
             conv = conv + prev[:, j] * k32[:, j]
         new = jnp.concatenate([prev[:, 1:], v[:, None]], 1).reshape(rows, keep * d)
-        out = (c * conv).astype(x.dtype)
-        return {"Out": [out],
+        return {"Out": [finish(conv)],
                 "StateOut": [state.at[idx].set(new.astype(state.dtype))]}
     if state is None:
         # whole sequences of seq_len rows each (all the rows are one when 0)
         t = int(attrs.get("seq_len", 0)) or rows
         vv = jnp.pad(v.reshape(rows // t, t, d), ((0, 0), (keep, 0), (0, 0)))
         conv = sum(vv[:, j:j + t] * k32[:, j] for j in range(taps))
-        return {"Out": [(c * conv.reshape(rows, d)).astype(x.dtype)]}
+        return {"Out": [finish(conv.reshape(rows, d))]}
     row = ins["Rows"][0].reshape(-1)[0].astype(jnp.int32)
     start = ins["Start"][0].reshape(-1)[0]
     prev = jnp.where(start > 0, state[row].astype(_F32).reshape(keep, d), 0.0)
@@ -315,10 +335,164 @@ def _short_conv(ctx, ins, attrs):
     n_live = jnp.sum(ins["Live"][0].reshape(-1).astype(jnp.int32))
     new = jax.lax.dynamic_slice_in_dim(vv, n_live, keep, 0)
     return {
-        "Out": [(c * conv).astype(x.dtype)],
+        "Out": [finish(conv)],
         "StateOut": [
             state.at[row].set(new.reshape(keep * d).astype(state.dtype))],
     }
+
+
+# ---------------------------------------------------------------------------
+# state-space scan (Mamba-2)
+# ---------------------------------------------------------------------------
+
+_HI = jax.lax.Precision.HIGHEST
+
+
+def ssd_chunk(x, dt, a, b, c, h0):
+    """One chunk of the state-space recurrence in its quadratic (SSD) form,
+    all float32. x [L, H, P] the heads' inputs, dt [L, H] the step sizes
+    (0 for a padding row: it decays nothing and adds nothing), a [H] the
+    heads' (negative) rates, b and c [L, H, N] the input and output maps a
+    head, h0 [H, P, N] the state before the chunk. Returns (y [L, H, P], h1
+    [H, P, N]) with, a head, H_t = exp(dt_t a) H_{t-1} + dt_t x_t (outer)
+    b_t and y_t = H_t c_t:
+
+        y_t = sum_{s<=t} exp(cum_t - cum_s) (c_t . b_s) dt_s x_s
+              + exp(cum_t) H_0 c_t,        cum_t = sum_{u<=t} dt_u a
+        H_L = exp(cum_L) H_0 + sum_s exp(cum_L - cum_s) dt_s x_s (outer) b_s
+    """
+    n = x.shape[0]
+    cum = jnp.cumsum(dt * a[None, :], axis=0)  # [L, H], falling
+    seg = cum[:, None, :] - cum[None, :, :]  # [t, s, H]
+    lower = jnp.tril(jnp.ones((n, n), bool))[:, :, None]
+    decay = jnp.where(lower, jnp.exp(jnp.where(lower, seg, 0.0)), 0.0)
+    cb = jnp.einsum("thn,shn->tsh", c, b, precision=_HI)
+    xdt = x * dt[:, :, None]
+    y = jnp.einsum("tsh,shp->thp", cb * decay, xdt, precision=_HI)
+    y = y + jnp.exp(cum)[:, :, None] * jnp.einsum(
+        "hpn,thn->thp", h0, c, precision=_HI)
+    tail = jnp.exp(cum[-1][None, :] - cum)  # [s, H]
+    h1 = jnp.exp(cum[-1])[:, None, None] * h0 + jnp.einsum(
+        "shp,shn->hpn", xdt * tail[:, :, None], b, precision=_HI)
+    return y, h1
+
+
+def _ssm_scan_infer(op, block):
+    z = _var(block, op, "Z")
+    out = _var(block, op, "Out", outputs=True)
+    out.shape, out.dtype = z.shape, z.dtype
+    _state_out_like_state(op, block)
+
+
+@register("ssm_scan", no_grad=True, infer_shape=_ssm_scan_infer)
+def _ssm_scan(ctx, ins, attrs):
+    """The Mamba-2 state-space operator between its convolution and its
+    output projection. X is the convolved [x | B | C] ([rows, H * P + 2 * G
+    * N]: `heads` H of `d_head` P, `groups` G of B and C, `d_state` N), Dt
+    [rows, H] the raw step sizes, Z [rows, H * P] the gate. For head j (of
+    group g) at position t, in float32 whatever the rows' dtype:
+
+        dt_t = softplus(Dt_t + DtBias);   A = -exp(ALog)
+        H_t = exp(dt_t A_j) H_{t-1} + dt_t x_t^j (outer) B_t^g   [P, N]
+        y_t^j = H_t C_t^g + D_j x_t^j
+        Out_t = rms(y_t * silu(Z_t); NormScale, eps) over all H * P
+
+    rounded once, to Z's dtype. The state is float32, a row of the
+    persistable pool State [state_rows, N, H * P] a sequence (the heads'
+    entries on the lanes, so that a step's row update is whole-vreg work; a
+    sequence's state its own tiles, so that a row is copied or updated
+    without touching its neighbours'), row 0 scratch, indexed by Rows as
+    short_conv's.
+
+    mode "chunk": the rows are consecutive positions of ONE sequence from
+    Start, at most `chunk` of them a quadratic form (ssd_chunk) and the
+    state carried between; the state before them is State[Rows[0]], zeros
+    when Start is 0 (a cold admission writes no zeros to the pool); rows
+    with Live == 0 (padding, behind the live ones) take dt = 0 and leave
+    the state alone. With no State the rows are whole sequences of seq_len
+    positions each, every one from zeros. mode "step": one position of a
+    sequence a row with its own state row Rows[r]; rows whose Rows[r] is 0
+    (idle slots) are not computed and touch no state but the scratch row.
+    On a TPU the step is pallas_kernels.ssm_state_step: the pool updated in
+    place, live rows only."""
+    (x,) = ins["X"]
+    (dt_raw,) = ins["Dt"]
+    (z,) = ins["Z"]
+    state = ins.get("State", [None])[0]
+    n_head, p = int(attrs["heads"]), int(attrs["d_head"])
+    n, groups = int(attrs["d_state"]), int(attrs.get("groups", 1))
+    chunk = int(attrs.get("chunk", 256))
+    rows, hp = x.shape[0], n_head * p
+    a = -jnp.exp(ins["ALog"][0].astype(_F32))
+    d_skip = ins["D"][0].astype(_F32)
+    dt = jax.nn.softplus(
+        dt_raw.astype(_F32) + ins["DtBias"][0].astype(_F32)[None, :])
+    x32 = x.astype(_F32)
+    xs = x32[:, :hp].reshape(rows, n_head, p)
+    per_head = lambda m: jnp.repeat(
+        m.reshape(rows, groups, n), n_head // groups, axis=1)
+    b, c = x32[:, hp:hp + groups * n], x32[:, hp + groups * n:]
+
+    def finish(y):
+        """y [rows, H, P] without the skip -> Out."""
+        y = (y + d_skip[None, :, None] * xs).reshape(rows, hp)
+        g = y * jax.nn.silu(z.astype(_F32))
+        g = g * jax.lax.rsqrt(
+            jnp.mean(g * g, -1, keepdims=True)
+            + float(attrs.get("epsilon", 1e-5)))
+        return (g * ins["NormScale"][0].astype(_F32)).astype(z.dtype)
+
+    to_pool = lambda h: jnp.transpose(h, (2, 0, 1)).reshape(n, hp)
+    from_pool = lambda r: jnp.transpose(r.reshape(n, n_head, p), (1, 2, 0))
+
+    if attrs.get("mode", "chunk") == "step":
+        idx = ins["Rows"][0].reshape(-1).astype(jnp.int32)
+        decay = jnp.exp(dt * a[None, :])  # [rows, H]
+        xdt = (xs * dt[:, :, None]).reshape(rows, hp)
+        if _pk.ssm_step_path_taken(hp, n, groups):
+            # no pallas_kernel= scope: its device time reads op:ssm_scan
+            y, new_state = _pk.ssm_state_step(
+                state, idx, xdt, jnp.repeat(decay, p, axis=1), b, c)
+        else:
+            # [rows, n, hp]: a group's B and C under each of its heads' lanes
+            lanes = lambda m: jnp.repeat(
+                jnp.swapaxes(m.reshape(rows, groups, n), 1, 2),
+                hp // groups, axis=2)
+            prev = jnp.take(state, idx, axis=0)
+            new = (prev * jnp.repeat(decay, p, axis=1)[:, None, :]
+                   + lanes(b) * xdt[:, None, :])
+            y = jnp.sum(new * lanes(c), axis=1)
+            # idle rows write nothing of theirs: the scratch row keeps what
+            # it had (a zero-filled row is rewritten as it was read)
+            keep = (idx != 0)[:, None, None]
+            new_state = state.at[idx].set(jnp.where(keep, new, prev))
+        return {"Out": [finish(y.reshape(rows, n_head, p))],
+                "StateOut": [new_state]}
+
+    def scan(xs_, dt_, b_, c_, h0):
+        """One sequence, `chunk` positions a quadratic form."""
+        ys, h = [], h0
+        for s in range(0, xs_.shape[0], chunk):
+            part = slice(s, s + chunk)
+            y, h = ssd_chunk(xs_[part], dt_[part], a, b_[part], c_[part], h)
+            ys.append(y)
+        return jnp.concatenate(ys, 0), h
+
+    b, c = per_head(b), per_head(c)
+    zeros = jnp.zeros((n_head, p, n), _F32)
+    if state is None:
+        t = int(attrs.get("seq_len", 0)) or rows
+        seqs = lambda m: m.reshape((rows // t, t) + m.shape[1:])
+        y = jax.vmap(lambda *m: scan(*m, zeros)[0])(
+            seqs(xs), seqs(dt), seqs(b), seqs(c))
+        return {"Out": [finish(y.reshape(rows, n_head, p))]}
+    row = ins["Rows"][0].reshape(-1)[0].astype(jnp.int32)
+    start = ins["Start"][0].reshape(-1)[0]
+    live = ins["Live"][0].reshape(-1, 1) != 0
+    h0 = jnp.where(start > 0, from_pool(state[row]), zeros)
+    y, h1 = scan(xs, jnp.where(live, dt, 0.0), b, c, h0)
+    return {"Out": [finish(y)],
+            "StateOut": [state.at[row].set(to_pool(h1))]}
 
 
 # ---------------------------------------------------------------------------

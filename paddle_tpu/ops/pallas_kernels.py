@@ -54,6 +54,8 @@ __all__ = [
     "hyper_maps_path_taken",
     "grouped_expert_ffn",
     "grouped_ffn_path_taken",
+    "ssm_state_step",
+    "ssm_step_path_taken",
     "KERNELS_REVISION",
     "fused_layer_norm",
     "fused_layer_norm_grad",
@@ -2111,6 +2113,139 @@ def hyper_maps(raw, n, iters, eps, clamp, interpret=None):
         interpret=interpret,
     )(raw_t)
     return out[:, :rows].T
+
+
+# ---------------------------------------------------------------------------
+# state-space decode step (ssm_scan, mode "step"): the live rows of the
+# float32 state pool updated in place
+# ---------------------------------------------------------------------------
+
+# The lanes of a state row one program holds: 16 heads of 64 at the widths
+# this was written for, [128, 1024] float32 = 512 KB a block, two blocks in
+# and two out in VMEM. A row is [d_state, heads * d_head], the heads' entries
+# on the lanes, so the decay and the input are lane-dense rows broadcast down
+# the sublanes and the output's sum over d_state is adds of whole vregs.
+_SSM_BLOCK_LANES = 1024
+_SSM_VMEM_BYTES = 32 * 1024 * 1024
+
+
+def ssm_step_path_taken(hp, n, groups):
+    """EXACT mirror of ssm_scan's kernel-vs-XLA decision for its decode
+    step: the kernel on a TPU when a state row is whole float32 tiles
+    (heads * d_head a multiple of 128 lanes, d_state of 8 sublanes) and B
+    and C are one group's. XLA's gather, update and scatter move every row
+    of the step three times over; the kernel moves the live rows once each
+    way."""
+    return (jax.default_backend() == "tpu" and int(hp) % _LANES == 0
+            and int(n) % 8 == 0 and int(groups) == 1)
+
+
+def _ssm_step_kernel(rows_ref, order_ref, live_ref, xdt_ref, decay_ref, b_ref,
+                     c_ref, h_ref, y_ref, o_ref):
+    """One block of lanes of one slot's row: h' = decay * h + b (outer) xdt,
+    y = sum over d_state of c * h'. The grid walks the slots live ones
+    first; programs past them keep the last live block's indices, so the
+    pipeline copies nothing for them and their body leaves the pool's block
+    alone: it still holds that block's result when it is written back,
+    once."""
+    i = pl.program_id(0)
+    n_live = live_ref[0]
+    n = h_ref.shape[1]
+    # a [1, n] row as an [n, 1] column: its diagonal summed over the lanes
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
+    column = lambda row: jnp.sum(
+        jnp.where(eye, jnp.broadcast_to(row, (n, n)), 0.0), axis=1,
+        keepdims=True)
+
+    @pl.when(i < n_live)
+    def _update():
+        new = h_ref[0] * decay_ref[0] + column(b_ref[0]) * xdt_ref[0]
+        o_ref[0] = new
+        y_ref[0] = jnp.sum(new * column(c_ref[0]), axis=0, keepdims=True)
+
+    @pl.when(i >= n_live)
+    def _idle():
+        y_ref[...] = jnp.zeros_like(y_ref)
+
+    @pl.when(jnp.logical_and(n_live == 0, i == 0))
+    def _scratch():  # no live row: the scratch row is written as it was read
+        o_ref[...] = h_ref[...]
+
+
+def ssm_state_step(state, rows, xdt, decay, b, c, *, interpret=None):
+    """One decode step of the state-space recurrence over a pool of state
+    rows, in place. state [state_rows, n, hp] float32, a row the state of
+    one sequence (n = d_state, hp = heads * d_head); rows [slots] int32 each
+    slot's state row, 0 for a slot that is not live; xdt [slots, hp] = dt *
+    x, decay [slots, hp] = exp(dt * A) a head, repeated over its d_head, b
+    and c [slots, n], all float32. For every live slot s
+
+        state[rows[s]] <- decay[s] * state[rows[s]] + b[s] (outer) xdt[s]
+        y[s] = sum_n c[s, n] * state[rows[s]][n]          (the new state)
+
+    and y[s] = 0 for the others. Returns (y [slots, hp], the pool): the
+    pool is aliased to the input, so where the caller donates it (the
+    serving variants do) nothing is copied, and only the live rows are read
+    and written: the grid takes the live slots first (`order`, a scalar
+    table like the rows') and the programs behind them stay on the last
+    live block."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    slots, hp = xdt.shape
+    n = b.shape[1]
+    lanes = min(_SSM_BLOCK_LANES, hp)
+    if hp % lanes:
+        lanes = _LANES
+    n_blocks = hp // lanes
+    rows = rows.reshape(-1).astype(jnp.int32)
+    on = rows != 0
+    order = jnp.argsort(jnp.logical_not(on), stable=True).astype(jnp.int32)
+    n_live = jnp.sum(on.astype(jnp.int32))
+    compact = jnp.take(rows, order)
+    # behind the live rows: the last live row again (the scratch row, with
+    # none), so that its block is neither fetched nor written a second time
+    compact = jnp.where(jnp.arange(slots) < n_live, compact,
+                        compact[jnp.maximum(n_live - 1, 0)])
+    f32 = lambda m: m.astype(jnp.float32)[:, None, :]
+
+    def pooled(i, j, rows_r, order_r, live_r):
+        return rows_r[i], 0, jnp.where(i >= live_r[0], n_blocks - 1, j)
+
+    mine = lambda i, j, rows_r, order_r, live_r: (order_r[i], 0, j)
+    whole = lambda i, j, rows_r, order_r, live_r: (order_r[i], 0, 0)
+    _note_dispatch("ssm_step")
+    y, pool = pl.pallas_call(
+        _ssm_step_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(slots, n_blocks),
+            in_specs=[
+                pl.BlockSpec((1, 1, lanes), mine),  # xdt
+                pl.BlockSpec((1, 1, lanes), mine),  # decay
+                pl.BlockSpec((1, 1, n), whole),  # b
+                pl.BlockSpec((1, 1, n), whole),  # c
+                pl.BlockSpec((1, n, lanes), pooled),  # the pool
+            ],
+            out_specs=[
+                pl.BlockSpec((1, 1, lanes), mine),
+                pl.BlockSpec((1, n, lanes), pooled),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((slots, 1, hp), jnp.float32),
+            jax.ShapeDtypeStruct(state.shape, jnp.float32),
+        ],
+        # operands: rows, order, n_live, xdt, decay, b, c, pool -> output 1
+        input_output_aliases={7: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_SSM_VMEM_BYTES,
+        ),
+        interpret=interpret,
+    )(compact, order, n_live.reshape(1), f32(xdt), f32(decay), f32(b), f32(c),
+      state)
+    return y[:, 0, :], pool
 
 
 # ---------------------------------------------------------------------------

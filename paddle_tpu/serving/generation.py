@@ -345,7 +345,8 @@ def _take_ids(serve):
 def cache_specs(model):
     """A model's cache tensors as per-layer specs (ROADMAP R-A4):
     {name, kind, width, dtype}, kind "paged" (rows indexed through block
-    tables) or "state" (one fixed row a slot, row 0 scratch). A model that
+    tables) or "state" (one fixed row a slot, row 0 scratch; `shape`, where
+    a row is not flat). A model that
     only names its K/V pools (GPTDecoder) gets d_model-wide paged rows in
     its kv_dtype and, in int8 mode, its scale pools (width 0: one float a
     row)."""
@@ -407,7 +408,10 @@ class GenerationEngine:
     def __init__(self, model, name="generation", scope=None, place=None,
                  max_slots=4, page_size=8, pool_pages=None, max_context=None,
                  prefill_buckets=None, prefill_chunk=None, prefix_cache=True,
-                 cache_dir=None):
+                 cache_dir=None, snapshot_bytes=None):
+        """`snapshot_bytes`: the device memory the prefix cache's snapshots
+        of the per-slot sequence state may take together (a model with conv
+        or recurrent layers; default four a slot)."""
         import jax.numpy as jnp
 
         self.model = model
@@ -459,22 +463,30 @@ class GenerationEngine:
         # (model.kv_scale_names) — ~1/4 the f32 bytes per cached token
         self.kv_dtype = getattr(model, "kv_dtype", "float32")
         # per-layer cache specs: paged rows of each layer's own width, and
-        # for a layer whose state is fixed (a short convolution) one row a
-        # slot in a [max_slots + 1, width] pool, row 0 scratch: a decode
-        # step's idle and mid-prefill rows write there, as they write the
-        # scratch page
+        # for a layer whose state is fixed (a short convolution's last
+        # inputs, a recurrence's carry) one row a slot in a [max_slots + 1,
+        # width] pool, row 0 scratch: a decode step's idle and mid-prefill
+        # rows write there, as they write the scratch page (a recurrence's
+        # step skips them: its row is megabytes)
         specs = cache_specs(model)
         self._state = {}
         self._state_names = []  # the fixed per-slot state pools
         self.kv_state_bytes = self.seq_state_bytes = 0
         self.state_row_bytes = 0  # one slot's state: what a snapshot holds
+        # its recurrent part (form "recurrent": a state-space layer's
+        # carry), which a decode step reads and writes for every live row
+        self.recurrent_row_bytes = 0
         for spec in specs:
             dtype = jnp.dtype(spec["dtype"])
             if spec["kind"] == "state":
-                arr = jnp.zeros((self.max_slots + 1, spec["width"]), dtype)
+                arr = jnp.zeros(
+                    (self.max_slots + 1,)
+                    + tuple(spec.get("shape") or (spec["width"],)), dtype)
                 self._state_names.append(spec["name"])
                 self.seq_state_bytes += arr.size * dtype.itemsize
                 self.state_row_bytes += spec["width"] * dtype.itemsize
+                if spec.get("form") == "recurrent":
+                    self.recurrent_row_bytes += spec["width"] * dtype.itemsize
             else:
                 if spec["width"]:
                     arr = jnp.zeros((pool_rows, spec["width"]), dtype)
@@ -498,7 +510,8 @@ class GenerationEngine:
         self.pool.row_bytes = self.kv_state_bytes // pool_rows
         self.pool.state_bytes = self.seq_state_bytes
         self.prefix_cache = (
-            PrefixCache(self.pool, state_bytes=self.state_row_bytes)
+            PrefixCache(self.pool, state_bytes=self.state_row_bytes,
+                        snapshot_bytes=snapshot_bytes)
             if prefix_cache else None)
         self._build_kw = (
             {"state_rows": self.max_slots + 1} if self._state_names else {})
@@ -670,12 +683,37 @@ class GenerationEngine:
         self._m_state_bytes = reg.gauge(
             p + "/gen_state_bytes",
             "resident bytes of the fixed per-slot sequence state (conv "
-            "layers' rows), scratch row included",
+            "and state-space layers' rows), scratch row included",
         )
         self._m_state_bytes.set(float(self.seq_state_bytes))
         self._m_snap_bytes = reg.gauge(
             p + "/gen_state_snapshot_bytes",
-            "bytes of the sequence-state snapshots the prefix cache holds",
+            "bytes of the sequence-state snapshots the prefix cache holds "
+            "(what=held) and may hold (what=reserved)",
+        )
+        self._m_snap_copy_bytes = reg.counter(
+            p + "/gen_state_snapshot_copy_bytes",
+            "bytes of sequence state copied on the device for the prefix "
+            "cache, by event: taken (a slot's rows into a snapshot), "
+            "restored (a snapshot into a slot's rows)",
+        )
+        self._m_ssm_rows = reg.histogram(
+            p + "/gen_ssm_rows",
+            "live rows whose recurrent state one decode step updates",
+            buckets=EXPERT_BUCKETS,
+        )
+        self._m_ssm_state_bytes = reg.histogram(
+            p + "/gen_ssm_state_bytes",
+            "bytes of recurrent state one decode step's live rows have to "
+            "read and write, summed over the state-space layers (rows x 2 "
+            "x a slot's recurrent bytes)",
+            buckets=tuple(b * 2 ** 20 for b in CTX_TOKEN_BUCKETS),
+        )
+        self._m_chunk_ssm_tokens = reg.histogram(
+            p + "/gen_chunk_ssm_tokens",
+            "positions one prefill chunk scans through the state-space "
+            "layers (its live rows)",
+            buckets=EXPERT_BUCKETS,
         )
         self._m_restore_ms = reg.histogram(
             p + "/gen_state_restore_ms",
@@ -896,9 +934,9 @@ class GenerationEngine:
         """(snapshot, restore) over the state pools, compiled once (at
         warm-up, so that a prefix hit in the hot loop compiles nothing), or
         None for a model whose every layer's state is pages. snapshot(pools,
-        row) -> a tuple of the row of each pool (a copy: 82 KB for ten conv
-        layers of 2 x 2048 bf16); restore(pools, snapshot, row) -> the pools
-        with that row set, the pools donated."""
+        row) -> a tuple of the row of each pool (a copy, state_row_bytes of
+        it: whatever the model's layers keep a sequence); restore(pools,
+        snapshot, row) -> the pools with that row set, the pools donated."""
         if not self._state_names or self._state_fns is not None:
             return self._state_fns
         import jax
@@ -916,6 +954,13 @@ class GenerationEngine:
         self._state_fns = (snapshot, restore)
         return self._state_fns
 
+    def state_rows(self, slot):
+        """{pool name: a copy of the slot's row} of the fixed per-slot
+        sequence state, as the pools hold it now (a parity surface, like
+        last_logits: tests and the benchmark's probe hold a recurrent state
+        to the reference's)."""
+        return dict(zip(self._state_names, self._snapshot_state(slot)))
+
     def _snapshot_state(self, slot):
         snapshot, _ = self._state_programs()
         pools = tuple(self._state[n] for n in self._state_names)
@@ -929,6 +974,7 @@ class GenerationEngine:
             pools = tuple(self._state[n] for n in self._state_names)
             new = restore(pools, snap, np.int32(slot + 1))
             self._state.update(zip(self._state_names, new))
+        self._m_snap_copy_bytes.inc(self.state_row_bytes, event="restored")
 
     # ---- hot swap ---------------------------------------------------------
     def set_params(self, updates, version=None, stamp=None):
@@ -1117,15 +1163,20 @@ class GenerationEngine:
         self._m_chunk_ctx_tokens.observe(start + n_real)
         self._m_chunk_ctx_pairs.observe(
             n_real * start + n_real * (n_real + 1) // 2)
+        if self.recurrent_row_bytes:
+            self._m_chunk_ssm_tokens.observe(n_real)
         run.pf_pos = start + n_real
         if extra:
             run.chunk_picks.append((start, n_real, extra[0]))
-        ps = self.page_size
-        if (self._state_names and self.prefix_cache is not None
-                and run.pf_pos % ps == 0 and run.pf_pos <= (L - 1) // ps * ps):
-            # the chunk ended on a page boundary a later lookup can reach:
-            # keep the state there, for the prefix cache to hold
+        if run.pf_pos == run.cut:
+            # the chunk ended on the boundary other prompts were seen to
+            # share (admit set it): keep the state there, for the prefix
+            # cache to hold. Only there: a snapshot is a copy of the slot's
+            # whole state (gen_state_snapshot_copy_bytes counts it), and one
+            # at every page-aligned chunk end is mostly of boundaries inside
+            # a prompt's own tail, which no other prompt reaches
             run.snaps[run.pf_pos] = self._snapshot_state(run.slot)
+            self._m_snap_copy_bytes.inc(self.state_row_bytes, event="taken")
         if run.pf_pos < L:
             return False
         self._m_prefills.inc()
@@ -1336,6 +1387,10 @@ class GenerationEngine:
         self._m_step_ms.observe(step_ms)
         self._m_ctx_tokens.observe(step.ctx_tokens)
         self._m_walked_tokens.observe(step.walked_tokens)
+        if self.recurrent_row_bytes:
+            self._m_ssm_rows.observe(len(runs))
+            self._m_ssm_state_bytes.observe(
+                len(runs) * 2 * self.recurrent_row_bytes)
         self._m_steps.inc()
         if picks is not None:
             self._note_picks(picks, step)
@@ -1415,7 +1470,9 @@ class GenerationEngine:
             st = self.prefix_cache.stats()
             self._m_prefix_hit.set(st["hit_rate"])
             if self._state_names:
-                self._m_snap_bytes.set(float(st["state_bytes"]))
+                self._m_snap_bytes.set(float(st["state_bytes"]), what="held")
+                self._m_snap_bytes.set(
+                    float(st["state_bytes_reserved"]), what="reserved")
                 for event, key in (("taken", "states_taken"),
                                    ("restored", "states_hit"),
                                    ("evicted", "states_evicted")):
@@ -1473,18 +1530,21 @@ class GenerationEngine:
                 k: v
                 for k, v in _pk.KERNEL_DISPATCHES.items()
                 if k in ("paged_flash", "paged_flash_int8", "paged_latent",
-                         "hyper_maps", "moe_grouped",
+                         "hyper_maps", "moe_grouped", "ssm_step",
                          "gemm_dbuf", "gemm_epilogue", "gemm_int8",
                          "gemm_fp8")
             },
             "kv": {
                 "dtype": self.kv_dtype,
                 "resident_bytes": self.kv_state_bytes,
-                # the fixed per-slot state beside the pages (conv layers'
-                # rows, scratch row included), and one slot's share of it:
-                # what a prefix-cache snapshot holds
+                # the fixed per-slot state beside the pages (conv and
+                # state-space layers' rows, scratch row included), one
+                # slot's share of it (what a prefix-cache snapshot holds),
+                # and the recurrent part of that (what a decode step reads
+                # and writes for every live row)
                 "state_bytes": self.seq_state_bytes,
                 "state_row_bytes": self.state_row_bytes,
+                "recurrent_row_bytes": self.recurrent_row_bytes,
             },
         }
         if self.prefix_cache is not None:

@@ -54,9 +54,11 @@ opaque handle the engine made: device arrays, ``state_bytes`` each),
 ``lookup_state`` returns pages only down to the deepest boundary that has
 one, with it, and reports how deep the prompt *matched* — where a later
 prompt's prefill should leave a snapshot so that the next one can hit.
-Snapshots are capped at ``capacity_states`` (LRU among the nodes that have
-one; the node and its page stay) and go with their node on eviction;
-``stats()`` counts them and their bytes beside the pages.
+Snapshots are budgeted in bytes, whatever a state is wide (a few KB of a
+convolution's rows, tens of MB of a recurrence's): they are held under the
+reserve ``snapshot_bytes`` (the coldest go first, LRU among the nodes that
+have one; the node and its page stay) and go with their node on eviction;
+``stats()`` reports the bytes held and reserved beside the pages.
 
 Thread-safety: the GenerationScheduler's worker thread is the only caller;
 a lock still guards acquire/release so `stats()` from other threads is
@@ -248,15 +250,17 @@ class PrefixCache:
     """Prompt-token trie over immutable full KV pages (module docstring)."""
 
     def __init__(self, pool, capacity_pages=None, state_bytes=0,
-                 capacity_states=None):
+                 snapshot_bytes=None):
         self.pool = pool
         # state_bytes > 0: the model keeps a fixed per-sequence state beside
-        # its pages, and only a boundary that carries a snapshot of it can
-        # be hit. The reserve: capacity_states snapshots of state_bytes.
+        # its pages, state_bytes a sequence, and only a boundary that
+        # carries a snapshot of it can be hit. The reserve snapshot_bytes is
+        # the device memory the snapshots may take together (default: four a
+        # slot), so it holds as many as fit, not a count.
         self.state_bytes = int(state_bytes)
-        if capacity_states is None:
-            capacity_states = 4 * pool.max_slots if self.state_bytes else 0
-        self.capacity_states = int(capacity_states)
+        if snapshot_bytes is None:
+            snapshot_bytes = 4 * pool.max_slots * self.state_bytes
+        self.snapshot_bytes = int(snapshot_bytes)
         self._n_states = 0  # nodes that carry a snapshot now
         self.states_taken = 0
         self.states_hit = 0
@@ -381,15 +385,15 @@ class PrefixCache:
                     self._n_states += 1
                 added += 1
                 parent, parent_key = node, key
-            if self._n_states > self.capacity_states:
+            if self._n_states * self.state_bytes > self.snapshot_bytes:
                 self._cap_states_locked()
         return added
 
     def _cap_states_locked(self):
-        """Drop the coldest snapshots beyond capacity_states; their nodes
-        and pages stay (a prompt can still match through them)."""
+        """Drop the coldest snapshots until those left fit the reserve;
+        their nodes and pages stay (a prompt can still match through them)."""
         kept = [n for n in self._nodes.values() if n.state is not None]
-        over = len(kept) - self.capacity_states
+        over = len(kept) - self.snapshot_bytes // max(self.state_bytes, 1)
         for node in sorted(kept, key=lambda n: n.stamp)[:max(over, 0)]:
             node.state = None
             self.states_evicted += 1
@@ -451,7 +455,7 @@ class PrefixCache:
             return {
                 "cached_states": n_states,
                 "state_bytes": n_states * self.state_bytes,
-                "state_bytes_reserved": self.capacity_states * self.state_bytes,
+                "state_bytes_reserved": self.snapshot_bytes,
                 "states_taken": self.states_taken,
                 "states_hit": self.states_hit,
                 "states_evicted": self.states_evicted,

@@ -113,8 +113,9 @@ def test_prefix_hit_restores_the_state_and_equals_a_cold_admission():
     for p in (first, second):  # the second leaves the snapshot at 12
         warm.finish(warm.start(GenRequest(p, max_new_tokens=2)))
     st = warm.prefix_cache.stats()
-    assert st["states_taken"] >= 2 and st["state_bytes"] == (
-        st["cached_states"] * warm.state_row_bytes)
+    # the one snapshot: at the boundary the second prompt shared
+    assert st["states_taken"] == st["cached_states"] == 1
+    assert st["state_bytes"] == warm.state_row_bytes
     run = warm.start(GenRequest(third, max_new_tokens=4))
     assert run.picks[0][0] == 12  # prefill began at the shared boundary
     assert warm.prefix_cache.stats()["states_hit"] == st["states_hit"] + 1
@@ -139,10 +140,11 @@ def test_lookup_returns_only_a_boundary_that_has_a_state():
     pc = eng.prefix_cache
     pages, state, matched = pc.lookup_state(shared + _prompt(10, 9))
     pc.pool.unpin_pages(pages)
-    # chunks of 8: snapshots at 8 and 16, so 2 of the 3 shared pages are usable
-    assert (len(pages), matched) == (2, 3) and state is not None
+    # a cold prompt leaves no snapshot (nobody was seen to share any of it):
+    # the three shared pages match and none is usable
+    assert (len(pages), matched) == (0, 3) and state is None
     run = eng.admit(GenRequest(shared + _prompt(10, 9), max_new_tokens=2))
-    assert (run.pf_pos, run.cut) == (8, 12)
+    assert (run.pf_pos, run.cut) == (0, 12)
     while not eng.prefill_step(run):
         pass
     eng.finish(run)
@@ -154,17 +156,21 @@ def test_lookup_returns_only_a_boundary_that_has_a_state():
 def test_state_snapshots_are_capped_evicted_and_counted():
     model, eng = _engine()
     pc = eng.prefix_cache
-    pc.capacity_states = 2
-    for seed in range(4):
-        eng.finish(eng.start(GenRequest(_prompt(17, 20 + seed), max_new_tokens=1)))
+    # room for two and a half snapshots: two fit
+    pc.snapshot_bytes = 5 * eng.state_row_bytes // 2
+    for seed in range(4):  # each head twice: the second leaves its snapshot
+        for tail in (1, 2):
+            eng.finish(eng.start(GenRequest(
+                _prompt(8, 20 + seed) + _prompt(9, 40 + seed + tail),
+                max_new_tokens=1)))
     st = pc.stats()
-    assert st["cached_states"] == 2 and st["states_taken"] == 8
-    assert st["states_evicted"] == 6
+    assert st["cached_states"] == 2 and st["states_taken"] == 4
+    assert st["states_evicted"] == 2
     assert st["state_bytes"] == 2 * eng.state_row_bytes
-    assert st["state_bytes_reserved"] == 2 * eng.state_row_bytes
+    assert st["state_bytes_reserved"] == 5 * eng.state_row_bytes // 2
     pc.evict_for(10 ** 6)
     st = pc.stats()
-    assert st["cached_states"] == 0 and st["states_evicted"] == 8
+    assert st["cached_states"] == 0 and st["states_evicted"] == 4
     assert eng.stats()["kv"]["state_bytes"] == eng.seq_state_bytes > 0
     assert eng.pool.stats()["state_bytes"] == eng.seq_state_bytes
 
@@ -628,3 +634,244 @@ def test_xing4_bf16_stays_in_its_band_of_float32():
         picks=_picks(run, len(prompt)))
     assert float(np.max(gaps)) < 5e-2
     assert np.abs(got - np.asarray(want)[-1]).max() < BF16_BAND
+
+
+# ---------------------------------------------------------------------------
+# the granitemoehybrid architecture at a tiny size: Mamba-2 state-space
+# layers with an attention layer among them (no positions, no q/k norm, its
+# own score scale), a dense feed-forward in every layer, four multipliers
+# ---------------------------------------------------------------------------
+
+from paddle_tpu.models import granite4h_reference as gref  # noqa: E402
+from paddle_tpu.models.block_decoder import granitemoehybrid  # noqa: E402
+
+GRANITE = dict(
+    model_type="granitemoehybrid", vocab_size=97, hidden_size=64,
+    intermediate_size=96, shared_intermediate_size=96, num_hidden_layers=4,
+    layer_types=["mamba", "mamba", "attention", "mamba"],
+    num_attention_heads=4, num_key_value_heads=2, mamba_n_heads=8,
+    mamba_d_head=16, mamba_d_state=16, mamba_n_groups=1, mamba_d_conv=4,
+    mamba_expand=2, mamba_chunk_size=8, mamba_conv_bias=True,
+    mamba_proj_bias=False, attention_bias=False, attention_multiplier=0.0625,
+    embedding_multiplier=12, residual_multiplier=0.22, logits_scaling=8,
+    rms_norm_eps=1e-5, rope_theta=10000, position_embedding_type="nope",
+    num_local_experts=0, num_experts_per_tok=0, tie_word_embeddings=True,
+    max_position_embeddings=128)
+# float32 both sides, on logits of ~0.2: the chunked form and the
+# recurrence sum in another order
+GRANITE_TOL = 2e-6
+
+
+def _granite_engine(dtype="float32", seed=3, **kw):
+    model = granitemoehybrid(GRANITE, max_context=128, dtype=dtype)
+    kw = dict(dict(max_slots=3, page_size=4, prefill_chunk=8), **kw)
+    _granite_engine.made += 1  # a name of its own: the registry counts by name
+    return model, GenerationEngine(
+        model, name="tgranite%d" % _granite_engine.made, scope=Scope(seed=seed),
+        **kw)
+
+
+_granite_engine.made = 0
+
+
+@pytest.fixture(scope="module")
+def granite():
+    return _granite_engine()
+
+
+def _granite_reference(model, eng, tokens):
+    return np.asarray(gref.forward(
+        _weights(model, eng), tokens, gref.spec_of(model)))
+
+
+def test_granitemoehybrid_is_built_from_the_source_s_keys(granite):
+    model, eng = granite
+    assert model.blocks == [("mamba", "dense"), ("mamba", "dense"),
+                            ("attention", "dense"), ("mamba", "dense")]
+    assert model.ssm == {"heads": 8, "d_head": 16, "d_state": 16, "groups": 1,
+                         "conv": 4, "chunk": 8}
+    assert (model.position, model.qk_norm, model.attn_scale) == ("none", False, 0.0625)
+    assert (model.embed_scale, model.residual_scale, model.logit_scale) == (12.0, 0.22, 8.0)
+    assert model.d_ffn == 96 and model.tied_head and model.ssm_widths() == (128, 160)
+    # a mamba layer: two state pools of different dtype and width; the
+    # attention layer its K and V pages
+    specs = model.cache_specs()
+    assert [(s["kind"], s["layer"]) for s in specs] == [
+        ("state", 0), ("state", 0), ("state", 1), ("state", 1),
+        ("paged", 2), ("paged", 2), ("state", 3), ("state", 3)]
+    conv, rec = specs[0], specs[1]
+    assert (conv["width"], conv["dtype"], conv.get("form")) == (3 * 160, "float32", None)
+    assert (rec["width"], rec["shape"], rec["dtype"], rec["form"]) == (
+        8 * 16 * 16, [16, 128], "float32", "recurrent")
+    bf16 = granitemoehybrid(GRANITE, max_context=128).cache_specs()
+    assert (bf16[0]["dtype"], bf16[1]["dtype"]) == ("bfloat16", "float32")
+    st = eng.stats()["kv"]
+    assert st["recurrent_row_bytes"] == 3 * 8 * 16 * 16 * 4
+    assert st["state_row_bytes"] == 3 * (8 * 16 * 16 + 3 * 160) * 4
+    assert st["state_bytes"] == 4 * st["state_row_bytes"]  # 3 slots + scratch
+    assert set(model.param_names()) == {
+        n for n in eng.scope.vars
+        if n.startswith("granite4h_") and "kv_" not in n and "state" not in n}
+    assert not any("q_norm" in n for n in model.param_names())
+    # Mamba-2's own initialisation: A in [1, 16], dt in [1e-3, 1e-1], D 1
+    w = _weights(model, eng)
+    a = np.exp(np.asarray(w["granite4h_l0_ssm_a_log"]))
+    dt = np.logaddexp(0, np.asarray(w["granite4h_l0_ssm_dt_bias"]))
+    assert a.min() >= 1 and a.max() <= 16 and a.std() > 1
+    assert dt.min() >= 0.999e-3 and dt.max() <= 1.001e-1
+    assert (np.asarray(w["granite4h_l0_ssm_d"]) == 1).all()
+    for key, value in (("num_local_experts", 4), ("attention_bias", True),
+                       ("mamba_proj_bias", True), ("mamba_conv_bias", False),
+                       ("tie_word_embeddings", False), ("mamba_n_heads", 4)):
+        with pytest.raises(ValueError):
+            granitemoehybrid(dict(GRANITE, **{key: value}))
+
+
+def test_granite_forward_program_matches_the_reference(granite):
+    model, eng = granite
+    tokens = np.array([_prompt(21, 1), _prompt(21, 2)], np.int64)
+    main, _, feeds, fetches = model.build_forward(2, 21)
+    with scope_guard(eng.scope):
+        (logits,) = Executor().run(
+            main, feed={"fwd_tokens": tokens[:, :, None]}, fetch_list=fetches)
+    for b in range(2):
+        want = _granite_reference(model, eng, tokens[b])
+        assert np.abs(want).max() > 0.05
+        np.testing.assert_allclose(np.asarray(logits)[b], want, atol=GRANITE_TOL)
+
+
+def test_granite_chunked_prefill_then_decode_matches_the_full_forward_pass():
+    """29 prompt tokens in chunks of 8, 8, 8 and 5, then six decode steps
+    through the conv and recurrent state rows and the attention layer's
+    pages: the first-token logits and every step's against the reference's
+    one pass over the sequence, position by position."""
+    model, eng = _granite_engine()
+    prompt = _prompt(29)
+    run = eng.start(GenRequest(prompt, max_new_tokens=8))
+    got = [np.array(eng.last_prefill_logits)]
+    for _ in range(6):
+        eng.decode_step([run])
+        got.append(np.array(eng.last_logits[run.slot]))
+    tokens = prompt + run.tokens[:6]
+    state = eng.state_rows(run.slot)
+    eng.finish(run)
+    want = _granite_reference(model, eng, tokens)
+    for i, g in enumerate(got):
+        np.testing.assert_allclose(g, want[len(prompt) - 1 + i], atol=GRANITE_TOL)
+    # the slot's recurrent state of the first layer is the reference's
+    _, last = gref.forward(_weights(model, eng), tokens, gref.spec_of(model),
+                           states=True)
+    assert last.shape == (3, 8, 16, 16)  # the mamba layers', in layer order
+    for i, layer in enumerate((0, 1, 3)):
+        mine = np.asarray(state["granite4h_l%d_ssm_state" % layer])
+        np.testing.assert_allclose(
+            mine.reshape(16, 8, 16).transpose(1, 2, 0), np.asarray(last[i]),
+            atol=1e-6)
+    from paddle_tpu.observability import registry
+
+    hist = {n.rsplit("/", 1)[1]: (m["sum"], m["count"]) for n, m in
+            registry.default_registry().snapshot().items()
+            if n.startswith("serving/%s/gen_" % eng.name) and "ssm" in n}
+    assert hist["gen_ssm_rows"] == (6, 6)  # six steps of one live row
+    assert hist["gen_ssm_state_bytes"] == (6 * 2 * 3 * 8 * 16 * 16 * 4, 6)
+    assert hist["gen_chunk_ssm_tokens"] == (29, 4)
+
+
+def test_granite_prefix_hit_restores_both_states_and_equals_a_cold_admission():
+    """A prompt admitted through a prefix hit (pages shared, conv and
+    recurrent state restored from the boundary's snapshot) against the same
+    prompt in an engine that has seen nothing, and against the reference."""
+    model, warm = _granite_engine()
+    _, cold = _granite_engine(prefix_cache=False)
+    shared = _prompt(12, 7)
+    first, second, third = (shared + _prompt(n, n) for n in (9, 10, 11))
+    for p in (first, second):  # the second leaves the snapshot at 12
+        warm.finish(warm.start(GenRequest(p, max_new_tokens=2)))
+    st = warm.prefix_cache.stats()
+    assert st["states_taken"] == st["cached_states"] == 1
+    assert st["state_bytes"] == warm.state_row_bytes
+    copied = warm._m_snap_copy_bytes
+    assert copied.value(event="taken") == warm.state_row_bytes
+    assert copied.value(event="restored") == 0
+    run = warm.admit(GenRequest(third, max_new_tokens=4))
+    assert (run.pf_pos, run.cut) == (12, 0)
+    assert copied.value(event="restored") == warm.state_row_bytes
+    while not warm.prefill_step(run):
+        pass
+    run_cold = cold.start(GenRequest(third, max_new_tokens=4))
+    want = _granite_reference(model, warm, third)[-1]
+    np.testing.assert_allclose(warm.last_prefill_logits, want, atol=GRANITE_TOL)
+    np.testing.assert_allclose(
+        warm.last_prefill_logits, cold.last_prefill_logits, atol=GRANITE_TOL)
+    for _ in range(3):
+        warm.decode_step([run])
+        cold.decode_step([run_cold])
+        np.testing.assert_allclose(
+            warm.last_logits[run.slot], cold.last_logits[run_cold.slot],
+            atol=GRANITE_TOL)
+    assert run.tokens == run_cold.tokens
+
+
+def test_granite_decode_step_leaves_idle_and_mid_prefill_states_untouched():
+    """A decode step for one run: the other slots' state rows (one idle, one
+    in the middle of its prefill) keep their bits, the recurrent ones too."""
+    model, eng = _granite_engine()
+    a = eng.start(GenRequest(_prompt(9, 1), max_new_tokens=4))
+    b = eng.admit(GenRequest(_prompt(20, 2), max_new_tokens=4))
+    eng.prefill_step(b)  # one chunk of three
+    before = {s: eng.state_rows(s) for s in range(3)}
+    eng.decode_step([a])
+    after = {s: eng.state_rows(s) for s in range(3)}
+    for s in set(range(3)) - {a.slot}:
+        for name in before[s]:
+            np.testing.assert_array_equal(
+                np.asarray(before[s][name]), np.asarray(after[s][name]))
+    changed = [name for name in before[a.slot] if not np.array_equal(
+        np.asarray(before[a.slot][name]), np.asarray(after[a.slot][name]))]
+    assert len(changed) == 6  # three layers' conv and recurrent rows
+    eng.finish(a)
+    eng.finish(b)
+
+
+DEPARTURES = {
+    "embedding_multiplier": dict(embedding_multiplier=1),
+    "residual_multiplier": dict(residual_multiplier=1.0),
+    "logits_scaling": dict(logits_scaling=1),
+    "attention_multiplier": dict(attention_multiplier=16 ** -0.5),
+    "rotary_added": dict(position_embedding_type="rope"),
+}
+
+
+@pytest.mark.parametrize("which", sorted(DEPARTURES) + ["qk_norm_added"])
+def test_granite_multipliers_positions_and_norms_are_the_configuration_s(which):
+    """Each of the four multipliers, the missing rotary and the missing q/k
+    norm is in the decoder because the configuration says so: a decoder
+    built with that one dropped (or added) over the same weights leaves the
+    reference by a thousand times what the true one does. Weights ten times
+    the published spread, so that the attention layer's scores are not all
+    alike and its scale and positions show."""
+    build = lambda config: granitemoehybrid(
+        config, max_context=128, dtype="float32", init_std=0.2)
+    model = build(GRANITE)
+    if which == "qk_norm_added":
+        other = build(GRANITE)
+        other.qk_norm = True  # the source has no key for it: lfm2's norm
+    else:
+        other = build(dict(GRANITE, **DEPARTURES[which]))
+    scope = Scope(seed=3)
+    other.ensure_params(scope)  # the true decoder's, and the norm's scales
+    tokens = np.array([_prompt(21, 1)], np.int64)
+    want = np.asarray(gref.forward(
+        {n: scope.vars[n] for n in model.param_names()}, tokens[0],
+        gref.spec_of(model)))
+
+    def off(decoder):
+        main, _, _, fetches = decoder.build_forward(1, 21)
+        with scope_guard(scope):
+            (logits,) = Executor().run(
+                main, feed={"fwd_tokens": tokens[:, :, None]}, fetch_list=fetches)
+        return float(np.abs(np.asarray(logits)[0] - want).max())
+
+    true = off(model)
+    assert true < 1e-4 * np.abs(want).max(), true
+    assert off(other) > 1000 * true, (which, off(other), true)

@@ -899,5 +899,305 @@ def test_latent_positions_walked_mirrors_the_kernel():
     assert pk.latent_positions_walked([5, 70], 16, 6) == 2 * 96
 
 
+# ---------------------------------------------------------------------------
+# the state-space scan (Mamba-2) and the convolution in front of it
+# ---------------------------------------------------------------------------
+
+
+def test_short_conv_silu_form_against_numpy_and_through_its_state():
+    """form "silu": X is convolved itself, Out = silu(conv + bias); whole
+    sequences, and the same bits through a chunk, a padded chunk and steps
+    over a bf16 state."""
+    rng = np.random.default_rng(7)
+    d, t, taps = 8, 11, 4
+    x = jnp.asarray(rng.standard_normal((t, d)), jnp.bfloat16)
+    kern = jnp.asarray(rng.standard_normal((d, taps)), jnp.float32)
+    bias = jnp.asarray(rng.standard_normal(d), jnp.float32)
+    attrs = {"mode": "chunk", "form": "silu"}
+    whole = _lower("short_conv", {"X": [x], "Kernel": [kern], "Bias": [bias]},
+                   attrs)["Out"][0]
+    x64 = np.concatenate([np.zeros((taps - 1, d)), np.asarray(x, np.float64)])
+    conv = sum(x64[j:j + t] * np.asarray(kern, np.float64)[:, j]
+               for j in range(taps)) + np.asarray(bias, np.float64)
+    np.testing.assert_allclose(
+        np.asarray(whole, np.float64), _silu(conv), rtol=1e-2, atol=1e-2)
+    assert whole.shape == (t, d) and whole.dtype == jnp.bfloat16
+    state = jnp.zeros((3, (taps - 1) * d), jnp.bfloat16)
+    outs = []
+    for start, n, rows in ((0, 5, 5), (5, 3, 8)):
+        chunk = jnp.zeros((rows, d), jnp.bfloat16).at[:n].set(x[start:start + n])
+        got = _lower("short_conv", {
+            "X": [chunk], "Kernel": [kern], "Bias": [bias], "State": [state],
+            "Rows": [jnp.array([2], jnp.int32)],
+            "Start": [jnp.array([start], jnp.int32)],
+            "Live": [(jnp.arange(rows) < n).astype(jnp.int32)]}, attrs)
+        state = got["StateOut"][0]
+        outs.append(got["Out"][0][:n])
+    for pos in range(8, t):
+        got = _lower("short_conv", {
+            "X": [x[pos:pos + 1]], "Kernel": [kern], "Bias": [bias],
+            "State": [state], "Rows": [jnp.array([2], jnp.int32)]},
+            {"mode": "step", "form": "silu"})
+        state = got["StateOut"][0]
+        outs.append(got["Out"][0])
+    np.testing.assert_array_equal(
+        np.asarray(jnp.concatenate(outs), np.float32), np.asarray(whole, np.float32))
+
+
+SSM = dict(heads=8, d_head=16, d_state=16, groups=1, chunk=64, epsilon=1e-5)
+_HP = SSM["heads"] * SSM["d_head"]
+
+
+def _ssm_case(t, seed=0, groups=1):
+    rng = np.random.default_rng(seed)
+    h, n = SSM["heads"], SSM["d_state"]
+    f32 = lambda a: np.asarray(a, np.float32)
+    return {
+        "x": f32(rng.standard_normal((t, _HP + 2 * groups * n))),
+        "dt": f32(rng.standard_normal((t, h))),
+        "z": f32(rng.standard_normal((t, _HP))),
+        "dt_bias": f32(np.log(np.expm1(np.exp(rng.uniform(
+            np.log(1e-3), np.log(1e-1), h))))),
+        "a_log": f32(np.log(rng.uniform(1, 16, h))),
+        "d": f32(rng.standard_normal(h)),
+        "w": f32(1 + 0.1 * rng.standard_normal(_HP)),
+    }
+
+
+def _ssm_recurrence(c, h0=None, groups=1, dtype=np.float32, state_dtype=None,
+                    decay_dtype=None):
+    """The definition, position by position, in `dtype` (float32: what the
+    op is held to; `state_dtype` / `decay_dtype` jnp.bfloat16: the state, or
+    dt and the decays, kept one precision lower). Returns (out [t, H * P],
+    the last state [H, P, N])."""
+    h, p, n = SSM["heads"], SSM["d_head"], SSM["d_state"]
+    t = c["x"].shape[0]
+    low = lambda a, dt: np.asarray(jnp.asarray(a, dt), dtype) if dt else a
+    state = np.zeros((h, p, n), dtype) if h0 is None else h0.astype(dtype)
+    a = -np.exp(c["a_log"].astype(dtype))
+    out = np.zeros((t, _HP), dtype)
+    for i in range(t):
+        xs = c["x"][i, :_HP].reshape(h, p).astype(dtype)
+        b, cc = (np.repeat(m.reshape(groups, n), h // groups, 0).astype(dtype)
+                 for m in (c["x"][i, _HP:_HP + groups * n],
+                           c["x"][i, _HP + groups * n:]))
+        dt = low(np.logaddexp(0, c["dt"][i].astype(dtype) + c["dt_bias"]).astype(dtype),
+                 decay_dtype)
+        decay = low(np.exp(dt * low(a, decay_dtype)).astype(dtype), decay_dtype)
+        state = low((decay[:, None, None] * state
+                     + (dt[:, None] * xs)[:, :, None] * b[:, None, :]).astype(dtype),
+                    state_dtype)
+        y = np.einsum("hpn,hn->hp", state, cc) + c["d"][:, None] * xs
+        g = y.reshape(-1) * _silu(c["z"][i].astype(dtype))
+        out[i] = g / np.sqrt(np.mean(g * g) + SSM["epsilon"]) * c["w"]
+    return out, state
+
+
+def _ssm_ins(c, rows=slice(None), **more):
+    ins = {"X": [jnp.asarray(c["x"][rows])], "Dt": [jnp.asarray(c["dt"][rows])],
+           "Z": [jnp.asarray(c["z"][rows])], "DtBias": [jnp.asarray(c["dt_bias"])],
+           "ALog": [jnp.asarray(c["a_log"])], "D": [jnp.asarray(c["d"])],
+           "NormScale": [jnp.asarray(c["w"])]}
+    ins.update({k: [jnp.asarray(v)] for k, v in more.items()})
+    return ins
+
+
+def _to_pool(h):
+    """[H, P, N] -> the pool's row [N, H * P]."""
+    return np.transpose(h, (2, 0, 1)).reshape(h.shape[2], -1)
+
+
+SSM_TOL = dict(rtol=1e-5, atol=1e-5)
+
+
+class TestSsmScanThroughTheExecutor(OpTest):
+    """The op as a program runs it (shape rule, dtypes, slots): one sequence
+    of 70 positions from zeros, two chunks of the form."""
+
+    def setUp(self):
+        self.op_type = "ssm_scan"
+        c = _ssm_case(70, 12)
+        self.inputs = {"X": c["x"], "Dt": c["dt"], "Z": c["z"],
+                       "DtBias": c["dt_bias"], "ALog": c["a_log"], "D": c["d"],
+                       "NormScale": c["w"]}
+        self.attrs = dict(SSM, mode="chunk", seq_len=0)
+        self.outputs = {"Out": _ssm_recurrence(c)[0]}
+
+    def test_check_output(self):
+        self.check_output(atol=1e-5)
+
+
+@pytest.mark.parametrize("groups", [1, 2])
+def test_ssm_scan_whole_sequences_against_the_recurrence(groups):
+    """No state: two sequences of 150 positions from zeros, three chunks of
+    the quadratic form each, against the recurrence position by position."""
+    t = 150
+    cases = [_ssm_case(t, seed, groups) for seed in (1, 2)]
+    both = {k: (np.concatenate([c[k] for c in cases]) if cases[0][k].ndim == 2
+                else cases[0][k]) for k in cases[0]}
+    got = _lower("ssm_scan", _ssm_ins(both), dict(
+        SSM, groups=groups, mode="chunk", seq_len=t))["Out"][0]
+    want = np.concatenate([
+        _ssm_recurrence(dict(both, **{k: c[k] for k in ("x", "dt", "z")}),
+                        groups=groups)[0] for c in cases])
+    assert got.shape == (2 * t, _HP) and got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got), want, **SSM_TOL)
+
+
+def test_ssm_scan_chunk_reads_and_writes_its_state_row():
+    """A chunk of one sequence from position 40 with three rows of padding:
+    it starts from its state row, the padding rows leave the state what the
+    last live row made it, and every other row of the pool keeps its bits."""
+    c = _ssm_case(16, 3)
+    rng = np.random.default_rng(4)
+    h0 = rng.standard_normal((8, 16, 16)).astype(np.float32)
+    pool = rng.standard_normal((4, 16, _HP)).astype(np.float32)
+    pool[2] = _to_pool(h0)
+    live = 13
+    got = _lower("ssm_scan", _ssm_ins(
+        c, State=pool, Rows=np.array([2], np.int32),
+        Start=np.array([40], np.int64),
+        Live=(np.arange(16) < live).astype(np.int32)), dict(SSM, mode="chunk"))
+    part = {k: (v[:live] if v.ndim == 2 else v) for k, v in c.items()}
+    want, last = _ssm_recurrence(part, h0)
+    np.testing.assert_allclose(np.asarray(got["Out"][0])[:live], want, **SSM_TOL)
+    new = np.asarray(got["StateOut"][0])
+    np.testing.assert_allclose(new[2], _to_pool(last), **SSM_TOL)
+    np.testing.assert_array_equal(new[[0, 1, 3]], pool[[0, 1, 3]])
+    # Start 0: from zeros, whatever the row holds (a cold admission writes
+    # no zeros to the pool)
+    cold = _lower("ssm_scan", _ssm_ins(
+        c, State=pool, Rows=np.array([2], np.int32),
+        Start=np.array([0], np.int64),
+        Live=np.ones(16, np.int32)), dict(SSM, mode="chunk"))
+    np.testing.assert_allclose(
+        np.asarray(cold["Out"][0]), _ssm_recurrence(c)[0], **SSM_TOL)
+    # a chunk of padding alone leaves its row bit for bit
+    idle = _lower("ssm_scan", _ssm_ins(
+        c, State=pool, Rows=np.array([2], np.int32),
+        Start=np.array([40], np.int64),
+        Live=np.zeros(16, np.int32)), dict(SSM, mode="chunk"))
+    np.testing.assert_array_equal(np.asarray(idle["StateOut"][0]), pool)
+
+
+@pytest.mark.parametrize("groups", [1, 2])
+def test_ssm_scan_step_updates_live_rows_and_leaves_idle_slots_alone(groups):
+    """One position a row, each with its own state row; rows 1 and 3 are
+    idle (Rows 0): every state row keeps its bits but the live rows'."""
+    c = _ssm_case(4, 5, groups)
+    rng = np.random.default_rng(6)
+    pool = rng.standard_normal((6, 16, _HP)).astype(np.float32)
+    idx = np.array([3, 0, 5, 0], np.int32)
+    got = _lower("ssm_scan", _ssm_ins(c, State=pool, Rows=idx),
+                 dict(SSM, groups=groups, mode="step"))
+    out, new = np.asarray(got["Out"][0]), np.asarray(got["StateOut"][0])
+    for r in (0, 2):
+        one = {k: (v[r:r + 1] if v.ndim == 2 else v) for k, v in c.items()}
+        h0 = np.transpose(pool[idx[r]].reshape(16, 8, 16), (1, 2, 0))
+        want, last = _ssm_recurrence(one, h0, groups=groups)
+        np.testing.assert_allclose(out[r], want[0], **SSM_TOL)
+        np.testing.assert_allclose(new[idx[r]], _to_pool(last), **SSM_TOL)
+    np.testing.assert_array_equal(new[[0, 1, 2, 4]], pool[[0, 1, 2, 4]])
+
+
+def test_ssm_scan_does_not_depend_on_the_cut():
+    """A 200-token prompt as one call (four chunks of the form) and as 128 +
+    72 through the state row, the second padded to 128, then three steps:
+    the same rows to float32 rounding, and the recurrence's."""
+    c = _ssm_case(203, 8)
+    want, _ = _ssm_recurrence(c)
+    attrs = dict(SSM, mode="chunk")
+    whole = np.asarray(_lower("ssm_scan", _ssm_ins(c), attrs)["Out"][0])
+    np.testing.assert_allclose(whole, want, **SSM_TOL)
+    state = jnp.asarray(np.random.default_rng(9).standard_normal(
+        (3, 16, _HP)), jnp.float32)  # stale: Start 0 reads none of it
+    outs = []
+    for start, n, rows in ((0, 128, 128), (128, 72, 128)):
+        pad = {k: np.concatenate([v[start:start + n], np.zeros(
+            (rows - n,) + v.shape[1:], v.dtype)]) if v.ndim == 2 else v
+            for k, v in c.items()}
+        got = _lower("ssm_scan", _ssm_ins(
+            pad, State=state, Rows=np.array([1], np.int32),
+            Start=np.array([start], np.int64),
+            Live=(np.arange(rows) < n).astype(np.int32)), attrs)
+        state = got["StateOut"][0]
+        outs.append(np.asarray(got["Out"][0])[:n])
+    for pos in range(200, 203):
+        got = _lower("ssm_scan", _ssm_ins(
+            c, slice(pos, pos + 1), State=state, Rows=np.array([1], np.int32)),
+            dict(SSM, mode="step"))
+        state = got["StateOut"][0]
+        outs.append(np.asarray(got["Out"][0]))
+    cut = np.concatenate(outs)
+    np.testing.assert_allclose(cut, whole, **SSM_TOL)
+    np.testing.assert_allclose(cut, want, **SSM_TOL)
+
+
+@pytest.mark.parametrize("kept_low", ["state", "decay"])
+def test_ssm_scan_bar_tells_a_bfloat16_state_or_decay_apart(kept_low):
+    """The bar the op is held to fails the same recurrence with its state,
+    or its dt and decays, kept in bfloat16: by a hundred times the bar, so
+    an op that kept either there could not pass the tests above. And the op
+    keeps them in float32 whatever its rows' dtype: bf16 rows read the bf16
+    inputs' own recurrence in float32 to bf16's last place, where the lower
+    precision inside does not."""
+    c = _ssm_case(200, 10)
+    want, _ = _ssm_recurrence(c)
+    low, _ = _ssm_recurrence(c, **{kept_low + "_dtype": jnp.bfloat16})
+    worst = np.max(np.abs(low - want) - 1e-5 * np.abs(want))
+    assert worst > 100 * 1e-5, worst
+    with pytest.raises(AssertionError):
+        np.testing.assert_allclose(low, want, **SSM_TOL)
+    # bf16 rows: inputs rounded, everything inside float32, one rounding out
+    rounded = {k: (np.asarray(jnp.asarray(v, jnp.bfloat16), np.float32)
+                   if k in ("x", "dt", "z") else v) for k, v in c.items()}
+    ins = _ssm_ins(rounded)
+    for k in ("X", "Dt", "Z"):
+        ins[k] = [ins[k][0].astype(jnp.bfloat16)]
+    got = _lower("ssm_scan", ins, dict(SSM, mode="chunk"))["Out"][0]
+    assert got.dtype == jnp.bfloat16
+    exact, _ = _ssm_recurrence(rounded)
+    got = np.asarray(got, np.float32)
+    assert np.max(np.abs(got - exact) / (np.abs(exact) + 1e-2)) < 2 ** -7
+    inside_low, _ = _ssm_recurrence(rounded, **{kept_low + "_dtype": jnp.bfloat16})
+    assert np.max(np.abs(got - exact)) < np.max(np.abs(inside_low - exact))
+
+
+@pytest.mark.parametrize("rows", [
+    [3, 0, 1, 0, 6, 0], [0] * 6, [1, 2, 3, 4, 5, 6], [0, 0, 0, 0, 0, 4]])
+def test_ssm_state_step_kernel_matches_the_lowering(rows, monkeypatch):
+    """The Pallas step (interpret mode) against numpy over a pool of six
+    slots, two blocks of lanes a row: live rows updated, every other row bit
+    for bit, idle rows' y 0."""
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setattr(pk, "_SSM_BLOCK_LANES", 128)
+
+    rng = np.random.default_rng(11)
+    slots, hp, n = 6, 256, 16
+    pool = rng.standard_normal((slots + 1, n, hp)).astype(np.float32)
+    xdt, b, c = (rng.standard_normal(s).astype(np.float32)
+                 for s in ((slots, hp), (slots, n), (slots, n)))
+    decay = rng.uniform(0, 1, (slots, hp)).astype(np.float32)
+    rows = np.asarray(rows, np.int32)
+    before = pk.KERNEL_DISPATCHES.get("ssm_step", 0)
+    y, new = pk.ssm_state_step(
+        jnp.asarray(pool), jnp.asarray(rows), jnp.asarray(xdt),
+        jnp.asarray(decay), jnp.asarray(b), jnp.asarray(c), interpret=True)
+    assert pk.KERNEL_DISPATCHES.get("ssm_step", 0) == before + 1
+    y, new = np.asarray(y), np.asarray(new)
+    live = rows != 0
+    want_rows = (pool[rows] * decay[:, None, :]
+                 + b[:, :, None] * xdt[:, None, :])
+    want_y = np.sum(want_rows * c[:, :, None], axis=1)
+    np.testing.assert_allclose(y[live], want_y[live], rtol=1e-5, atol=1e-5)
+    assert (y[~live] == 0).all()
+    np.testing.assert_allclose(new[rows[live]], want_rows[live], rtol=1e-6, atol=1e-6)
+    untouched = np.setdiff1d(np.arange(slots + 1), rows[live])
+    np.testing.assert_array_equal(new[untouched], pool[untouched])
+    # the decision the op makes: the kernel on a TPU, for whole tiles
+    assert not pk.ssm_step_path_taken(256, 16, 1)  # this backend is no TPU
+
+
 if __name__ == "__main__":
     unittest.main()

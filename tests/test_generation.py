@@ -833,3 +833,139 @@ def test_warmup_leaves_nothing_to_compile_for_a_sampled_row():
         for run in runs:
             eng.finish(run)
     assert compiles == [] and eng.traces == traces
+
+
+# ---------------------------------------------------------------------------
+# snapshots of a per-slot sequence state: a reserve in bytes, and when one is
+# taken (a model with a conv or recurrent state: test_block_decoder's tiny
+# lfm2_moe, whose slot row is 384 B)
+# ---------------------------------------------------------------------------
+
+
+def _state_engine(**kw):
+    from paddle_tpu.models.block_decoder import lfm2_moe
+    from test_block_decoder import TINY
+
+    model = lfm2_moe(TINY, max_context=64, dtype="float32")
+    kw = dict(dict(max_slots=3, page_size=4, prefill_chunk=8, cache_dir=None), **kw)
+    _state_engine.made += 1  # a name of its own: the registry's counters are by name
+    return GenerationEngine(
+        model, name="tstate%d" % _state_engine.made, scope=Scope(seed=3), **kw)
+
+
+_state_engine.made = 0
+
+
+def _tokens(n, seed):
+    return [int(v) for v in np.random.default_rng(seed).integers(2, 97, n)]
+
+
+def test_snapshots_are_evicted_by_bytes_under_the_reserve():
+    """The reserve is device bytes, whatever a state is wide: by default
+    four snapshots a slot; with `snapshot_bytes` as many as fit, the coldest
+    going first; stats() and the gauge report held and reserved bytes."""
+    eng = _state_engine()
+    row = eng.state_row_bytes
+    assert row == 3 * 2 * 32 * 4  # three conv layers' two rows of 32 float32
+    assert eng.prefix_cache.stats()["state_bytes_reserved"] == 4 * 3 * row
+    eng = _state_engine(snapshot_bytes=3 * row + row // 2)
+    pc = eng.prefix_cache
+    heads = [_tokens(8, 50 + i) for i in range(5)]
+    for i, head in enumerate(heads):  # each head twice: the second snapshots it
+        for tail in (1, 2):
+            eng.finish(eng.start(GenRequest(
+                head + _tokens(7, 70 + 2 * i + tail), max_new_tokens=1)))
+    st = pc.stats()
+    assert (st["states_taken"], st["states_evicted"], st["cached_states"]) == (5, 2, 3)
+    assert st["state_bytes"] == 3 * row <= st["state_bytes_reserved"] == 3 * row + row // 2
+    assert eng._m_snap_bytes.value(what="held") == 3 * row
+    assert eng._m_snap_bytes.value(what="reserved") == 3 * row + row // 2
+    assert eng._m_snap_copy_bytes.value(event="taken") == 5 * row
+    # the coldest went: the first two heads match pages and restore nothing,
+    # the last three restore
+    for i, head in enumerate(heads):
+        pages, state, matched = pc.lookup_state(head + _tokens(5, 90 + i))
+        pc.pool.unpin_pages(pages)
+        assert matched == 2 and (state is not None) == (i >= 2), i
+    # a reserve smaller than one snapshot holds none; the pages stay shared
+    eng = _state_engine(snapshot_bytes=row - 1)
+    for tail in (1, 2, 3):
+        eng.finish(eng.start(GenRequest(heads[0] + _tokens(7, tail), max_new_tokens=1)))
+    st = eng.prefix_cache.stats()
+    assert st["cached_states"] == 0 and st["states_taken"] == st["states_evicted"] == 2
+    assert st["states_hit"] == 0 and st["cached_pages"] >= 2
+
+
+def test_prompts_of_one_shared_head_leave_the_one_snapshot_the_policy_says():
+    """Six prompts of one shared 12-token head and unique tails (up to five
+    chunks of 8): a snapshot is taken only at a boundary another prompt was
+    seen to share. The first prompt leaves none, the second one at the
+    head's end, and every later one restores it and copies nothing more:
+    not one at each of the tails' page-aligned chunk ends."""
+    eng = _state_engine()
+    row = eng.state_row_bytes
+    head = _tokens(12, 5)
+    taken, hit = [], []
+    for i, n in enumerate((9, 30, 17, 26, 5, 38)):
+        eng.finish(eng.start(GenRequest(head + _tokens(n, 10 + i), max_new_tokens=2)))
+        st = eng.prefix_cache.stats()
+        taken.append(st["states_taken"])
+        hit.append(st["states_hit"])
+    assert taken == [0, 1, 1, 1, 1, 1] and hit == [0, 0, 1, 2, 3, 4]
+    assert st["cached_states"] == 1 and st["state_bytes"] == row
+    assert eng._m_snap_copy_bytes.value(event="taken") == row
+    assert eng._m_snap_copy_bytes.value(event="restored") == 4 * row
+    # two of them share 20 tokens more: the next of those leaves one there
+    deeper = head + _tokens(30, 11)[:20]
+    eng.finish(eng.start(GenRequest(deeper + _tokens(6, 30), max_new_tokens=2)))
+    assert eng.prefix_cache.stats()["states_taken"] == 2
+    pages, state, matched = eng.prefix_cache.lookup_state(deeper + _tokens(3, 31))
+    eng.prefix_cache.pool.unpin_pages(pages)
+    assert (len(pages), matched) == (8, 8) and state is not None
+
+
+def _program_digest(model):
+    """sha256 over every op (type, attrs, input and output names by slot) of
+    the decode, a prefill and the forward program, and their startups."""
+    import hashlib
+
+    h = hashlib.sha256()
+    builds = (model.build_decode(3, 4, 16, 68, state_rows=4),
+              model.build_prefill(8, 4, 16, 68, state_rows=4),
+              model.build_forward(2, 8))
+    for main, startup, feeds, fetches in builds:
+        for prog in (main, startup):
+            for op in prog.global_block().ops:
+                attrs = {k: (v.tolist() if isinstance(v, np.ndarray) else v)
+                         for k, v in op.attrs.items()}
+                h.update(json.dumps(
+                    [op.type, sorted(attrs.items(), key=lambda kv: kv[0]),
+                     sorted(op.inputs.items()), sorted(op.outputs.items())],
+                    sort_keys=True, default=str).encode())
+        h.update(json.dumps([feeds, fetches]).encode())
+    return h.hexdigest()
+
+
+@pytest.mark.parametrize("which", ["lfm2_moe", "xing4_0"])
+def test_block_decoders_built_before_the_state_space_operator_keep_their_programs(which):
+    """What BlockDecoder gained for granitemoehybrid (the mamba operator, the
+    position / q-k norm / score scale choices, the three multipliers, the
+    convolution's second form) defaults to what lfm2_moe and xing4_0 built
+    before: their programs' op lists, attrs and variable names, and the
+    spec the compile cache folds in, are PR 32's (digests taken there with
+    this function). A PR that changes them on purpose takes them anew."""
+    from paddle_tpu.models.block_decoder import lfm2_moe, xing4_0
+    from test_block_decoder import TINY, XING
+
+    model, digest = {
+        "lfm2_moe": (lambda: lfm2_moe(TINY, max_context=64, dtype="float32"),
+                     "359183df59cf841822f1e10a0dd7c7d5e73d3264e786d207d05e20c5733e7583"),
+        "xing4_0": (lambda: xing4_0(XING, max_context=64, dtype="float32",
+                                    experts_held=None),
+                    "71074496b20431f033e3f0162ad7d862333d6b922bbdbd8ba729cf3b5eab39b3"),
+    }[which]
+    model = model()
+    assert _program_digest(model) == digest
+    assert set(model.spec()) == {
+        "blocks", "dtype", "n_head", "n_kv_head", "d_head", "d_model", "d_ffn",
+        "conv_taps", "moe", "mla", "hyper", "tied_head"}

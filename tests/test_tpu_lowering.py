@@ -107,6 +107,14 @@ def _latent(per_row, rows, g):
     ]
 
 
+def _ssm_step(slots, hp, n):
+    """granite4h_burst_chat's decode step at slots=48, hp=4096, n=128."""
+    return (lambda pool, rows, xdt, decay, b, c:
+            pk.ssm_state_step(pool, rows, xdt, decay, b, c)), [
+        S((slots + 1, n, hp), f32), S((slots,), i32), S((slots, hp), f32),
+        S((slots, hp), f32), S((slots, n), f32), S((slots, n), f32)]
+
+
 def _adam(moment_dtype):
     shapes = [(256, 384), (384,), (7, 13)]
 
@@ -201,6 +209,8 @@ FAMILIES = {
     "paged_latent bf16 decode xing4_docs_chat": _latent(True, 32, _LATENT_CELL),
     "paged_latent bf16 shared 256 xing4_docs_chat": _latent(False, 256, _LATENT_CELL),
     "paged_latent bf16 shared 32 xing4_docs_chat": _latent(False, 32, _LATENT_CELL),
+    "ssm_step toy": _ssm_step(4, 128, 16),
+    "ssm_step granite4h_burst_chat": _ssm_step(48, 4096, 128),
     "moe_grouped decode lfm2moe_chat": _moe_grouped(128),
     "moe_grouped chunk lfm2moe_chat": _moe_grouped(512),
     "moe_grouped two row tiles": _moe_grouped(640, d=256, f=384, held=4),
