@@ -263,7 +263,7 @@ def phase_train(cfg=TRAIN, n_steps=8, multistep=4):
     check(not ref["dispatches"],
           "train: kernels dispatched with the passes off: %s" % ref["dispatches"])
     disp = run["dispatches"]
-    for fam in ("layer_norm", "layer_norm_grad", "multi_adam", "gemm_epilogue"):
+    for fam in ("layer_norm", "layer_norm_grad", "gemm_epilogue"):
         check(disp.get(fam, 0) > 0,
               "train: no %s dispatch under training_fused: %s" % (fam, disp))
     check(not run["off_device"] and not ref["off_device"],
@@ -272,7 +272,7 @@ def phase_train(cfg=TRAIN, n_steps=8, multistep=4):
     if jax.devices()[0].platform == "tpu":
         calls = run["custom_calls"]
         for fam in ("flash_attention", "flash_attention_grad", "gemm_epilogue",
-                    "layer_norm", "layer_norm_grad", "multi_adam"):
+                    "layer_norm", "layer_norm_grad"):
             check(calls.get(fam, 0) > 0,
                   "train: no tpu_custom_call for %s in the compiled step: %s"
                   % (fam, calls))

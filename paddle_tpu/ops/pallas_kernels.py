@@ -60,8 +60,6 @@ __all__ = [
     "fused_layer_norm",
     "fused_layer_norm_grad",
     "ln_path_taken",
-    "multi_tensor_adam",
-    "adam_path_taken",
     "KERNEL_DISPATCHES",
 ]
 
@@ -995,8 +993,8 @@ def _flash_attention_grad_op(ctx, ins, attrs):
 
 
 # ---------------------------------------------------------------------------
-# kernel-substitution tier: fused GEMM epilogue, fused layer_norm(+residual),
-# and multi-tensor Adam. Each is reached through a `fuse_*` pass
+# kernel-substitution tier: fused GEMM epilogue and fused
+# layer_norm(+residual). Each is reached through a `fuse_*` pass
 # (passes/builtin.py) that tags op runs with PALLAS_GROUP_ATTR /
 # PALLAS_KERNEL_ATTR; registry.lower_ops hands a tagged run to the
 # @register_fused lowering below, which validates shapes/attrs at TRACE time
@@ -2673,118 +2671,6 @@ def fused_layer_norm_grad(x2, scale, mean, var, dy2, eps, *, interpret=None):
 
 
 # ---------------------------------------------------------------------------
-# multi-tensor Adam: one kernel over flattened, chunk-padded param groups —
-# f32 master math, outputs rounded to the per-slot storage dtypes (bf16
-# moments supported), per-param lr_t selected via scalar-prefetched indices
-# ---------------------------------------------------------------------------
-
-_ADAM_CHUNK_ROWS = 256  # 256x128 = 32k elements per grid step
-# elements one kernel call packs. The flattened copies of a call's params,
-# grads and moments (in and out) are live in HBM together, so one call over
-# the whole bench transformer (0.67G params) needed a second copy of the
-# model: XLA's TPU compiler put that step 0.5 GB over a v5e's 15.75 GB. A
-# few calls over bounded slabs fit with room to spare.
-_ADAM_CALL_ELEMS = 1 << 26
-
-
-def adam_path_taken(n_params, zero1=False, sharded=False):
-    """Mirror of the fused multi-tensor-Adam dispatch decision: the kernel is
-    total over shapes (params are chunk-padded), so the only declines are a
-    degenerate group and the sharded tiers — ZeRO-1 and rule-sharded
-    (FSDP/TP) params — whose per-param GSPMD sharding constraints
-    (core_ops._opt_f32) the flattened kernel cannot express."""
-    return n_params >= 2 and not zero1 and not sharded
-
-
-def _multi_adam_kernel(c2p_ref, lrt_ref, p_ref, g_ref, m1_ref, m2_ref,
-                       po_ref, m1o_ref, m2o_ref, *, beta1, beta2, eps):
-    """One chunk: the EXACT _adam update expressions (core_ops) on the f32
-    upcast, rounded to the storage dtypes on write — within a few ulps of the
-    unfused per-param chain where that chain's math is f32 (the same
-    expressions; where XLA contracts an FMA is not fixed). lr_t (per param,
-    bias correction included) rides a scalar-prefetch table indexed by the
-    chunk->param map."""
-    i = pl.program_id(0)
-    lr_t = lrt_ref[c2p_ref[i]]
-    g = g_ref[...].astype(jnp.float32)
-    m1 = m1_ref[...].astype(jnp.float32)
-    m2 = m2_ref[...].astype(jnp.float32)
-    p = p_ref[...].astype(jnp.float32)
-    m1o = beta1 * m1 + (1 - beta1) * g
-    m2o = beta2 * m2 + (1 - beta2) * jnp.square(g)
-    po = p - lr_t * m1o / (jnp.sqrt(m2o) + eps)
-    po_ref[...] = po.astype(po_ref.dtype)
-    m1o_ref[...] = m1o.astype(m1o_ref.dtype)
-    m2o_ref[...] = m2o.astype(m2o_ref.dtype)
-
-
-def _pack_rows(arrs, rows_per):
-    """Ravel each array, zero-pad to its chunk-aligned row count, and stack
-    lane-major — zero pad rows are mathematically inert in the Adam update
-    (0 - lr*0/(sqrt(0)+eps) = 0) and sliced off on unpack."""
-    flat = []
-    for a, r in zip(arrs, rows_per):
-        v = a.reshape(-1)
-        pad = r * _LANES - v.size
-        if pad:
-            v = jnp.concatenate([v, jnp.zeros((pad,), v.dtype)])
-        flat.append(v.reshape(r, _LANES))
-    return jnp.concatenate(flat, axis=0)
-
-
-def multi_tensor_adam(params, grads, m1s, m2s, lr_ts, beta1, beta2, epsilon,
-                      *, interpret=None):
-    """Fused Adam over a param group: flatten every (param, grad, m1, m2)
-    quadruple into chunk-padded (rows, 128) slabs, run ONE kernel over the
-    concatenation, split back. lr_ts are per-param f32 scalars with bias
-    correction already applied (lr * sqrt(1-b2^t)/(1-b1^t)). Params must
-    share a dtype per slot (the fused lowering groups by dtype). Returns
-    (param_outs, m1_outs, m2_outs) in the input storage dtypes."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    chunk = _ADAM_CHUNK_ROWS * _LANES
-    sizes = [int(p.size) for p in params]
-    rows_per = [-(-s // chunk) * _ADAM_CHUNK_ROWS for s in sizes]
-    chunks_per = [r // _ADAM_CHUNK_ROWS for r in rows_per]
-    c2p = np.repeat(np.arange(len(params), dtype=np.int32), chunks_per)
-    lrt = jnp.stack([jnp.asarray(v, jnp.float32).reshape(()) for v in lr_ts])
-    p_cat = _pack_rows(params, rows_per)
-    g_cat = _pack_rows(grads, rows_per)
-    m1_cat = _pack_rows(m1s, rows_per)
-    m2_cat = _pack_rows(m2s, rows_per)
-    total_rows = int(p_cat.shape[0])
-    blk = pl.BlockSpec(
-        (_ADAM_CHUNK_ROWS, _LANES), lambda i, c2p, lrt: (i, 0)
-    )
-    po, m1o, m2o = pl.pallas_call(
-        functools.partial(
-            _multi_adam_kernel, beta1=beta1, beta2=beta2, eps=epsilon
-        ),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(sum(chunks_per),),
-            in_specs=[blk, blk, blk, blk],
-            out_specs=[blk, blk, blk],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((total_rows, _LANES), p_cat.dtype),
-            jax.ShapeDtypeStruct((total_rows, _LANES), m1_cat.dtype),
-            jax.ShapeDtypeStruct((total_rows, _LANES), m2_cat.dtype),
-        ],
-        interpret=interpret,
-    )(jnp.asarray(c2p), lrt, p_cat, g_cat, m1_cat, m2_cat)
-    p_outs, m1_outs, m2_outs = [], [], []
-    row = 0
-    for p, r, size in zip(params, rows_per, sizes):
-        sl = slice(row, row + r)
-        p_outs.append(po[sl].reshape(-1)[:size].reshape(p.shape))
-        m1_outs.append(m1o[sl].reshape(-1)[:size].reshape(p.shape))
-        m2_outs.append(m2o[sl].reshape(-1)[:size].reshape(p.shape))
-        row += r
-    return p_outs, m1_outs, m2_outs
-
-
-# ---------------------------------------------------------------------------
 # fused lowerings: registry.lower_ops hands tagged runs here; every path that
 # cannot reproduce the per-op semantics returns False (per-op fallback)
 # ---------------------------------------------------------------------------
@@ -3089,84 +2975,4 @@ def _fused_layer_norm_grad(ctx, ops, env):
         outs["Bias@GRAD"] = [db.reshape(bias.shape).astype(bias.dtype)]
     scatter_op_outputs(op, outs, env)
     _note_dispatch("layer_norm_grad")
-    return True
-
-
-@register_fused("multi_adam")
-def _fused_multi_adam(ctx, ops, env):
-    """A contiguous run of dense adam ops through multi_tensor_adam: one
-    call per (param, grad, moment) dtype signature and _ADAM_CALL_ELEMS
-    packed elements. lr_t (bias correction) is computed OUTSIDE the kernel
-    with the exact _adam expressions, so the fused update is the per-param
-    f32 chain to a few ulps. The ZeRO-1
-    tier declines: _opt_f32's per-param GSPMD reduce-scatter/all-gather
-    constraints don't survive flattening. Likewise rule-sharded (FSDP/TP)
-    params — their storage layouts are per-tensor."""
-    if ctx.zero1_axis is not None and ctx.mesh is not None:
-        return False
-    if _mesh_declines(ctx, ops):
-        return False
-    if len(ops) < 2 or any(op.type != "adam" for op in ops):
-        return False
-    a0 = ops[0].attrs
-    b1 = a0.get("beta1", 0.9)
-    b2 = a0.get("beta2", 0.999)
-    eps = a0.get("epsilon", 1e-8)
-    recs = []
-    for op in ops:
-        a = op.attrs
-        if (
-            a.get("beta1", 0.9) != b1
-            or a.get("beta2", 0.999) != b2
-            or a.get("epsilon", 1e-8) != eps
-        ):
-            return False
-        ins = gather_op_inputs(op, env)
-        vals = [
-            ins.get(s, [None])[0]
-            for s in (
-                "Param", "Grad", "Moment1", "Moment2",
-                "LearningRate", "Beta1Pow", "Beta2Pow",
-            )
-        ]
-        if any(v is None for v in vals):
-            return False
-        recs.append((op, vals))
-    if not adam_path_taken(len(recs), zero1=False):
-        return False
-    by_dtype = {}
-    for op, (p, g, m1, m2, lr, b1p, b2p) in recs:
-        lr_t = (
-            lr.reshape(()).astype(jnp.float32)
-            * jnp.sqrt(1 - b2p.astype(jnp.float32).reshape(()))
-            / (1 - b1p.astype(jnp.float32).reshape(()))
-        )
-        key = (str(p.dtype), str(g.dtype), str(m1.dtype), str(m2.dtype))
-        by_dtype.setdefault(key, []).append((op, p, g, m1, m2, lr_t))
-    calls = []
-    for group in by_dtype.values():
-        calls.append([])
-        packed = 0
-        for rec in group:
-            if calls[-1] and packed + rec[1].size > _ADAM_CALL_ELEMS:
-                calls.append([])
-                packed = 0
-            calls[-1].append(rec)
-            packed += rec[1].size
-    for group in calls:
-        p_outs, m1_outs, m2_outs = multi_tensor_adam(
-            [r[1] for r in group],
-            [r[2] for r in group],
-            [r[3] for r in group],
-            [r[4] for r in group],
-            [r[5] for r in group],
-            b1, b2, eps,
-        )
-        for (op, *_), po, m1o, m2o in zip(group, p_outs, m1_outs, m2_outs):
-            scatter_op_outputs(
-                op,
-                {"ParamOut": [po], "Moment1Out": [m1o], "Moment2Out": [m2o]},
-                env,
-            )
-    _note_dispatch("multi_adam")
     return True

@@ -252,11 +252,11 @@ FUSION_GROUP_ATTR = "__fusion_group__"
 FUSION_SCOPE_PREFIX = "fusion_group="
 
 # Kernel-substitution tier (docs/passes.md "Kernel substitution"): the
-# fuse_gemm_epilogue / fuse_layer_norm / fuse_optimizer passes tag op runs
+# fuse_gemm_epilogue / fuse_layer_norm passes tag op runs
 # with a group id + a kernel family name; lower_ops hands a contiguous
 # same-group run to the family's registered FUSED lowering (a Pallas kernel,
 # ops/pallas_kernels.py) instead of lowering op by op. A fused lowering may
-# DECLINE at trace time (ragged shapes, unsupported attrs, ZeRO-1 sharding)
+# DECLINE at trace time (ragged shapes, unsupported attrs, a mesh)
 # by returning False — the run then falls back to per-op lowering with
 # identical semantics, so tagging is always safe. Like FUSION_GROUP_ATTR
 # the tags are attr-only: def-use, op order, and count are untouched.
@@ -376,7 +376,7 @@ def lower_ops(ctx, ops, env):
     profiler's attribution both see the chain as a unit.
 
     Contiguous runs sharing a PALLAS_GROUP_ATTR value (tagged by the
-    fuse_gemm_epilogue / fuse_layer_norm / fuse_optimizer passes) are handed
+    fuse_gemm_epilogue / fuse_layer_norm passes) are handed
     to the family's fused Pallas lowering (FUSED_LOWERINGS); a decline falls
     back to per-op lowering. Pallas tags take precedence over fusion-group
     tags when an op carries both (the kernel subsumes the XLA fusion hint)."""

@@ -17,7 +17,6 @@ __all__ = [
     "FuseElemwiseActPass",
     "FuseGemmEpiloguePass",
     "FuseLayerNormPass",
-    "FuseOptimizerPass",
     "InplaceDonationPlanPass",
 ]
 
@@ -607,54 +606,6 @@ class FuseLayerNormPass(Pass):
         ctx.results[self.name] = {"groups": groups, "ops_tagged": tagged}
         if groups:
             graph.program._bump_version()
-
-
-@register_pass("fuse_optimizer")
-class FuseOptimizerPass(Pass):
-    """Tag maximal contiguous runs (≥ 2) of dense adam ops sharing
-    (beta1, beta2, epsilon, LearningRate input) for the fused multi-tensor
-    Adam kernel (`multi_adam` family): every param group flattened into
-    chunk-padded slabs and updated by ONE kernel, f32 master math rounded to
-    the storage dtypes. AdamOptimizer emits exactly this shape — one adam
-    per param back to back, beta-pow scale ops appended after the run."""
-
-    def apply(self, graph, ctx):
-        ops = graph.program.global_block().ops
-        groups = 0
-        tagged = 0
-        i = 0
-        while i < len(ops):
-            op = ops[i]
-            if op.type != "adam" or not _pallas_free(op):
-                i += 1
-                continue
-            key = self._group_key(op)
-            j = i + 1
-            while (
-                j < len(ops)
-                and ops[j].type == "adam"
-                and _pallas_free(ops[j])
-                and self._group_key(ops[j]) == key
-            ):
-                j += 1
-            run = ops[i:j]
-            if len(run) >= 2:
-                _tag_run(run, "madam%d" % groups, "multi_adam")
-                groups += 1
-                tagged += len(run)
-            i = j
-        ctx.results[self.name] = {"groups": groups, "ops_tagged": tagged}
-        if groups:
-            graph.program._bump_version()
-
-    @staticmethod
-    def _group_key(op):
-        return (
-            op.attrs.get("beta1", 0.9),
-            op.attrs.get("beta2", 0.999),
-            op.attrs.get("epsilon", 1e-8),
-            op.input("LearningRate")[0],
-        )
 
 
 @register_pass("inplace_donation_plan")

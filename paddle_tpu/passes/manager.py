@@ -27,11 +27,11 @@ aot_serve_lowering):
   it because that pass rewrites parameter values in the scope — opt in
   explicitly (or via the InferenceTranspiler shim).
 - training_fused: training_default plus the Pallas kernel-substitution
-  taggers (fuse_gemm_epilogue, fuse_layer_norm, fuse_optimizer) — tagged
-  chains lower to hand-tuned kernels (ops/pallas_kernels.py) instead of
-  per-op XLA; fused-vs-unfused parity is within bf16 rounding (one
-  rounding per fused chain instead of one per op), a few ulps where
-  the chain's math was already f32 (the multi-tensor Adam update).
+  taggers (fuse_gemm_epilogue, fuse_layer_norm) — tagged chains lower to
+  hand-tuned kernels (ops/pallas_kernels.py) instead of per-op XLA;
+  fused-vs-unfused parity is within bf16 rounding (one rounding per fused
+  chain instead of one per op). The optimizer's ops lower per op in every
+  preset.
 - inference_int8: the calibrated-int8 serving pipeline (passes/quant.py) —
   calibrate records activation ranges from representative feeds riding
   ctx.attrs["calibrate"], quantize_serving freezes weights to int8 and
@@ -75,7 +75,6 @@ PRESETS = {
         "fuse_elemwise_act",
         "fuse_gemm_epilogue",
         "fuse_layer_norm",
-        "fuse_optimizer",
         "inplace_donation_plan",
     ),
     "inference_int8": (

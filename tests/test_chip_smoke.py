@@ -24,7 +24,9 @@ def test_train_phase_toy_width():
         dict(b=1, t=128, d=128, n_layer=1, vocab=128), n_steps=2, multistep=2
     )
     assert facts["loss_last"] < facts["loss_first"]
-    assert facts["dispatches"]["multi_adam"] == 1
+    # one fused GEMM tile body off the chip; the optimizer is XLA's own
+    assert set(facts["dispatches"]) == {
+        "gemm_epilogue", "layer_norm", "layer_norm_grad"}
     assert facts["custom_calls"] is None  # interpreted: nothing to find
 
 

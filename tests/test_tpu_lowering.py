@@ -13,6 +13,9 @@ to "tpu" for the duration of a lowering, which also makes every `auto` flag
 choose what it would choose on the chip.
 """
 
+import collections
+import re
+
 import pytest
 
 import jax
@@ -115,17 +118,6 @@ def _ssm_step(slots, hp, n):
         S((slots, hp), f32), S((slots, n), f32), S((slots, n), f32)]
 
 
-def _adam(moment_dtype):
-    shapes = [(256, 384), (384,), (7, 13)]
-
-    def fn(p, g, m1, m2, lr):
-        return pk.multi_tensor_adam(p, g, m1, m2, lr, 0.9, 0.999, 1e-8)
-
-    ps = [S(s, f32) for s in shapes]
-    ms = [S(s, moment_dtype) for s in shapes]
-    return fn, [ps, ps, ms, ms, [S((), f32)] * len(shapes)]
-
-
 def _gemm(act, dbuf):
     return (
         lambda x, w, b: pk.gemm_bias_act(x, w, b, act),
@@ -185,8 +177,6 @@ FAMILIES = {
         lambda x, s, m, v, dy: pk.fused_layer_norm_grad(x, s, m, v, dy, 1e-5),
         [_LN[0], _LN[1], _LN[2], _LN[2], _LN[0]],
     ),
-    "multi_adam": _adam(f32),
-    "multi_adam bf16 moments": _adam(bf16),
     "paged_flash decode": _paged(False, True, 4),
     "paged_flash shared": _paged(False, False, 8),
     "paged_flash shared 2 rows": _paged(False, False, 2),
@@ -253,9 +243,11 @@ def test_paged_auto_declines_a_page_that_is_not_whole_tiles(as_on_tpu):
     assert not pk.paged_flash_path_taken(8, 128, 12, 12, 64)
 
 
-def test_training_fused_step_lowers_for_tpu(monkeypatch):
-    """The one-layer chip_smoke transformer step under training_fused — every
-    fused family and both flash directions in one module."""
+@pytest.fixture(scope="module")
+def fused_step():
+    """The one-layer chip_smoke transformer step under training_fused,
+    exported for the TPU: (the module's text, KERNEL_DISPATCHES of its
+    trace)."""
     import chip_smoke
     import paddle_tpu.fluid as fluid
     from paddle_tpu.executor import (
@@ -267,10 +259,10 @@ def test_training_fused_step_lowers_for_tpu(monkeypatch):
         b=1, t=128, d=128, n_layer=1, vocab=128, moment_dtype="bfloat16"
     )
     scope = Scope(seed=0)
-    with scope_guard(scope):
+    with scope_guard(scope), pytest.MonkeyPatch.context() as patch:
         fluid.Executor().run(startup)  # on the CPU: the scope's avals
         Bf16Transpiler().transpile(main)
-        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        patch.setattr(jax, "default_backend", lambda: "tpu")
         feeds = list(feed)
         prog = _apply_pass_pipeline(
             main, scope, feeds, [loss.name], pipeline="training_fused"
@@ -284,8 +276,33 @@ def test_training_fused_step_lowers_for_tpu(monkeypatch):
             {n: aval(scope.vars[n]) for n in block.mut_names},
             aval(scope.rng_key),
         ).mlir_module()
-    for fam in ("gemm_dbuf", "gemm_epilogue", "layer_norm", "layer_norm_grad",
-                "multi_adam"):
-        assert pk.KERNEL_DISPATCHES.get(fam, 0) > 0, pk.KERNEL_DISPATCHES
-    # 3 flash forward + 3 backward, 4 GEMMs, 5 + 5 layer norms, 1 Adam
-    assert text.count("tpu_custom_call") >= 21, text.count("tpu_custom_call")
+        return text, dict(pk.KERNEL_DISPATCHES)
+
+
+def test_training_fused_step_lowers_for_tpu(fused_step):
+    """Every fused family and both flash directions in one module."""
+    text, dispatches = fused_step
+    for fam in ("gemm_dbuf", "gemm_epilogue", "layer_norm", "layer_norm_grad"):
+        assert dispatches.get(fam, 0) > 0, dispatches
+    # 3 flash forward + 3 backward, 4 GEMMs, 5 + 5 layer norms
+    assert text.count("@tpu_custom_call") == 20, text.count("@tpu_custom_call")
+
+
+def test_training_fused_step_leaves_the_optimizer_to_xla(fused_step):
+    """Each custom call sits under the scope its lowering ran in
+    (registry.lower_ops: "jit(run)/<scope>/.../pallas_call"): flash, the
+    fused GEMM and the layer norms, and none under an adam op, whose update
+    is in the module as plain XLA."""
+    text, _ = fused_step
+    locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    scopes = [
+        locs[ref].split("/")[1].split(".")[0]
+        for ref in re.findall(
+            r"@tpu_custom_call.*loc\((#loc\d+)\)$", text, re.M)
+    ]
+    assert collections.Counter(scopes) == {
+        "flash_attention": 3, "flash_attention_grad": 3,
+        "pallas_kernel=gemm_epilogue": 4, "pallas_kernel=layer_norm": 5,
+        "pallas_kernel=layer_norm_grad": 5,
+    }
+    assert any(name.startswith("jit(run)/adam/") for name in locs.values())
