@@ -1706,6 +1706,9 @@ class Executor:
         # check_nan_inf / nan-provenance reports cite (the telemetry step
         # counter only advances when telemetry is on)
         self._run_seq = 0
+        # collections are spans and a histogram (profiler.watch_gc); run()
+        # folds the pauses since the last one into the registry
+        self._gc = _prof.watch_gc()
 
     def close(self):
         """Reference Executor::Close (executor.cc:111-119): notify pservers
@@ -1740,10 +1743,12 @@ class Executor:
         with jax.default_device(self._device), _prof.RecordEvent(
             span="executor.run", iter=self._run_seq
         ):
-            return self._run(
+            result = self._run(
                 program, feed, fetch_list, scope, return_numpy,
                 use_program_cache, steps_per_run,
             )
+            self._gc.fold()
+            return result
 
     def _run(self, program, feed, fetch_list, scope, return_numpy,
              use_program_cache, steps_per_run):

@@ -30,6 +30,9 @@ Trigger sites (each passes reason-specific context):
 - ``slo_alert``           an SLO burn-rate alert or drift sentinel fired
                           (observability/slo.py) — info carries the
                           offending window's merged series
+- ``slow_pass``           a serving scheduler pass of SLOW_PASS_MS or more
+                          (serving/generation.py) — info carries the fields
+                          of its [fluid.slow_pass] stderr line
 
 The module-level ``trigger(reason, **info)`` is the only call sites use; it
 is a near-free no-op when FLAGS_flightrec_dir is unset and must NEVER raise

@@ -161,6 +161,11 @@ class Histogram(_Metric):
         with self._lock:
             return self._count
 
+    @property
+    def sum(self):
+        with self._lock:
+            return self._sum
+
     def percentile(self, q):
         """q in [0, 100]. Interpolated within the containing bucket; the
         overflow bucket reports the observed max."""
@@ -308,6 +313,15 @@ class MetricRegistry:
     def __init__(self):
         self._lock = threading.Lock()
         self._metrics = {}
+        self._before_snapshot = []
+
+    def before_snapshot(self, fold):
+        """Have `fold()` called at the start of every snapshot(), outside
+        the lock: whoever must keep observations outside the registry for a
+        while (profiler's collection hook, which may not take the lock) folds
+        them in there, so that a reader never sees a stale sum."""
+        if fold not in self._before_snapshot:
+            self._before_snapshot.append(fold)
 
     def _get_or_create(self, cls, name, help, **kw):
         with self._lock:
@@ -357,6 +371,8 @@ class MetricRegistry:
     def snapshot(self):
         """{name: {kind, values|buckets...}} — one lock pass, so the view is
         consistent across metrics."""
+        for fold in self._before_snapshot:
+            fold()
         with self._lock:
             return {
                 name: m._snapshot_locked()

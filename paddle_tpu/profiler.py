@@ -16,6 +16,7 @@ kept API-compatible.
 """
 
 import contextlib
+import gc
 import json
 import os
 import threading
@@ -32,6 +33,7 @@ __all__ = [
     "is_profiling",
     "xla_trace",
     "device_op_profile",
+    "watch_gc",
 ]
 
 _state = {"on": False, "mode": "All"}
@@ -108,6 +110,73 @@ class RecordEvent:
                 with _events_lock:
                     _events.append((full, self._start, end, threading.get_ident()))
         return False
+
+
+class _GcWatch:
+    """The gc.callbacks hook: a span fluid.host.gc (tag `generation`) around
+    every collection of the process, whichever thread it interrupts, and its
+    pause in the registry histogram host/gc_pause_ms.
+
+    A collection can start inside Histogram.observe, which holds the
+    registry's one non-reentrant lock, so the callback never touches the
+    registry: it adds to plain attributes (`ms` and `count`, every pause so
+    far) and to `pending`, and fold() moves the pending pauses into the
+    histogram from ordinary code (a scheduler pass's end, Executor.run's
+    finish, the registry's snapshot())."""
+
+    def __init__(self, hist):
+        self.ms = 0.0
+        self.count = 0
+        self.pending = []
+        self._hist = hist
+        self._event = None
+
+    def __call__(self, phase, info):
+        if phase == "start":
+            self._event = RecordEvent(
+                span="host.gc", generation=info["generation"]).__enter__()
+        elif self._event is not None:
+            event, self._event = self._event, None
+            event.__exit__(None, None, None)
+            self.ms += event.ms
+            self.count += 1
+            self.pending.append(event.ms)
+
+    def fold(self):
+        # pop, never swap the list: the callback may append at any bytecode,
+        # and another thread may be folding too
+        while self.pending:
+            try:
+                ms = self.pending.pop()
+            except IndexError:
+                return
+            self._hist.observe(ms)
+
+
+_gc_watch = None
+_gc_watch_lock = threading.Lock()
+
+
+def watch_gc():
+    """Install the collection hook, once a process however often it is called
+    (a GenerationScheduler's start and an Executor's construction do), and
+    return it. Nothing of the collector's behaviour changes."""
+    global _gc_watch
+    if _gc_watch is None:
+        from .observability import registry as _registry
+
+        reg = _registry.default_registry()
+        hist = reg.histogram(
+            "host/gc_pause_ms",
+            "one garbage collection of the process (span fluid.host.gc), "
+            "wall ms: every thread stands still for it",
+        )
+        with _gc_watch_lock:
+            if _gc_watch is None:
+                _gc_watch = _GcWatch(hist)
+                gc.callbacks.append(_gc_watch)
+                reg.before_snapshot(_gc_watch.fold)
+    return _gc_watch
 
 
 def reset_profiler():

@@ -68,14 +68,16 @@ shares the batch. That determinism is the parity contract the tests pin.
 
 import hashlib
 import json
+import sys
 import threading
 import time
 
 import numpy as np
 
 from ..executor import Scope, aot_serve_lowering, scope_guard
+from ..observability import flightrec as _flightrec
 from ..observability import tracing as _tracing
-from ..profiler import RecordEvent
+from ..profiler import RecordEvent, watch_gc
 from ..observability.tracing import NULL_SPAN
 from ..ops import pallas_kernels as _pk
 from .batcher import (
@@ -249,7 +251,7 @@ class _Step:
 
     __slots__ = ("by_slot", "positions", "variant", "logits", "ids", "picks",
                  "rows", "fetch_bytes", "ctx_tokens", "walked_tokens", "span",
-                 "host_ms", "dispatch_ms")
+                 "host_ms", "dispatch_ms", "seq")
 
     def __init__(self, runs):
         self.by_slot = {run.slot: run for run in runs}
@@ -291,8 +293,8 @@ CTX_TOKEN_BUCKETS = (
 )
 
 
-# gen_experts_touched / gen_expert_rows_max: small counts (of the experts
-# this chip holds: a pick of an expert held elsewhere is no work here)
+# gen_experts_touched: small counts (of the experts this chip holds: a pick
+# of an expert held elsewhere is no work here)
 EXPERT_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024, 2048, 4096)
 
 
@@ -379,11 +381,10 @@ def _moe_kernel_layers(block):
 
 
 def _experts_touched(picks, held=None):
-    """(distinct experts picked, summed over the expert layers; the most
-    rows any one expert of any layer got) of picks [n_moe, live rows, k].
-    `held`: the global ids of the experts this chip holds (None: all); a
-    pick of an expert held elsewhere is no work here and is not counted,
-    as moe_experts does not visit it."""
+    """Distinct experts picked, summed over the expert layers, of picks
+    [n_moe, live rows, k]. `held`: the global ids of the experts this chip
+    holds (None: all); a pick of an expert held elsewhere is no work here
+    and is not counted, as moe_experts does not visit it."""
     n_moe = picks.shape[0]
     width = int(picks.max()) + 1
     counts = np.bincount(
@@ -392,9 +393,7 @@ def _experts_touched(picks, held=None):
         minlength=n_moe * width).reshape(n_moe, width)
     if held is not None:
         counts = counts[:, [e for e in held if e < width]]
-    if not counts.size:
-        return 0, 0
-    return int(np.count_nonzero(counts)), int(counts.max())
+    return int(np.count_nonzero(counts))
 
 
 class GenerationEngine:
@@ -608,6 +607,66 @@ class GenerationEngine:
                            "row with a temperature: host sampling), wall ms"),
             )
         }
+        # what an admission costs once a prompt, where the work happens: the
+        # slot and pages, the prefix cache's three entry points (the cache
+        # gets its events from _prefix_phase), the last chunk's blocking
+        # read and its first token
+        self._m_admit_ms = reg.histogram(
+            p + "/gen_admit_ms",
+            "admit(): a slot and pages for one request, the prefix lookup, "
+            "an eviction for room and a state restore included (span "
+            "fluid.engine.admit), wall ms",
+        )
+        self._m_prefix_ms = {
+            what: reg.histogram(p + "/prefix_%s_ms" % what, help)
+            for what, help in (
+                ("lookup", "PrefixCache.lookup_state of one prompt, its "
+                           "tokens' tuple included (span fluid.prefix."
+                           "lookup), wall ms"),
+                ("insert", "PrefixCache.insert of one prefilled prompt's "
+                           "pages, an eviction at the trie's cap included "
+                           "(span fluid.prefix.insert), wall ms"),
+                ("evict", "one eviction pass of the prefix cache, from "
+                          "insert at the trie's cap or from admit for room "
+                          "(span fluid.prefix.evict), wall ms"),
+            )
+        }
+        if self.prefix_cache is not None:
+            self.prefix_cache.phase = self._prefix_phase
+        self._m_prefill_phase_ms = {
+            "wait": reg.histogram(
+                p + "/gen_prefill_wait_ms",
+                "a prompt's last chunk: the host blocked on its first "
+                "token's id and the chunks' picks, the pipeline's drain "
+                "once a prompt (span fluid.engine.wait, kind=prefill), "
+                "wall ms"),
+            "sample": reg.histogram(
+                p + "/gen_prefill_sample_ms",
+                "a prompt's last chunk: its first token, the prefix "
+                "cache's insert, arming the slot (span fluid.engine.sample, "
+                "kind=prefill), wall ms"),
+        }
+        # the device run dry: programs dispatched so far (_dispatch numbers
+        # them), and the instant a blocking read returned for the one
+        # dispatched last, until the next dispatch
+        self._n_dispatched = 0
+        self._last_ids = None  # the ids of the program dispatched last
+        self._dry_since = None
+        self._m_starved_ms = reg.histogram(
+            p + "/gen_device_starved_ms",
+            "from a blocking read of the program dispatched last (the device "
+            "has nothing) to the next dispatch's return, wall ms: a lower "
+            "bound of the device's idle time (it misses the launch, and a "
+            "device that ran dry while the host read nothing); the time with "
+            "no request at all is in it",
+        )
+        self._m_found_dry = reg.histogram(
+            p + "/gen_dispatch_found_dry",
+            "at a pipelined decode dispatch, before its feed: 1 where the "
+            "program dispatched last had already finished (the device ran "
+            "dry while the host was still in its pass), else 0",
+            buckets=(0, 1),
+        )
         self._m_in_flight = reg.histogram(
             p + "/gen_steps_in_flight",
             "decode steps dispatched and unread at a decode dispatch, this "
@@ -736,12 +795,6 @@ class GenerationEngine:
             p + "/gen_chunk_experts_touched",
             "distinct experts with a live row in one prefill chunk, summed "
             "over the expert layers (observed with the prompt's last chunk)",
-            buckets=EXPERT_BUCKETS,
-        )
-        self._m_expert_rows_max = reg.histogram(
-            p + "/gen_expert_rows_max",
-            "most live rows any one expert of any layer got in one decode "
-            "step",
             buckets=EXPERT_BUCKETS,
         )
         self._m_moe_kernel_layers = reg.histogram(
@@ -1021,13 +1074,23 @@ class GenerationEngine:
 
     def _phase(self, phase, kind):
         """One leaf of a model step: the span fluid.engine.<phase>, the
-        phase's histogram on decode steps, the stall of the host-only ones."""
+        phase's histogram (a decode step's four; of a prefill chunk the
+        wait and sample a prompt's last has), the stall of the host-only
+        ones."""
+        hists = (self._m_phase_ms if kind == "decode"
+                 else self._m_prefill_phase_ms)
         return RecordEvent(
-            span="engine." + phase,
-            hist=self._m_phase_ms[phase] if kind == "decode" else None,
+            span="engine." + phase, hist=hists.get(phase),
             stall=self.host_stall if phase in ("feed", "sample") else None,
             kind=kind, iter=self.iter,
         )
+
+    def _prefix_phase(self, what):
+        """The prefix cache's event for its lookup, insert or evict: the span
+        fluid.prefix.<what> and the model's prefix_<what>_ms."""
+        return RecordEvent(
+            span="prefix." + what, hist=self._m_prefix_ms[what],
+            iter=self.iter)
 
     def _feeds(self, variant, np_feeds):
         """(feeds, mut_in) for one call of a variant: the feed phase's end.
@@ -1041,9 +1104,23 @@ class GenerationEngine:
         return feeds, {n: self._state[n] for n in variant.mut_names}
 
     def _dispatch(self, variant, feeds, mut_in):
+        """Every program the engine gives the device goes through here, and
+        is numbered; its ids are its last fetch."""
         fetches, new_mut = variant.fn(feeds, variant.ro, mut_in)
         self._state.update(new_mut)
+        self._n_dispatched += 1
+        self._last_ids = fetches[-1]
+        if self._dry_since is not None:
+            self._m_starved_ms.observe(
+                (time.perf_counter() - self._dry_since) * 1e3)
+            self._dry_since = None
         return fetches
+
+    def _note_read(self, seq):
+        """A blocking read of program `seq` has returned: if none was
+        dispatched behind it, the device has nothing from now on."""
+        if seq == self._n_dispatched:
+            self._dry_since = time.perf_counter()
 
     # ---- admission / prefill / decode / retire -----------------------------
     def prefill_bucket(self, n):
@@ -1079,38 +1156,42 @@ class GenerationEngine:
                 "prompt of %d tokens exceeds max_prompt_len %d"
                 % (L, self.max_prompt_len)
             )
-        max_new = self._max_new(req)
-        shared, state, matched = [], None, 0
-        if self.prefix_cache is not None:
-            # pages pinned; with a per-sequence state, only down to a
-            # boundary whose snapshot comes with them
-            shared, state, matched = self.prefix_cache.lookup_state(req.prompt)
-        try:
+        with RecordEvent(span="engine.admit", hist=self._m_admit_ms,
+                         iter=self.iter):
+            max_new = self._max_new(req)
+            shared, state, matched = [], None, 0
+            if self.prefix_cache is not None:
+                # pages pinned; with a per-sequence state, only down to a
+                # boundary whose snapshot comes with them
+                shared, state, matched = self.prefix_cache.lookup_state(
+                    req.prompt)
             try:
-                slot, table = self.pool.acquire(L + max_new, shared)
-            except PoolExhausted:
-                need = self.pool.pages_for(L + max_new) - len(shared)
-                if self.prefix_cache is None or not self.prefix_cache.evict_for(need):
-                    raise
-                slot, table = self.pool.acquire(L + max_new, shared)
-        finally:
-            if shared:
-                self.pool.unpin_pages(shared)  # slot ref (or nothing) holds now
-        seed = req.seed
-        if seed is None:
-            seed = (self.scope._seed, self._sample_counter)
-            self._sample_counter += 1
-        run = _SlotRun(req, slot, table, np.random.default_rng(seed))
-        run.pf_pos = len(shared) * self.page_size
-        if self._state_names and self.prefix_cache is not None:
-            if state is not None:
-                self._restore_state(slot, state)
-            if matched > len(shared):
-                # other prompts share more of this one than any snapshot
-                # covers: end a chunk at that boundary and leave one there
-                run.cut = matched * self.page_size
-        self._set_pool_gauges()
-        return run
+                try:
+                    slot, table = self.pool.acquire(L + max_new, shared)
+                except PoolExhausted:
+                    need = self.pool.pages_for(L + max_new) - len(shared)
+                    if (self.prefix_cache is None
+                            or not self.prefix_cache.evict_for(need)):
+                        raise
+                    slot, table = self.pool.acquire(L + max_new, shared)
+            finally:
+                if shared:
+                    self.pool.unpin_pages(shared)  # slot ref (or nothing) holds now
+            seed = req.seed
+            if seed is None:
+                seed = (self.scope._seed, self._sample_counter)
+                self._sample_counter += 1
+            run = _SlotRun(req, slot, table, np.random.default_rng(seed))
+            run.pf_pos = len(shared) * self.page_size
+            if self._state_names and self.prefix_cache is not None:
+                if state is not None:
+                    self._restore_state(slot, state)
+                if matched > len(shared):
+                    # other prompts share more of this one than any snapshot
+                    # covers: end a chunk at that boundary and leave one there
+                    run.cut = matched * self.page_size
+            self._set_pool_gauges()
+            return run
 
     def prefill_step(self, run):
         """Advance one admitted run by ONE prefill chunk (one device call).
@@ -1180,13 +1261,15 @@ class GenerationEngine:
         if run.pf_pos < L:
             return False
         self._m_prefills.inc()
+        seq = self._n_dispatched
         with self._phase("wait", "prefill"):
             self._last_prefill_logits = _Logits(logits)
             ids = np.asarray(ids)
+            self._note_read(seq)
             for first, n_live, picks in run.chunk_picks:
                 picks = np.asarray(picks)[:, :n_live]
                 self._m_chunk_experts.observe(
-                    _experts_touched(picks, self._experts_held)[0])
+                    _experts_touched(picks, self._experts_held))
                 if self.record_picks:
                     run.picks.append((first, picks))
             run.chunk_picks = []
@@ -1306,6 +1389,8 @@ class GenerationEngine:
             )
         step = _Step(runs)
         step.span = span
+        if prev is not None:
+            self._m_found_dry.observe(1 if self._last_ids.is_ready() else 0)
         t0 = time.perf_counter()
         try:
             with self._phase("feed", "decode"):
@@ -1343,6 +1428,7 @@ class GenerationEngine:
                 # start when the step ends, not when the host asks
                 step.logits, *extra, step.ids = self._dispatch(
                     variant, feeds, mut_in)
+                step.seq = self._n_dispatched
                 self._ids = step.ids
                 step.picks = extra[0] if extra else None
                 step.rows = {
@@ -1368,6 +1454,7 @@ class GenerationEngine:
         try:
             with self._phase("wait", "decode") as wait:
                 ids = np.asarray(step.ids)
+                self._note_read(step.seq)
                 picks = None if step.picks is None else np.asarray(step.picks)
                 rows = {slot: np.asarray(row)
                         for slot, row in step.rows.items()}
@@ -1410,10 +1497,8 @@ class GenerationEngine:
         """Experts touched by one decode step's live rows, from its picks."""
         self.last_picks = picks  # parity surface, like last_logits
         runs = step.runs
-        touched, rows_max = _experts_touched(
-            picks[:, [run.slot for run in runs], :], self._experts_held)
-        self._m_experts_touched.observe(touched)
-        self._m_expert_rows_max.observe(rows_max)
+        self._m_experts_touched.observe(_experts_touched(
+            picks[:, [run.slot for run in runs], :], self._experts_held))
         if self.record_picks:
             for run, pos in zip(runs, step.positions):
                 if not run.done:
@@ -1554,6 +1639,14 @@ class GenerationEngine:
         return out
 
 
+# A scheduler pass at or over this is a stall: it is observed a second time
+# (sched_slow_iter_ms) and leaves a line on stderr. A constant: the slowest
+# benchmark cell's pass is 22.5 ms (PERF.md).
+SLOW_PASS_MS = 100.0
+# at most one such line in this many seconds; the next says how many it held
+SLOW_PASS_LINE_EVERY_S = 1.0
+
+
 class _Pending:
     __slots__ = ("req", "future", "t_submit", "span")
 
@@ -1629,7 +1722,28 @@ class GenerationScheduler(ContinuousBatcher):
             "(admit, retire, the engine's feed and sample): the scheduler "
             "thread waiting for the interpreter lock, a mutex or the OS",
         )
+        self._m_slow_ms = reg.histogram(
+            p + "/sched_slow_iter_ms",
+            "an iteration of SLOW_PASS_MS (100) or more, wall ms: observed "
+            "here a second time, beside the [fluid.slow_pass] line on stderr",
+        )
         self._iter = 0
+        # what a pass leaves for the slow-pass line: its phases' events, the
+        # prompts it admitted, the chunks it ran, its host stall
+        self._phases = {}
+        self._admitted = self._chunks = 0
+        self._host_stall_ms = 0.0
+        self._slow_line_at = float("-inf")
+        self._slow_held_back = 0
+        self._gc = watch_gc()
+        # the engine's histograms whose sum inside a slow pass its line gives
+        self._m_inside = {
+            "prefill_wait_ms": engine._m_prefill_phase_ms["wait"],
+            "prefill_sample_ms": engine._m_prefill_phase_ms["sample"],
+            "decode_dispatch_ms": engine._m_phase_ms["dispatch"],
+            "decode_wait_ms": engine._m_phase_ms["wait"],
+            "prefix_evict_ms": engine._m_prefix_ms["evict"],
+        }
         super().__init__(
             engine,
             max_queue_rows=max_queue_requests,
@@ -1682,10 +1796,11 @@ class GenerationScheduler(ContinuousBatcher):
 
     # ---- step loop --------------------------------------------------------
     def _phase(self, phase, stall=None):
-        return RecordEvent(
+        event = self._phases[phase] = RecordEvent(
             span="sched." + phase, hist=self._m_sched_ms[phase], stall=stall,
             iter=self._iter,
         )
+        return event
 
     def _loop(self):
         while True:
@@ -1696,9 +1811,48 @@ class GenerationScheduler(ContinuousBatcher):
                 self._wait_for_work()
             self._iter += 1
             self.engine.iter = self._iter
-            with self._phase("iter"):
-                if not self._pass():
-                    return
+            self._phases.clear()
+            self._admitted = self._chunks = 0
+            self._host_stall_ms = 0.0
+            gc_ms = self._gc.ms
+            before = [h.sum for h in self._m_inside.values()]
+            with self._phase("iter") as it:
+                alive = self._pass()
+            self._gc.fold()
+            if it.ms >= SLOW_PASS_MS:
+                self._slow_pass(it.ms, self._gc.ms - gc_ms, {
+                    name: round(h.sum - was, 3) for (name, h), was
+                    in zip(self._m_inside.items(), before)})
+            if not alive:
+                return
+
+    def _slow_pass(self, ms, gc_ms, inside):
+        """A pass that stalled: sched_slow_iter_ms, and the record an
+        untraced run keeps of it: one line on stderr (as the telemetry
+        health line is written), the same fields to the flight recorder (a
+        no-op without FLAGS_flightrec_dir)."""
+        self._m_slow_ms.observe(ms)
+        now = time.monotonic()
+        if now - self._slow_line_at < SLOW_PASS_LINE_EVERY_S:
+            self._slow_held_back += 1
+            return
+        fields = {"model": self.engine.name, "iter": self._iter,
+                  "ms": round(ms, 3)}
+        for phase in ("lock", "admit", "prefill", "decode", "retire"):
+            event = self._phases.get(phase)  # None: the pass did not reach it
+            fields[phase + "_ms"] = round(event.ms, 3) if event else 0.0
+        fields.update(
+            host_stall_ms=round(self._host_stall_ms, 3),
+            gc_ms=round(gc_ms, 3), admitted=self._admitted,
+            chunks=self._chunks, **inside,
+            live=len(self._runs), queued=self._queued_rows,
+            held_back=self._slow_held_back,
+        )
+        self._slow_line_at, self._slow_held_back = now, 0
+        print("[fluid.slow_pass] "
+              + " ".join("%s=%s" % kv for kv in fields.items()),
+              file=sys.stderr, flush=True)
+        _flightrec.trigger("slow_pass", **fields)
 
     def _busy(self):
         """Whether a pass has anything to do: a request queued, admitted or
@@ -1732,6 +1886,7 @@ class GenerationScheduler(ContinuousBatcher):
                 admits = self._admit_requests_locked()
             finally:
                 self._cond.release()
+            self._admitted = len(admits)
             self._admit(admits)
         with self._phase("prefill"):
             self._prefill_chunks()
@@ -1746,7 +1901,8 @@ class GenerationScheduler(ContinuousBatcher):
                     if run.done:
                         del self._runs[run.slot]
                         self._retire(run)
-        self._m_stall_ms.observe(stall.take())
+        self._host_stall_ms = stall.take()
+        self._m_stall_ms.observe(self._host_stall_ms)
         return True
 
     def _admit_requests_locked(self):
@@ -1841,6 +1997,7 @@ class GenerationScheduler(ContinuousBatcher):
         order = sorted(self._prefills,
                        key=lambda r: len(r.req.prompt) - r.pf_pos)
         for run in order[:n_chunks]:
+            self._chunks += 1
             try:
                 with _tracing.tracer().activate(run.span):
                     finished = eng.prefill_step(run)

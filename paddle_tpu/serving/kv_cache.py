@@ -65,6 +65,7 @@ a lock still guards acquire/release so `stats()` from other threads is
 consistent.
 """
 
+import contextlib
 import threading
 
 import numpy as np
@@ -246,6 +247,10 @@ class _PrefixNode:
         self.children = 0
 
 
+def _no_phase(what):
+    return contextlib.nullcontext()
+
+
 class PrefixCache:
     """Prompt-token trie over immutable full KV pages (module docstring)."""
 
@@ -282,6 +287,12 @@ class PrefixCache:
         self.pages_hit = 0
         self.pages_eligible = 0
         self.evictions = 0
+        # phase("lookup" | "insert" | "evict") -> a context around that entry
+        # point's work. Whoever serves through the cache hands it its own
+        # (GenerationEngine: the spans fluid.prefix.* and its model's
+        # prefix_*_ms, which the cache could not name); without, nothing is
+        # recorded
+        self.phase = _no_phase
 
     def lookup(self, prompt):
         """lookup_state()'s pages alone: all a model without a state needs."""
@@ -301,39 +312,40 @@ class PrefixCache:
         (None without a hit) and `matched` >= len(pages) is how many pages
         of the prompt the trie holds at all: a boundary other prompts share,
         where this prompt's prefill should leave a snapshot."""
-        ps = self.pool.page_size
-        prompt = tuple(int(t) for t in prompt)
-        eligible = (len(prompt) - 1) // ps
-        found, state = [], None
-        with self._lock:
-            self._clock += 1
-            parent = 0
-            for i in range(eligible):
-                node = self._nodes.get((parent, prompt[i * ps:(i + 1) * ps]))
-                if node is None:
-                    break
-                node.stamp = self._clock
-                found.append(node)
-                parent = node.uid
-            matched = len(found)
-            if self.state_bytes:
-                usable = 0
-                for i in range(matched, 0, -1):
-                    if found[i - 1].state is not None:
-                        usable, state = i, found[i - 1].state
-                        self.states_hit += 1
+        with self.phase("lookup"):
+            ps = self.pool.page_size
+            prompt = tuple(int(t) for t in prompt)
+            eligible = (len(prompt) - 1) // ps
+            found, state = [], None
+            with self._lock:
+                self._clock += 1
+                parent = 0
+                for i in range(eligible):
+                    node = self._nodes.get((parent, prompt[i * ps:(i + 1) * ps]))
+                    if node is None:
                         break
-                del found[usable:]
-            pages = [node.page for node in found]
-            self.pages_eligible += eligible
-            self.pages_hit += len(pages)
+                    node.stamp = self._clock
+                    found.append(node)
+                    parent = node.uid
+                matched = len(found)
+                if self.state_bytes:
+                    usable = 0
+                    for i in range(matched, 0, -1):
+                        if found[i - 1].state is not None:
+                            usable, state = i, found[i - 1].state
+                            self.states_hit += 1
+                            break
+                    del found[usable:]
+                pages = [node.page for node in found]
+                self.pages_eligible += eligible
+                self.pages_hit += len(pages)
+                if pages:
+                    self.hits += 1
+                elif eligible:
+                    self.misses += 1
             if pages:
-                self.hits += 1
-            elif eligible:
-                self.misses += 1
-        if pages:
-            self.pool.pin_pages(pages)
-        return pages, state, matched
+                self.pool.pin_pages(pages)
+            return pages, state, matched
 
     def insert(self, prompt, table, states=None):
         """Publish a finished prefill's full prompt pages into the trie.
@@ -342,52 +354,53 @@ class PrefixCache:
         them. Already-cached depths are left alone, but for a snapshot they
         lack: `states` is {tokens: the sequence state after that many prompt
         tokens}, what this prefill left at its page-aligned chunk ends."""
-        states = states or {}
-        ps = self.pool.page_size
-        prompt = tuple(int(t) for t in prompt)
-        n_full = len(prompt) // ps
-        added = 0
-        with self._lock:
-            self._clock += 1
-            parent = parent_key = None
-            for i in range(n_full):
-                key = (parent.uid if parent else 0, prompt[i * ps:(i + 1) * ps])
-                state = states.get((i + 1) * ps)
-                node = self._nodes.get(key)
-                if node is not None:
-                    node.stamp = self._clock
-                    if state is not None and node.state is None:
-                        node.state = state
+        with self.phase("insert"):
+            states = states or {}
+            ps = self.pool.page_size
+            prompt = tuple(int(t) for t in prompt)
+            n_full = len(prompt) // ps
+            added = 0
+            with self._lock:
+                self._clock += 1
+                parent = parent_key = None
+                for i in range(n_full):
+                    key = (parent.uid if parent else 0, prompt[i * ps:(i + 1) * ps])
+                    state = states.get((i + 1) * ps)
+                    node = self._nodes.get(key)
+                    if node is not None:
+                        node.stamp = self._clock
+                        if state is not None and node.state is None:
+                            node.state = state
+                            self.states_taken += 1
+                            self._n_states += 1
+                        parent, parent_key = node, key
+                        continue
+                    if len(self._nodes) >= self.capacity_pages:
+                        if not self._evict_locked(1):
+                            break
+                        # the path just walked is the warmest there is, but its
+                        # end is a leaf until this page hangs from it: had the
+                        # eviction taken it, a child would be unreachable
+                        if parent is not None and (
+                                self._nodes.get(parent_key) is not parent):
+                            break
+                    page = int(table[i])
+                    if page == SCRATCH_PAGE:
+                        break
+                    self.pool.pin_pages([page])
+                    node = _PrefixNode(page, self._clock, state, self._next_uid, parent)
+                    self._next_uid += 1
+                    if parent is not None:
+                        parent.children += 1
+                    self._nodes[key] = node
+                    if state is not None:
                         self.states_taken += 1
                         self._n_states += 1
+                    added += 1
                     parent, parent_key = node, key
-                    continue
-                if len(self._nodes) >= self.capacity_pages:
-                    if not self._evict_locked(1):
-                        break
-                    # the path just walked is the warmest there is, but its
-                    # end is a leaf until this page hangs from it: had the
-                    # eviction taken it, a child would be unreachable
-                    if parent is not None and (
-                            self._nodes.get(parent_key) is not parent):
-                        break
-                page = int(table[i])
-                if page == SCRATCH_PAGE:
-                    break
-                self.pool.pin_pages([page])
-                node = _PrefixNode(page, self._clock, state, self._next_uid, parent)
-                self._next_uid += 1
-                if parent is not None:
-                    parent.children += 1
-                self._nodes[key] = node
-                if state is not None:
-                    self.states_taken += 1
-                    self._n_states += 1
-                added += 1
-                parent, parent_key = node, key
-            if self._n_states * self.state_bytes > self.snapshot_bytes:
-                self._cap_states_locked()
-        return added
+                if self._n_states * self.state_bytes > self.snapshot_bytes:
+                    self._cap_states_locked()
+            return added
 
     def _cap_states_locked(self):
         """Drop the coldest snapshots until those left fit the reserve;
@@ -406,31 +419,32 @@ class PrefixCache:
             return self._evict_locked(n_pages)
 
     def _evict_locked(self, n_pages):
-        # children before parents: a deeper node is at least as cold as the
-        # node above it, and dropping a parent first would leave unreachable
-        # descendants pinned
-        order = sorted(
-            self._nodes.items(), key=lambda kv: (kv[1].stamp, -kv[1].depth)
-        )
-        evicted = 0
-        for key, node in order:
-            if evicted >= n_pages:
-                break
-            # only pages no slot is reading (our pin is the sole reference)
-            if self.pool.page_refcount(node.page) != 1:
-                continue
-            if node.children:
-                continue  # has live descendants; they sort earlier anyway
-            del self._nodes[key]
-            if node.parent is not None:
-                node.parent.children -= 1
-            self.pool.unpin_pages([node.page])
-            if node.state is not None:
-                self.states_evicted += 1
-                self._n_states -= 1
-            self.evictions += 1
-            evicted += 1
-        return evicted
+        with self.phase("evict"):
+            # children before parents: a deeper node is at least as cold as the
+            # node above it, and dropping a parent first would leave unreachable
+            # descendants pinned
+            order = sorted(
+                self._nodes.items(), key=lambda kv: (kv[1].stamp, -kv[1].depth)
+            )
+            evicted = 0
+            for key, node in order:
+                if evicted >= n_pages:
+                    break
+                # only pages no slot is reading (our pin is the sole reference)
+                if self.pool.page_refcount(node.page) != 1:
+                    continue
+                if node.children:
+                    continue  # has live descendants; they sort earlier anyway
+                del self._nodes[key]
+                if node.parent is not None:
+                    node.parent.children -= 1
+                self.pool.unpin_pages([node.page])
+                if node.state is not None:
+                    self.states_evicted += 1
+                    self._n_states -= 1
+                self.evictions += 1
+                evicted += 1
+            return evicted
 
     def reclaimable(self):
         """Cached pages only the trie holds — evictable on demand (the

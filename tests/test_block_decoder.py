@@ -555,15 +555,15 @@ def test_xing4_engine_counts_the_held_experts_and_the_chunks_context():
     from paddle_tpu.serving.generation import _experts_touched
 
     picks = np.array([[[0, 5], [5, 7]], [[1, 2], [2, 6]]])
-    assert _experts_touched(picks) == (6, 2)
-    assert _experts_touched(picks, held=[0, 1, 2, 3]) == (3, 2)
-    assert _experts_touched(picks, held=[4]) == (0, 0)
+    assert _experts_touched(picks) == 6
+    assert _experts_touched(picks, held=[0, 1, 2, 3]) == 3
+    assert _experts_touched(picks, held=[4]) == 0
     model, eng = _xing_engine(held=[0, 1, 2, 5], name="xing_held")
     snap = lambda h: registry.default_registry().snapshot()["serving/xing_held/" + h]
     run = eng.start(GenRequest(_prompt(21), max_new_tokens=4))
     eng.decode_step([run])
-    all_picked, _ = _experts_touched(eng.last_picks[:, [run.slot], :])
-    held_picked, _ = _experts_touched(eng.last_picks[:, [run.slot], :], [0, 1, 2, 5])
+    all_picked = _experts_touched(eng.last_picks[:, [run.slot], :])
+    held_picked = _experts_touched(eng.last_picks[:, [run.slot], :], [0, 1, 2, 5])
     assert snap("gen_experts_touched")["sum"] == held_picked <= all_picked
     eng.finish(run)
     # chunks of 16 and 5 rows: 16 + 21 positions; 16 * 17 / 2 + (5 * 16 + 15) pairs

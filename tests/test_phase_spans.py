@@ -4,10 +4,16 @@ docs/observability.md): under a real CPU profiler session the spans land in
 the xplane, the scheduler's five phases partition their iteration and the
 engine's leaves nest in them; the phase histograms add up; gen_ctx_tokens
 counts a hand-built step; both executors leave their three leaves; outputs do
-not change with a session on; the repaired span tags; the public reads."""
+not change with a session on; the repaired span tags; the public reads.
+An admission's spans (fluid.engine.admit, fluid.prefix.*) nest where the work
+happens and their histograms count with no session; the engine counts the
+device run dry; a collection is a span and a count, and may start inside an
+observe; a slow pass leaves its line."""
 
+import gc
 import glob
 import os
+import threading
 import time
 
 import jax
@@ -21,6 +27,7 @@ from paddle_tpu.executor import Scope, scope_guard
 from paddle_tpu.models.gpt_decoder import GPTDecoder
 from paddle_tpu.observability import registry, tracing
 from paddle_tpu.serving import GenerationEngine, GenerationScheduler, GenRequest
+from paddle_tpu.serving import generation
 
 MODEL_KW = dict(
     vocab_size=24, n_layer=2, n_head=2, d_model=16, d_inner=32, max_context=32
@@ -109,6 +116,8 @@ def test_scheduler_phases_partition_the_iteration(tmp_path):
 
     by_iter = {}
     for s in prof.spans:
+        if s["name"] == "host.gc":
+            continue  # a collection belongs to no iteration
         assert "iter" in s["tags"], s
         by_iter.setdefault(s["tags"]["iter"], []).append(s)
     covered_share = []  # of each decoding pass
@@ -131,15 +140,17 @@ def test_scheduler_phases_partition_the_iteration(tmp_path):
         assert not any(s["name"] == "sched.idle" and inside(s, wrapper)
                        for s in group)
         held = {s["name"]: s for s in phases}
-        for leaf in (s for s in group if s["name"].startswith("engine.")):
+        for leaf in (s for s in group if "kind" in s["tags"]):
             outer = held["sched." + leaf["tags"]["kind"]]
             assert inside(leaf, outer), (leaf, outer)
+        for admit in (s for s in group if s["name"] == "engine.admit"):
+            assert inside(admit, held["sched.admit"])
         # a pass feeds and dispatches step n+1, then waits for and samples
         # step n (the host reads a decode step one step late): the first
         # decoding pass has no step to read, the last none to dispatch
         decode_leaves = [s["name"][len("engine."):] for s in sorted(
-            (s for s in group if s["name"].startswith("engine.")
-             and s["tags"]["kind"] == "decode"), key=lambda s: s["start"])]
+            (s for s in group if s["tags"].get("kind") == "decode"),
+            key=lambda s: s["start"])]
         assert decode_leaves in (
             [], list(ENGINE_LEAVES), list(ENGINE_LEAVES[:2]),
             list(ENGINE_LEAVES[2:])), decode_leaves
@@ -200,17 +211,19 @@ def test_engine_leaves_partition_a_decode_step_whatever_its_rows_fetch(tmp_path)
                              temperature=0.8, top_k=4, seed=3)),
     ]
     steps = 8
+    gc.disable()  # a collection between two leaves is in the step and in none
     try:
         with Session(tmp_path / "prof") as prof:
             for step in range(steps):
                 eng.iter = step + 1
                 eng.decode_step(runs if step % 2 else runs[:1])
     finally:
+        gc.enable()
         for r in runs:
             eng.finish(r)
     by_iter = {}
     for s in prof.spans:
-        if s["name"].startswith("engine.") and s["tags"]["kind"] == "decode":
+        if s["tags"].get("kind") == "decode":
             by_iter.setdefault(s["tags"]["iter"], []).append(s)
     assert len(by_iter) == steps
     for group in by_iter.values():
@@ -347,7 +360,7 @@ def test_executor_run_leaves_its_three_phases(tmp_path, kind):
     assert iters == list(range(iters[0], iters[0] + 3))  # the running count
     for r in runs:
         leaves = sorted((s for s in prof.spans
-                         if s["tags"]["iter"] == r["tags"]["iter"]
+                         if s["tags"].get("iter") == r["tags"]["iter"]
                          and s["name"] != "executor.run"),
                         key=lambda s: s["start"])
         assert [s["name"] for s in leaves] == [
@@ -367,9 +380,12 @@ def test_outputs_are_bit_identical_with_a_session_on_and_null_span_stays(tmp_pat
             eng.last_prefill_logits)
 
     off = generate()
+    assert profiler.watch_gc() in gc.callbacks  # the collection hook is on
     with Session(tmp_path / "prof") as prof:
         on = generate()
-    assert prof.named("engine.wait")  # the session did record the run
+    # the session did record the run, an admission's spans among the rest
+    assert prof.named("engine.wait") and prof.named("engine.admit")
+    assert prof.named("prefix.lookup") and prof.named("prefix.insert")
     assert on[0] == off[0]
     assert on[1].tobytes() == off[1].tobytes()
     assert on[2].tobytes() == off[2].tobytes()
@@ -482,3 +498,206 @@ def test_executor_memory_plan_is_the_last_run_s_memory_analysis():
                   "alias_size_in_bytes"):
         assert getattr(plan, field) >= 0
     assert plan.argument_size_in_bytes >= 8 * 8 * 4  # the feed alone
+
+
+# ---- the rare, long things: an admission, the device run dry, a collection,
+# a pass that stalls
+
+def hist(name):
+    snap = registry.default_registry().snapshot()[name]
+    return snap["count"], snap["sum"]
+
+
+def test_an_admission_leaves_its_spans_where_the_work_happens(tmp_path):
+    """fluid.engine.admit holds the prefix lookup; the last chunk's
+    fluid.engine.sample holds the insert, and the insert of a trie at its
+    cap the eviction pass."""
+    eng = make_engine("admitgen")
+    eng.prefix_cache.capacity_pages = 1  # the second prompt's page finds it full
+    runs = []
+    try:
+        with Session(tmp_path / "prof") as prof:
+            for i, first in enumerate((1, 7)):
+                eng.iter = i + 1
+                runs.append(eng.start(GenRequest(
+                    list(range(first, first + 6)), max_new_tokens=2,
+                    eos_id=NO_EOS)))
+    finally:
+        for r in runs:
+            eng.finish(r)
+    admits, lookups = prof.named("engine.admit"), prof.named("prefix.lookup")
+    assert len(admits) == len(lookups) == 2
+    for lookup, admit in zip(lookups, admits):
+        assert inside(lookup, admit)
+        assert lookup["tags"]["iter"] == admit["tags"]["iter"]
+    samples = [s for s in prof.named("engine.sample")
+               if s["tags"]["kind"] == "prefill"]
+    inserts = prof.named("prefix.insert")
+    assert len(samples) == len(inserts) == 2
+    for insert, sample in zip(inserts, samples):
+        assert inside(insert, sample)
+    (evict,) = prof.named("prefix.evict")  # the first insert found room
+    assert inside(evict, inserts[1])
+    assert [s["tags"]["iter"] for s in inserts] == [1, 2]
+
+
+def test_the_new_histograms_count_with_no_session(monkeypatch):
+    monkeypatch.setattr(generation, "SLOW_PASS_MS", 0.0)
+    eng = make_engine("raregen")
+    eng.prefix_cache.capacity_pages = 1
+    sched = GenerationScheduler(eng, timeout_ms=60000.0)
+    try:
+        for first in (1, 7, 13):
+            fut = sched.submit(list(range(first, first + 6)),
+                               max_new_tokens=5, eos_id=NO_EOS)
+            assert len(fut.result(60.0).tokens) == 5
+    finally:
+        sched.close()
+    gc.collect()
+    snap = registry.default_registry().snapshot()
+    prompts = 3
+    for name, least in (
+            ("gen_admit_ms", prompts), ("prefix_lookup_ms", prompts),
+            ("prefix_insert_ms", prompts), ("prefix_evict_ms", prompts - 1),
+            ("gen_prefill_wait_ms", prompts),
+            ("gen_prefill_sample_ms", prompts),
+            # each prompt's last chunk is read with nothing behind it
+            ("gen_device_starved_ms", prompts),
+            ("gen_dispatch_found_dry", prompts),
+            ("sched_slow_iter_ms", prompts)):
+        h = snap["serving/raregen/" + name]
+        assert h["count"] >= least and h["sum"] >= 0, (name, h)
+    assert snap["serving/raregen/gen_admit_ms"]["count"] == prompts
+    assert snap["serving/raregen/gen_dispatch_found_dry"]["buckets"] == [0, 1]
+    found = snap["serving/raregen/gen_dispatch_found_dry"]
+    assert 0 <= found["sum"] <= found["count"]
+    assert (snap["serving/raregen/sched_slow_iter_ms"]["count"]
+            == snap["serving/raregen/sched_iter_ms"]["count"])
+    assert snap["host/gc_pause_ms"]["count"] >= 1
+
+
+def test_the_engine_counts_the_device_run_dry_once_and_not_under_a_step():
+    """Dispatch, read, dispatch: gen_device_starved_ms observes at the
+    second dispatch, and only when the read was of the program dispatched
+    last; gen_dispatch_found_dry observes once a pipelined dispatch."""
+    eng = make_engine("drygen")
+    starved = lambda: hist("serving/drygen/gen_device_starved_ms")[0]
+    found = lambda: hist("serving/drygen/gen_dispatch_found_dry")[0]
+    # the prompt's one chunk is dispatched and read: the device has nothing
+    run = eng.start(GenRequest([1, 2, 3, 4, 5], max_new_tokens=8, eos_id=NO_EOS))
+    try:
+        assert (starved(), found()) == (0, 0)
+        eng.decode_step([run], ahead=True)  # step 1 out: the dry spell ends
+        assert (starved(), found()) == (1, 0)
+        assert eng.steps_in_flight == 1
+        # step 2 goes out before step 1 is read: a pipelined dispatch, and
+        # the read that follows is not of the program dispatched last
+        eng.decode_step([run], ahead=True)
+        eng.decode_step([run], ahead=True)
+        assert (starved(), found()) == (1, 2)
+        eng.drain("idle")  # step 3 read, none behind it: dry again
+        assert starved() == 1
+        eng.decode_step([run], ahead=True)
+        assert (starved(), found()) == (2, 2)
+        eng.drain("idle")
+    finally:
+        eng.finish(run)
+    h = registry.default_registry().snapshot()[
+        "serving/drygen/gen_device_starved_ms"]
+    assert h["count"] == 2 and h["sum"] > 0
+
+
+def test_a_collection_is_one_span_and_one_count_and_the_hook_installs_once(
+        tmp_path):
+    watch = profiler.watch_gc()
+    assert profiler.watch_gc() is watch
+    assert gc.callbacks.count(watch) == 1
+    gc.disable()  # no collection but the one asked for
+    try:
+        count, ms = hist("host/gc_pause_ms")
+        seen = watch.count
+        with Session(tmp_path / "prof") as prof:
+            gc.collect()
+        (span,) = prof.named("host.gc")
+        assert span["tags"]["generation"] == 2
+        assert watch.count == seen + 1 and watch.pending
+        after = hist("host/gc_pause_ms")  # the snapshot folds what is pending
+        assert after[0] == count + 1 and after[1] > ms
+        assert not watch.pending
+    finally:
+        gc.enable()
+
+
+class _CollectingLock:
+    """The registry's lock, with a collection forced while it is held: what
+    happens when the interpreter decides to collect inside an observe."""
+
+    def __init__(self, lock):
+        self.lock = lock
+
+    def __enter__(self):
+        self.lock.acquire()
+        gc.collect()
+
+    def __exit__(self, *exc):
+        self.lock.release()
+
+
+def test_a_collection_inside_an_observe_does_not_take_the_registry_s_lock():
+    watch = profiler.watch_gc()
+    reg = registry.default_registry()
+    h = reg.histogram("test/collected_inside_observe")
+    gc.disable()  # no collection but the one inside the observe
+    try:
+        h._lock = _CollectingLock(reg._lock)
+        seen, before = watch.count, hist("host/gc_pause_ms")[0]
+        t = threading.Thread(target=h.observe, args=(1.0,), daemon=True)
+        t.start()
+        t.join(30.0)
+        assert not t.is_alive()  # the callback did not wait for the lock
+        assert not reg._lock.locked()
+        assert watch.count == seen + 1
+        # the pause was kept aside, and a reader finds it folded
+        assert hist("host/gc_pause_ms")[0] == before + 1
+        assert h.count == 1
+    finally:
+        gc.enable()
+        reg.remove("test/collected_inside_observe")
+
+
+SLOW_PASS_FIELDS = (
+    "model", "iter", "ms", "lock_ms", "admit_ms", "prefill_ms", "decode_ms",
+    "retire_ms", "host_stall_ms", "gc_ms", "admitted", "chunks",
+    "prefill_wait_ms", "prefill_sample_ms", "decode_dispatch_ms",
+    "decode_wait_ms", "prefix_evict_ms", "live", "queued", "held_back")
+
+
+def test_a_slow_pass_leaves_one_line_and_holds_the_next_back(
+        monkeypatch, capsys):
+    monkeypatch.setattr(generation, "SLOW_PASS_MS", 0.0)  # every pass is slow
+    eng = make_engine("slowgen")
+    sched = GenerationScheduler(eng, timeout_ms=60000.0)
+    try:
+        fut = sched.submit([3, 1, 4, 1, 5], max_new_tokens=6, eos_id=NO_EOS)
+        assert len(fut.result(60.0).tokens) == 6
+    finally:
+        sched.close()
+    lines = [l for l in capsys.readouterr().err.splitlines()
+             if l.startswith("[fluid.slow_pass] ")]
+    records = [dict(kv.split("=", 1) for kv in l.split(" ")[1:]) for l in lines]
+    assert records and all(tuple(r) == SLOW_PASS_FIELDS for r in records)
+    first = records[0]
+    assert first["model"] == "slowgen" and first["held_back"] == "0"
+    # the first pass admitted the prompt and ran its one chunk
+    assert (first["iter"], first["admitted"], first["chunks"]) == ("1", "1", "1")
+    assert float(first["ms"]) >= float(first["prefill_ms"]) > 0
+    # the one chunk was the prompt's last: its read and its first token
+    assert float(first["prefill_ms"]) >= float(first["prefill_wait_ms"]) > 0
+    assert float(first["prefill_sample_ms"]) > 0
+    passes, _ = hist("serving/slowgen/sched_slow_iter_ms")
+    assert passes == hist("serving/slowgen/sched_iter_ms")[0] >= 6
+    # one line a second: every pass is counted, and each is either a line's
+    # own, one a later line says it held back, or still held
+    assert len(lines) < passes
+    assert passes == len(lines) + sched._slow_held_back + sum(
+        int(r["held_back"]) for r in records)
