@@ -260,8 +260,11 @@ def phase_train(cfg=TRAIN, n_steps=8, multistep=4):
     check(rel <= 1e-2,
           "train: first-step loss %.6f under training_fused is %.2e relative "
           "from %.6f with the passes off" % (losses[0], rel, ref["losses"][0]))
-    check(not ref["dispatches"],
-          "train: kernels dispatched with the passes off: %s" % ref["dispatches"])
+    # the flash tiers are the op's own lowering, not a pass's substitution
+    fused = {k: n for k, n in ref["dispatches"].items()
+             if not k.startswith("flash_")}
+    check(not fused,
+          "train: kernels dispatched with the passes off: %s" % fused)
     disp = run["dispatches"]
     for fam in ("layer_norm", "layer_norm_grad", "gemm_epilogue"):
         check(disp.get(fam, 0) > 0,

@@ -1398,6 +1398,8 @@ class _MultiStepBlock:
         self.mut_names = inner.mut_names
         self.zero1_axis = inner.zero1_axis
         self.zero1_state_names = inner.zero1_state_names
+        self._ro_sh = getattr(inner, "_ro_sh", None)
+        self._mut_sh = getattr(inner, "_mut_sh", None)
         self._feed_sharding = None
 
         def run_k(stacked_feeds, ro_state, mut_state, rng_key):

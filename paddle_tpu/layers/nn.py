@@ -1306,10 +1306,14 @@ def beam_search_decode(ids, scores, beam_size, end_id, name=None, parents=None):
     return sentence_ids, sentence_scores
 
 
-def flash_attention(q, k, v, causal=False, sm_scale=None, name=None):
-    """Fused blockwise attention over (b, h, t, d) tensors — emits the
-    Pallas flash-attention op (ops/pallas_kernels.py), the hand-tuned-kernel
-    tier analog of the reference's math/jit_kernel fused primitives."""
+def flash_attention(q, k, v, causal=False, sm_scale=None, n_head=None,
+                    name=None):
+    """Fused blockwise attention — emits the Pallas flash-attention op
+    (ops/pallas_kernels.py), the hand-tuned-kernel tier analog of the
+    reference's math/jit_kernel fused primitives. With n_head, over
+    [b, t, h·d] tensors, the heads side by side on the last axis as a
+    projection leaves them (no head split before and no merge after);
+    without, over (b, h, t, d). Returns the context in q's layout."""
     from ..ops.pallas_kernels import flash_path_taken
 
     helper = LayerHelper("flash_attention", name=name)
@@ -1317,13 +1321,16 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, name=None):
     attrs = {"causal": bool(causal)}
     if sm_scale is not None:
         attrs["sm_scale"] = float(sm_scale)
+    if n_head is not None:
+        attrs["n_head"] = int(n_head)
     outputs = {"Out": [out.name]}
     # declare the logsumexp residual exactly when the static shapes make the
     # lowering take the Pallas path (flash_path_taken is that decision's
     # mirror), so flash_attention_grad consumes the saved residual instead
     # of re-running the forward inside jax.vjp (see _flash_attention_op)
-    tq = q.shape[2] if q.shape is not None and len(q.shape) == 4 else -1
-    tk = k.shape[2] if k.shape is not None and len(k.shape) == 4 else -1
+    rank, t_axis = (3, 1) if n_head is not None else (4, 2)
+    tq = q.shape[t_axis] if q.shape is not None and len(q.shape) == rank else -1
+    tk = k.shape[t_axis] if k.shape is not None and len(k.shape) == rank else -1
     if flash_path_taken(tq, tk, causal=bool(causal)):
         lse = helper.create_variable_for_type_inference("float32")
         lse.stop_gradient = True

@@ -32,17 +32,27 @@ def multi_head_attention(
     queries, keys, values, attn_bias, d_key, d_value, d_model, n_head, dropout_rate,
     use_flash=False, causal=False,
 ):
-    """With use_flash=True and no additive bias the score→softmax→context
-    chain is emitted as ONE flash_attention op — the Pallas blockwise kernel
-    (ops/pallas_kernels.py), ~2x faster than the dense chain at t=4096 on
-    TPU and O(t) in attention memory. `causal` replaces a triangular
-    attn_bias; it is honored on the dense path too."""
+    """With use_flash=True, no additive bias and d_key == d_value, the head
+    split, the score→softmax→context chain and the head merge are emitted
+    as ONE flash_attention op on the projections' [b, t, h·d] outputs — the
+    Pallas blockwise kernel (ops/pallas_kernels.py), ~2x faster than the
+    dense chain at t=4096 on TPU and O(t) in attention memory. `causal`
+    replaces a triangular attn_bias; it is honored on the dense path too."""
     q = layers.fc(queries, size=d_key * n_head, num_flatten_dims=2, bias_attr=False)
     k = layers.fc(keys, size=d_key * n_head, num_flatten_dims=2, bias_attr=False)
     v = layers.fc(values, size=d_value * n_head, num_flatten_dims=2, bias_attr=False)
 
+    if use_flash and attn_bias is None and d_key == d_value:
+        # the kernel reads the heads where the projections leave them, side
+        # by side on the last axis, and writes the context so: no reshape or
+        # transpose2 on either side of it. Attention-weight dropout has no
+        # home inside the fused kernel; it is skipped here like every
+        # production flash-attention integration
+        ctx = layers.flash_attention(
+            q, k, v, causal=causal, sm_scale=d_key ** -0.5, n_head=n_head)
+        return layers.fc(ctx, size=d_model, num_flatten_dims=2, bias_attr=False)
+
     def split_heads(x, d):
-        b_t = x.shape
         reshaped = layers.reshape(x, [0, 0, n_head, d])
         return layers.transpose(reshaped, [0, 2, 1, 3])  # (b, n, t, d)
 
@@ -50,27 +60,22 @@ def multi_head_attention(
     k = split_heads(k, d_key)
     v = split_heads(v, d_value)
 
-    if use_flash and attn_bias is None:
-        # attention-weight dropout has no home inside the fused kernel; it is
-        # skipped here like every production flash-attention integration
-        ctx = layers.flash_attention(q, k, v, causal=causal, sm_scale=d_key ** -0.5)
-    else:
-        scores = layers.matmul(q, k, transpose_y=True, alpha=d_key ** -0.5)
-        if attn_bias is not None:
-            scores = layers.elementwise_add(scores, attn_bias)
-        if causal:
-            # the dense path must honor causal too, or a fallback would
-            # silently leak future positions
-            t_q, t_k = scores.shape[-2], scores.shape[-1]
-            tri = np.triu(np.full((t_q, t_k), -1e9, "float32"), k=1 + t_k - t_q)
-            causal_bias = layers.assign(tri)
-            scores = layers.elementwise_add(scores, causal_bias)
-        weights = layers.softmax(scores)
-        if dropout_rate:
-            weights = layers.dropout(
-                weights, dropout_prob=dropout_rate, dropout_implementation="upscale_in_train"
-            )
-        ctx = layers.matmul(weights, v)  # (b, n, tq, dv)
+    scores = layers.matmul(q, k, transpose_y=True, alpha=d_key ** -0.5)
+    if attn_bias is not None:
+        scores = layers.elementwise_add(scores, attn_bias)
+    if causal:
+        # the dense path must honor causal too, or a fallback would
+        # silently leak future positions
+        t_q, t_k = scores.shape[-2], scores.shape[-1]
+        tri = np.triu(np.full((t_q, t_k), -1e9, "float32"), k=1 + t_k - t_q)
+        causal_bias = layers.assign(tri)
+        scores = layers.elementwise_add(scores, causal_bias)
+    weights = layers.softmax(scores)
+    if dropout_rate:
+        weights = layers.dropout(
+            weights, dropout_prob=dropout_rate, dropout_implementation="upscale_in_train"
+        )
+    ctx = layers.matmul(weights, v)  # (b, n, tq, dv)
     ctx = layers.transpose(ctx, [0, 2, 1, 3])
     ctx = layers.reshape(ctx, [0, 0, d_value * n_head])
     return layers.fc(ctx, size=d_model, num_flatten_dims=2, bias_attr=False)

@@ -10,7 +10,11 @@ saving only the per-row logsumexp) and a fused flash-attention-2 style
 backward — one kernel per K block computing dK, dV, and dQ partials, so the
 score matrix and dO·Vᵀ are built once instead of twice (the classic
 two-kernel split recomputes both; measured 2.4 -> 1.56 ms per fwd+grad at
-t=1024 on chip). Long-context shapes stream the non-resident side through
+t=1024 on chip). Both read q, k, v and dO as [b, t, h·d], the layout a
+projection leaves the heads in, a 128-lane block of the last axis a program
+(two 64-wide heads, or one of 128: docs/flash_attention.md); rank-4
+(b, h, t, d) callers run the same bodies over the (b·h, t, d) view.
+Long-context shapes stream the non-resident side through
 the grid (separate dQ / dKV kernels there, where VMEM residency is the
 binding constraint, not flop count). Ragged tile shapes fall back to the
 dense form in both directions (a trace-time decision).
@@ -39,6 +43,7 @@ __all__ = [
     "flash_attention",
     "flash_tiles_ok",
     "flash_path_taken",
+    "flash_tier",
     "gemm_bias_act",
     "gemm_path_taken",
     "gemm_dbuf_path_taken",
@@ -134,100 +139,197 @@ def _attention_reference(q, k, v, causal, sm_scale):
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref=None, *, block_k, causal,
-                  sm_scale, q_block_idx_axis, t_q_total, lse_packed=True):
-    """One (batch*head, q_block) program: stream KV blocks with the online
-    softmax recurrence (m = running max, l = running sum, acc = running PV)."""
-    qi = pl.program_id(q_block_idx_axis)
+def _heads_a_block(n_head, d):
+    """Heads that share one block of the last axis of a [b, t, h·d] operand:
+    the one parameter the flash kernels adapt to. A block is one 128-lane
+    vector wide, so two 64-wide heads stand in it side by side (four of 32);
+    a head whose width is whole vectors is a block of its own, and so is the
+    whole axis where there is one head (the (b·h, t, d) view the rank-4
+    callers are run through). 0: the heads cannot be blocked (d_head 96, an
+    odd head count at 64) and the caller splits them first."""
+    if n_head == 1 or d % _LANES == 0:
+        return 1
+    per = _LANES // d
+    return per if _LANES % d == 0 and n_head % per == 0 else 0
+
+
+def _own_lanes(x, j, d_head, other=0):
+    """(rows, W) x with every column outside head j's d_head set to `other`:
+    how a head of a shared block reads and writes through full-width vectors.
+    A contraction over W lanes of an operand so zeroed is the head's own
+    d_head-wide one, at the MXU passes a d_head-wide tile padded to a lane
+    costs anyway. The heads of a block are unrolled (j is static): their
+    bodies are independent, and the scheduler runs one head's softmax under
+    the other's products."""
+    if x.shape[1] == d_head:
+        return x
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    mine = (lane >= j * d_head) & (lane < (j + 1) * d_head)
+    return jnp.where(mine, x, jnp.full_like(x, other))
+
+
+def _sum_own_lanes(xs, d_head):
+    """Head j's columns of xs[j], side by side: the block's result."""
+    out = _own_lanes(xs[0], 0, d_head)
+    for j in range(1, len(xs)):
+        out = out + _own_lanes(xs[j], j, d_head)
+    return out
+
+
+def _scores(q, k_blk, sm_scale, causal, q_first, k_first, offset):
+    """(block_q, block_k) f32 scores of one head, causal-masked with the
+    bottom-right alignment of _attention_reference's tril(k=tk-tq): query
+    row i may see keys up to i + offset."""
+    s = jax.lax.dot_general(
+        q, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * sm_scale
+    if causal:
+        q_pos = q_first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        k_pos = k_first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(q_pos + offset >= k_pos, s, -jnp.inf)
+    return s
+
+
+def _softmax_step(s, m_prev, l_prev):
+    """One online-softmax step over a block of scores: (p, alpha, m, l)."""
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+    # rows fully masked so far (m still -inf) must not poison the step with
+    # exp(-inf + inf): against 0 their scores and their m_prev give exp(-inf)
+    m_safe = jnp.where(m_new == -jnp.inf, 0.0, m_new)
+    alpha = jnp.exp(m_prev - m_safe)
+    p = jnp.exp(s - m_safe[:, None])
+    return p, alpha, m_new, l_prev * alpha + jnp.sum(p, axis=1)
+
+
+def _saved_probs(s, lse):
+    """The forward's probabilities again, from the saved logsumexp (finite
+    in every row, see _lse_of: a masked score gives exp(-inf) = 0)."""
+    return jnp.exp(s - lse[:, None])
+
+
+def _lse_of(m, l):
+    # fully-masked rows get a finite sentinel; their p = exp(-inf - lse) is 0
+    return jnp.where(m == -jnp.inf, 0.0, m + jnp.log(jnp.maximum(l, 1e-20)))
+
+
+def _store_lse(lse_ref, j, q_first, lse, t_q, packed):
+    """The logsumexp residual of head j of the block. PACKED: one
+    (1, heads · t_q) lane-major row a block, head j's t_q values from lane
+    j · t_q — the earlier 128-lane broadcast layout cost ~67 MB of HBM
+    write+read per bench attention layer where this is ~0.5 MB. A t_q that
+    is not whole lanes keeps the broadcast layout: Mosaic cannot vector-store
+    partial lanes."""
+    if packed:
+        # (block_q,) comes out of the row reductions one value a sublane row;
+        # the row wants it one value a lane. Mosaic's own relayout is XLU
+        # transposes, ~2.6 us a program here and in the open (measured on
+        # chip, PR 37: the forward 0.45 -> 0.62 ms with the lse); picking the
+        # diagonal of each 128 x 128 lane-broadcast square with a sublane sum
+        # is a few hundred VPU operations, and exact (one term is not 0)
+        n = lse.shape[0] // _LANES
+        square = jnp.broadcast_to(
+            lse[:, None], (lse.shape[0], _LANES)).reshape(n, _LANES, _LANES)
+        diagonal = (jax.lax.broadcasted_iota(jnp.int32, square.shape, 1)
+                    == jax.lax.broadcasted_iota(jnp.int32, square.shape, 2))
+        rows = jnp.sum(jnp.where(diagonal, square, 0.0), axis=1)
+        for r in range(n):
+            start = pl.multiple_of(j * t_q + q_first + r * _LANES, _LANES)
+            lse_ref[0, pl.ds(start, _LANES)] = rows[r].astype(lse_ref.dtype)
+    else:
+        lse_ref[j] = jnp.broadcast_to(
+            lse[:, None], lse_ref.shape[1:]
+        ).astype(lse_ref.dtype)
+
+
+def _load_rows(ref, j, q_first, block_q, t_q, packed):
+    """Head j's (block_q,) f32 rows of an lse-layout ref (see _store_lse);
+    the unpacked layout is per-q-block in the streamed kernels (q_first
+    None) and whole-t in the fused one."""
+    if packed:
+        return ref[0, pl.ds(j * t_q + q_first, block_q)].astype(jnp.float32)
+    if q_first is None:
+        return ref[j][..., 0].astype(jnp.float32)
+    return ref[j, pl.ds(q_first, block_q), 0].astype(jnp.float32)
+
+
+def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref=None, *, d_head, block_k,
+                  causal, sm_scale, t_q_total, lse_packed=True):
+    """One (batch, head block, q block) program over blocks of [b, t, h·d]
+    operands, the layout the projections leave them in: q and o are
+    (block_q, W), k and v (t_k, W), W lanes holding W // d_head heads. Each
+    head streams the KV blocks with the online softmax recurrence (m =
+    running max, l = running sum, acc = running PV) and keeps its own columns
+    of the result."""
+    qi = pl.program_id(2)
+    block_q, width = q_ref.shape
+    heads = width // d_head
+    t_k = k_ref.shape[0]
+    nk = pl.cdiv(t_k, block_k)
+    offset = t_k - t_q_total
     # operands stay in their native dtype (bf16 on the train path): the MXU
     # multiplies bf16 pairs at full rate and accumulates f32 via
     # preferred_element_type — upcasting to f32 FIRST forces the multi-pass
-    # f32 MXU emulation at a fraction of peak (measured: the whole fwd
-    # kernel 131 -> 178 TF/s from this change alone)
-    q = q_ref[...]  # (block_q, d)
-    block_q = q.shape[0]
-    t_k = k_ref.shape[0]
-    nk = pl.cdiv(t_k, block_k)
+    # f32 MXU emulation at a fraction of peak (measured: the whole fwd kernel
+    # 131 -> 178 TF/s from this change alone)
+    q = q_ref[...]
+    qs = [_own_lanes(q, j, d_head) for j in range(heads)]
 
     def body(ki, carry):
-        acc, m_prev, l_prev = carry
         k_blk = k_ref[pl.ds(ki * block_k, block_k), :]
         v_blk = v_ref[pl.ds(ki * block_k, block_k), :]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * sm_scale  # (block_q, block_k)
-        if causal:
-            # bottom-right alignment (same contract as _attention_reference's
-            # tril(k=tk-tq)): query row i may see keys up to i + (tk - tq)
-            offset = t_k - t_q_total
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
+        new = []
+        for q_j, (acc, m_prev, l_prev) in zip(qs, carry):
+            s = _scores(q_j, k_blk, sm_scale, causal, qi * block_q,
+                        ki * block_k, offset)
+            p, alpha, m_new, l_new = _softmax_step(s, m_prev, l_prev)
+            # p rounds to v's dtype for the PV dot — the same rounding the
+            # dense XLA chain applies (probs.astype(q.dtype) in
+            # _attention_reference)
+            acc = acc * alpha[:, None] + jax.lax.dot_general(
+                p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
             )
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
-            s = jnp.where(q_pos + offset >= k_pos, s, -jnp.inf)
-        m_cur = jnp.max(s, axis=1)
-        m_new = jnp.maximum(m_prev, m_cur)
-        # -inf rows (fully masked so far) must not poison the rescale
-        alpha = jnp.exp(jnp.where(m_prev == -jnp.inf, -jnp.inf, m_prev - m_new))
-        p = jnp.exp(s - m_new[:, None])
-        p = jnp.where(jnp.isfinite(s), p, 0.0)
-        l_new = l_prev * alpha + jnp.sum(p, axis=1)
-        # p rounds to v's dtype for the PV dot — the same rounding the dense
-        # XLA chain applies (probs.astype(q.dtype) in _attention_reference)
-        acc = acc * alpha[:, None] + jax.lax.dot_general(
-            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        return acc, m_new, l_new
+            new.append((acc, m_new, l_new))
+        return tuple(new)
 
-    d = q.shape[1]
     init = (
-        jnp.zeros((block_q, d), jnp.float32),
+        jnp.zeros((block_q, width), jnp.float32),
         jnp.full((block_q,), -jnp.inf, jnp.float32),
         jnp.zeros((block_q,), jnp.float32),
     )
     if causal:
         # only KV blocks reaching this q block's last visible key contribute
-        last_key = qi * block_q + block_q - 1 + (t_k - t_q_total)
+        last_key = qi * block_q + block_q - 1 + offset
         nk_needed = jnp.clip((last_key + block_k) // block_k, 0, nk)
     else:
         nk_needed = nk
-    acc, m, l = jax.lax.fori_loop(0, nk_needed, body, init)
-    o_ref[...] = (acc / jnp.maximum(l, 1e-20)[:, None]).astype(o_ref.dtype)
+    state = jax.lax.fori_loop(0, nk_needed, body, (init,) * heads)
     if lse_ref is not None:
-        # logsumexp residual for the flash backward, PACKED as a (1, t_q)
-        # lane-major row per (b*h) — the earlier 128-lane broadcast layout
-        # cost ~67 MB of HBM write+read per bench attention layer where
-        # this is ~0.5 MB (the relayout from the row-reduction's sublane
-        # vector is a cheap in-register transpose). Fully-masked rows get
-        # a finite sentinel; their p = exp(-inf - lse) is 0 either way.
-        lse = jnp.where(m == -jnp.inf, 0.0, m + jnp.log(jnp.maximum(l, 1e-20)))
-        if lse_packed:
-            lse_ref[0, pl.ds(qi * block_q, block_q)] = lse.astype(lse_ref.dtype)
-        else:
-            # sub-128-lane t: Mosaic cannot vector-store partial lanes, so
-            # tiny shapes keep the 128-lane broadcast residual layout
-            lse_ref[...] = jnp.broadcast_to(
-                lse[:, None], lse_ref.shape
-            ).astype(lse_ref.dtype)
+        for j, (_, m, l) in enumerate(state):
+            _store_lse(lse_ref, j, qi * block_q, _lse_of(m, l), t_q_total,
+                       lse_packed)
+    o_ref[...] = _sum_own_lanes(
+        [acc / jnp.maximum(l, 1e-20)[:, None] for acc, _, l in state], d_head
+    ).astype(o_ref.dtype)
 
 
 def _flash_kernel_streamed(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
-                           m_ref, l_ref, *, causal, sm_scale, t_q_total,
-                           t_k_total, with_lse, lse_packed=True):
-    """Long-context forward: grid (bh, q_blocks, k_blocks) with K/V streamed
-    through the innermost grid dim, so VMEM holds one (block_q, d) query tile
-    plus one (block_k, d) K/V tile regardless of t — the whole-KV-resident
-    kernel above overflows VMEM past ~8k tokens (see _resident_ok). The
-    online-softmax state (acc, m, l) lives in f32 VMEM scratch across the
-    k-block sweep; the output tile is written on the last k step."""
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
-    block_q = q_ref.shape[0]
+                           m_ref, l_ref, *, d_head, causal, sm_scale,
+                           t_q_total, t_k_total, with_lse, lse_packed=True):
+    """Long-context forward: grid (b, head blocks, q_blocks, k_blocks) with
+    K/V streamed through the innermost grid dim, so VMEM holds one
+    (block_q, W) query tile plus one (block_k, W) K/V tile regardless of t —
+    the whole-KV-resident kernel above overflows VMEM past ~8k tokens (see
+    _resident_ok). The online-softmax state lives in f32 VMEM scratch across
+    the k-block sweep (acc for the whole block, m and l a head); the output
+    tile is written on the last k step."""
+    qi = pl.program_id(2)
+    ki = pl.program_id(3)
+    nk = pl.num_programs(3)
+    block_q, width = q_ref.shape
     block_k = k_ref.shape[0]
+    heads = width // d_head
+    offset = t_k_total - t_q_total
 
     @pl.when(ki == 0)
     def _init():
@@ -236,7 +338,6 @@ def _flash_kernel_streamed(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
 
     if causal:
-        offset = t_k_total - t_q_total
         needed = ki * block_k <= qi * block_q + block_q - 1 + offset
     else:
         needed = qi >= 0  # trivially true, keeps pl.when uniform
@@ -246,107 +347,136 @@ def _flash_kernel_streamed(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
         q = q_ref[...]
         k_blk = k_ref[...]
         v_blk = v_ref[...]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * sm_scale
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
+        for j in range(heads):
+            s = _scores(_own_lanes(q, j, d_head), k_blk, sm_scale, causal,
+                        qi * block_q, ki * block_k, offset)
+            p, alpha, m_new, l_new = _softmax_step(
+                s, m_ref[j][..., 0], l_ref[j][..., 0]
             )
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
+            pv = jax.lax.dot_general(
+                p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
             )
-            s = jnp.where(q_pos + (t_k_total - t_q_total) >= k_pos, s, -jnp.inf)
-        m_prev = m_ref[..., 0]
-        l_prev = l_ref[..., 0]
-        m_cur = jnp.max(s, axis=1)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(jnp.where(m_prev == -jnp.inf, -jnp.inf, m_prev - m_new))
-        p = jnp.exp(s - m_new[:, None])
-        p = jnp.where(jnp.isfinite(s), p, 0.0)
-        l_new = l_prev * alpha + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
+            # the other heads' columns of acc keep their own scale
+            scale = jnp.broadcast_to(alpha[:, None], pv.shape)
+            acc_ref[...] = acc_ref[...] * _own_lanes(
+                scale, j, d_head, other=1
+            ) + _own_lanes(pv, j, d_head)
+            m_ref[j] = jnp.broadcast_to(m_new[:, None], m_ref.shape[1:])
+            l_ref[j] = jnp.broadcast_to(l_new[:, None], l_ref.shape[1:])
 
     @pl.when(ki == nk - 1)
     def _finish():
-        m = m_ref[..., 0]
-        l = l_ref[..., 0]
-        o_ref[...] = (acc_ref[...] / jnp.maximum(l, 1e-20)[:, None]).astype(
-            o_ref.dtype
-        )
-        if with_lse:
-            lse = jnp.where(m == -jnp.inf, 0.0, m + jnp.log(jnp.maximum(l, 1e-20)))
-            if lse_packed:
-                lse_ref[0, pl.ds(qi * block_q, block_q)] = lse.astype(
-                    lse_ref.dtype
-                )
-            else:  # sub-128-lane t_q: see _flash_kernel's note
-                lse_ref[...] = jnp.broadcast_to(
-                    lse[:, None], lse_ref.shape
-                ).astype(lse_ref.dtype)
-
-
-def _flash_forward_streamed(q3, k3, v3, causal, sm_scale, block_q, block_k,
-                            interpret, with_lse, out_dtype):
-    bh, tq, d = q3.shape
-    tk = k3.shape[1]
-    grid = (bh, tq // block_q, tk // block_k)
-    packed = tq % _LANES == 0
-    out_shapes = [jax.ShapeDtypeStruct((bh, tq, d), out_dtype)]
-    out_specs = [pl.BlockSpec((None, block_q, d), lambda bh, qi, ki: (bh, qi, 0))]
-    if with_lse:
-        if packed:
-            out_shapes.append(jax.ShapeDtypeStruct((bh, 1, tq), jnp.float32))
-            out_specs.append(
-                pl.BlockSpec((None, 1, tq), lambda bh, qi, ki: (bh, 0, 0))
-            )
-        else:
-            out_shapes.append(
-                jax.ShapeDtypeStruct((bh, tq, _LANES), jnp.float32)
-            )
-            out_specs.append(
-                pl.BlockSpec(
-                    (None, block_q, _LANES), lambda bh, qi, ki: (bh, qi, 0)
-                )
-            )
-    kernel = functools.partial(
-        _flash_kernel_streamed,
-        causal=causal,
-        sm_scale=sm_scale,
-        t_q_total=tq,
-        t_k_total=tk,
-        with_lse=with_lse,
-        lse_packed=packed,
-    )
-    if not with_lse:
-        kernel = functools.partial(_no_lse_adapter, kernel)
-    res = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((None, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((None, block_k, d), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((None, block_k, d), lambda bh, qi, ki: (bh, ki, 0)),
-        ],
-        out_specs=out_specs if with_lse else out_specs[0],
-        out_shape=out_shapes if with_lse else out_shapes[0],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-        ],
-        interpret=interpret,
-    )(q3, k3, v3)
-    return res
+        outs = []
+        for j in range(heads):
+            m = m_ref[j][..., 0]
+            l = l_ref[j][..., 0]
+            if with_lse:
+                _store_lse(lse_ref, j, qi * block_q, _lse_of(m, l), t_q_total,
+                       lse_packed)
+            outs.append(acc_ref[...] / jnp.maximum(l, 1e-20)[:, None])
+        o_ref[...] = _sum_own_lanes(outs, d_head).astype(o_ref.dtype)
 
 
 def _no_lse_adapter(kernel, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref):
     kernel(q_ref, k_ref, v_ref, o_ref, None, acc_ref, m_ref, l_ref)
+
+
+def _lse_shape_spec(b, n_blocks, heads, tq, block_q, q_index):
+    """(shape, BlockSpec) of the kernels' logsumexp layout, see _store_lse.
+    q_index(*grid ids) -> the q block, or None for the whole t_q."""
+    if tq % _LANES == 0:
+        return (b, n_blocks, 1, heads * tq), pl.BlockSpec(
+            (None, None, 1, heads * tq), lambda bi, hi, *rest: (bi, hi, 0, 0))
+    shape = (b, n_blocks, heads, tq, _LANES)
+    if q_index is None:
+        return shape, pl.BlockSpec(
+            (None, None, heads, tq, _LANES),
+            lambda bi, hi, *rest: (bi, hi, 0, 0, 0))
+    return shape, pl.BlockSpec(
+        (None, None, heads, block_q, _LANES),
+        lambda bi, hi, *rest: (bi, hi, 0, q_index(*rest), 0),
+    )
+
+
+def _lse_to_kernel(x, heads):
+    """(b, h, tq) f32 -> the kernels' layout (see _store_lse)."""
+    b, h, tq = x.shape
+    if tq % _LANES == 0:
+        return x.reshape(b, h // heads, 1, heads * tq)
+    return jnp.broadcast_to(
+        x.reshape(b, h // heads, heads, tq, 1),
+        (b, h // heads, heads, tq, _LANES),
+    )
+
+
+def _lse_from_kernel(x, tq):
+    """The kernels' logsumexp layout -> (b, h, tq)."""
+    if x.ndim == 4:  # packed: (b, blocks, 1, heads · tq)
+        return x.reshape(x.shape[0], -1, tq)
+    return x[..., 0].reshape(x.shape[0], -1, tq)
+
+
+def _flash_fwd_call(q, k, v, n_head, heads, causal, sm_scale, blocks,
+                    interpret, with_lse):
+    """The forward kernels over [b, t, h·d] operands whose last axis blocks
+    into `heads` heads a block (_heads_a_block > 0; blocks from
+    _flash_blocks): out, or (out, lse (b, h, tq))."""
+    block_q, block_k, streamed = blocks
+    b, tq, f = q.shape
+    tk = k.shape[1]
+    d = f // n_head
+    width = heads * d
+    n_blocks = n_head // heads
+    if streamed:
+        # long-context tier: stream K/V through the grid instead of holding
+        # them whole in VMEM
+        grid = (b, n_blocks, tq // block_q, tk // block_k)
+        q_spec = pl.BlockSpec(
+            (None, block_q, width), lambda bi, hi, qi, ki: (bi, qi, hi))
+        k_spec = pl.BlockSpec(
+            (None, block_k, width), lambda bi, hi, qi, ki: (bi, ki, hi))
+        kernel = functools.partial(
+            _flash_kernel_streamed, d_head=d, causal=causal,
+            sm_scale=sm_scale, t_q_total=tq, t_k_total=tk, with_lse=with_lse,
+            lse_packed=tq % _LANES == 0,
+        )
+        if not with_lse:
+            kernel = functools.partial(_no_lse_adapter, kernel)
+        scratch = [
+            pltpu.VMEM((block_q, width), jnp.float32),
+            pltpu.VMEM((heads, block_q, _LANES), jnp.float32),
+            pltpu.VMEM((heads, block_q, _LANES), jnp.float32),
+        ]
+    else:
+        grid = (b, n_blocks, tq // block_q)
+        q_spec = pl.BlockSpec(
+            (None, block_q, width), lambda bi, hi, qi: (bi, qi, hi))
+        k_spec = pl.BlockSpec((None, tk, width), lambda bi, hi, qi: (bi, 0, hi))
+        kernel = functools.partial(
+            _flash_kernel, d_head=d, block_k=block_k, causal=causal,
+            sm_scale=sm_scale, t_q_total=tq, lse_packed=tq % _LANES == 0,
+        )
+        scratch = []
+    out_shapes = [jax.ShapeDtypeStruct((b, tq, f), q.dtype)]
+    out_specs = [q_spec]
+    if with_lse:
+        shape, spec = _lse_shape_spec(
+            b, n_blocks, heads, tq, block_q, lambda qi, *ki: qi)
+        out_shapes.append(jax.ShapeDtypeStruct(shape, jnp.float32))
+        out_specs.append(spec)
+    res = pl.pallas_call(
+        kernel,
+        grid=grid,
+        in_specs=[q_spec, k_spec, k_spec],
+        out_specs=out_specs if with_lse else out_specs[0],
+        out_shape=out_shapes if with_lse else out_shapes[0],
+        scratch_shapes=scratch,
+        interpret=interpret,
+    )(q, k, v)
+    if with_lse:
+        return res[0], _lse_from_kernel(res[1], tq)
+    return res
 
 
 def flash_tiles_ok(t, block=None):
@@ -364,7 +494,7 @@ def flash_tiles_ok(t, block=None):
 
 
 def flash_path_taken(tq, tk, causal=False, block_q=None, block_k=None):
-    """EXACT mirror of _flash_forward's pallas-vs-dense decision, for code
+    """EXACT mirror of the flash forward's pallas-vs-dense decision, for code
     that must predict it from static shapes (layers.flash_attention decides
     whether to declare the Lse output with this — a mismatch would either
     dangle a declared var or silently drop the saved residual and force the
@@ -375,238 +505,262 @@ def flash_path_taken(tq, tk, causal=False, block_q=None, block_k=None):
     return _auto_block(tq, bq) > 0 and _auto_block(tk, bk) > 0
 
 
+def _flash_blocks(tq, tk, width, itemsize, causal, block_q, block_k,
+                  backward=False):
+    """(block_q, block_k, streamed) as the kernels take them; blocks of 0
+    for ragged tiles. Streamed: a side is too long to sit whole in VMEM. The
+    fused backward needs both sides resident (breaks past ~8k tokens) and
+    materializes an (nk, tq, W) dQ-partials HBM temporary, bounded to <= 2x
+    dQ by the nk cap here; everything bigger takes the grid-streamed tier
+    (any t, O(t) memory), whose optimum is larger tiles (the gate passes on
+    the resident targets, and the stream targets only widen it)."""
+    bq, bk = _resolve_blocks(block_q, block_k, causal)
+    bq, bk = _auto_block(tq, bq), _auto_block(tk, bk)
+    if not (bq and bk):
+        return 0, 0, False
+    streamed = not _resident_ok(tk, width, itemsize)
+    if backward:
+        streamed = (streamed or tk // bk > 2
+                    or not _resident_ok(tq, width, itemsize))
+    if streamed:
+        return (_auto_block(tq, block_q or _DEF_STREAM_BLOCK),
+                _auto_block(tk, block_k or _DEF_STREAM_BLOCK), True)
+    if max(tq, tk) >= 4096:
+        # the (1024, block_k) f32 score/probability temporaries + resident
+        # slabs (K/V with tk; q/do/o with tq in the fused backward) overflow
+        # VMEM once EITHER side reaches t=4096 (compile-checked on chip,
+        # including asymmetric tq=1024/tk=4096); 512 holds through 8192
+        bq = min(bq, 512)
+    return bq, bk, False
+
+
+FLASH_DENSE = "flash_dense"
+FLASH_SPLIT = "flash_split_heads"
+
+
+def flash_tier(tq, tk, n_head, d, causal=False, block_q=None, block_k=None):
+    """(KERNEL_DISPATCHES key, heads a block the kernels run with) of the
+    tier a [b, t, h·d] flash call takes, from static shapes alone:
+    "flash_heads_a_block:2" (64-wide heads, two a 128-lane block; ":4" at
+    32), "flash_heads_a_block:1" (a head of whole lanes, or one head),
+    "flash_split_heads" where the last axis cannot be blocked so (d_head 96,
+    an odd head count at 64, a t_q under a lane): the heads are split into
+    (b·h, t, d), run one a block and merged, the two transposes the packed
+    tiers exist to avoid; "flash_dense" for ragged tiles."""
+    if not flash_path_taken(tq, tk, causal, block_q, block_k):
+        return FLASH_DENSE, 0
+    heads = _heads_a_block(n_head, d)
+    if not heads or (n_head > 1 and tq % _LANES):
+        return FLASH_SPLIT, 1
+    return "flash_heads_a_block:%d" % heads, heads
+
+
+def _split_heads(x, n_head):
+    """[b, t, h·d] -> (b·h, t, d), one head a row of the batch."""
+    b, t, f = x.shape
+    return x.reshape(b, t, n_head, f // n_head).transpose(0, 2, 1, 3).reshape(
+        b * n_head, t, f // n_head)
+
+
+def _merge_heads(x, n_head):
+    """(b·h, t, d) -> [b, t, h·d]."""
+    bh, t, d = x.shape
+    return x.reshape(bh // n_head, n_head, t, d).transpose(0, 2, 1, 3).reshape(
+        bh // n_head, t, n_head * d)
+
+
+def _dense(q, k, v, n_head, causal, sm_scale):
+    """_attention_reference over either layout: [b, t, h·d] operands with
+    n_head, (b, h, t, d) without."""
+    if n_head is None:
+        return _attention_reference(q, k, v, causal, sm_scale)
+    b = q.shape[0]
+
+    def four(x):
+        return _split_heads(x, n_head).reshape(b, n_head, x.shape[1], -1)
+
+    out = _attention_reference(four(q), four(k), four(v), causal, sm_scale)
+    return _merge_heads(out.reshape((-1,) + out.shape[2:]), n_head)
+
+
 def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
-                   with_lse=False):
-    b, h, tq, d = q.shape
-    tk = k.shape[2]
-    raw_bq, raw_bk = block_q, block_k
-    block_q, block_k = _resolve_blocks(block_q, block_k, causal)
-    block_q = _auto_block(tq, block_q)
-    block_k = _auto_block(tk, block_k)
-    if not (block_q and block_k):
+                   with_lse=False, n_head=None):
+    """Flash forward. With n_head over [b, t, h·d] operands, the heads side
+    by side on the last axis: out [b, tq, h·d], with_lse also lse (b, h, tq)
+    (None on the dense fallback); picks the tier from the shapes and says
+    which in KERNEL_DISPATCHES. Without, the rank-4 callers' entry (the flash
+    ring, the attention-fusing pass, layers.flash_attention on (b, h, t, d)):
+    the same kernels over the free (b·h, t, d) view, one head a block."""
+    if n_head is None:
+        b, h, tq, d = q.shape
+        res = _flash_forward(
+            q.reshape(b * h, tq, d), k.reshape(b * h, -1, d),
+            v.reshape(b * h, -1, d), causal, sm_scale, block_q, block_k,
+            interpret, with_lse, n_head=1,
+        )
+        if not with_lse:
+            return res.reshape(b, h, tq, d)
+        out, lse = res
+        return out.reshape(b, h, tq, d), (
+            None if lse is None else lse.reshape(b, h, tq))
+    b, tq, f = q.shape
+    tk = k.shape[1]
+    d = f // n_head
+    tier, heads = flash_tier(tq, tk, n_head, d, causal, block_q, block_k)
+    _note_dispatch(tier)
+    if tier == FLASH_DENSE:
         # ragged tails: fall back to the dense form (shapes are static, so
         # this is a trace-time decision, not a runtime branch)
-        out = _attention_reference(q, k, v, causal, sm_scale)
+        out = _dense(q, k, v, n_head, causal, sm_scale)
         return (out, None) if with_lse else out
-    q3 = q.reshape(b * h, tq, d)
-    k3 = k.reshape(b * h, tk, d)
-    v3 = v.reshape(b * h, tk, d)
-    if not _resident_ok(tk, d, k.dtype.itemsize):
-        # long-context tier: stream K/V through the grid instead of holding
-        # them whole in VMEM; the streamed optimum is larger tiles (the gate
-        # above already passed, and the stream targets only widen it)
-        res = _flash_forward_streamed(
-            q3, k3, v3, causal, sm_scale,
-            _auto_block(tq, raw_bq or _DEF_STREAM_BLOCK),
-            _auto_block(tk, raw_bk or _DEF_STREAM_BLOCK),
-            interpret, with_lse, q.dtype,
-        )
-        if with_lse:
-            out, lse = res
-            if tq % _LANES:
-                lse = lse[..., 0]
-            return out.reshape(b, h, tq, d), lse.reshape(b, h, tq)
-        return res.reshape(b, h, tq, d)
-    if max(tq, tk) >= 4096:
-        # same VMEM clamp as the fused backward: the (1024, block_k) f32
-        # score/probability temporaries + resident K/V slabs overflow VMEM
-        # once EITHER side reaches t=4096 (the slabs scale with tk, the
-        # temporaries with block_q*block_k — compile-checked on chip,
-        # including asymmetric tq=1024/tk=4096); 512 holds through 8192
-        block_q = min(block_q, 512)
-    grid = (b * h, tq // block_q)
-    packed = tq % _LANES == 0  # see _flash_kernel's sub-128-lane note
-    out_shapes = [jax.ShapeDtypeStruct((b * h, tq, d), q.dtype)]
-    out_specs = [pl.BlockSpec((None, block_q, d), lambda bh, qi: (bh, qi, 0))]
+    split = tier == FLASH_SPLIT
+    if split:
+        q, k, v = (_split_heads(x, n_head) for x in (q, k, v))
+    blocks = _flash_blocks(tq, tk, heads * d, k.dtype.itemsize, causal,
+                           block_q, block_k)
+    res = _flash_fwd_call(q, k, v, 1 if split else n_head, heads, causal,
+                          sm_scale, blocks, interpret, with_lse)
+    if not split:
+        return res
     if with_lse:
-        if packed:
-            out_shapes.append(jax.ShapeDtypeStruct((b * h, 1, tq), jnp.float32))
-            out_specs.append(pl.BlockSpec((None, 1, tq), lambda bh, qi: (bh, 0, 0)))
-        else:
-            out_shapes.append(
-                jax.ShapeDtypeStruct((b * h, tq, _LANES), jnp.float32)
-            )
-            out_specs.append(
-                pl.BlockSpec((None, block_q, _LANES), lambda bh, qi: (bh, qi, 0))
-            )
-    res = pl.pallas_call(
-        functools.partial(
-            _flash_kernel,
-            block_k=block_k,
-            causal=causal,
-            sm_scale=sm_scale,
-            q_block_idx_axis=1,
-            t_q_total=tq,
-            lse_packed=packed,
-        ),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((None, block_q, d), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((None, tk, d), lambda bh, qi: (bh, 0, 0)),
-            pl.BlockSpec((None, tk, d), lambda bh, qi: (bh, 0, 0)),
-        ],
-        out_specs=out_specs if with_lse else out_specs[0],
-        out_shape=out_shapes if with_lse else out_shapes[0],
-        interpret=interpret,
-    )(q3, k3, v3)
-    if with_lse:
-        out, lse = res
-        if not packed:
-            lse = lse[..., 0]
-        return out.reshape(b, h, tq, d), lse.reshape(b, h, tq)
-    return res.reshape(b, h, tq, d)
-
-
+        return _merge_heads(res[0], n_head), res[1].reshape(b, n_head, tq)
+    return _merge_heads(res, n_head)
 
 
 # ---------------------------------------------------------------------------
-# flash backward (flash-attention-2 style): dQ in one kernel over q blocks,
-# dK/dV in another over k blocks, both streaming the opposite side and using
-# the saved logsumexp L plus D = rowsum(dO * O)
+# flash backward (flash-attention-2 style) against the saved logsumexp L and
+# D = rowsum(dO * O): one fused kernel a K block where both sides sit in
+# VMEM, a dQ kernel over q blocks and a dK/dV kernel over k blocks, each
+# streaming the opposite side, where they do not
 # ---------------------------------------------------------------------------
 
 
 def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
-                            dk_ref, dv_ref, dqp_ref, *, block_q, causal,
-                            sm_scale, t_q_total, lse_packed=True):
-    """Fused resident backward: one (bh, k_block) program computes dK and dV
-    for its K block AND this K block's partial contribution to every dQ row
-    (summed over k blocks by XLA outside). The two-kernel form recomputes the
-    score matrix s and dp = dO·Vᵀ in BOTH kernels — 7 matmul-units per
-    backward vs the 5 this kernel executes (s, dp, dV, dK, dQ-partial), a
-    28%% flop cut on the exact tier the MFU bench runs (measured on chip:
-    fwd+grad 2.42 -> 1.87 ms at t=1024 bh=128 non-causal)."""
-    ki = pl.program_id(1)
-    k_blk = k_ref[...]  # (block_k, d)
-    v_blk = v_ref[...]
-    block_k = k_blk.shape[0]
-    t_k_total = pl.num_programs(1) * block_k
+                            dk_ref, dv_ref, dqp_ref, *, d_head, block_q,
+                            causal, sm_scale, t_q_total, lse_packed=True):
+    """Fused resident backward: one (b, head block, k_block) program computes
+    dK and dV for its K block AND this K block's partial contribution to
+    every dQ row (summed over k blocks by XLA outside). The two-kernel form
+    recomputes the score matrix s and dp = dO·Vᵀ in BOTH kernels — 7
+    matmul-units per backward vs the 5 this kernel executes (s, dp, dV, dK,
+    dQ-partial), a 28%% flop cut on the exact tier the MFU bench runs
+    (measured on chip: fwd+grad 2.42 -> 1.87 ms at t=1024 bh=128
+    non-causal). A head of a shared block reads k and v with the other
+    heads' columns zeroed (_own_lanes), so its scores, dP and dQ partial are
+    its own, and keeps its own columns of dK and dV."""
+    ki = pl.program_id(2)
+    block_k, width = k_ref.shape
+    heads = width // d_head
+    t_k_total = pl.num_programs(2) * block_k
     offset = t_k_total - t_q_total  # bottom-right causal alignment
     t_q = q_ref.shape[0]
     nq = pl.cdiv(t_q, block_q)
+    ks = [_own_lanes(k_ref[...], j, d_head) for j in range(heads)]
+    vs = [_own_lanes(v_ref[...], j, d_head) for j in range(heads)]
 
     dqp_ref[...] = jnp.zeros_like(dqp_ref)  # skipped causal rows stay 0
 
     def body(qi, carry):
-        dk, dv = carry
-        q_blk = q_ref[pl.ds(qi * block_q, block_q), :]
-        do_blk = do_ref[pl.ds(qi * block_q, block_q), :]
-        if lse_packed:
-            lse = lse_ref[0, pl.ds(qi * block_q, block_q)].astype(jnp.float32)
-        else:
-            lse = lse_ref[pl.ds(qi * block_q, block_q), 0].astype(jnp.float32)
+        rows = pl.ds(qi * block_q, block_q)
+        q_blk = q_ref[rows, :]
+        do_blk = do_ref[rows, :]
         # delta = rowsum(dO * O) computed here from the saved forward output
         # rather than as an XLA prologue: the prologue form writes + re-reads
         # a 128-lane-broadcast f32 tensor per layer (~134 MB of HBM traffic)
         # where this is a VPU rowsum over tiles already resident
-        o_blk = o_ref[pl.ds(qi * block_q, block_q), :]
-        delta = jnp.sum(
-            do_blk.astype(jnp.float32) * o_blk.astype(jnp.float32), axis=1
-        )
-        s = jax.lax.dot_general(
-            q_blk, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * sm_scale
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
+        do_o = do_blk.astype(jnp.float32) * o_ref[rows, :].astype(jnp.float32)
+        new, dqp = [], None
+        for j, (dk, dv) in enumerate(carry):
+            lse = _load_rows(lse_ref, j, qi * block_q, block_q, t_q_total,
+                             lse_packed)
+            delta = jnp.sum(_own_lanes(do_o, j, d_head), axis=1)
+            s = _scores(q_blk, ks[j], sm_scale, causal, qi * block_q,
+                        ki * block_k, offset)
+            p = _saved_probs(s, lse)
+            dv = dv + jax.lax.dot_general(
+                p.astype(do_blk.dtype), do_blk, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
             )
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
+            dp = jax.lax.dot_general(
+                do_blk, vs[j], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
             )
-            s = jnp.where(q_pos + offset >= k_pos, s, -jnp.inf)
-        p = jnp.exp(s - lse[:, None])
-        p = jnp.where(jnp.isfinite(s), p, 0.0)
-        dv = dv + jax.lax.dot_general(
-            p.astype(do_blk.dtype), do_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(
-            do_blk, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = (p * (dp - delta[:, None]) * sm_scale).astype(q_blk.dtype)
-        dk = dk + jax.lax.dot_general(
-            ds, q_blk, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        dqp_ref[pl.ds(qi * block_q, block_q), :] = jax.lax.dot_general(
-            ds, k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ).astype(dqp_ref.dtype)
-        return dk, dv
+            ds = (p * (dp - delta[:, None]) * sm_scale).astype(q_blk.dtype)
+            dk = dk + jax.lax.dot_general(
+                ds, q_blk, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            # zero outside the head's columns, as ks[j] is: the heads'
+            # partials add into the shared rows exactly
+            dqp_j = jax.lax.dot_general(
+                ds, ks[j], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            dqp = dqp_j if dqp is None else dqp + dqp_j
+            new.append((dk, dv))
+        dqp_ref[rows, :] = dqp.astype(dqp_ref.dtype)
+        return tuple(new)
 
     if causal:
         first_q_row = ki * block_k - offset
         q_start = jnp.clip(first_q_row // block_q, 0, nq)
     else:
         q_start = 0
-    d = k_blk.shape[1]
-    dk, dv = jax.lax.fori_loop(
-        q_start,
-        nq,
-        body,
-        (jnp.zeros((block_k, d), jnp.float32), jnp.zeros((block_k, d), jnp.float32)),
-    )
-    dk_ref[...] = dk.astype(dk_ref.dtype)
-    dv_ref[...] = dv.astype(dv_ref.dtype)
+    zero = jnp.zeros((block_k, width), jnp.float32)
+    grads = jax.lax.fori_loop(q_start, nq, body, ((zero, zero),) * heads)
+    dk_ref[...] = _sum_own_lanes([g[0] for g in grads], d_head).astype(
+        dk_ref.dtype)
+    dv_ref[...] = _sum_own_lanes([g[1] for g in grads], d_head).astype(
+        dv_ref.dtype)
 
 
 def _flash_bwd_dq_streamed(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                           dq_ref, dq_acc, *, causal, sm_scale, t_q_total,
-                           t_k_total, lse_packed=True):
-    """Streamed dQ: grid (bh, q_blocks, k_blocks); K/V tiles ride the inner
-    grid dim, dQ accumulates in f32 scratch and lands on the last k step."""
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
-    block_q = q_ref.shape[0]
+                           dq_ref, dq_acc, *, d_head, causal, sm_scale,
+                           t_q_total, t_k_total, lse_packed=True):
+    """Streamed dQ: grid (b, head blocks, q_blocks, k_blocks); K/V tiles ride
+    the inner grid dim, dQ accumulates in f32 scratch and lands on the last
+    k step."""
+    qi = pl.program_id(2)
+    ki = pl.program_id(3)
+    nk = pl.num_programs(3)
+    block_q, width = q_ref.shape
     block_k = k_ref.shape[0]
+    offset = t_k_total - t_q_total
 
     @pl.when(ki == 0)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
     if causal:
-        offset = t_k_total - t_q_total
         needed = ki * block_k <= qi * block_q + block_q - 1 + offset
     else:
         needed = qi >= 0
 
     @pl.when(needed)
     def _step():
-        block_q_ = q_ref.shape[0]
         q = q_ref[...]
         do = do_ref[...]
-        if lse_packed:
-            lse = lse_ref[0, pl.ds(qi * block_q_, block_q_)].astype(jnp.float32)
-            delta = delta_ref[0, pl.ds(qi * block_q_, block_q_)].astype(
-                jnp.float32
+        q_first = qi * block_q if lse_packed else None
+        for j in range(width // d_head):
+            lse = _load_rows(lse_ref, j, q_first, block_q, t_q_total,
+                             lse_packed)
+            delta = _load_rows(delta_ref, j, q_first, block_q, t_q_total,
+                               lse_packed)
+            # zero outside the head's columns: so is the head's dQ
+            k_blk = _own_lanes(k_ref[...], j, d_head)
+            v_blk = _own_lanes(v_ref[...], j, d_head)
+            s = _scores(q, k_blk, sm_scale, causal, qi * block_q,
+                        ki * block_k, offset)
+            p = _saved_probs(s, lse)
+            dp = jax.lax.dot_general(
+                do, v_blk, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
             )
-        else:  # per-q-block 128-lane broadcast layout (sub-128-lane t_q)
-            lse = lse_ref[..., 0].astype(jnp.float32)
-            delta = delta_ref[..., 0].astype(jnp.float32)
-        k_blk = k_ref[...]
-        v_blk = v_ref[...]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * sm_scale
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
+            ds = (p * (dp - delta[:, None]) * sm_scale).astype(k_blk.dtype)
+            dq_acc[...] = dq_acc[...] + jax.lax.dot_general(
+                ds, k_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
             )
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
-            s = jnp.where(q_pos + (t_k_total - t_q_total) >= k_pos, s, -jnp.inf)
-        p = jnp.exp(s - lse[:, None])
-        p = jnp.where(jnp.isfinite(s), p, 0.0)
-        dp = jax.lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = (p * (dp - delta[:, None]) * sm_scale).astype(k_blk.dtype)
-        dq_acc[...] = dq_acc[...] + jax.lax.dot_general(
-            ds, k_blk, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
 
     @pl.when(ki == nk - 1)
     def _finish():
@@ -614,22 +768,22 @@ def _flash_bwd_dq_streamed(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_bwd_dkv_streamed(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                            dk_ref, dv_ref, dk_acc, dv_acc, *, causal,
+                            dk_ref, dv_ref, dk_acc, dv_acc, *, d_head, causal,
                             sm_scale, t_q_total, t_k_total, lse_packed=True):
-    """Streamed dK/dV: grid (bh, k_blocks, q_blocks); Q/dO/lse/delta tiles
-    ride the inner grid dim, dK/dV accumulate in f32 scratch."""
-    ki = pl.program_id(1)
-    qi = pl.program_id(2)
-    nq = pl.num_programs(2)
-    block_q = q_ref.shape[0]
+    """Streamed dK/dV: grid (b, head blocks, k_blocks, q_blocks); Q/dO/lse/
+    delta tiles ride the inner grid dim, dK/dV accumulate in f32 scratch."""
+    ki = pl.program_id(2)
+    qi = pl.program_id(3)
+    nq = pl.num_programs(3)
+    block_q, width = q_ref.shape
     block_k = k_ref.shape[0]
+    offset = t_k_total - t_q_total
 
     @pl.when(qi == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    offset = t_k_total - t_q_total
     if causal:
         # q rows before this k block's first key see nothing of it
         needed = qi * block_q + block_q - 1 + offset >= ki * block_k
@@ -638,45 +792,34 @@ def _flash_bwd_dkv_streamed(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(needed)
     def _step():
-        block_q_ = q_ref.shape[0]
-        q_blk = q_ref[...]
-        do_blk = do_ref[...]
-        if lse_packed:
-            lse = lse_ref[0, pl.ds(qi * block_q_, block_q_)].astype(jnp.float32)
-            delta = delta_ref[0, pl.ds(qi * block_q_, block_q_)].astype(
-                jnp.float32
-            )
-        else:
-            lse = lse_ref[..., 0].astype(jnp.float32)
-            delta = delta_ref[..., 0].astype(jnp.float32)
         k_blk = k_ref[...]
         v_blk = v_ref[...]
-        s = jax.lax.dot_general(
-            q_blk, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * sm_scale
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
+        q_first = qi * block_q if lse_packed else None
+        for j in range(width // d_head):
+            lse = _load_rows(lse_ref, j, q_first, block_q, t_q_total,
+                             lse_packed)
+            delta = _load_rows(delta_ref, j, q_first, block_q, t_q_total,
+                               lse_packed)
+            # zero outside the head's columns, so the products below land in
+            # the head's columns of the shared accumulators and nowhere else
+            q_blk = _own_lanes(q_ref[...], j, d_head)
+            do_blk = _own_lanes(do_ref[...], j, d_head)
+            s = _scores(q_blk, k_blk, sm_scale, causal, qi * block_q,
+                        ki * block_k, offset)
+            p = _saved_probs(s, lse)
+            dv_acc[...] = dv_acc[...] + jax.lax.dot_general(
+                p.astype(do_blk.dtype), do_blk, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
             )
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
+            dp = jax.lax.dot_general(
+                do_blk, v_blk, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
             )
-            s = jnp.where(q_pos + offset >= k_pos, s, -jnp.inf)
-        p = jnp.exp(s - lse[:, None])
-        p = jnp.where(jnp.isfinite(s), p, 0.0)
-        dv_acc[...] = dv_acc[...] + jax.lax.dot_general(
-            p.astype(do_blk.dtype), do_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(
-            do_blk, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = (p * (dp - delta[:, None]) * sm_scale).astype(q_blk.dtype)
-        dk_acc[...] = dk_acc[...] + jax.lax.dot_general(
-            ds, q_blk, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+            ds = (p * (dp - delta[:, None]) * sm_scale).astype(q_blk.dtype)
+            dk_acc[...] = dk_acc[...] + jax.lax.dot_general(
+                ds, q_blk, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
 
     @pl.when(qi == nq - 1)
     def _finish():
@@ -684,179 +827,141 @@ def _flash_bwd_dkv_streamed(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _flash_backward_streamed(q3, k3, v3, do3, lse3, delta, causal, sm_scale,
-                             block_q, block_k, interpret, out_dtypes):
-    bh, tq, d = q3.shape
-    tk = k3.shape[1]
-    packed = tq % _LANES == 0  # lse3/delta arrive in the matching layout
-    q_spec = pl.BlockSpec((None, block_q, d), lambda bh, qi, ki: (bh, qi, 0))
-    k_spec = pl.BlockSpec((None, block_k, d), lambda bh, qi, ki: (bh, ki, 0))
-    lane_q = (
-        pl.BlockSpec((None, 1, tq), lambda bh, qi, ki: (bh, 0, 0))
-        if packed
-        else pl.BlockSpec((None, block_q, _LANES), lambda bh, qi, ki: (bh, qi, 0))
-    )
+def _flash_bwd_call(q, k, v, out, lse, dout, n_head, heads, causal, sm_scale,
+                    blocks, interpret):
+    """The backward kernels over [b, t, h·d] operands (see _flash_fwd_call;
+    lse (b, h, tq)): dq, dk, dv in the operands' layout."""
+    b, tq, f = q.shape
+    tk = k.shape[1]
+    d = f // n_head
+    width = heads * d
+    n_blocks = n_head // heads
+    block_q, block_k, streamed = blocks
+    common = dict(d_head=d, causal=causal, sm_scale=sm_scale, t_q_total=tq,
+                  lse_packed=tq % _LANES == 0)
+    if not streamed:
+        nk = tk // block_k
+        q_spec = pl.BlockSpec((None, tq, width), lambda bi, hi, ki: (bi, 0, hi))
+        k_spec = pl.BlockSpec(
+            (None, block_k, width), lambda bi, hi, ki: (bi, ki, hi))
+        lse_shape, lse_spec = _lse_shape_spec(
+            b, n_blocks, heads, tq, block_q, None)
+        dk, dv, dqp = pl.pallas_call(
+            functools.partial(
+                _flash_bwd_fused_kernel, block_q=block_q, **common),
+            grid=(b, n_blocks, nk),
+            in_specs=[q_spec, k_spec, k_spec, q_spec, q_spec, lse_spec],
+            out_specs=[
+                k_spec, k_spec,
+                pl.BlockSpec((None, None, tq, width),
+                             lambda bi, hi, ki: (bi, ki, 0, hi)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((b, tk, f), k.dtype),
+                jax.ShapeDtypeStruct((b, tk, f), v.dtype),
+                # dQ partials, one slab per k block, in q's dtype: each
+                # partial is already f32-accumulated inside its dot; the
+                # cross-block sum over nk<=2 terms (_flash_blocks routes
+                # tk//block_k > 2 to the streamed tier) loses nothing the
+                # final cast keeps
+                jax.ShapeDtypeStruct((b, nk, tq, f), q.dtype),
+            ],
+            interpret=interpret,
+        )(q, k, v, dout, out, _lse_to_kernel(lse, heads))
+        dq = (
+            dqp[:, 0]
+            if nk == 1
+            else jnp.sum(dqp, axis=1, dtype=jnp.float32).astype(q.dtype)
+        )
+        return dq, dk, dv
+
+    delta = jnp.sum(
+        (dout.astype(jnp.float32) * out.astype(jnp.float32)).reshape(
+            b, tq, n_head, d),
+        axis=-1,
+    ).transpose(0, 2, 1)  # (b, h, tq), the lse's layout
+    rows = (_lse_to_kernel(lse, heads), _lse_to_kernel(delta, heads))
+    q_spec = pl.BlockSpec(
+        (None, block_q, width), lambda bi, hi, qi, ki: (bi, qi, hi))
+    k_spec = pl.BlockSpec(
+        (None, block_k, width), lambda bi, hi, qi, ki: (bi, ki, hi))
+    _, lane_q = _lse_shape_spec(
+        b, n_blocks, heads, tq, block_q, lambda qi, ki: qi)
     dq = pl.pallas_call(
-        functools.partial(
-            _flash_bwd_dq_streamed,
-            causal=causal, sm_scale=sm_scale, t_q_total=tq, t_k_total=tk,
-            lse_packed=packed,
-        ),
-        grid=(bh, tq // block_q, tk // block_k),
+        functools.partial(_flash_bwd_dq_streamed, t_k_total=tk, **common),
+        grid=(b, n_blocks, tq // block_q, tk // block_k),
         in_specs=[q_spec, k_spec, k_spec, q_spec, lane_q, lane_q],
         out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((bh, tq, d), out_dtypes[0]),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        out_shape=jax.ShapeDtypeStruct((b, tq, f), q.dtype),
+        scratch_shapes=[pltpu.VMEM((block_q, width), jnp.float32)],
         interpret=interpret,
-    )(q3, k3, v3, do3, lse3, delta)
+    )(q, k, v, dout, *rows)
 
-    kq_spec = pl.BlockSpec((None, block_q, d), lambda bh, ki, qi: (bh, qi, 0))
-    kk_spec = pl.BlockSpec((None, block_k, d), lambda bh, ki, qi: (bh, ki, 0))
-    klane_q = (
-        pl.BlockSpec((None, 1, tq), lambda bh, ki, qi: (bh, 0, 0))
-        if packed
-        else pl.BlockSpec((None, block_q, _LANES), lambda bh, ki, qi: (bh, qi, 0))
-    )
+    kq_spec = pl.BlockSpec(
+        (None, block_q, width), lambda bi, hi, ki, qi: (bi, qi, hi))
+    kk_spec = pl.BlockSpec(
+        (None, block_k, width), lambda bi, hi, ki, qi: (bi, ki, hi))
+    _, klane_q = _lse_shape_spec(
+        b, n_blocks, heads, tq, block_q, lambda ki, qi: qi)
     dk, dv = pl.pallas_call(
-        functools.partial(
-            _flash_bwd_dkv_streamed,
-            causal=causal, sm_scale=sm_scale, t_q_total=tq, t_k_total=tk,
-            lse_packed=packed,
-        ),
-        grid=(bh, tk // block_k, tq // block_q),
+        functools.partial(_flash_bwd_dkv_streamed, t_k_total=tk, **common),
+        grid=(b, n_blocks, tk // block_k, tq // block_q),
         in_specs=[kq_spec, kk_spec, kk_spec, kq_spec, klane_q, klane_q],
         out_specs=[kk_spec, kk_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, tk, d), out_dtypes[1]),
-            jax.ShapeDtypeStruct((bh, tk, d), out_dtypes[2]),
+            jax.ShapeDtypeStruct((b, tk, f), k.dtype),
+            jax.ShapeDtypeStruct((b, tk, f), v.dtype),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, width), jnp.float32),
+            pltpu.VMEM((block_k, width), jnp.float32),
         ],
         interpret=interpret,
-    )(q3, k3, v3, do3, lse3, delta)
+    )(q, k, v, dout, *rows)
     return dq, dk, dv
 
 
 def _flash_backward(q, k, v, out, lse, dout, causal, sm_scale, block_q,
-                    block_k, interpret):
-    b, h, tq, d = q.shape
-    tk = k.shape[2]
-    raw_bq, raw_bk = block_q, block_k
-    block_q, block_k = _resolve_blocks(block_q, block_k, causal)
-    block_q = _auto_block(tq, block_q)
-    block_k = _auto_block(tk, block_k)
-    q3 = q.reshape(b * h, tq, d)
-    k3 = k.reshape(b * h, tk, d)
-    v3 = v.reshape(b * h, tk, d)
-    do3 = dout.reshape(b * h, tq, d)
-    packed = tq % _LANES == 0  # matches the forward's residual layout rule
-    if packed:
-        lse3 = lse.reshape(b * h, 1, tq)
-    else:
-        lse3 = jnp.broadcast_to(
-            lse.reshape(b * h, tq)[..., None], (b * h, tq, _LANES)
+                    block_k, interpret, n_head=None):
+    """Flash backward against the forward's saved out and lse (b, h, tq),
+    through the tier the forward took (flash_tier reads the same shapes):
+    dq, dk, dv in the operands' layout, [b, t, h·d] with n_head and
+    (b, h, t, d) without (see _flash_forward)."""
+    if n_head is None:
+        b, h, tq, d = q.shape
+        dq, dk, dv = _flash_backward(
+            *(x.reshape(b * h, -1, d) for x in (q, k, v, out)),
+            lse.reshape(b * h, 1, tq), dout.reshape(b * h, tq, d), causal,
+            sm_scale, block_q, block_k, interpret, n_head=1,
         )
-
-    # the fused kernel needs whole-side VMEM residency (breaks past ~8k
-    # tokens) and materializes an (nk, tq, d) dQ-partials HBM temporary —
-    # bounded to <=2x dQ by the nk cap here; everything bigger takes the
-    # grid-streamed two-kernel tier (any t, O(t) memory)
-    if tk // block_k > 2 or not (
-        _resident_ok(tk, d, k.dtype.itemsize)
-        and _resident_ok(tq, d, q.dtype.itemsize)
-    ):
-        delta = jnp.sum(
-            dout.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
-        )
-        if packed:
-            delta = delta.reshape(b * h, 1, tq)
-        else:  # must mirror lse3's layout — the kernels' specs follow it
-            delta = jnp.broadcast_to(
-                delta.reshape(b * h, tq)[..., None], (b * h, tq, _LANES)
-            )
-        dq, dk, dv = _flash_backward_streamed(
-            q3, k3, v3, do3, lse3, delta, causal, sm_scale,
-            _auto_block(tq, raw_bq or _DEF_STREAM_BLOCK),
-            _auto_block(tk, raw_bk or _DEF_STREAM_BLOCK),
-            interpret, (q.dtype, k.dtype, v.dtype),
-        )
-        return (
-            dq.reshape(b, h, tq, d),
-            dk.reshape(b, h, tk, d),
-            dv.reshape(b, h, tk, d),
-        )
-
-    if max(tq, tk) >= 4096:
-        # the fused kernel's f32 score/probability temporaries at
-        # block_q=1024 overflow VMEM once the resident slabs (q/do/o with
-        # tq, K/V with tk) reach t=4096 (compile-checked on chip); 512
-        # holds through t=8192
-        block_q = min(block_q, 512)
-    nk = tk // block_k
-    dk, dv, dqp = pl.pallas_call(
-        functools.partial(
-            _flash_bwd_fused_kernel,
-            block_q=block_q,
-            causal=causal,
-            sm_scale=sm_scale,
-            t_q_total=tq,
-            lse_packed=packed,
-        ),
-        grid=(b * h, nk),
-        in_specs=[
-            pl.BlockSpec((None, tq, d), lambda bh, ki: (bh, 0, 0)),
-            pl.BlockSpec((None, block_k, d), lambda bh, ki: (bh, ki, 0)),
-            pl.BlockSpec((None, block_k, d), lambda bh, ki: (bh, ki, 0)),
-            pl.BlockSpec((None, tq, d), lambda bh, ki: (bh, 0, 0)),
-            pl.BlockSpec((None, tq, d), lambda bh, ki: (bh, 0, 0)),
-            (
-                pl.BlockSpec((None, 1, tq), lambda bh, ki: (bh, 0, 0))
-                if packed
-                else pl.BlockSpec((None, tq, _LANES), lambda bh, ki: (bh, 0, 0))
-            ),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, block_k, d), lambda bh, ki: (bh, ki, 0)),
-            pl.BlockSpec((None, block_k, d), lambda bh, ki: (bh, ki, 0)),
-            pl.BlockSpec((None, None, tq, d), lambda bh, ki: (bh, ki, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, tk, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, tk, d), v.dtype),
-            # dQ partials, one slab per k block, in q's dtype: each partial
-            # is already f32-accumulated inside its dot; the cross-block sum
-            # over nk<=2 terms (the tier gate above routes tk//block_k > 2
-            # to the streamed path) loses nothing the final bf16 cast keeps
-            jax.ShapeDtypeStruct((b * h, nk, tq, d), q.dtype),
-        ],
-        interpret=interpret,
-    )(q3, k3, v3, do3, out.reshape(b * h, tq, d), lse3)
-    dq = (
-        dqp[:, 0]
-        if nk == 1
-        else jnp.sum(dqp, axis=1, dtype=jnp.float32).astype(q.dtype)
-    )
-
-    return (
-        dq.reshape(b, h, tq, d),
-        dk.reshape(b, h, tk, d),
-        dv.reshape(b, h, tk, d),
-    )
+        return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+    b, tq, f = q.shape
+    tk = k.shape[1]
+    d = f // n_head
+    tier, heads = flash_tier(tq, tk, n_head, d, causal, block_q, block_k)
+    split = tier == FLASH_SPLIT
+    if split:
+        q, k, v, out, dout = (
+            _split_heads(x, n_head) for x in (q, k, v, out, dout))
+        lse = lse.reshape(b * n_head, 1, tq)
+    blocks = _flash_blocks(tq, tk, heads * d, k.dtype.itemsize, causal,
+                           block_q, block_k, backward=True)
+    grads = _flash_bwd_call(q, k, v, out, lse, dout, 1 if split else n_head,
+                            heads, causal, sm_scale, blocks, interpret)
+    return tuple(_merge_heads(g, n_head) for g in grads) if split else grads
 
 
-def _resolve_defaults(q, sm_scale, interpret):
+def _resolve_defaults(q, sm_scale, interpret, n_head=None):
     """Single source of the defaulting rule: forward, _fwd and _bwd must
     agree or a custom_vjp would silently produce wrong gradients."""
     if sm_scale is None:
-        sm_scale = q.shape[-1] ** -0.5
+        sm_scale = (q.shape[-1] // (n_head or 1)) ** -0.5
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     return sm_scale, interpret
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_attention(
     q,
     k,
@@ -866,76 +971,114 @@ def flash_attention(
     block_q=None,
     block_k=None,
     interpret=None,
+    n_head=None,
 ):
-    """softmax(QKᵀ·scale [causal-masked]) V over (b, h, t, d) tensors.
+    """softmax(QKᵀ·scale [causal-masked]) V. With n_head, over [b, t, h·d]
+    tensors, the heads side by side on the last axis as a projection leaves
+    them (no head split or merge around the call); without, over
+    (b, h, t, d).
 
     block_q/block_k of None pick tuned per-direction defaults adapted to the
     sequence length (_auto_block); explicit values act as upper-bound targets.
     """
-    sm_scale, interpret = _resolve_defaults(q, sm_scale, interpret)
-    return _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret)
+    sm_scale, interpret = _resolve_defaults(q, sm_scale, interpret, n_head)
+    return _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
+                          interpret, n_head=n_head)
 
 
-def _fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
-    sm_scale, interpret = _resolve_defaults(q, sm_scale, interpret)
-    res = _flash_forward(
-        q, k, v, causal, sm_scale, block_q, block_k, interpret, with_lse=True
-    )
-    out, lse = res
+def _fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, n_head):
+    sm_scale, interpret = _resolve_defaults(q, sm_scale, interpret, n_head)
+    out, lse = _flash_forward(
+        q, k, v, causal, sm_scale, block_q, block_k, interpret,
+        with_lse=True, n_head=n_head)
     return out, (q, k, v, out, lse)
 
 
-def _bwd(causal, sm_scale, block_q, block_k, interpret, res, dout):
+def _dense_vjp(q, k, v, dout, n_head, causal, sm_scale):
+    """The ragged-tile fallback's backward: the dense form's own vjp (the
+    same trace-time decision as the forward fallback)."""
+    return jax.vjp(
+        lambda a, b, c: _dense(a, b, c, n_head, causal, sm_scale), q, k, v
+    )[1](dout)
+
+
+def _bwd(causal, sm_scale, block_q, block_k, interpret, n_head, res, dout):
     q, k, v, out, lse = res
-    sm_scale, interpret = _resolve_defaults(q, sm_scale, interpret)
+    sm_scale, interpret = _resolve_defaults(q, sm_scale, interpret, n_head)
     if lse is None:
-        # ragged-tail fallback: dense recompute-vjp (same trace-time decision
-        # as the forward fallback)
-        _, vjp = jax.vjp(
-            lambda a, b, c: _attention_reference(a, b, c, causal, sm_scale), q, k, v
-        )
-        return vjp(dout)
+        return _dense_vjp(q, k, v, dout, n_head, causal, sm_scale)
     return _flash_backward(
-        q, k, v, out, lse, dout, causal, sm_scale, block_q, block_k, interpret
-    )
+        q, k, v, out, lse, dout, causal, sm_scale, block_q, block_k,
+        interpret, n_head=n_head)
 
 
 flash_attention.defvjp(_fwd, _bwd)
 
 
-def _flash_on_mesh(ctx, fn, args, out_ranks):
-    """fn(*args) as the flash ops run it: as is on one device, and under
-    shard_map on an SPMD mesh, where GSPMD cannot partition a Mosaic kernel
-    (see _mesh_declines). Attention is independent per (batch, head), so the
-    batch of the (b, h, t, d) operands splits over the data axes and the
-    heads over tp with no collective; a dim the axes do not divide stays
-    whole on every device. Operands and results are rank 4, (b, h, t, d),
-    or rank 3, the (b, h, t) lse; out_ranks gives the results'."""
+def _flash_on_mesh(ctx, fn, args, kinds, out_kinds, n_head):
+    """fn(n_head, *args) as the flash ops run it: as is on one device, and
+    under shard_map on an SPMD mesh, where GSPMD cannot partition a Mosaic
+    kernel (see _mesh_declines). Attention is independent per (batch, head),
+    so the batch splits over the data axes and the heads over tp with no
+    collective; a dim the axes do not divide stays whole on every device.
+    kinds / out_kinds name each operand's and result's layout: "x", an
+    attention operand, [b, t, h·d] with n_head or (b, h, t, d) without, and
+    "l", the (b, h, t) lse. On [b, t, h·d] the heads split over tp only where
+    a device's share of the last axis is whole 128-lane blocks; fn is handed
+    the heads a device holds."""
     mesh = ctx.mesh
     if mesh is None or mesh.size == 1:
-        return fn(*args)
+        return fn(n_head, *args)
     from jax.sharding import PartitionSpec as P
 
     from ..parallel.collectives import shard_map
 
-    b, h = args[0].shape[:2]
+    x = args[kinds.index("x")]
+    b = x.shape[0]
+    h = n_head or x.shape[1]
     data = tuple(a for a in ("dp", "fsdp") if mesh.shape.get(a, 1) > 1)
     if not data or b % int(np.prod([mesh.shape[a] for a in data])):
         data = None
-    tp = "tp" if mesh.shape.get("tp", 1) > 1 and h % mesh.shape["tp"] == 0 else None
-    spec = {4: P(data, tp, None, None), 3: P(data, tp, None)}
+    n_tp = mesh.shape.get("tp", 1)
+    tp = "tp" if n_tp > 1 and h % n_tp == 0 else None
+    if tp and n_head and (x.shape[2] // n_tp) % _LANES:
+        tp = None
+    spec = {
+        "x": P(data, None, tp) if n_head else P(data, tp, None, None),
+        "l": P(data, tp, None),
+    }
+    local = n_head // n_tp if n_head and tp else n_head
     return shard_map(
-        fn, mesh=mesh,
-        in_specs=tuple(spec[a.ndim] for a in args),
-        out_specs=tuple(spec[r] for r in out_ranks),
+        functools.partial(fn, local), mesh=mesh,
+        in_specs=tuple(spec[c] for c in kinds),
+        out_specs=tuple(spec[c] for c in out_kinds),
         check_vma=False,
     )(*args)
 
 
+def _flash_op_operands(ins, attrs):
+    """(q, k, v, n_head, causal, sm_scale, interpret, takes the kernels) of
+    a flash op. n_head: the attribute, with Q/K/V [b, t, h·d]; None with the
+    rank-4 (b, h, t, d) operands of the attention-fusing pass."""
+    (q,) = ins["Q"]
+    (k,) = ins["K"]
+    (v,) = ins["V"]
+    n_head = int(attrs["n_head"]) if q.ndim == 3 else None
+    causal = bool(attrs.get("causal", False))
+    sm_scale, interpret = _resolve_defaults(
+        q, attrs.get("sm_scale"), None, n_head)
+    t_axis = 1 if n_head else 2
+    kernels = flash_path_taken(q.shape[t_axis], k.shape[t_axis], causal)
+    return q, k, v, n_head, causal, sm_scale, interpret, kernels
+
+
 @register("flash_attention")
 def _flash_attention_op(ctx, ins, attrs):
-    """Graph-op form: Q/K/V (b, h, t, d) → Out (+ Lse residual). The
-    transformer layers emit this in place of the matmul+softmax+matmul chain.
+    """Graph-op form: Q/K/V → Out (+ Lse residual, (b, h, t) f32), Out in
+    Q's layout. With the attribute n_head the operands are [b, t, h·d], what
+    a projection's mul leaves: the transformer layers emit this in place of
+    reshape + transpose2 + matmul + softmax + matmul + transpose2 + reshape.
+    Rank-4 operands are (b, h, t, d) (docs/flash_attention.md).
 
     The logsumexp residual is emitted as a side output so the explicit
     flash_attention_grad below can run the flash backward against the SAVED
@@ -943,21 +1086,18 @@ def _flash_attention_op(ctx, ins, attrs):
     inside jax.vjp, and since the duplicate is a pallas custom-call with a
     different output arity, XLA CSE cannot deduplicate it (one extra forward
     kernel run per attention block per step, measured on chip)."""
-    (q,) = ins["Q"]
-    (k,) = ins["K"]
-    (v,) = ins["V"]
-    causal = bool(attrs.get("causal", False))
-    sm_scale, interpret = _resolve_defaults(q, attrs.get("sm_scale"), None)
-    if not flash_path_taken(q.shape[2], k.shape[2], causal):
+    q, k, v, n_head, causal, sm_scale, interpret, kernels = (
+        _flash_op_operands(ins, attrs))
+    if not kernels:
         # ragged tiles: the dense form, plain XLA that GSPMD partitions
-        return {"Out": [_attention_reference(q, k, v, causal, sm_scale)]}
-    out, lse = _flash_on_mesh(
-        ctx,
-        lambda q, k, v: _flash_forward(
-            q, k, v, causal, sm_scale, None, None, interpret, with_lse=True
-        ),
-        (q, k, v), (4, 3),
-    )
+        _note_dispatch(FLASH_DENSE)
+        return {"Out": [_dense(q, k, v, n_head, causal, sm_scale)]}
+
+    def forward(n_head, q, k, v):
+        return _flash_forward(q, k, v, causal, sm_scale, None, None,
+                              interpret, with_lse=True, n_head=n_head)
+
+    out, lse = _flash_on_mesh(ctx, forward, (q, k, v), "xxx", "xl", n_head)
     return {"Out": [out], "Lse": [lse]}
 
 
@@ -966,29 +1106,22 @@ def _flash_attention_grad_op(ctx, ins, attrs):
     """Explicit grad: flash backward kernels against the saved Out/Lse.
     Falls back to the dense recompute-vjp when the forward took the dense
     path (no Lse in the program — ragged tiles)."""
-    (q,) = ins["Q"]
-    (k,) = ins["K"]
-    (v,) = ins["V"]
+    q, k, v, n_head, causal, sm_scale, interpret, _ = (
+        _flash_op_operands(ins, attrs))
     (dout,) = ins["Out@GRAD"]
-    causal = bool(attrs.get("causal", False))
-    sm_scale, interpret = _resolve_defaults(q, attrs.get("sm_scale"), None)
     lse = ins.get("Lse", [None])[0]
     if lse is None:
-        _, vjp = jax.vjp(
-            lambda a, b, c: _attention_reference(a, b, c, causal, sm_scale),
-            q, k, v,
-        )
-        dq, dk, dv = vjp(dout.astype(q.dtype))
+        dq, dk, dv = _dense_vjp(
+            q, k, v, dout.astype(q.dtype), n_head, causal, sm_scale)
     else:
         (out,) = ins["Out"]
+
+        def backward(n_head, q, k, v, out, lse, dout):
+            return _flash_backward(q, k, v, out, lse, dout, causal, sm_scale,
+                                   None, None, interpret, n_head=n_head)
+
         dq, dk, dv = _flash_on_mesh(
-            ctx,
-            lambda q, k, v, out, lse, dout: _flash_backward(
-                q, k, v, out, lse, dout, causal, sm_scale, None, None,
-                interpret,
-            ),
-            (q, k, v, out, lse, dout), (4, 4, 4),
-        )
+            ctx, backward, (q, k, v, out, lse, dout), "xxxxlx", "xxx", n_head)
     return {"Q@GRAD": [dq], "K@GRAD": [dk], "V@GRAD": [dv]}
 
 

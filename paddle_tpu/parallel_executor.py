@@ -397,6 +397,7 @@ class ParallelExecutor:
                             sharding_rules=bs_rules,
                         )
                     self._cache[key] = compiled
+                    self._place_state(compiled)
 
                 # place the global batch sharded over the mesh before dispatch;
                 # rank-0 feeds (scalars like a lr) cannot be batch-sharded — replicate
@@ -439,6 +440,30 @@ class ParallelExecutor:
                         schedule=plan["schedule"] if plan else None,
                     )
                 return result
+
+    def _place_state(self, compiled):
+        """Put the scope's state and its rng key on the mesh as the block's
+        in_shardings will, before the block's first call. What a plain
+        Executor's startup program left on one device has no mesh in its
+        type, the step's own outputs have one, and jit keys its traces on
+        that: without this the step is traced and compiled once for the first
+        call and again for the second, two executables a program where one
+        does (tbig_train_dp4: four of 46 MB a run, 184 MB of the 192 MiB the
+        chip machine's compile cache may hold; PERF.md, PR 37)."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        repl = NamedSharding(self._mesh, P())
+        if not repl.is_fully_addressable:
+            return  # a mesh over several processes: jit places its inputs
+        wanted = dict(getattr(compiled, "_ro_sh", None) or {})
+        wanted.update(getattr(compiled, "_mut_sh", None) or {})
+        scope = self._scope
+        for name, sharding in wanted.items():
+            value = scope.vars.get(name)
+            if isinstance(value, jax.Array) and value.sharding != sharding:
+                scope.vars[name] = jax.device_put(value, sharding)
+        if scope.rng_key.sharding != repl:
+            scope.rng_key = jax.device_put(scope.rng_key, repl)
 
     def compiled_hlo(self):
         """Post-optimization HLO text of the most recently run SPMD block

@@ -24,9 +24,11 @@ def test_train_phase_toy_width():
         dict(b=1, t=128, d=128, n_layer=1, vocab=128), n_steps=2, multistep=2
     )
     assert facts["loss_last"] < facts["loss_first"]
-    # one fused GEMM tile body off the chip; the optimizer is XLA's own
+    # one fused GEMM tile body off the chip; the optimizer is XLA's own;
+    # flash's tier at 16 heads of 8: sixteen a 128-lane block
     assert set(facts["dispatches"]) == {
-        "gemm_epilogue", "layer_norm", "layer_norm_grad"}
+        "gemm_epilogue", "layer_norm", "layer_norm_grad",
+        "flash_heads_a_block:16"}
     assert facts["custom_calls"] is None  # interpreted: nothing to find
 
 

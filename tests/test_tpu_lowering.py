@@ -48,6 +48,17 @@ def _flash(causal):
     return lambda q, k, v: pk.flash_attention(q, k, v, causal)
 
 
+def _flash_btf(causal, n_head, grad=False):
+    """Over [b, t, h·d] operands, the heads side by side on the last axis."""
+    def fwd(q, k, v):
+        return pk.flash_attention(q, k, v, causal, None, None, None, None, n_head)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(f32).sum()
+
+    return jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
+
+
 def _flash_grad(causal):
     def loss(q, k, v):
         return pk.flash_attention(q, k, v, causal).astype(f32).sum()
@@ -152,6 +163,23 @@ FAMILIES = {
     "flash causal": (_flash(True), [S((2, 2, 256, 64), bf16)] * 3),
     "flash_grad": (_flash_grad(False), [S((2, 2, 256, 64), bf16)] * 3),
     "flash_grad causal": (_flash_grad(True), [S((2, 2, 256, 64), bf16)] * 3),
+    # [b, t, h·d]: two 64-wide heads a 128-lane block, one 128-wide head,
+    # cross attention (tq != tk), heads that have to be split first (d 96)
+    "flash two heads a block": (_flash_btf(False, 4), [S((2, 256, 256), bf16)] * 3),
+    "flash two heads a block causal": (_flash_btf(True, 4), [S((2, 256, 256), bf16)] * 3),
+    "flash_grad two heads a block": (
+        _flash_btf(False, 4, grad=True), [S((2, 256, 256), bf16)] * 3),
+    "flash_grad two heads a block causal": (
+        _flash_btf(True, 4, grad=True), [S((2, 256, 256), bf16)] * 3),
+    "flash_grad one head a block causal": (
+        _flash_btf(True, 2, grad=True), [S((2, 256, 256), bf16)] * 3),
+    "flash_grad two heads a block cross": (
+        _flash_btf(False, 2, grad=True),
+        [S((2, 128, 128), bf16), S((2, 384, 128), bf16), S((2, 384, 128), bf16)]),
+    "flash_grad split heads": (
+        _flash_btf(True, 2, grad=True), [S((2, 128, 192), bf16)] * 3),
+    "flash_grad two heads a block streamed": (
+        _flash_btf(True, 2, grad=True), [S((1, 16384, 128), bf16)] * 3),
     # the long-context tier takes over past ~8k tokens at d 128
     "flash streamed": (_flash(True), [S((1, 1, 16384, 128), bf16)] * 3),
     "flash_grad streamed": (_flash_grad(True), [S((1, 1, 16384, 128), bf16)] * 3),
