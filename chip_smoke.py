@@ -650,9 +650,89 @@ def phase_kernels():
     finally:
         flags.set_flags(prev)
     _kernel_ssm_state_step(done)
+    _kernel_dsa(done)
 
     say("kernels: ok, %s" % ", ".join(sorted(done)))
     return done
+
+
+def _kernel_dsa(done):
+    """The learned-sparse-attention kernels at glm52_longdocs' widths, each
+    against its dense lowering (the paged_flash flag "off"): the index scan
+    (32 heads of 128 over a bfloat16 index-key pool, pages of 64; decode
+    rows, one idle, and a 256-row chunk), the exact top-2048 (tie-free
+    scores, -inf past each row; positions equal, not close), and the sparse
+    latent walk (64 heads over 640-wide latent rows, 2048 selected a row)."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu import flags
+    from paddle_tpu.ops import registry
+
+    rng = np.random.RandomState(0)
+    bf16 = jnp.bfloat16
+    prev = flags.get_flags("paged_flash")
+    lower = lambda op, ins, attrs: registry.get(op).lower(None, ins, attrs)["Out"][0]
+
+    def both(name, op, ins, attrs):
+        """The op with paged_flash "on" (dsa_index: the Pallas scan; the
+        other two are XLA's) and "off"."""
+        flags.set_flags({"paged_flash": "on"})
+        fn = lambda ins: lower(op, ins, attrs)
+        out = (_compiled(name, fn, ins) if op == "dsa_index"
+               else jax.block_until_ready(jax.jit(fn)(ins)))
+        flags.set_flags({"paged_flash": "off"})
+        return np.asarray(out), np.asarray(jax.jit(lambda ins: lower(op, ins, attrs))(ins))
+
+    try:
+        ps, n_pages, slots, heads, width = 64, 64, 8, 32, 128
+        table = rng.permutation(np.arange(1, slots * n_pages + 1)).astype("int32")
+        pool = jnp.asarray(rng.randn((slots * n_pages + 1) * ps, width), bf16)
+        cases = {
+            "dsa_index decode": (slots, table.reshape(slots, n_pages), np.array(
+                [0, 63, 64, 511, 2047, 3000, n_pages * ps - 1, -1], "int32")),
+            "dsa_index shared": (256, table[:n_pages],
+                                 (3000 + np.arange(256)).astype("int32")),
+        }
+        for name, (rows, bt, pos) in cases.items():
+            got, want = both(name, "dsa_index", {
+                "Q": [jnp.asarray(rng.randn(rows, heads * width) * 0.3, bf16)],
+                "W": [jnp.asarray(rng.randn(rows, heads), jnp.float32)],
+                "Pool": [pool], "BlockTable": [jnp.asarray(bt)],
+                "Pos": [jnp.asarray(pos)]}, {"page_size": ps})
+            check(np.array_equal(np.isinf(got), np.isinf(want)),
+                  "kernels: %s masks other positions than its dense lowering" % name)
+            live = np.isfinite(want)
+            _close(name, got[live], want[live], MXU_RTOL, MXU_ATOL)
+            done[name] = "compiled"
+
+        n, k = 65664, 2048
+        scores = rng.randn(32, n).astype("float32")
+        scores[np.arange(n)[None, :] > rng.randint(100, n, 32)[:, None]] = -np.inf
+        scores[0, 1000:] = -np.inf  # fewer than k positions
+        got, want = both("dsa_topk", "dsa_topk", {"X": [jnp.asarray(scores)]}, {"k": k})
+        check(np.array_equal(got, want),
+              "kernels: dsa_topk's selection differs from jax.lax.top_k's")
+        done["dsa_topk"] = "compiled"
+
+        heads, width, dv = 64, 640, 512
+        latent = jnp.asarray(
+            rng.randn((slots * n_pages + 1) * ps, width) * 0.5, bf16).at[:, 576:].set(0)
+        pos = np.array([5, 2047, 2048, 3000, 4000, 4095, 100, 0], "int32")
+        sel = np.full((slots, k), -1, "int32")
+        for r, p in enumerate(pos):
+            pick = np.sort(rng.permutation(p + 1)[:k])
+            sel[r, :pick.size] = pick
+        got, want = both("mla_sparse_attention", "mla_sparse_attention", {
+            "Q": [jnp.asarray(rng.randn(slots, heads * width) * 0.5, bf16)],
+            "Pool": [latent], "BlockTable": [jnp.asarray(table.reshape(slots, n_pages))],
+            "Selected": [jnp.asarray(sel)]},
+            {"page_size": ps, "d_value": dv, "sm_scale": 256 ** -0.5})
+        _close("mla_sparse_attention", got.astype("float32"),
+               want.astype("float32"), MXU_RTOL, MXU_ATOL)
+        done["mla_sparse_attention"] = "compiled"
+    finally:
+        flags.set_flags(prev)
 
 
 def _kernel_ssm_state_step(done):

@@ -84,6 +84,10 @@ __all__ = [
     "hyper_connection",
     "hyper_connection_post",
     "mla_attention",
+    "dsa_index",
+    "dsa_topk",
+    "mla_sparse_attention",
+    "dsa_count",
     "swiglu",
     "short_conv",
     "ssm_scan",
@@ -1530,6 +1534,72 @@ def mla_attention(q, pool, block_table, pos, page_size, d_value, sm_scale,
         outputs={"Out": [out.name]},
         attrs={"page_size": int(page_size), "d_value": int(d_value),
                "sm_scale": float(sm_scale)},
+    )
+    return out
+
+
+def dsa_index(q, w, pool, block_table, pos, page_size, name=None):
+    """The lightning indexer's scores of every cached position a row's block
+    table holds (ops/generation_ops.py ``dsa_index``): ``q`` ``[rows, heads *
+    width]`` against a paged pool of index keys ``[pool_rows, width]``,
+    ``w`` ``[rows, heads]`` float32; returns ``[rows, pages * page_size]``
+    float32, -inf past each row's position."""
+    helper = LayerHelper("dsa_index", name=name)
+    out = helper.create_variable_for_type_inference("float32")
+    helper.append_op(
+        type="dsa_index",
+        inputs={"Q": [q.name], "W": [w.name], "Pool": [pool.name],
+                "BlockTable": [block_table.name], "Pos": [pos.name]},
+        outputs={"Out": [out.name]},
+        attrs={"page_size": int(page_size)},
+    )
+    return out
+
+
+def dsa_topk(scores, k, name=None):
+    """The positions of each row's ``k`` largest scores, ascending, -1
+    behind a row that has fewer (ops/generation_ops.py ``dsa_topk``):
+    ``([rows, k] int32, the same selection as a [rows, positions] bool
+    mask)``."""
+    helper = LayerHelper("dsa_topk", name=name)
+    out = helper.create_variable_for_type_inference("int32")
+    mask = helper.create_variable_for_type_inference("bool")
+    helper.append_op(
+        type="dsa_topk", inputs={"X": [scores.name]},
+        outputs={"Out": [out.name], "Mask": [mask.name]}, attrs={"k": int(k)},
+    )
+    return out, mask
+
+
+def mla_sparse_attention(q, pool, block_table, selected, page_size, d_value,
+                         sm_scale, name=None):
+    """mla_attention over the positions ``selected`` ``[rows, k]`` alone
+    (ops/generation_ops.py ``mla_sparse_attention``); returns ``[rows, n_head
+    * d_value]``."""
+    helper = LayerHelper("mla_sparse_attention", name=name)
+    out = helper.create_variable_for_type_inference(q.dtype)
+    helper.append_op(
+        type="mla_sparse_attention",
+        inputs={"Q": [q.name], "Pool": [pool.name],
+                "BlockTable": [block_table.name], "Selected": [selected.name]},
+        outputs={"Out": [out.name]},
+        attrs={"page_size": int(page_size), "d_value": int(d_value),
+               "sm_scale": float(sm_scale)},
+    )
+    return out
+
+
+def dsa_count(mask, block_table, pos, page_size, name=None):
+    """int32 ``[2]``: the distinct pool rows an index scan over these rows
+    reads, and those the selection ``mask`` (``dsa_topk``'s) names
+    (ops/generation_ops.py ``dsa_count``)."""
+    helper = LayerHelper("dsa_count", name=name)
+    out = helper.create_variable_for_type_inference("int32")
+    helper.append_op(
+        type="dsa_count",
+        inputs={"Mask": [mask.name], "BlockTable": [block_table.name],
+                "Pos": [pos.name]},
+        outputs={"Out": [out.name]}, attrs={"page_size": int(page_size)},
     )
     return out
 

@@ -36,6 +36,20 @@ under the ``plain`` residual, with
     reads each pooled row once for all the heads), the context ``softmax
     c_kv`` through ``W_uv_h``. Rotary is partial (the ``d_rope`` columns),
     interleaved pairs, YaRN frequencies where the configuration scales them;
+  * OP ``"dsa"``: latent attention over a selection (DeepSeek-V3.2's learned
+    sparse attention, ``dsa={heads, d_head, topk, rope_dim, norm_eps,
+    roles}``). A layer whose role is ``"full"`` runs a lightning indexer:
+    index queries ``rot(c_q W_qI)`` (``heads`` of ``d_head``, from the MLA
+    query's own normed latent ``c_q``), one index key ``rot(LayerNorm(u
+    W_kI))`` a position that every index head shares, kept in a paged pool of
+    its own beside the latent pool, head weights ``(u W_w) heads^-0.5``; the
+    score of position s for query t is ``d_head^-0.5 sum_j w_j ReLU(q_j .
+    k_s)`` over s <= t, and the query keeps its ``topk`` best positions
+    (``dsa_index``, ``dsa_topk``; rotary on the first ``rope_dim`` of the
+    ``d_head`` columns, interleaved). A ``"shared"`` layer has no indexer and
+    reuses the selection of the nearest full layer before it. Either way the
+    layer attends absorbed as ``"mla"`` does, over the selected positions
+    alone (``mla_sparse_attention``);
   * FF ``"dense"``: SwiGLU of width ``d_ffn``;
   * FF ``"moe"``: sigmoid top-k router over ``num_experts`` and the held
     experts' gated feed-forwards of width ``moe_width`` (``moe_router`` /
@@ -62,7 +76,8 @@ conv taps are float32, and the norms, router, softmax and the conv's
 accumulation compute in float32 whatever ``dtype`` is. Logits leave the
 last product in float32.
 
-``lfm2_moe(config)``, ``xing4_0(config)`` and ``granitemoehybrid(config)``
+``lfm2_moe(config)``, ``xing4_0(config)``, ``granitemoehybrid(config)`` and
+``glm_moe_dsa(config)``
 turn a ``config.json`` of those model types into such a decoder: the architecture is data for this
 module.
 """
@@ -76,9 +91,10 @@ from ..initializer import Constant, Initializer, Normal
 from ..layer_helper import LayerHelper
 from ..param_attr import ParamAttr
 
-__all__ = ["BlockDecoder", "lfm2_moe", "xing4_0", "granitemoehybrid"]
+__all__ = ["BlockDecoder", "lfm2_moe", "xing4_0", "granitemoehybrid",
+           "glm_moe_dsa"]
 
-OPERATORS = ("attention", "conv", "mla", "mamba")
+OPERATORS = ("attention", "conv", "mla", "mamba", "dsa")
 _LANES = 128  # a latent pool's row is padded to whole lane tiles
 FEED_FORWARDS = ("dense", "moe")
 
@@ -123,14 +139,17 @@ class BlockDecoder:
                  max_context=2048, eos_id=-1, prefix="bd", dtype="bfloat16",
                  init_std=0.02, mla=None, hyper=None, tied_head=True,
                  ssm=None, position="rotary", qk_norm=True, attn_scale=None,
-                 embed_scale=1.0, residual_scale=1.0, logit_scale=1.0):
+                 embed_scale=1.0, residual_scale=1.0, logit_scale=1.0,
+                 dsa=None):
         """``blocks`` is ``[(operator, feed_forward)]``, a layer each.
         ``moe`` is ``{num_experts, k, width, use_bias, norm_topk, scale,
         experts_held, shared_width}`` (the last two optional). ``mla`` is
         ``{q_rank, kv_rank, d_nope, d_rope, d_value, sm_scale, yarn,
         rope_mscale}`` (``yarn`` = [factor, original positions, beta_fast,
         beta_slow] or None). ``hyper`` is ``{streams, iters, eps, clamp}``
-        or None for the plain residual."""
+        or None for the plain residual. ``dsa`` is ``{heads, d_head, topk,
+        rope_dim, norm_eps, roles}``, ``roles`` a layer each ("full",
+        "shared", or None where the operator is not "dsa")."""
         self.blocks = [(str(o), str(f)) for o, f in blocks]
         for o, f in self.blocks:
             if o not in OPERATORS or f not in FEED_FORWARDS:
@@ -149,8 +168,17 @@ class BlockDecoder:
         if any(f == "moe" for _, f in self.blocks) and not self.moe:
             raise ValueError("a moe block needs the moe settings")
         self.mla = dict(mla or {})
-        if any(o == "mla" for o, _ in self.blocks) and not self.mla:
+        if any(o in ("mla", "dsa") for o, _ in self.blocks) and not self.mla:
             raise ValueError("an mla block needs the mla settings")
+        self.dsa = dict(dsa or {})
+        roles = self.dsa.get("roles") or [None] * len(self.blocks)
+        if len(roles) != len(self.blocks) or any(
+                (o == "dsa") != (r in ("full", "shared"))
+                for (o, _), r in zip(self.blocks, roles)):
+            raise ValueError("dsa roles: 'full' or 'shared' for each dsa layer, "
+                             "None for every other")
+        if [r for r in roles if r][:1] == ["shared"]:
+            raise ValueError("a shared dsa layer needs a full one before it")
         self.ssm = dict(ssm or {})
         if any(o == "mamba" for o, _ in self.blocks) and not self.ssm:
             raise ValueError("a mamba block needs the ssm settings")
@@ -195,6 +223,8 @@ class BlockDecoder:
         # its key in the compile cache stays what it was
         if self.ssm:
             spec["ssm"] = {k: self.ssm[k] for k in sorted(self.ssm)}
+        if self.dsa:
+            spec["dsa"] = {k: self.dsa[k] for k in sorted(self.dsa)}
         own = {"position": self.position, "qk_norm": self.qk_norm,
                "attn_scale": self.attn_scale, "embed_scale": self.embed_scale,
                "residual_scale": self.residual_scale,
@@ -227,15 +257,34 @@ class BlockDecoder:
     def moe_layers(self):
         return [i for i, (_, f) in enumerate(self.blocks) if f == "moe"]
 
+    def dsa_role(self, i):
+        """"full", "shared" or None: layer i's part in the selection."""
+        return (self.dsa.get("roles") or [None] * self.n_layer)[i]
+
+    def dsa_groups(self):
+        """[(full layer, the layers that attend its selection)], in layer
+        order: a full layer and the shared layers behind it."""
+        groups = []
+        for i in range(self.n_layer):
+            role = self.dsa_role(i)
+            if role == "full":
+                groups.append((i, [i]))
+            elif role == "shared":
+                groups[-1][1].append(i)
+        return groups
+
     def param_names(self):
         names = [self._p("tok_emb")]
         for i, (op, ff) in enumerate(self.blocks):
             parts = ["op_norm_w"]
             if op == "conv":
                 parts += ["conv_in_w", "conv_k", "conv_out_w"]
-            elif op == "mla":
+            elif op in ("mla", "dsa"):
                 parts += ["q_a_w", "q_a_norm_w", "q_b_w", "kv_a_w",
                           "kv_a_norm_w", "uk_w", "uv_w", "o_w"]
+                if self.dsa_role(i) == "full":
+                    parts += ["idx_q_w", "idx_k_w", "idx_k_norm_w",
+                              "idx_k_norm_b", "idx_w_w"]
             elif op == "mamba":
                 parts += ["ssm_in_w", "ssm_conv_k", "ssm_conv_b",
                           "ssm_dt_bias", "ssm_a_log", "ssm_d", "ssm_norm_w",
@@ -270,7 +319,9 @@ class BlockDecoder:
         (a ``[max_slots + 1, width]`` pool of fixed per-slot rows, row 0
         scratch). An attention layer has a K and a V pool; an mla layer ONE
         pool, ``form`` "latent", whose row holds ``values`` = kv_rank +
-        d_rope values and zeros up to ``width``; a mamba layer two state
+        d_rope values and zeros up to ``width``; a dsa layer the same, and a
+        full one a second pool beside it, ``form`` "index", one index key
+        (``d_head`` values) a position, on the same pages; a mamba layer two state
         pools of different dtype and width, the convolution's and the
         float32 recurrent state's (``form`` "recurrent"; its row is not
         flat but ``shape`` = [d_state, heads * d_head], so a pool is
@@ -285,12 +336,18 @@ class BlockDecoder:
                         "name": self._p(li, s), "kind": "paged", "layer": i,
                         "width": self.n_kv_head * self.d_head,
                         "dtype": self.dtype})
-            elif op == "mla":
+            elif op in ("mla", "dsa"):
                 values, width = self.latent_width()
                 specs.append({
                     "name": self._p(li, "kv_latent"), "kind": "paged",
                     "layer": i, "width": width, "values": values,
                     "form": "latent", "dtype": self.dtype})
+                if self.dsa_role(i) == "full":
+                    d = self.dsa["d_head"]
+                    specs.append({
+                        "name": self._p(li, "index_k"), "kind": "paged",
+                        "layer": i, "width": d, "values": d, "form": "index",
+                        "dtype": self.dtype})
             elif op == "mamba":
                 d_inner, conv_dim = self.ssm_widths()
                 specs.append({
@@ -310,10 +367,10 @@ class BlockDecoder:
         return specs
 
     def kv_pool_names(self):
-        """[(k_pool, v_pool)] of the attention layers (an mla layer's one
-        pool is in cache_specs alone)."""
+        """[(k_pool, v_pool)] of the attention layers (an mla or dsa layer's
+        pools are in cache_specs alone)."""
         paged = [s["name"] for s in self.cache_specs()
-                 if s["kind"] == "paged" and s.get("form") != "latent"]
+                 if s["kind"] == "paged" and s.get("form") is None]
         return list(zip(paged[0::2], paged[1::2]))
 
     # ------------------------------------------------------------ submodules
@@ -368,13 +425,18 @@ class BlockDecoder:
                 lead + [width - values], self.dtype, 0.0)]
         return layers.concat(parts, axis=axis)
 
-    def _mla_query(self, u, i, pos):
+    def _mla_cq(self, u, i):
+        """The query's normed latent c_q = rms(u W_qa) [rows, q_rank]."""
+        return self._norm(self._linear(u, i, "q_a_w", self.mla["q_rank"]),
+                          self._p("l%d" % i, "q_a_norm_w"))
+
+    def _mla_query(self, u, i, pos, c_q=None):
         """The absorbed query [rows, n_head * width]: a head's [q_nope @
         W_uk_h^T | rot(q_rope) | zeros], what meets a latent pool's row."""
-        m, li, rows = self.mla, "l%d" % i, int(u.shape[0])
+        m, rows = self.mla, int(u.shape[0])
         h, dn, dr = self.n_head, m["d_nope"], m["d_rope"]
-        c_q = self._norm(self._linear(u, i, "q_a_w", m["q_rank"]),
-                         self._p(li, "q_a_norm_w"))
+        if c_q is None:
+            c_q = self._mla_cq(u, i)
         q = self._mla_rotary(
             self._linear(c_q, i, "q_b_w", h * (dn + dr)), pos, h, dr)
         q_nope, q_rope = layers.split(
@@ -396,6 +458,40 @@ class BlockDecoder:
         return self._latent_row(
             [self._norm(c_kv, self._p(li, "kv_a_norm_w")),
              self._mla_rotary(k_r, pos, 1)], [int(u.shape[0])], 1)
+
+    def _index_rotary(self, x, pos, heads):
+        """x [rows, heads * d_head] with rotary on the first rope_dim columns
+        of every head, interleaved pairs."""
+        rows, d, dr = int(x.shape[0]), self.dsa["d_head"], self.dsa["rope_dim"]
+        rope, rest = layers.split(
+            layers.reshape(x, [rows, heads, d]), [dr, d - dr], dim=2)
+        rope = layers.reshape(self._mla_rotary(
+            layers.reshape(rope, [rows, heads * dr]), pos, heads),
+            [rows, heads, dr])
+        return layers.reshape(layers.concat([rope, rest], axis=2),
+                              [rows, heads * d])
+
+    def _indexer(self, u, c_q, i, pos):
+        """A full layer's index queries [rows, heads * d_head], each head's
+        weight [rows, heads] float32 (d_head^-0.5 heads^-0.5 folded in) and
+        the rows' index keys [rows, d_head]."""
+        x, li = self.dsa, "l%d" % i
+        heads, d = x["heads"], x["d_head"]
+        q = self._index_rotary(
+            self._linear(c_q, i, "idx_q_w", heads * d), pos, heads)
+        k = layers.layer_norm(
+            layers.cast(self._linear(u, i, "idx_k_w", d), "float32"),
+            epsilon=x["norm_eps"],
+            param_attr=ParamAttr(name=self._p(li, "idx_k_norm_w"),
+                                 initializer=Normal(1.0, 0.1)),
+            bias_attr=ParamAttr(name=self._p(li, "idx_k_norm_b"),
+                                initializer=Normal(0.0, 0.1)))
+        k = self._index_rotary(layers.cast(k, self.dtype), pos, 1)
+        w = layers.fc(layers.cast(u, "float32"), size=heads, num_flatten_dims=1,
+                      param_attr=ParamAttr(name=self._p(li, "idx_w_w"),
+                                           initializer=Normal(0.0, self.init_std)),
+                      bias_attr=False)
+        return q, layers.scale(w, scale=float((heads * d) ** -0.5)), k
 
     def _mla_output(self, ctx, i):
         """Each head's context over c_kv [rows, n_head * kv_rank] through
@@ -551,6 +647,39 @@ class BlockDecoder:
         """[n_moe_layers, rows, k] int32, or None without moe layers."""
         return layers.stack(picks_out, axis=0) if picks_out else None
 
+    def _fetches(self, logits, picks, selection):
+        """logits, then the routers' picks (moe layers), then the full dsa
+        layers' selections [n_full, rows, topk] int32 and what the selection
+        path read, [2 * n_full] int32 (dsa_count's a full layer)."""
+        out = [logits.name] + ([picks.name] if picks is not None else [])
+        if selection["sels"]:
+            out += [layers.stack(selection["sels"], axis=0).name,
+                    layers.concat(selection["counts"], axis=0).name]
+        return out
+
+    def _dsa_attend(self, u, i, pos, pool, table, page_size, selection,
+                    index_pool=None):
+        """A dsa layer's attention: a full layer writes its index keys, scans
+        and selects (selection["sel"], and the count of what it read); every
+        dsa layer then attends the latent pool over the selection."""
+        c_q = self._mla_cq(u, i)
+        if self.dsa_role(i) == "full":
+            q_i, w_i, k_i = self._indexer(u, c_q, i, pos)
+            if index_pool is None:  # a whole sequence: its keys are the pool
+                index_pool = k_i
+            else:
+                layers.kv_cache_write(index_pool, k_i, table, pos, page_size)
+            sel, mask = layers.dsa_topk(layers.dsa_index(
+                q_i, w_i, index_pool, table, pos, page_size),
+                self.dsa["topk"])
+            selection["sel"] = sel
+            selection["sels"].append(sel)
+            selection["counts"].append(layers.dsa_count(
+                mask, table, selection.get("count_pos", pos), page_size))
+        return self._mla_output(layers.mla_sparse_attention(
+            self._mla_query(u, i, pos, c_q), pool, table, selection["sel"],
+            page_size, self.mla["kv_rank"], self.mla["sm_scale"]), i)
+
     def _cache_vars(self, pool_rows, state_rows):
         block = framework.default_main_program().global_block()
         out = {}
@@ -561,12 +690,22 @@ class BlockDecoder:
                 dtype=s["dtype"], persistable=True)
         return out
 
-    def _cached_operator(self, caches, table, pos, page_size, conv_state):
+    def _cached_operator(self, caches, table, pos, page_size, conv_state,
+                         selection=None):
         """OP through the caches: attention writes its K/V rows and attends
-        the pool; mla writes its latent row and attends it absorbed; conv
-        and mamba read and write their state row(s)."""
+        the pool; mla writes its latent row and attends it absorbed (dsa
+        over its selection, `selection` carrying it from a full layer to the
+        shared ones behind it); conv and mamba read and write their state
+        row(s)."""
         def operator(u, i):
             li = "l%d" % i
+            if self.blocks[i][0] == "dsa":
+                pool = caches[self._p(li, "kv_latent")]
+                layers.kv_cache_write(
+                    pool, self._mla_latent(u, i, pos), table, pos, page_size)
+                return self._dsa_attend(
+                    u, i, pos, pool, table, page_size, selection,
+                    caches.get(self._p(li, "index_k")))
             if self.blocks[i][0] == "conv":
                 return self._conv(
                     u, i, state=caches[self._p(li, "conv_state")], **conv_state)
@@ -640,6 +779,21 @@ class BlockDecoder:
                         [t, self.n_head * m["kv_rank"]]))
                 return self._mla_output(layers.concat(ctxs, axis=0), i)
 
+            # dsa: a sequence's latent rows are its pool, one page of t rows
+            one_page = layers.assign(np.zeros([1], "int32")) if self.dsa else None
+            pos_t = layers.assign(np.arange(t, dtype="int64")) if self.dsa else None
+            selections = [{"sels": [], "counts": []} for _ in range(batch)]
+
+            def dsa_operator(u, i):
+                rows = lambda y, b: layers.slice(y, [0], [b * t], [(b + 1) * t])
+                lat = self._mla_latent(u, i, pos)
+                outs = []
+                for b in range(batch):
+                    outs.append(self._dsa_attend(
+                        rows(u, b), i, pos_t, rows(lat, b), one_page, t,
+                        selections[b]))
+                return layers.concat(outs, axis=0)
+
             def operator(u, i):
                 if self.blocks[i][0] == "conv":
                     return self._conv(u, i, seq_len=t)
@@ -647,6 +801,8 @@ class BlockDecoder:
                     return self._mamba(u, i, seq_len=t)
                 if self.blocks[i][0] == "mla":
                     return mla_operator(u, i)
+                if self.blocks[i][0] == "dsa":
+                    return dsa_operator(u, i)
                 q, k, v = self._qkv(u, i, pos)
                 # [b, n_kv, group * t, d] against [b, n_kv, t, d]
                 q = layers.reshape(
@@ -673,7 +829,13 @@ class BlockDecoder:
                 x = self._layer(x, i, operator, None, picks_out)
             logits = layers.reshape(self._logits(x), [batch, t, self.vocab_size])
             picks = self._picks(picks_out)
-        fetches = [logits.name] + ([picks.name] if picks is not None else [])
+            # the selections [n_full, batch * t, topk], sequence after sequence
+            selection = {"sels": [], "counts": []}
+            for f in range(len(selections[0]["sels"])):
+                selection["sels"].append(layers.concat(
+                    [sl["sels"][f] for sl in selections], axis=0))
+                selection["counts"].append(selections[0]["counts"][f])
+            fetches = self._fetches(logits, picks, selection)
         return main, startup, ["fwd_tokens"], fetches
 
     def build_prefill(self, t, page_size, max_pages, pool_rows, state_rows=1):
@@ -699,17 +861,26 @@ class BlockDecoder:
             pos = layers.elementwise_add(
                 layers.assign(np.arange(t, dtype="int64")), start)
             x = self._embed(tokens, t)
+            selection = {"sels": [], "counts": []}
+            if self.dsa:  # the padding rows behind the prompt count nothing
+                selection["count_pos"] = layers.elementwise_add(
+                    layers.elementwise_mul(
+                        layers.elementwise_add(pos, layers.fill_constant(
+                            [t], "int64", 1.0)),
+                        layers.cast(live, "int64")),
+                    layers.fill_constant([t], "int64", -1.0))
             operator = self._cached_operator(
                 caches, pages, pos, page_size,
-                dict(rows=state_row, start=start, live=live, mode="chunk"))
+                dict(rows=state_row, start=start, live=live, mode="chunk"),
+                selection)
             picks_out = []
             for i in range(self.n_layer):
                 x = self._layer(x, i, operator, live, picks_out)
             logits = self._logits(layers.gather(x, last))
             picks = self._picks(picks_out)
+            fetches = self._fetches(logits, picks, selection)
         feeds = ["gen_tokens", "gen_start", "gen_last", "gen_pages",
                  "gen_live", "gen_state_row"]
-        fetches = [logits.name] + ([picks.name] if picks is not None else [])
         return main, startup, feeds, fetches
 
     def build_decode(self, slots, page_size, max_pages, pool_rows, state_rows=1):
@@ -732,17 +903,18 @@ class BlockDecoder:
             state_rows_ = data("dec_state_rows", [slots], "int32")
             caches = self._cache_vars(pool_rows, state_rows)
             x = self._embed(tokens, slots)
+            selection = {"sels": [], "counts": []}
             operator = self._cached_operator(
                 caches, table, positions, page_size,
-                dict(rows=state_rows_, mode="step"))
+                dict(rows=state_rows_, mode="step"), selection)
             picks_out = []
             for i in range(self.n_layer):
                 x = self._layer(x, i, operator, live, picks_out)
             logits = self._logits(x)
             picks = self._picks(picks_out)
+            fetches = self._fetches(logits, picks, selection)
         feeds = ["dec_tokens", "dec_positions", "dec_block_table", "dec_live",
                  "dec_state_rows"]
-        fetches = [logits.name] + ([picks.name] if picks is not None else [])
         return main, startup, feeds, fetches
 
     # ---------------------------------------------------------------- params
@@ -926,3 +1098,89 @@ def granitemoehybrid(config, **kw):
         embed_scale=float(c["embedding_multiplier"]),
         residual_scale=float(c["residual_multiplier"]),
         logit_scale=float(c["logits_scaling"]), **kw)
+
+
+def glm_moe_dsa(config, **kw):
+    """The BlockDecoder of a ``glm_moe_dsa`` ``config.json`` (as a dict,
+    under the source's own keys): every layer latent attention (``q_lora_rank``,
+    ``kv_lora_rank``, ``qk_nope_head_dim``, ``qk_rope_head_dim``,
+    ``v_head_dim``; rotary from ``rope_parameters``, interleaved) over a
+    learned sparse selection: ``indexer_types`` gives each layer's role, a
+    "full" layer runs the lightning indexer (``index_n_heads`` heads of
+    ``index_head_dim``, rotary on the first ``qk_rope_head_dim`` of them,
+    ``indexer_rope_interleave``) and keeps ``index_topk`` positions a query,
+    a "shared" one reuses the selection of the full layer before it.
+    ``mlp_layer_types`` gives each layer's feed-forward: "dense" (SwiGLU of
+    ``intermediate_size``) or "sparse" (``n_routed_experts`` sigmoid top-k
+    experts, ``noaux_tc``, beside ``n_shared_experts`` shared ones). The head
+    is untied unless ``tie_word_embeddings``. ``experts_held`` (keyword)
+    lists the routed experts this chip holds; the router keeps its width.
+    Not built: the multi-token-prediction layers (``num_nextn_predict_layers``:
+    the main model's forward pass does not read them), DeepSeek-V3.2's
+    Hadamard rotation and FP8 of the index queries and keys (the rotation is
+    orthogonal and leaves q . k as it is). Further keywords (max_context,
+    dtype, eos_id, prefix) pass through."""
+    c = config
+
+    def need(key, want):
+        raise ValueError("%s = %r is not built (only %s)" % (
+            key, c.get(key), want))
+
+    for key, want in (("n_group", 1), ("topk_group", 1), ("moe_layer_freq", 1),
+                      ("scoring_func", "sigmoid"), ("topk_method", "noaux_tc"),
+                      ("hidden_act", "silu"), ("attention_bias", False),
+                      ("rope_interleave", True),
+                      ("indexer_rope_interleave", True),
+                      ("index_topk_pattern", None)):
+        if c.get(key, want) != want:
+            need(key, repr(want))
+    rope = c.get("rope_parameters") or {}
+    if rope.get("rope_type", "default") != "default":
+        need("rope_parameters", "rope_type 'default'")
+    n, n_head = int(c["num_hidden_layers"]), int(c["num_attention_heads"])
+    if int(c.get("num_key_value_heads", n_head)) != n_head:
+        need("num_key_value_heads", n_head)
+    d_nope, d_rope = int(c["qk_nope_head_dim"]), int(c["qk_rope_head_dim"])
+    if int(c.get("qk_head_dim", d_nope + d_rope)) != d_nope + d_rope:
+        need("qk_head_dim", d_nope + d_rope)
+    roles, mlps = list(c.get("indexer_types") or []), list(c.get("mlp_layer_types") or [])
+    if len(roles) != n or set(roles) - {"full", "shared"} or roles[0] != "full":
+        need("indexer_types", "'full' or 'shared' for each of num_hidden_layers "
+             "layers, the first 'full'")
+    if len(mlps) != n or set(mlps) - {"dense", "sparse"}:
+        need("mlp_layer_types", "'dense' or 'sparse' for each layer")
+    blocks = [("dsa", "dense" if f == "dense" else "moe") for f in mlps]
+    moe = {
+        "num_experts": int(c["n_routed_experts"]),
+        "k": int(c["num_experts_per_tok"]),
+        "width": int(c["moe_intermediate_size"]),
+        "use_bias": True,
+        "norm_topk": bool(c.get("norm_topk_prob", True)),
+        "scale": float(c.get("routed_scaling_factor", 1.0)),
+        "shared_width": (int(c.get("n_shared_experts") or 0)
+                         * int(c["moe_intermediate_size"])),
+    }
+    held = kw.pop("experts_held", None)
+    if held is not None:
+        moe["experts_held"] = [int(e) for e in held]
+    mla = {
+        "q_rank": int(c["q_lora_rank"]), "kv_rank": int(c["kv_lora_rank"]),
+        "d_nope": d_nope, "d_rope": d_rope, "d_value": int(c["v_head_dim"]),
+        "sm_scale": (d_nope + d_rope) ** -0.5, "yarn": None, "rope_mscale": 1.0,
+    }
+    dsa = {
+        "heads": int(c["index_n_heads"]), "d_head": int(c["index_head_dim"]),
+        "topk": int(c["index_topk"]), "rope_dim": d_rope,
+        # DeepSeek-V3.2's index-key LayerNorm; the config does not say
+        "norm_eps": 1e-6, "roles": roles,
+    }
+    kw.setdefault("max_context", int(c["max_position_embeddings"]))
+    kw.setdefault("prefix", "glm5")
+    return BlockDecoder(
+        vocab_size=int(c["vocab_size"]), d_model=int(c["hidden_size"]),
+        blocks=blocks, n_head=n_head, n_kv_head=n_head,
+        d_head=d_nope + d_rope, d_ffn=int(c["intermediate_size"]), moe=moe,
+        norm_eps=float(c["rms_norm_eps"]),
+        rope_theta=float(rope.get("rope_theta", c.get("rope_theta", 10000.0))),
+        mla=mla, dsa=dsa,
+        tied_head=bool(c.get("tie_word_embeddings", False)), **kw)

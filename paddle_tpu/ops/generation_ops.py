@@ -266,3 +266,278 @@ def _mla_attention(ctx, ins, attrs):
     out = jnp.einsum(
         "shc,cv->shv" if shared else "shc,scv->shv", w, k[..., :d_value])
     return {"Out": [out.reshape(s, n_head * d_value).astype(q.dtype)]}
+
+
+# ---------------------------------------------------------------------------
+# learned sparse attention (DeepSeek-V3.2's DSA): a lightning indexer scores
+# every cached position, each query keeps its top `k`, and latent attention
+# reads only those
+# ---------------------------------------------------------------------------
+
+
+def _table_rows(bt, page_size):
+    """Pool row of every position a block table holds, [..., P * page_size]."""
+    offsets = jnp.arange(page_size, dtype=jnp.int32)
+    return (bt.astype(jnp.int32)[..., None] * page_size + offsets).reshape(
+        bt.shape[:-1] + (bt.shape[-1] * page_size,))
+
+
+def _positions_rows(bt, positions, page_size):
+    """Pool row of each position in `positions` [S, k] (< 0: none, row 0)
+    through the block table ([S, P], or [P] shared by every row)."""
+    p = jnp.maximum(positions, 0)
+    page = p // page_size
+    if bt.ndim == 1:
+        page_id = bt.astype(jnp.int32)[page]
+    else:
+        page_id = jnp.take_along_axis(bt.astype(jnp.int32), page, axis=1)
+    return jnp.where(positions >= 0, page_id * page_size + p % page_size, 0)
+
+
+def _dsa_index_infer(op, block):
+    q = block._var_recursive(op.inputs["Q"][0])
+    bt = block._var_recursive(op.inputs["BlockTable"][0])
+    out = block._var_recursive(op.outputs["Out"][0])
+    out.shape = (q.shape[0], bt.shape[-1] * int(op.attrs["page_size"]))
+    out.dtype = "float32"
+
+
+@register("dsa_index", no_grad=True, infer_shape=_dsa_index_infer)
+def _dsa_index(ctx, ins, attrs):
+    """The lightning indexer's scan over a paged pool of index keys (one
+    key a position, shared by every index head). Q is ``[S, heads * width]``
+    (each head's rotated query), W ``[S, heads]`` (each head's weight, its
+    scales folded in), BlockTable and Pos paged_attention's. Out ``[S, P *
+    page_size]`` float32: ``sum_j W[s, j] ReLU(Q[s, j] . key(c))`` at every
+    position c <= Pos[s] of the row's table, -inf past it. On TPU (or when
+    FLAGS_paged_flash forces it) the lowering is the Pallas scan
+    dsa_index_scores, which walks only the pages up to each program's
+    largest position and sums the heads inside the kernel; the dense form
+    below is the decline target and the parity oracle."""
+    (q,) = ins["Q"]
+    (w,) = ins["W"]
+    (pool,) = ins["Pool"]
+    (bt,) = ins["BlockTable"]
+    (pos,) = ins["Pos"]
+    page_size = int(attrs["page_size"])
+    s, width = q.shape[0], pool.shape[-1]
+    heads = q.shape[-1] // width
+
+    from . import pallas_kernels as _pk
+
+    if _pk.dsa_index_path_taken(s, bt.shape[-1], page_size, width):
+        return {"Out": [_pk.dsa_index_scores(
+            q, w, pool, bt, pos, heads=heads, page_size=page_size)]}
+    flat = _table_rows(bt, page_size)
+    k = jnp.take(pool, flat.reshape(-1), axis=0).astype(jnp.float32).reshape(
+        flat.shape + (width,))
+    qh = q.reshape(s, heads, width).astype(jnp.float32)
+    dots = jnp.einsum("shw,cw->shc" if bt.ndim == 1 else "shw,scw->shc", qh, k)
+    scores = jnp.einsum("shc,sh->sc", jnp.maximum(dots, 0.0),
+                        w.astype(jnp.float32))
+    live = (jnp.arange(flat.shape[-1], dtype=jnp.int32)[None, :]
+            <= pos.reshape(-1, 1).astype(jnp.int32))
+    return {"Out": [jnp.where(live, scores, -jnp.inf)]}
+
+
+def _ordered_keys(scores):
+    """float32 -> uint32 in the same order (-0.0 and 0.0 apart, -inf
+    least)."""
+    u = jax.lax.bitcast_convert_type(scores.astype(jnp.float32), jnp.uint32)
+    return jnp.where(u >> 31 == 1, ~u, u | jnp.uint32(0x80000000))
+
+
+def topk_positions(scores, k):
+    """The exact top-k of every row of scores [S, L] (-inf: no position):
+    (positions [S, k] int32 in ascending order, -1 behind the row's last
+    where it has fewer than k; the same selection as a mask [S, L] bool).
+    Ties at the k-th score go to the lowest positions. No sort over L and no
+    gather: the k-th largest key is found four bits at a time (8 passes,
+    each counting the row against 15 candidates at once), the positions
+    above it and enough of the ties are marked, and the j-th marked position
+    is found by counting: the 128-wide block whose running count passes j
+    by comparison, then the lane inside it through a one-hot product with
+    the blocks' running counts (integers under 129: exact in bfloat16)."""
+    rows, n = scores.shape
+    key = _ordered_keys(scores)
+    digits = jnp.arange(1, 16, dtype=jnp.uint32)
+
+    def one_digit(i, t):
+        shift = (28 - 4 * i).astype(jnp.uint32)
+        cand = t[:, None] | (digits[None, :] << shift)  # [rows, 15]
+        above = jnp.sum((key[:, None, :] >= cand[:, :, None]).astype(jnp.int32),
+                        axis=2)
+        # counts fall as the digit rises: how many pass is the digit
+        return t | (jnp.sum((above >= k).astype(jnp.uint32), axis=1) << shift)
+
+    t = jax.lax.fori_loop(0, 8, one_digit, jnp.zeros((rows,), jnp.uint32))
+    gt = key > t[:, None]
+    eq = key == t[:, None]
+    room = k - jnp.sum(gt.astype(jnp.int32), axis=1, keepdims=True)
+    take = gt | (eq & (jnp.cumsum(eq.astype(jnp.int32), axis=1) <= room))
+    take = take & (scores > -jnp.inf)
+    count = jnp.cumsum(take.astype(jnp.int32), axis=1)  # inclusive
+    pad = -n % 128
+    if pad:
+        count = jnp.pad(count, ((0, 0), (0, pad)), mode="edge")
+    blocks = count.reshape(rows, -1, 128)
+    ends = blocks[:, :, -1]  # [rows, nb]: marked up to a block's end
+    before = jnp.concatenate(
+        [jnp.zeros((rows, 1), jnp.int32), ends[:, :-1]], axis=1)
+    j = jnp.arange(k, dtype=jnp.int32)[None, :, None]
+    hot = (before[:, None, :] <= j) & (ends[:, None, :] > j)  # [rows, k, nb]
+    blk = jnp.sum(jnp.where(hot, jnp.arange(ends.shape[1], dtype=jnp.int32), 0),
+                  axis=2)
+    rank = j[:, :, 0] - jnp.sum(jnp.where(hot, before[:, None, :], 0), axis=2)
+    lanes = jnp.einsum(
+        "rkb,rbl->rkl", hot.astype(jnp.bfloat16),
+        (blocks - before[:, :, None]).astype(jnp.bfloat16),
+        preferred_element_type=jnp.float32)
+    at = blk * 128 + jnp.sum((lanes <= rank[:, :, None]).astype(jnp.int32), axis=2)
+    return jnp.where(j[:, :, 0] < count[:, -1:], at, -1).astype(jnp.int32), take
+
+
+def _dsa_oracle():
+    """The dsa_topk and mla_sparse_attention lowerings' choice, under the
+    paged_flash flag: "off" takes their parity oracles (jax.lax.top_k, a
+    sort on the chip; every position of the table, masked to the
+    selection), anything else the served forms."""
+    from .. import flags as _flags
+
+    return _flags.get_flags("paged_flash")["paged_flash"] == "off"
+
+
+def _dsa_topk_infer(op, block):
+    x = block._var_recursive(op.inputs["X"][0])
+    out = block._var_recursive(op.outputs["Out"][0])
+    out.shape = (x.shape[0], int(op.attrs["k"]))
+    out.dtype = "int32"
+    mask = block._var_recursive(op.outputs["Mask"][0])
+    mask.shape, mask.dtype = tuple(x.shape), "bool"
+
+
+@register("dsa_topk", no_grad=True, infer_shape=_dsa_topk_infer)
+def _dsa_topk(ctx, ins, attrs):
+    """The positions of each row's k largest scores (X [S, L] float32, -inf
+    where there is no position), ascending, -1 behind a row with fewer than
+    k: Out [S, k] int32; Mask [S, L] bool, the same selection. Exact; ties go
+    to the lowest positions."""
+    (x,) = ins["X"]
+    k, n = int(attrs["k"]), x.shape[1]
+    if k > n:
+        x = jnp.pad(x, ((0, 0), (0, k - n)), constant_values=-jnp.inf)
+    if not _dsa_oracle():
+        out, mask = topk_positions(x, k)
+        return {"Out": [out], "Mask": [mask[:, :n]]}
+    top, idx = jax.lax.top_k(x.astype(jnp.float32), k)
+    idx = jnp.sort(jnp.where(top > -jnp.inf, idx, x.shape[1]), axis=1)
+    rows = jnp.arange(x.shape[0])[:, None]
+    mask = jnp.zeros((x.shape[0], x.shape[1] + 1), bool).at[rows, idx].set(True)
+    return {"Out": [jnp.where(idx < x.shape[1], idx, -1).astype(jnp.int32)],
+            "Mask": [mask[:, :n]]}
+
+
+def _mla_sparse_infer(op, block):
+    q = block._var_recursive(op.inputs["Q"][0])
+    pool = block._var_recursive(op.inputs["Pool"][0])
+    out = block._var_recursive(op.outputs["Out"][0])
+    heads = q.shape[-1] // pool.shape[-1]
+    out.shape = (q.shape[0], heads * int(op.attrs["d_value"]))
+    out.dtype = q.dtype
+
+
+@register("mla_sparse_attention", no_grad=True, infer_shape=_mla_sparse_infer)
+def _mla_sparse_attention(ctx, ins, attrs):
+    """mla_attention over the positions Selected [S, k] (ascending, -1: none)
+    alone: each row gathers its k pooled rows through the block table, by
+    (page, offset), and attends them absorbed, softmax over the selection.
+    Every query head of a row shares its selection. With FLAGS_paged_flash
+    "off" the lowering is the parity oracle instead: every position of the
+    table, masked to the selection."""
+    (q,) = ins["Q"]
+    (pool,) = ins["Pool"]
+    (bt,) = ins["BlockTable"]
+    (sel,) = ins["Selected"]
+    page_size = int(attrs["page_size"])
+    d_value = int(attrs["d_value"])
+    scale = float(attrs["sm_scale"])
+    s, width = q.shape[0], pool.shape[-1]
+    n_head = q.shape[-1] // width
+    sel = sel.astype(jnp.int32)
+    qh = q.reshape(s, n_head, width)
+    if not _dsa_oracle():
+        rows = _positions_rows(bt, sel, page_size)
+        kv = jnp.take(pool, rows.reshape(-1), axis=0).reshape(s, -1, width)
+        live = (sel >= 0)[:, None, :]
+        # the gathered rows as the left operand, contracted on their last
+        # axis: the layout the gather leaves them in, so they are not
+        # copied to a transposed one first
+        scores = jnp.einsum("skw,shw->shk", kv, qh.astype(pool.dtype),
+                            preferred_element_type=jnp.float32)
+    else:
+        flat = _table_rows(bt, page_size)
+        kv = jnp.take(pool, flat.reshape(-1), axis=0).reshape(
+            flat.shape + (width,))
+        n = flat.shape[-1]
+        live = jnp.any(jnp.arange(n)[None, None, :] == sel[:, :, None],
+                       axis=1)[:, None, :]
+        if bt.ndim == 1:
+            kv = jnp.broadcast_to(kv, (s, n, width))
+        scores = jnp.einsum("shw,skw->shk", qh.astype(pool.dtype), kv,
+                            preferred_element_type=jnp.float32)
+    scores = jnp.where(live, scores * scale, -jnp.inf)
+    m = jnp.max(scores, axis=-1, keepdims=True)
+    m = jnp.where(jnp.isfinite(m), m, 0.0)
+    w = jnp.where(live, jnp.exp(scores - m), 0.0)
+    denom = jnp.sum(w, axis=-1, keepdims=True)
+    w = w / jnp.where(denom > 0.0, denom, 1.0)
+    out = jnp.einsum("shk,skv->shv", w.astype(pool.dtype), kv[..., :d_value],
+                     preferred_element_type=jnp.float32)
+    return {"Out": [out.reshape(s, n_head * d_value).astype(q.dtype)]}
+
+
+def _dsa_count_infer(op, block):
+    out = block._var_recursive(op.outputs["Out"][0])
+    out.shape, out.dtype = (2,), "int32"
+
+
+@register("dsa_count", no_grad=True, infer_shape=_dsa_count_infer)
+def _dsa_count(ctx, ins, attrs):
+    """What a step's selection path read, for the rooflines: Out int32 [2] =
+    [distinct pool rows the index scan had to read (every position up to
+    each row's own through its table), distinct pool rows the selection Mask
+    [S, P * page_size] names]. A row shared by several query rows (a
+    document's pages) counts once; the scratch page, and a query row whose
+    Pos is < 0, count never. No sort: a decode row counts a page's rows
+    unless a lower row's table holds the same page, found by comparing the
+    tables page by page and, for the selection, one [S, S] product a page."""
+    (mask,) = ins["Mask"]
+    (bt,) = ins["BlockTable"]
+    (pos,) = ins["Pos"]
+    ps = int(attrs["page_size"])
+    pos = pos.reshape(-1).astype(jnp.int32)
+    mask = mask & (pos[:, None] >= 0)
+    if bt.ndim == 1:  # one sequence: positions 0..max(pos), one table
+        scanned = jnp.maximum(jnp.max(pos) + 1, 0)
+        real = jnp.repeat(bt.astype(jnp.int32) > 0, ps)[None, :]
+        picked = jnp.sum((jnp.any(mask, axis=0) & real[0]).astype(jnp.int32))
+        return {"Out": [jnp.stack([scanned, picked]).astype(jnp.int32)]}
+    s, n_pages = bt.shape
+    pages = bt.astype(jnp.int32)
+    first = jnp.arange(n_pages, dtype=jnp.int32)[None, :] * ps
+    used = jnp.where(pages > 0, jnp.clip(pos[:, None] - first + 1, 0, ps), 0)
+    # same[p, r, r']: rows r and r' hold one page at table entry p, r' < r
+    lower = jnp.arange(s)[:, None] > jnp.arange(s)[None, :]
+    held = pages.T[:, :, None] == pages.T[:, None, :]  # [P, S, S]
+    same = held & (pages.T[:, :, None] > 0) & lower[None]
+    # a page's rows count with the first row that holds it, as many as any
+    # holder reads
+    most = jnp.max(jnp.where(held, used.T[:, None, :], 0), axis=2).T
+    held_before = jnp.any(same & (used.T[:, None, :] > 0), axis=2).T
+    scanned = jnp.sum(jnp.where((used > 0) & ~held_before, most, 0))
+    m = mask.reshape(s, n_pages, ps).transpose(1, 0, 2).astype(jnp.bfloat16)
+    before = jnp.einsum("prq,pqo->pro", same.astype(jnp.bfloat16), m,
+                        preferred_element_type=jnp.float32)
+    picked = jnp.sum(((m > 0) & (before == 0)
+                      & (pages.T[:, :, None] > 0)).astype(jnp.int32))
+    return {"Out": [jnp.stack([scanned, picked]).astype(jnp.int32)]}

@@ -55,6 +55,9 @@ __all__ = [
     "latent_paged_attention",
     "latent_paged_path_taken",
     "latent_positions_walked",
+    "dsa_index_scores",
+    "dsa_index_path_taken",
+    "index_positions_walked",
     "hyper_maps",
     "hyper_maps_path_taken",
     "grouped_expert_ffn",
@@ -2185,6 +2188,185 @@ def latent_paged_attention(q, pool, block_table, pos, *, n_head, page_size,
         q.astype(pool.dtype).reshape(n_prog * own, width), pool,
     )
     return out[: rows * n_head].reshape(rows, n_head * d_value)
+
+
+# ---------------------------------------------------------------------------
+# the lightning indexer's scan (dsa_index): every index key a row's block
+# table holds up to its position, scored by the row's index heads
+# ---------------------------------------------------------------------------
+
+# Positions in one compute block of the scan, and the tokens of a prefill
+# chunk one program owns (x heads query rows: 16 x 32 = 512 MXU rows against
+# 512 positions). A decode program owns one token, as the latent walk's does.
+_INDEX_BLOCK_POSITIONS = 512
+_INDEX_CHUNK_TOKENS = 16
+_INDEX_VMEM_BYTES = 64 * 1024 * 1024
+
+
+def dsa_index_path_taken(n_q, n_pages, page_size, width):
+    """EXACT mirror of the dsa_index lowering's kernel-vs-dense decision,
+    under the paged_flash flag as latent_paged_path_taken: "auto" takes the
+    kernel on a TPU when a page is whole sublane tiles of bfloat16 and an
+    index key whole lane tiles."""
+    from .. import flags as _flags
+
+    mode = _flags.get_flags("paged_flash")["paged_flash"]
+    if mode == "off":
+        return False
+    if min(int(n_q), int(n_pages), int(page_size), int(width)) < 1:
+        return False
+    if mode == "on":
+        return True
+    return (jax.default_backend() == "tpu" and int(page_size) % 16 == 0
+            and int(width) % _LANES == 0)
+
+
+def _index_block_pages(n_pages, page_size):
+    return max(1, min(_INDEX_BLOCK_POSITIONS // int(page_size), int(n_pages)))
+
+
+def index_positions_walked(pos, page_size, n_pages):
+    """EXACT mirror of dsa_index_scores' trip count for decode rows, in
+    positions, as latent_positions_walked is of the latent walk's: each row
+    takes pos // block + 1 blocks, none for pos < 0."""
+    block = int(page_size) * _index_block_pages(n_pages, page_size)
+    pos = np.asarray(pos, dtype=np.int64).reshape(-1)
+    most = -(-int(n_pages) * int(page_size) // block)
+    return int(np.clip(pos // block + 1, 0, most).sum()) * block
+
+
+def _dsa_index_kernel(bt_ref, last_ref, pos_ref, q_ref, w_ref, pool, o_ref,
+                      buf, sem, *, page_size, n_pages, block_pages, per_row,
+                      heads):
+    """One program's tokens (heads query rows each) against the index keys
+    one block table holds up to `last`, the program's largest position: a
+    block of keys is read once and met by one MXU product, rows x block; the
+    ReLU, each head's weight and the sum over a token's heads stay on the
+    VPU in float32, so no [tokens, heads, positions] array leaves the
+    kernel. o_ref is (blocks, tokens, block): a block's scores where the
+    position is at most the token's own, -inf everywhere else. The walk and
+    its double buffer are _latent_paged_kernel's."""
+    i = pl.program_id(0)
+    last = last_ref[i]
+    page_of = (lambda j: bt_ref[i, j]) if per_row else (lambda j: bt_ref[j])
+    pos = pos_ref[...]  # (tokens, 1)
+    tokens = pos.shape[0]
+    block = block_pages * page_size
+    n_blocks = jnp.clip(last // block + 1, 0, pl.cdiv(n_pages, block_pages))
+
+    @pl.when(i == 0)
+    def _once():  # what a page never copied leaves in a buffer stays finite
+        buf[...] = jnp.zeros_like(buf)
+
+    o_ref[...] = jnp.full(o_ref.shape, -jnp.inf, o_ref.dtype)
+
+    def page_copies(b, slot, start):
+        first = b * block_pages
+        n_live = jnp.clip(
+            jnp.minimum(last // page_size + 1, n_pages) - first, 0, block_pages
+        )
+
+        def _page(j, _):
+            row0 = pl.multiple_of(page_of(first + j) * page_size, page_size)
+            copy = pltpu.make_async_copy(
+                pool.at[pl.ds(row0, page_size)], buf.at[slot, j], sem.at[slot])
+            copy.start() if start else copy.wait()
+
+        jax.lax.fori_loop(0, n_live, _page, None)
+
+    @pl.when(n_blocks > 0)
+    def _first():
+        page_copies(0, 0, True)
+
+    def _block(b, _):
+        slot = b % 2
+
+        @pl.when(b + 1 < n_blocks)
+        def _next():
+            page_copies(b + 1, 1 - slot, True)
+
+        page_copies(b, slot, False)
+        k = buf[slot].reshape(block, buf.shape[-1])
+        s = jax.lax.dot_general(
+            q_ref[...], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        s = (jnp.maximum(s, 0.0) * w_ref[...]).reshape(tokens, heads, block)
+        live = b * block + jax.lax.broadcasted_iota(
+            jnp.int32, (tokens, block), 1) <= pos
+        o_ref[b] = jnp.where(live, jnp.sum(s, axis=1), -jnp.inf)
+
+    jax.lax.fori_loop(0, n_blocks, _block, None)
+
+
+def dsa_index_scores(q, w, pool, block_table, pos, *, heads, page_size,
+                     interpret=None):
+    """The lightning indexer's scores of every position a row's table holds:
+    q [rows, heads * width] (each head's query, rotated), w [rows, heads]
+    float32 (each head's weight, its scales folded in), pool [pool_rows,
+    width] (one index key a position, shared by every head). Returns
+    [rows, n_pages * page_size] float32: at position c of row r, sum_j
+    w[r, j] * ReLU(q[r, j] . key(c)) where c <= pos[r], -inf elsewhere (a
+    row with pos < 0: -inf whole). block_table is [rows, P] (decode) or [P]
+    (a prefill chunk), as latent_paged_attention's; a page wholly past a
+    program's largest position is never copied."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    rows, feat = q.shape
+    width = feat // heads
+    bt = block_table.astype(jnp.int32)
+    pos_v = pos.reshape(-1).astype(jnp.int32)
+    w = w.astype(jnp.float32)
+    per_row = bt.ndim == 2
+    n_pages = bt.shape[-1]
+    block_pages = _index_block_pages(n_pages, page_size)
+    block = block_pages * page_size
+    n_blk = pl.cdiv(n_pages, block_pages)
+    tokens = 1 if per_row else min(rows, _INDEX_CHUNK_TOKENS)
+    n_prog = pl.cdiv(rows, tokens)
+    if n_prog * tokens > rows:  # rows of no token: masked whole
+        pad = n_prog * tokens - rows
+        q = jnp.pad(q, ((0, pad), (0, 0)))
+        w = jnp.pad(w, ((0, pad), (0, 0)))
+        pos_v = jnp.pad(pos_v, (0, pad), constant_values=-1)
+    own = tokens * heads  # a program's query rows
+    _note_dispatch("dsa_index")
+    mine = lambda i, bt_r, last_r: (i, 0, 0)
+    out = pl.pallas_call(
+        functools.partial(
+            _dsa_index_kernel, page_size=page_size, n_pages=n_pages,
+            block_pages=block_pages, per_row=per_row, heads=heads,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n_prog,),
+            in_specs=[
+                pl.BlockSpec((None, tokens, 1), mine),
+                pl.BlockSpec((None, own, width), mine),
+                pl.BlockSpec((None, own, 1), mine),
+                pl.BlockSpec(memory_space=pltpu.HBM),
+            ],
+            out_specs=pl.BlockSpec(
+                (None, n_blk, tokens, block), lambda i, bt_r, last_r: (i, 0, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, block_pages, page_size, width), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(
+            (n_prog, n_blk, tokens, block), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_INDEX_VMEM_BYTES,
+        ),
+        interpret=interpret,
+    )(
+        bt, pos_v.reshape(n_prog, tokens).max(axis=1),
+        pos_v.reshape(n_prog, tokens, 1),
+        q.astype(pool.dtype).reshape(n_prog, own, width),
+        w.reshape(n_prog, own, 1), pool,
+    )
+    out = out.transpose(0, 2, 1, 3).reshape(n_prog * tokens, n_blk * block)
+    return out[:rows, : n_pages * page_size]
 
 
 # ---------------------------------------------------------------------------

@@ -181,7 +181,8 @@ class _SlotRun:
 
     __slots__ = ("req", "slot", "table", "tokens", "next_pos", "rng",
                  "pf_pos", "done", "finish_reason", "future", "t_submit",
-                 "t_first", "span", "cut", "snaps", "picks", "chunk_picks")
+                 "t_first", "span", "cut", "snaps", "picks", "chunk_picks",
+                 "selections")
 
     def __init__(self, req, slot, table, rng):
         self.req = req
@@ -200,8 +201,12 @@ class _SlotRun:
         # engine.record_picks: the routers' picks of each chunk and step,
         # [(first position, picks [n_moe, rows, k])]
         self.picks = []
-        # the prefill chunks' picks still on the device, [(first position,
-        # live rows, array)]: read with the prompt's last chunk's logits
+        # ... and the full dsa layers' selections, [(first position,
+        # selections [n_full, rows, topk])]
+        self.selections = []
+        # the prefill chunks' fetches still on the device, [(first position,
+        # live rows, picks, selections, dsa counts)], each None where the
+        # model has none: read with the prompt's last chunk's logits
         self.chunk_picks = []
         self.done = False
         self.finish_reason = None
@@ -250,8 +255,8 @@ class _Step:
     """One decode step dispatched and not yet read: what its read needs."""
 
     __slots__ = ("by_slot", "positions", "variant", "logits", "ids", "picks",
-                 "rows", "fetch_bytes", "ctx_tokens", "walked_tokens", "span",
-                 "host_ms", "dispatch_ms", "seq")
+                 "sel", "counts", "rows", "fetch_bytes", "ctx_tokens",
+                 "walked_tokens", "span", "host_ms", "dispatch_ms", "seq")
 
     def __init__(self, runs):
         self.by_slot = {run.slot: run for run in runs}
@@ -496,14 +501,24 @@ class GenerationEngine:
                 self.kv_state_bytes += arr.size * dtype.itemsize
             self.scope.vars[spec["name"]] = arr
             self._state[spec["name"]] = arr
-        paged = [s for s in specs if s["kind"] == "paged" and s["width"]]
-        # one pooled row of one layer, as the paged kernel copies it
-        self._kv_row_bytes = (
-            paged[0]["width"] * jnp.dtype(paged[0]["dtype"]).itemsize
-            if paged else 0)
-        # latent pools (one a layer, read once as key and value): the walk
-        # is ops.pallas_kernels.latent_paged_attention's
-        self._latent = bool(paged) and paged[0].get("form") == "latent"
+        # one pooled row of one layer of each form of paged pool, as its
+        # kernel copies it: None, a K or a V pool (paged_attention); "latent"
+        # (one a layer, read once as key and value: mla_attention, or
+        # mla_sparse_attention at its selection); "index" (a full dsa
+        # layer's index keys, dsa_index). Pools of every form share the page
+        # ids and the block tables, so a prefix hit brings all of them
+        self.pool_row_bytes = {}
+        for spec in specs:
+            if spec["kind"] == "paged" and spec["width"]:
+                self.pool_row_bytes.setdefault(
+                    spec.get("form"),
+                    spec["width"] * jnp.dtype(spec["dtype"]).itemsize)
+        self._kv_row_bytes = self.pool_row_bytes.get(None, 0)
+        self._latent = "latent" in self.pool_row_bytes
+        # learned sparse attention: [(full layer, the layers that attend its
+        # selection)], and the positions a query keeps
+        self._dsa_groups = getattr(model, "dsa_groups", list)()
+        self._dsa_topk = model.dsa["topk"] if self._dsa_groups else 0
         # the routed experts this chip holds (None: all of them)
         self._experts_held = getattr(model, "experts_held", None)
         self.pool.row_bytes = self.kv_state_bytes // pool_rows
@@ -709,6 +724,36 @@ class GenerationEngine:
             "and every position up to its own",
             buckets=tuple(b * 64 for b in CTX_TOKEN_BUCKETS),
         )
+        # learned sparse attention, decode steps and prefill chunks alike:
+        # what the full layers' index scans and the layers' selected reads
+        # had to touch (a pool row two query rows share counted once), and
+        # the (query row, position) pairs they computed
+        self._m_dsa = {
+            what: reg.histogram(p + "/gen_dsa_%s" % what, help, buckets=b)
+            for what, help, b in (
+                ("index_positions",
+                 "distinct index-key rows one decode step or prefill chunk's "
+                 "index scans have to read, summed over the full dsa layers",
+                 CTX_TOKEN_BUCKETS),
+                ("index_pairs",
+                 "(query row, position) pairs one decode step or prefill "
+                 "chunk's index scans score, summed over the full dsa layers",
+                 tuple(b * 64 for b in CTX_TOKEN_BUCKETS)),
+                ("selected_positions",
+                 "distinct latent rows one decode step or prefill chunk's "
+                 "selections name, summed over the dsa layers (a shared layer "
+                 "reads its own pool at its full layer's selection)",
+                 CTX_TOKEN_BUCKETS),
+                ("selected_pairs",
+                 "(query row, selected position) pairs one decode step or "
+                 "prefill chunk attends, summed over the dsa layers",
+                 tuple(b * 64 for b in CTX_TOKEN_BUCKETS)),
+                ("selected_share",
+                 "one decode step: positions selected over positions that "
+                 "could be attended, mean over its rows, percent",
+                 (1, 2, 5, 10, 20, 50, 100)),
+            )
+        }
         self._m_feed_bytes = reg.histogram(
             p + "/gen_feed_bytes",
             "bytes one decode step feeds the device: tokens, positions, the "
@@ -1232,6 +1277,7 @@ class GenerationEngine:
                 })
             with self._phase("dispatch", "prefill") as dispatch:
                 logits, *extra, ids = self._dispatch(variant, feeds, mut_in)
+                picks, sel, counts = self._extras(extra)
         except Exception as e:
             span.error(e).end()
             raise
@@ -1247,8 +1293,10 @@ class GenerationEngine:
         if self.recurrent_row_bytes:
             self._m_chunk_ssm_tokens.observe(n_real)
         run.pf_pos = start + n_real
-        if extra:
-            run.chunk_picks.append((start, n_real, extra[0]))
+        if extra:  # the selections only where they are recorded
+            run.chunk_picks.append((
+                start, n_real, picks, sel if self.record_picks else None,
+                counts))
         if run.pf_pos == run.cut:
             # the chunk ended on the boundary other prompts were seen to
             # share (admit set it): keep the state there, for the prefix
@@ -1266,12 +1314,19 @@ class GenerationEngine:
             self._last_prefill_logits = _Logits(logits)
             ids = np.asarray(ids)
             self._note_read(seq)
-            for first, n_live, picks in run.chunk_picks:
-                picks = np.asarray(picks)[:, :n_live]
-                self._m_chunk_experts.observe(
-                    _experts_touched(picks, self._experts_held))
-                if self.record_picks:
-                    run.picks.append((first, picks))
+            for first, n_live, picks, sel, counts in run.chunk_picks:
+                if picks is not None:
+                    picks = np.asarray(picks)[:, :n_live]
+                    self._m_chunk_experts.observe(
+                        _experts_touched(picks, self._experts_held))
+                    if self.record_picks:
+                        run.picks.append((first, picks))
+                if counts is not None:
+                    self._observe_dsa(
+                        np.asarray(counts), np.arange(first, first + n_live))
+                    if sel is not None:
+                        run.selections.append(
+                            (first, np.asarray(sel)[:, :n_live]))
             run.chunk_picks = []
         with self._phase("sample", "prefill"):
             # one row: a prompt with a temperature reads its logits whole
@@ -1409,12 +1464,17 @@ class GenerationEngine:
                     state_rows[run.slot] = run.slot + 1
                     run.next_pos += 1
                 step.ctx_tokens = sum(step.positions)
-                step.walked_tokens = (
-                    _pk.latent_positions_walked(
+                if self._dsa_groups:
+                    walked = _pk.index_positions_walked(
                         positions, self.page_size, self.max_pages)
-                    if self._latent else _pk.paged_positions_walked(
+                elif self._latent:
+                    walked = _pk.latent_positions_walked(
+                        positions, self.page_size, self.max_pages)
+                else:
+                    walked = _pk.paged_positions_walked(
                         positions, self.page_size, self.max_pages,
-                        self._kv_row_bytes))
+                        self._kv_row_bytes)
+                step.walked_tokens = walked
                 step.variant = variant = self._variant("decode")
                 feeds, mut_in = self._feeds(variant, feeds)
                 self._m_feed_bytes.observe(
@@ -1430,12 +1490,17 @@ class GenerationEngine:
                     variant, feeds, mut_in)
                 step.seq = self._n_dispatched
                 self._ids = step.ids
-                step.picks = extra[0] if extra else None
+                step.picks, step.sel, step.counts = self._extras(extra)
                 step.rows = {
                     run.slot: self._row_program()(
                         step.logits, np.int32(run.slot))
                     for run in runs if run.req.temperature}
-                fetch = (step.ids, *extra, *step.rows.values())
+                # the selections (n_full x max_slots x topk ids) only where
+                # they are recorded
+                fetch = tuple(a for a in (
+                    step.ids, step.picks, step.counts,
+                    step.sel if self.record_picks else None, *step.rows.values())
+                    if a is not None)
                 for arr in fetch:
                     arr.copy_to_host_async()
                 step.fetch_bytes = sum(arr.nbytes for arr in fetch)
@@ -1456,6 +1521,7 @@ class GenerationEngine:
                 ids = np.asarray(step.ids)
                 self._note_read(step.seq)
                 picks = None if step.picks is None else np.asarray(step.picks)
+                counts = None if step.counts is None else np.asarray(step.counts)
                 rows = {slot: np.asarray(row)
                         for slot, row in step.rows.items()}
         except Exception as e:
@@ -1482,6 +1548,14 @@ class GenerationEngine:
         if picks is not None:
             self._note_picks(picks, step)
             self._m_moe_kernel_layers.observe(step.variant.moe_kernel_layers)
+        if counts is not None:
+            self._observe_dsa(counts, step.positions, step=True)
+            if self.record_picks:
+                sel = np.asarray(step.sel)
+                for run, pos in zip(runs, step.positions):
+                    if not run.done:
+                        run.selections.append(
+                            (pos, sel[:, run.slot:run.slot + 1]))
         with self._phase("sample", "decode"):
             for run in runs:
                 if run.done:
@@ -1492,6 +1566,34 @@ class GenerationEngine:
                        if run.req.temperature else int(ids[run.slot]))
                 self._append_token(run, tok, self._max_new(run.req))
         return runs
+
+    def _extras(self, extra):
+        """(picks, selections, dsa counts): a variant's fetches between its
+        logits and its ids, each None where the model has none (picks: expert
+        layers; the other two: full dsa layers, BlockDecoder._fetches)."""
+        picks = sel = counts = None
+        if self._dsa_groups:
+            *extra, sel, counts = extra
+        if extra:
+            picks = extra[0]
+        return picks, sel, counts
+
+    def _observe_dsa(self, counts, positions, step=False):
+        """The gen_dsa_* histograms of one decode step (`step`) or prefill
+        chunk: counts [2 * n_full] int32 from its program (dsa_count: rows
+        the scan read, rows the selection names, a full layer each), and the
+        positions of its query rows (each attends 0..position)."""
+        counts = np.asarray(counts, np.int64).reshape(-1, 2)
+        used = np.array([len(g[1]) for g in self._dsa_groups], np.int64)
+        attended = np.asarray(positions, np.int64) + 1
+        picked = np.minimum(attended, self._dsa_topk)
+        m = self._m_dsa
+        m["index_positions"].observe(int(counts[:, 0].sum()))
+        m["index_pairs"].observe(int(attended.sum()) * len(used))
+        m["selected_positions"].observe(int((counts[:, 1] * used).sum()))
+        m["selected_pairs"].observe(int(picked.sum() * used.sum()))
+        if step and attended.size:
+            m["selected_share"].observe(float(100.0 * np.mean(picked / attended)))
 
     def _note_picks(self, picks, step):
         """Experts touched by one decode step's live rows, from its picks."""
@@ -1615,7 +1717,7 @@ class GenerationEngine:
                 k: v
                 for k, v in _pk.KERNEL_DISPATCHES.items()
                 if k in ("paged_flash", "paged_flash_int8", "paged_latent",
-                         "hyper_maps", "moe_grouped", "ssm_step",
+                         "dsa_index", "hyper_maps", "moe_grouped", "ssm_step",
                          "gemm_dbuf", "gemm_epilogue", "gemm_int8",
                          "gemm_fp8")
             },

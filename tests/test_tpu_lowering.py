@@ -121,6 +121,20 @@ def _latent(per_row, rows, g):
     ]
 
 
+def _dsa_index(per_row, rows, pages, pool_rows, heads=32, width=128, page=64):
+    """glm52_longdocs' index scan: 32 heads of 128 over a bf16 index-key pool
+    of 64-row pages, a 1026-page table; decode rows and a 256-row chunk."""
+    def fn(q, w, pool, bt, pos):
+        return pk.dsa_index_scores(q, w, pool, bt, pos, heads=heads,
+                                   page_size=page)
+
+    return fn, [
+        S((rows, heads * width), bf16), S((rows, heads), f32),
+        S((pool_rows, width), bf16),
+        S((rows, pages) if per_row else (pages,), i32), S((rows,), i32),
+    ]
+
+
 def _ssm_step(slots, hp, n):
     """granite4h_burst_chat's decode step at slots=48, hp=4096, n=128."""
     return (lambda pool, rows, xdt, decay, b, c:
@@ -227,6 +241,9 @@ FAMILIES = {
     "paged_latent bf16 decode xing4_docs_chat": _latent(True, 32, _LATENT_CELL),
     "paged_latent bf16 shared 256 xing4_docs_chat": _latent(False, 256, _LATENT_CELL),
     "paged_latent bf16 shared 32 xing4_docs_chat": _latent(False, 32, _LATENT_CELL),
+    "dsa_index decode glm52_longdocs": _dsa_index(True, 32, 1026, 467008),
+    "dsa_index shared 256 glm52_longdocs": _dsa_index(False, 256, 1026, 467008),
+    "dsa_index shared 64 glm52_longdocs": _dsa_index(False, 64, 1026, 467008),
     "ssm_step toy": _ssm_step(4, 128, 16),
     "ssm_step granite4h_burst_chat": _ssm_step(48, 4096, 128),
     "moe_grouped decode lfm2moe_chat": _moe_grouped(128),
