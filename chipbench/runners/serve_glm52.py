@@ -29,8 +29,8 @@ from . import serve, serve_xing4
 PROBE_TAIL, PROBE_STEPS = 300, 8
 
 # ---- the limits of `correct`. Two readings each (PERF.md section 4 has the
-# table): what this change read on the chip, twelve readings over seven
-# weight seeds in the driver's range, and what the reference one precision
+# table): what the system read on one v5e chip, twelve readings over seven
+# weight seeds near 2**31, and what the reference one precision
 # lower (`precision="bfloat16"`: the indexer's scores, the selection, the
 # norms, the router and the softmax in bfloat16 like the rest) reads when it
 # is put in the system's place and held to the float32 reference, three
@@ -38,30 +38,30 @@ PROBE_TAIL, PROBE_STEPS = 300, 8
 # bfloat16 between float32 computations is itself most of the distance to
 # float32, as in xing4_docs_chat, so the lower precision is told apart by one
 # limit, the share of rows whose expert picks differ; every other limit is
-# set at twice the change's largest reading or more (a false
-# `correct: false` refuses every later PR).
+# set at twice the system's largest reading or more, so that a correct run
+# is not read as incorrect.
 #
 # The share of (layer, position) rows where the system picked another set of
 # experts than the reference's own scores would (256 experts, 8 a token:
-# near ties abound). This change 8.89-9.10 %; lower precision 18.6-19.2 %:
+# near ties abound). The system 8.89-9.10 %; lower precision 18.6-19.2 %:
 # not correct, each seed.
 PICK_ROWS_SHARE = 0.14
 # Every differing pick has to be a near tie: its selection score at most
-# PICK_EPS under the reference's own 8th largest. This change 0.7e-2 -
+# PICK_EPS under the reference's own 8th largest. The system 0.7e-2 -
 # 1.2e-2; lower precision 1.3e-2 - 1.4e-2. A router that picks by something
 # else differs in most rows, not by a margin.
 PICK_EPS = 8e-2
 # Of the positions the system's full layers selected, the share that the
-# reference's own top-2048 (its float32 index scores) does not hold. This
-# change 0.87-0.88 %; lower precision 1.18-1.19 %. An indexer that scores by
+# reference's own top-2048 (its float32 index scores) does not hold. The
+# system 0.87-0.88 %; lower precision 1.18-1.19 %. An indexer that scores by
 # something else differs in most of its 2048.
 SEL_OUTSIDE_SHARE = 0.02
 # Every such position has to be a near tie: its reference index score at
-# most SEL_EPS under the reference's own 2048th. This change 0.104-0.122;
+# most SEL_EPS under the reference's own 2048th. The system 0.104-0.122;
 # lower precision 0.143-0.172 (bfloat16 keys and queries).
 SEL_EPS = 0.3
 # Logits, max abs difference on logits of up to ~8, following the system's
-# picks and selections. This change 0.069-0.093; lower precision
+# picks and selections. The system 0.069-0.093; lower precision
 # 0.098-0.120.
 LOGIT_ATOL = 0.2
 # Of the window's prompt pages that could have come from the prefix cache,
