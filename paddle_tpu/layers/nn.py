@@ -1590,9 +1590,9 @@ def mla_sparse_attention(q, pool, block_table, selected, page_size, d_value,
 
 
 def dsa_count(mask, block_table, pos, page_size, name=None):
-    """int32 ``[2]``: the distinct pool rows an index scan over these rows
-    reads, and those the selection ``mask`` (``dsa_topk``'s) names
-    (ops/generation_ops.py ``dsa_count``)."""
+    """int32 ``[1]``: the distinct pool rows the selection ``mask``
+    (``dsa_topk``'s) names through ``block_table`` (ops/generation_ops.py
+    ``dsa_count``)."""
     helper = LayerHelper("dsa_count", name=name)
     out = helper.create_variable_for_type_inference("int32")
     helper.append_op(

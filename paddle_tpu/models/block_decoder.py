@@ -649,8 +649,8 @@ class BlockDecoder:
 
     def _fetches(self, logits, picks, selection):
         """logits, then the routers' picks (moe layers), then the full dsa
-        layers' selections [n_full, rows, topk] int32 and what the selection
-        path read, [2 * n_full] int32 (dsa_count's a full layer)."""
+        layers' selections [n_full, rows, topk] int32 and the distinct rows
+        they name, [n_full] int32 (dsa_count's a full layer)."""
         out = [logits.name] + ([picks.name] if picks is not None else [])
         if selection["sels"]:
             out += [layers.stack(selection["sels"], axis=0).name,

@@ -498,46 +498,39 @@ def _mla_sparse_attention(ctx, ins, attrs):
 
 def _dsa_count_infer(op, block):
     out = block._var_recursive(op.outputs["Out"][0])
-    out.shape, out.dtype = (2,), "int32"
+    out.shape, out.dtype = (1,), "int32"
 
 
 @register("dsa_count", no_grad=True, infer_shape=_dsa_count_infer)
 def _dsa_count(ctx, ins, attrs):
-    """What a step's selection path read, for the rooflines: Out int32 [2] =
-    [distinct pool rows the index scan had to read (every position up to
-    each row's own through its table), distinct pool rows the selection Mask
-    [S, P * page_size] names]. A row shared by several query rows (a
-    document's pages) counts once; the scratch page, and a query row whose
-    Pos is < 0, count never. No sort: a decode row counts a page's rows
-    unless a lower row's table holds the same page, found by comparing the
-    tables page by page and, for the selection, one [S, S] product a page."""
+    """What a step's selection names, for serve.sparse_attn_roofline: Out
+    int32 [1], the distinct pool rows the selection Mask [S, P * page_size]
+    (dsa_topk's) names through BlockTable. A row that several query rows
+    select (a document's pages) counts once; the scratch page, and a query
+    row whose Pos is < 0, count never. No sort: a decode row counts a
+    selected row unless a lower row's table holds its page and selects it
+    too (one [S, S] product a page). What the index scan read follows from
+    the tables and positions alone, and the host counts it
+    (pallas_kernels.index_rows_scanned)."""
     (mask,) = ins["Mask"]
     (bt,) = ins["BlockTable"]
     (pos,) = ins["Pos"]
     ps = int(attrs["page_size"])
     pos = pos.reshape(-1).astype(jnp.int32)
     mask = mask & (pos[:, None] >= 0)
-    if bt.ndim == 1:  # one sequence: positions 0..max(pos), one table
-        scanned = jnp.maximum(jnp.max(pos) + 1, 0)
-        real = jnp.repeat(bt.astype(jnp.int32) > 0, ps)[None, :]
-        picked = jnp.sum((jnp.any(mask, axis=0) & real[0]).astype(jnp.int32))
-        return {"Out": [jnp.stack([scanned, picked]).astype(jnp.int32)]}
+    if bt.ndim == 1:  # one sequence, one table
+        real = jnp.repeat(bt.astype(jnp.int32) > 0, ps)
+        picked = jnp.sum((jnp.any(mask, axis=0) & real).astype(jnp.int32))
+        return {"Out": [picked.reshape(1).astype(jnp.int32)]}
     s, n_pages = bt.shape
     pages = bt.astype(jnp.int32)
-    first = jnp.arange(n_pages, dtype=jnp.int32)[None, :] * ps
-    used = jnp.where(pages > 0, jnp.clip(pos[:, None] - first + 1, 0, ps), 0)
     # same[p, r, r']: rows r and r' hold one page at table entry p, r' < r
     lower = jnp.arange(s)[:, None] > jnp.arange(s)[None, :]
-    held = pages.T[:, :, None] == pages.T[:, None, :]  # [P, S, S]
-    same = held & (pages.T[:, :, None] > 0) & lower[None]
-    # a page's rows count with the first row that holds it, as many as any
-    # holder reads
-    most = jnp.max(jnp.where(held, used.T[:, None, :], 0), axis=2).T
-    held_before = jnp.any(same & (used.T[:, None, :] > 0), axis=2).T
-    scanned = jnp.sum(jnp.where((used > 0) & ~held_before, most, 0))
+    same = ((pages.T[:, :, None] == pages.T[:, None, :])
+            & (pages.T[:, :, None] > 0) & lower[None])
     m = mask.reshape(s, n_pages, ps).transpose(1, 0, 2).astype(jnp.bfloat16)
     before = jnp.einsum("prq,pqo->pro", same.astype(jnp.bfloat16), m,
                         preferred_element_type=jnp.float32)
     picked = jnp.sum(((m > 0) & (before == 0)
                       & (pages.T[:, :, None] > 0)).astype(jnp.int32))
-    return {"Out": [jnp.stack([scanned, picked]).astype(jnp.int32)]}
+    return {"Out": [picked.reshape(1).astype(jnp.int32)]}

@@ -2235,6 +2235,25 @@ def index_positions_walked(pos, page_size, n_pages):
     return int(np.clip(pos // block + 1, 0, most).sum()) * block
 
 
+def index_rows_scanned(tables, pos, page_size):
+    """The distinct index-key pool rows a decode step's scan has to read:
+    every position up to each row's own through its table row (tables
+    [rows, pages], pos [rows]); a pool row that several rows' tables hold (a
+    document's pages) counts once, the scratch page (0) and a row at pos < 0
+    never. What the work requires, for serve.dsa_index_roofline, where
+    index_positions_walked is what the kernel walks."""
+    pos = np.asarray(pos, dtype=np.int64).reshape(-1)
+    tables = np.asarray(tables, dtype=np.int64).reshape(pos.size, -1)
+    first = np.arange(tables.shape[1], dtype=np.int64) * int(page_size)
+    used = np.clip(pos[:, None] - first[None, :] + 1, 0, int(page_size))
+    read = (tables > 0) & (used > 0)
+    if not read.any():
+        return 0
+    most = np.zeros(int(tables.max()) + 1, np.int64)
+    np.maximum.at(most, tables[read], used[read])
+    return int(most.sum())
+
+
 def _dsa_index_kernel(bt_ref, last_ref, pos_ref, q_ref, w_ref, pool, o_ref,
                       buf, sem, *, page_size, n_pages, block_pages, per_row,
                       heads):

@@ -256,7 +256,8 @@ class _Step:
 
     __slots__ = ("by_slot", "positions", "variant", "logits", "ids", "picks",
                  "sel", "counts", "rows", "fetch_bytes", "ctx_tokens",
-                 "walked_tokens", "span", "host_ms", "dispatch_ms", "seq")
+                 "walked_tokens", "index_rows", "span", "host_ms",
+                 "dispatch_ms", "seq")
 
     def __init__(self, runs):
         self.by_slot = {run.slot: run for run in runs}
@@ -1322,8 +1323,11 @@ class GenerationEngine:
                     if self.record_picks:
                         run.picks.append((first, picks))
                 if counts is not None:
+                    # one table: the scan reads every position up to the
+                    # chunk's last
                     self._observe_dsa(
-                        np.asarray(counts), np.arange(first, first + n_live))
+                        np.asarray(counts), np.arange(first, first + n_live),
+                        first + n_live)
                     if sel is not None:
                         run.selections.append(
                             (first, np.asarray(sel)[:, :n_live]))
@@ -1467,6 +1471,8 @@ class GenerationEngine:
                 if self._dsa_groups:
                     walked = _pk.index_positions_walked(
                         positions, self.page_size, self.max_pages)
+                    step.index_rows = _pk.index_rows_scanned(
+                        feeds["dec_block_table"], positions, self.page_size)
                 elif self._latent:
                     walked = _pk.latent_positions_walked(
                         positions, self.page_size, self.max_pages)
@@ -1549,7 +1555,8 @@ class GenerationEngine:
             self._note_picks(picks, step)
             self._m_moe_kernel_layers.observe(step.variant.moe_kernel_layers)
         if counts is not None:
-            self._observe_dsa(counts, step.positions, step=True)
+            self._observe_dsa(counts, step.positions, step.index_rows,
+                              step=True)
             if self.record_picks:
                 sel = np.asarray(step.sel)
                 for run, pos in zip(runs, step.positions):
@@ -1578,19 +1585,20 @@ class GenerationEngine:
             picks = extra[0]
         return picks, sel, counts
 
-    def _observe_dsa(self, counts, positions, step=False):
+    def _observe_dsa(self, counts, positions, scanned, step=False):
         """The gen_dsa_* histograms of one decode step (`step`) or prefill
-        chunk: counts [2 * n_full] int32 from its program (dsa_count: rows
-        the scan read, rows the selection names, a full layer each), and the
-        positions of its query rows (each attends 0..position)."""
-        counts = np.asarray(counts, np.int64).reshape(-1, 2)
+        chunk: counts [n_full] int32 from its program (dsa_count: the rows
+        a full layer's selection names), the positions of its query rows
+        (each attends 0..position) and the distinct index-key rows one scan
+        reads (the host's count: every full layer scans the same)."""
+        counts = np.asarray(counts, np.int64).reshape(-1)
         used = np.array([len(g[1]) for g in self._dsa_groups], np.int64)
         attended = np.asarray(positions, np.int64) + 1
         picked = np.minimum(attended, self._dsa_topk)
         m = self._m_dsa
-        m["index_positions"].observe(int(counts[:, 0].sum()))
+        m["index_positions"].observe(int(scanned) * len(used))
         m["index_pairs"].observe(int(attended.sum()) * len(used))
-        m["selected_positions"].observe(int((counts[:, 1] * used).sum()))
+        m["selected_positions"].observe(int((counts * used).sum()))
         m["selected_pairs"].observe(int(picked.sum() * used.sum()))
         if step and attended.size:
             m["selected_share"].observe(float(100.0 * np.mean(picked / attended)))

@@ -42,7 +42,7 @@ TOY_GLM = {
     "rope_parameters": {"rope_theta": 8000000, "rope_type": "default"},
     "tie_word_embeddings": False, "max_position_embeddings": 1024,
     "num_nextn_predict_layers": 0,
-    "engine": {"max_slots": 4, "max_context": 512, "page_size": 16,
+    "engine": {"max_slots": 8, "max_context": 512, "page_size": 16,
                "pool_pages": 120, "prefill_buckets": [32, 64]},
     "server": {"request_timeout_ms": 60000}, "reduced": [], "assumed": {},
 }
@@ -250,10 +250,9 @@ def test_probe_catches_a_wrong_weight_and_tells_a_lower_precision_apart(tree, on
     matrix is changed in the scope only (the compiled variants hold the old
     one), the reference's own selections and the engine's disagree, and the
     probe says so. The probe itself goes through a prefix hit on the first
-    document, and the reference in bfloat16 can stand in the system's
-    place."""
-    import jax.numpy as jnp
-
+    document into a slot a filler left and decodes among the other live
+    rows, the row in the highest live slot is held to the reference too, and
+    the reference in bfloat16 can stand in the system's place."""
     from chipbench.runners import serve_glm52
 
     _toy_cell(tree)
@@ -263,25 +262,31 @@ def test_probe_catches_a_wrong_weight_and_tells_a_lower_precision_apart(tree, on
     assert [len(d) for d in docs] == [64, 80, 96]
     assert doc_picks.shape == (4, 64, 2) and doc_sel.shape == (2, 64, 16)
     problems = []
-    ran = serve_glm52.probe_run(engine, model, 5, docs[0])
+    ran = serve_glm52.probe_run(engine, model, 5, docs)
     notes = serve_glm52._probe(
-        engine, model, 5, problems, docs[0], doc_picks, doc_sel, ran=ran)
+        engine, model, 5, problems, docs, doc_picks, doc_sel, ran=ran)
     assert problems == [] and notes["prefix_hit_at"] == 64
-    n = 64 + 300 + 8
+    # five fillers in slots 0-4, the cold row in 5; fillers 1 and 4 retired,
+    # the probe in the slot retired last
+    assert notes["live_slots"] == [0, 1, 2, 3, 5] and notes["slot"] == 1
+    assert notes["other_row"]["slot"] == 5
+    n, m = 64 + 300 + 8, 256 + 8
     assert notes["tail_chunks"] >= 2 and notes["pick_rows"] == 4 * n
     assert notes["selected"] == 2 * sum(min(16, p + 1) for p in range(n))
+    assert notes["other_row"]["pick_rows"] == 4 * m
+    assert notes["other_row"]["selected"] == 2 * sum(min(16, p + 1) for p in range(m))
     low = serve_glm52._probe(
-        engine, model, 5, [], docs[0], doc_picks, doc_sel, stand_in="bfloat16",
+        engine, model, 5, [], docs, doc_picks, doc_sel, stand_in="bfloat16",
         ran=ran)
     assert low["first-token"] > 0 and low["selected"] == notes["selected"]
     name = [n for n in model.param_names() if n.endswith("l0_idx_k_w")][0]
     engine.scope.vars[name] = -engine.scope.vars[name]
     problems = []
-    serve_glm52._probe(engine, model, 6, problems, docs[0], doc_picks, doc_sel)
+    serve_glm52._probe(engine, model, 6, problems, docs, doc_picks, doc_sel)
     assert any("top-2048" in p or "not a tie" in p for p in problems), problems
     engine.scope.vars[name] = -engine.scope.vars[name]
     # a probe that finds no cached document says so
     engine.prefix_cache.clear()
     problems = []
-    serve_glm52._probe(engine, model, 7, problems, docs[0], doc_picks, doc_sel)
+    serve_glm52._probe(engine, model, 7, problems, docs, doc_picks, doc_sel)
     assert any("prefix hit" in p for p in problems)

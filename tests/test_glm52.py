@@ -308,10 +308,13 @@ def test_rows_short_of_topk_attend_every_position_as_dense_mla():
 
 @pytest.mark.parametrize("per_row", [True, False], ids=["decode", "chunk"])
 def test_the_count_of_distinct_rows_is_a_brute_force_s(per_row):
-    """dsa_count against sets of (page, offset): rows that share pages (a
-    document under several questions) count a shared row once, the
-    scratch page and a row at Pos < 0 never."""
+    """dsa_count (the selected rows, on the device) and the host's count of
+    the rows a decode step's scan reads, against sets of (page, offset):
+    rows that share pages (a document under several questions) count a
+    shared row once, the scratch page and a row at Pos < 0 never."""
     import jax.numpy as jnp
+
+    from paddle_tpu.ops import pallas_kernels as pk
 
     rng = np.random.default_rng(3)
     rows, ps, n_pages = 6, 4, 8
@@ -341,9 +344,9 @@ def test_the_count_of_distinct_rows_is_a_brute_force_s(per_row):
                for c in range(pos[r] + 1) if tables[r, c // ps] > 0}
     picked = {(tables[r, c // ps], c % ps) for r in range(rows) if pos[r] >= 0
               for c in np.flatnonzero(mask[r]) if tables[r, c // ps] > 0}
-    if not per_row:  # one sequence: every position up to the last row's
-        scanned = set(range(max(pos) + 1))
-    assert list(got) == [len(scanned), len(picked)]
+    assert list(got) == [len(picked)]
+    if per_row:
+        assert pk.index_rows_scanned(table, pos, ps) == len(scanned)
 
 
 # ---- each op through a program (its shape rule too), against numpy
@@ -432,13 +435,11 @@ class TestDsaCountOp(OpTest):
         bt[1, :2] = bt[2, :2]  # rows 1 and 2 share their first two pages
         mask = np.random.default_rng(4).random((4, 20)) < 0.5
         mask &= np.arange(20)[None, :] <= pos[:, None]
-        scanned = {(bt[r, c // 4], c % 4) for r in range(4)
-                   for c in range(pos[r] + 1)}
         picked = {(bt[r, c // 4], c % 4) for r in range(4)
                   for c in np.flatnonzero(mask[r])}
         self.inputs = {"Mask": mask, "BlockTable": bt, "Pos": pos}
         self.attrs = {"page_size": 4}
-        self.outputs = {"Out": np.array([len(scanned), len(picked)], np.int32)}
+        self.outputs = {"Out": np.array([len(picked)], np.int32)}
 
     def test_check_output(self):
         self.check_output(atol=0)
