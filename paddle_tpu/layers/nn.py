@@ -1572,16 +1572,20 @@ def dsa_topk(scores, k, name=None):
 
 
 def mla_sparse_attention(q, pool, block_table, selected, page_size, d_value,
-                         sm_scale, name=None):
+                         sm_scale, live=None, name=None):
     """mla_attention over the positions ``selected`` ``[rows, k]`` alone
     (ops/generation_ops.py ``mla_sparse_attention``); returns ``[rows, n_head
-    * d_value]``."""
+    * d_value]``. ``live`` ``[rows]`` int32 (1: a real row), with per-row
+    block tables: the other rows walk nothing and give 0."""
     helper = LayerHelper("mla_sparse_attention", name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
+    inputs = {"Q": [q.name], "Pool": [pool.name],
+              "BlockTable": [block_table.name], "Selected": [selected.name]}
+    if live is not None:
+        inputs["Live"] = [live.name]
     helper.append_op(
         type="mla_sparse_attention",
-        inputs={"Q": [q.name], "Pool": [pool.name],
-                "BlockTable": [block_table.name], "Selected": [selected.name]},
+        inputs=inputs,
         outputs={"Out": [out.name]},
         attrs={"page_size": int(page_size), "d_value": int(d_value),
                "sm_scale": float(sm_scale)},

@@ -661,7 +661,8 @@ class BlockDecoder:
                     index_pool=None):
         """A dsa layer's attention: a full layer writes its index keys, scans
         and selects (selection["sel"], and the count of what it read); every
-        dsa layer then attends the latent pool over the selection."""
+        dsa layer then attends the latent pool over the selection, for the
+        rows selection["live"] names where the variant has that feed."""
         c_q = self._mla_cq(u, i)
         if self.dsa_role(i) == "full":
             q_i, w_i, k_i = self._indexer(u, c_q, i, pos)
@@ -678,7 +679,8 @@ class BlockDecoder:
                 mask, table, selection.get("count_pos", pos), page_size))
         return self._mla_output(layers.mla_sparse_attention(
             self._mla_query(u, i, pos, c_q), pool, table, selection["sel"],
-            page_size, self.mla["kv_rank"], self.mla["sm_scale"]), i)
+            page_size, self.mla["kv_rank"], self.mla["sm_scale"],
+            live=selection.get("live")), i)
 
     def _cache_vars(self, pool_rows, state_rows):
         block = framework.default_main_program().global_block()
@@ -861,7 +863,7 @@ class BlockDecoder:
             pos = layers.elementwise_add(
                 layers.assign(np.arange(t, dtype="int64")), start)
             x = self._embed(tokens, t)
-            selection = {"sels": [], "counts": []}
+            selection = {"sels": [], "counts": [], "live": live}
             if self.dsa:  # the padding rows behind the prompt count nothing
                 selection["count_pos"] = layers.elementwise_add(
                     layers.elementwise_mul(
@@ -886,7 +888,8 @@ class BlockDecoder:
     def build_decode(self, slots, page_size, max_pages, pool_rows, state_rows=1):
         """One token for every slot, as GPTDecoder.build_decode, with two
         more feeds: dec_live [slots] int32 (0 for an idle or mid-prefill
-        slot: it routes to no expert) and dec_state_rows [slots] int32 (each
+        slot: it routes to no expert, and a dsa layer's latent walk gathers
+        nothing for it) and dec_state_rows [slots] int32 (each
         slot's row of the conv state pools, the scratch row 0 for a slot
         that is not live). Fetch logits [slots, vocab] float32, then the
         routers' picks [n_moe, slots, k]."""
@@ -903,7 +906,7 @@ class BlockDecoder:
             state_rows_ = data("dec_state_rows", [slots], "int32")
             caches = self._cache_vars(pool_rows, state_rows)
             x = self._embed(tokens, slots)
-            selection = {"sels": [], "counts": []}
+            selection = {"sels": [], "counts": [], "live": live}
             operator = self._cached_operator(
                 caches, table, positions, page_size,
                 dict(rows=state_rows_, mode="step"), selection)

@@ -48,6 +48,7 @@ Conventions:
 
 import jax
 import jax.numpy as jnp
+from jax._src import source_info_util as _name_stack
 
 from .registry import register
 
@@ -446,18 +447,100 @@ def _mla_sparse_infer(op, block):
     out.dtype = q.dtype
 
 
+SPARSE_WALK_BLOCK = 8  # query rows a trip: one float32 sublane tile
+
+
+def sparse_walk_blocks(n_live, slots):
+    """(rows a block, blocks) of mla_sparse_attention's walk over a decode
+    step's per-row tables with `n_live` of its `slots` rows live (an int, or
+    a traced int32 inside the op): min(slots, 8) rows a block, as many
+    blocks as hold the live rows. The host's mirror of the op's trip count;
+    their product is the rows the walk gathers for (gen_dsa_walk_rows)."""
+    block = min(int(slots), SPARSE_WALK_BLOCK)
+    return block, (n_live + block - 1) // block
+
+
+def _attend_selected(scores, kv, live, scale, d_value):
+    """Absorbed attention over latent rows kv [S, k, W] from their scores
+    [S, H, k] float32, softmax over `live` [S, 1, k]: [S, H, d_value]
+    float32."""
+    scores = jnp.where(live, scores * scale, -jnp.inf)
+    m = jnp.max(scores, axis=-1, keepdims=True)
+    m = jnp.where(jnp.isfinite(m), m, 0.0)
+    w = jnp.where(live, jnp.exp(scores - m), 0.0)
+    denom = jnp.sum(w, axis=-1, keepdims=True)
+    w = w / jnp.where(denom > 0.0, denom, 1.0)
+    return jnp.einsum("shk,skv->shv", w.astype(kv.dtype), kv[..., :d_value],
+                      preferred_element_type=jnp.float32)
+
+
+def _gathered_walk(qh, pool, bt, sel, page_size, scale, d_value):
+    """Each row gathers its selected rows through the block table, by
+    (page, offset), and attends them: [S, H, d_value] float32."""
+    rows = _positions_rows(bt, sel, page_size)
+    kv = jnp.take(pool, rows.reshape(-1), axis=0).reshape(
+        sel.shape[0], -1, pool.shape[-1])
+    # the gathered rows as the left operand, contracted on their last axis:
+    # the layout the gather leaves them in, so they are not copied to a
+    # transposed one first
+    scores = jnp.einsum("skw,shw->shk", kv, qh.astype(pool.dtype),
+                        preferred_element_type=jnp.float32)
+    return _attend_selected(scores, kv, (sel >= 0)[:, None, :], scale, d_value)
+
+
+def _live_rows_walk(qh, pool, bt, sel, live, page_size, scale, d_value,
+                    dtype):
+    """_gathered_walk for the live rows of per-row tables alone: the rows
+    in live-first order (a stable argsort), a block of them a trip, as many
+    trips as sparse_walk_blocks counts; each block's results go back to its
+    rows' slots, and the rows behind the live ones in the last block write
+    nowhere. An idle row's output is 0."""
+    s = qh.shape[0]
+    on = live.reshape(-1) != 0
+    order = jnp.argsort(jnp.logical_not(on), stable=True).astype(jnp.int32)
+    n_live = jnp.sum(on.astype(jnp.int32))
+    block, trips = sparse_walk_blocks(n_live, s)
+    scope = _name_stack.current_name_stack()
+
+    def trip(b, out):
+        with _name_stack.set_name_stack(scope):
+            at = b * block + jnp.arange(block, dtype=jnp.int32)
+            rows = jnp.take(order, jnp.minimum(at, s - 1))
+            ctx = _gathered_walk(jnp.take(qh, rows, axis=0),
+                                 pool, jnp.take(bt, rows, axis=0),
+                                 jnp.take(sel, rows, axis=0), page_size,
+                                 scale, d_value)
+            return out.at[jnp.where(at < n_live, rows, s)].set(
+                ctx.astype(dtype), mode="drop")
+
+    # a device trace gives the loop an event of its own around its body's:
+    # the loop goes out under no op's name and its body under this op's, so
+    # that the op's device time counts the walk once
+    with _name_stack.reset_name_stack():
+        return jax.lax.fori_loop(
+            0, trips, trip,
+            jnp.zeros((s,) + qh.shape[1:2] + (d_value,), dtype))
+
+
 @register("mla_sparse_attention", no_grad=True, infer_shape=_mla_sparse_infer)
 def _mla_sparse_attention(ctx, ins, attrs):
     """mla_attention over the positions Selected [S, k] (ascending, -1: none)
     alone: each row gathers its k pooled rows through the block table, by
     (page, offset), and attends them absorbed, softmax over the selection.
-    Every query head of a row shares its selection. With FLAGS_paged_flash
-    "off" the lowering is the parity oracle instead: every position of the
-    table, masked to the selection."""
+    Every query head of a row shares its selection. Live [S] int32 (1: a
+    real row), where given with per-row tables, names the rows to walk: the
+    others (idle and mid-prefill slots, whose scratch-page selections are
+    not empty) gather nothing and give 0. A shared table (one sequence's
+    chunk) walks every row. With FLAGS_paged_flash "off" the lowering is
+    the parity oracle instead: every position of the table, masked to the
+    selection."""
     (q,) = ins["Q"]
     (pool,) = ins["Pool"]
     (bt,) = ins["BlockTable"]
     (sel,) = ins["Selected"]
+    live = ins.get("Live", [None])[0]
+    if bt.ndim == 1:
+        live = None
     page_size = int(attrs["page_size"])
     d_value = int(attrs["d_value"])
     scale = float(attrs["sm_scale"])
@@ -466,33 +549,24 @@ def _mla_sparse_attention(ctx, ins, attrs):
     sel = sel.astype(jnp.int32)
     qh = q.reshape(s, n_head, width)
     if not _dsa_oracle():
-        rows = _positions_rows(bt, sel, page_size)
-        kv = jnp.take(pool, rows.reshape(-1), axis=0).reshape(s, -1, width)
-        live = (sel >= 0)[:, None, :]
-        # the gathered rows as the left operand, contracted on their last
-        # axis: the layout the gather leaves them in, so they are not
-        # copied to a transposed one first
-        scores = jnp.einsum("skw,shw->shk", kv, qh.astype(pool.dtype),
-                            preferred_element_type=jnp.float32)
-    else:
-        flat = _table_rows(bt, page_size)
-        kv = jnp.take(pool, flat.reshape(-1), axis=0).reshape(
-            flat.shape + (width,))
-        n = flat.shape[-1]
-        live = jnp.any(jnp.arange(n)[None, None, :] == sel[:, :, None],
-                       axis=1)[:, None, :]
-        if bt.ndim == 1:
-            kv = jnp.broadcast_to(kv, (s, n, width))
-        scores = jnp.einsum("shw,skw->shk", qh.astype(pool.dtype), kv,
-                            preferred_element_type=jnp.float32)
-    scores = jnp.where(live, scores * scale, -jnp.inf)
-    m = jnp.max(scores, axis=-1, keepdims=True)
-    m = jnp.where(jnp.isfinite(m), m, 0.0)
-    w = jnp.where(live, jnp.exp(scores - m), 0.0)
-    denom = jnp.sum(w, axis=-1, keepdims=True)
-    w = w / jnp.where(denom > 0.0, denom, 1.0)
-    out = jnp.einsum("shk,skv->shv", w.astype(pool.dtype), kv[..., :d_value],
-                     preferred_element_type=jnp.float32)
+        if live is not None:
+            out = _live_rows_walk(qh, pool, bt, sel, live, page_size, scale,
+                                  d_value, q.dtype)
+        else:
+            out = _gathered_walk(qh, pool, bt, sel, page_size, scale, d_value)
+        return {"Out": [out.reshape(s, n_head * d_value).astype(q.dtype)]}
+    flat = _table_rows(bt, page_size)
+    kv = jnp.take(pool, flat.reshape(-1), axis=0).reshape(flat.shape + (width,))
+    n = flat.shape[-1]
+    mask = jnp.any(jnp.arange(n)[None, None, :] == sel[:, :, None],
+                   axis=1)[:, None, :]
+    if bt.ndim == 1:
+        kv = jnp.broadcast_to(kv, (s, n, width))
+    scores = jnp.einsum("shw,skw->shk", qh.astype(pool.dtype), kv,
+                        preferred_element_type=jnp.float32)
+    out = _attend_selected(scores, kv, mask, scale, d_value)
+    if live is not None:
+        out = jnp.where((live.reshape(-1) != 0)[:, None, None], out, 0.0)
     return {"Out": [out.reshape(s, n_head * d_value).astype(q.dtype)]}
 
 

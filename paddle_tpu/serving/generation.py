@@ -79,6 +79,7 @@ from ..observability import flightrec as _flightrec
 from ..observability import tracing as _tracing
 from ..profiler import RecordEvent, watch_gc
 from ..observability.tracing import NULL_SPAN
+from ..ops import generation_ops as _gen_ops
 from ..ops import pallas_kernels as _pk
 from .batcher import (
     ContinuousBatcher,
@@ -753,6 +754,11 @@ class GenerationEngine:
                  "one decode step: positions selected over positions that "
                  "could be attended, mean over its rows, percent",
                  (1, 2, 5, 10, 20, 50, 100)),
+                ("walk_rows",
+                 "query rows one decode step's sparse latent walk gathers "
+                 "for: its live rows in whole blocks (ops.generation_ops."
+                 "sparse_walk_blocks)",
+                 (8, 16, 32, 64, 128, 256)),
             )
         }
         self._m_feed_bytes = reg.histogram(
@@ -1589,8 +1595,9 @@ class GenerationEngine:
         """The gen_dsa_* histograms of one decode step (`step`) or prefill
         chunk: counts [n_full] int32 from its program (dsa_count: the rows
         a full layer's selection names), the positions of its query rows
-        (each attends 0..position) and the distinct index-key rows one scan
-        reads (the host's count: every full layer scans the same)."""
+        (each attends 0..position; a step's are its live rows) and the
+        distinct index-key rows one scan reads (the host's count: every full
+        layer scans the same)."""
         counts = np.asarray(counts, np.int64).reshape(-1)
         used = np.array([len(g[1]) for g in self._dsa_groups], np.int64)
         attended = np.asarray(positions, np.int64) + 1
@@ -1600,6 +1607,10 @@ class GenerationEngine:
         m["index_pairs"].observe(int(attended.sum()) * len(used))
         m["selected_positions"].observe(int((counts * used).sum()))
         m["selected_pairs"].observe(int(picked.sum() * used.sum()))
+        if step:
+            block, trips = _gen_ops.sparse_walk_blocks(
+                attended.size, self.max_slots)
+            m["walk_rows"].observe(block * trips)
         if step and attended.size:
             m["selected_share"].observe(float(100.0 * np.mean(picked / attended)))
 

@@ -165,23 +165,34 @@ def test_glm52_chunked_prefill_then_decode_matches_the_full_forward_pass(
     all through the latent and index-key pages: the dense lowerings, and the
     Pallas scan in interpret mode with the threshold selection and the
     gathered walk; the first-token logits and every step's against the
-    reference's one pass over the whole sequence."""
+    reference's one pass over the whole sequence. A second prompt decodes
+    beside it in slot 2, the slot between them idle (its request retired),
+    so that the walk over live rows leaves a gap and puts each result back
+    in its own slot."""
     model, eng = _engine()
-    prompt = _prompt(37)
+    prompt, other = _prompt(37), _prompt(21, 5)
     run = eng.start(GenRequest(prompt, max_new_tokens=8))
     got = [np.array(eng.last_prefill_logits)]
+    gone = eng.start(GenRequest(_prompt(9, 6), max_new_tokens=2))
+    run2 = eng.start(GenRequest(other, max_new_tokens=8))
+    got2 = [np.array(eng.last_prefill_logits)]
+    eng.finish(gone)
+    assert (run.slot, gone.slot, run2.slot) == (0, 1, 2)
     for _ in range(6):
-        eng.decode_step([run])
+        eng.decode_step([run, run2])
         got.append(np.array(eng.last_logits[run.slot]))
-    tokens = prompt + run.tokens[:6]
-    eng.finish(run)
+        got2.append(np.array(eng.last_logits[run2.slot]))
+    for r in (run, run2):
+        eng.finish(r)
     assert [s for s, _ in run.selections[:3]] == [0, 16, 32]
     scans = eng.stats()["kernel_dispatches"].get("dsa_index", 0)
     assert (scans > 0) == (paged_flash == "on")
-    want = _reference(model, eng, tokens, _by_position(run.picks, len(tokens)),
-                      _by_position(run.selections, len(tokens)))
-    for i, g in enumerate(got):
-        np.testing.assert_allclose(g, want[len(prompt) - 1 + i], atol=F32_TOL)
+    for r, p, g in ((run, prompt, got), (run2, other, got2)):
+        tokens = p + r.tokens[:6]
+        want = _reference(model, eng, tokens, _by_position(r.picks, len(tokens)),
+                          _by_position(r.selections, len(tokens)))
+        for i, row in enumerate(g):
+            np.testing.assert_allclose(row, want[len(p) - 1 + i], atol=F32_TOL)
 
 
 def _lower(op, ins, attrs, mode):
@@ -278,6 +289,128 @@ def test_the_sparse_walk_matches_its_dense_lowering(per_row):
     want = _lower("mla_sparse_attention", ins, attrs, "off")
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
     assert not got[np.asarray(pos) < 0].any()
+
+
+def _todays_walk(q, pool, table, sel, page_size, d_value, sm_scale):
+    """The served walk before it took Live: every row gathers its selected
+    rows, idle or not."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.generation_ops import _positions_rows
+
+    s, width = q.shape[0], pool.shape[-1]
+    qh = q.reshape(s, -1, width)
+    rows = _positions_rows(table, sel, page_size)
+    kv = jnp.take(pool, rows.reshape(-1), axis=0).reshape(s, -1, width)
+    live = (sel >= 0)[:, None, :]
+    scores = jnp.einsum("skw,shw->shk", kv, qh.astype(pool.dtype),
+                        preferred_element_type=jnp.float32)
+    scores = jnp.where(live, scores * sm_scale, -jnp.inf)
+    m = jnp.max(scores, axis=-1, keepdims=True)
+    m = jnp.where(jnp.isfinite(m), m, 0.0)
+    w = jnp.where(live, jnp.exp(scores - m), 0.0)
+    denom = jnp.sum(w, axis=-1, keepdims=True)
+    w = w / jnp.where(denom > 0.0, denom, 1.0)
+    out = jnp.einsum("shk,skv->shv", w.astype(pool.dtype), kv[..., :d_value],
+                     preferred_element_type=jnp.float32)
+    return np.asarray(out.reshape(s, -1).astype(q.dtype))
+
+
+@pytest.mark.parametrize("case", [
+    "live-first", "live-middle", "live-last", "none-live", "all-live",
+    "two-trips", "no-live-input", "shared-table"])
+def test_the_walk_over_live_rows(case, monkeypatch):
+    """With Live and per-row tables the walk gathers for the live rows
+    alone, a block of min(S, 8) a trip: on the live rows it is the walk of
+    every row (and the dense oracle) within 1e-5 in float32, on the idle
+    ones 0, and it runs as many trips as the host's mirror counts. Without
+    Live, and on a shared table (Live ignored), it is bit for bit the walk
+    of every row. An idle row's selection is the scratch page's, not empty,
+    as an idle slot's is."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import generation_ops as go
+
+    rows = 20 if case == "two-trips" else 5
+    live = {"live-first": [0], "live-middle": [2], "live-last": [4],
+            "none-live": [], "all-live": range(5),
+            "two-trips": [0, 2, 3, 5, 8, 9, 11, 14, 15, 17, 19]}.get(case, range(5))
+    on = np.isin(np.arange(rows), list(live)).astype(np.int32)
+    rng = np.random.default_rng(rows + on.sum())
+    per_row = case != "shared-table"
+    heads, width, k, ps = 3, 16, 6, 4
+    n_pages = 6 if per_row else 12
+    pool = jnp.asarray(rng.standard_normal((80 * ps, width)), jnp.float32)
+    if per_row:
+        table = rng.integers(1, 80, (rows, n_pages)).astype(np.int32)
+        pos = rng.integers(0, n_pages * ps, rows).astype(np.int32)
+    else:
+        table = rng.permutation(np.arange(1, 80))[:n_pages].astype(np.int32)
+        pos = (20 + np.arange(rows)).astype(np.int32)
+    sel = np.full((rows, k), -1, np.int32)
+    for r, p in enumerate(pos):
+        pick = np.sort(rng.permutation(int(p) + 1)[:k])
+        sel[r, :pick.size] = pick
+    if per_row:  # idle slots: table 0 and position 0, selection [0, -1, ...]
+        table[on == 0] = 0
+        sel[on == 0] = [0] + [-1] * (k - 1)
+    q = jnp.asarray(rng.standard_normal((rows, heads * width)), jnp.float32)
+    ins = {"Q": [q], "Pool": [pool], "BlockTable": [jnp.asarray(table)],
+           "Selected": [jnp.asarray(sel)]}
+    if case != "no-live-input":
+        ins["Live"] = [jnp.asarray(on)]
+    attrs = {"page_size": ps, "d_value": 12, "sm_scale": 0.3}
+    today = _todays_walk(q, pool, jnp.asarray(table), jnp.asarray(sel), ps,
+                         12, 0.3)
+    got = _lower("mla_sparse_attention", ins, attrs, "on")
+    if "Live" not in ins or not per_row:
+        assert np.array_equal(got, today)
+        return
+    idle = on == 0
+    assert not got[idle].any()
+    np.testing.assert_allclose(got[~idle], today[~idle], rtol=1e-5, atol=1e-5)
+    dense = _lower("mla_sparse_attention", ins, attrs, "off")
+    assert not dense[idle].any()
+    np.testing.assert_allclose(got[~idle], dense[~idle], rtol=1e-5, atol=1e-5)
+    # the trips, counted with the loop run in Python
+    walked = []
+    inner = go._gathered_walk
+    monkeypatch.setattr(go, "_gathered_walk", lambda qh, *a: (
+        walked.append(qh.shape[0]), inner(qh, *a))[1])
+    with jax.disable_jit():
+        _lower("mla_sparse_attention", ins, attrs, "on")
+    block, trips = go.sparse_walk_blocks(int(on.sum()), rows)
+    assert walked == [block] * trips and block == min(rows, 8)
+    assert sum(walked) == {"none-live": 0, "two-trips": 16}.get(case, 5)
+
+
+def test_the_walk_s_loop_goes_out_under_no_op_s_name():
+    """A device trace gives a loop an event of its own around its body's
+    events: the loop's instruction carries no op's name and every gather
+    of its body carries mla_sparse_attention's, so the op's device time
+    counts the walk once."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    def f(q, pool, bt, sel, live):
+        ins = {"Q": [q], "Pool": [pool], "BlockTable": [bt], "Selected": [sel],
+               "Live": [live]}
+        with jax.named_scope("mla_sparse_attention"), jax.named_scope("out=o"):
+            return registry.get("mla_sparse_attention").lower(
+                None, ins, {"page_size": 4, "d_value": 12, "sm_scale": 0.3})["Out"][0]
+
+    hlo = jax.jit(f).lower(
+        jnp.zeros((10, 48)), jnp.zeros((160, 16)), jnp.zeros((10, 6), jnp.int32),
+        jnp.zeros((10, 6), jnp.int32), jnp.ones((10,), jnp.int32)).compile().as_text()
+    names = lambda opcode: [
+        re.search(r'op_name="([^"]*)"', line).group(1)
+        for line in hlo.splitlines() if " %s(" % opcode in line]
+    loops, gathers = names("while"), names("gather")
+    assert loops and not any("mla_sparse_attention" in n for n in loops)
+    assert gathers and all("mla_sparse_attention/out=o" in n for n in gathers)
 
 
 def test_rows_short_of_topk_attend_every_position_as_dense_mla():
@@ -496,7 +629,8 @@ def test_glm52_prefix_hit_brings_the_index_keys_and_equals_a_cold_admission():
 def test_glm52_engine_counts_what_the_selection_path_read():
     """gen_dsa_*: a decode step of two rows on one document counts the
     document's index rows and its selected rows once, the pairs for each
-    row; the step's share is selected over attended."""
+    row; the step's share is selected over attended; the walk gathers for
+    the two live rows in a block of min(3 slots, 8)."""
     from paddle_tpu.observability import registry as reg
 
     model, eng = _engine()
@@ -505,7 +639,8 @@ def test_glm52_engine_counts_what_the_selection_path_read():
     runs = [eng.start(GenRequest(doc + _prompt(2, 10 + i), max_new_tokens=3))
             for i in range(2)]
     before = {h: snap("gen_dsa_" + h)["sum"] for h in (
-        "index_positions", "index_pairs", "selected_positions", "selected_pairs")}
+        "index_positions", "index_pairs", "selected_positions", "selected_pairs",
+        "walk_rows")}
     eng.decode_step(runs)
     got = {h: snap("gen_dsa_" + h)["sum"] - before[h] for h in before}
     for r in runs:
@@ -519,6 +654,8 @@ def test_glm52_engine_counts_what_the_selection_path_read():
     distinct = [len({(int(s) if s < 24 else (i, int(s))) for i in range(2)
                      for s in sels[i][f]}) for f in range(2)]
     assert got["selected_positions"] == distinct[0] * 4 + distinct[1]
+    block = min(eng.max_slots, 8)
+    assert got["walk_rows"] == block * -(-2 // block) == 3
     share = snap("gen_dsa_selected_share")
     assert share["sum"] / share["count"] <= 100.0
 
