@@ -1384,7 +1384,7 @@ def kv_cache_write(pool, rows, block_table, pos, page_size, scales=None,
 
 def paged_attention(q, k_pool, v_pool, block_table, pos, n_head, page_size,
                     sm_scale=None, k_scales=None, v_scales=None, name=None,
-                    n_kv_head=None):
+                    n_kv_head=None, window=None):
     """One-query-per-slot attention over a paged KV pool.
 
     ``q`` is ``[slots, n_head * d_head]`` (one decode token per slot),
@@ -1395,10 +1395,17 @@ def paged_attention(q, k_pool, v_pool, block_table, pos, n_head, page_size,
     neither) read int8 pools: per-row f32 scales dequantize the gathered
     levels inline (see ops/generation_ops.py int8 pool mode). ``n_kv_head``
     (grouped-query attention): the pools' rows are ``n_kv_head * d_head``
-    wide and query head ``i`` reads KV head ``i // (n_head // n_kv_head)``."""
-    helper = LayerHelper("paged_attention", name=name)
+    wide and query head ``i`` reads KV head ``i // (n_head // n_kv_head)``.
+    ``window`` (a sliding-window layer): the query at ``pos`` attends only
+    ``pos - window < s <= pos``, through the op ``window_attention`` (the
+    same lowering; table entries behind the window may be the scratch
+    page)."""
+    helper = LayerHelper("window_attention" if window else "paged_attention",
+                         name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
     attrs = {"n_head": int(n_head), "page_size": int(page_size)}
+    if window:
+        attrs["window"] = int(window)
     if n_kv_head is not None and int(n_kv_head) != int(n_head):
         attrs["n_kv_head"] = int(n_kv_head)
     if sm_scale is not None:
@@ -1414,7 +1421,7 @@ def paged_attention(q, k_pool, v_pool, block_table, pos, n_head, page_size,
         inputs["KScales"] = [k_scales.name]
         inputs["VScales"] = [v_scales.name]
     helper.append_op(
-        type="paged_attention",
+        type="window_attention" if window else "paged_attention",
         inputs=inputs,
         outputs={"Out": [out.name]},
         attrs=attrs,

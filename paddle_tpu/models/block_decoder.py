@@ -9,10 +9,17 @@ under the ``plain`` residual, with
 
   * OP ``"attention"``: grouped-query attention, per-head RMS norm of q and
     k (``qk_norm``) before rotary positions (``position`` "rotary", the
-    half-rotation form; "none": no position enters at all), scores scaled by
-    ``attn_scale`` (default ``d_head ** -0.5``), causal, through
-    ``kv_cache_write`` / ``paged_attention`` over pools whose rows are
-    ``n_kv_head * d_head`` wide;
+    half-rotation form; "none": no position enters at all; a list gives each
+    layer its own), scores scaled by ``attn_scale`` (default ``d_head **
+    -0.5``), causal, through ``kv_cache_write`` / ``paged_attention`` over
+    pools whose rows are ``n_kv_head * d_head`` wide. A layer with a
+    ``windows`` entry W > 0 is a sliding-window layer: query t attends only
+    t - W < s <= t (``window_attention``), and its pools are a page space of
+    their own whose pages the engine returns once every position on them
+    lies behind the window (``cache_specs``: ``window``). With
+    ``attn_gate`` the context is gated before the output projection,
+    ``W_o (ctx * sigmoid(u W_g))``, one gate an element, u the layer's
+    normed input;
   * OP ``"mamba"``: the Mamba-2 state-space operator (``ssm={heads, d_head,
     d_state, groups, conv, chunk}``): one projection gives ``[z | xBC |
     dt]``, ``xBC`` goes through a depthwise causal convolution with bias and
@@ -57,6 +64,9 @@ under the ``plain`` residual, with
     plus, with ``shared_width``, a dense SwiGLU of that width that every row
     takes (the shared expert: plain ``fc`` / ``swiglu`` ops).
 
+With ``sandwich_norm`` each sub-layer's output is RMS-normed again before
+it is added, ``h = x + norm'(OP(norm(x)))``, ``x' = h + norm'(FF(norm(h)))``.
+
 Under the ``hyper`` residual (``hyper={streams, iters, eps, clamp}``) the
 stream between layers is ``streams`` copies wide, ``[rows, streams *
 d_model]``: each sub-layer reads a learned, input-dependent mix of the
@@ -76,8 +86,8 @@ conv taps are float32, and the norms, router, softmax and the conv's
 accumulation compute in float32 whatever ``dtype`` is. Logits leave the
 last product in float32.
 
-``lfm2_moe(config)``, ``xing4_0(config)``, ``granitemoehybrid(config)`` and
-``glm_moe_dsa(config)``
+``lfm2_moe(config)``, ``xing4_0(config)``, ``granitemoehybrid(config)``,
+``glm_moe_dsa(config)`` and ``afmoe(config)``
 turn a ``config.json`` of those model types into such a decoder: the architecture is data for this
 module.
 """
@@ -92,7 +102,7 @@ from ..layer_helper import LayerHelper
 from ..param_attr import ParamAttr
 
 __all__ = ["BlockDecoder", "lfm2_moe", "xing4_0", "granitemoehybrid",
-           "glm_moe_dsa"]
+           "glm_moe_dsa", "afmoe"]
 
 OPERATORS = ("attention", "conv", "mla", "mamba", "dsa")
 _LANES = 128  # a latent pool's row is padded to whole lane tiles
@@ -140,7 +150,8 @@ class BlockDecoder:
                  init_std=0.02, mla=None, hyper=None, tied_head=True,
                  ssm=None, position="rotary", qk_norm=True, attn_scale=None,
                  embed_scale=1.0, residual_scale=1.0, logit_scale=1.0,
-                 dsa=None):
+                 dsa=None, windows=None, attn_gate=False,
+                 sandwich_norm=False):
         """``blocks`` is ``[(operator, feed_forward)]``, a layer each.
         ``moe`` is ``{num_experts, k, width, use_bias, norm_topk, scale,
         experts_held, shared_width}`` (the last two optional). ``mla`` is
@@ -149,7 +160,10 @@ class BlockDecoder:
         beta_slow] or None). ``hyper`` is ``{streams, iters, eps, clamp}``
         or None for the plain residual. ``dsa`` is ``{heads, d_head, topk,
         rope_dim, norm_eps, roles}``, ``roles`` a layer each ("full",
-        "shared", or None where the operator is not "dsa")."""
+        "shared", or None where the operator is not "dsa"). ``windows``: a
+        layer each, an attention layer's sliding window (0 or None: its
+        whole context). ``position``: "rotary", "none", or a layer each of
+        those."""
         self.blocks = [(str(o), str(f)) for o, f in blocks]
         for o, f in self.blocks:
             if o not in OPERATORS or f not in FEED_FORWARDS:
@@ -182,9 +196,22 @@ class BlockDecoder:
         self.ssm = dict(ssm or {})
         if any(o == "mamba" for o, _ in self.blocks) and not self.ssm:
             raise ValueError("a mamba block needs the ssm settings")
-        if position not in ("rotary", "none"):
-            raise ValueError("position must be 'rotary' or 'none'")
+        positions = (list(position) if isinstance(position, (list, tuple))
+                     else [position] * len(self.blocks))
+        if (len(positions) != len(self.blocks)
+                or set(positions) - {"rotary", "none"}):
+            raise ValueError("position must be 'rotary' or 'none', or one of "
+                             "those a layer")
         self.position = position
+        self.positions = positions
+        self.windows = [int(w or 0) for w in (windows or [0] * len(self.blocks))]
+        if len(self.windows) != len(self.blocks) or any(
+                w and o != "attention"
+                for w, (o, _) in zip(self.windows, self.blocks)):
+            raise ValueError("windows: a layer each, and only attention "
+                             "layers may have one")
+        self.attn_gate = bool(attn_gate)
+        self.sandwich_norm = bool(sandwich_norm)
         self.qk_norm = bool(qk_norm)
         self.attn_scale = (
             self.d_head ** -0.5 if attn_scale is None else float(attn_scale))
@@ -225,6 +252,12 @@ class BlockDecoder:
             spec["ssm"] = {k: self.ssm[k] for k in sorted(self.ssm)}
         if self.dsa:
             spec["dsa"] = {k: self.dsa[k] for k in sorted(self.dsa)}
+        if any(self.windows):
+            spec["windows"] = list(self.windows)
+        if self.attn_gate:
+            spec["attn_gate"] = True
+        if self.sandwich_norm:
+            spec["sandwich_norm"] = True
         own = {"position": self.position, "qk_norm": self.qk_norm,
                "attn_scale": self.attn_scale, "embed_scale": self.embed_scale,
                "residual_scale": self.residual_scale,
@@ -293,7 +326,11 @@ class BlockDecoder:
                 parts += ["q_w", "k_w", "v_w"]
                 if self.qk_norm:
                     parts += ["q_norm_w", "k_norm_w"]
+                if self.attn_gate:
+                    parts.append("g_w")
                 parts.append("o_w")
+            if self.sandwich_norm:
+                parts.append("op_post_norm_w")
             parts.append("ffn_norm_w")
             if ff == "dense":
                 parts += ["ff1_w", "ff3_w", "ff2_w"]
@@ -304,6 +341,8 @@ class BlockDecoder:
                 parts += ["exp_w1", "exp_w3", "exp_w2"]
                 if self.moe.get("shared_width"):
                     parts += ["sh_w1", "sh_w3", "sh_w2"]
+            if self.sandwich_norm:
+                parts.append("ffn_post_norm_w")
             if self.hyper:
                 parts += [w + s for w in ("op", "ffn")
                           for s in ("_hc_w", "_hc_a", "_hc_b")]
@@ -326,7 +365,9 @@ class BlockDecoder:
         float32 recurrent state's (``form`` "recurrent"; its row is not
         flat but ``shape`` = [d_state, heads * d_head], so a pool is
         ``[max_slots + 1, d_state, heads * d_head]``: a sequence's state in
-        tiles of its own)."""
+        tiles of its own). A sliding-window attention layer's K and V pools
+        carry ``window``: they are indexed through the window block table,
+        in a page space of their own."""
         specs = []
         for i, (op, _) in enumerate(self.blocks):
             li = "l%d" % i
@@ -336,6 +377,8 @@ class BlockDecoder:
                         "name": self._p(li, s), "kind": "paged", "layer": i,
                         "width": self.n_kv_head * self.d_head,
                         "dtype": self.dtype})
+                    if self.windows[i]:
+                        specs[-1]["window"] = self.windows[i]
             elif op in ("mla", "dsa"):
                 values, width = self.latent_width()
                 specs.append({
@@ -365,6 +408,14 @@ class BlockDecoder:
                     "layer": i, "width": (self.conv_taps - 1) * self.d_model,
                     "dtype": self.dtype})
         return specs
+
+    def window(self):
+        """The sliding window of the model's window layers (0: none has
+        one). One width for all of them: they share a page space."""
+        ws = {w for w in self.windows if w}
+        if len(ws) > 1:
+            raise ValueError("window layers of different widths: %s" % sorted(ws))
+        return ws.pop() if ws else 0
 
     def kv_pool_names(self):
         """[(k_pool, v_pool)] of the attention layers (an mla or dsa layer's
@@ -512,7 +563,7 @@ class BlockDecoder:
             q = self._norm(q, self._p("l%d" % i, "q_norm_w"), groups=self.n_head)
             k = self._norm(
                 k, self._p("l%d" % i, "k_norm_w"), groups=self.n_kv_head)
-        if self.position == "rotary":
+        if self.positions[i] == "rotary":
             q = layers.rotary_embedding(q, pos, self.n_head, self.rope_theta)
             k = layers.rotary_embedding(k, pos, self.n_kv_head, self.rope_theta)
         return q, k, v
@@ -543,6 +594,16 @@ class BlockDecoder:
             d_attr=at("ssm_d", Constant(1.0)),
             norm_attr=at("ssm_norm_w", Normal(1.0, 0.1)), **state)
         return self._linear(y, i, "ssm_out_w", self.d_model)
+
+    def _attn_out(self, att, u, i):
+        """The output projection of an attention layer's context [rows,
+        n_head * d_head]; with ``attn_gate`` the context times sigmoid(u
+        W_g) first, the sigmoid in float32."""
+        if self.attn_gate:
+            g = layers.sigmoid(layers.cast(
+                self._linear(u, i, "g_w", self.n_head * self.d_head), "float32"))
+            att = layers.elementwise_mul(att, layers.cast(g, self.dtype))
+        return self._linear(att, i, "o_w", self.d_model)
 
     def _conv(self, u, i, **state):
         bcz = self._linear(u, i, "conv_in_w", 3 * self.d_model)
@@ -593,6 +654,10 @@ class BlockDecoder:
         same through a hyper-connection over the stream's copies. `which`
         is "op" or "ffn": the names of its norm and its connection."""
         norm = self._p("l%d" % i, which + "_norm_w")
+        if self.sandwich_norm:
+            bare = fn
+            fn = lambda u: self._norm(
+                bare(u), self._p("l%d" % i, which + "_post_norm_w"))
         if self.residual_scale != 1.0:
             inner = fn
             fn = lambda u: self._scaled(inner(u), self.residual_scale)
@@ -682,20 +747,23 @@ class BlockDecoder:
             page_size, self.mla["kv_rank"], self.mla["sm_scale"],
             live=selection.get("live")), i)
 
-    def _cache_vars(self, pool_rows, state_rows):
+    def _cache_vars(self, pool_rows, state_rows, window_rows=None):
         block = framework.default_main_program().global_block()
         out = {}
         for s in self.cache_specs():
             rows = pool_rows if s["kind"] == "paged" else state_rows
+            if s.get("window"):
+                rows = window_rows
             out[s["name"]] = block.create_var(
                 name=s["name"], shape=[rows] + list(s.get("shape") or [s["width"]]),
                 dtype=s["dtype"], persistable=True)
         return out
 
     def _cached_operator(self, caches, table, pos, page_size, conv_state,
-                         selection=None):
+                         selection=None, window_table=None):
         """OP through the caches: attention writes its K/V rows and attends
-        the pool; mla writes its latent row and attends it absorbed (dsa
+        the pool (a window layer through `window_table`, its own page
+        space); mla writes its latent row and attends it absorbed (dsa
         over its selection, `selection` carrying it from a full layer to the
         shared ones behind it); conv and mamba read and write their state
         row(s)."""
@@ -725,14 +793,16 @@ class BlockDecoder:
             q, k, v = self._qkv(u, i, pos)
             k_pool = caches[self._p(li, "kv_k")]
             v_pool = caches[self._p(li, "kv_v")]
-            layers.kv_cache_write(k_pool, k, table, pos, page_size)
-            layers.kv_cache_write(v_pool, v, table, pos, page_size)
+            window = self.windows[i]
+            pages = window_table if window else table
+            layers.kv_cache_write(k_pool, k, pages, pos, page_size)
+            layers.kv_cache_write(v_pool, v, pages, pos, page_size)
             att = layers.paged_attention(
-                q, k_pool, v_pool, table, pos, n_head=self.n_head,
+                q, k_pool, v_pool, pages, pos, n_head=self.n_head,
                 page_size=page_size, n_kv_head=self.n_kv_head,
                 sm_scale=(None if self.attn_scale == self.d_head ** -0.5
-                          else self.attn_scale))
-            return self._linear(att, i, "o_w", self.d_model)
+                          else self.attn_scale), window=window or None)
+            return self._attn_out(att, u, i)
         return operator
 
     # -------------------------------------------------------------- programs
@@ -753,6 +823,11 @@ class BlockDecoder:
             # one sequence are (group member, position)
             tri = layers.assign(np.tile(
                 np.triu(np.full((t, t), -1e9, "float32"), k=1), (group, 1)))
+            # a window layer's: and nothing W or more positions back
+            tri_w = {w: layers.assign(np.tile(
+                np.triu(np.full((t, t), -1e9, "float32"), k=1)
+                + np.tril(np.full((t, t), -1e9, "float32"), k=-w),
+                (group, 1))) for w in set(self.windows) if w}
             x = self._embed(tokens, batch * t)
             # mla: a sequence's rows are (position, head), every head of a
             # position under the same mask
@@ -817,14 +892,15 @@ class BlockDecoder:
                 scores = layers.matmul(
                     q, kv(k), transpose_y=True, alpha=self.attn_scale)
                 w = layers.softmax(layers.elementwise_add(
-                    layers.cast(scores, "float32"), tri))
+                    layers.cast(scores, "float32"),
+                    tri_w[self.windows[i]] if self.windows[i] else tri))
                 ctx = layers.matmul(layers.cast(w, self.dtype), kv(v))
                 ctx = layers.reshape(
                     layers.transpose(layers.reshape(
                         ctx, [batch, self.n_kv_head, group, t, self.d_head]),
                         [0, 3, 1, 2, 4]),
                     [batch * t, self.n_head * self.d_head])
-                return self._linear(ctx, i, "o_w", self.d_model)
+                return self._attn_out(ctx, u, i)
 
             picks_out = []
             for i in range(self.n_layer):
@@ -840,12 +916,15 @@ class BlockDecoder:
             fetches = self._fetches(logits, picks, selection)
         return main, startup, ["fwd_tokens"], fetches
 
-    def build_prefill(self, t, page_size, max_pages, pool_rows, state_rows=1):
+    def build_prefill(self, t, page_size, max_pages, pool_rows, state_rows=1,
+                      window_rows=None):
         """One prompt chunk of t rows (batch 1), as GPTDecoder.build_prefill,
         with two more feeds: gen_live [t] int32 (1 for a real row, 0 for the
         padding behind them: padding routes to no expert and does not enter
         the conv state) and gen_state_row [1] int32 (the slot's row of the
-        conv state pools). Fetch the gen_last row's logits [1, vocab]
+        conv state pools); with window layers a third, gen_window_pages
+        [max_pages] int32, the slot's table into the window layers' pools
+        (window_rows rows). Fetch the gen_last row's logits [1, vocab]
         float32, then the routers' picks [n_moe, t, k]."""
         main, startup = framework.Program(), framework.Program()
         with framework.program_guard(main, startup), unique_name.guard(
@@ -859,7 +938,9 @@ class BlockDecoder:
             pages = data("gen_pages", [max_pages], "int32")
             live = data("gen_live", [t], "int32")
             state_row = data("gen_state_row", [1], "int32")
-            caches = self._cache_vars(pool_rows, state_rows)
+            wpages = (data("gen_window_pages", [max_pages], "int32")
+                      if self.window() else None)
+            caches = self._cache_vars(pool_rows, state_rows, window_rows)
             pos = layers.elementwise_add(
                 layers.assign(np.arange(t, dtype="int64")), start)
             x = self._embed(tokens, t)
@@ -874,7 +955,7 @@ class BlockDecoder:
             operator = self._cached_operator(
                 caches, pages, pos, page_size,
                 dict(rows=state_row, start=start, live=live, mode="chunk"),
-                selection)
+                selection, wpages)
             picks_out = []
             for i in range(self.n_layer):
                 x = self._layer(x, i, operator, live, picks_out)
@@ -883,16 +964,21 @@ class BlockDecoder:
             fetches = self._fetches(logits, picks, selection)
         feeds = ["gen_tokens", "gen_start", "gen_last", "gen_pages",
                  "gen_live", "gen_state_row"]
+        if wpages is not None:
+            feeds.append("gen_window_pages")
         return main, startup, feeds, fetches
 
-    def build_decode(self, slots, page_size, max_pages, pool_rows, state_rows=1):
+    def build_decode(self, slots, page_size, max_pages, pool_rows, state_rows=1,
+                     window_rows=None):
         """One token for every slot, as GPTDecoder.build_decode, with two
         more feeds: dec_live [slots] int32 (0 for an idle or mid-prefill
         slot: it routes to no expert, and a dsa layer's latent walk gathers
         nothing for it) and dec_state_rows [slots] int32 (each
         slot's row of the conv state pools, the scratch row 0 for a slot
-        that is not live). Fetch logits [slots, vocab] float32, then the
-        routers' picks [n_moe, slots, k]."""
+        that is not live); with window layers a third, dec_window_table
+        [slots, max_pages] int32 (build_prefill's gen_window_pages a slot).
+        Fetch logits [slots, vocab] float32, then the routers' picks
+        [n_moe, slots, k]."""
         main, startup = framework.Program(), framework.Program()
         with framework.program_guard(main, startup), unique_name.guard(
             "%s_dec%d_" % (self.prefix, slots)
@@ -904,12 +990,14 @@ class BlockDecoder:
             table = data("dec_block_table", [slots, max_pages], "int32")
             live = data("dec_live", [slots], "int32")
             state_rows_ = data("dec_state_rows", [slots], "int32")
-            caches = self._cache_vars(pool_rows, state_rows)
+            wtable = (data("dec_window_table", [slots, max_pages], "int32")
+                      if self.window() else None)
+            caches = self._cache_vars(pool_rows, state_rows, window_rows)
             x = self._embed(tokens, slots)
             selection = {"sels": [], "counts": [], "live": live}
             operator = self._cached_operator(
                 caches, table, positions, page_size,
-                dict(rows=state_rows_, mode="step"), selection)
+                dict(rows=state_rows_, mode="step"), selection, wtable)
             picks_out = []
             for i in range(self.n_layer):
                 x = self._layer(x, i, operator, live, picks_out)
@@ -918,6 +1006,8 @@ class BlockDecoder:
             fetches = self._fetches(logits, picks, selection)
         feeds = ["dec_tokens", "dec_positions", "dec_block_table", "dec_live",
                  "dec_state_rows"]
+        if wtable is not None:
+            feeds.append("dec_window_table")
         return main, startup, feeds, fetches
 
     # ---------------------------------------------------------------- params
@@ -1186,4 +1276,73 @@ def glm_moe_dsa(config, **kw):
         norm_eps=float(c["rms_norm_eps"]),
         rope_theta=float(rope.get("rope_theta", c.get("rope_theta", 10000.0))),
         mla=mla, dsa=dsa,
+        tied_head=bool(c.get("tie_word_embeddings", False)), **kw)
+
+
+def afmoe(config, **kw):
+    """The BlockDecoder of an ``afmoe`` ``config.json`` (Arcee's Trinity;
+    as a dict, under the source's own keys). ``layer_types`` gives each
+    layer's attention: "sliding_attention" attends the last
+    ``sliding_window`` positions with rotary positions over the whole head
+    (``rope_theta``), "full_attention" its whole context with no position
+    encoding at all; both are grouped-query (``num_key_value_heads`` of
+    ``head_dim``) with a per-head RMS norm of q and k and a sigmoid gate an
+    element on the context before the output projection. Every sub-layer is
+    sandwiched between two RMS norms. The first ``num_dense_layers`` layers
+    have the dense SwiGLU of ``intermediate_size``, the rest ``num_experts``
+    sigmoid-scored experts of ``moe_intermediate_size``, top
+    ``num_experts_per_tok`` of score + a bias, their scores normalised to
+    sum 1 (``route_norm``) and times ``route_scale``, beside
+    ``num_shared_experts`` shared ones. With ``mup_enabled`` the embedding
+    is scaled by hidden_size^1/2. The head is untied unless
+    ``tie_word_embeddings``. ``experts_held`` (keyword) lists the routed
+    experts this chip holds; the router keeps its width. Further keywords
+    (max_context, dtype, eos_id, prefix) pass through."""
+    c = config
+
+    def need(key, want):
+        raise ValueError("%s = %r is not built (only %s)" % (
+            key, c.get(key), want))
+
+    for key, want in (("n_group", 1), ("topk_group", 1),
+                      ("num_expert_groups", 1), ("num_limited_groups", 1),
+                      ("score_func", "sigmoid"), ("hidden_act", "silu"),
+                      ("rope_scaling", None)):
+        if c.get(key, want) != want:
+            need(key, repr(want))
+    n = int(c["num_hidden_layers"])
+    types = list(c.get("layer_types") or [])
+    if len(types) != n or set(types) - {"sliding_attention", "full_attention"}:
+        need("layer_types", "'sliding_attention' or 'full_attention' for each "
+             "of num_hidden_layers layers")
+    dense = int(c["num_dense_layers"])
+    blocks = [("attention", "dense" if i < dense else "moe") for i in range(n)]
+    window = int(c["sliding_window"])
+    moe = {
+        "num_experts": int(c["num_experts"]),
+        "k": int(c["num_experts_per_tok"]),
+        "width": int(c["moe_intermediate_size"]),
+        "use_bias": True,
+        "norm_topk": bool(c.get("route_norm", True)),
+        "scale": float(c.get("route_scale", 1.0)),
+        "shared_width": (int(c.get("num_shared_experts") or 0)
+                         * int(c["moe_intermediate_size"])),
+    }
+    held = kw.pop("experts_held", None)
+    if held is not None:
+        moe["experts_held"] = [int(e) for e in held]
+    d_model, n_head = int(c["hidden_size"]), int(c["num_attention_heads"])
+    sliding = [t == "sliding_attention" for t in types]
+    kw.setdefault("max_context", int(c["max_position_embeddings"]))
+    kw.setdefault("prefix", "afmoe")
+    return BlockDecoder(
+        vocab_size=int(c["vocab_size"]), d_model=d_model, blocks=blocks,
+        n_head=n_head, n_kv_head=int(c["num_key_value_heads"]),
+        d_head=int(c.get("head_dim") or d_model // n_head),
+        d_ffn=int(c["intermediate_size"]), moe=moe,
+        norm_eps=float(c["rms_norm_eps"]), rope_theta=float(c["rope_theta"]),
+        position=["rotary" if w else "none" for w in sliding],
+        windows=[window if w else 0 for w in sliding], qk_norm=True,
+        attn_gate=True, sandwich_norm=True,
+        embed_scale=float(d_model ** 0.5) if c.get("mup_enabled") else 1.0,
         tied_head=bool(c.get("tie_word_embeddings", False)), **kw)

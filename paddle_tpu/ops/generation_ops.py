@@ -133,6 +133,9 @@ def _paged_attention(ctx, ins, attrs):
     # head i reads KV head i // (n_head // n_kv_head)
     n_kv = int(attrs.get("n_kv_head") or n_head)
     page_size = int(attrs["page_size"])
+    # window_attention: a sliding-window layer's query attends only the
+    # last `window` positions up to its own
+    window = int(attrs.get("window") or 0)
     s = q.shape[0]
     p = bt.shape[-1]
     ctx_len = p * page_size
@@ -147,7 +150,7 @@ def _paged_attention(ctx, ins, attrs):
         out = _pk.paged_flash_attention(
             q, kp, vp, bt, pos,
             n_head=n_head, page_size=page_size, sm_scale=scale,
-            k_scales=ks, v_scales=vs, n_kv_head=n_kv,
+            k_scales=ks, v_scales=vs, n_kv_head=n_kv, window=window,
         )
         return {"Out": [out]}
 
@@ -189,10 +192,12 @@ def _paged_attention(ctx, ins, attrs):
     # pos sees context rows 0..pos inclusive (its own K/V row was written
     # earlier this step). Dead rows are EXCLUDED (weight exactly 0), not
     # additively depressed; a fully-masked row (pos < 0) emits zeros.
-    live = (
-        jnp.arange(ctx_len, dtype=jnp.int32)[None, :]
-        <= pos.reshape(-1).astype(jnp.int32)[:, None]
-    )[:, None, :]
+    at = jnp.arange(ctx_len, dtype=jnp.int32)[None, :]
+    row_pos = pos.reshape(-1).astype(jnp.int32)[:, None]
+    live = at <= row_pos
+    if window:
+        live = live & (at > row_pos - window)
+    live = live[:, None, :]
     scores = jnp.where(live, scores, -jnp.inf)
     m = jnp.max(scores, axis=-1, keepdims=True)
     m = jnp.where(jnp.isfinite(m), m, 0.0)
@@ -204,6 +209,15 @@ def _paged_attention(ctx, ins, attrs):
     else:
         out = jnp.einsum("shc,schd->shd", w, v.astype(jnp.float32))
     return {"Out": [out.reshape(s, n_head * d).astype(q.dtype)]}
+
+
+# A sliding-window layer's attention: paged_attention's lowering with the
+# attribute `window`, under an op name of its own so that a device trace
+# tells the two kinds of layer apart. Its block table may point behind the
+# window at the scratch page: those positions are masked, and the kernel does
+# not walk them.
+register("window_attention", no_grad=True,
+         infer_shape=_paged_attention_infer)(_paged_attention)
 
 
 def _mla_attention_infer(op, block):

@@ -1598,6 +1598,17 @@ _PAGED_PAGES_PER_BLOCK_CHUNK = 32  # chunked prefill: one shared table
 # what the walk's four page buffers (K and V, two blocks each) may take of the
 # ~16 MiB of VMEM a kernel is given: wide heads or large pages shrink the block
 _PAGED_BUFFER_BYTES = 8 * 1024 * 1024
+# A chunk's block holds at most this many positions: the kernel's code is
+# unrolled over a head's (rows, block) scores, and 32 pages of 64 (2,048
+# positions) made a 256-row chunk program of 32 layers 215 MB of code (a
+# described v5e); the accepted cells' 32 pages of 16 are 512
+_PAGED_CHUNK_BLOCK_POSITIONS = 512
+# A chunk whose heads are _PAGED_WIDE_HEAD wide or wider is given
+# _PAGED_WIDE_VMEM_BYTES of VMEM: its carries and a head's K and V slices in
+# float32 are twice what heads of 64 take, and a chunk of 256 rows of 32 heads
+# of 128 needed 44.5 MiB of the 40.4 the compiler allows by default
+_PAGED_WIDE_HEAD = 128
+_PAGED_WIDE_VMEM_BYTES = 100 * 1024 * 1024
 
 # Folded into serving/compile_cache.variant_key: an exported serving module
 # embeds the Mosaic kernels it was lowered with, so a cached export must miss
@@ -1632,35 +1643,45 @@ def paged_flash_path_taken(n_q, n_pages, page_size, n_head, d_head):
     return jax.default_backend() == "tpu" and int(page_size) % 8 == 0
 
 
-def _paged_block_pages(n_pages, page_bytes, shared):
+def _paged_block_pages(n_pages, page_bytes, shared, page_size=None):
     """Pages in a compute block: the module constant of the path, or the
     whole table where that is narrower, or what of it the two K and two V
     buffers' VMEM budget holds (page_bytes: one pool page, page_size *
-    n_head*d * itemsize)."""
+    n_head*d * itemsize); a chunk's at most _PAGED_CHUNK_BLOCK_POSITIONS
+    positions of `page_size`."""
     most = _PAGED_PAGES_PER_BLOCK_CHUNK if shared else _PAGED_PAGES_PER_BLOCK
+    if shared and page_size:
+        most = min(most, max(1, _PAGED_CHUNK_BLOCK_POSITIONS // int(page_size)))
     fits = _PAGED_BUFFER_BYTES // (4 * int(page_bytes))
     return max(1, min(most, int(n_pages), fits))
 
 
-def paged_positions_walked(pos, page_size, n_pages, row_bytes, shared=False):
+def paged_positions_walked(pos, page_size, n_pages, row_bytes, shared=False,
+                           window=0):
     """EXACT mirror of the kernel's trip count, in positions: what one
     paged_flash_attention call walks for these positions over a table
     n_pages wide whose pool rows take row_bytes (n_head*d * itemsize).
     Each row (decode), or the one shared table at max(pos) (chunked
     prefill, shared=True), takes pos // block + 1 blocks, none for
-    pos < 0, at most the table's worth."""
+    pos < 0, at most the table's worth. With a `window` the walk starts at
+    the block of the row's first position in it, max(pos - window + 1, 0)
+    (a chunk: of its first row's, min(pos)), and the blocks before are not
+    walked."""
     block = int(page_size) * _paged_block_pages(
-        n_pages, page_size * row_bytes, shared
+        n_pages, page_size * row_bytes, shared, page_size
     )
     pos = np.asarray(pos, dtype=np.int64).reshape(-1)
+    low = pos.min(keepdims=True) if shared else pos
     if shared:
         pos = pos.max(keepdims=True)
+    first = np.maximum(low - window + 1, 0) // block if window else 0
     most = -(-int(n_pages) * int(page_size) // block)
-    return int(np.clip(pos // block + 1, 0, most).sum()) * block
+    return int(np.clip(
+        np.clip(pos // block + 1, 0, most) - first, 0, None).sum()) * block
 
 
 def _paged_flash_kernel(*refs, page_size, n_head, n_kv_head, sm_scale,
-                        per_row, quant, n_pages, block_pages):
+                        per_row, quant, n_pages, block_pages, window=0):
     """Every query row the program owns against the pages its table holds up
     to pos, a compute block of block_pages pages a loop trip. per_row
     (decode): grid (slot,), the slot's one query row, its own block-table
@@ -1693,18 +1714,26 @@ def _paged_flash_kernel(*refs, page_size, n_head, n_kv_head, sm_scale,
     group, so each is one whole-feature product with the same K and V block
     and the indicator matrices of row g put its heads on lanes g * n_kv_head
     + j (with group 1 this is the one row and the one pair of matrices there
-    was). A chunk's query head h reads the lane slice of KV head h // group."""
+    was). A chunk's query head h reads the lane slice of KV head h // group.
+
+    window > 0 (a sliding-window layer): query t attends t - window < s <= t
+    alone. The walk starts at the block of the row's first such position
+    (decode) or of the chunk's first row's (shared: the scalar-prefetched
+    min of the positions), the pages before that position are not copied,
+    and the mask drops what lies behind each row's own bound."""
     bt_ref, sp_ref = refs[:2]
     refs = refs[2:]
     if per_row:
         row = pl.program_id(0)
         pos = last = sp_ref[row]
         page_of = lambda j: bt_ref[row, j]
+        low = jnp.maximum(pos - window + 1, 0) if window else 0
     else:
         posv_ref, refs = refs[0], refs[1:]
         pos = posv_ref[...]  # (rows, 1)
         last = sp_ref[0]
         page_of = lambda j: bt_ref[j]
+        low = jnp.maximum(sp_ref[1] - window + 1, 0) if window else 0
     q_ref, refs = refs[0], refs[1:]
     if quant:
         ks_ref, vs_ref, refs = refs[0], refs[1], refs[2:]
@@ -1717,6 +1746,7 @@ def _paged_flash_kernel(*refs, page_size, n_head, n_kv_head, sm_scale,
     d = (feat if per_row else bufs[0].shape[-1]) // n_kv_head
     block = block_pages * page_size
     n_blocks = jnp.clip(last // block + 1, 0, pl.cdiv(n_pages, block_pages))
+    first_block = low // block
 
     def head_lanes(shape, head_axis, g):
         # 1.0 where the lane (the other axis) belongs to the KV head whose
@@ -1740,8 +1770,11 @@ def _paged_flash_kernel(*refs, page_size, n_head, n_kv_head, sm_scale,
     l_ref[...] = jnp.zeros_like(l_ref)
 
     def page_copies(b, slot, start):
-        # the block's pages that hold a position up to pos, and are in the table
+        # the block's pages that hold a position up to pos, and are in the
+        # table, from the page of the window's first position on
         first = b * block_pages
+        skip = (jnp.clip(low // page_size - first, 0, block_pages)
+                if window else 0)
         n_live = jnp.clip(
             jnp.minimum(last // page_size + 1, n_pages) - first, 0, block_pages
         )
@@ -1755,7 +1788,7 @@ def _paged_flash_kernel(*refs, page_size, n_head, n_kv_head, sm_scale,
                 )
                 copy.start() if start else copy.wait()
 
-        jax.lax.fori_loop(0, n_live, _page, None)
+        jax.lax.fori_loop(skip, n_live, _page, None)
 
     def dot(x, y, contract=((1,), (0,))):
         return jax.lax.dot_general(
@@ -1792,9 +1825,8 @@ def _paged_flash_kernel(*refs, page_size, n_head, n_kv_head, sm_scale,
         ) * sm_scale
         if quant:
             s = s * column(ks_ref[pl.ds(b, 1), :])
-        live = b * block + jax.lax.broadcasted_iota(
-            jnp.int32, (block, _LANES), 0
-        ) <= pos
+        at = b * block + jax.lax.broadcasted_iota(jnp.int32, (block, _LANES), 0)
+        live = (at <= pos) & (at > pos - window) if window else at <= pos
         p, alpha, m_ref[...], l_ref[...] = softmax_step(
             s, live, m_ref[...], l_ref[...], 0
         )
@@ -1808,9 +1840,8 @@ def _paged_flash_kernel(*refs, page_size, n_head, n_kv_head, sm_scale,
             )
 
     def chunk_block(b, slot):
-        live = b * block + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, block), 1
-        ) <= pos
+        at = b * block + jax.lax.broadcasted_iota(jnp.int32, (rows, block), 1)
+        live = (at <= pos) & (at > pos - window) if window else at <= pos
         if quant:  # (1, block): this block's per-position scales
             k_scale = ks_ref[pl.ds(b, 1), :] * sm_scale
             v_scale = vs_ref[pl.ds(b, 1), :]
@@ -1830,9 +1861,9 @@ def _paged_flash_kernel(*refs, page_size, n_head, n_kv_head, sm_scale,
             m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
             l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
 
-    @pl.when(n_blocks > 0)
+    @pl.when(n_blocks > first_block)
     def _first():
-        page_copies(0, 0, True)
+        page_copies(first_block, first_block % 2, True)
 
     def _block(b, _):
         slot = b % 2
@@ -1844,7 +1875,7 @@ def _paged_flash_kernel(*refs, page_size, n_head, n_kv_head, sm_scale,
         page_copies(b, slot, False)
         (one_row_block if per_row else chunk_block)(b, slot)
 
-    jax.lax.fori_loop(0, n_blocks, _block, None)
+    jax.lax.fori_loop(first_block, n_blocks, _block, None)
 
     # safe softmax tail: a fully-masked row (pos < 0, nothing live) has
     # l = 0 and emits zeros instead of 0/0 nan — the where-mask contract
@@ -1867,7 +1898,8 @@ def _paged_flash_kernel(*refs, page_size, n_head, n_kv_head, sm_scale,
 
 def paged_flash_attention(q, k_pool, v_pool, block_table, pos, *, n_head,
                           page_size, sm_scale=None, k_scales=None,
-                          v_scales=None, interpret=None, n_kv_head=None):
+                          v_scales=None, interpret=None, n_kv_head=None,
+                          window=0):
     """Paged attention over the KV pool without materializing the gathered
     context. q is [rows, n_head*d]; block_table is [rows, P] (decode — one
     page list per query row) or [P] (chunked prefill — one list shared by
@@ -1888,7 +1920,12 @@ def paged_flash_attention(q, k_pool, v_pool, block_table, pos, *, n_head,
     [pool_rows] f32 per-row scales ride along, gathered through the table
     ahead of the call; the kernel scales the scores and the probabilities
     by them on the block-table walk, so no dequantized f32 row exists
-    anywhere."""
+    anywhere.
+
+    window > 0: a sliding-window layer's rows, each attending the last
+    `window` positions up to its own (pos - window < s <= pos); the walk
+    starts at the first of them and paged_positions_walked(window=) mirrors
+    it."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     rows, feat = q.shape
@@ -1901,8 +1938,13 @@ def paged_flash_attention(q, k_pool, v_pool, block_table, pos, *, n_head,
     per_row = bt.ndim == 2
     n_pages = bt.shape[-1]
     block_pages = _paged_block_pages(
-        n_pages, page_size * kv_feat * k_pool.dtype.itemsize, not per_row
+        n_pages, page_size * kv_feat * k_pool.dtype.itemsize, not per_row,
+        page_size
     )
+    wide = {}
+    if not per_row and d >= _PAGED_WIDE_HEAD:
+        wide["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=_PAGED_WIDE_VMEM_BYTES)
     _note_dispatch("paged_flash_int8" if quant else "paged_flash")
     if per_row:
         grid = (rows,)
@@ -1927,7 +1969,8 @@ def paged_flash_attention(q, k_pool, v_pool, block_table, pos, *, n_head,
         grid = (1,)
         whole = lambda i, bt_r, last_r: (0, 0)
         q_spec = pl.BlockSpec((rows, feat), whole)
-        prefetch = [bt, jnp.max(pos_v).reshape(1)]
+        prefetch = [bt, (jnp.stack([jnp.max(pos_v), jnp.min(pos_v)])
+                         if window else jnp.max(pos_v).reshape(1))]
         operands = [pos_v.reshape(rows, 1), q]
         in_specs = [pl.BlockSpec((rows, 1), whole), q_spec]
         out_shape = jax.ShapeDtypeStruct((rows, feat), q.dtype)
@@ -1968,7 +2011,7 @@ def paged_flash_attention(q, k_pool, v_pool, block_table, pos, *, n_head,
             _paged_flash_kernel, page_size=page_size, n_head=n_head,
             n_kv_head=n_kv, sm_scale=float(sm_scale or 0.0) or d**-0.5,
             per_row=per_row, quant=quant, n_pages=n_pages,
-            block_pages=block_pages,
+            block_pages=block_pages, window=int(window or 0),
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
@@ -1983,6 +2026,7 @@ def paged_flash_attention(q, k_pool, v_pool, block_table, pos, *, n_head,
         ),
         out_shape=out_shape,
         interpret=interpret,
+        **wide,
     )(*prefetch, *operands)
     if per_row:
         out = out.reshape(rows, group, n_kv, d).transpose(0, 2, 1, 3)

@@ -16,7 +16,9 @@ of the training executors.
 - generation.GenerationEngine / GenerationScheduler — autoregressive
   serving (ROADMAP item 3): AOT prefill buckets + one fixed-shape decode
   step over a paged KV-cache pool (kv_cache.PagedKVPool), token-level
-  continuous batching with mid-batch admission and EOS/max-len retirement.
+  continuous batching with mid-batch admission and EOS/max-len retirement;
+  a model's sliding-window layers keep their own page space
+  (kv_cache.WindowPages), only each row's last window of it.
 
 docs/serving.md covers the architecture, bucketing policy, cache layout,
 generation slot/page lifecycle, and flags.
@@ -38,7 +40,7 @@ from .generation import (  # noqa: F401
     GenRequest,
     GenResult,
 )
-from .kv_cache import PagedKVPool, PoolExhausted, PrefixCache  # noqa: F401
+from .kv_cache import PagedKVPool, PoolExhausted, PrefixCache, WindowPages  # noqa: F401
 from .server import ModelServer  # noqa: F401
 
 __all__ = [
@@ -57,4 +59,5 @@ __all__ = [
     "PagedKVPool",
     "PrefixCache",
     "PoolExhausted",
+    "WindowPages",
 ]

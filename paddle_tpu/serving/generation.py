@@ -88,7 +88,7 @@ from .batcher import (
     ServingFuture,
     ShutdownError,
 )
-from .kv_cache import PagedKVPool, PoolExhausted, PrefixCache
+from .kv_cache import PagedKVPool, PoolExhausted, PrefixCache, WindowPages
 from . import compile_cache as _cc
 
 __all__ = [
@@ -183,12 +183,13 @@ class _SlotRun:
     __slots__ = ("req", "slot", "table", "tokens", "next_pos", "rng",
                  "pf_pos", "done", "finish_reason", "future", "t_submit",
                  "t_first", "span", "cut", "snaps", "picks", "chunk_picks",
-                 "selections")
+                 "selections", "wtable")
 
     def __init__(self, req, slot, table, rng):
         self.req = req
         self.slot = slot
         self.table = table
+        self.wtable = None  # the sliding-window layers' table, if any
         self.tokens = []
         self.next_pos = len(req.prompt)
         self.rng = rng
@@ -414,10 +415,13 @@ class GenerationEngine:
     def __init__(self, model, name="generation", scope=None, place=None,
                  max_slots=4, page_size=8, pool_pages=None, max_context=None,
                  prefill_buckets=None, prefill_chunk=None, prefix_cache=True,
-                 cache_dir=None, snapshot_bytes=None):
+                 cache_dir=None, snapshot_bytes=None, window_pool_pages=None):
         """`snapshot_bytes`: the device memory the prefix cache's snapshots
         of the per-slot sequence state may take together (a model with conv
-        or recurrent layers; default four a slot)."""
+        or recurrent layers; default four a slot). `window_pool_pages`: the
+        pages of the sliding-window layers' own page space (a model with
+        such layers; default every slot's worst case, W + a chunk + a page
+        of positions, and the scratch page)."""
         import jax.numpy as jnp
 
         self.model = model
@@ -464,6 +468,19 @@ class GenerationEngine:
         self.scope = scope or Scope()
         model.ensure_params(self.scope, place)
         pool_rows = self.pool_pages * self.page_size
+        # sliding-window layers: their pools are a page space of their own
+        # (kv_cache.WindowPages), a row holding only its window's pages
+        self.window = int(getattr(model, "window", lambda: 0)())
+        self.window_pool = None
+        window_rows = 0
+        if self.window:
+            self.window_pool_pages = int(
+                window_pool_pages or self.max_slots * WindowPages.row_pages_for(
+                    self.window, self.prefill_chunk, self.page_size) + 1)
+            self.window_pool = WindowPages(
+                self.window_pool_pages, self.page_size, self.max_slots,
+                self.max_pages, self.window, self.prefill_chunk)
+            window_rows = self.window_pool_pages * self.page_size
         # int8 pool mode (model.kv_dtype == "int8"): level pools are int8
         # and each gains a [pool_rows] f32 per-row scale pool sibling
         # (model.kv_scale_names) — ~1/4 the f32 bytes per cached token
@@ -493,6 +510,8 @@ class GenerationEngine:
                 self.state_row_bytes += spec["width"] * dtype.itemsize
                 if spec.get("form") == "recurrent":
                     self.recurrent_row_bytes += spec["width"] * dtype.itemsize
+            elif spec.get("window"):
+                arr = jnp.zeros((window_rows, spec["width"]), dtype)
             else:
                 if spec["width"]:
                     arr = jnp.zeros((pool_rows, spec["width"]), dtype)
@@ -527,10 +546,13 @@ class GenerationEngine:
         self.pool.state_bytes = self.seq_state_bytes
         self.prefix_cache = (
             PrefixCache(self.pool, state_bytes=self.state_row_bytes,
-                        snapshot_bytes=snapshot_bytes)
+                        snapshot_bytes=snapshot_bytes,
+                        window_pool=self.window_pool)
             if prefix_cache else None)
         self._build_kw = (
             {"state_rows": self.max_slots + 1} if self._state_names else {})
+        if self.window:
+            self._build_kw["window_rows"] = window_rows
         self._state_fns = None  # (snapshot, restore), compiled at warm-up
         self._row_fn = None  # a sampled row's logits, compiled at warm-up
         # the last decode step's and the last prompt's logits (_Logits)
@@ -566,6 +588,10 @@ class GenerationEngine:
             "dec_live": np.zeros((self.max_slots,), np.int32),
             "dec_state_rows": np.zeros((self.max_slots,), np.int32),
         }
+        if self.window:
+            # each slot's window-layer table, kept as its window moves
+            self._dec_feeds["dec_window_table"] = np.zeros(
+                (self.max_slots, self.max_pages), np.int32)
         # the ids of the decode step dispatched last, on the device: what the
         # next one is fed as dec_prev_ids, read by the host or not
         self._ids = jnp.zeros((self.max_slots,), jnp.int32)
@@ -768,6 +794,35 @@ class GenerationEngine:
             "live and state rows",
             buckets=FETCH_BYTE_BUCKETS,
         )
+        # sliding-window layers: what their attention had to read, and the
+        # pages they hold (live rows' and the prefix cache's)
+        self._m_window = {
+            what: reg.histogram(p + "/gen_%s" % what, help,
+                                **({"buckets": b} if b else {}))
+            for what, help, b in (
+                ("window_ctx_tokens",
+                 "positions one decode step's window layers attend, summed "
+                 "over its live rows (each row at most the window)",
+                 CTX_TOKEN_BUCKETS),
+                ("chunk_window_tokens",
+                 "distinct positions one prefill chunk's window layers "
+                 "attend: its rows and the window behind its first",
+                 CTX_TOKEN_BUCKETS),
+                ("chunk_window_pairs",
+                 "(row, position) pairs one prefill chunk's window layers "
+                 "attend: each real row and the window up to it",
+                 tuple(b * 64 for b in CTX_TOKEN_BUCKETS)),
+                ("window_pages_held",
+                 "window-layer pages held by live rows and the prefix cache, "
+                 "observed once a decode step",
+                 CTX_TOKEN_BUCKETS),
+                ("window_release_ms",
+                 "drawing the window pages a program's rows reach and "
+                 "returning those their windows passed (span fluid.engine."
+                 "window_release), wall ms",
+                 None),
+            )
+        } if self.window else {}
         # whoever drives the engine step by step (the scheduler) writes its
         # iteration count here; every fluid.engine.* span and the request
         # tracer's engine.decode / engine.prefill spans carry it as `iter`
@@ -886,6 +941,9 @@ class GenerationEngine:
             "max_context": self.max_context,
             "kv_dtype": self.kv_dtype,
         }
+        if self.window:
+            geo["window"] = self.window
+            geo["window_pool_pages"] = self.window_pool_pages
         spec = getattr(self.model, "spec", None)
         if spec is not None:  # a block-specified decoder: its layers are data
             geo["block_spec"] = spec()
@@ -1214,9 +1272,12 @@ class GenerationEngine:
             shared, state, matched = [], None, 0
             if self.prefix_cache is not None:
                 # pages pinned; with a per-sequence state, only down to a
-                # boundary whose snapshot comes with them
+                # boundary whose snapshot comes with them; with window
+                # layers, to a depth whose window pages are kept, with them
                 shared, state, matched = self.prefix_cache.lookup_state(
                     req.prompt)
+            wshared = state if self.window and state else []
+            wtable = None
             try:
                 try:
                     slot, table = self.pool.acquire(L + max_new, shared)
@@ -1226,14 +1287,26 @@ class GenerationEngine:
                             or not self.prefix_cache.evict_for(need)):
                         raise
                     slot, table = self.pool.acquire(L + max_new, shared)
+                if self.window:
+                    try:
+                        wtable = self.window_pool.acquire(
+                            slot, L + max_new, wshared,
+                            len(shared) - len(wshared),
+                            spare=self._window_spare())
+                    except PoolExhausted:
+                        self.pool.release(slot)
+                        raise
             finally:
                 if shared:
                     self.pool.unpin_pages(shared)  # slot ref (or nothing) holds now
+                if wshared:
+                    self.window_pool.unpin_pages(wshared)
             seed = req.seed
             if seed is None:
                 seed = (self.scope._seed, self._sample_counter)
                 self._sample_counter += 1
             run = _SlotRun(req, slot, table, np.random.default_rng(seed))
+            run.wtable = wtable
             run.pf_pos = len(shared) * self.page_size
             if self._state_names and self.prefix_cache is not None:
                 if state is not None:
@@ -1268,6 +1341,8 @@ class GenerationEngine:
             )
         t0 = time.perf_counter()
         try:
+            if self.window:
+                self._advance_window([(run, start, start + n_real)])
             with self._phase("feed", "prefill"):
                 tokens = np.zeros((1, c, 1), np.int64)
                 tokens[0, :n_real, 0] = req.prompt[start:start + n_real]
@@ -1281,6 +1356,7 @@ class GenerationEngine:
                     # conv state
                     "gen_live": np.arange(c) < n_real,
                     "gen_state_row": np.array([run.slot + 1], np.int32),
+                    "gen_window_pages": run.wtable,
                 })
             with self._phase("dispatch", "prefill") as dispatch:
                 logits, *extra, ids = self._dispatch(variant, feeds, mut_in)
@@ -1299,6 +1375,12 @@ class GenerationEngine:
             n_real * start + n_real * (n_real + 1) // 2)
         if self.recurrent_row_bytes:
             self._m_chunk_ssm_tokens.observe(n_real)
+        if self.window:
+            w = self.window
+            self._m_window["chunk_window_tokens"].observe(
+                start + n_real - max(0, start - w + 1))
+            reach = np.minimum(np.arange(start, start + n_real) + 1, w)
+            self._m_window["chunk_window_pairs"].observe(int(reach.sum()))
         run.pf_pos = start + n_real
         if extra:  # the selections only where they are recorded
             run.chunk_picks.append((
@@ -1344,13 +1426,16 @@ class GenerationEngine:
                    if req.temperature else int(ids[0]))
             self._append_token(run, tok, self._max_new(req))
             if self.prefix_cache is not None:
-                self.prefix_cache.insert(req.prompt, run.table, run.snaps)
+                self.prefix_cache.insert(req.prompt, run.table, run.snaps,
+                                         run.wtable)
                 run.snaps = {}
             # arm the slot's persistent decode-feed rows only now: until the
             # last chunk lands, an interleaved decode step must keep this
             # slot on the scratch page, never writing a page a chunk already
             # filled
             self._dec_feeds["dec_block_table"][run.slot] = run.table
+            if self.window:
+                self._dec_feeds["dec_window_table"][run.slot] = run.wtable
             self._dec_feeds["dec_tokens"][run.slot, 0] = run.tokens[-1]
             self._dec_feeds["dec_positions"][run.slot, 0] = run.next_pos
             self._set_pool_gauges()
@@ -1458,6 +1543,15 @@ class GenerationEngine:
             self._m_found_dry.observe(1 if self._last_ids.is_ready() else 0)
         t0 = time.perf_counter()
         try:
+            if self.window:
+                self._advance_window(
+                    [(run, run.next_pos, run.next_pos + 1) for run in runs])
+                for run in runs:
+                    self._dec_feeds["dec_window_table"][run.slot] = run.wtable
+                self._m_window["window_ctx_tokens"].observe(sum(
+                    min(run.next_pos + 1, self.window) for run in runs))
+                self._m_window["window_pages_held"].observe(
+                    self.window_pool.stats()["pages_in_use"])
             with self._phase("feed", "decode"):
                 feeds = self._dec_feeds
                 tokens, positions = feeds["dec_tokens"], feeds["dec_positions"]
@@ -1625,18 +1719,47 @@ class GenerationEngine:
                 if not run.done:
                     run.picks.append((pos, picks[:, run.slot:run.slot + 1]))
 
+    def _advance_window(self, spans):
+        """Before a program: for each (run, first query position, end) the
+        window pages its positions reach, drawn, and those its window has
+        passed, returned (kv_cache.WindowPages.advance)."""
+        with RecordEvent(span="engine.window_release",
+                         hist=self._m_window["window_release_ms"],
+                         iter=self.iter):
+            for run, first, end in spans:
+                while True:
+                    try:
+                        run.wtable = self.window_pool.advance(
+                            run.slot, first, end)
+                        break
+                    except PoolExhausted:
+                        # the row's reserve counted the pages only the
+                        # prefix cache holds: take one of those
+                        if (self.prefix_cache is None
+                                or not self.prefix_cache.evict_window(1)):
+                            raise
+
+    def _window_spare(self):
+        """Window pages only the prefix cache holds: free on demand."""
+        return (self.prefix_cache.reclaimable_window()
+                if self.prefix_cache is not None else 0)
+
     def finish(self, run):
         """Retire a run's slot: pages return to the pool for reuse (cached
         prefix pages stay alive under the trie's reference) and the slot's
         persistent decode-feed rows drop back to the scratch page so the
         next tenant can't inherit a stale table."""
         self.pool.release(run.slot)
+        if self.window:
+            self.window_pool.release(run.slot)
         self._disarm(run.slot)
         self._set_pool_gauges()
 
     def _disarm(self, slot):
         """A slot's persistent decode-feed rows back to the scratch page."""
         self._dec_feeds["dec_block_table"][slot] = 0
+        if self.window:
+            self._dec_feeds["dec_window_table"][slot] = 0
         self._dec_feeds["dec_tokens"][slot] = 0
         self._dec_feeds["dec_positions"][slot] = 0
 
@@ -1729,6 +1852,7 @@ class GenerationEngine:
             # step's
             "decode_fetch_bytes": self.fetch_bytes,
             "pool": self.pool.stats(),
+            **({"window_pool": self.window_pool.stats()} if self.window else {}),
             # lowering-time kernel choices (counts are per trace, not per
             # call): the smoke/bench stages assert paged_flash shows up here
             # when the flag forces it
@@ -2043,6 +2167,11 @@ class GenerationScheduler(ContinuousBatcher):
         pages_left = st["pages_total"] - st["pages_in_use"]
         if self.engine.prefix_cache is not None:
             pages_left += self.engine.prefix_cache.reclaimable()
+        wpool = self.engine.window_pool
+        # the window layers' page space: a row's reserve, and the window of
+        # cached pages a hit pins beside it (both at their most)
+        window_left = (wpool.available(self.engine._window_spare())
+                       if wpool is not None else 0)
         admits = []
         while self._queue and len(admits) < min(budget, slots_left):
             nxt = self._queue[0]
@@ -2064,6 +2193,14 @@ class GenerationScheduler(ContinuousBatcher):
             )
             if need > pages_left:
                 break
+            if wpool is not None:
+                wneed = (wpool.reservation(
+                    len(nxt.req.prompt) + self.engine._max_new(nxt.req))
+                    + min(wpool.pages_for(len(nxt.req.prompt)),
+                          wpool.window_pages))
+                if wneed > window_left:
+                    break
+                window_left -= wneed
             pages_left -= need
             admits.append(self._queue.pop(0))
             self._queued_rows -= 1

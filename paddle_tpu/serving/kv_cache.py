@@ -60,6 +60,27 @@ reserve ``snapshot_bytes`` (the coldest go first, LRU among the nodes that
 have one; the node and its page stay) and go with their node on eviction;
 ``stats()`` reports the bytes held and reserved beside the pages.
 
+**A bounded window of cache a layer** (ROADMAP R-A4: a model whose
+sliding-window layers attend only the last W positions). Those layers' pools
+are a page space of their own, **WindowPages**, beside the full layers':
+page 0 scratch, free list and refcounts as above, one table a slot indexed
+by the same logical page (position // page_size). A row draws a window
+page only as it reaches it and returns each page once every position on it
+lies behind the window of its next query (``advance``), so a row holds
+O(W) positions there, not O(context); its table entry drops back to the
+scratch page. Admission reserves what the row can hold at once, min(its
+positions, W + a chunk + a page) less what a prefix hit brings, and the
+pool admits a row only while the free pages and the prefix cache's
+reclaimable ones cover every live row's reserve. A prefix-cache node keeps
+its full page and, where the row that inserted it still held it, the
+window page of the same positions; a hit is taken only at a depth whose
+last W positions' window pages are all kept (``lookup_state``: those
+window pages are what such a hit restores, as a snapshot is a state's), so a
+cached document of any length comes back with all of its full pages and
+the last window of its window pages. The engine counts the window pages
+only the cache holds (``reclaimable_window``) as free when it reserves, and
+evicts them (``evict_window``) when a row finds the free list empty.
+
 Thread-safety: the GenerationScheduler's worker thread is the only caller;
 a lock still guards acquire/release so `stats()` from other threads is
 consistent.
@@ -70,7 +91,7 @@ import threading
 
 import numpy as np
 
-__all__ = ["PagedKVPool", "PoolExhausted", "PrefixCache"]
+__all__ = ["PagedKVPool", "PoolExhausted", "PrefixCache", "WindowPages"]
 
 SCRATCH_PAGE = 0
 
@@ -234,10 +255,144 @@ class PagedKVPool:
             }
 
 
+class WindowPages(PagedKVPool):
+    """The sliding-window layers' page space (module docstring): pages drawn
+    as a row reaches them and returned once its window has passed them.
+    Page ids, refcounts, pins and tables are PagedKVPool's; a slot is the
+    full pool's, given. `spare`, where a method takes it, is the pages only
+    the prefix cache holds (PrefixCache.reclaimable_window): free on
+    demand."""
+
+    def __init__(self, n_pages, page_size, max_slots, max_pages_per_slot,
+                 window, chunk):
+        super().__init__(n_pages, page_size, max_slots, max_pages_per_slot)
+        self.window = int(window)
+        self.row_pages = self.row_pages_for(window, chunk, page_size)
+        # the pages behind a hit that its first query attends
+        self.window_pages = -(-self.window // self.page_size)
+        self._reserve = {}  # slot -> pages it may hold at once
+        self._drawn = {}  # slot -> pages it drew and still holds
+        self._hit = {}  # slot -> the table entries a prefix hit filled
+        self._low = {}  # slot -> the first entry it may still hold
+        self.returned = 0  # pages a live row passed and let go of
+
+    @staticmethod
+    def row_pages_for(window, chunk, page_size):
+        """The most window pages a row holds at once: a chunk's rows attend
+        back window - 1 positions from its first, and the span may straddle
+        one more page."""
+        return -(-(int(window) + int(chunk)) // int(page_size)) + 1
+
+    def reservation(self, n_positions, hit_pages=0):
+        """Window pages a row of `n_positions` positions may hold at once
+        beyond the `hit_pages` a prefix hit brings."""
+        return max(0, min(self.pages_for(n_positions) - int(hit_pages),
+                          self.row_pages))
+
+    def available(self, spare=0):
+        """Pages a new row may reserve: the free ones and the `spare` ones,
+        less what the live rows may still draw."""
+        with self._lock:
+            owed = sum(self._reserve[s] - self._drawn[s] for s in self._tables)
+            free = len(self._free_pages)
+        return free + int(spare) - owed
+
+    def acquire(self, slot, n_positions, shared=(), first=0, spare=0):
+        """A table for `slot`: `shared` (alive, pinned by the caller's
+        lookup) fill entries first, first + 1, ..., each gaining a
+        reference; the reserve is counted, no page is drawn. Raises
+        PoolExhausted when the reserve does not fit."""
+        shared = [int(p) for p in shared]
+        hit = first + len(shared)
+        need, have = self.reservation(n_positions, hit), self.available(spare)
+        if self.pages_for(n_positions) > self.max_pages_per_slot:
+            raise PoolExhausted("%d positions are past the window table"
+                                % n_positions)
+        if need > have:
+            raise PoolExhausted("need %d window pages, %d available"
+                                % (need, have))
+        table = np.full(self.max_pages_per_slot, SCRATCH_PAGE, np.int32)
+        with self._lock:
+            for i, pid in enumerate(shared):
+                if self._refs.get(pid, 0) < 1:
+                    raise ValueError("shared window page %d is not alive" % pid)
+                table[first + i] = pid
+                self._refs[pid] += 1
+            self._tables[slot] = table
+            self._reserve[slot], self._drawn[slot] = need, 0
+            self._low[slot], self._hit[slot] = first, hit
+        return table
+
+    def advance(self, slot, first_query, end):
+        """Before a program writes positions up to `end` (exclusive) of
+        `slot` and attends from `first_query` on: draw the pages those
+        positions reach and return those wholly behind first_query's window
+        (their entries drop back to the scratch page). Returns the table.
+        Raises PoolExhausted when the free list runs out first (the caller
+        evicts a cached page and calls again: the pages drawn stay)."""
+        ps = self.page_size
+        keep = max(0, int(first_query) - self.window + 1) // ps
+        with self._lock:
+            table = self._tables[slot]
+            for j in range(self._low[slot], keep):
+                pid = int(table[j])
+                if pid != SCRATCH_PAGE:
+                    table[j] = SCRATCH_PAGE
+                    if j >= self._hit[slot]:
+                        self._drawn[slot] -= 1
+                    self._unref_locked(pid)
+                    self.returned += 1
+            self._low[slot] = max(self._low[slot], keep)
+            want = [j for j in range(max(self._hit[slot], keep),
+                                     -(-int(end) // ps))
+                    if table[j] == SCRATCH_PAGE]
+        for j in want:
+            with self._lock:
+                if self._drawn[slot] >= self._reserve[slot]:
+                    raise RuntimeError(
+                        "slot %d drew its reserve of %d window pages"
+                        % (slot, self._reserve[slot]))
+                if not self._free_pages:
+                    raise PoolExhausted(
+                        "slot %d: no window page for position %d" % (slot, j * ps))
+                pid = self._free_pages.pop()
+                self._refs[pid] = 1
+                table[j] = pid
+                self._drawn[slot] += 1
+        return table
+
+    def release(self, slot):
+        with self._lock:
+            table = self._tables.pop(slot, None)
+            if table is None:
+                return
+            for p in table:
+                if p != SCRATCH_PAGE:
+                    self._unref_locked(int(p))
+            for d in (self._reserve, self._drawn, self._hit, self._low):
+                del d[slot]
+
+    def stats(self):
+        with self._lock:
+            in_use = (self.n_pages - 1) - len(self._free_pages)
+            rows = sum(int(np.count_nonzero(t)) for t in self._tables.values())
+            return {
+                "pages_total": self.n_pages - 1,
+                "pages_in_use": in_use,
+                "pages_in_tables": rows,
+                "pages_reserved": sum(self._reserve.values()),
+                "pages_returned": self.returned,
+                "row_pages": self.row_pages,
+                "window": self.window,
+            }
+
+
 class _PrefixNode:
-    __slots__ = ("page", "stamp", "state", "uid", "parent", "depth", "children")
+    __slots__ = ("page", "stamp", "state", "uid", "parent", "depth", "children",
+                 "wpage")
 
     def __init__(self, page, stamp, state, uid, parent):
+        self.wpage = None  # the window layers' page of the same positions
         self.page = page
         self.stamp = stamp
         self.state = state  # the sequence state at this boundary, if kept
@@ -255,8 +410,14 @@ class PrefixCache:
     """Prompt-token trie over immutable full KV pages (module docstring)."""
 
     def __init__(self, pool, capacity_pages=None, state_bytes=0,
-                 snapshot_bytes=None):
+                 snapshot_bytes=None, window_pool=None):
         self.pool = pool
+        # WindowPages of a model with sliding-window layers: a node also
+        # keeps their page where the inserting row still held it
+        self.wpool = window_pool
+        if window_pool is not None and state_bytes:
+            raise ValueError("a prefix cache keeps window pages or state "
+                             "snapshots, not both")
         # state_bytes > 0: the model keeps a fixed per-sequence state beside
         # its pages, state_bytes a sequence, and only a boundary that
         # carries a snapshot of it can be hit. The reserve snapshot_bytes is
@@ -311,7 +472,14 @@ class PrefixCache:
         deepest boundary that carries a snapshot, `state` is that snapshot
         (None without a hit) and `matched` >= len(pages) is how many pages
         of the prompt the trie holds at all: a boundary other prompts share,
-        where this prompt's prefill should leave a snapshot."""
+        where this prompt's prefill should leave a snapshot.
+
+        With sliding-window layers (a window pool) the window pages are the
+        state a hit restores: the pages stop at the deepest depth k whose
+        first query's window, the pages from (k * page_size - W + 1) //
+        page_size to k - 1, all kept their window page, and `state` is
+        those window pages in order (the first at entry k - len(state)),
+        pinned as the pages are."""
         with self.phase("lookup"):
             ps = self.pool.page_size
             prompt = tuple(int(t) for t in prompt)
@@ -336,6 +504,19 @@ class PrefixCache:
                             self.states_hit += 1
                             break
                     del found[usable:]
+                if self.wpool is not None:
+                    w = self.wpool.window
+                    usable = len(found)
+                    while usable:
+                        lo = max(0, usable * ps - w + 1) // ps
+                        gap = [j for j in range(lo, usable)
+                               if found[j].wpage is None]
+                        if not gap:
+                            break
+                        usable = gap[-1]
+                    lo = max(0, usable * ps - w + 1) // ps
+                    state = [node.wpage for node in found[lo:usable]]
+                    del found[usable:]
                 pages = [node.page for node in found]
                 self.pages_eligible += eligible
                 self.pages_hit += len(pages)
@@ -345,15 +526,20 @@ class PrefixCache:
                     self.misses += 1
             if pages:
                 self.pool.pin_pages(pages)
+                if self.wpool is not None:
+                    self.wpool.pin_pages(state)
             return pages, state, matched
 
-    def insert(self, prompt, table, states=None):
+    def insert(self, prompt, table, states=None, window_table=None):
         """Publish a finished prefill's full prompt pages into the trie.
         Valid by the immutability invariant: pages 0..len(prompt)//ps - 1
         hold exactly the prompt tokens' K/V and nothing ever rewrites
         them. Already-cached depths are left alone, but for a snapshot they
         lack: `states` is {tokens: the sequence state after that many prompt
-        tokens}, what this prefill left at its page-aligned chunk ends."""
+        tokens}, what this prefill left at its page-aligned chunk ends; and
+        for a window page they lack: `window_table` is the row's window
+        table, whose entries still held (not the scratch page) the nodes
+        keep."""
         with self.phase("insert"):
             states = states or {}
             ps = self.pool.page_size
@@ -366,9 +552,14 @@ class PrefixCache:
                 for i in range(n_full):
                     key = (parent.uid if parent else 0, prompt[i * ps:(i + 1) * ps])
                     state = states.get((i + 1) * ps)
+                    wpage = (int(window_table[i]) if window_table is not None
+                             else SCRATCH_PAGE)
                     node = self._nodes.get(key)
                     if node is not None:
                         node.stamp = self._clock
+                        if wpage != SCRATCH_PAGE and node.wpage is None:
+                            self.wpool.pin_pages([wpage])
+                            node.wpage = wpage
                         if state is not None and node.state is None:
                             node.state = state
                             self.states_taken += 1
@@ -389,6 +580,9 @@ class PrefixCache:
                         break
                     self.pool.pin_pages([page])
                     node = _PrefixNode(page, self._clock, state, self._next_uid, parent)
+                    if wpage != SCRATCH_PAGE:
+                        self.wpool.pin_pages([wpage])
+                        node.wpage = wpage
                     self._next_uid += 1
                     if parent is not None:
                         parent.children += 1
@@ -433,18 +627,46 @@ class PrefixCache:
                 # only pages no slot is reading (our pin is the sole reference)
                 if self.pool.page_refcount(node.page) != 1:
                     continue
+                if node.wpage is not None and (
+                        self.wpool.page_refcount(node.wpage) != 1):
+                    continue
                 if node.children:
                     continue  # has live descendants; they sort earlier anyway
                 del self._nodes[key]
                 if node.parent is not None:
                     node.parent.children -= 1
                 self.pool.unpin_pages([node.page])
+                if node.wpage is not None:
+                    self.wpool.unpin_pages([node.wpage])
                 if node.state is not None:
                     self.states_evicted += 1
                     self._n_states -= 1
                 self.evictions += 1
                 evicted += 1
             return evicted
+
+    def evict_window(self, n_pages):
+        """Free up to `n_pages` window pages only the trie holds, the least
+        recently used first; their nodes and full pages stay (a deeper hit
+        through them falls back to a depth whose window is whole)."""
+        with self._lock, self.phase("evict"):
+            kept = sorted((n for n in self._nodes.values()
+                           if n.wpage is not None), key=lambda n: n.stamp)
+            freed = 0
+            for node in kept:
+                if freed >= n_pages:
+                    break
+                if self.wpool.page_refcount(node.wpage) == 1:
+                    self.wpool.unpin_pages([node.wpage])
+                    node.wpage = None
+                    freed += 1
+            return freed
+
+    def reclaimable_window(self):
+        """Window pages only the trie holds."""
+        with self._lock:
+            return self.wpool.count_sole_refs(
+                [n.wpage for n in self._nodes.values() if n.wpage is not None])
 
     def reclaimable(self):
         """Cached pages only the trie holds — evictable on demand (the
@@ -457,6 +679,8 @@ class PrefixCache:
         with self._lock:
             for node in self._nodes.values():
                 self.pool.unpin_pages([node.page])
+                if node.wpage is not None:
+                    self.wpool.unpin_pages([node.wpage])
             n = len(self._nodes)
             self._nodes.clear()
             self._n_states = 0
@@ -474,6 +698,8 @@ class PrefixCache:
                 "states_hit": self.states_hit,
                 "states_evicted": self.states_evicted,
                 "cached_pages": len(self._nodes),
+                "cached_window_pages": sum(
+                    1 for n in self._nodes.values() if n.wpage is not None),
                 "capacity_pages": self.capacity_pages,
                 "lookups_hit": self.hits,
                 "lookups_miss": self.misses,
